@@ -27,8 +27,8 @@ buffer must come back deleted (donated to XLA), not merely unused.
 Artifact
 --------
 ``--out`` (default COLDSTART_r01.json) gets a
-``paddle_tpu.coldstart_bench.v1`` document; BENCHMARKS.md records the
-acceptance row (aot boot >= --min-speedup x faster, default 3.0).
+``paddle_tpu.coldstart_bench.v1`` document; the acceptance row is aot
+boot >= --min-speedup x faster (default 3.0).
 
 Usage
 -----

@@ -30,8 +30,8 @@ XLA program per bucket, hit rate ~1.0).
 Artifact
 --------
 ``--out`` (default serving_bench.json) gets a
-``paddle_tpu.serving_bench.v1`` document; BENCHMARKS.md documents the
-schema and records the acceptance row.
+``paddle_tpu.serving_bench.v1`` document (a CPU control-flow check when
+run under JAX_PLATFORMS=cpu, not a device measurement).
 
 Usage
 -----

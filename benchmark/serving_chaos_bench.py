@@ -28,7 +28,7 @@ Modes (``--mode``, default ``chaos``; ``--smoke`` runs the CI gate):
 
 Artifact: ``--out`` (default serving_chaos_bench.json) gets a
 ``paddle_tpu.serving_chaos.v1`` document; the checked-in run is
-``SERVING_CHAOS_r01.json`` (schema documented in BENCHMARKS.md).
+``SERVING_CHAOS_r01.json``.
 
 Usage:
     python benchmark/serving_chaos_bench.py [--mode=chaos|fairness|all]

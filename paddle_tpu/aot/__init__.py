@@ -7,12 +7,11 @@ as serialized XLA executables in a versioned artifact directory
 from that store: the Executor consults it at every compile-cache miss
 and, on a manifest match, deserializes instead of tracing+compiling.
 
-Unlike the jax persistent compile cache (unusable on this jaxlib —
-PR 15's ``_donation_ok()`` kill-switch exists because cache-loaded
-executables corrupt donation aliasing), this path serializes through
-``jax.experimental.serialize_executable`` with the donation mask pinned
-in the manifest and re-proved at load: donation stays ACTIVE on
-artifact-booted replicas.  Any mismatch — version skew, device kind,
+Beside the jax persistent compile cache (which skips XLA compilation
+but still traces and lowers), this path serializes whole executables
+through ``jax.experimental.serialize_executable`` with the donation mask
+pinned in the manifest and re-proved at load: a boot neither traces nor
+compiles, and donation stays active on artifact-booted replicas.  Any mismatch — version skew, device kind,
 tuning-DB drift, fingerprint drift, corrupt payload, donation drift —
 is a loud JIT fallback counted in ``aot_load_total{result}``: slower,
 never wrong.

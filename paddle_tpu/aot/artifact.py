@@ -165,6 +165,10 @@ class ArtifactWriter:
             "out_state_names": list(out_state_names),
             "written_names": list(written_names),
             "uses_rng": bool(uses_rng),
+            # the devices the executable was compiled for: the load side
+            # must name them, or jax loads it over every visible device
+            "device_ids": [d.id for d in
+                           executable.runtime_executable().local_devices()],
             "file": rel,
             "sha256": hashlib.sha256(blob).hexdigest(),
             "nbytes": len(blob),
@@ -304,6 +308,7 @@ class ArtifactStore:
         return meta, loaded
 
     def _deserialize(self, meta: dict):
+        import jax
         from jax.experimental import serialize_executable as _ser
 
         path = os.path.join(self.root, meta["file"])
@@ -314,8 +319,10 @@ class ArtifactStore:
                 raise ValueError("payload sha256 mismatch (truncated or "
                                  "corrupt executable file)")
             doc = pickle.loads(blob)
+            by_id = {d.id: d for d in jax.devices()}
             return _ser.deserialize_and_load(
-                doc["payload"], doc["in_tree"], doc["out_tree"])
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[by_id[i] for i in meta["device_ids"]])
         except Exception as exc:
             self._warn(f"entry {meta['id']}: {type(exc).__name__}: {exc}")
             self._count("rejected_corrupt")

@@ -19,7 +19,7 @@ into pages once.
 
 Everything runs under ``interpret=True`` on CPU for numerics tests; the
 jnp reference (``ragged_paged_attention_reference``) is both the test
-oracle and the dispatch fallback off-TPU.
+oracle and what dispatch picks off-TPU or at shapes ``fits()`` rejects.
 """
 
 from __future__ import annotations
@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.pallas import compat as _compat
 
 _F32 = jnp.float32
 _NEG_INF = -1e30  # matches flash_attention: finite, avoids inf-inf NaN
@@ -114,6 +113,32 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_tables,
 # ---------------------------------------------------------------------------
 
 
+def _page_update(q, k, v, m_prev, l_prev, acc_prev, t0, seq_len, scale):
+    """One page's online-softmax update for ONE query row per head.
+
+    q (H, D); k, v (page, H, D); m/l (H, 1); acc (H, D); ``t0`` is the
+    page's first position.  With a single query token there is no free
+    (row) dimension for an MXU matmul — Mosaic rejects a batched
+    ``dot_general`` whose left operand has only a batch and a
+    contracting dim — so the scores and the ``pr . v`` product are VPU
+    multiply-reduces: over the lane dim D for the scores, over the
+    leading page dim for the accumulator.  Decode attention is bound by
+    the page DMA, not by these FLOPs.
+    """
+    q = q.astype(_F32)
+    k = k.astype(_F32)
+    v = v.astype(_F32)
+    sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale  # (page, H, 1)
+    t_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+    sc = jnp.where(t_pos < seq_len, sc, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))            # (H, 1)
+    pr = jnp.exp(sc - m_new[None])                              # (page, H, 1)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(pr, axis=0)
+    acc_new = acc_prev * corr + jnp.sum(pr * v, axis=0)         # (H, D)
+    return m_new, l_new, acc_new
+
+
 def _rpa_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                 m_scr, l_scr, acc_scr, *, scale, page, npp):
     """One (slot, page) grid step: accumulate this page's contribution
@@ -134,31 +159,16 @@ def _rpa_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page < seq_len)
     def _page():
-        q = q_ref[0].astype(_F32)                       # (H, D)
-        k = k_ref[0].astype(_F32)                       # (page, H, D)
-        v = v_ref[0].astype(_F32)
-        # scores (H, page): per-head q . k_t, contracted over D
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=_F32) * scale
-        t_pos = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        sc = jnp.where(t_pos < seq_len, sc, _NEG_INF)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        pr = jnp.exp(sc - m_new)                        # (H, page)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, 0:1] = l_scr[:, 0:1] * corr + jnp.sum(pr, axis=1,
-                                                       keepdims=True)
-        m_scr[:, 0:1] = m_new
-        # (H, page) x (page, H, D) batched over H -> (H, D)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            pr, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=_F32)
+        m_new, l_new, acc_new = _page_update(
+            q_ref[0], k_ref[0], v_ref[0], m_scr[...], l_scr[...],
+            acc_scr[...], p * page, seq_len, scale)
+        m_scr[...] = m_new
+        l_scr[...] = l_new
+        acc_scr[...] = acc_new
 
     @pl.when(p == npp - 1)
     def _finish():
-        l = l_scr[:, 0:1]
+        l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
@@ -187,27 +197,13 @@ def _rpa_kernel_blocked(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page < seq_len)
     def _page():
-        q = q_ref[pl.ds(r, 1)][0].astype(_F32)          # (H, D)
-        k = k_ref[0].astype(_F32)                       # (page, H, D)
-        v = v_ref[0].astype(_F32)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=_F32) * scale
-        t_pos = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        sc = jnp.where(t_pos < seq_len, sc, _NEG_INF)
-        m_prev = m_scr[pl.ds(r, 1)][0]                  # (H, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        pr = jnp.exp(sc - m_new)                        # (H, page)
-        corr = jnp.exp(m_prev - m_new)
-        l_prev = l_scr[pl.ds(r, 1)][0]
-        l_scr[pl.ds(r, 1)] = (l_prev * corr + jnp.sum(
-            pr, axis=1, keepdims=True))[None]
+        m_new, l_new, acc_new = _page_update(
+            q_ref[pl.ds(r, 1)][0], k_ref[0], v_ref[0],
+            m_scr[pl.ds(r, 1)][0], l_scr[pl.ds(r, 1)][0],
+            acc_scr[pl.ds(r, 1)][0], p * page, seq_len, scale)
         m_scr[pl.ds(r, 1)] = m_new[None]
-        acc_prev = acc_scr[pl.ds(r, 1)][0]
-        acc_scr[pl.ds(r, 1)] = (acc_prev * corr + jax.lax.dot_general(
-            pr, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=_F32))[None]
+        l_scr[pl.ds(r, 1)] = l_new[None]
+        acc_scr[pl.ds(r, 1)] = acc_new[None]
 
     @pl.when(p == npp - 1)
     def _finish():
@@ -266,7 +262,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
                               npp=P, sb=sb),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(sem, "arbitrary")),
             interpret=interpret,
         )(ptab, lens32, q, k_pages, v_pages)
@@ -294,7 +290,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
         functools.partial(_rpa_kernel, scale=scale, page=page, npp=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(sem, "arbitrary")),
         interpret=interpret,
     )(ptab, lens32, q, k_pages, v_pages)
@@ -412,50 +408,47 @@ def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
                           npp=P, T=T),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, H, D), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_tables.astype(jnp.int32), lens.astype(jnp.int32),
       q, k_pages, v_pages)
 
 
+def _use_kernel(kernel: str, page_size: int, H: int, D: int) -> bool:
+    """The decode kernels' dispatch rule (``pallas.policy`` with no
+    size threshold): the Pallas kernel at every shape ``fits()``
+    accepts unless the mode is off — compiled on a TPU, interpreted
+    where interpret mode is set off-TPU — else the jnp reference."""
+    from paddle_tpu import pallas as pk
+
+    return pk.dispatch(kernel, pk.policy(fits(page_size, H, D), True))
+
+
 def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
                           scale=None):
-    """Dispatcher for the chunked step (mirrors ``paged_attention``):
-    the Pallas chunk kernel when the pallas mode allows it, else the
-    jnp reference — identical contract."""
+    """Dispatcher for the chunked step (mirrors ``paged_attention``)."""
     from paddle_tpu import pallas as pk
 
     S, T, H, D = q.shape
-    mode = pk.mode()
-    if mode != "off" and fits(k_pages.shape[1], H, D):
-        if mode == "on":
-            return ragged_paged_attention_chunk(
-                q, k_pages, v_pages, page_tables, lens, scale=scale,
-                interpret=pk.interpret_mode())
-        if pk._tpu_backend():
-            return ragged_paged_attention_chunk(
-                q, k_pages, v_pages, page_tables, lens, scale=scale)
+    if _use_kernel("ragged_paged_attention_chunk", k_pages.shape[1], H, D):
+        return ragged_paged_attention_chunk(
+            q, k_pages, v_pages, page_tables, lens, scale=scale,
+            interpret=pk.interpret_mode())
     return ragged_paged_attention_chunk_reference(
         q, k_pages, v_pages, page_tables, lens, scale=scale)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lens, scale=None):
-    """Dispatcher: the Pallas kernel when the pallas mode allows it
-    (forced on, or auto on a TPU backend at supported shapes), else the
-    jnp reference — both jit-embeddable, identical contract."""
+    """Dispatcher: the Pallas kernel or the jnp reference — both
+    jit-embeddable, identical contract (see ``_use_kernel``)."""
     from paddle_tpu import pallas as pk
 
     S, H, D = q.shape
-    mode = pk.mode()
-    if mode != "off" and fits(k_pages.shape[1], H, D):
-        if mode == "on":
-            return ragged_paged_attention(
-                q, k_pages, v_pages, page_tables, lens, scale=scale,
-                interpret=pk.interpret_mode())
-        if pk._tpu_backend():
-            return ragged_paged_attention(
-                q, k_pages, v_pages, page_tables, lens, scale=scale)
+    if _use_kernel("ragged_paged_attention", k_pages.shape[1], H, D):
+        return ragged_paged_attention(
+            q, k_pages, v_pages, page_tables, lens, scale=scale,
+            interpret=pk.interpret_mode())
     return ragged_paged_attention_reference(
         q, k_pages, v_pages, page_tables, lens, scale=scale)
 
@@ -478,8 +471,8 @@ def dense_prefill_attention(q, k, v, causal: bool = True):
     qb = jnp.moveaxis(q, 1, 0)            # (H, T, D) = (BH, S, D)
     kb = jnp.moveaxis(k, 1, 0)
     vb = jnp.moveaxis(v, 1, 0)
-    if pk.mode() != "off" and fa.fits(1, H, T, D) and (
-            pk.mode() == "on" or pk._tpu_backend()):
+    if pk.dispatch("prefill_flash_attention",
+                   pk.policy(fa.fits(1, H, T, D), True)):
         out = fa.flash_attention(qb, kb, vb, causal=causal,
                                  interpret=pk.interpret_mode())
     else:

@@ -22,10 +22,8 @@ deserializes a ``paddle compile``-exported executable instead of
 tracing + compiling.  Donation is RESTORED on that path — the
 serialized executable carries its input-output aliasing and the
 manifest's donation mask is re-proved against the live analyzer before
-load — unlike the jax persistent compile cache, under which
-``_donation_ok()`` must disable donation entirely (cache-deserialized
-executables corrupt aliasing on this jaxlib).  Any mismatch is a loud
-JIT fallback counted in ``aot_load_total{result}``.
+load.  Any mismatch is a loud JIT fallback counted in
+``aot_load_total{result}``.
 """
 
 from __future__ import annotations
@@ -83,23 +81,14 @@ _M_FETCH_BYTES = _metrics.counter(
     "bytes copied device->host materializing return_numpy fetches")
 
 
-def _donation_ok() -> bool:
-    """Whether jit state donation is safe in this process.
-
-    jax 0.4.37's persistent compilation cache deserializes executables
-    with broken input-output aliasing: a cache-loaded executable for a
-    structurally-identical program reads its donated state as garbage
-    (reproduced: a second SequenceGenerator over cloned weights decodes
-    noise, and long suites crash natively in later tests).  Donation is
-    a perf feature — skip it whenever the persistent cache is enabled;
-    everything still runs, state updates just copy instead of aliasing.
-    """
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return False
-    except AttributeError:  # pragma: no cover - future jax renames
-        pass
-    return True
+_M_AOT_EXPORT_SKIPPED = _metrics.counter(
+    "executor_aot_export_skipped_total",
+    "compile misses inside an aot.capture window whose AOT export "
+    "raised and was skipped (the JIT path ran instead)")
+_M_DONATION_FAILED = _metrics.counter(
+    "executor_donation_analysis_failed_total",
+    "compiles whose donation-safety analysis raised, so the step ran "
+    "with no donated state")
 
 
 def _fetch_nbytes(v) -> int:
@@ -537,7 +526,7 @@ class Executor:
         """The donation mask _compile would prove right now — the AOT
         load side re-derives it and refuses an entry on drift (the
         serialized executable's aliasing is baked in)."""
-        if not state_names or not _donation_ok():
+        if not state_names:
             return ()
         from paddle_tpu.analysis import optimize as _opt
 
@@ -646,6 +635,7 @@ class Executor:
         except Exception as exc:
             import sys
 
+            _M_AOT_EXPORT_SKIPPED.inc()
             print(f"[paddle_tpu.aot] export skipped for program "
                   f"{fp[:12]}: {type(exc).__name__}: {exc}",
                   file=sys.stderr)
@@ -742,12 +732,10 @@ class Executor:
         # value — overwritten at top level, never read after its last
         # write, never aliased into a control-flow sub-block.  This
         # replaces the old all-or-nothing donate_argnums=(0,) on the
-        # whole state dict; the _donation_ok() kill-switch (persistent
-        # jax cache breaks executable aliasing metadata) still forces
-        # the mask empty.
+        # whole state dict.
         donated_names: tuple = ()
         donation = {}
-        if jit and state_names and _donation_ok():
+        if jit and state_names:
             from paddle_tpu.analysis import optimize as _opt
 
             try:
@@ -755,6 +743,7 @@ class Executor:
                     program, set(feed_vals), fetch_names)
             except Exception:
                 donation = {}  # analysis must never block execution
+                _M_DONATION_FAILED.inc()
             donated_names = tuple(
                 n for n in state_names
                 if n in donation and donation[n].eligible)
@@ -881,21 +870,32 @@ class Executor:
                 {n: state_sh[n] for n in held_names},
             ) + tuple(sh["in_shardings"][1:])
             jit_kwargs["out_shardings"] = sh["out_shardings"]
-        elif self.place._backend is not None:
-            jit_kwargs["backend"] = self.place._backend
         jfn = jax.jit(run_block_split, **jit_kwargs)
+        # A place that names a device (CPUPlace) commits every argument
+        # to it, so the step runs there whatever the default backend is;
+        # TPUPlace leaves placement to jax's default device.
+        device = self.place.device() if self.strategy is None else None
 
-        def _split(state):
-            return ({n: state[n] for n in donated_names},
-                    {n: state[n] for n in held_names})
+        def _args(state, feeds, rest):
+            args = ({n: state[n] for n in donated_names},
+                    {n: state[n] for n in held_names}, feeds) + rest
+            return args if device is None else jax.device_put(args, device)
+
+        def _placed():
+            return (contextlib.nullcontext() if device is None
+                    else jax.default_device(device))
 
         def fn(state, feeds, *rest):
-            return jfn(*_split(state), feeds, *rest)
+            with _placed():
+                return jfn(*_args(state, feeds, rest))
 
         # preserve the jitted object's introspection surface through the
         # wrapper (tests/benchmarks call compiled.fn.lower(state, feeds))
-        fn.lower = lambda state, feeds, *rest: jfn.lower(
-            *_split(state), feeds, *rest)
+        def _lower(state, feeds, *rest):
+            with _placed():
+                return jfn.lower(*_args(state, feeds, rest))
+
+        fn.lower = _lower
         return _Compiled(fn, state_names, written_names, fetch_names, uses_rng,
                          donated_names=donated_names, held_names=held_names,
                          out_state_names=out_state_names)

@@ -5,44 +5,54 @@ ctypes.  Components: recordio, data loader, master service."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(_PKG_DIR, "src")
+_SOURCES = ["recordio.cc", "data_loader.cc", "master_service.cc",
+            "optimizer.cc", "pserver_service.cc", "coord_store.cc",
+            "memory.cc"]
 
 
 def _lib_path() -> str:
-    """Build target: next to the sources when writable (checkout /
-    editable install), else a per-user cache dir (system installs)."""
+    """Build target, named by the digest of the sources it is built
+    from — a stale library copied along with a tree (the build output
+    is git-ignored, mtimes do not survive a copy) can never be loaded
+    for sources it does not match.  Next to the sources when writable
+    (checkout / editable install), else a per-user cache dir."""
+    h = hashlib.sha256()
+    for s in _SOURCES:
+        with open(os.path.join(_SRC_DIR, s), "rb") as f:
+            h.update(f.read())
+    name = f"libpaddle_tpu_native.{h.hexdigest()[:12]}.so"
     if os.access(_SRC_DIR, os.W_OK):
-        return os.path.join(_SRC_DIR, "libpaddle_tpu_native.so")
+        return os.path.join(_SRC_DIR, name)
     cache = os.path.join(
         os.environ.get("XDG_CACHE_HOME",
                        os.path.join(os.path.expanduser("~"), ".cache")),
         "paddle_tpu")
     os.makedirs(cache, exist_ok=True)
-    return os.path.join(cache, "libpaddle_tpu_native.so")
+    return os.path.join(cache, name)
 
 
 _LIB_PATH = _lib_path()
-_SOURCES = ["recordio.cc", "data_loader.cc", "master_service.cc",
-            "optimizer.cc", "pserver_service.cc", "coord_store.cc",
-            "memory.cc"]
 
 _lock = threading.Lock()
 _lib = None
 
 
 def _build():
-    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= newest_src:
+    if os.path.exists(_LIB_PATH):
         return
+    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"  # xdist workers build at once
     cmd = ["g++", "-std=c++17", "-O2", "-shared", "-fPIC", "-pthread",
-           "-o", _LIB_PATH] + srcs
+           "-o", tmp] + srcs
     subprocess.run(cmd, check=True, capture_output=True)
+    os.replace(tmp, _LIB_PATH)
 
 
 def lib() -> ctypes.CDLL:

@@ -4,22 +4,35 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU).
 
 Policy (PADDLE_TPU_USE_PALLAS, default ``auto``):
 
-- ``auto``: only the kernels with a *measured* win over their XLA
-  lowering dispatch (see benchmark/pallas_bench.py, PALLAS_BENCH.md):
-  the fused whole-sequence LSTM (1.2-1.6x at the RNN-bench shapes) and
-  the row softmax for narrow rows (1.5x at cols<=256).  The blocked
-  matmul and scalar-prefetch gather measurably LOSE to XLA on TPU
-  (0.6-0.9x) and are never auto-dispatched — they remain as tested
+- ``auto``: on a TPU backend, the kernels an earlier setup measured
+  ahead of their XLA lowering dispatch (benchmark/pallas_bench.py is
+  the harness): the fused whole-sequence LSTM at H<=384, the row
+  softmax at cols<=256, flash attention at S>=1024.  The thresholds
+  come from that earlier setup and are NOT re-measured on the locally
+  attached v5e.  The blocked matmul and scalar-prefetch gather lost to
+  XLA there and are never auto-dispatched — they remain as tested
   reference kernels and custom-epilogue scaffolds.
 - ``1``/``on``: force every kernel on (benchmarking, tests).
 - ``0``/``off``: pure XLA lowerings.
 
-All kernels run under ``interpret=True`` on CPU for numerics tests.
+Off a TPU the kernels run under ``interpret=True`` for numerics tests.
+On a TPU backend a kernel that dispatches runs compiled or raises —
+interpret mode is ignored there, and the jnp/XLA reference is chosen
+only by ``fits()`` and the mode.  Every dispatch decision is counted at
+trace time in ``pallas_dispatch_total{kernel, path}`` (path =
+compiled | interpret | reference).
 """
 
 from __future__ import annotations
 
 import os
+
+from paddle_tpu.observability import metrics as _metrics
+
+_M_DISPATCH = _metrics.counter(
+    "pallas_dispatch_total",
+    "Pallas kernel dispatch decisions, counted at trace time, by kernel "
+    "and path (compiled | interpret | reference = the jnp/XLA lowering)")
 
 _MODE_ENV = os.environ.get("PADDLE_TPU_USE_PALLAS", "auto").lower()
 _STATE = {
@@ -49,91 +62,99 @@ def mode() -> str:
     return _STATE["mode"]
 
 
+def tpu_backend() -> bool:
+    """Whether traced work lands on a TPU: the platform of jax's default
+    device when one is pinned (``jax.default_device`` — the Executor
+    sets it for CPUPlace), else the default backend."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    platform = (getattr(dev, "platform", dev) if dev is not None
+                else jax.default_backend())
+    return platform == "tpu"
+
+
 def interpret_mode() -> bool:
-    return _STATE["interpret"]
+    """Interpret mode is for hosts without a TPU: on a TPU backend a
+    dispatched kernel is always compiled, whatever the flag says."""
+    return _STATE["interpret"] and not tpu_backend()
 
 
-def _tpu_backend() -> bool:
-    try:
-        import jax
-
-        return jax.default_backend() not in ("cpu", "gpu")
-    except Exception:
-        return False
-
-
-def _auto_ok() -> bool:
+def auto_ok() -> bool:
     # auto mode dispatches real kernels only on a TPU backend; interpret
-    # mode works anywhere (CPU numerics tests set it explicitly)
-    return _STATE["interpret"] or _tpu_backend()
+    # mode works off-TPU (CPU numerics tests set it explicitly)
+    return _STATE["interpret"] or tpu_backend()
+
+
+def dispatch(kernel: str, use: bool) -> bool:
+    """Count one dispatch decision (trace time) and return it."""
+    path = ("reference" if not use
+            else "interpret" if interpret_mode() else "compiled")
+    _M_DISPATCH.inc(kernel=kernel, path=path)
+    return use
+
+
+def policy(fits: bool, auto: bool) -> bool:
+    """fits() gates everything; 'on' forces, 'auto' asks the threshold."""
+    if _STATE["mode"] == "off" or not fits:
+        return False
+    return _STATE["mode"] == "on" or (auto_ok() and auto)
 
 
 def use_lstm(b: int, h: int) -> bool:
     from paddle_tpu.pallas import lstm as _l
 
-    if _STATE["mode"] == "off" or not _l.fits(b, h):
-        return False
-    if _STATE["mode"] == "on":
-        return True
-    return _auto_ok() and h <= 384  # measured: XLA wins at H>=512
+    # earlier setup: XLA won at H>=512
+    return dispatch("lstm", policy(_l.fits(b, h), h <= 384))
 
 
 def use_softmax(rows: int, cols: int) -> bool:
     from paddle_tpu.pallas import softmax as _s
 
-    if _STATE["mode"] == "off" or not _s.fits(rows, cols):
-        return False
-    if _STATE["mode"] == "on":
-        return True
-    return _auto_ok() and cols <= 256  # measured: XLA wins at 512
+    # earlier setup: XLA won at cols=512
+    return dispatch("softmax", policy(_s.fits(rows, cols), cols <= 256))
+
 
 def use_flash_attention(bh: int, s_q: int, s_k: int, d: int) -> bool:
-    """Blocked online-softmax attention.  Measured (PALLAS_BENCH.md):
-    beats the jnp softmax(QK^T)V lowering at S>=1024 where the S x S
-    score tensor stops fitting cache-friendly fusions; below that XLA's
-    fused unblocked attention wins on kernel-count."""
+    """Blocked online-softmax attention.  On an earlier setup it beat
+    the jnp softmax(QK^T)V lowering at S>=1024, where the S x S score
+    tensor stops fitting cache-friendly fusions; below that XLA's fused
+    unblocked attention won on kernel count.  Not re-measured."""
     from paddle_tpu.pallas import flash_attention as _f
 
-    if _STATE["mode"] == "off" or not _f.fits(1, bh, s_q, d) or s_q != s_k:
-        return False
-    if _STATE["mode"] == "on":
-        return True
-    return _auto_ok() and s_q >= 1024
+    return dispatch("flash_attention", policy(
+        _f.fits(1, bh, s_q, d) and s_q == s_k, s_q >= 1024))
 
 
 def use_batch_norm(rows: int, cols: int) -> bool:
-    """Fused BN stats+normalize / BN-grad kernels.  Measured
-    (PALLAS_BENCH.md): XLA's BN lowering runs at a higher fraction of
-    HBM bandwidth at ResNet shapes (and fuses the statistics into the
-    producing conv's epilogue inside real models), so the kernels are
-    never auto-dispatched — they remain as tested reference kernels
-    and the building block for fused epilogue variants."""
+    """Fused BN stats+normalize / BN-grad kernels.  On an earlier setup
+    XLA's BN lowering ran at a higher fraction of HBM bandwidth at
+    ResNet shapes (and fuses the statistics into the producing conv's
+    epilogue inside real models), so the kernels are never
+    auto-dispatched — they remain as tested reference kernels."""
     from paddle_tpu.pallas import batch_norm as _b
 
-    return _STATE["mode"] == "on" and _b.fits(rows, cols)
+    return dispatch("batch_norm", policy(_b.fits(rows, cols), False))
 
 
 def use_conv2d(n: int, h: int, w: int, c: int, o: int, kh: int, kw: int,
                stride: int, padding: int) -> bool:
-    """Implicit-GEMM conv kernels (pallas/conv.py).  Measured
-    (PALLAS_BENCH.md round 4, R=64 value-chains on the v5e): the XLA
-    conv emitter wins at every ResNet-50 hot shape — best kernel ratio
-    0.96x (c5 bwd-input), typical 0.83-0.90x, worst 0.37x (c2, where
-    C=64 wastes half the MXU lanes) — so the kernels are never
-    auto-dispatched; they remain as verified scaffolds for fused
-    custom-epilogue experiments."""
+    """Implicit-GEMM conv kernels (pallas/conv.py).  On an earlier setup
+    the XLA conv emitter won at every ResNet-50 hot shape, so the
+    kernels are never auto-dispatched; they remain as verified
+    scaffolds for fused custom-epilogue experiments."""
     from paddle_tpu.pallas import conv as _c
 
-    return _STATE["mode"] == "on" and _c.fits(n, h, w, c, o, kh, kw,
-                                              stride, padding)
+    return dispatch("conv2d", policy(
+        _c.fits(n, h, w, c, o, kh, kw, stride, padding), False))
 
 
 def use_matmul() -> bool:
-    return _STATE["mode"] == "on"  # measured 0.6-0.9x vs XLA: never auto
+    return dispatch("matmul", policy(True, False))  # lost to XLA: never auto
 
 
 def use_gather() -> bool:
-    return _STATE["mode"] == "on"  # measured 0.5x vs XLA: never auto
+    return dispatch("gather", policy(True, False))  # lost to XLA: never auto
 
 
 from paddle_tpu.pallas.matmul import matmul as pallas_matmul  # noqa: E402

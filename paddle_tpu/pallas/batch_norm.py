@@ -31,7 +31,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.pallas import compat as _compat
 
 _F32 = jnp.float32
 
@@ -145,7 +144,7 @@ def _bn_fwd_impl(x2d, gamma, beta, eps: float, interpret: bool = False,
             jax.ShapeDtypeStruct((1, C), _F32),
         ],
         scratch_shapes=[pltpu.VMEM((2, C), _F32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(x2d, gamma.reshape(1, C), beta.reshape(1, C))
@@ -219,7 +218,7 @@ def _bn_bwd_impl(x2d, dy2d, gamma, mean, inv, interpret: bool = False):
             jax.ShapeDtypeStruct((1, C), _F32),
         ],
         scratch_shapes=[pltpu.VMEM((2, C), _F32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(x2d, dy2d, gamma.reshape(1, C), mean.reshape(1, C), inv.reshape(1, C))

@@ -35,7 +35,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.pallas import compat as _compat
 
 _F32 = jnp.float32
 _NEG_INF = -1e30  # large-but-finite: avoids inf-inf NaNs in corrections
@@ -176,7 +175,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, scale: float,
         # of the shared (1, nq, blk_q) lse block, and a megacore split
         # over qi would flush two partially-written private copies of
         # that block (BH carries the core-level parallelism instead)
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -305,7 +304,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), _F32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse3, delta3)
@@ -332,7 +331,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
         ],
         scratch_shapes=[pltpu.VMEM((blk_k, D), _F32),
                         pltpu.VMEM((blk_k, D), _F32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse3, delta3)

@@ -29,7 +29,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.pallas import compat as _compat
 
 
 def _lstm_kernel(xp_ref, w_ref, b_ref, h0_ref, c0_ref,
@@ -141,7 +140,7 @@ def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False,
             ],
             scratch_shapes=[pltpu.VMEM((bb, H), jnp.float32),
                             pltpu.VMEM((bb, H), jnp.float32)],
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
         )(xproj, w, bias.reshape(1, H4), h0, c0)
@@ -167,7 +166,7 @@ def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False,
         ],
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32),
                         pltpu.VMEM((B, H), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xproj, w, bias.reshape(1, H4), h0, c0)

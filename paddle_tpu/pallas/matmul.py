@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.pallas import compat as _compat
 
 # The one place the guessed tile lives (ISSUE 16: `fits` and `matmul`
 # used to repeat bm=256, bk=512, bn=256 independently — a tuned default
@@ -106,7 +105,7 @@ def _matmul_impl(x, y, bm: int = None, bk: int = None, bn: int = None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, y)

@@ -51,7 +51,7 @@ class TuningDB:
     "default_time_ms": float, "speedup": float, "interpret": bool,
     "n_configs": int, "n_infeasible": int, "shape": [...]}`` — only
     ``config`` is consumed by dispatch; the rest is provenance the
-    speedup tables and BENCHMARKS.md rows are built from.
+    speedup tables are built from.
     """
 
     def __init__(self, entries: Optional[Dict[str, dict]] = None,
@@ -153,9 +153,6 @@ def normalize_device_kind(kind: str) -> str:
 
 
 def current_device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return normalize_device_kind(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
+    return normalize_device_kind(jax.devices()[0].device_kind)

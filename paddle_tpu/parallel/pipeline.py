@@ -121,9 +121,7 @@ def gpipe(layer_fn: Callable, stacked_params, x, *, mesh, pp_axis: str,
 
     pspec = jax.tree.map(lambda _: P(pp_axis), stacked_params)
     xspec = P(None, batch_axis, sp_axis) if x_mb.ndim >= 3 else P(None, batch_axis)
-    from paddle_tpu.parallel.compat import shard_map as _shard_map
-
-    mapped = _shard_map(run, mesh=mesh, in_specs=(pspec, xspec),
-                        out_specs=xspec)
+    mapped = jax.shard_map(run, mesh=mesh, in_specs=(pspec, xspec),
+                           out_specs=xspec, check_vma=False)
     out = mapped(stacked_params, x_mb)
     return un_mb(out)

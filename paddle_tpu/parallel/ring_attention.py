@@ -50,11 +50,8 @@ def _use_flash_chunks(B, H, S, D) -> bool:
     from paddle_tpu import pallas as pk
     from paddle_tpu.pallas import flash_attention as fa
 
-    if pk.mode() == "off" or not fa.fits(B, H, S, D):
-        return False
-    if pk.mode() == "on":
-        return True
-    return pk._auto_ok() and S >= 1024
+    return pk.dispatch("ring_flash_attention",
+                       pk.policy(fa.fits(B, H, S, D), S >= 1024))
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
@@ -74,8 +71,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     kernel's causal mask, earlier chunks run unmasked.  Shapes the
     kernel rejects fall back to the jnp online-softmax block.
     """
-    n = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-         else lax.psum(1, axis_name))  # pre-0.4.38 spelling
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, S, D = q.shape
     if scale is None:
@@ -203,8 +199,6 @@ def ring_attention_sharded(mesh, sp_axis: str, q, k, v,
     """
     spec = P(batch_axis, head_axis, sp_axis, None)
     fn = functools.partial(ring_attention, axis_name=sp_axis, causal=causal)
-    from paddle_tpu.parallel.compat import shard_map as _shard_map
-
-    mapped = _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
     return mapped(q, k, v)

@@ -110,6 +110,7 @@ from typing import Optional
 
 import numpy as np
 
+from paddle_tpu import framework
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.observability.events import GLOBAL_EVENTS as _EVENTS
 from paddle_tpu.serving.batching import (
@@ -270,6 +271,7 @@ class InferenceServer:
                     self._reply(200, {
                         "status": "degraded" if reasons else "ok",
                         "reasons": reasons,
+                        "device": framework.device_record(),
                         "self_healing": server.self_healing_info(),
                         "feeds": server.feed_names,
                         "fetches": [getattr(f, "name", str(f))

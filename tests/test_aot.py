@@ -93,13 +93,9 @@ def test_roundtrip_bit_identical(artifact_dir):
 
 
 def test_donation_restored_under_aot(artifact_dir):
-    """Donation through the loaded executable — or, when the
-    ``_donation_ok()`` kill-switch is active (the persistent XLA
-    compile cache the test conftest enables breaks executable
-    aliasing in this jax), a coherently donation-free artifact:
-    export and load must agree on the mask either way."""
-    from paddle_tpu.executor import _donation_ok
-
+    """Donation through the loaded executable (with the persistent XLA
+    compile cache on, as the test conftest sets it): export and load
+    agree on the mask and the aliasing survives serialization."""
     art, _ = artifact_dir
     store = ArtifactStore(art)
     exe = Executor()
@@ -111,17 +107,10 @@ def test_donation_restored_under_aot(artifact_dir):
     w_step1 = scope.get("W")
     exe.run(prog, feed={}, fetch_list=["Y"], scope=scope)
     entry = next(iter(store.entries.values()))
-    if _donation_ok():
-        # step 2 donated its input (step 1's own output) — the aliasing
-        # win survived serialization, it isn't silently dropped on load
-        assert entry["donated_names"] == ["W"]
-        assert w_step1.is_deleted()
-    else:
-        # kill-switch on: export proved no donation, live analysis
-        # re-derives the same empty mask, so the entry still loads
-        # (no donation_drift rejection) and nothing is deleted
-        assert entry["donated_names"] == []
-        assert not w_step1.is_deleted()
+    # step 2 donated its input (step 1's own output) — the aliasing
+    # win survived serialization, it isn't silently dropped on load
+    assert entry["donated_names"] == ["W"]
+    assert w_step1.is_deleted()
     # the caller's host array is never clobbered by donation (the
     # first step copies any buffer the executable doesn't own)
     assert np.array_equal(W0, np.arange(64, dtype=np.float32).reshape(8, 8))
@@ -129,10 +118,10 @@ def test_donation_restored_under_aot(artifact_dir):
 
 
 def test_donation_restored_fresh_process():
-    """End-to-end donation proof in a subprocess WITHOUT the persistent
-    compile cache (which flips the executor's donation kill-switch):
-    export, reload in a fresh executor, and assert step 2's donated
-    input — step 1's own output — comes back deleted."""
+    """End-to-end donation proof in a fresh process without the
+    persistent compile cache: export, reload in a fresh executor, and
+    assert step 2's donated input — step 1's own output — comes back
+    deleted."""
     import subprocess
     import sys
     import textwrap
@@ -150,9 +139,8 @@ def test_donation_restored_fresh_process():
         import jax.numpy as jnp
         from paddle_tpu import aot, framework
         from paddle_tpu.aot.artifact import ArtifactStore, ArtifactWriter
-        from paddle_tpu.executor import Executor, Scope, _donation_ok
+        from paddle_tpu.executor import Executor, Scope
 
-        assert _donation_ok(), "cache env leaked into subprocess"
         prog = framework.Program()
         b = prog.global_block()
         b.create_var(name="W", shape=(8, 8), dtype="float32",

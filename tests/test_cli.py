@@ -170,9 +170,6 @@ def test_cluster_launch_end_to_end(tmp_path):
     trainer_script.write_text("""
 import os, sys
 sys.path.insert(0, %r)
-import jax
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 import numpy as np
 import paddle_tpu.v2 as paddle
 

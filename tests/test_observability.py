@@ -588,9 +588,3 @@ def test_bench_telemetry_artifact_writer(tmp_path):
                for e in art["events"]["traceEvents"])
     # a cached step ran, so the overhead fraction is reported and sane
     assert 0 < art["telemetry_overhead_fraction_of_step"] < 0.5
-
-    # the checked-in baseline artifact parses and pins the headline
-    with open(os.path.join(repo, "BENCH_TELEMETRY_BASELINE.json")) as f:
-        base = json.load(f)
-    assert base["schema"] == "paddle_tpu.bench_telemetry.v1"
-    assert base["headline"]["value"] >= base["regression_floor"]["value"]

@@ -2,8 +2,7 @@
 (paddle_tpu/analysis/optimize.py): every rewrite the pipeline makes
 must be invisible at the fetch surface — bit-identical outputs, a
 verifier-clean program — and the donation-safety analyzer must reject
-exactly the aliasing shapes that corrupted state before the PR-15
-donation kill-switch."""
+exactly the aliasing shapes that would corrupt state."""
 
 import numpy as np
 import pytest
