@@ -174,9 +174,10 @@ def _mfu(ips: float) -> float:
 def write_telemetry_artifact(path, headline):
     """Per-run telemetry artifact (schema paddle_tpu.bench_telemetry.v1):
     the headline record plus the observability registry snapshot
-    (compile/step/feed/fetch metrics the run accumulated), the host
-    event trace, and a measured per-step telemetry overhead with its
-    fraction of the mean cached step.
+    (compile/step/feed/fetch metrics the run accumulated), the span
+    ring's events (empty unless the run was made under
+    ``observability.recording()``), and a measured per-step telemetry
+    overhead with its fraction of the mean cached step.
     """
     from paddle_tpu import observability as obs
     from paddle_tpu.framework import device_record

@@ -485,7 +485,7 @@ def cmd_lint(argv):
 
 def cmd_stats(argv):
     """paddle stats [--json] [--run=script.py] [--file=artifact.json]
-    [--url=http://host:port] [--trace=out.json]
+    [--url=http://host:port] [--trace=out.json [--seconds=N]]
 
     Dump the observability registry (paddle_tpu/observability): every
     counter/gauge/histogram the executor, serving, and trainer paths
@@ -493,6 +493,11 @@ def cmd_stats(argv):
     a live server's /stats endpoint (--url), a bench telemetry artifact
     (--file), or this process's registry (optionally after exec'ing a
     fluid script via --run so its Executor.run calls are measured).
+
+    --trace writes the program's spans as Chrome-trace JSON: those of
+    the --run script (the span ring is on while it runs), the events a
+    --file artifact embeds, or — with --url — what the server records
+    over the next --seconds (default 5) through its GET /trace.
     """
     import json as json_mod
 
@@ -500,16 +505,18 @@ def cmd_stats(argv):
 
     args, rest = _kv_args(argv)
     as_json = "--json" in rest
-    if args.get("trace") and args.get("url"):
-        print("--trace: host events live in the server process and are "
-              "not exported over /stats; run paddle stats --trace "
-              "in-process instead", file=sys.stderr)
-        return 2
+    trace = None          # a Chrome-trace document fetched from elsewhere
     if args.get("url"):
         import urllib.request
 
-        url = args["url"].rstrip("/") + "/stats"
-        with urllib.request.urlopen(url, timeout=30) as r:
+        base = args["url"].rstrip("/")
+        if args.get("trace"):
+            seconds = float(args.get("seconds", 5))
+            with urllib.request.urlopen(
+                    f"{base}/trace?seconds={seconds}",
+                    timeout=seconds + 30) as r:
+                trace = json_mod.loads(r.read())
+        with urllib.request.urlopen(base + "/stats", timeout=30) as r:
             snap = json_mod.loads(r.read())
     elif args.get("file"):
         with open(args["file"]) as f:
@@ -519,11 +526,16 @@ def cmd_stats(argv):
         snap = data.get("metrics", data) or {}
     else:
         if args.get("run"):
+            import contextlib
+
             _cwd_importable()
             path = args["run"]
             glb = {"__file__": path, "__name__": "__paddle_stats__"}
             with open(path) as f:
-                exec(compile(f.read(), path, "exec"), glb)
+                code = compile(f.read(), path, "exec")
+            with (obs.recording() if args.get("trace")
+                  else contextlib.nullcontext()):
+                exec(code, glb)
         snap = obs.snapshot()
     if as_json:
         print(json_mod.dumps(snap, indent=1, sort_keys=True))
@@ -532,7 +544,7 @@ def cmd_stats(argv):
         print(table if table else
               "telemetry registry is empty (no metrics recorded)")
     if args.get("trace"):
-        if args.get("file"):
+        if args.get("file") and not args.get("url"):
             # a bench artifact embeds its run's Chrome trace — export
             # that, not this CLI process's (empty) event ring
             trace = data.get("events")
@@ -540,6 +552,7 @@ def cmd_stats(argv):
                 print(f"--trace: {args['file']} carries no embedded "
                       "host events", file=sys.stderr)
                 return 2
+        if trace is not None:
             with open(args["trace"], "w") as f:
                 json_mod.dump(trace, f)
         else:

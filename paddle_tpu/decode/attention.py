@@ -264,6 +264,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
             out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(sem, "arbitrary")),
+            name="ragged_paged_attention_blocked",
             interpret=interpret,
         )(ptab, lens32, q, k_pages, v_pages)
 
@@ -292,6 +293,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(sem, "arbitrary")),
+        name="ragged_paged_attention",
         interpret=interpret,
     )(ptab, lens32, q, k_pages, v_pages)
 
@@ -410,6 +412,7 @@ def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
         out_shape=jax.ShapeDtypeStruct((S, T, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="ragged_paged_attention_chunk",
         interpret=interpret,
     )(page_tables.astype(jnp.int32), lens.astype(jnp.int32),
       q, k_pages, v_pages)

@@ -20,6 +20,7 @@ from paddle_tpu.decode.session import (
     DecodeRequest,
     DecodeSession,
 )
+from paddle_tpu.observability.events import span
 
 __all__ = ["AdmissionRefused", "BeamRequest", "DecodeRequest",
            "GenerationEngine"]
@@ -89,24 +90,28 @@ class GenerationEngine:
                deadline: Optional[float] = None,
                temperature: Optional[float] = None,
                top_k: Optional[int] = None,
-               seed: Optional[int] = None) -> DecodeRequest:
+               seed: Optional[int] = None,
+               rid: Optional[int] = None) -> DecodeRequest:
         """Queue a generation request.  Raises AdmissionRefused when the
         engine cannot take it (503-shaped), otherwise returns the
         request handle — ``wait()``/``result()`` or stream via
         ``on_token``.  ``temperature``/``top_k``/``seed`` switch the
-        slot from greedy argmax to seeded sampling."""
+        slot from greedy argmax to seeded sampling.  ``rid`` is the id
+        the caller's spans already carry (``session.next_rid()``); the
+        request draws its own without it."""
         req = DecodeRequest(self._prompt_of(list(src_ids)),
                             max_new_tokens=self._budget(max_new_tokens),
                             on_token=on_token, deadline=deadline,
                             temperature=temperature, top_k=top_k,
-                            seed=seed)
+                            seed=seed, rid=rid)
         self.session.submit(req)
         self._wake.set()
         return req
 
     def submit_beam(self, src_ids: List[int], beam_size: int,
                     max_new_tokens: Optional[int] = None,
-                    deadline: Optional[float] = None) -> BeamRequest:
+                    deadline: Optional[float] = None,
+                    rid: Optional[int] = None) -> BeamRequest:
         """Queue a beam-search request (k sibling slots sharing the
         prompt's pages copy-on-write).  Refused when beam search is
         disabled (``beam_max`` 0) or wider than the configured cap."""
@@ -118,7 +123,7 @@ class GenerationEngine:
         req = BeamRequest(self._prompt_of(list(src_ids)),
                           beam_size=beam_size,
                           max_new_tokens=self._budget(max_new_tokens),
-                          deadline=deadline)
+                          deadline=deadline, rid=rid)
         self.session.submit(req)
         self._wake.set()
         return req
@@ -158,7 +163,9 @@ class GenerationEngine:
     def _stepper(self) -> None:
         while not self._stop.is_set():
             if self.session.idle():
-                self._wake.wait(timeout=0.05)
+                # the device is idle because no request is there
+                with span("decode.idle_wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             try:

@@ -32,6 +32,7 @@ from paddle_tpu.decode.attention import (
     paged_chunk_attention,
 )
 from paddle_tpu.decode.paged_kv import PageAllocator
+from paddle_tpu.observability.events import span
 
 _F32 = jnp.float32
 
@@ -186,23 +187,27 @@ class TinyDecoderLM:
         scores the token *after* tokens[:, j].  Rollback of rejected
         rows is the caller's business: stale K/V past ``lens`` is
         unreachable through the length mask."""
-        logits, self.k_pool, self.v_pool = _verify_step(
-            self.params, self.k_pool, self.v_pool,
-            jnp.asarray(tables.astype(np.int32)),
-            jnp.asarray(lens.astype(np.int32)),
-            jnp.asarray(tokens.astype(np.int32)),
-            heads=self.heads, page_size=self.page_size)
-        return np.asarray(logits), []
+        return self._step(_verify_step, tokens, tables, lens)
 
     def decode(self, tokens: np.ndarray, states, tables: np.ndarray,
                lens: np.ndarray):
-        logits, self.k_pool, self.v_pool = _decode_step(
-            self.params, self.k_pool, self.v_pool,
-            jnp.asarray(tables.astype(np.int32)),
-            jnp.asarray(lens.astype(np.int32)),
-            jnp.asarray(tokens[:, 0].astype(np.int32)),
-            heads=self.heads, page_size=self.page_size)
-        return np.asarray(logits), []
+        return self._step(_decode_step, tokens[:, 0], tables, lens)
+
+    def _step(self, jitted, tokens, tables, lens):
+        """One jitted step over every slot, in the three parts a tick
+        pays for on the host: the upload of tables, lengths and tokens,
+        the dispatch (returns before the device is done), and the wait
+        for the device plus the logits' copy to the host."""
+        with span("decode.upload"):
+            tables = jnp.asarray(tables.astype(np.int32))
+            lens = jnp.asarray(lens.astype(np.int32))
+            tokens = jnp.asarray(tokens.astype(np.int32))
+        with span("decode.dispatch"):
+            logits, self.k_pool, self.v_pool = jitted(
+                self.params, self.k_pool, self.v_pool, tables, lens,
+                tokens, heads=self.heads, page_size=self.page_size)
+        with span("decode.logits_to_host"):
+            return np.asarray(logits), []
 
 
 @jax.jit

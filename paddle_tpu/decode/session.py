@@ -25,6 +25,7 @@ parity tests pin this against.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Callable, List, Optional
@@ -35,6 +36,7 @@ from paddle_tpu.decode.paged_kv import PoolExhausted, cow_split
 from paddle_tpu.decode.spec import accept_greedy, observe_chunk
 from paddle_tpu.generation import beam_select
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.observability.events import span
 
 _M_ACTIVE = _metrics.gauge(
     "decode_active_slots", "sequences currently decoding in the session")
@@ -42,6 +44,15 @@ _M_WAITING = _metrics.gauge(
     "decode_waiting_requests", "admitted-but-queued generation requests")
 _M_STEPS = _metrics.counter(
     "decode_steps_total", "fixed-shape decode steps dispatched")
+_M_SLOT_STEPS = _metrics.counter(
+    "decode_active_slot_steps_total",
+    "live slots summed over dispatched decode steps; over "
+    "decode_steps_total x max_slots it is occupancy (a masked lane is "
+    "computed and thrown away)")
+_M_QUEUE_WAIT = _metrics.histogram(
+    "decode_queue_wait_seconds",
+    "submit to the admission that seats the request (its prefill "
+    "follows), once per request")
 _M_TOKENS = _metrics.counter(
     "decode_tokens_total", "tokens generated across all sequences")
 _M_REFUSED = _metrics.counter(
@@ -62,6 +73,22 @@ _M_STEP_FAIL = _metrics.counter(
 _M_CANCELLED = _metrics.counter(
     "decode_cancelled_total",
     "generation requests cancelled by their consumer (pages freed)")
+
+
+_RIDS = itertools.count(1)
+
+
+def next_rid() -> int:
+    """A process-wide request id: every span of one request carries it
+    (``rid``), from the serving handler down to the decode tick."""
+    return next(_RIDS)
+
+
+def _prompt_len(prompt) -> int:
+    # a seq2seq prompt is one reader row wrapping the id list
+    if len(prompt) == 1 and isinstance(prompt[0], (list, tuple)):
+        return len(prompt[0])
+    return len(prompt)
 
 
 class AdmissionRefused(RuntimeError):
@@ -89,7 +116,9 @@ class DecodeRequest:
                  deadline: Optional[float] = None,
                  temperature: Optional[float] = None,
                  top_k: Optional[int] = None,
-                 seed: Optional[int] = None):
+                 seed: Optional[int] = None,
+                 rid: Optional[int] = None):
+        self.rid = next_rid() if rid is None else rid
         self.prompt = prompt
         self.max_new_tokens = int(max_new_tokens)
         self.on_token = on_token
@@ -106,6 +135,7 @@ class DecodeRequest:
         self.error: Optional[BaseException] = None
         self.finish_reason: Optional[str] = None   # eos|length|deadline|error
         self.submitted_at = time.monotonic()
+        self.admitted_at: Optional[float] = None   # first seating
         self.first_token_at: Optional[float] = None
         self.step_failures = 0         # decode steps that died under us
         self.cancelled = False         # consumer gone; evict next tick
@@ -170,9 +200,10 @@ class BeamRequest(DecodeRequest):
     best-first."""
 
     def __init__(self, prompt, beam_size: int, max_new_tokens: int = 32,
-                 deadline: Optional[float] = None):
+                 deadline: Optional[float] = None,
+                 rid: Optional[int] = None):
         super().__init__(prompt, max_new_tokens=max_new_tokens,
-                         deadline=deadline)
+                         deadline=deadline, rid=rid)
         if beam_size < 1:
             raise ValueError(f"beam_size must be >= 1, got {beam_size}")
         self.beam_size = int(beam_size)
@@ -343,7 +374,16 @@ class DecodeSession:
         the batch are evicted — first offense requeued to retry from
         scratch, second offense quarantined with 503 ``step_failed`` —
         and the stepper thread lives on."""
-        self._sweep_cancelled()
+        live = [s.req.rid for s in self._slots if s is not None]
+        with span("decode.tick", active=len(live),
+                  waiting=len(self._pending),
+                  rids=",".join(map(str, live))):
+            return self._tick()
+
+    def _tick(self) -> int:
+        with span("decode.sweep"):
+            self._sweep_cancelled()
+            self._sweep_expired()
         self._admit()
         active_idx = [i for i, s in enumerate(self._slots) if s is not None]
         if not active_idx:
@@ -353,23 +393,26 @@ class DecodeSession:
         if self.model.grows_kv:
             # the step writes each live slot's next KV row: split any
             # page shared with a fork / the prefix cache first
-            for i in active_idx:
-                if (self._slots[i] is not None
-                        and not self._slots[i].dead):
-                    self._ensure_private(i, rows=1)
+            with span("decode.cow"):
+                for i in active_idx:
+                    if (self._slots[i] is not None
+                            and not self._slots[i].dead):
+                        self._ensure_private(i, rows=1)
             active_idx = [i for i in active_idx
                           if self._slots[i] is not None]
             if not active_idx:
                 return 0
         t0 = time.perf_counter()
         try:
-            logits, new_states = self.model.decode(
-                self._tokens, self._states, self._tables, self._lens)
+            with span("decode.step"):
+                logits, new_states = self.model.decode(
+                    self._tokens, self._states, self._tables, self._lens)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
         _M_STEP_SEC.observe(time.perf_counter() - t0)
         _M_STEPS.inc()
+        _M_SLOT_STEPS.inc(len(active_idx))
         logits = np.asarray(logits)
         for i, buf in enumerate(self._states):
             buf[...] = np.asarray(new_states[i])
@@ -378,6 +421,14 @@ class DecodeSession:
                 if not self._slots[i].dead:
                     self._slots[i].ctx_len += 1
                     self._lens[i] = self._slots[i].ctx_len
+        with span("decode.sample"):
+            self._sample(active_idx, logits)
+        _M_ACTIVE.set(self.active)
+        return len(active_idx)
+
+    def _sample(self, active_idx: List[int], logits: np.ndarray) -> None:
+        """The per-slot end of a tick: expiry, the next token (argmax or
+        the slot's sampler) on the host, emission, eviction."""
         now = time.monotonic()
         groups_seen = set()
         for i in active_idx:
@@ -402,8 +453,6 @@ class DecodeSession:
                 continue
             tok = self._choose(slot, logits[i])
             self._emit_token(i, tok)
-        _M_ACTIVE.set(self.active)
-        return len(active_idx)
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive the session until every queued request finishes (the
@@ -594,35 +643,38 @@ class DecodeSession:
             return 0
         t0 = time.perf_counter()
         try:
-            logits, new_states = self.model.verify_chunk(
-                tokens, self._states, self._tables, self._lens)
+            with span("decode.step", chunk=k):
+                logits, new_states = self.model.verify_chunk(
+                    tokens, self._states, self._tables, self._lens)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
         _M_STEP_SEC.observe(time.perf_counter() - t0)
         _M_STEPS.inc()
+        _M_SLOT_STEPS.inc(len(active_idx))
         logits = np.asarray(logits)                     # (S, k, V)
         for i, buf in enumerate(self._states):
             if new_states:
                 buf[...] = np.asarray(new_states[i])
-        now = time.monotonic()
-        for i in active_idx:
-            slot = self._slots[i]
-            if slot.req.expired(now):
-                self._evict(i, "deadline",
-                            TimeoutError("generation deadline expired"))
-                continue
-            target = np.argmax(logits[i], axis=-1)      # (k,)
-            emitted, accepted = accept_greedy(drafts[i], target)
-            observe_chunk(k - 1, accepted, k)
-            # rows of [prev] + accepted drafts are real; later rows are
-            # speculative garbage the length mask never reaches
-            slot.ctx_len += 1 + accepted
-            self._lens[i] = slot.ctx_len
-            for tok in emitted:
-                self._emit_token(i, tok)
-                if self._slots[i] is not slot:          # eos / budget
-                    break
+        with span("decode.sample"):
+            now = time.monotonic()
+            for i in active_idx:
+                slot = self._slots[i]
+                if slot.req.expired(now):
+                    self._evict(i, "deadline", TimeoutError(
+                        "generation deadline expired"))
+                    continue
+                target = np.argmax(logits[i], axis=-1)      # (k,)
+                emitted, accepted = accept_greedy(drafts[i], target)
+                observe_chunk(k - 1, accepted, k)
+                # rows of [prev] + accepted drafts are real; later rows
+                # are speculative garbage the length mask never reaches
+                slot.ctx_len += 1 + accepted
+                self._lens[i] = slot.ctx_len
+                for tok in emitted:
+                    self._emit_token(i, tok)
+                    if self._slots[i] is not slot:          # eos / budget
+                        break
         _M_ACTIVE.set(self.active)
         return len(active_idx)
 
@@ -734,14 +786,12 @@ class DecodeSession:
         return [i for i, s in enumerate(self._slots) if s is None]
 
     def _requeue_head(self, req: DecodeRequest) -> None:
-        # pages/slots are busy with live sequences: requeue at the head
-        # — an evict next tick frees them.  Not a refusal; refusal
-        # happens at submit (never fits / queue full).
         with self._lock:
             self._pending.insert(0, req)
             _M_WAITING.set(len(self._pending))
 
-    def _prefill_with_cache(self, req: DecodeRequest, need: int):
+    def _prefill_with_cache(self, req: DecodeRequest, need: int,
+                            admit_span):
         """Allocate + prefill one prompt, reusing cached prefix pages
         when the cache has them.  Returns (pages, ctx_len, state_rows,
         first_logits) or None when the pool cannot host the fresh part
@@ -763,13 +813,15 @@ class DecodeSession:
                 return None
         t0 = time.perf_counter()
         pages = cached_pages + alloc.alloc(fresh_need)
+        admit_span.set(cached_len=cached_len, pages=len(pages))
         try:
-            if cached_len:
-                ctx_len, state_rows, first_logits = self.model.prefill(
-                    req.prompt, pages, cached_len=cached_len)
-            else:
-                ctx_len, state_rows, first_logits = self.model.prefill(
-                    req.prompt, pages)
+            with span("decode.prefill", rid=req.rid):
+                if cached_len:
+                    ctx_len, state_rows, first_logits = self.model.prefill(
+                        req.prompt, pages, cached_len=cached_len)
+                else:
+                    ctx_len, state_rows, first_logits = self.model.prefill(
+                        req.prompt, pages)
         except BaseException:
             alloc.free(pages)
             raise
@@ -792,7 +844,6 @@ class DecodeSession:
             buf[i] = row
 
     def _admit(self) -> None:
-        self._sweep_expired()
         while True:
             frees = self._free_slots()
             if not frees:
@@ -802,37 +853,56 @@ class DecodeSession:
                 _M_WAITING.set(len(self._pending))
             if req is None:
                 return
-            if isinstance(req, BeamRequest):
-                if len(frees) < req.beam_size:
-                    self._requeue_head(req)
-                    return
-            need = self.model.context_pages(req.prompt, req.max_new_tokens)
-            try:
-                got = self._prefill_with_cache(req, need)
-                if got is None:
-                    self._requeue_head(req)
-                    return
-                pages, ctx_len, state_rows, first_logits = got
-            except PoolExhausted as e:   # raced with another allocator user
-                _M_REFUSED.inc(reason="pool_exhausted")
-                req._finish("error", AdmissionRefused("pool_exhausted",
-                                                      str(e)))
-                continue
-            except BaseException as e:
-                req._finish("error", e)
-                continue
-            if isinstance(req, BeamRequest):
-                self._admit_beam(req, frees[:req.beam_size], pages,
-                                 ctx_len, state_rows, first_logits)
-            else:
-                self._place(frees[0], _Slot(req, pages, ctx_len),
-                            ctx_len, state_rows)
-                if first_logits is not None:
-                    slot = self._slots[frees[0]]
-                    tok = self._choose(slot,
+            with span("decode.admit", rid=req.rid,
+                      prompt_len=_prompt_len(req.prompt)) as admit_span:
+                seated = self._admit_one(req, frees, admit_span)
+            if not seated:
+                return
+
+    def _admit_one(self, req: DecodeRequest, frees: List[int],
+                   admit_span) -> bool:
+        """Seat one popped request: prefill, place, first token.  False
+        when slots or pages are busy with live sequences and the request
+        went back to the head of the queue (an evict next tick frees
+        them: not a refusal, that happens at submit); a request that
+        failed is finished and counts as handled."""
+        popped_at = time.monotonic()
+        if isinstance(req, BeamRequest) and len(frees) < req.beam_size:
+            self._requeue_head(req)
+            return False
+        need = self.model.context_pages(req.prompt, req.max_new_tokens)
+        try:
+            got = self._prefill_with_cache(req, need, admit_span)
+        except PoolExhausted as e:   # raced with another allocator user
+            _M_REFUSED.inc(reason="pool_exhausted")
+            req._finish("error", AdmissionRefused("pool_exhausted",
+                                                  str(e)))
+            return True
+        except BaseException as e:
+            req._finish("error", e)
+            return True
+        if got is None:
+            self._requeue_head(req)
+            return False
+        if req.admitted_at is None:
+            # a request that a failed step sent back is seated again,
+            # and has waited once
+            req.admitted_at = popped_at
+            _M_QUEUE_WAIT.observe(popped_at - req.submitted_at)
+        pages, ctx_len, state_rows, first_logits = got
+        if isinstance(req, BeamRequest):
+            self._admit_beam(req, frees[:req.beam_size], pages,
+                             ctx_len, state_rows, first_logits)
+        else:
+            self._place(frees[0], _Slot(req, pages, ctx_len),
+                        ctx_len, state_rows)
+            if first_logits is not None:
+                with span("decode.first_token", rid=req.rid):
+                    tok = self._choose(self._slots[frees[0]],
                                        np.asarray(first_logits))
                     self._emit_token(frees[0], tok)
-            _M_ACTIVE.set(self.active)
+        _M_ACTIVE.set(self.active)
+        return True
 
     def _admit_beam(self, req: BeamRequest, slot_idx: List[int],
                     pages: List[int], ctx_len: int, state_rows,

@@ -41,7 +41,7 @@ from paddle_tpu import framework
 from paddle_tpu.framework import Program, Variable, TPUPlace, Place
 from paddle_tpu.lod import LoDArray
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.observability.events import GLOBAL_EVENTS as _EVENTS
+from paddle_tpu.observability.events import span
 from paddle_tpu.registry import LowerContext, OpRegistry, RngState
 from paddle_tpu.sparse import SparseGrad
 
@@ -318,66 +318,83 @@ class Executor:
             # the plain one never collide
             program = self._optimized(program, feed, fetch_names)
 
-        block = program.global_block()
-        fp = self._program_key(program)
-        prog_label = fp[:12]
+        with span("executor.run", step=self._step + 1) as run_span:
+            return self._run(program, feed, fetch_names, scope,
+                             return_numpy, run_span)
 
-        t_feed = time.perf_counter()
-        feed_vals = {
-            name: _convert_feed(v, block.find_var(name)) for name, v in feed.items()
-        }
-        _M_FEED_SEC.observe(time.perf_counter() - t_feed, program=prog_label)
+    def _run(self, program, feed, fetch_names, scope, return_numpy,
+             run_span):
+        """One step inside its ``executor.run`` span; the children, in
+        order: feed, lookup, compile (misses only), gather_state, step,
+        commit_state, fetch."""
+        block = program.global_block()
+        with span("executor.feed"):
+            t_feed = time.perf_counter()
+            feed_vals = {
+                name: _convert_feed(v, block.find_var(name))
+                for name, v in feed.items()
+            }
+            dt_feed = time.perf_counter() - t_feed
 
         from paddle_tpu import amp
         from paddle_tpu import pallas as pk
         from paddle_tpu.flags import FLAGS
 
-        key = (
-            fp,
-            _feed_signature(feed_vals),
-            fetch_names,
-            self.place,
-            id(self.strategy),
-            amp.is_enabled(),
-            pk.mode(),
-            pk.interpret_mode(),
-            bool(FLAGS.get("trace_ops")),
-        )
-        compiled = self._cache.get(key)
+        with span("executor.lookup"):
+            fp = self._program_key(program)
+            prog_label = fp[:12]
+            key = (
+                fp,
+                _feed_signature(feed_vals),
+                fetch_names,
+                self.place,
+                id(self.strategy),
+                amp.is_enabled(),
+                pk.mode(),
+                pk.interpret_mode(),
+                bool(FLAGS.get("trace_ops")),
+            )
+            compiled = self._cache.get(key)
         cache_hit = compiled is not None
+        tag = "hit" if cache_hit else "miss"
+        run_span.set(program=prog_label, cached=tag)
+        _M_FEED_SEC.observe(dt_feed, program=prog_label)
         t_compile = time.perf_counter()
-        if compiled is None:
-            # compile miss: the artifact store (paddle_tpu/aot) gets
-            # first refusal — a manifest match deserializes the exported
-            # executable (donation intact) instead of trace+compile
-            compiled = self._aot_lookup(program, fp, feed_vals, fetch_names)
-        if compiled is not None and not cache_hit:
-            _M_CACHE_MISS.inc(program=prog_label, source="aot")
-            self.compile_counts["aot"] += 1
-            self._cache[key] = compiled
-        elif compiled is None:
-            # Pre-compile static checks (paddle_tpu/analysis).  The fetch
-            # check always runs — fetching a never-written variable must
-            # name the variable up front, not die as a KeyError mid-trace.
-            # With the check_program flag on, the full error tier runs
-            # (def-before-use, dtype clash, bad sub-blocks, ...) before
-            # any JAX tracing.  Cache hits skip both: already vetted.
-            _M_CACHE_MISS.inc(program=prog_label, source="jit")
-            self.compile_counts["jit"] += 1
-            with _EVENTS.span("executor.compile", program=prog_label):
-                self._verify(program, feed_vals, fetch_names)
-                compiled = self._compile(program, feed_vals, fetch_names, scope)
-            self._cache[key] = compiled
-        else:
+        if cache_hit:
             _M_CACHE_HIT.inc(program=prog_label, source=compiled.source)
+        else:
+            with span("executor.compile", program=prog_label):
+                # compile miss: the artifact store (paddle_tpu/aot) gets
+                # first refusal — a manifest match deserializes the
+                # exported executable (donation intact) instead of
+                # trace+compile
+                compiled = self._aot_lookup(program, fp, feed_vals,
+                                            fetch_names)
+                source = "jit" if compiled is None else "aot"
+                _M_CACHE_MISS.inc(program=prog_label, source=source)
+                self.compile_counts[source] += 1
+                if compiled is None:
+                    # Pre-compile static checks (paddle_tpu/analysis).
+                    # The fetch check always runs — fetching a
+                    # never-written variable must name the variable up
+                    # front, not die as a KeyError mid-trace.  With the
+                    # check_program flag on, the full error tier runs
+                    # (def-before-use, dtype clash, bad sub-blocks, ...)
+                    # before any JAX tracing.  Cache hits skip both:
+                    # already vetted.
+                    self._verify(program, feed_vals, fetch_names)
+                    compiled = self._compile(program, feed_vals,
+                                             fetch_names, scope)
+            self._cache[key] = compiled
 
-        state = {}
-        missing = []
-        for n in compiled.state_names:
-            v = scope.get(n)
-            if v is None:
-                missing.append(n)
-            state[n] = v
+        with span("executor.gather_state"):
+            state = {}
+            missing = []
+            for n in compiled.state_names:
+                v = scope.get(n)
+                if v is None:
+                    missing.append(n)
+                state[n] = v
         if missing:
             raise RuntimeError(
                 f"persistable variables not initialized in scope: {missing}; "
@@ -399,14 +416,11 @@ class Executor:
         args = [state, feed_vals]
         if compiled.uses_rng:
             args.append(np.int64(self._seed_for_step(program)))
-        tag = "hit" if cache_hit else "miss"
-        ev_t0 = _EVENTS.now()
-        t_step = time.perf_counter()
-        fetches, new_state = compiled.fn(*args)
-        dt_step = time.perf_counter() - t_step
+        with span("executor.step"):
+            t_step = time.perf_counter()
+            fetches, new_state = compiled.fn(*args)
+            dt_step = time.perf_counter() - t_step
         _M_STEP_SEC.observe(dt_step, program=prog_label, cached=tag)
-        _EVENTS.complete("executor.step", ev_t0, dt_step,
-                         program=prog_label, cached=tag)
         if not cache_hit:
             # trace + jit + the first (compiling) dispatch: jax defers
             # tracing/XLA work to the first call, so the honest
@@ -414,28 +428,31 @@ class Executor:
             _M_COMPILE_SEC.observe(time.perf_counter() - t_compile,
                                    program=prog_label)
 
-        for n, v in new_state.items():
-            scope.set(n, v)
+        with span("executor.commit_state"):
+            for n, v in new_state.items():
+                scope.set(n, v)
 
-        t_fetch = time.perf_counter()
-        out = []
-        nbytes = 0
-        for v in fetches:
-            if return_numpy:
+        if not (return_numpy and fetches):
+            return list(fetches)
+        with span("executor.fetch"):
+            t_fetch = time.perf_counter()
+            out = []
+            nbytes = 0
+            for v in fetches:
                 if isinstance(v, LoDArray):
-                    v = LoDArray(np.asarray(v.data), tuple(np.asarray(o) for o in v.lod))
+                    v = LoDArray(np.asarray(v.data),
+                                 tuple(np.asarray(o) for o in v.lod))
                 elif isinstance(v, SparseGrad):
                     v = SparseGrad(np.asarray(v.rows), np.asarray(v.values),
                                    v.height)
                 else:
                     v = np.asarray(v)
                 nbytes += _fetch_nbytes(v)
-            out.append(v)
-        if return_numpy and out:
+                out.append(v)
             _M_FETCH_SEC.observe(time.perf_counter() - t_fetch,
                                  program=prog_label)
-            if nbytes:
-                _M_FETCH_BYTES.inc(nbytes, program=prog_label)
+        if nbytes:
+            _M_FETCH_BYTES.inc(nbytes, program=prog_label)
         return out
 
     # -- internals ----------------------------------------------------------
@@ -852,10 +869,11 @@ class Executor:
         # so donate_argnums=(0,) donates exactly the buffers the mask
         # proved safe; the public _Compiled.fn keeps the historical
         # fn(state, feeds[, seed]) calling convention and splits the dict.
-        def run_block_split(donated, held, feeds, seed=None):
+        def paddle_step(donated, held, feeds, seed=None):
             merged = dict(held)
             merged.update(donated)
-            return run_block(merged, feeds, seed)
+            with jax.named_scope("paddle_step"):
+                return run_block(merged, feeds, seed)
 
         jit_kwargs: Dict[str, Any] = (
             {"donate_argnums": (0,)} if donated_names else {})
@@ -870,7 +888,9 @@ class Executor:
                 {n: state_sh[n] for n in held_names},
             ) + tuple(sh["in_shardings"][1:])
             jit_kwargs["out_shardings"] = sh["out_shardings"]
-        jfn = jax.jit(run_block_split, **jit_kwargs)
+        # the function's name is the module's in a device trace:
+        # jit_paddle_step, whatever the program
+        jfn = jax.jit(paddle_step, **jit_kwargs)
         # A place that names a device (CPUPlace) commits every argument
         # to it, so the step runs there whatever the default backend is;
         # TPUPlace leaves placement to jax's default device.

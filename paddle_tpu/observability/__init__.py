@@ -7,11 +7,14 @@ The instrument panel for everything the ROADMAP wants measured:
   on every compile/step/request.  Exposed as Prometheus text on the
   server's ``GET /metrics``, as JSON/tables via ``paddle stats``, and
   as the bench telemetry artifact.
-- ``events``    — bounded host-side event ring exporting Chrome-trace
-  JSON (compile/step/serving spans) for ``chrome://tracing``.
-- device-side naming — ``flags trace_ops=1`` wraps each op's lowering
-  in ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so xprof
-  traces show op names instead of anonymous XLA regions (executor.py).
+- ``events``    — ``span(name, **args)``, the one way the program
+  writes a span: a ``jax.profiler.TraceAnnotation`` (in a jax profile,
+  on the device trace's clock) and, while ``recording()`` is on, a
+  Chrome-trace event with id/parent/args in the bounded
+  ``GLOBAL_EVENTS`` ring (``paddle stats --trace``, ``GET /trace``).
+- device-side naming — the executor's jitted step is ``jit_paddle_step``
+  and every Pallas kernel has a ``name=``; ``flags trace_ops=1`` also
+  wraps each op's lowering in ``jax.named_scope`` (executor.py).
 
 ``reset()`` clears recorded values (registered metric families survive,
 so module-level handles stay valid) — tests call it per-case.
@@ -40,6 +43,8 @@ from paddle_tpu.observability.metrics import (  # noqa: F401
 from paddle_tpu.observability.events import (  # noqa: F401
     EventRecorder,
     GLOBAL_EVENTS,
+    recording,
+    span,
 )
 
 
@@ -54,27 +59,62 @@ def export_chrome_trace(path: str) -> str:
     return GLOBAL_EVENTS.export(path)
 
 
+# spans one cached Executor.run writes (executor.compile: misses only)
+_RUN_SPANS = ("executor.feed", "executor.lookup", "executor.gather_state",
+              "executor.step", "executor.commit_state", "executor.fetch")
+
+
 def measure_step_overhead(iters: int = 2000) -> float:
     """Average wall cost (seconds) of the telemetry writes Executor.run
-    adds to one *cached* step: the cache-hit counter, the feed/step
-    histogram observes, the fetch-bytes counter, and one host event.
+    adds to one *cached* step with nobody recording: the cache-hit
+    counter, the feed/step histogram observes, the fetch-bytes counter,
+    and the ``executor.run`` span with its six children.
 
-    Runs against private registry/recorder instances so measuring does
-    not pollute live metrics.  Recorded into the bench telemetry
-    artifact (``telemetry_overhead`` fields) and asserted ≤ budget in
-    tests — the hot-path ≤2% guarantee, measured instead of promised.
+    The counters go to a private registry so measuring does not pollute
+    live metrics.  Recorded into the bench telemetry artifact
+    (``telemetry_overhead`` fields) and asserted ≤ budget in tests —
+    the hot-path ≤2% guarantee, measured instead of promised.
     """
     reg = MetricsRegistry()
     hits = reg.counter("overhead_probe_hits_total")
     fetched = reg.counter("overhead_probe_bytes_total")
     steps = reg.histogram("overhead_probe_seconds")
-    ev = EventRecorder(max_events=16)
     t0 = time.perf_counter()
-    for _ in range(iters):
-        t = ev.now()
+    for i in range(iters):
+        with span("executor.run", program="fingerprint0", step=i) as run:
+            for name in _RUN_SPANS:
+                with span(name):
+                    pass
+            run.set(cached="hit")
         hits.inc(program="fingerprint0")
         steps.observe(1e-4, program="fingerprint0", stage="feed")
         steps.observe(1e-3, program="fingerprint0", cached="hit")
         fetched.inc(4096, program="fingerprint0")
-        ev.complete("executor.step", t, 1e-3, program="fingerprint0")
     return (time.perf_counter() - t0) / iters
+
+
+def measure_span_overhead(iters: int = 20000) -> dict:
+    """Seconds per ``span(name, a=, b=)`` with both sinks off
+    (``off``), while a jax profile is being captured (``profile``) and
+    with the ring on (``ring``).  The ring mode records into
+    ``GLOBAL_EVENTS`` and clears it afterwards; the profile is written
+    to a temporary directory and thrown away."""
+    import tempfile
+
+    from paddle_tpu.profiler import profiler
+
+    def per_span():
+        t0 = time.perf_counter()
+        for i in range(iters):
+            with span("overhead.probe", rid=i, kind="probe"):
+                pass
+        return (time.perf_counter() - t0) / iters
+
+    out = {"off": per_span()}
+    with tempfile.TemporaryDirectory(prefix="span_overhead_") as path:
+        with profiler(path):
+            out["profile"] = per_span()
+    with recording() as ring:
+        out["ring"] = per_span()
+    ring.clear()
+    return out

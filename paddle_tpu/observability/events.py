@@ -1,21 +1,40 @@
-"""Host-side event recorder exporting Chrome-trace JSON.
+"""The program's one span API, and the host-side ring behind it.
 
-Complements jax.profiler (device timeline): this records the *host*
-story — compile vs cached step vs serving request — as complete ("X")
-events loadable in ``chrome://tracing`` / Perfetto alongside an xprof
-capture.  The ring is bounded (``max_events``) so an always-on recorder
-cannot grow without limit under serving traffic.
+``span(name, **args)`` is the only way the program writes a span.  It
+has two sinks:
+
+- a ``jax.profiler.TraceAnnotation``: recorded only while a jax profile
+  is being captured (``paddle_tpu.profiler.profiler()``, xprof, the
+  benchmark's ``--trace 1`` run), in the profile's host plane, on the
+  same clock as the device ops — so a device-idle gap can be named
+  after what the host was doing in it.  With no profile running it
+  costs one object construction.
+- the ``GLOBAL_EVENTS`` ring, **off by default**: while enabled
+  (``recording()``, ``paddle stats --trace``, ``GET /trace``) every
+  span is kept as a Chrome-trace complete event with an ``id``, its
+  ``parent`` (the innermost span open on the same thread, 0 for none)
+  and its args; spans of one request share ``rid``.  The ring runs on
+  ``time.perf_counter`` (CLOCK_MONOTONIC, the clock a load generator
+  stamps its sends on) and its export carries that clock's value at the
+  ring's epoch, so a ring span lines up with a client-side record.
+
+A profile's timestamps count from the profile's start, so ring and
+profile are aligned by content, not by clock: with both sinks on the
+same span is in both.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List
+
+from jax.profiler import TraceAnnotation
 
 
 class EventRecorder:
@@ -23,7 +42,11 @@ class EventRecorder:
 
     Timestamps are microseconds since the recorder's epoch
     (``perf_counter`` based, monotonic), which is what the trace viewer
-    expects; wall-clock anchoring is recorded once in metadata.
+    expects; the epoch is anchored once in metadata, on the wall clock
+    and on ``perf_counter`` itself.  ``enabled`` gates only what
+    ``span()`` writes into ``GLOBAL_EVENTS``: ``complete``/``instant``
+    called directly always record (a private recorder in a test or an
+    artifact).
     """
 
     def __init__(self, max_events: int = 100_000):
@@ -31,6 +54,16 @@ class EventRecorder:
         self._epoch_unix = time.time()
         self._events: collections.deque = collections.deque(maxlen=max_events)
         self._lock = threading.Lock()
+        self.enabled = False
+
+    def enable(self):
+        """Start a recording: drop what an earlier one left, then let
+        ``span()`` write here."""
+        self.clear()
+        self.enabled = True
+
+    def disable(self):
+        self.enabled = False
 
     def now(self) -> float:
         """Seconds since the recorder epoch."""
@@ -40,6 +73,9 @@ class EventRecorder:
                  cat: str = "paddle", **args):
         """Record a complete ("X") event; ``start``/``dur`` in seconds
         on the ``now()`` clock."""
+        self._complete(name, start, dur, cat, args)
+
+    def _complete(self, name, start, dur, cat, args):
         ev: Dict[str, Any] = {
             "name": name, "cat": cat, "ph": "X",
             "ts": start * 1e6, "dur": max(dur, 0.0) * 1e6,
@@ -49,14 +85,6 @@ class EventRecorder:
             ev["args"] = args
         with self._lock:
             self._events.append(ev)
-
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "paddle", **args):
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.now() - t0, cat, **args)
 
     def instant(self, name: str, cat: str = "paddle", **args):
         ev: Dict[str, Any] = {
@@ -88,6 +116,7 @@ class EventRecorder:
             "otherData": {
                 "recorder": "paddle_tpu.observability",
                 "epoch_unix_sec": self._epoch_unix,
+                "epoch_perf_counter_sec": self._t0,
             },
         }
 
@@ -99,3 +128,60 @@ class EventRecorder:
 
 
 GLOBAL_EVENTS = EventRecorder()
+
+_SPAN_IDS = itertools.count(1)       # next() is atomic under the GIL
+_OPEN = threading.local()            # .stack: ids of this thread's open spans
+
+
+class span:
+    """``with span("decode.tick", active=3): ...`` — see the module's
+    docstring.  Safe from any thread and re-entrant; an argument known
+    only inside the span is added with ``set`` (it reaches the profile
+    and the ring alike)."""
+
+    __slots__ = ("name", "args", "_ann", "_open")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def set(self, **args):
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if GLOBAL_EVENTS.enabled:
+            try:
+                stack = _OPEN.stack
+            except AttributeError:
+                stack = _OPEN.stack = []
+            sid = next(_SPAN_IDS)
+            self._open = (sid, stack[-1] if stack else 0,
+                          GLOBAL_EVENTS.now(), stack)
+            stack.append(sid)
+        else:
+            self._open = None
+        return self
+
+    def __exit__(self, *exc):
+        if self._open is not None:
+            sid, parent, t0, stack = self._open
+            stack.remove(sid)
+            GLOBAL_EVENTS._complete(
+                self.name, t0, GLOBAL_EVENTS.now() - t0, "paddle",
+                dict(self.args, id=sid, parent=parent))
+        self._ann.__exit__(*exc)
+        return False
+
+
+@contextlib.contextmanager
+def recording():
+    """Record every ``span()`` of the enclosed block into
+    ``GLOBAL_EVENTS``; yields the ring."""
+    GLOBAL_EVENTS.enable()
+    try:
+        yield GLOBAL_EVENTS
+    finally:
+        GLOBAL_EVENTS.disable()

@@ -146,6 +146,7 @@ def _bn_fwd_impl(x2d, gamma, beta, eps: float, interpret: bool = False,
         scratch_shapes=[pltpu.VMEM((2, C), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="batch_norm_fwd",
         interpret=interpret,
     )(x2d, gamma.reshape(1, C), beta.reshape(1, C))
     return y, mean.reshape(C), var.reshape(C)
@@ -220,6 +221,7 @@ def _bn_bwd_impl(x2d, dy2d, gamma, mean, inv, interpret: bool = False):
         scratch_shapes=[pltpu.VMEM((2, C), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="batch_norm_bwd",
         interpret=interpret,
     )(x2d, dy2d, gamma.reshape(1, C), mean.reshape(1, C), inv.reshape(1, C))
     return dx, dgamma.reshape(C), dbeta.reshape(C)

@@ -216,6 +216,7 @@ def _conv_fwd_impl(x, w, padding: int, interpret: bool = False,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
+        name="conv2d_fwd",
         interpret=interpret,
     )(xp, w)
 
@@ -272,6 +273,7 @@ def _conv_dw_impl(x, g, kernel: int, padding: int, interpret: bool = False):
         scratch_shapes=[pltpu.VMEM((kw * c, o), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="conv2d_bwd_dw",
         interpret=interpret,
     )(xp, g)
 

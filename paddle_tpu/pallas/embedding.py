@@ -64,6 +64,7 @@ def _gather_impl(w, ids, interpret: bool = False):
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, d), w.dtype),
+        name="embedding_gather",
         interpret=interpret,
     )(ids.astype(jnp.int32), w3)
     return out.reshape(n, d)

@@ -177,6 +177,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, scale: float,
         # that block (BH carries the core-level parallelism instead)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse.reshape(BH, S)
@@ -306,6 +307,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
         scratch_shapes=[pltpu.VMEM((blk_q, D), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse3, delta3)
 
@@ -333,6 +335,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
                         pltpu.VMEM((blk_k, D), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse3, delta3)
     return dq, dk, dv

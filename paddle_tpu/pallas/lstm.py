@@ -142,6 +142,7 @@ def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False,
                             pltpu.VMEM((bb, H), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
+            name="lstm_fused_blocked",
             interpret=interpret,
         )(xproj, w, bias.reshape(1, H4), h0, c0)
     return pl.pallas_call(
@@ -168,6 +169,7 @@ def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False,
                         pltpu.VMEM((B, H), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name="lstm_fused",
         interpret=interpret,
     )(xproj, w, bias.reshape(1, H4), h0, c0)
 

@@ -107,5 +107,6 @@ def _matmul_impl(x, y, bm: int = None, bk: int = None, bn: int = None,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="matmul_tiled",
         interpret=interpret,
     )(x, y)

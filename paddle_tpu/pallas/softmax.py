@@ -75,5 +75,6 @@ def _softmax_impl(x, block_rows: int = None, interpret: bool = False):
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="softmax_rows",
         interpret=interpret,
     )(x)

@@ -42,9 +42,3 @@ def profiler(output_dir: str = "/tmp/paddle_tpu_profile", **kwargs):
 
 # reference-compatible alias (fluid.profiler.cuda_profiler)
 cuda_profiler = profiler
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    with jax.profiler.TraceAnnotation(name):
-        yield

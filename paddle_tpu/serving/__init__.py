@@ -107,12 +107,13 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
+from urllib.parse import parse_qs, urlsplit
 
 import numpy as np
 
 from paddle_tpu import framework
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.observability.events import GLOBAL_EVENTS as _EVENTS
+from paddle_tpu.observability.events import recording, span
 from paddle_tpu.serving.batching import (
     DEFAULT_TENANT,
     BatchSpec,
@@ -146,6 +147,13 @@ _M_INFLIGHT = _metrics.gauge(
     "serving_inflight_requests", "requests currently being handled")
 _M_RESPONSES = _metrics.counter(
     "serving_responses_total", "HTTP responses by status code")
+_M_FIRST_WRITE_LAG = _metrics.histogram(
+    "serving_generate_first_write_lag_seconds",
+    "a streamed /generate request's first token: emitted by the decode "
+    "stepper to written on the socket by the handler thread")
+# GET /trace records for at most this long: the ring is bounded, the
+# handler thread sleeps meanwhile
+TRACE_MAX_SECONDS = 60.0
 _M_REJECTED = _metrics.counter(
     "serving_rejected_total",
     "requests shed for graceful degradation, by reason "
@@ -229,6 +237,7 @@ class InferenceServer:
                       if self._bundle else None)
         self._request_timeout = request_timeout
         self._max_inflight = max_inflight
+        self._trace_lock = threading.Lock()   # one /trace at a time
         self._slots = (threading.BoundedSemaphore(max_inflight)
                        if max_inflight else None)
         if warmup and self._pool is not None:
@@ -287,8 +296,36 @@ class InferenceServer:
                         raw=_metrics.render_prometheus().encode())
                 elif self.path == "/stats":
                     self._reply(200, _metrics.snapshot())
+                elif urlsplit(self.path).path == "/trace":
+                    self._handle_trace(urlsplit(self.path).query)
                 else:
                     self._reply(404, {"error": "unknown path"})
+
+            def _handle_trace(self, query: str) -> None:
+                """GET /trace?seconds=N: record every span of this
+                process for N seconds (capped) and reply with the
+                ring's Chrome-trace JSON."""
+                try:
+                    seconds = float(
+                        parse_qs(query).get("seconds", ["1"])[0])
+                except ValueError:
+                    seconds = -1.0
+                if not 0 <= seconds <= TRACE_MAX_SECONDS:
+                    self._reply(400, {
+                        "error": "'seconds' must be a number in "
+                                 f"[0, {TRACE_MAX_SECONDS}]"})
+                    return
+                if not server._trace_lock.acquire(blocking=False):
+                    self._reply(409, {"error": "a /trace recording is "
+                                      "already running"})
+                    return
+                try:
+                    with recording() as ring:
+                        time.sleep(seconds)
+                        doc = ring.to_chrome_trace()
+                finally:
+                    server._trace_lock.release()
+                self._reply(200, doc)
 
             def do_POST(self):
                 # always consume the body first: on keep-alive
@@ -317,8 +354,11 @@ class InferenceServer:
                     self._reply(503, {"error": "server overloaded "
                                       f"(max_inflight={server._max_inflight})"})
                     return
+                with span("serving.predict"):
+                    self._handle_predict(raw_body)
+
+            def _handle_predict(self, raw_body: bytes) -> None:
                 _M_INFLIGHT.inc()
-                ev_t0 = _EVENTS.now()
                 t0 = time.perf_counter()
                 tenant = (self.headers.get("X-Tenant")
                           or DEFAULT_TENANT).strip() or DEFAULT_TENANT
@@ -363,8 +403,6 @@ class InferenceServer:
                     if server._slots is not None:
                         server._slots.release()
                     _M_REQ_SEC.observe(dt, endpoint="/predict")
-                    _EVENTS.complete("serving.predict", ev_t0, dt,
-                                     cat="serving")
 
             # -- generation (paged-KV decode engine) ---------------------
 
@@ -374,38 +412,52 @@ class InferenceServer:
                                  + data + b"\r\n")
 
             def _handle_generate(self, raw_body: bytes) -> None:
-                from paddle_tpu.decode import AdmissionRefused
+                from paddle_tpu.decode.session import next_rid
 
                 if server._generator is None:
                     self._reply(400, {"error": "no generation engine "
                                       "mounted (serve with --gen_config)"})
                     return
+                rid = next_rid()
+                with span("serving.generate", rid=rid) as gen_span:
+                    self._generate(raw_body, rid, gen_span)
+
+            def _generate(self, raw_body: bytes, rid: int,
+                          gen_span) -> None:
+                """One /generate request inside its ``serving.generate``
+                span; ``rid`` goes on every span the request causes,
+                here and in the decode engine."""
+                from paddle_tpu.decode import AdmissionRefused
+
                 _M_INFLIGHT.inc()
-                ev_t0 = _EVENTS.now()
                 t0 = time.perf_counter()
                 tenant = (self.headers.get("X-Tenant")
                           or DEFAULT_TENANT).strip() or DEFAULT_TENANT
                 try:
-                    payload = json.loads(raw_body or b"{}")
-                    if not isinstance(payload, dict):
-                        raise ValueError(
-                            "request body must be a JSON object")
-                    if "tenant" in payload:
-                        tenant = str(payload.pop("tenant")) or tenant
-                    src = payload.get("src")
-                    if (not isinstance(src, list) or not src
-                            or not all(isinstance(t, int) for t in src)):
-                        raise ValueError(
-                            "'src' must be a non-empty list of int ids")
-                    unknown = set(payload) - {"src", "max_new_tokens",
-                                              "stream", "beam",
-                                              "temperature", "top_k",
-                                              "seed"}
-                    if unknown:
-                        raise ValueError(
-                            f"unknown payload key {sorted(unknown)[0]!r}; "
-                            "expected src / max_new_tokens / stream / "
-                            "beam / temperature / top_k / seed / tenant")
+                    with span("serving.parse", rid=rid):
+                        payload = json.loads(raw_body or b"{}")
+                        if not isinstance(payload, dict):
+                            raise ValueError(
+                                "request body must be a JSON object")
+                        if "tenant" in payload:
+                            tenant = str(payload.pop("tenant")) or tenant
+                        src = payload.get("src")
+                        if (not isinstance(src, list) or not src
+                                or not all(isinstance(t, int)
+                                           for t in src)):
+                            raise ValueError(
+                                "'src' must be a non-empty list of int "
+                                "ids")
+                        unknown = set(payload) - {
+                            "src", "max_new_tokens", "stream", "beam",
+                            "temperature", "top_k", "seed"}
+                        if unknown:
+                            raise ValueError(
+                                f"unknown payload key "
+                                f"{sorted(unknown)[0]!r}; expected src / "
+                                "max_new_tokens / stream / beam / "
+                                "temperature / top_k / seed / tenant")
+                    gen_span.set(tenant=tenant)
                     # same token buckets as /predict: a generation call
                     # spends one admission token for its tenant
                     server._tenants.admit(tenant)
@@ -423,9 +475,10 @@ class InferenceServer:
                                 or isinstance(beam, bool)):
                             raise ValueError(
                                 "'beam' must be a positive int")
-                        req = server._generator.submit_beam(
-                            src, beam_size=beam,
-                            max_new_tokens=budget, deadline=deadline)
+                        with span("serving.submit", rid=rid):
+                            req = server._generator.submit_beam(
+                                src, beam_size=beam, max_new_tokens=budget,
+                                deadline=deadline, rid=rid)
                         ids = req.result(timeout)
                         self._reply(200, {
                             "ids": ids,
@@ -434,13 +487,14 @@ class InferenceServer:
                             "finish_reason": req.finish_reason})
                     elif payload.get("stream", True):
                         self._stream_generate(src, budget, deadline,
-                                              payload)
+                                              payload, rid)
                     else:
-                        req = server._generator.submit(
-                            src, budget, deadline=deadline,
-                            temperature=payload.get("temperature"),
-                            top_k=payload.get("top_k"),
-                            seed=payload.get("seed"))
+                        with span("serving.submit", rid=rid):
+                            req = server._generator.submit(
+                                src, budget, deadline=deadline,
+                                temperature=payload.get("temperature"),
+                                top_k=payload.get("top_k"),
+                                seed=payload.get("seed"), rid=rid)
                         ids = req.result(timeout)
                         self._reply(200, {
                             "ids": ids,
@@ -469,11 +523,9 @@ class InferenceServer:
                     dt = time.perf_counter() - t0
                     _M_INFLIGHT.dec()
                     _M_REQ_SEC.observe(dt, endpoint="/generate")
-                    _EVENTS.complete("serving.generate", ev_t0, dt,
-                                     cat="serving")
 
             def _stream_generate(self, src, budget, deadline,
-                                 payload=None) -> None:
+                                 payload, rid) -> None:
                 """Chunked ndjson: one line per token as the decode
                 session emits it, then the summary line.  Admission
                 refusals (503) and pre-stream deadline expiry (504)
@@ -482,12 +534,12 @@ class InferenceServer:
                 ``finish_reason: "deadline"`` (the status is already
                 on the wire)."""
                 q: queue_mod.Queue = queue_mod.Queue()
-                payload = payload or {}
-                req = server._generator.submit(
-                    src, budget, on_token=q.put, deadline=deadline,
-                    temperature=payload.get("temperature"),
-                    top_k=payload.get("top_k"),
-                    seed=payload.get("seed"))
+                with span("serving.submit", rid=rid):
+                    req = server._generator.submit(
+                        src, budget, on_token=q.put, deadline=deadline,
+                        temperature=payload.get("temperature"),
+                        top_k=payload.get("top_k"),
+                        seed=payload.get("seed"), rid=rid)
                 if deadline is not None:
                     # hold the 200 until the stream actually starts:
                     # a request that dies of its deadline before its
@@ -508,12 +560,23 @@ class InferenceServer:
                                      "application/x-ndjson")
                     self.send_header("Transfer-Encoding", "chunked")
                     self.end_headers()
+                    first = True
                     while True:
                         try:
-                            self._chunk({"token": q.get(timeout=0.05)})
+                            token = q.get(timeout=0.05)
                         except queue_mod.Empty:
                             if req.done and q.empty():
                                 break
+                            continue
+                        if not first:
+                            self._chunk({"token": token})
+                            continue
+                        first = False
+                        with span("serving.first_write", rid=rid):
+                            self._chunk({"token": token})
+                        # the handler waking up behind the stepper
+                        _M_FIRST_WRITE_LAG.observe(
+                            time.monotonic() - req.first_token_at)
                     final = {"done": True, "ids": req.tokens,
                              "finish_reason": req.finish_reason}
                     if req.error is not None:

@@ -1,0 +1,5 @@
+"""Executor: the host work of one ``exe.run`` before its dispatch (the
+program's ``executor.feed`` + ``executor.lookup`` +
+``executor.gather_state`` spans), mean per run, LM training cell."""
+
+from perf.harness.program_spans import exec_prepare_ms as read  # noqa: F401

@@ -12,6 +12,7 @@ described device cannot be read back without one).
 """
 
 import os
+import re
 
 import pytest
 
@@ -45,6 +46,13 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_op_names(text):
+    """The op_name of every Pallas custom call of a compiled program:
+    what a trace reduction finds a kernel's device events by."""
+    return [m.group(1) for line in text.splitlines() if MARKER in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
 # (slots, heads, head_dim, page, pool pages, pages/seq, dtype): a real
 # decode batch, and the /generate model chip_smoke.py serves
 RPA_REAL = (64, 16, 128, 16, 2048, 32, jnp.bfloat16)
@@ -64,6 +72,9 @@ def test_ragged_paged_attention_compiles(one_chip, shape, slots_per_block):
         one_chip, ((S, H, D), dt), ((N, page, H, D), dt),
         ((N, page, H, D), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
     assert MARKER in text
+    want = ("ragged_paged_attention_blocked/" if slots_per_block > 1
+            else "ragged_paged_attention/")
+    assert all(want in op for op in _kernel_op_names(text))
 
 
 def test_ragged_paged_attention_chunk_compiles(one_chip):
@@ -75,6 +86,41 @@ def test_ragged_paged_attention_chunk_compiles(one_chip):
         ((S, T, H, D), dt), ((N, page, H, D), dt), ((N, page, H, D), dt),
         ((S, P), jnp.int32), ((S,), jnp.int32))
     assert MARKER in text
+    assert all("ragged_paged_attention_chunk/" in op
+               for op in _kernel_op_names(text))
+
+
+def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
+    """The jitted decode step of the /generate model: every Pallas
+    custom call's op_name holds the kernel's own name (what the
+    per-kernel metrics of a later PR match) and the jitted wrapper's
+    (what ``rpa_ms_per_step`` matches today)."""
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import model as dm
+
+    # jax's backend is the CPU here, so "auto" would take the jnp
+    # reference: steer the dispatch in the test, as on the chip
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    lm_kw = dict(vocab=64, d=32, heads=4, layers=2, max_len=64)
+    params = jax.eval_shape(
+        lambda: dm._init_params(jax.random.key(0), **lm_kw))
+    S, N, pg, P = 4, 16, 8, 8
+    dh = lm_kw["d"] // lm_kw["heads"]
+    pool = ((lm_kw["layers"], N, pg, lm_kw["heads"], dh), jnp.float32)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = dm._decode_step.lower(
+        jax.tree.map(lambda a: sds(a.shape, a.dtype), params),
+        sds(*pool), sds(*pool), sds((S, P), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.int32),
+        heads=lm_kw["heads"], page_size=pg).compile().as_text()
+    ops = _kernel_op_names(text)
+    assert len(ops) == lm_kw["layers"]
+    assert all("_decode_step" in op and "ragged_paged_attention" in op
+               for op in ops)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
@@ -91,6 +137,17 @@ def test_flash_attention_compiles(one_chip, grad):
     qkv = [((384, 1024, 128), jnp.bfloat16)] * 3
     text = _compiled_text(bwd if grad else fwd, one_chip, *qkv)
     assert text.count(MARKER) >= (2 if grad else 1)
+    # forward and backward can be told apart by the kernels' own names,
+    # beside the jitted wrappers' that flash_attn_ms_per_step matches
+    ops = _kernel_op_names(text)
+    fwd_ops = [op for op in ops if "flash_attention_fwd/" in op]
+    bwd_ops = [op for op in ops if "flash_attention_bwd_" in op]
+    assert len(fwd_ops) == 1 and "_flash_fwd_impl" in fwd_ops[0]
+    assert len(bwd_ops) == (2 if grad else 0)
+    assert all("_flash_bwd_impl" in op for op in bwd_ops)
+    assert {op.split("/")[-2] for op in bwd_ops} == (
+        {"flash_attention_bwd_dq", "flash_attention_bwd_dkv"} if grad
+        else set())
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

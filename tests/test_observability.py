@@ -147,16 +147,27 @@ def test_format_table_alignment():
 # ---------------------------------------------------------------------------
 
 
-def test_chrome_trace_export_well_formed(tmp_path):
-    rec = obs.EventRecorder(max_events=100)
-    with rec.span("outer", cat="test", program="p"):
-        with rec.span("inner", cat="test"):
+@pytest.fixture
+def ring():
+    """The span ring is off by default: record for one test."""
+    with obs.recording() as r:
+        yield r
+
+
+def test_chrome_trace_export_well_formed(tmp_path, ring):
+    with obs.span("outer", program="p"):
+        with obs.span("inner"):
             pass
-    rec.instant("marker", cat="test")
-    path = rec.export(str(tmp_path / "trace.json"))
+    ring.instant("marker", cat="test")
+    path = obs.export_chrome_trace(str(tmp_path / "trace.json"))
     with open(path) as f:
         trace = json.load(f)
     assert trace["displayTimeUnit"] == "ms"
+    # the ring's epoch on both clocks: wall, and the perf_counter a load
+    # generator stamps its sends on
+    other = trace["otherData"]
+    assert other["epoch_unix_sec"] > 0
+    assert 0 < other["epoch_perf_counter_sec"] <= time.perf_counter()
     evs = trace["traceEvents"]
     assert len(evs) == 3
     for ev in evs:
@@ -202,7 +213,7 @@ def _prog_label():
     return Executor._program_key(fluid.default_main_program())[:12]
 
 
-def test_executor_cache_miss_then_hit_counters():
+def test_executor_cache_miss_then_hit_counters(ring):
     """Two identical Executor.run calls: the first is a compile-cache
     miss, the second a hit — the acceptance-criterion transition."""
     exe, pred = _tiny_model()
@@ -234,7 +245,7 @@ def test_executor_cache_miss_then_hit_counters():
     assert fetched[(("program", label),)]["value"] == 2 * 2 * 3 * 4  # f32
 
     # host events recorded the compile + both steps
-    names = [e["name"] for e in obs.GLOBAL_EVENTS.events()]
+    names = [e["name"] for e in ring.events()]
     assert names.count("executor.step") >= 2
     assert "executor.compile" in names
 
@@ -346,8 +357,22 @@ def test_paddle_stats_empty_and_file_and_trace(tmp_path, capsys):
         embedded = json.load(f)
     assert [e["name"] for e in embedded["traceEvents"]] == ["from_artifact"]
     assert cmd_stats([f"--file={p}", f"--trace={t2}"]) == 2  # no events
-    assert cmd_stats(["--url=http://localhost:1", f"--trace={t2}"]) == 2
     capsys.readouterr()
+
+    # --run --trace records the script's own spans: the ring is on
+    # while it runs and off again afterwards
+    script = tmp_path / "script.py"
+    script.write_text(
+        "from paddle_tpu import observability as obs\n"
+        "with obs.span('script.work', n=1):\n"
+        "    pass\n")
+    t3 = tmp_path / "run_trace.json"
+    assert cmd_stats([f"--run={script}", f"--trace={t3}"]) == 0
+    capsys.readouterr()
+    with open(t3) as f:
+        ran = json.load(f)["traceEvents"]
+    assert [e["name"] for e in ran] == ["script.work"]
+    assert not obs.GLOBAL_EVENTS.enabled
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +418,7 @@ def test_metrics_endpoint_on_live_server(tmp_path, capsys):
         # *after* the reply is on the wire — give the scrape a moment
         # to see both requests settle
         want = 'serving_request_seconds_count{endpoint="/predict"} 2'
-        for _ in range(100):
+        for scrapes in range(100):
             with urllib.request.urlopen(f"{base}/metrics", timeout=30) as r:
                 ctype = r.headers["Content-Type"]
                 text = r.read().decode()
@@ -404,7 +429,8 @@ def test_metrics_endpoint_on_live_server(tmp_path, capsys):
         assert "# TYPE serving_request_seconds histogram" in text
         assert 'serving_request_seconds_bucket{endpoint="/predict",le="+Inf"} 2' in text
         assert 'serving_request_seconds_count{endpoint="/predict"} 2' in text
-        assert 'serving_responses_total{code="200"} 2' in text
+        # every earlier scrape was a 200 too
+        assert f'serving_responses_total{{code="200"}} {2 + scrapes}' in text
         assert "serving_inflight_requests 0" in text
         # executor metrics ride on the same registry
         assert "executor_compile_cache_miss_total" in text
@@ -558,7 +584,7 @@ def test_trainer_show_layer_stat_and_log_period_flags(capsys):
     assert "executor_compile_cache_miss_total" in out
 
 
-def test_bench_telemetry_artifact_writer(tmp_path):
+def test_bench_telemetry_artifact_writer(tmp_path, ring):
     import importlib.util
     import os
 
@@ -588,3 +614,449 @@ def test_bench_telemetry_artifact_writer(tmp_path):
                for e in art["events"]["traceEvents"])
     # a cached step ran, so the overhead fraction is reported and sane
     assert 0 < art["telemetry_overhead_fraction_of_step"] < 0.5
+
+
+# ---------------------------------------------------------------------------
+# The one span API: ring (off by default) + TraceAnnotation
+# ---------------------------------------------------------------------------
+
+
+def _by_name(events):
+    out = {}
+    for e in events:
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def test_span_nests_with_parent_ids_and_late_args(ring):
+    with obs.span("a", rid=7) as a:
+        with obs.span("b", rid=7):
+            with obs.span("c"):
+                pass
+        with obs.span("b2"):
+            pass
+        a.set(cached="hit")
+    with obs.span("root2"):
+        pass
+    ev = {n: es[0] for n, es in _by_name(ring.events()).items()}
+    ids = {n: e["args"]["id"] for n, e in ev.items()}
+    assert len(set(ids.values())) == 5
+    assert ev["a"]["args"]["parent"] == 0 == ev["root2"]["args"]["parent"]
+    assert ev["b"]["args"]["parent"] == ids["a"] == ev["b2"]["args"]["parent"]
+    assert ev["c"]["args"]["parent"] == ids["b"]
+    # spans of one request share rid; an argument set inside the span
+    # is kept
+    assert ev["a"]["args"]["rid"] == ev["b"]["args"]["rid"] == 7
+    assert ev["a"]["args"]["cached"] == "hit"
+    # a child lies inside its parent on the ring's clock
+    assert ev["a"]["ts"] <= ev["b"]["ts"]
+    assert ev["b"]["ts"] + ev["b"]["dur"] <= ev["a"]["ts"] + ev["a"]["dur"]
+
+
+def test_span_is_reentrant_and_survives_exceptions(ring):
+    s = obs.span("again", n=1)
+    for _ in range(2):
+        with s:
+            pass
+    with pytest.raises(KeyError):
+        with obs.span("outer"):
+            with obs.span("raises"):
+                raise KeyError("x")
+    with obs.span("after"):
+        pass
+    ev = _by_name(ring.events())
+    assert len(ev["again"]) == 2
+    assert ev["raises"][0]["args"]["parent"] == ev["outer"][0]["args"]["id"]
+    # the thread's stack of open spans unwound: the next span is a root
+    assert ev["after"][0]["args"]["parent"] == 0
+
+
+def test_span_thread_safety_under_contention(ring):
+    """More threads than cores, a short switch interval: every span is
+    recorded once and parents never cross threads."""
+    import sys
+
+    n_threads, n_iter = 16, 200
+    errs = []
+
+    def work(k):
+        try:
+            for i in range(n_iter):
+                with obs.span("t.outer", k=k):
+                    with obs.span("t.inner", k=k):
+                        pass
+        except Exception as e:  # noqa: BLE001
+            errs.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errs
+    ev = _by_name(ring.events())
+    assert len(ev["t.outer"]) == len(ev["t.inner"]) == n_threads * n_iter
+    outer = {e["args"]["id"]: e for e in ev["t.outer"]}
+    assert len(outer) == n_threads * n_iter          # ids are unique
+    for e in ev["t.inner"]:
+        parent = outer[e["args"]["parent"]]
+        assert parent["tid"] == e["tid"] and parent["args"]["k"] == e["args"]["k"]
+    assert all(e["args"]["parent"] == 0 for e in ev["t.outer"])
+
+
+def test_span_with_both_sinks_off_records_nothing():
+    assert not obs.GLOBAL_EVENTS.enabled          # off by default
+    with obs.span("nobody.listens", rid=1) as sp:
+        sp.set(more=2)
+        with obs.span("nested"):
+            pass
+    assert obs.GLOBAL_EVENTS.events() == []
+    # ... and an Executor.run appends nothing either
+    exe, pred = _tiny_model()
+    xs = np.zeros((2, 4), "float32")
+    exe.run(feed={"x": xs}, fetch_list=[pred])
+    exe.run(feed={"x": xs}, fetch_list=[pred])
+    assert obs.GLOBAL_EVENTS.events() == []
+
+
+def test_recording_turns_the_ring_on_and_off():
+    with obs.span("before"):
+        pass
+    with obs.recording() as r:
+        assert r is obs.GLOBAL_EVENTS and r.enabled
+        with obs.span("during"):
+            pass
+    with obs.span("after"):
+        pass
+    assert [e["name"] for e in obs.GLOBAL_EVENTS.events()] == ["during"]
+    # a new recording starts empty
+    with obs.recording() as r:
+        assert r.events() == []
+
+
+def _capture_profile(tmp_path, body):
+    """Run ``body`` under a CPU jax.profiler capture; the host plane's
+    events as {name: [(start_ns, dur_ns, stats dict)]}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    d = str(tmp_path / "profile")
+    jax.profiler.start_trace(d)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(d + "/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_span_lands_in_a_jax_profile_and_in_the_ring(tmp_path, ring):
+    """With both sinks on the same span is in both: as a
+    TraceAnnotation on the profile's clock, its args as the event's
+    stats, and in the ring with id and parent."""
+    def body():
+        with obs.span("both.outer", rid=41, tenant="t0") as sp:
+            sp.set(cached="miss")
+            with obs.span("both.inner", rid=41):
+                time.sleep(0.002)
+
+    prof = _capture_profile(tmp_path, body)
+    (o_start, o_dur, o_stats), = prof["both.outer"]
+    (i_start, i_dur, i_stats), = prof["both.inner"]
+    assert o_stats["rid"] == 41 == i_stats["rid"]
+    assert o_stats["tenant"] == "t0" and o_stats["cached"] == "miss"
+    assert o_start <= i_start and i_start + i_dur <= o_start + o_dur
+    assert i_dur >= 2e6                               # ns
+    ev = _by_name(ring.events())
+    assert ev["both.inner"][0]["args"]["parent"] == \
+        ev["both.outer"][0]["args"]["id"]
+    assert ev["both.outer"][0]["args"]["rid"] == 41
+    # the two clocks differ by an offset only: durations agree
+    assert ev["both.inner"][0]["dur"] * 1e3 == pytest.approx(i_dur, rel=0.5)
+
+
+def test_executor_span_tree_in_order(ring):
+    """One Executor.run: executor.run with its children in order,
+    executor.compile only on the miss, the run's args on the root."""
+    exe, pred = _tiny_model()
+    ring.clear()                                   # drop the startup run
+    xs = np.random.RandomState(0).randn(2, 4).astype("float32")
+    trees = []
+    for _ in range(2):
+        exe.run(feed={"x": xs}, fetch_list=[pred])
+        evs = sorted(ring.events(), key=lambda e: e["ts"])
+        ring.clear()
+        (root,) = [e for e in evs if e["name"] == "executor.run"]
+        kids = [e for e in evs if e["args"].get("parent") == root["args"]["id"]]
+        assert len(kids) == len(evs) - 1
+        trees.append((root, [k["name"] for k in kids]))
+    cached = ["executor.feed", "executor.lookup", "executor.gather_state",
+              "executor.step", "executor.commit_state", "executor.fetch"]
+    assert trees[0][1] == cached[:2] + ["executor.compile"] + cached[2:]
+    assert trees[1][1] == cached
+    (miss, _), (hit, _) = trees
+    assert miss["args"]["cached"] == "miss" and hit["args"]["cached"] == "hit"
+    assert miss["args"]["program"] == hit["args"]["program"] == _prog_label()
+    assert hit["args"]["step"] == miss["args"]["step"] + 1
+
+
+def test_executor_step_is_named_in_the_compiled_module():
+    """The jitted step has a stable name whatever the program: the
+    module a device trace shows is jit_paddle_step, its ops sit under
+    the paddle_step scope."""
+    exe, pred = _tiny_model()
+    xs = np.zeros((2, 4), "float32")
+    exe.run(feed={"x": xs}, fetch_list=[pred])
+    comp = list(exe._cache.values())[-1]
+    state = {n: fluid.global_scope().get(n) for n in comp.state_names}
+    lowered = comp.fn.lower(state, {"x": xs})
+    assert "jit_paddle_step" in lowered.as_text()[:200]
+    assert "paddle_step/" in lowered.as_text(debug_info=True)
+
+
+def test_span_overhead_probe_reports_the_three_modes():
+    got = obs.measure_span_overhead(iters=500)
+    assert set(got) == {"off", "profile", "ring"}
+    # a span nobody records must stay far under a dispatch (µs, not ms)
+    assert 0 < got["off"] < 100e-6 and got["ring"] < 200e-6
+    assert not obs.GLOBAL_EVENTS.enabled and not obs.GLOBAL_EVENTS.events()
+
+
+def test_every_pallas_call_has_a_name():
+    """An AST walk over paddle_tpu/: every pl.pallas_call passes a
+    literal, distinct name= (the name a device trace and the compiled
+    text's op_name show)."""
+    import ast
+    import os
+
+    root = os.path.dirname(os.path.abspath(fluid.__file__))
+    names, unnamed = [], []
+    for dirpath, _, files in os.walk(root):
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, fn)
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "pallas_call"):
+                    kw = {k.arg: k.value for k in node.keywords}
+                    if isinstance(kw.get("name"), ast.Constant):
+                        names.append(kw["name"].value)
+                    else:
+                        unnamed.append(f"{path}:{node.lineno}")
+    assert not unnamed
+    assert len(names) >= 15 and len(set(names)) == len(names)
+    assert {"flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv", "ragged_paged_attention",
+            "ragged_paged_attention_blocked",
+            "ragged_paged_attention_chunk"} <= set(names)
+
+
+def test_the_program_writes_spans_through_span_only():
+    """No TraceAnnotation and no direct ring write outside
+    paddle_tpu/observability, except flags trace_ops's per-op wrap."""
+    import os
+    import re
+
+    root = os.path.dirname(os.path.abspath(fluid.__file__))
+    rx = re.compile(r"TraceAnnotation\(|_EVENTS\.(complete|span|instant)\("
+                    r"|GLOBAL_EVENTS\.(complete|instant)\(")
+    hits = []
+    for dirpath, _, files in os.walk(root):
+        if os.path.basename(dirpath) == "observability":
+            continue
+        for fn in files:
+            if fn.endswith(".py"):
+                path = os.path.join(dirpath, fn)
+                with open(path) as f:
+                    for i, line in enumerate(f, 1):
+                        if rx.search(line):
+                            hits.append((os.path.relpath(path, root), i))
+    assert [h[0] for h in hits] == ["executor.py"], hits   # trace_ops
+
+
+# ---------------------------------------------------------------------------
+# /generate: one rid from the handler down to the decode tick; GET /trace
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def toy_gen_server():
+    from paddle_tpu.decode.engine import GenerationEngine
+    from paddle_tpu.decode.model import TinyDecoderLM
+    from paddle_tpu.serving import InferenceServer
+
+    lm = TinyDecoderLM(vocab=32, d_model=16, num_heads=2, num_layers=2,
+                       num_pages=32, page_size=4, pages_per_seq=8, seed=0)
+    srv = InferenceServer(None, generator=GenerationEngine(
+        lm, max_slots=2, max_new_tokens=8))
+    try:
+        _generate(srv, [1, 5, 9], 3)        # compiles outside the tests
+        yield srv
+    finally:
+        srv.stop()
+
+
+def _generate(srv, src, n):
+    import urllib.request
+
+    req = urllib.request.Request(
+        f"http://{srv.address}/generate",
+        data=json.dumps({"src": src, "max_new_tokens": n}).encode())
+    with urllib.request.urlopen(req, timeout=300) as r:
+        lines = r.read().decode().strip().splitlines()
+    return json.loads(lines[-1])["ids"]
+
+
+def _registry_totals(*names):
+    snap = obs.snapshot()
+    out = {}
+    for n in names:
+        vals = snap.get(n, {}).get("values", [])
+        out[n] = sum(v.get("count", v.get("value", 0)) for v in vals)
+    return out
+
+
+def test_generate_spans_share_one_rid_and_counters_move(toy_gen_server):
+    counters = ("decode_queue_wait_seconds",
+                "decode_active_slot_steps_total",
+                "serving_generate_first_write_lag_seconds",
+                "decode_steps_total")
+    before = _registry_totals(*counters)
+    with obs.recording() as ring:
+        ids = _generate(toy_gen_server, [1, 5, 9], 4)
+        # the handler's span closes after the reply is on the wire
+        for _ in range(200):
+            ev = _by_name(ring.events())
+            if "serving.generate" in ev:
+                break
+            time.sleep(0.01)
+    assert len(ids) == 4
+    (gen,) = ev["serving.generate"]
+    rid = gen["args"]["rid"]
+    assert gen["args"]["tenant"] == "default" and gen["args"]["parent"] == 0
+    for name in ("serving.parse", "serving.submit", "serving.first_write"):
+        (e,) = ev[name]
+        assert e["args"]["rid"] == rid
+        assert e["args"]["parent"] == gen["args"]["id"], name
+    # the stepper thread: admit (with prefill and first token) inside a
+    # tick, all carrying the handler's rid
+    (admit,) = ev["decode.admit"]
+    assert admit["args"]["rid"] == rid and admit["tid"] != gen["tid"]
+    assert admit["args"]["prompt_len"] == 3 and admit["args"]["pages"] == 2
+    assert admit["args"]["cached_len"] == 0
+    for name in ("decode.prefill", "decode.first_token"):
+        (e,) = ev[name]
+        assert e["args"]["rid"] == rid
+        assert e["args"]["parent"] == admit["args"]["id"]
+    ticks = {e["args"]["id"]: e for e in ev["decode.tick"]}
+    assert admit["args"]["parent"] in ticks
+    # the ticks after the admitting one name the request among their
+    # slots' rids, and each holds one decode step in its three parts
+    later = [t for t in ticks.values() if t["ts"] > admit["ts"]]
+    assert later and all(str(rid) in t["args"]["rids"].split(",")
+                         for t in later)
+    steps = {e["args"]["id"]: e for e in ev["decode.step"]}
+    assert all(s["args"]["parent"] in ticks for s in steps.values())
+    for name in ("decode.upload", "decode.dispatch",
+                 "decode.logits_to_host"):
+        assert len(ev[name]) == len(steps)
+        assert all(e["args"]["parent"] in steps for e in ev[name])
+    assert len(ev["decode.sample"]) == len(steps) == 3   # 4 tokens, 1 prefill
+    after = _registry_totals(*counters)
+    assert after["decode_queue_wait_seconds"] == \
+        before["decode_queue_wait_seconds"] + 1
+    assert after["serving_generate_first_write_lag_seconds"] == \
+        before["serving_generate_first_write_lag_seconds"] + 1
+    # one live slot in each of the three steps
+    assert after["decode_steps_total"] == before["decode_steps_total"] + 3
+    assert after["decode_active_slot_steps_total"] == \
+        before["decode_active_slot_steps_total"] + 3
+
+
+def test_generate_rid_reaches_the_profile_as_a_stat(toy_gen_server, tmp_path):
+    """The same spans as TraceAnnotations in a jax profile: the rid is
+    the event's stat, on handler and stepper thread alike."""
+    prof = _capture_profile(
+        tmp_path, lambda: (_generate(toy_gen_server, [1, 7], 3),
+                           time.sleep(0.05)))
+    (_, _, gen), = prof["serving.generate"]
+    for name in ("serving.submit", "decode.admit", "decode.prefill",
+                 "decode.first_token", "serving.first_write"):
+        (_, _, stats), = prof[name]
+        assert stats["rid"] == gen["rid"], name
+    assert prof["decode.tick"] and prof["decode.logits_to_host"]
+
+
+def test_idle_stepper_writes_idle_wait_spans(toy_gen_server):
+    with obs.recording() as ring:
+        time.sleep(0.2)
+        names = {e["name"] for e in ring.events()}
+    assert names == {"decode.idle_wait"}
+
+
+def test_trace_endpoint_records_for_n_seconds(toy_gen_server, tmp_path,
+                                              capsys):
+    """GET /trace?seconds=N turns the ring on for N seconds and replies
+    with its Chrome trace; `paddle stats --url --trace` writes it."""
+    import urllib.error
+    import urllib.request
+
+    from paddle_tpu.cli import cmd_stats
+
+    base = f"http://{toy_gen_server.address}"
+    got = {}
+
+    def fetch():
+        with urllib.request.urlopen(f"{base}/trace?seconds=1.5",
+                                    timeout=60) as r:
+            got["doc"] = json.loads(r.read())
+
+    t = threading.Thread(target=fetch)
+    t.start()
+    for _ in range(200):                    # until the recording is on
+        if obs.GLOBAL_EVENTS.enabled:
+            break
+        time.sleep(0.01)
+    # a second recording at the same time is refused, not interleaved
+    with pytest.raises(urllib.error.HTTPError) as e409:
+        urllib.request.urlopen(f"{base}/trace?seconds=0", timeout=30)
+    assert e409.value.code == 409
+    _generate(toy_gen_server, [1, 5, 9], 3)
+    t.join(timeout=60)
+    assert not t.is_alive() and not obs.GLOBAL_EVENTS.enabled
+    names = {e["name"] for e in got["doc"]["traceEvents"]}
+    assert {"serving.generate", "decode.admit", "decode.tick"} <= names
+    assert "epoch_perf_counter_sec" in got["doc"]["otherData"]
+
+    for bad in ("seconds=-1", "seconds=1e9", "seconds=soon"):
+        with pytest.raises(urllib.error.HTTPError) as e400:
+            urllib.request.urlopen(f"{base}/trace?{bad}", timeout=30)
+        assert e400.value.code == 400
+
+    out = tmp_path / "server_trace.json"
+    assert cmd_stats([f"--url={base}", f"--trace={out}",
+                      "--seconds=0.2"]) == 0
+    capsys.readouterr()
+    with open(out) as f:
+        assert "traceEvents" in json.load(f)
