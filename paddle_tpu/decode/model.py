@@ -2,13 +2,20 @@
 
 The self-attention consumer of the ragged paged-attention kernel (the
 seq2seq adapter pages a *static* cross-attention context; this model
-exercises the growing-KV case): prefill runs the dense causal forward
-(``dense_prefill_attention`` — the flash-attention path when the shape
-fits) and pages the prompt's K/V once; every decode step appends one
-K/V row per sequence into its pages and attends over its page table.
-The decode step is ONE jitted fixed-shape function of
-``(pools, page_tables, lens, tokens)`` — batch composition churn never
-re-traces.
+exercises the growing-KV case): prefill is ONE jitted program per
+length *bucket* (the shared pow2 ladder of ``pallas/tuning/bucket.py``,
+from 64 up to the sequence capacity).  The prompt is padded on the
+right to its bucket, the program runs the dense causal forward
+(``dense_prefill_attention`` — the flash-attention path when the
+bucket's shape fits), scatters the K/V rows into the donated pools in
+place and returns the logits of the last real token alone.  Causal
+attention hides the padding from every real row; the padding's own K/V
+rows land past the prompt's length inside the sequence's pages (never
+read: ``lens`` masks them, decode overwrites them) or, past its pages,
+in the reserved null page 0.  Every decode step appends one K/V row
+per sequence into its pages and attends over its page table.  The
+decode step is ONE jitted fixed-shape function of ``(pools,
+page_tables, lens, tokens)`` — batch composition churn never re-traces.
 
 Weights are randomly initialized from a seed: this model exists to
 prove the kernel + session mechanics (tests pin the paged decode
@@ -32,7 +39,21 @@ from paddle_tpu.decode.attention import (
     paged_chunk_attention,
 )
 from paddle_tpu.decode.paged_kv import PageAllocator
+from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.observability.events import span
+from paddle_tpu.pallas.tuning.bucket import bucket_dim
+
+_M_PREFILL_TOKENS = _metrics.counter(
+    "decode_prefill_tokens_total",
+    "prompt tokens of the bucketed (full-prompt) prefills")
+_M_PREFILL_PADDED = _metrics.counter(
+    "decode_prefill_padded_tokens_total",
+    "rows the bucketed prefills computed: each prompt padded to its "
+    "bucket; over decode_prefill_tokens_total it is what buckets cost")
+_M_PREFILL_PROGRAMS = _metrics.counter(
+    "decode_prefill_programs_total",
+    "prefill programs traced, by bucket (one per bucket and model "
+    "shape; a rise under traffic is a compile in the serving path)")
 
 _F32 = jnp.float32
 
@@ -67,6 +88,32 @@ def _ln(x, scale):
     return (x - m) * jax.lax.rsqrt(v + 1e-5) * scale
 
 
+def _dense_blocks(params, tokens, heads):
+    """The dense causal forward over (T,) tokens up to the head: the
+    last block's output (T, d) and per-layer K/V rows (L, T, heads,
+    dh).  Pure: the eager oracle and the jitted prefill both run it."""
+    T = tokens.shape[0]
+    x = params["emb"][tokens] + params["pos"][:T]
+    dh = x.shape[1] // heads
+    ks, vs = [], []
+    for lp in params["layers"]:
+        h = _ln(x, lp["ln1"])
+        q = (h @ lp["wq"]).reshape(T, heads, dh)
+        k = (h @ lp["wk"]).reshape(T, heads, dh)
+        v = (h @ lp["wv"]).reshape(T, heads, dh)
+        ks.append(k)
+        vs.append(v)
+        a = dense_prefill_attention(q, k, v, causal=True)
+        x = x + a.reshape(T, heads * dh) @ lp["wo"]
+        h2 = _ln(x, lp["ln2"])
+        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+    return x, jnp.stack(ks), jnp.stack(vs)
+
+
+def _head(params, x):
+    return _ln(x, params["ln_f"]) @ params["emb"].T
+
+
 class TinyDecoderLM:
     grows_kv = True
     supports_prefix_cache = True      # prefill accepts cached_len
@@ -98,23 +145,8 @@ class TinyDecoderLM:
     def _forward(self, tokens: jnp.ndarray):
         """Full dense causal forward over (T,) tokens -> (logits (T, V),
         per-layer K/V rows (L, T, heads, dh))."""
-        p = self.params
-        T = tokens.shape[0]
-        x = p["emb"][tokens] + p["pos"][:T]
-        ks, vs = [], []
-        for lp in p["layers"]:
-            h = _ln(x, lp["ln1"])
-            q = (h @ lp["wq"]).reshape(T, self.heads, self.dh)
-            k = (h @ lp["wk"]).reshape(T, self.heads, self.dh)
-            v = (h @ lp["wv"]).reshape(T, self.heads, self.dh)
-            ks.append(k)
-            vs.append(v)
-            a = dense_prefill_attention(q, k, v, causal=True)
-            x = x + a.reshape(T, self.d) @ lp["wo"]
-            h2 = _ln(x, lp["ln2"])
-            x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-        logits = _ln(x, p["ln_f"]) @ p["emb"].T
-        return logits, jnp.stack(ks), jnp.stack(vs)
+        x, ks, vs = _dense_blocks(self.params, tokens, self.heads)
+        return _head(self.params, x), ks, vs
 
     def dense_greedy(self, prompt: Sequence[int],
                      max_new_tokens: int) -> List[int]:
@@ -141,16 +173,36 @@ class TinyDecoderLM:
         t[:len(pages)] = np.asarray(pages, np.int32)
         return t
 
+    def prefill_bucket(self, n: int) -> int:
+        """Rows the prefill program of an ``n``-token prompt computes:
+        the next power of two from 64 (or the page size) up, capped at
+        what one sequence can hold — a model smaller than 64 rows has
+        that capacity as its one bucket."""
+        cap = min(self.max_len, self.pages_per_seq * self.page_size)
+        if not 0 < n <= cap:
+            raise ValueError(
+                f"a prompt of {n} tokens is outside 1..{cap}, the rows "
+                "one sequence of this model can hold")
+        return min(max(bucket_dim(n), 64, self.page_size), cap)
+
     def prefill(self, prompt: Sequence[int], pages: Sequence[int],
                 cached_len: int = 0):
         """Page the prompt's K/V and return (ctx_len, states, last
-        logits).  With ``cached_len`` > 0 (a prefix-cache hit) the first
+        logits).  The prompt is padded on the right to its bucket and
+        runs as that bucket's one jitted program, whatever its page
+        count: the K/V of bucket rows past ``pages`` go to the null
+        page 0, those past the prompt inside ``pages`` are padding
+        that ``lens`` hides and decode overwrites.  The pools are
+        donated to the program, and the call returns once the logits
+        row is on the host.
+
+        With ``cached_len`` > 0 (a prefix-cache hit) the first
         ``cached_len`` rows already live in ``pages`` — only the suffix
         is computed, attending over the cached pages through the chunked
         paged kernel, and only the suffix's K/V rows are written."""
-        toks = jnp.asarray(list(prompt), jnp.int32)
-        T = toks.shape[0]
+        T = len(prompt)
         if cached_len:
+            toks = jnp.asarray(list(prompt), jnp.int32)
             if not (0 < cached_len < T and cached_len % self.page_size == 0):
                 raise ValueError(
                     f"cached_len {cached_len} must be a positive multiple "
@@ -161,18 +213,22 @@ class TinyDecoderLM:
                 jnp.asarray(table), np.int32(cached_len),
                 toks[cached_len:], heads=self.heads,
                 page_size=self.page_size)
-            return int(T), [], logits[-1]
-        logits, ks, vs = self._forward(toks)
-        cap = len(pages) * self.page_size
-        pad = cap - T
-        idx = jnp.asarray(np.asarray(pages, np.int32))
-        kr = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
-            self.layers, len(pages), self.page_size, self.heads, self.dh)
-        vr = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
-            self.layers, len(pages), self.page_size, self.heads, self.dh)
-        self.k_pool = self.k_pool.at[:, idx].set(kr)
-        self.v_pool = self.v_pool.at[:, idx].set(vr)
-        return int(T), [], logits[-1]
+            return T, [], logits[-1]
+        bucket = self.prefill_bucket(T)
+        toks = np.zeros((bucket,), np.int32)
+        toks[:T] = prompt
+        # the flat pool row of each bucket row: the table is null past
+        # the sequence's pages, so those rows scribble on page 0, as
+        # inactive decode slots do
+        rows = np.arange(bucket)
+        flat = (self.pool_table(pages)[rows // self.page_size]
+                * self.page_size + rows % self.page_size).astype(np.int32)
+        logits, self.k_pool, self.v_pool = _prefill_bucket(
+            self.params, self.k_pool, self.v_pool, toks, flat, np.int32(T),
+            heads=self.heads)
+        _M_PREFILL_TOKENS.inc(T)
+        _M_PREFILL_PADDED.inc(bucket)
+        return T, [], np.asarray(logits)
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page across both pools (the CoW split)."""
@@ -210,6 +266,25 @@ class TinyDecoderLM:
             return np.asarray(logits), []
 
 
+@functools.partial(jax.jit, static_argnames=("heads",),
+                   donate_argnums=(1, 2))
+def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads):
+    """The whole prefill of one prompt padded to ``tokens.shape[0]``
+    rows: the dense forward, K/V row ``i`` of every layer scattered to
+    pool row ``flat[i]`` of the donated pools, and the logits of row
+    ``n - 1`` (the 50k-wide head runs on that row alone).  Its shape
+    depends on the bucket only, not on the prompt's length or pages."""
+    _M_PREFILL_PROGRAMS.inc(bucket=str(tokens.shape[0]))   # at trace
+    x, ks, vs = _dense_blocks(params, tokens, heads)
+    L, N, pg, H, dh = k_pool.shape
+    k_pool = (k_pool.reshape(L, N * pg, H, dh).at[:, flat].set(ks)
+              .reshape(L, N, pg, H, dh))
+    v_pool = (v_pool.reshape(L, N * pg, H, dh).at[:, flat].set(vs)
+              .reshape(L, N, pg, H, dh))
+    last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1)
+    return _head(params, last)[0], k_pool, v_pool
+
+
 @jax.jit
 def _copy_pools_page(k_pool, v_pool, src, dst):
     return (k_pool.at[:, dst].set(k_pool[:, src]),
@@ -222,7 +297,7 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
     """Suffix prefill over cached pages: the suffix's Ts tokens are one
     chunk at positions cached_len..cached_len+Ts-1; attention sees the
     cached prefix rows plus the causal part of the suffix itself.
-    Retraces per suffix length, like the dense prefill."""
+    Retraces per suffix length (the full-prompt prefill does not)."""
     Ts = tokens.shape[0]
     L, N, pg, H, dh = k_pool.shape
     d = H * dh

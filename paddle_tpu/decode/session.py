@@ -274,6 +274,8 @@ class DecodeSession:
       enables speculative decoding
     - ``emits_probs``: decode returns distributions, not raw logits
       (affects sampling/beam log-prob handling)
+    - ``prefill_bucket(prompt_len) -> int``: rows the full-prompt
+      prefill pads to; it and the pad go on the ``decode.prefill`` span
     """
 
     def __init__(self, model, max_slots: int = 8,
@@ -815,7 +817,12 @@ class DecodeSession:
         pages = cached_pages + alloc.alloc(fresh_need)
         admit_span.set(cached_len=cached_len, pages=len(pages))
         try:
-            with span("decode.prefill", rid=req.rid):
+            args = {}
+            if not cached_len and hasattr(self.model, "prefill_bucket"):
+                n = _prompt_len(req.prompt)
+                bucket = self.model.prefill_bucket(n)
+                args = {"bucket": bucket, "pad": bucket - n}
+            with span("decode.prefill", rid=req.rid, **args):
                 if cached_len:
                     ctx_len, state_rows, first_logits = self.model.prefill(
                         req.prompt, pages, cached_len=cached_len)

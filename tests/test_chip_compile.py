@@ -123,6 +123,49 @@ def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
                for op in ops)
 
 
+def test_prefill_top_bucket_fits_and_aliases_its_pools(one_chip, monkeypatch):
+    """The 1,280-row prefill bucket of the ``cerebras-gpt-1.3b``
+    generate configuration (24 layers f32, 320 pages of 32 rows): the
+    two facts a CPU run cannot see.  The plan fits the chip's 16 GB,
+    and both donated pools are aliased input to output, so an
+    admission writes its rows in place and copies no pool."""
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import model as dm
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    bucket, d, H, L, N, pg = 1280, 2048, 16, 24, 320, 32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: dm._init_params(
+            jax.random.key(0), 50257, d, H, L, 2048)))
+    pool = sds((L, N, pg, H, d // H), jnp.float32)
+    compiled = dm._prefill_bucket.lower(
+        params, pool, pool, sds((bucket,), jnp.int32),
+        sds((bucket,), jnp.int32), sds((), jnp.int32), heads=H).compile()
+    m = compiled.memory_analysis()
+    pool_bytes = L * N * pg * d * 4
+    assert m.alias_size_in_bytes >= 2 * pool_bytes
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert planned < 16e9, planned
+    text = compiled.as_text()
+    header = text[:text.index("\n")]
+    n_leaves = len(jax.tree.leaves(params))
+    for out, arg in ((1, n_leaves), (2, n_leaves + 1)):
+        assert f"{{{out}}}: ({arg}, {{}}, may-alias)" in header, header[:300]
+    # one flash-attention kernel a layer at this length, under the
+    # prefill program's name
+    ops = _kernel_op_names(text)
+    assert len(ops) == L
+    assert all("_prefill_bucket" in op and "flash_attention_fwd" in op
+               for op in ops)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles(one_chip, grad):
     from paddle_tpu.pallas.flash_attention import flash_attention

@@ -305,6 +305,159 @@ def test_tiny_lm_paged_decode_matches_dense_oracle():
 
 
 # ---------------------------------------------------------------------------
+# bucketed jitted prefill (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+
+def _bucket_lm(**kw):
+    """A toy LM whose prefill ladder is 64, 128, 192 (the capacity)."""
+    from paddle_tpu.decode.model import TinyDecoderLM
+
+    cfg = dict(vocab=32, d_model=16, num_heads=2, num_layers=2,
+               max_len=256, num_pages=64, page_size=8, pages_per_seq=24,
+               seed=0)
+    cfg.update(kw)
+    return TinyDecoderLM(**cfg)
+
+
+def _prompt(T, vocab=32):
+    return np.random.RandomState(T).randint(1, vocab, T).tolist()
+
+
+def _pool_rows(pool, pages):
+    """(L, len(pages) * page_size, H, dh): a sequence's rows in order."""
+    a = np.asarray(pool)[:, list(pages)]
+    return a.reshape(a.shape[0], -1, *a.shape[3:])
+
+
+@pytest.mark.parametrize("T,bucket", [(63, 64), (64, 64), (65, 128),
+                                      (129, 192)])
+def test_bucketed_prefill_matches_eager_forward(T, bucket):
+    """Below, at and just above a bucket edge, and in the capped top
+    bucket: the last real row's logits and the K/V rows < T are the
+    eager dense forward's."""
+    import jax.numpy as jnp
+
+    lm = _bucket_lm()
+    assert lm.prefill_bucket(T) == bucket
+    prompt = _prompt(T)
+    pages = lm.allocator.alloc(lm.context_pages(prompt, 1))
+    n, states, logits = lm.prefill(prompt, pages)
+    want, ks, vs = lm._forward(jnp.asarray(prompt, jnp.int32))
+    assert (n, states) == (T, [])
+    assert logits.shape == (lm.vocab,)
+    np.testing.assert_allclose(logits, np.asarray(want[-1]), atol=1e-5)
+    np.testing.assert_allclose(_pool_rows(lm.k_pool, pages)[:, :T],
+                               np.asarray(ks), atol=1e-5)
+    np.testing.assert_allclose(_pool_rows(lm.v_pool, pages)[:, :T],
+                               np.asarray(vs), atol=1e-5)
+
+
+def test_padded_prefill_leaves_other_sequences_pages_untouched():
+    """65 tokens on 9 pages (72 rows) run as the 128-row bucket: the 56
+    rows past the sequence's pages go to the null page 0, so a live
+    neighbour's pages, and every free page, are bit-identical."""
+    lm = _bucket_lm()
+    a_pages = lm.allocator.alloc(5)
+    lm.prefill(_prompt(40), a_pages)
+    b_prompt = _prompt(65)
+    b_pages = lm.allocator.alloc(lm.context_pages(b_prompt, 1))
+    assert len(b_pages) * lm.page_size < lm.prefill_bucket(65)
+    others = [p for p in range(1, lm.allocator.num_pages)
+              if p not in b_pages]
+    before = [np.asarray(pool)[:, others].copy()
+              for pool in (lm.k_pool, lm.v_pool)]
+    assert np.abs(before[0][:, :len(a_pages)]).max() > 0
+    lm.prefill(b_prompt, b_pages)
+    for was, pool in zip(before, (lm.k_pool, lm.v_pool)):
+        np.testing.assert_array_equal(np.asarray(pool)[:, others], was)
+    assert np.abs(_pool_rows(lm.k_pool, b_pages)[:, :65]).min() > 0
+
+
+def test_one_prefill_program_per_bucket_and_session_parity():
+    """Prompts of different lengths AND page counts inside one bucket
+    run one traced program; a session over them (and a second bucket)
+    still equals the dense oracle token for token."""
+    from paddle_tpu.decode import model as dm
+
+    lm = _bucket_lm(vocab=37, seed=3)       # shapes no other test traces
+    count = dm._M_PREFILL_PROGRAMS.value
+    n64, n128 = count(bucket="64"), count(bucket="128")
+    for T, budget in ((9, 4), (40, 30), (64, 1)):
+        prompt = _prompt(T, 37)
+        pages = lm.allocator.alloc(lm.context_pages(prompt, budget))
+        lm.prefill(prompt, pages)
+        lm.allocator.free(pages)
+    assert count(bucket="64") == n64 + 1
+    assert count(bucket="128") == n128
+    prompts = [_prompt(T, 37) for T in (5, 33, 64, 70)]
+    want = [lm.dense_greedy(p, 6) for p in prompts]
+    sess = DecodeSession(lm, max_slots=2)
+    reqs = [sess.submit(DecodeRequest(p, max_new_tokens=6))
+            for p in prompts]
+    sess.run(max_steps=400)
+    assert [r.result(0) for r in reqs] == want
+    assert count(bucket="64") == n64 + 1
+    assert count(bucket="128") == n128 + 1
+    assert lm.allocator.pages_in_use == 0
+
+
+def test_prompt_over_the_top_bucket_is_refused_at_submit():
+    """The top bucket is the sequence capacity, so a prompt past it is
+    ``too_long`` at submit; where ``max_len`` is the smaller cap the
+    model refuses on the host, before anything is traced."""
+    from paddle_tpu.decode import model as dm
+
+    def traced():
+        return sum(v["value"]
+                   for v in dm._M_PREFILL_PROGRAMS.snapshot()["values"])
+
+    lm = _bucket_lm()
+    before = traced()
+    sess = DecodeSession(lm, max_slots=2)
+    with pytest.raises(AdmissionRefused) as ei:
+        sess.submit(DecodeRequest(_prompt(193), max_new_tokens=1))
+    assert ei.value.reason == "too_long"
+    short = _bucket_lm(max_len=100)          # 100 positions < 192 rows
+    assert short.prefill_bucket(100) == 100
+    pages = short.allocator.alloc(13)
+    with pytest.raises(ValueError, match="101 tokens"):
+        short.prefill(_prompt(101), pages)
+    short.allocator.free(pages)
+    # through a session it fits the pages, so it is seated, fails its
+    # prefill on the host and gives its pages back
+    sess = DecodeSession(short, max_slots=1)
+    req = sess.submit(DecodeRequest(_prompt(101), max_new_tokens=1))
+    sess.run(max_steps=4)
+    assert req.finish_reason == "error"
+    assert isinstance(req.error, ValueError)
+    assert short.allocator.pages_in_use == 0
+    assert traced() == before
+
+
+def test_prefill_counters_and_span_args():
+    """A 70-token prompt is 70 real and 128 computed rows, and the
+    ``decode.prefill`` span says so."""
+    from paddle_tpu import observability
+    from paddle_tpu.decode import model as dm
+
+    lm = _bucket_lm()
+    real, padded = dm._M_PREFILL_TOKENS.value, dm._M_PREFILL_PADDED.value
+    r0, p0 = real(), padded()
+    sess = DecodeSession(lm, max_slots=1)
+    req = sess.submit(DecodeRequest(_prompt(70), max_new_tokens=2))
+    with observability.recording() as ring:
+        sess.run(max_steps=8)
+        spans = [e for e in ring.events() if e["name"] == "decode.prefill"]
+    assert len(req.result(0)) == 2
+    assert (real() - r0, padded() - p0) == (70, 128)
+    assert len(spans) == 1
+    assert spans[0]["args"]["bucket"] == 128
+    assert spans[0]["args"]["pad"] == 58
+    assert spans[0]["args"]["rid"] == req.rid
+
+
+# ---------------------------------------------------------------------------
 # serving endpoint
 # ---------------------------------------------------------------------------
 
