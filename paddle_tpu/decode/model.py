@@ -1,30 +1,39 @@
-"""TinyDecoderLM: a pure-JAX decoder-only transformer over paged KV.
+"""Decoder-only LMs over paged KV: the skeleton, and the GPT-2 block.
 
-The self-attention consumer of the ragged paged-attention kernel (the
-seq2seq adapter pages a *static* cross-attention context; this model
-exercises the growing-KV case): prefill is ONE jitted program per
-length *bucket* (the shared pow2 ladder of ``pallas/tuning/bucket.py``,
-from 64 up to the sequence capacity).  The prompt is padded on the
-right to its bucket, the program runs the dense causal forward
-(``dense_prefill_attention`` — the flash-attention path when the
-bucket's shape fits), scatters the K/V rows into the donated pools in
-place and returns the logits of the last real token alone.  Causal
-attention hides the padding from every real row; the padding's own K/V
-rows land past the prompt's length inside the sequence's pages (never
-read: ``lens`` masks them, decode overwrites them) or, past its pages,
-in the reserved null page 0.  Every decode step appends one K/V row
-per sequence into its pages and attends over its page table.  The
-decode step is ONE jitted fixed-shape function of ``(pools,
-page_tables, lens, tokens)`` — batch composition churn never re-traces.
+``PagedDecoderLM`` is the self-attention consumer of the ragged
+paged-attention kernel (the seq2seq adapter pages a *static*
+cross-attention context; this exercises the growing-KV case), written
+ONCE over a *block*: pure functions of (parameters, rows, positions)
+for the embedding, the pre-attention norm and q/k/v, the
+post-attention projection, the feed-forward and the head.  The GPT-2
+block (``Gpt2Block``, served as ``TinyDecoderLM``) is defined here;
+OLMoE's lives in ``paddle_tpu/models/olmoe.py``.  The jitted programs
+take the block as a static argument.
 
-Weights are randomly initialized from a seed: this model exists to
+Prefill is ONE jitted program per length *bucket* (the shared pow2
+ladder of ``pallas/tuning/bucket.py``, from 64 up to the sequence
+capacity).  The prompt is padded on the right to its bucket, the
+program runs the dense causal forward (``dense_prefill_attention`` —
+the flash-attention path when the bucket's shape fits), scatters the
+K/V rows into the donated pools in place and returns the logits of the
+last real token alone.  Causal attention hides the padding from every
+real row; the padding's own K/V rows land past the prompt's length
+inside the sequence's pages (never read: ``lens`` masks them, decode
+overwrites them) or, past its pages, in the reserved null page 0.
+Every decode step appends one K/V row per sequence into its pages and
+attends over its page table.  The decode step is ONE jitted
+fixed-shape function of ``(pools, page_tables, lens, tokens)`` — batch
+composition churn never re-traces.
+
+Weights are randomly initialized from a seed: these models exist to
 prove the kernel + session mechanics (tests pin the paged decode
-against a dense incremental oracle) and to feed the decode benchmark,
-not to be a trained LM.
+against a dense incremental oracle and a float32 reference) and to
+feed the benchmark, not to be trained LMs.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import List, Sequence, Tuple
 
@@ -88,43 +97,91 @@ def _ln(x, scale):
     return (x - m) * jax.lax.rsqrt(v + 1e-5) * scale
 
 
-def _dense_blocks(params, tokens, heads):
-    """The dense causal forward over (T,) tokens up to the head: the
-    last block's output (T, d) and per-layer K/V rows (L, T, heads,
-    dh).  Pure: the eager oracle and the jitted prefill both run it."""
-    T = tokens.shape[0]
-    x = params["emb"][tokens] + params["pos"][:T]
-    dh = x.shape[1] // heads
-    ks, vs = [], []
-    for lp in params["layers"]:
+@dataclasses.dataclass(frozen=True)
+class Gpt2Block:
+    """The GPT-2 block as the paged skeleton below takes a block: pure
+    functions of (parameters, rows, positions), hashable so that the
+    jitted programs take it as a static argument.  ``rows`` have any
+    leading shape ((T,), (S,) or (S, T)); ``pos`` their absolute
+    positions, same shape (``embed`` also takes the slice ``0:T`` from
+    the dense forward, so that a position table is sliced, not
+    gathered).
+
+    Learned positions added to the embedding, pre-LayerNorm, no bias
+    anywhere, tanh-GELU, the head tied to the embedding, float32."""
+
+    def embed(self, params, tokens, pos):
+        return params["emb"][tokens] + params["pos"][pos]
+
+    def qkv(self, lp, x, pos, heads):
+        """Pre-attention norm and the three projections, each split to
+        (..., heads, dh); ``k`` and ``v`` are the rows the page gets."""
         h = _ln(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(T, heads, dh)
-        k = (h @ lp["wk"]).reshape(T, heads, dh)
-        v = (h @ lp["wv"]).reshape(T, heads, dh)
+        split = x.shape[:-1] + (heads, x.shape[-1] // heads)
+        return ((h @ lp["wq"]).reshape(split), (h @ lp["wk"]).reshape(split),
+                (h @ lp["wv"]).reshape(split))
+
+    def attn_out(self, lp, x, a):
+        """``a`` (..., d): the heads' outputs side by side."""
+        return x + a @ lp["wo"]
+
+    def mlp(self, lp, x, live):
+        """-> (rows after the feed-forward, what the layer reports of
+        this call or None).  ``live`` (rows' leading shape, bool; None:
+        all) marks the rows that are neither padding nor an inactive
+        slot's."""
+        h2 = _ln(x, lp["ln2"])
+        return x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"], None
+
+    def head(self, params, x):
+        return _ln(x, params["ln_f"]) @ params["emb"].T
+
+
+GPT2 = Gpt2Block()
+
+
+def _stack_reports(reports):
+    """Per-layer reports of ``block.mlp`` as one array (L, ...), or
+    None for a block that reports nothing."""
+    return None if reports[0] is None else jnp.stack(reports)
+
+
+def _dense_blocks(block, params, tokens, heads, live):
+    """The dense causal forward over (T,) tokens up to the head: the
+    last block's output (T, d), per-layer K/V rows (L, T, heads, dh)
+    and the layers' reports.  Pure: the eager oracle and the jitted
+    prefill both run it."""
+    T = tokens.shape[0]
+    pos = jnp.arange(T, dtype=jnp.int32)
+    x = block.embed(params, tokens, slice(0, T))
+    ks, vs, reports = [], [], []
+    for lp in params["layers"]:
+        q, k, v = block.qkv(lp, x, pos, heads)
         ks.append(k)
         vs.append(v)
         a = dense_prefill_attention(q, k, v, causal=True)
-        x = x + a.reshape(T, heads * dh) @ lp["wo"]
-        h2 = _ln(x, lp["ln2"])
-        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    return x, jnp.stack(ks), jnp.stack(vs)
+        x = block.attn_out(lp, x, a.reshape(T, -1))
+        x, report = block.mlp(lp, x, live)
+        reports.append(report)
+    return x, jnp.stack(ks), jnp.stack(vs), _stack_reports(reports)
 
 
-def _head(params, x):
-    return _ln(x, params["ln_f"]) @ params["emb"].T
+class PagedDecoderLM:
+    """The paged skeleton of a decoder-only LM behind ``/generate``:
+    page allocator and tables, the bucketed prefill, suffix prefill,
+    verify and decode steps over K/V pools, written once over a block
+    definition (``Gpt2Block`` here, ``models/olmoe.py``'s).  A subclass
+    sets ``block`` and ``params`` (``layers``: a list of one dict a
+    layer), then calls ``_make_pools``."""
 
-
-class TinyDecoderLM:
     grows_kv = True
     supports_prefix_cache = True      # prefill accepts cached_len
     emits_probs = False               # decode returns raw logits
     state_specs: List[Tuple[tuple, type]] = []   # position == KV length
+    block = GPT2
 
-    def __init__(self, vocab: int = 64, d_model: int = 32,
-                 num_heads: int = 4, num_layers: int = 2,
-                 max_len: int = 64, num_pages: int = 32,
-                 page_size: int = 8, pages_per_seq: int = 8,
-                 bos_id: int = 1, eos_id: int = 0, seed: int = 0):
+    def __init__(self, vocab, d_model, num_heads, num_layers, max_len,
+                 page_size, pages_per_seq, bos_id, eos_id):
         self.vocab, self.d = int(vocab), int(d_model)
         self.heads = int(num_heads)
         self.dh = self.d // self.heads
@@ -133,20 +190,25 @@ class TinyDecoderLM:
         self.page_size = int(page_size)
         self.pages_per_seq = int(pages_per_seq)
         self.bos_id, self.eos_id = int(bos_id), int(eos_id)
+
+    def _make_pools(self, num_pages: int, dtype) -> None:
         self.allocator = PageAllocator(num_pages)
-        self.params = _init_params(jax.random.key(seed), vocab, self.d,
-                                   self.heads, self.layers, self.max_len)
         shape = (self.layers, num_pages, self.page_size, self.heads, self.dh)
-        self.k_pool = jnp.zeros(shape, _F32)
-        self.v_pool = jnp.zeros(shape, _F32)
+        self.k_pool = jnp.zeros(shape, dtype)
+        self.v_pool = jnp.zeros(shape, dtype)
+
+    def _observe(self, phase: str, report) -> None:
+        """What the layers reported of one prefill or step (None for a
+        block that reports nothing), on the host."""
 
     # -- dense forward (prefill + test oracle) ------------------------------
 
     def _forward(self, tokens: jnp.ndarray):
         """Full dense causal forward over (T,) tokens -> (logits (T, V),
         per-layer K/V rows (L, T, heads, dh))."""
-        x, ks, vs = _dense_blocks(self.params, tokens, self.heads)
-        return _head(self.params, x), ks, vs
+        x, ks, vs, _ = _dense_blocks(self.block, self.params, tokens,
+                                     self.heads, None)
+        return self.block.head(self.params, x), ks, vs
 
     def dense_greedy(self, prompt: Sequence[int],
                      max_new_tokens: int) -> List[int]:
@@ -208,11 +270,12 @@ class TinyDecoderLM:
                     f"cached_len {cached_len} must be a positive multiple "
                     f"of page_size strictly inside the {T}-token prompt")
             table = self.pool_table(pages)
-            logits, self.k_pool, self.v_pool = _prefill_chunk(
+            logits, self.k_pool, self.v_pool, report = _prefill_chunk(
                 self.params, self.k_pool, self.v_pool,
                 jnp.asarray(table), np.int32(cached_len),
                 toks[cached_len:], heads=self.heads,
-                page_size=self.page_size)
+                page_size=self.page_size, block=self.block)
+            self._observe("prefill", report)
             return T, [], logits[-1]
         bucket = self.prefill_bucket(T)
         toks = np.zeros((bucket,), np.int32)
@@ -223,12 +286,14 @@ class TinyDecoderLM:
         rows = np.arange(bucket)
         flat = (self.pool_table(pages)[rows // self.page_size]
                 * self.page_size + rows % self.page_size).astype(np.int32)
-        logits, self.k_pool, self.v_pool = _prefill_bucket(
+        logits, self.k_pool, self.v_pool, report = _prefill_bucket(
             self.params, self.k_pool, self.v_pool, toks, flat, np.int32(T),
-            heads=self.heads)
+            heads=self.heads, block=self.block)
         _M_PREFILL_TOKENS.inc(T)
         _M_PREFILL_PADDED.inc(bucket)
-        return T, [], np.asarray(logits)
+        logits = np.asarray(logits)
+        self._observe("prefill", report)
+        return T, [], logits
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page across both pools (the CoW split)."""
@@ -259,30 +324,62 @@ class TinyDecoderLM:
             lens = jnp.asarray(lens.astype(np.int32))
             tokens = jnp.asarray(tokens.astype(np.int32))
         with span("decode.dispatch"):
-            logits, self.k_pool, self.v_pool = jitted(
+            logits, self.k_pool, self.v_pool, report = jitted(
                 self.params, self.k_pool, self.v_pool, tables, lens,
-                tokens, heads=self.heads, page_size=self.page_size)
+                tokens, heads=self.heads, page_size=self.page_size,
+                block=self.block)
         with span("decode.logits_to_host"):
-            return np.asarray(logits), []
+            logits = np.asarray(logits)
+            self._observe("decode", report)
+            return logits, []
 
 
-@functools.partial(jax.jit, static_argnames=("heads",),
+class TinyDecoderLM(PagedDecoderLM):
+    """The GPT-2 block over the paged skeleton, float32 end to end."""
+
+    block = GPT2
+
+    def __init__(self, vocab: int = 64, d_model: int = 32,
+                 num_heads: int = 4, num_layers: int = 2,
+                 max_len: int = 64, num_pages: int = 32,
+                 page_size: int = 8, pages_per_seq: int = 8,
+                 bos_id: int = 1, eos_id: int = 0, seed: int = 0):
+        super().__init__(vocab, d_model, num_heads, num_layers, max_len,
+                         page_size, pages_per_seq, bos_id, eos_id)
+        self.params = _init_params(jax.random.key(seed), vocab, self.d,
+                                   self.heads, self.layers, self.max_len)
+        self._make_pools(num_pages, _F32)
+
+
+def _write_rows(pool, li, flat, rows):
+    """Layer ``li``'s slab of ``pool`` with ``rows`` (R, H, dh) written
+    at its flat pool rows ``flat`` (R,)."""
+    L, N, pg, H, dh = pool.shape
+    return pool.at[li].set(
+        pool[li].reshape(N * pg, H, dh).at[flat].set(rows.astype(pool.dtype))
+        .reshape(N, pg, H, dh))
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "block"),
                    donate_argnums=(1, 2))
-def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads):
+def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
+                    block=GPT2):
     """The whole prefill of one prompt padded to ``tokens.shape[0]``
     rows: the dense forward, K/V row ``i`` of every layer scattered to
     pool row ``flat[i]`` of the donated pools, and the logits of row
     ``n - 1`` (the 50k-wide head runs on that row alone).  Its shape
-    depends on the bucket only, not on the prompt's length or pages."""
+    depends on the bucket only, not on the prompt's length or pages.
+    Rows from ``n`` on are padding: not ``live`` to the block."""
     _M_PREFILL_PROGRAMS.inc(bucket=str(tokens.shape[0]))   # at trace
-    x, ks, vs = _dense_blocks(params, tokens, heads)
+    live = jnp.arange(tokens.shape[0], dtype=jnp.int32) < n
+    x, ks, vs, report = _dense_blocks(block, params, tokens, heads, live)
     L, N, pg, H, dh = k_pool.shape
-    k_pool = (k_pool.reshape(L, N * pg, H, dh).at[:, flat].set(ks)
-              .reshape(L, N, pg, H, dh))
-    v_pool = (v_pool.reshape(L, N * pg, H, dh).at[:, flat].set(vs)
-              .reshape(L, N, pg, H, dh))
+    k_pool = (k_pool.reshape(L, N * pg, H, dh).at[:, flat]
+              .set(ks.astype(k_pool.dtype)).reshape(L, N, pg, H, dh))
+    v_pool = (v_pool.reshape(L, N * pg, H, dh).at[:, flat]
+              .set(vs.astype(v_pool.dtype)).reshape(L, N, pg, H, dh))
     last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1)
-    return _head(params, last)[0], k_pool, v_pool
+    return block.head(params, last)[0], k_pool, v_pool, report
 
 
 @jax.jit
@@ -291,100 +388,77 @@ def _copy_pools_page(k_pool, v_pool, src, dst):
             v_pool.at[:, dst].set(v_pool[:, src]))
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
 def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
-                   heads, page_size):
+                   heads, page_size, block=GPT2):
     """Suffix prefill over cached pages: the suffix's Ts tokens are one
     chunk at positions cached_len..cached_len+Ts-1; attention sees the
     cached prefix rows plus the causal part of the suffix itself.
     Retraces per suffix length (the full-prompt prefill does not)."""
     Ts = tokens.shape[0]
-    L, N, pg, H, dh = k_pool.shape
-    d = H * dh
     pos = cached_len + jnp.arange(Ts, dtype=jnp.int32)
-    x = params["emb"][tokens] + params["pos"][pos]          # (Ts, d)
+    x = block.embed(params, tokens, pos)                    # (Ts, d)
     flat = table[pos // page_size] * page_size + pos % page_size
     lens1 = cached_len[None] if jnp.ndim(cached_len) == 0 else cached_len
+    reports = []
     for li, lp in enumerate(params["layers"]):
-        h = _ln(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(Ts, H, dh)
-        k = (h @ lp["wk"]).reshape(Ts, H, dh)
-        v = (h @ lp["wv"]).reshape(Ts, H, dh)
-        k_pool = k_pool.at[li].set(
-            k_pool[li].reshape(N * pg, H, dh).at[flat].set(k)
-            .reshape(N, pg, H, dh))
-        v_pool = v_pool.at[li].set(
-            v_pool[li].reshape(N * pg, H, dh).at[flat].set(v)
-            .reshape(N, pg, H, dh))
+        q, k, v = block.qkv(lp, x, pos, heads)
+        k_pool = _write_rows(k_pool, li, flat, k)
+        v_pool = _write_rows(v_pool, li, flat, v)
         a = paged_chunk_attention(q[None], k_pool[li], v_pool[li],
                                   table[None], lens1)[0]
-        x = x + a.reshape(Ts, d) @ lp["wo"]
-        h2 = _ln(x, lp["ln2"])
-        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    logits = _ln(x, params["ln_f"]) @ params["emb"].T
-    return logits, k_pool, v_pool
+        x = block.attn_out(lp, x, a.reshape(Ts, -1))
+        x, report = block.mlp(lp, x, None)       # every suffix row is real
+        reports.append(report)
+    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
 def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
-                 heads, page_size):
+                 heads, page_size, block=GPT2):
     """k tokens for every slot in one step (the speculative verify):
     append all k K/V rows, attend with per-row causal offsets through
     the chunked kernel.  Fixed-shape per (S, k) — compiled once."""
     S, T = tokens.shape
-    L, N, pg, H, dh = k_pool.shape
-    d = H * dh
+    H, dh = k_pool.shape[3:]
     pos = lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (S, T)
-    x = params["emb"][tokens] + params["pos"][pos]          # (S, T, d)
+    x = block.embed(params, tokens, pos)                    # (S, T, d)
     flat = (jnp.take_along_axis(tables, pos // page_size, axis=1)
             * page_size + pos % page_size).reshape(-1)      # (S*T,)
+    # an inactive slot holds the null table: its rows are not live
+    live = jnp.broadcast_to(tables[:, :1] > 0, (S, T))
+    reports = []
     for li, lp in enumerate(params["layers"]):
-        h = _ln(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(S, T, H, dh)
-        k = (h @ lp["wk"]).reshape(S * T, H, dh)
-        v = (h @ lp["wv"]).reshape(S * T, H, dh)
-        k_pool = k_pool.at[li].set(
-            k_pool[li].reshape(N * pg, H, dh).at[flat].set(k)
-            .reshape(N, pg, H, dh))
-        v_pool = v_pool.at[li].set(
-            v_pool[li].reshape(N * pg, H, dh).at[flat].set(v)
-            .reshape(N, pg, H, dh))
+        q, k, v = block.qkv(lp, x, pos, heads)
+        k_pool = _write_rows(k_pool, li, flat, k.reshape(S * T, H, dh))
+        v_pool = _write_rows(v_pool, li, flat, v.reshape(S * T, H, dh))
         a = paged_chunk_attention(q, k_pool[li], v_pool[li], tables, lens)
-        x = x + a.reshape(S, T, d) @ lp["wo"]
-        h2 = _ln(x, lp["ln2"])
-        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    logits = _ln(x, params["ln_f"]) @ params["emb"].T
-    return logits, k_pool, v_pool
+        x = block.attn_out(lp, x, a.reshape(S, T, -1))
+        x, report = block.mlp(lp, x, live)
+        reports.append(report)
+    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
 def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
-                 heads, page_size):
+                 heads, page_size, block=GPT2):
     """One token for every slot: append K/V into pages, attend over the
     page tables.  Fixed-shape in every argument — compiled once."""
     S = tokens.shape[0]
-    L, N, pg, H, dh = k_pool.shape
-    d = H * dh
-    x = params["emb"][tokens] + params["pos"][lens]        # (S, d)
+    x = block.embed(params, tokens, lens)                   # (S, d)
     # flat pool row each slot's new KV lands in: its page at
     # lens // page_size, offset lens % page_size.  Inactive slots hold
     # the null table -> they scribble on reserved page 0, harmlessly.
     flat = (tables[jnp.arange(S), lens // page_size] * page_size
             + lens % page_size)                            # (S,)
+    live = tables[:, 0] > 0
+    reports = []
     for li, lp in enumerate(params["layers"]):
-        h = _ln(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(S, H, dh)
-        k = (h @ lp["wk"]).reshape(S, H, dh)
-        v = (h @ lp["wv"]).reshape(S, H, dh)
-        k_pool = k_pool.at[li].set(
-            k_pool[li].reshape(N * pg, H, dh).at[flat].set(k)
-            .reshape(N, pg, H, dh))
-        v_pool = v_pool.at[li].set(
-            v_pool[li].reshape(N * pg, H, dh).at[flat].set(v)
-            .reshape(N, pg, H, dh))
+        q, k, v = block.qkv(lp, x, lens, heads)
+        k_pool = _write_rows(k_pool, li, flat, k)
+        v_pool = _write_rows(v_pool, li, flat, v)
         a = paged_attention(q, k_pool[li], v_pool[li], tables, lens + 1)
-        x = x + a.reshape(S, d) @ lp["wo"]
-        h2 = _ln(x, lp["ln2"])
-        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    logits = _ln(x, params["ln_f"]) @ params["emb"].T
-    return logits, k_pool, v_pool
+        x = block.attn_out(lp, x, a.reshape(S, -1))
+        x, report = block.mlp(lp, x, live)
+        reports.append(report)
+    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)

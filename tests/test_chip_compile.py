@@ -166,6 +166,59 @@ def test_prefill_top_bucket_fits_and_aliases_its_pools(one_chip, monkeypatch):
                for op in ops)
 
 
+def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
+    """The decode step of the ``olmoe-1b-7b`` generate configuration at
+    its real sizes (8 layers, 64 experts of 1,024, bf16 weights and
+    1,537 pages of 32 bf16 rows, 32 slots): the rpa kernel takes bf16
+    pages at (32, 16, 128), ``jax.lax.ragged_dot`` becomes the chip's
+    grouped-matmul kernel (three a layer, not 64 dense matmuls), the
+    plan is the configuration's ``planned_bytes`` and fits the chip."""
+    import functools
+    import json
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import model as dm
+    from paddle_tpu.models import olmoe
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", "olmoe-1b-7b.json")) as f:
+        cfg = json.load(f)
+    g = cfg["generate"]
+    d, H, L = (cfg["hidden_size"], cfg["num_attention_heads"],
+               cfg["num_hidden_layers"])
+    dtype = jnp.dtype(g["dtype"])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(
+            olmoe.init_params, jax.random.key(0), vocab=cfg["vocab_size"],
+            d=d, layers=L, experts=cfg["num_experts"],
+            expert_width=cfg["intermediate_size"], dtype=dtype)))
+    S, P = 32, g["pages_per_seq"]
+    pool = sds((L, g["num_pages"], g["page_size"], H, d // H), dtype)
+    compiled = dm._decode_step.lower(
+        params, pool, pool, sds((S, P), jnp.int32), sds((S,), jnp.int32),
+        sds((S,), jnp.int32), heads=H, page_size=g["page_size"],
+        block=olmoe.OlmoeBlock(top_k=cfg["num_experts_per_tok"])).compile()
+    m = compiled.memory_analysis()
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert planned == g["planned_bytes"] and planned < 15.5e9, planned
+    text = compiled.as_text()
+    ops = _kernel_op_names(text)
+    rpa = [op for op in ops if "ragged_paged_attention" in op]
+    assert len(rpa) == L and all("_decode_step" in op for op in rpa)
+    assert sum(op == "ragged-dot-none" for op in ops) == 3 * L
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine"):
+        assert f"jit(_decode_step)/{scope}/" in text, scope
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles(one_chip, grad):
     from paddle_tpu.pallas.flash_attention import flash_attention
