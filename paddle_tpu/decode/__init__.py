@@ -42,6 +42,7 @@ from paddle_tpu.decode.paged_kv import (
     PageAllocator,
     PagedPool,
     PoolExhausted,
+    PoolsLost,
 )
 from paddle_tpu.decode.session import (
     AdmissionRefused,
@@ -54,5 +55,5 @@ from paddle_tpu.decode.engine import GenerationEngine
 __all__ = [
     "AdmissionRefused", "DecodeRequest", "DecodeSession",
     "GenerationEngine", "PageAllocator", "PagedPool",
-    "PagedSeq2SeqModel", "PoolExhausted",
+    "PagedSeq2SeqModel", "PoolExhausted", "PoolsLost",
 ]

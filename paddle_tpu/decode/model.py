@@ -25,6 +25,13 @@ attends over its page table.  The decode step is ONE jitted
 fixed-shape function of ``(pools, page_tables, lens, tokens)`` — batch
 composition churn never re-traces.
 
+Every program here takes both pools DONATED and hands back the same
+buffers: a layer's new rows are one scatter into the pool seen flat
+(``_write_rows``), and the kernels read the whole pool through page
+tables moved by the layer's offset (``_layer_pages``), so no program
+produces a value of a pool's or a layer slab's size.  A donated call
+that fails on the device has consumed the pools: ``_donating``.
+
 Weights are randomly initialized from a seed: these models exist to
 prove the kernel + session mechanics (tests pin the paged decode
 against a dense incremental oracle and a float32 reference) and to
@@ -33,6 +40,7 @@ feed the benchmark, not to be trained LMs.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import List, Sequence, Tuple
@@ -47,7 +55,7 @@ from paddle_tpu.decode.attention import (
     paged_attention,
     paged_chunk_attention,
 )
-from paddle_tpu.decode.paged_kv import PageAllocator
+from paddle_tpu.decode.paged_kv import PageAllocator, PoolsLost
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.observability.events import span
 from paddle_tpu.pallas.tuning.bucket import bucket_dim
@@ -63,6 +71,11 @@ _M_PREFILL_PROGRAMS = _metrics.counter(
     "decode_prefill_programs_total",
     "prefill programs traced, by bucket (one per bucket and model "
     "shape; a rise under traffic is a compile in the serving path)")
+
+_M_POOL_REBUILDS = _metrics.counter(
+    "decode_pool_rebuilds_total",
+    "times a program that had consumed its donated K/V pools failed and "
+    "both pools were made anew, every page's rows lost")
 
 _F32 = jnp.float32
 
@@ -197,6 +210,29 @@ class PagedDecoderLM:
         self.k_pool = jnp.zeros(shape, dtype)
         self.v_pool = jnp.zeros(shape, dtype)
 
+    @contextlib.contextmanager
+    def _donating(self):
+        """Round a call of a program that is given both pools DONATED
+        and the wait for its results (a failure on the device shows at
+        the wait, not at the dispatch).  A call that failed before it
+        consumed the pools leaves them as they were.  One that failed
+        after has lost every page's rows: both pools are made anew,
+        and ``PoolsLost`` tells the session."""
+        k_in, v_in = self.k_pool, self.v_pool
+        try:
+            yield
+        except BaseException as exc:
+            if not (k_in.is_deleted() or v_in.is_deleted()):
+                self.k_pool, self.v_pool = k_in, v_in
+                raise
+            self.k_pool = jnp.zeros(k_in.shape, k_in.dtype)
+            self.v_pool = jnp.zeros(v_in.shape, v_in.dtype)
+            _M_POOL_REBUILDS.inc()
+            raise PoolsLost(
+                "a program failed after it had consumed the K/V pools "
+                f"({type(exc).__name__}: {exc}); both were made anew, "
+                "empty") from exc
+
     def _observe(self, phase: str, report) -> None:
         """What the layers reported of one prefill or step (None for a
         block that reports nothing), on the host."""
@@ -270,13 +306,15 @@ class PagedDecoderLM:
                     f"cached_len {cached_len} must be a positive multiple "
                     f"of page_size strictly inside the {T}-token prompt")
             table = self.pool_table(pages)
-            logits, self.k_pool, self.v_pool, report = _prefill_chunk(
-                self.params, self.k_pool, self.v_pool,
-                jnp.asarray(table), np.int32(cached_len),
-                toks[cached_len:], heads=self.heads,
-                page_size=self.page_size, block=self.block)
-            self._observe("prefill", report)
-            return T, [], logits[-1]
+            with self._donating():
+                logits, self.k_pool, self.v_pool, report = _prefill_chunk(
+                    self.params, self.k_pool, self.v_pool,
+                    jnp.asarray(table), np.int32(cached_len),
+                    toks[cached_len:], heads=self.heads,
+                    page_size=self.page_size, block=self.block)
+                logits = np.asarray(logits[-1])
+                self._observe("prefill", report)
+            return T, [], logits
         bucket = self.prefill_bucket(T)
         toks = np.zeros((bucket,), np.int32)
         toks[:T] = prompt
@@ -286,19 +324,21 @@ class PagedDecoderLM:
         rows = np.arange(bucket)
         flat = (self.pool_table(pages)[rows // self.page_size]
                 * self.page_size + rows % self.page_size).astype(np.int32)
-        logits, self.k_pool, self.v_pool, report = _prefill_bucket(
-            self.params, self.k_pool, self.v_pool, toks, flat, np.int32(T),
-            heads=self.heads, block=self.block)
+        with self._donating():
+            logits, self.k_pool, self.v_pool, report = _prefill_bucket(
+                self.params, self.k_pool, self.v_pool, toks, flat,
+                np.int32(T), heads=self.heads, block=self.block)
+            logits = np.asarray(logits)
+            self._observe("prefill", report)
         _M_PREFILL_TOKENS.inc(T)
         _M_PREFILL_PADDED.inc(bucket)
-        logits = np.asarray(logits)
-        self._observe("prefill", report)
         return T, [], logits
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page across both pools (the CoW split)."""
-        self.k_pool, self.v_pool = _copy_pools_page(
-            self.k_pool, self.v_pool, np.int32(src), np.int32(dst))
+        with self._donating():
+            self.k_pool, self.v_pool = _copy_pools_page(
+                self.k_pool, self.v_pool, np.int32(src), np.int32(dst))
 
     def verify_chunk(self, tokens: np.ndarray, states, tables: np.ndarray,
                      lens: np.ndarray):
@@ -323,15 +363,16 @@ class PagedDecoderLM:
             tables = jnp.asarray(tables.astype(np.int32))
             lens = jnp.asarray(lens.astype(np.int32))
             tokens = jnp.asarray(tokens.astype(np.int32))
-        with span("decode.dispatch"):
-            logits, self.k_pool, self.v_pool, report = jitted(
-                self.params, self.k_pool, self.v_pool, tables, lens,
-                tokens, heads=self.heads, page_size=self.page_size,
-                block=self.block)
-        with span("decode.logits_to_host"):
-            logits = np.asarray(logits)
-            self._observe("decode", report)
-            return logits, []
+        with self._donating():
+            with span("decode.dispatch"):
+                logits, self.k_pool, self.v_pool, report = jitted(
+                    self.params, self.k_pool, self.v_pool, tables, lens,
+                    tokens, heads=self.heads, page_size=self.page_size,
+                    block=self.block)
+            with span("decode.logits_to_host"):
+                logits = np.asarray(logits)
+                self._observe("decode", report)
+                return logits, []
 
 
 class TinyDecoderLM(PagedDecoderLM):
@@ -352,12 +393,24 @@ class TinyDecoderLM(PagedDecoderLM):
 
 
 def _write_rows(pool, li, flat, rows):
-    """Layer ``li``'s slab of ``pool`` with ``rows`` (R, H, dh) written
-    at its flat pool rows ``flat`` (R,)."""
+    """``pool`` with layer ``li``'s ``rows`` (R, H, dh) written at its
+    flat rows ``flat`` (R,): one scatter into the pool's own (donated)
+    buffer.  The pool seen as (L * N * pg, H, dh) is a bitcast, and
+    layer ``li``'s rows start at ``li * N * pg``."""
     L, N, pg, H, dh = pool.shape
-    return pool.at[li].set(
-        pool[li].reshape(N * pg, H, dh).at[flat].set(rows.astype(pool.dtype))
-        .reshape(N, pg, H, dh))
+    return (pool.reshape(L * N * pg, H, dh).at[li * N * pg + flat]
+            .set(rows.astype(pool.dtype)).reshape(pool.shape))
+
+
+def _layer_pages(k_pool, v_pool, li, tables):
+    """What the paged kernels take for layer ``li``: they pick every
+    K/V block through the page table, so they get each whole pool as
+    (L * N, pg, H, dh) pages (a bitcast, no slab) and the tables moved
+    by ``li * N``.  The null page 0 of an inactive slot becomes layer
+    ``li``'s own page 0."""
+    L, N, pg, H, dh = k_pool.shape
+    return (k_pool.reshape(L * N, pg, H, dh),
+            v_pool.reshape(L * N, pg, H, dh), tables + li * N)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block"),
@@ -382,13 +435,14 @@ def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
     return block.head(params, last)[0], k_pool, v_pool, report
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _copy_pools_page(k_pool, v_pool, src, dst):
     return (k_pool.at[:, dst].set(k_pool[:, src]),
             v_pool.at[:, dst].set(v_pool[:, src]))
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
+                   donate_argnums=(1, 2))
 def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
                    heads, page_size, block=GPT2):
     """Suffix prefill over cached pages: the suffix's Ts tokens are one
@@ -405,15 +459,17 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
         q, k, v = block.qkv(lp, x, pos, heads)
         k_pool = _write_rows(k_pool, li, flat, k)
         v_pool = _write_rows(v_pool, li, flat, v)
-        a = paged_chunk_attention(q[None], k_pool[li], v_pool[li],
-                                  table[None], lens1)[0]
+        a = paged_chunk_attention(
+            q[None], *_layer_pages(k_pool, v_pool, li, table[None]),
+            lens1)[0]
         x = block.attn_out(lp, x, a.reshape(Ts, -1))
         x, report = block.mlp(lp, x, None)       # every suffix row is real
         reports.append(report)
     return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
+                   donate_argnums=(1, 2))
 def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
                  heads, page_size, block=GPT2):
     """k tokens for every slot in one step (the speculative verify):
@@ -432,14 +488,16 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
         q, k, v = block.qkv(lp, x, pos, heads)
         k_pool = _write_rows(k_pool, li, flat, k.reshape(S * T, H, dh))
         v_pool = _write_rows(v_pool, li, flat, v.reshape(S * T, H, dh))
-        a = paged_chunk_attention(q, k_pool[li], v_pool[li], tables, lens)
+        a = paged_chunk_attention(
+            q, *_layer_pages(k_pool, v_pool, li, tables), lens)
         x = block.attn_out(lp, x, a.reshape(S, T, -1))
         x, report = block.mlp(lp, x, live)
         reports.append(report)
     return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"))
+@functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
+                   donate_argnums=(1, 2))
 def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
                  heads, page_size, block=GPT2):
     """One token for every slot: append K/V into pages, attend over the
@@ -457,7 +515,8 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
         q, k, v = block.qkv(lp, x, lens, heads)
         k_pool = _write_rows(k_pool, li, flat, k)
         v_pool = _write_rows(v_pool, li, flat, v)
-        a = paged_attention(q, k_pool[li], v_pool[li], tables, lens + 1)
+        a = paged_attention(
+            q, *_layer_pages(k_pool, v_pool, li, tables), lens + 1)
         x = block.attn_out(lp, x, a.reshape(S, -1))
         x, report = block.mlp(lp, x, live)
         reports.append(report)

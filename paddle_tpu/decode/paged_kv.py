@@ -38,6 +38,12 @@ class PoolExhausted(RuntimeError):
     """The page pool cannot satisfy an allocation: refuse admission."""
 
 
+class PoolsLost(RuntimeError):
+    """A program that had consumed its donated pools failed; the model
+    made them anew, empty.  Every page's rows are gone: of every seated
+    sequence and of the prefix cache."""
+
+
 class PageAllocator:
     """Refcounted free-list page allocator.  Pages are ints in
     [0, num_pages).
@@ -184,12 +190,10 @@ class PagedPool:
             (self.num_pages, self.page_size) + self.feature_shape, dtype)
         import jax
 
-        # NOT donated: donated buffers interact badly with the
-        # persistent XLA compile cache on this jax version (cache-
-        # loaded executables mis-apply the aliasing — observed as both
-        # corrupted weights and later native crashes in long suites).
-        # The pool copy per write is ~pool-size and off the per-token
-        # path (one write per admission / appended row).
+        # not donated, so each write copies the pool: these writes are
+        # off the per-token path (one per admission or appended row of
+        # the seq2seq adapter, the only user; the decoder-only models
+        # keep their pools in ``decode/model.py`` and donate them)
         self._scatter = jax.jit(_scatter_pages)
         self._scatter_one = jax.jit(_scatter_row)
         self._copy = jax.jit(_copy_page)
@@ -281,13 +285,17 @@ def cow_split(allocator: PageAllocator, pages: List[int], page_idx: int,
     release the shared original, and patch the page list in place.
     Returns the new page id (or None when the page was already private).
     Raises ``PoolExhausted`` without touching anything when no page is
-    free for the copy."""
+    free for the copy; a copy that raises gives the fresh page back."""
     old = pages[page_idx]
     if not allocator.is_shared(old):
         return None
     (new,) = allocator.alloc(1)
-    for copy in copiers:
-        copy(old, new)
+    try:
+        for copy in copiers:
+            copy(old, new)
+    except BaseException:
+        allocator.free([new])
+        raise
     allocator.free([old])
     pages[page_idx] = new
     return new

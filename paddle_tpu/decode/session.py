@@ -32,7 +32,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from paddle_tpu.decode.paged_kv import PoolExhausted, cow_split
+from paddle_tpu.decode.paged_kv import PoolExhausted, PoolsLost, cow_split
 from paddle_tpu.decode.spec import accept_greedy, observe_chunk
 from paddle_tpu.generation import beam_select
 from paddle_tpu.observability import metrics as _metrics
@@ -68,8 +68,8 @@ _M_REQ_SEC = _metrics.histogram(
     "decode_request_seconds", "submit-to-finish latency per sequence")
 _M_STEP_FAIL = _metrics.counter(
     "decode_step_failures_total",
-    "decode/verify dispatches that raised (contained per-slot, "
-    "stepper survives)")
+    "decode/verify dispatches that raised, and prefills or page copies "
+    "that lost the pools (contained per-slot, stepper survives)")
 _M_CANCELLED = _metrics.counter(
     "decode_cancelled_total",
     "generation requests cancelled by their consumer (pages freed)")
@@ -375,12 +375,18 @@ class DecodeSession:
         contained (``_contain_step_failure``): the slots that were in
         the batch are evicted — first offense requeued to retry from
         scratch, second offense quarantined with 503 ``step_failed`` —
-        and the stepper thread lives on."""
+        and the stepper thread lives on.  So is a copy-on-write split
+        that lost the model's pools (``PoolsLost``; a step or a prefill
+        that did contains it where it is called)."""
         live = [s.req.rid for s in self._slots if s is not None]
         with span("decode.tick", active=len(live),
                   waiting=len(self._pending),
                   rids=",".join(map(str, live))):
-            return self._tick()
+            try:
+                return self._tick()
+            except PoolsLost as exc:
+                self._contain_step_failure([], exc)
+                return 0
 
     def _tick(self) -> int:
         with span("decode.sweep"):
@@ -702,8 +708,18 @@ class DecodeSession:
         offense: the request has now killed two dispatches and is
         quarantined with 503 ``step_failed`` — the decode-plane mirror
         of the replica pool's poison-batch rule.  Queued requests and
-        the stepper thread are untouched."""
+        the stepper thread are untouched.
+
+        ``PoolsLost`` (the program had consumed the donated pools; the
+        model made them anew, empty) widens the batch to every seated
+        sequence, whichever program failed, and drops the prefix index:
+        no page holds the rows it was filled with."""
         _M_STEP_FAIL.inc()
+        if isinstance(exc, PoolsLost):
+            active_idx = [i for i, s in enumerate(self._slots)
+                          if s is not None]
+            if self._prefix is not None:
+                self._prefix.clear()
         requeue: List[DecodeRequest] = []
         groups_seen = set()
         for i in list(active_idx):
@@ -886,6 +902,8 @@ class DecodeSession:
                                                   str(e)))
             return True
         except BaseException as e:
+            if isinstance(e, PoolsLost):
+                self._contain_step_failure([], e)
             req._finish("error", e)
             return True
         if got is None:

@@ -11,6 +11,7 @@ persistent compilation cache is off around them (an entry written for a
 described device cannot be read back without one).
 """
 
+import math
 import os
 import re
 
@@ -51,6 +52,66 @@ def _kernel_op_names(text):
     what a trace reduction finds a kernel's device events by."""
     return [m.group(1) for line in text.splitlines() if MARKER in line
             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
+_RESULT = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]\S*\s+([\w\-]+)\(")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+
+
+def _planned_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
+                           undonated_plan):
+    """A program ``(params, k_pool, v_pool, ...) -> (logits, k_pool,
+    v_pool, ...)`` compiled for the chip: both pools are aliased input
+    to output, and no instruction's result has as many elements as a
+    pool or as one layer's slab except the pools' parameters, their
+    bitcasts (the flat views the scatters and the kernels take) and the
+    in-place scatters (a ``scatter``, and the fusion whose root it is).
+    So no copy, slice or rewrite of a pool or a slab is left, and the
+    plan is at least two pools under ``undonated_plan``, the same
+    program's before its pools were donated.  -> the compiled text."""
+    pool_elems = math.prod(pool_shape)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool_elems * itemsize
+    planned = _planned_bytes(compiled)
+    assert planned <= undonated_plan - 2 * pool_elems * itemsize, planned
+    text = compiled.as_text()
+    header = text[:text.index("\n")]
+    for out, arg in ((1, n_param_leaves), (2, n_param_leaves + 1)):
+        assert f"{{{out}}}: ({arg}, {{}}, may-alias)" in header, header[:300]
+    big = {pool_elems, pool_elems // pool_shape[0]}
+    scatter_roots, cur, stray = set(), None, []
+    lines = text.splitlines()
+    for line in lines:
+        c = _COMPUTATION.match(line)
+        if c and " = " not in line.split("(")[0]:
+            cur = c.group(1)
+        elif "ROOT" in line and " scatter(" in line:
+            scatter_roots.add(cur)
+    n_scatters = 0
+    for line in lines:
+        r = _RESULT.match(line)
+        if not r or not r.group(2):
+            continue
+        if math.prod(map(int, r.group(2).split(","))) not in big:
+            continue
+        name, op = r.group(1), r.group(3)
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if op == "scatter":
+            n_scatters += 1
+        elif not (op in ("parameter", "bitcast") or (
+                op == "fusion" and called
+                and called.group(1) in scatter_roots)):
+            stray.append((name, op))
+    assert not stray, stray
+    assert n_scatters == 2 * pool_shape[0]        # K and V, every layer
+    return text
 
 
 # (slots, heads, head_dim, page, pool pages, pages/seq, dtype): a real
@@ -123,6 +184,50 @@ def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
                for op in ops)
 
 
+# the decode steps' plans at the parent of PR 27, whose steps were not
+# donated and held a second copy of both pools (PERF.md section 4,
+# ``perf/scratch_compile.py decode`` / ``scratch_compile_paged.py``)
+CEREBRAS_STEP_PLAN_UNDONATED = 13_665_261_056
+OLMOE_STEP_PLAN_UNDONATED = 14_397_756_928
+OLMOE_CHUNK_PLAN_UNDONATED = 14.42e9
+
+
+def test_cerebras_decode_step_writes_and_reads_its_pools_in_place(
+        one_chip, monkeypatch):
+    """The decode step of the ``cerebras-gpt-1.3b`` generate
+    configuration at its real sizes (24 layers f32, 320 pages of 32
+    rows, 16 slots): the donated pools are aliased, every K/V row is
+    scattered into the pool's own buffer and the rpa kernel reads the
+    whole pool through moved page tables, so the plan is at least two
+    pools under the undonated step's."""
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import model as dm
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    d, H, L, N, pg, S, P = 2048, 16, 24, 320, 32, 16, 40
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: dm._init_params(
+            jax.random.key(0), 50257, d, H, L, 2048)))
+    shape = (L, N, pg, H, d // H)
+    pool = sds(shape, jnp.float32)
+    compiled = dm._decode_step.lower(
+        params, pool, pool, sds((S, P), jnp.int32), sds((S,), jnp.int32),
+        sds((S,), jnp.int32), heads=H, page_size=pg).compile()
+    text = _assert_pools_in_place(
+        compiled, len(jax.tree.leaves(params)), shape, 4,
+        CEREBRAS_STEP_PLAN_UNDONATED)
+    ops = _kernel_op_names(text)
+    assert len(ops) == L
+    assert all("_decode_step" in op and "ragged_paged_attention" in op
+               for op in ops)
+
+
 def test_prefill_top_bucket_fits_and_aliases_its_pools(one_chip, monkeypatch):
     """The 1,280-row prefill bucket of the ``cerebras-gpt-1.3b``
     generate configuration (24 layers f32, 320 pages of 32 rows): the
@@ -166,18 +271,14 @@ def test_prefill_top_bucket_fits_and_aliases_its_pools(one_chip, monkeypatch):
                for op in ops)
 
 
-def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
-    """The decode step of the ``olmoe-1b-7b`` generate configuration at
-    its real sizes (8 layers, 64 experts of 1,024, bf16 weights and
-    1,537 pages of 32 bf16 rows, 32 slots): the rpa kernel takes bf16
-    pages at (32, 16, 128), ``jax.lax.ragged_dot`` becomes the chip's
-    grouped-matmul kernel (three a layer, not 64 dense matmuls), the
-    plan is the configuration's ``planned_bytes`` and fits the chip."""
+def _olmoe_cell(one_chip, monkeypatch):
+    """The ``olmoe-1b-7b`` generate configuration at its real sizes, as
+    shapes on the described chip: (cfg, params, pool, pool shape, block,
+    sds)."""
     import functools
     import json
 
     from paddle_tpu import pallas as pk
-    from paddle_tpu.decode import model as dm
     from paddle_tpu.models import olmoe
 
     monkeypatch.setitem(pk._STATE, "mode", "on")
@@ -199,17 +300,35 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
             olmoe.init_params, jax.random.key(0), vocab=cfg["vocab_size"],
             d=d, layers=L, experts=cfg["num_experts"],
             expert_width=cfg["intermediate_size"], dtype=dtype)))
+    shape = (L, g["num_pages"], g["page_size"], H, d // H)
+    block = olmoe.OlmoeBlock(top_k=cfg["num_experts_per_tok"])
+    return cfg, params, sds(shape, dtype), shape, block, sds
+
+
+def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
+    """The decode step of the ``olmoe-1b-7b`` generate configuration at
+    its real sizes (8 layers, 64 experts of 1,024, bf16 weights and
+    1,537 pages of 32 bf16 rows, 32 slots): the rpa kernel takes bf16
+    pages at (32, 16, 128), ``jax.lax.ragged_dot`` becomes the chip's
+    grouped-matmul kernel (three a layer, not 64 dense matmuls), both
+    donated pools are aliased and written and read in place, and the
+    plan fits the chip.  The plan is pinned here as a literal: the
+    configuration's ``planned_bytes`` is the undonated step's (PR 26)
+    and is a benchmark file, which PR 27 could not edit."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, shape, block, sds = _olmoe_cell(one_chip, monkeypatch)
+    g, L = cfg["generate"], cfg["num_hidden_layers"]
     S, P = 32, g["pages_per_seq"]
-    pool = sds((L, g["num_pages"], g["page_size"], H, d // H), dtype)
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, P), jnp.int32), sds((S,), jnp.int32),
-        sds((S,), jnp.int32), heads=H, page_size=g["page_size"],
-        block=olmoe.OlmoeBlock(top_k=cfg["num_experts_per_tok"])).compile()
-    m = compiled.memory_analysis()
-    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
-               + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert planned == g["planned_bytes"] and planned < 15.5e9, planned
-    text = compiled.as_text()
+        sds((S,), jnp.int32), heads=shape[3], page_size=g["page_size"],
+        block=block).compile()
+    planned = _planned_bytes(compiled)
+    assert planned == 10_367_797_760, planned
+    text = _assert_pools_in_place(
+        compiled, len(jax.tree.leaves(params)), shape, 2,
+        OLMOE_STEP_PLAN_UNDONATED)
     ops = _kernel_op_names(text)
     rpa = [op for op in ops if "ragged_paged_attention" in op]
     assert len(rpa) == L and all("_decode_step" in op for op in rpa)
@@ -217,6 +336,28 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
         assert f"jit(_decode_step)/{scope}/" in text, scope
+
+
+def test_olmoe_suffix_prefill_writes_and_reads_its_pools_in_place(
+        one_chip, monkeypatch):
+    """The suffix prefill over cached pages (a 136-row chunk at the
+    ``olmoe-1b-7b`` cell's sizes, which planned 14.42 GB undonated):
+    the same in-place writes and whole-pool reads through the chunked
+    kernel, two pools fewer bytes."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, shape, block, sds = _olmoe_cell(one_chip, monkeypatch)
+    g, L = cfg["generate"], cfg["num_hidden_layers"]
+    compiled = dm._prefill_chunk.lower(
+        params, pool, pool, sds((g["pages_per_seq"],), jnp.int32),
+        sds((), jnp.int32), sds((136,), jnp.int32), heads=shape[3],
+        page_size=g["page_size"], block=block).compile()
+    text = _assert_pools_in_place(
+        compiled, len(jax.tree.leaves(params)), shape, 2,
+        OLMOE_CHUNK_PLAN_UNDONATED)
+    chunk = [op for op in _kernel_op_names(text)
+             if "ragged_paged_attention_chunk" in op]
+    assert len(chunk) == L and all("_prefill_chunk" in op for op in chunk)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

@@ -458,6 +458,77 @@ def test_prefill_counters_and_span_args():
 
 
 # ---------------------------------------------------------------------------
+# K/V rows written and read in the donated pools in place (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+def _seat(lm, T, budget):
+    """Prefill a ``T``-token prompt into fresh pages -> (prompt, pages)."""
+    prompt = _prompt(T)
+    pages = lm.allocator.alloc(lm.context_pages(prompt, budget))
+    lm.prefill(prompt, pages)
+    return prompt, pages
+
+
+def _rows(lm, mask, pages, first, n):
+    """Mark rows ``first .. first + n - 1`` of a sequence in every layer
+    of the (L, N, pg) ``mask``."""
+    for t in range(first, first + n):
+        mask[:, pages[t // lm.page_size], t % lm.page_size] = True
+
+
+@pytest.mark.parametrize("op", ["decode", "verify", "suffix_prefill",
+                                "copy_page"])
+def test_programs_consume_their_pools_and_write_only_their_rows(op):
+    """Each of the four programs that take the pools donates them (the
+    pool objects it was given are deleted) and changes, in every layer,
+    exactly the rows it appends: a slot's next row(s) in that slot's
+    pages, an inactive slot's in page 0 of each layer.  A wrong layer
+    offset into the whole-pool view would write another layer's rows."""
+    lm = _bucket_lm(num_layers=3)
+    L, N, pg = lm.layers, lm.allocator.num_pages, lm.page_size
+    a_prompt, a_pages = _seat(lm, 21, 8)
+    b_prompt, b_pages = _seat(lm, 40, 8)
+    S = 3                                # slot 1 stays inactive
+    tables = np.zeros((S, lm.pages_per_seq), np.int32)
+    tables[0], tables[2] = lm.pool_table(a_pages), lm.pool_table(b_pages)
+    lens = np.array([21, 1, 40], np.int64)
+    write = np.zeros((L, N, pg), bool)
+    if op == "suffix_prefill":
+        # a third prompt over two of A's pages: rows 16..25 are its own
+        c_prompt = a_prompt[:16] + _prompt(10)
+        c_pages = lm.allocator.fork(a_pages[:2]) + lm.allocator.alloc(2)
+        _rows(lm, write, c_pages, 16, 10)
+    elif op == "copy_page":
+        src, (dst,) = b_pages[1], lm.allocator.alloc(1)
+        write[:, dst] = True
+    else:
+        k = 1 if op == "decode" else 3
+        _rows(lm, write, a_pages, 21, k)
+        _rows(lm, write, b_pages, 40, k)
+        write[:, 0, 1:1 + k] = True      # the inactive slot's null page
+    old = (lm.k_pool, lm.v_pool)
+    was = [np.asarray(pool).copy() for pool in old]
+    if op == "decode":
+        lm.decode(np.full((S, 1), 5, np.int64), [], tables, lens)
+    elif op == "verify":
+        lm.verify_chunk(np.full((S, 3), 5, np.int64), [], tables, lens)
+    elif op == "suffix_prefill":
+        lm.prefill(c_prompt, c_pages, cached_len=16)
+    else:
+        lm.copy_page(src, dst)
+    assert all(pool.is_deleted() for pool in old)
+    for before, pool in zip(was, (lm.k_pool, lm.v_pool)):
+        now = np.asarray(pool)
+        assert now.shape == before.shape == (L, N, pg, lm.heads, lm.dh)
+        np.testing.assert_array_equal(now[~write], before[~write])
+        changed = (now[write] != before[write]).any(axis=(-1, -2))
+        assert changed.all(), (op, int(changed.sum()), int(write.sum()))
+        if op == "copy_page":
+            np.testing.assert_array_equal(now[:, dst], before[:, src])
+
+
+# ---------------------------------------------------------------------------
 # serving endpoint
 # ---------------------------------------------------------------------------
 

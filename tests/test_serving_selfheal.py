@@ -416,6 +416,97 @@ def test_decode_request_failing_twice_is_quarantined_503():
     assert model.allocator.pages_in_use == 0
 
 
+# -- a donated program that fails has consumed its pools (ISSUE 27) ---------
+
+
+def _lose_pools_on_next_call(monkeypatch, name):
+    """``decode/model.py``'s jitted program ``name`` fails on its next
+    call after consuming the pools it was given, as a donated call that
+    fails on the device does; later calls run."""
+    from paddle_tpu.decode import model as dm
+
+    real, failed = getattr(dm, name), []
+
+    def program(*args, **kw):
+        if not failed:
+            failed.append(True)
+            first = 0 if name == "_copy_pools_page" else 1
+            for pool in args[first:first + 2]:
+                pool.delete()
+            raise RuntimeError("injected: the device halted")
+        return real(*args, **kw)
+
+    monkeypatch.setattr(dm, name, program)
+
+
+def _paged_lm(seed):
+    from paddle_tpu.decode.model import TinyDecoderLM
+
+    return TinyDecoderLM(vocab=16, d_model=8, num_heads=2, num_layers=2,
+                         num_pages=24, page_size=4, pages_per_seq=5,
+                         seed=seed)
+
+
+@pytest.mark.parametrize("program", ["_decode_step", "_prefill_bucket",
+                                     "_prefill_chunk", "_copy_pools_page"])
+def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
+    """The pools are made anew and counted once; every seated sequence
+    goes back by the strike rule and completes from a fresh prefill with
+    the oracle's tokens; the prefix index is dropped, so no later hit is
+    served from a page whose rows are gone; every page comes back."""
+    from paddle_tpu.decode import model as dm
+    from paddle_tpu.decode.paged_kv import PoolsLost
+    from paddle_tpu.decode.prefix import PrefixCache
+    from paddle_tpu.decode.session import (AdmissionRefused, BeamRequest,
+                                           DecodeRequest, DecodeSession)
+
+    lm = _paged_lm(11)
+    cache = PrefixCache(lm.allocator, lm.page_size)
+    sess = DecodeSession(lm, max_slots=4, prefix_cache=cache)
+    shared = [1, 5, 9, 3, 7, 2, 8, 4]              # two full pages
+    p_a, p_b, p_c = shared + [6], shared + [11, 12], [1, 13, 14]
+    want = {tuple(p): lm.dense_greedy(p, 5) for p in (p_a, p_b, p_c)}
+    rebuilds = dm._M_POOL_REBUILDS.value
+    n0 = rebuilds()
+
+    a = sess.submit(DecodeRequest(p_a, max_new_tokens=5))
+    sess.run(max_steps=50)
+    assert a.result(0) == want[tuple(p_a)] and cache.cached_pages == 2
+    # B hits A's two pages and is seated beside C; then the fault
+    b = sess.submit(DecodeRequest(p_b, max_new_tokens=5))
+    c = sess.submit(DecodeRequest(p_c, max_new_tokens=5))
+    # the request whose own program fails (the decode step has none): a
+    # fresh prompt, one that hits the cache, and a beam, whose members
+    # share the prompt's last page and split it copy-on-write
+    victims = {
+        "_prefill_bucket": DecodeRequest([1, 2, 3], max_new_tokens=2),
+        "_prefill_chunk": DecodeRequest(shared + [15], max_new_tokens=2),
+        "_copy_pools_page": BeamRequest([1, 2, 3], beam_size=2,
+                                        max_new_tokens=2)}
+    victim = None
+    if program in victims:
+        sess.step()                                # B and C are seated
+        victim = sess.submit(victims[program])
+    _lose_pools_on_next_call(monkeypatch, program)
+    old = (lm.k_pool, lm.v_pool)
+    sess.run(max_steps=100)
+
+    assert rebuilds() == n0 + 1
+    assert all(pool.is_deleted() for pool in old)
+    assert not lm.k_pool.is_deleted() and lm.k_pool.shape == old[0].shape
+    assert (b.step_failures, c.step_failures) == (1, 1)
+    assert b.result(0) == want[tuple(p_b)]
+    assert c.result(0) == want[tuple(p_c)]
+    # B's one hit was before the fault; seated again it found nothing
+    # (nor did the chunk's victim: its prefill failed)
+    assert cache.hits == 1, cache.stats()
+    if victim is not None:
+        with pytest.raises((PoolsLost, AdmissionRefused)):
+            victim.result(0)
+    cache.clear()
+    assert lm.allocator.pages_in_use == 0
+
+
 # -- mid-stream disconnect cancels the decode slot (satellite) --------------
 
 
