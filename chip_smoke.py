@@ -50,7 +50,7 @@ SIZES = {
     # bench.py's headline configuration
     "train": dict(batch=256, image=(3, 224, 224), classes=1000, steps=6,
                   small_batch=8),
-    # benchmark/transformer_bench.py's defaults
+    # a 4-layer d=2048 LM block at S=1024, where flash attention starts
     "transformer": dict(B=8, S=1024, D=2048, L=4, V=32768, steps=3),
     # benchmark/run.py's "lstm" row (h=256)
     "lstm": dict(B=64, T=100, emb=512, hidden=256, steps=3),

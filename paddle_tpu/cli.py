@@ -47,7 +47,7 @@ Subcommands:
       (static program verification — paddle_tpu/analysis; exits nonzero
        on error diagnostics.  --audit-registry checks op-metadata
        coverage against the checked-in baseline)
-  paddle tune [--kernel=matmul,flash_attention,...] [--shapes=MxKxN;...]
+  paddle tune [--kernel=softmax,flash_attention,...] [--shapes=RxC;...]
               [--budget=N] [--reps=N] [--output=PATH] [--smoke]
       (Pallas kernel autotuner — paddle_tpu/pallas/tuning: measure tile
        configs over each kernel family's valid space and persist the

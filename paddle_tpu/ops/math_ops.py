@@ -151,18 +151,7 @@ def _mul(ctx):
 
     out_dt = amp.out_dtype(x)
     x2, y2 = amp.cast_operands(_flatten2d(x, xn), _flatten2d(y, yn))
-    out = None
-    from paddle_tpu import pallas as pk
-
-    if pk.use_matmul():
-        from paddle_tpu.pallas import matmul as pk_mm
-
-        m, k = x2.shape
-        n = y2.shape[1]
-        if pk_mm.fits(m, k, n):
-            out = pk.pallas_matmul(x2, y2, interpret=pk.interpret_mode()).astype(out_dt)
-    if out is None:
-        out = jnp.dot(x2, y2, preferred_element_type=_pref()).astype(out_dt)
+    out = jnp.dot(x2, y2, preferred_element_type=_pref()).astype(out_dt)
     out_shape = x.shape[:xn] + y.shape[yn:]
     ctx.set_output("Out", rewrap(ctx.input("X"), jnp.reshape(out, out_shape)))
 

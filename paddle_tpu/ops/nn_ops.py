@@ -256,21 +256,6 @@ def _conv2d(ctx):
     groups = ctx.attr("groups", 1)
     out_dt = amp.out_dtype(x)
     x, w = amp.cast_operands(x, w)
-    from paddle_tpu import pallas as pk
-
-    if (groups == 1 and dilations == (1, 1) and pads[0] == pads[1]
-            and strides[0] == strides[1] and pk.use_conv2d(
-                x.shape[0], x.shape[2], x.shape[3], x.shape[1], w.shape[0],
-                w.shape[2], w.shape[3], strides[0], pads[0])):
-        from paddle_tpu.pallas.conv import conv2d_nhwc
-
-        out = conv2d_nhwc(
-            jnp.transpose(x, (0, 2, 3, 1)),
-            jnp.transpose(w, (2, 3, 1, 0)).astype(x.dtype), pads[0],
-            pk.interpret_mode())
-        ctx.set_output("Output",
-                       jnp.transpose(out, (0, 3, 1, 2)).astype(out_dt))
-        return
     out = lax.conv_general_dilated(
         x,
         w,

@@ -390,16 +390,7 @@ def _lookup_table(ctx):
     squeeze = ids_data.shape[-1] == 1
     flat = ids_data[..., 0] if squeeze else ids_data
     padding_idx = ctx.attr("padding_idx", None)
-    out = None
-    from paddle_tpu import pallas as pk
-
-    if pk.use_gather() and flat.ndim == 1:
-        from paddle_tpu.pallas import embedding as pk_emb
-
-        if pk_emb.fits(flat.shape[0], w.shape[1]):
-            out = pk.pallas_gather_rows(w, flat, interpret=pk.interpret_mode())
-    if out is None:
-        out = jnp.take(w, flat, axis=0)
+    out = jnp.take(w, flat, axis=0)
     if padding_idx is not None and padding_idx >= 0:
         mask = (flat != padding_idx)[..., None]
         out = out * mask.astype(out.dtype)

@@ -1,19 +1,30 @@
 """Hand-written Pallas TPU kernels for hot ops (north star: the
 reference's hand-written CUDA kernels — paddle/operators/math/*.cu,
-paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU).
+paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
+and the one place where the choice between a kernel and its jnp/XLA
+lowering is made.
 
-Policy (PADDLE_TPU_USE_PALLAS, default ``auto``):
+Four kernel families, nine ``pl.pallas_call``s: the fused
+whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
+1), flash attention forward and backward (``flash_attention.py``, 3;
+also run by ring attention's chunks and by the decoder's prefill) and
+ragged paged attention (``decode/attention.py``, 3).
 
-- ``auto``: on a TPU backend, the kernels an earlier setup measured
-  ahead of their XLA lowering dispatch (benchmark/pallas_bench.py is
-  the harness): the fused whole-sequence LSTM at H<=384, the row
-  softmax at cols<=256, flash attention at S>=1024.  The thresholds
-  come from that earlier setup and are NOT re-measured on the locally
-  attached v5e.  The blocked matmul and scalar-prefetch gather lost to
-  XLA there and are never auto-dispatched — they remain as tested
-  reference kernels and custom-epilogue scaffolds.
-- ``1``/``on``: force every kernel on (benchmarking, tests).
-- ``0``/``off``: pure XLA lowerings.
+Mode (``enable()``; a process starts in ``auto``, not interpreted):
+
+- ``auto``: on a TPU backend a kernel dispatches where its ``fits()``
+  accepts the shape and its threshold below holds: the LSTM at
+  ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
+  SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
+  decode kernels (ragged paged attention, prefill flash attention)
+  have no threshold.  All three thresholds come from an earlier setup.
+  Flash attention at S=2048 and the decode kernels are what the LM and
+  generate cells run; the LSTM's and the softmax's thresholds are not
+  re-measured on this chip and no cell runs them.
+- ``on``: every kernel wherever ``fits()`` holds (tests force kernels
+  at toy shapes with ``enable(True, interpret=True)``).
+- ``off``: the jnp/XLA lowerings only (the reference ``chip_smoke.py``
+  compares each kernel against).
 
 Off a TPU the kernels run under ``interpret=True`` for numerics tests.
 On a TPU backend a kernel that dispatches runs compiled or raises —
@@ -25,8 +36,6 @@ compiled | interpret | reference).
 
 from __future__ import annotations
 
-import os
-
 from paddle_tpu.observability import metrics as _metrics
 
 _M_DISPATCH = _metrics.counter(
@@ -34,17 +43,17 @@ _M_DISPATCH = _metrics.counter(
     "Pallas kernel dispatch decisions, counted at trace time, by kernel "
     "and path (compiled | interpret | reference = the jnp/XLA lowering)")
 
-_MODE_ENV = os.environ.get("PADDLE_TPU_USE_PALLAS", "auto").lower()
-_STATE = {
-    "mode": {"1": "on", "on": "on", "0": "off", "off": "off"}.get(
-        _MODE_ENV, "auto"),
-    "interpret": os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1",
-}
+# The auto-mode thresholds, each written here and nowhere else.
+LSTM_MAX_HIDDEN = 384    # earlier setup: XLA won at H>=512
+SOFTMAX_MAX_COLS = 256   # earlier setup: XLA won at cols=512
+FLASH_MIN_SEQ = 1024     # below it XLA's fused unblocked attention won
+
+_STATE = {"mode": "auto", "interpret": False}
 
 
 def enable(flag=True, interpret: bool | None = None):
     """enable(True)='on', enable(False)='off', enable('auto')='auto'.
-    Strings follow the env convention: '1'/'on', '0'/'off', 'auto'."""
+    Strings: '1'/'on'/'true', '0'/'off'/'false', 'auto'."""
     if isinstance(flag, str):
         norm = {"1": "on", "on": "on", "true": "on",
                 "0": "off", "off": "off", "false": "off",
@@ -104,65 +113,29 @@ def policy(fits: bool, auto: bool) -> bool:
 def use_lstm(b: int, h: int) -> bool:
     from paddle_tpu.pallas import lstm as _l
 
-    # earlier setup: XLA won at H>=512
-    return dispatch("lstm", policy(_l.fits(b, h), h <= 384))
+    return dispatch("lstm", policy(_l.fits(b, h), h <= LSTM_MAX_HIDDEN))
 
 
 def use_softmax(rows: int, cols: int) -> bool:
     from paddle_tpu.pallas import softmax as _s
 
-    # earlier setup: XLA won at cols=512
-    return dispatch("softmax", policy(_s.fits(rows, cols), cols <= 256))
+    return dispatch("softmax", policy(_s.fits(rows, cols),
+                                      cols <= SOFTMAX_MAX_COLS))
 
 
 def use_flash_attention(bh: int, s_q: int, s_k: int, d: int) -> bool:
     """Blocked online-softmax attention.  On an earlier setup it beat
-    the jnp softmax(QK^T)V lowering at S>=1024, where the S x S score
-    tensor stops fitting cache-friendly fusions; below that XLA's fused
-    unblocked attention won on kernel count.  Not re-measured."""
+    the jnp softmax(QK^T)V lowering from S=FLASH_MIN_SEQ up, where the
+    S x S score tensor stops fitting cache-friendly fusions; below that
+    XLA's fused unblocked attention won on kernel count.  The threshold
+    is not re-measured."""
     from paddle_tpu.pallas import flash_attention as _f
 
     return dispatch("flash_attention", policy(
-        _f.fits(1, bh, s_q, d) and s_q == s_k, s_q >= 1024))
+        _f.fits(1, bh, s_q, d) and s_q == s_k, s_q >= FLASH_MIN_SEQ))
 
 
-def use_batch_norm(rows: int, cols: int) -> bool:
-    """Fused BN stats+normalize / BN-grad kernels.  On an earlier setup
-    XLA's BN lowering ran at a higher fraction of HBM bandwidth at
-    ResNet shapes (and fuses the statistics into the producing conv's
-    epilogue inside real models), so the kernels are never
-    auto-dispatched — they remain as tested reference kernels."""
-    from paddle_tpu.pallas import batch_norm as _b
-
-    return dispatch("batch_norm", policy(_b.fits(rows, cols), False))
-
-
-def use_conv2d(n: int, h: int, w: int, c: int, o: int, kh: int, kw: int,
-               stride: int, padding: int) -> bool:
-    """Implicit-GEMM conv kernels (pallas/conv.py).  On an earlier setup
-    the XLA conv emitter won at every ResNet-50 hot shape, so the
-    kernels are never auto-dispatched; they remain as verified
-    scaffolds for fused custom-epilogue experiments."""
-    from paddle_tpu.pallas import conv as _c
-
-    return dispatch("conv2d", policy(
-        _c.fits(n, h, w, c, o, kh, kw, stride, padding), False))
-
-
-def use_matmul() -> bool:
-    return dispatch("matmul", policy(True, False))  # lost to XLA: never auto
-
-
-def use_gather() -> bool:
-    return dispatch("gather", policy(True, False))  # lost to XLA: never auto
-
-
-from paddle_tpu.pallas.matmul import matmul as pallas_matmul  # noqa: E402
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402
-from paddle_tpu.pallas.embedding import gather_rows as pallas_gather_rows  # noqa: E402
 from paddle_tpu.pallas.lstm import lstm_seq as pallas_lstm_seq  # noqa: E402
 from paddle_tpu.pallas.flash_attention import (  # noqa: E402
     flash_attention as pallas_flash_attention)
-from paddle_tpu.pallas.batch_norm import (  # noqa: E402
-    batch_norm_train as pallas_batch_norm_train)
-from paddle_tpu.pallas.conv import conv2d_nhwc as pallas_conv2d_nhwc  # noqa: E402

@@ -1,10 +1,9 @@
 """On-device measurement for the autotuner.
 
-Borrowed from ``bench.py`` / ``benchmark/pallas_bench.py``: each timed
-call runs ``space.CHAIN`` chained kernel applications inside one jit so
-the per-dispatch floor amortizes, timings force a host read, and the
-reported number is the *best of N* repetitions (min is the standard
-autotuner statistic — noise only ever adds time).
+Each timed call runs ``space.CHAIN`` chained kernel applications
+inside one jit so the per-dispatch floor amortizes, timings force a
+host read, and the reported number is the *best of N* repetitions (min
+is the standard autotuner statistic — noise only ever adds time).
 
 Configs that fail to compile or lower are recorded as infeasible
 (``Infeasible`` carries the reason), never propagated as a crash: a

@@ -34,7 +34,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-CHAIN = 4  # sequential in-jit applications per timed call (bench.py idiom)
+CHAIN = 4  # sequential in-jit applications per timed call
 
 _POW2_BLOCKS = (64, 128, 256, 512, 1024)
 
@@ -78,35 +78,6 @@ def _chain_accumulate(apply, out_shape, args):
 
     jitted = jax.jit(run)
     return lambda: jitted(*args)
-
-
-# ---------------------------------------------------------------------------
-# matmul: (m, k, n) -> tile (bm, bk, bn)
-# ---------------------------------------------------------------------------
-
-
-def _matmul_configs(shape):
-    from paddle_tpu.pallas import matmul as mm
-
-    m, k, n = shape
-    out = []
-    for bm, bk, bn in itertools.product(_POW2_BLOCKS, repeat=3):
-        if mm.fits(m, k, n, bm, bk, bn):
-            out.append({"bm": bm, "bk": bk, "bn": bn})
-    return out
-
-
-def _matmul_build(shape, dtype, cfg, interpret):
-    from paddle_tpu.pallas import matmul as mm
-
-    m, k, n = shape
-    cfg = cfg or {}
-    x = jax.random.normal(_key(0), (m, k), dtype)
-    y = jax.random.normal(_key(1), (k, n), dtype)
-    return _chain_accumulate(
-        lambda a, b: mm._matmul_impl(a, b, cfg.get("bm"), cfg.get("bk"),
-                                     cfg.get("bn"), interpret),
-        (m, n), (x, y))
 
 
 # ---------------------------------------------------------------------------
@@ -161,66 +132,6 @@ def _flash_build(shape, dtype, cfg, interpret):
             a, b, c, False, scale, interpret,
             blk_q=cfg.get("blk_q"), blk_k=cfg.get("blk_k"))[0],
         (bh, s, d), (q, k, v))
-
-
-# ---------------------------------------------------------------------------
-# conv forward: (n, h, w, c, o, k) -> (bb, fold_kw)
-# ---------------------------------------------------------------------------
-
-
-def _conv_configs(shape):
-    from paddle_tpu.pallas import conv as cv
-
-    n, h, w, c, o, k = shape
-    wp = w + 2 * (k // 2)
-    out = []
-    for bb in _divisors(n):
-        for fold_kw in (False, True):
-            if cv.fwd_block_ok(bb, n, w, wp, c, o, k, k, fold_kw):
-                out.append({"bb": bb, "fold_kw": fold_kw})
-    return out
-
-
-def _conv_build(shape, dtype, cfg, interpret):
-    from paddle_tpu.pallas import conv as cv
-
-    n, h, w, c, o, k = shape
-    cfg = cfg or {}
-    x = jax.random.normal(_key(0), (n, h, w, c), dtype)
-    wts = jax.random.normal(_key(1), (k, k, c, o), dtype) * 0.05
-    return _chain_accumulate(
-        lambda a, b: cv._conv_fwd_impl(
-            a, b, k // 2, interpret, fold_kw=cfg.get("fold_kw"),
-            bb=cfg.get("bb")),
-        (n, h, w, o), (x, wts))
-
-
-# ---------------------------------------------------------------------------
-# batch norm forward: (rows, cols) -> block_rows
-# ---------------------------------------------------------------------------
-
-
-def _bn_configs(shape):
-    from paddle_tpu.pallas import batch_norm as bn
-
-    rows, cols = shape
-    return [{"block_rows": rt} for rt in _divisors(rows)
-            if bn.block_ok(rows, cols, rt)]
-
-
-def _bn_build(shape, dtype, cfg, interpret):
-    from paddle_tpu.pallas import batch_norm as bn
-
-    rows, cols = shape
-    cfg = cfg or {}
-    x = jax.random.normal(_key(0), (rows, cols), dtype)
-    gamma = jnp.ones((cols,), dtype)
-    beta = jnp.zeros((cols,), dtype)
-    return _chain_accumulate(
-        lambda a, g, b: bn._bn_fwd_impl(
-            a, g, b, 1e-5, interpret,
-            block_rows=cfg.get("block_rows"))[0],
-        (rows, cols), (x, gamma, beta))
 
 
 # ---------------------------------------------------------------------------
@@ -298,11 +209,6 @@ def _rpa_build(shape, dtype, cfg, interpret):
 
 
 SPACES: Dict[str, Family] = {
-    "matmul": Family(
-        "matmul", ("m", "k", "n"),
-        default_shapes=[(1024, 1024, 1024), (2048, 2048, 2048)],
-        smoke_shapes=[(256, 512, 256)],
-        configs=_matmul_configs, build=_matmul_build),
     "softmax": Family(
         "softmax", ("rows", "cols"),
         default_shapes=[(8192, 512), (4096, 1024)],
@@ -313,16 +219,6 @@ SPACES: Dict[str, Family] = {
         default_shapes=[(8, 2048, 2048, 128)],
         smoke_shapes=[(2, 256, 256, 8)],
         configs=_flash_configs, build=_flash_build),
-    "conv": Family(
-        "conv", ("n", "h", "w", "c", "o", "k"),
-        default_shapes=[(64, 28, 28, 128, 128, 3)],
-        smoke_shapes=[(16, 8, 8, 64, 64, 3)],
-        configs=_conv_configs, build=_conv_build),
-    "batch_norm": Family(
-        "batch_norm", ("rows", "cols"),
-        default_shapes=[(16384, 256)],
-        smoke_shapes=[(512, 128)],
-        configs=_bn_configs, build=_bn_build),
     "lstm": Family(
         "lstm", ("t", "b", "h"),
         default_shapes=[(64, 64, 512)],

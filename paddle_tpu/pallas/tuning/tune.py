@@ -10,8 +10,8 @@ layer.
 
 Flags (``--k=v`` style, the repo CLI convention):
 
-  --kernel=matmul,softmax   families to tune (default: all)
-  --shapes=1024x1024x1024;2048x2048x2048
+  --kernel=softmax,lstm     families to tune (default: all)
+  --shapes=8192x512;4096x1024
                             per-family shapes (default: the family's
                             ``default_shapes``; dims are 'x'-joined,
                             shapes ';'-separated)

@@ -51,7 +51,7 @@ def _use_flash_chunks(B, H, S, D) -> bool:
     from paddle_tpu.pallas import flash_attention as fa
 
     return pk.dispatch("ring_flash_attention",
-                       pk.policy(fa.fits(B, H, S, D), S >= 1024))
+                       pk.policy(fa.fits(B, H, S, D), S >= pk.FLASH_MIN_SEQ))
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,

@@ -865,7 +865,7 @@ def test_every_pallas_call_has_a_name():
                     else:
                         unnamed.append(f"{path}:{node.lineno}")
     assert not unnamed
-    assert len(names) >= 15 and len(set(names)) == len(names)
+    assert len(names) == 9 and len(set(names)) == len(names)
     assert {"flash_attention_fwd", "flash_attention_bwd_dq",
             "flash_attention_bwd_dkv", "ragged_paged_attention",
             "ragged_paged_attention_blocked",

@@ -1,5 +1,5 @@
-"""Pallas kernel tests (interpret mode on CPU: numerics vs jnp, plus
-the op-lowering integration path with the flag on)."""
+"""Pallas kernel tests (interpret mode on CPU: numerics vs jnp, the
+op-lowering integration path with the flag on, and the dispatch rules)."""
 
 import numpy as np
 import pytest
@@ -9,28 +9,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu import pallas as pk
-from paddle_tpu.pallas.embedding import gather_rows
-from paddle_tpu.pallas.matmul import matmul
 from paddle_tpu.pallas.softmax import softmax
-
-
-def test_matmul_kernel_numerics(rng):
-    x = rng.randn(512, 1024).astype("float32")
-    y = rng.randn(1024, 512).astype("float32")
-    got = np.asarray(matmul(jnp.asarray(x), jnp.asarray(y), interpret=True))
-    np.testing.assert_allclose(got, x @ y, atol=5e-3, rtol=1e-4)
-
-
-def test_matmul_kernel_grad(rng):
-    x = jnp.asarray(rng.randn(256, 512).astype("float32"))
-    y = jnp.asarray(rng.randn(512, 256).astype("float32"))
-
-    def loss(a, b):
-        return jnp.sum(matmul(a, b, 256, 512, 256, True) ** 2)
-
-    gx, gy = jax.grad(loss, argnums=(0, 1))(x, y)
-    want_gx = 2 * (np.asarray(x) @ np.asarray(y)) @ np.asarray(y).T
-    np.testing.assert_allclose(np.asarray(gx), want_gx, atol=1e-1, rtol=1e-3)
 
 
 def test_softmax_kernel_numerics(rng):
@@ -40,17 +19,10 @@ def test_softmax_kernel_numerics(rng):
     np.testing.assert_allclose(got, e / e.sum(-1, keepdims=True), atol=1e-6)
 
 
-def test_gather_kernel(rng):
-    w = rng.randn(1000, 128).astype("float32")
-    ids = rng.randint(0, 1000, 64).astype("int32")
-    got = np.asarray(gather_rows(jnp.asarray(w), jnp.asarray(ids),
-                                 interpret=True))
-    np.testing.assert_allclose(got, w[ids])
-
-
 def test_op_lowering_uses_pallas_and_trains(rng):
-    """fc + softmax through the op path with pallas on (interpret):
-    forward matches flag-off run and gradients still flow."""
+    """fc + softmax through the op path with pallas on (interpret): the
+    softmax kernel runs in the lowered program, the forward matches the
+    flag-off run and gradients still flow."""
     def build_and_run():
         fluid.framework.reset_default_programs()
         from paddle_tpu import executor as em
@@ -76,12 +48,14 @@ def test_op_lowering_uses_pallas_and_trains(rng):
     rng.seed(42)
     pk.enable(False)
     base = build_and_run()
+    before = _dispatch_counts("softmax")["interpret"]
     try:
         pk.enable(True, interpret=True)
         rng.seed(42)
         with_pallas = build_and_run()
     finally:
         pk.enable("auto", interpret=False)
+    assert _dispatch_counts("softmax")["interpret"] > before
     np.testing.assert_allclose(base[0], with_pallas[0], atol=1e-4)
     # loss decreased in both modes (grads flowed through custom vjp)
     assert with_pallas[1] < with_pallas[0]
@@ -160,53 +134,6 @@ def test_lstm_op_pallas_path_matches_scan(rng):
         pk.enable("auto", interpret=False)
     np.testing.assert_allclose(h_pal, h_scan, atol=1e-6)
     np.testing.assert_allclose(c_pal, c_scan, atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# batch_norm kernels (pallas/batch_norm.py)
-# ---------------------------------------------------------------------------
-
-
-def _bn_ref(x, g, b, eps=1e-5):
-    m = x.mean(0)
-    v = (x * x).mean(0) - m * m
-    return (x - m) / np.sqrt(v + eps) * g + b, m, v
-
-
-def test_batch_norm_kernel_fwd(rng):
-    from paddle_tpu.pallas.batch_norm import batch_norm_train
-
-    x = rng.randn(1024, 96).astype("float32")
-    g = (rng.rand(96) + 0.5).astype("float32")
-    b = rng.randn(96).astype("float32")
-    y, m, v = batch_norm_train(jnp.asarray(x), jnp.asarray(g),
-                               jnp.asarray(b), 1e-5, True)
-    want_y, want_m, want_v = _bn_ref(x, g, b)
-    np.testing.assert_allclose(np.asarray(y), want_y, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(m), want_m, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(v), want_v, atol=1e-5)
-
-
-def test_batch_norm_kernel_grads_match_xla(rng):
-    from paddle_tpu.pallas.batch_norm import batch_norm_train
-
-    x = jnp.asarray(rng.randn(512, 64).astype("float32"))
-    g = jnp.asarray((rng.rand(64) + 0.5).astype("float32"))
-    b = jnp.asarray(rng.randn(64).astype("float32"))
-
-    def loss_k(x, g, b):
-        return jnp.sum(jnp.sin(batch_norm_train(x, g, b, 1e-5, True)[0]))
-
-    def loss_r(x, g, b):
-        m = jnp.mean(x, 0)
-        v = jnp.mean(x * x, 0) - m * m
-        return jnp.sum(jnp.sin((x - m) / jnp.sqrt(v + 1e-5) * g + b))
-
-    got = jax.grad(loss_k, (0, 1, 2))(x, g, b)
-    want = jax.grad(loss_r, (0, 1, 2))(x, g, b)
-    for a, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                   atol=5e-4, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -341,96 +268,128 @@ def test_ring_attention_flash_chunks_match_jnp(rng):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_conv_kernel_numerics_and_grads(rng):
-    """Implicit-GEMM conv kernels (pallas/conv.py) vs the XLA conv, fwd
-    + both backwards, interpret mode (incl. the fold_kw variant)."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.pallas.conv import _conv_fwd_impl, conv2d_nhwc
-
-    N, H, W, C, O, K = 16, 8, 8, 64, 64, 3
-    x = jnp.asarray(rng.randn(N, H, W, C).astype(np.float32))
-    w = jnp.asarray((rng.randn(K, K, C, O) * 0.05).astype(np.float32))
-    g = jnp.asarray(rng.randn(N, H, W, O).astype(np.float32))
-
-    def ref(x, w):
-        return jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-    np.testing.assert_allclose(
-        np.asarray(conv2d_nhwc(x, w, 1, True)), np.asarray(ref(x, w)),
-        atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(_conv_fwd_impl(x, w, 1, True, fold_kw=True)),
-        np.asarray(ref(x, w)), atol=2e-5)
-    gx_p, gw_p = jax.grad(
-        lambda x, w: jnp.vdot(conv2d_nhwc(x, w, 1, True), g), (0, 1))(x, w)
-    gx_r, gw_r = jax.grad(
-        lambda x, w: jnp.vdot(ref(x, w), g), (0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(gx_p), np.asarray(gx_r),
-                               atol=2e-4)
-    np.testing.assert_allclose(np.asarray(gw_p), np.asarray(gw_r),
-                               rtol=2e-4, atol=2e-3)
 
 
-def test_conv_bn_stats_fused_kernel(rng):
-    """Round-5 epilogue-fusion experiment: the fused conv+BN-stats
-    kernel's output and batch statistics match XLA conv + direct
-    mean/var (the composite the ResNet step executes)."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.pallas.conv import conv2d_bn_stats_nhwc
-
-    N, H, W, C, O, K = 8, 14, 14, 256, 256, 3
-    x = jnp.asarray(rng.randn(N, H, W, C).astype(np.float32))
-    w = jnp.asarray(rng.randn(K, K, C, O).astype(np.float32) * 0.05)
-    out, mean, var = conv2d_bn_stats_nhwc(x, w, 1, interpret=True)
-    ref = jax.lax.conv_general_dilated(
-        x, w, (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-3, rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(mean),
-                               np.asarray(ref.mean((0, 1, 2))), atol=1e-3)
-    np.testing.assert_allclose(np.asarray(var),
-                               np.asarray(ref.var((0, 1, 2))),
-                               atol=2e-2, rtol=1e-3)
+# ---------------------------------------------------------------------------
+# which path runs: the rules of pallas/__init__.py and the sites that
+# pass their own fits() (parallel/ring_attention.py, decode/attention.py)
+# ---------------------------------------------------------------------------
 
 
-def test_conv2d_op_pallas_path_matches_xla(rng):
-    """conv2d lowering dispatches to the pallas kernel under mode 'on'
-    (interpret) and matches the XLA path."""
-    import paddle_tpu as fluid
-    from paddle_tpu import executor as executor_mod
-    from paddle_tpu import pallas as pk
+def _dispatch_counts(kernel):
+    return {p: pk._M_DISPATCH.value(kernel=kernel, path=p)
+            for p in ("compiled", "interpret", "reference")}
 
-    def run(mode):
-        fluid.framework.reset_default_programs()
-        img = fluid.layers.data(name="img", shape=[64, 8, 8],
-                                dtype="float32")
-        out = fluid.layers.conv2d(input=img, num_filters=64,
-                                  filter_size=3, padding=1, act=None,
-                                  bias_attr=False)
-        exe = fluid.Executor(fluid.CPUPlace())
-        scope = executor_mod.Scope()
-        xs = rng.randn(4, 64, 8, 8).astype("float32")
-        if mode:
-            pk.enable(True, interpret=True)
-        else:
-            pk.enable(False)
-        try:
-            with executor_mod.scope_guard(scope):
-                exe.run(fluid.default_startup_program())
-                (v,) = exe.run(feed={"img": xs}, fetch_list=[out])
-        finally:
-            pk.enable("auto", interpret=False)
-        return np.asarray(v)
 
-    rng_state = rng.get_state()
-    a = run(True)
-    rng.set_state(rng_state)
-    b = run(False)
-    np.testing.assert_allclose(a, b, atol=2e-5)
+def _decide(kernel, shape):
+    """Run one dispatch decision of ``kernel`` at ``shape`` the way its
+    caller does; the decision where the site returns one."""
+    import importlib
+
+    from paddle_tpu.decode import attention as da
+
+    if kernel == "lstm":
+        return pk.use_lstm(*shape)
+    if kernel == "softmax":
+        return pk.use_softmax(*shape)
+    if kernel == "flash_attention":
+        return pk.use_flash_attention(*shape)
+    if kernel == "ring_flash_attention":
+        ra = importlib.import_module("paddle_tpu.parallel.ring_attention")
+        return ra._use_flash_chunks(*shape)
+    if kernel.startswith("ragged_paged_attention"):
+        return da._use_kernel(kernel, *shape)
+    assert kernel == "prefill_flash_attention"
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
+    # a new function each time: eval_shape caches a function's trace
+    jax.eval_shape(lambda q, k, v: da.dense_prefill_attention(q, k, v),
+                   x, x, x)
+    return None
+
+
+# in: a shape the kernel's fits() accepts on the kernel's side of its
+# threshold; out: one fits() accepts on the other side
+_IN = {"lstm": (16, 384), "softmax": (1024, 256),
+       "flash_attention": (8, 1024, 1024, 128),
+       "ring_flash_attention": (1, 8, 1024, 128)}
+_OUT = {"lstm": (16, 512), "softmax": (1024, 512),
+        "flash_attention": (8, 512, 512, 128),
+        "ring_flash_attention": (1, 8, 512, 128)}
+
+_POLICY_CASES = (
+    # auto on a TPU backend: each threshold, both sides
+    [(k, _IN[k], "auto", True, False, "compiled") for k in _IN]
+    + [(k, _OUT[k], "auto", True, False, "reference") for k in _OUT]
+    # auto off a TPU: the reference, unless interpret mode is set, and
+    # then the threshold decides as it does on the chip
+    + [(k, _IN[k], "auto", False, False, "reference") for k in _IN]
+    + [(k, _IN[k], "auto", False, True, "interpret") for k in _IN]
+    + [(k, _OUT[k], "auto", False, True, "reference") for k in _OUT]
+    # on: fits() alone decides; off: always the reference
+    + [(k, _OUT[k], "on", True, False, "compiled") for k in _OUT]
+    + [("lstm", (12, 512), "on", True, False, "reference"),
+       ("softmax", (1024, 200), "on", True, False, "reference"),
+       ("flash_attention", (8, 512, 256, 128), "on", True, False,
+        "reference"),
+       ("flash_attention", (8, 512, 512, 128), "on", False, True,
+        "interpret")]
+    + [(k, _IN[k], "off", True, False, "reference") for k in _IN]
+    # the decode kernels have no threshold: wherever fits() holds
+    + [("ragged_paged_attention", (16, 16, 128), "auto", True, False,
+        "compiled"),
+       ("ragged_paged_attention_chunk", (16, 16, 128), "auto", False, True,
+        "interpret"),
+       ("ragged_paged_attention", (16, 16, 128), "auto", False, False,
+        "reference"),
+       ("ragged_paged_attention", (12, 16, 128), "on", True, False,
+        "reference"),
+       ("ragged_paged_attention_chunk", (16, 16, 128), "off", True, False,
+        "reference"),
+       ("prefill_flash_attention", (128, 2, 8), "auto", True, False,
+        "compiled"),
+       ("prefill_flash_attention", (128, 2, 8), "auto", False, True,
+        "interpret"),
+       ("prefill_flash_attention", (64, 2, 8), "auto", True, False,
+        "reference"),
+       ("prefill_flash_attention", (128, 2, 8), "off", True, False,
+        "reference")])
+
+
+@pytest.fixture
+def pallas_state():
+    saved = dict(pk._STATE)
+    yield
+    pk._STATE.update(saved)
+
+
+@pytest.mark.parametrize(
+    "kernel,shape,mode,on_tpu,interpret,path", _POLICY_CASES,
+    ids=[f"{k}-{'x'.join(map(str, s))}-{m}-{'tpu' if t else 'cpu'}"
+         f"{'-interpret' if i else ''}" for k, s, m, t, i, _ in _POLICY_CASES])
+def test_kernel_policy(monkeypatch, pallas_state, kernel, shape, mode,
+                       on_tpu, interpret, path):
+    """(kernel, shape, mode, backend, interpret) -> the path that runs,
+    and the ``pallas_dispatch_total{kernel, path}`` label it counts."""
+    monkeypatch.setattr(pk, "tpu_backend", lambda: on_tpu)
+    pk.enable(mode, interpret=interpret)
+    before = _dispatch_counts(kernel)
+    use = _decide(kernel, shape)
+    after = _dispatch_counts(kernel)
+    moved = {p: after[p] - before[p] for p in after if after[p] != before[p]}
+    assert moved == {path: 1}
+    assert use in (None, path != "reference")
+
+
+def test_flash_threshold_is_read_from_one_place(monkeypatch, pallas_state):
+    """Moving ``pallas.FLASH_MIN_SEQ`` moves the attention op's choice
+    (``use_flash_attention``, all ``ops/attention_ops.py`` asks) and ring
+    attention's together."""
+    monkeypatch.setattr(pk, "tpu_backend", lambda: True)
+    pk.enable("auto", interpret=False)
+    op, ring = _OUT["flash_attention"], _OUT["ring_flash_attention"]
+    assert pk.FLASH_MIN_SEQ == 1024
+    assert not _decide("flash_attention", op)
+    assert not _decide("ring_flash_attention", ring)
+    monkeypatch.setattr(pk, "FLASH_MIN_SEQ", 512)
+    assert _decide("flash_attention", op)
+    assert _decide("ring_flash_attention", ring)
