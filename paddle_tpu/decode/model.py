@@ -23,7 +23,10 @@ overwrites them) or, past its pages, in the reserved null page 0.
 Every decode step appends one K/V row per sequence into its pages and
 attends over its page table.  The decode step is ONE jitted
 fixed-shape function of ``(pools, page_tables, lens, tokens)`` — batch
-composition churn never re-traces.
+composition churn never re-traces.  It also chooses each slot's greedy
+token on the device: the host is handed ``(S,)`` ids, and the
+vocabulary-wide logits stay on the device unless somebody reads them
+(``StepLogits``).
 
 Every program here takes both pools DONATED and hands back the same
 buffers: a layer's new rows are one scatter into the pool seen flat
@@ -177,6 +180,31 @@ def _dense_blocks(block, params, tokens, heads, live):
         x, report = block.mlp(lp, x, live)
         reports.append(report)
     return x, jnp.stack(ks), jnp.stack(vs), _stack_reports(reports)
+
+
+class StepLogits:
+    """What a decode or verify step hands the host in place of its
+    logits: ``ids``, the greedy choice the step made of them on the
+    device (int32, the logits' shape less the vocabulary, on the host;
+    the first index on a tie, as ``np.argmax``), and the logits
+    themselves, left on the device until somebody indexes or converts
+    this — then the whole array comes to the host (jax keeps that
+    copy, so it comes once)."""
+
+    __slots__ = ("ids", "_dev")
+
+    def __init__(self, dev, ids):
+        self.ids, self._dev = ids, dev
+
+    @property
+    def shape(self):
+        return self._dev.shape
+
+    def __array__(self, dtype=None, copy=None):
+        return self._dev.__array__(dtype, copy=copy)
+
+    def __getitem__(self, idx):
+        return np.asarray(self)[idx]
 
 
 class PagedDecoderLM:
@@ -345,34 +373,44 @@ class PagedDecoderLM:
         """Speculative verification: feed ``k`` tokens per slot in ONE
         step (tokens (S, k)), appending all k K/V rows and attending
         with per-row causal offsets.  Returns logits (S, k, V) — row j
-        scores the token *after* tokens[:, j].  Rollback of rejected
+        scores the token *after* tokens[:, j] — as ``StepLogits``,
+        whose ``ids`` are (S, k).  Rollback of rejected
         rows is the caller's business: stale K/V past ``lens`` is
         unreachable through the length mask."""
         return self._step(_verify_step, tokens, tables, lens)
 
     def decode(self, tokens: np.ndarray, states, tables: np.ndarray,
                lens: np.ndarray):
+        """-> (logits (S, V) as ``StepLogits``, whose ``ids`` are (S,),
+        new states)."""
         return self._step(_decode_step, tokens[:, 0], tables, lens)
 
     def _step(self, jitted, tokens, tables, lens):
         """One jitted step over every slot, in the three parts a tick
         pays for on the host: the upload of tables, lengths and tokens,
         the dispatch (returns before the device is done), and the wait
-        for the device plus the logits' copy to the host."""
+        for the device plus the copy of what the host always wants of
+        it: the ids the step chose and the block's report.  The logits
+        stay where they are (``StepLogits``)."""
         with span("decode.upload"):
             tables = jnp.asarray(tables.astype(np.int32))
             lens = jnp.asarray(lens.astype(np.int32))
             tokens = jnp.asarray(tokens.astype(np.int32))
         with self._donating():
             with span("decode.dispatch"):
-                logits, self.k_pool, self.v_pool, report = jitted(
+                logits, self.k_pool, self.v_pool, report, ids = jitted(
                     self.params, self.k_pool, self.v_pool, tables, lens,
                     tokens, heads=self.heads, page_size=self.page_size,
                     block=self.block)
+                # queued behind the step, so the wait below ends with
+                # both on the host and asks the device for nothing more
+                ids.copy_to_host_async()
+                if report is not None:
+                    report.copy_to_host_async()
             with span("decode.logits_to_host"):
-                logits = np.asarray(logits)
+                ids = np.asarray(ids)
                 self._observe("decode", report)
-                return logits, []
+                return StepLogits(logits, ids), []
 
 
 class TinyDecoderLM(PagedDecoderLM):
@@ -468,13 +506,20 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
     return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
 
+def _greedy_ids(logits):
+    """The greedy choice over the last axis, int32: the first index on
+    a tie, as ``np.argmax`` of the same numbers on the host."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
                    donate_argnums=(1, 2))
 def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
                  heads, page_size, block=GPT2):
     """k tokens for every slot in one step (the speculative verify):
     append all k K/V rows, attend with per-row causal offsets through
-    the chunked kernel.  Fixed-shape per (S, k) — compiled once."""
+    the chunked kernel.  Fixed-shape per (S, k) — compiled once.
+    Outputs as ``_decode_step``'s, the ids (S, k)."""
     S, T = tokens.shape
     H, dh = k_pool.shape[3:]
     pos = lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (S, T)
@@ -493,7 +538,9 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
         x = block.attn_out(lp, x, a.reshape(S, T, -1))
         x, report = block.mlp(lp, x, live)
         reports.append(report)
-    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
+    logits = block.head(params, x)
+    return (logits, k_pool, v_pool, _stack_reports(reports),
+            _greedy_ids(logits))
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
@@ -501,7 +548,9 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
 def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
                  heads, page_size, block=GPT2):
     """One token for every slot: append K/V into pages, attend over the
-    page tables.  Fixed-shape in every argument — compiled once."""
+    page tables.  Fixed-shape in every argument — compiled once.
+    -> (logits (S, V), both pools, the layers' reports, the greedy
+    choice of every slot (S,) int32, inactive slots included)."""
     S = tokens.shape[0]
     x = block.embed(params, tokens, lens)                   # (S, d)
     # flat pool row each slot's new KV lands in: its page at
@@ -520,4 +569,6 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
         x = block.attn_out(lp, x, a.reshape(S, -1))
         x, report = block.mlp(lp, x, live)
         reports.append(report)
-    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
+    logits = block.head(params, x)
+    return (logits, k_pool, v_pool, _stack_reports(reports),
+            _greedy_ids(logits))

@@ -66,6 +66,12 @@ _M_TTFT = _metrics.histogram(
     "decode_ttft_seconds", "submit-to-first-token latency per sequence")
 _M_REQ_SEC = _metrics.histogram(
     "decode_request_seconds", "submit-to-finish latency per sequence")
+_M_CHOICE = _metrics.counter(
+    "decode_token_choice_total",
+    "tokens chosen at decode and verify steps, one per live slot per "
+    "step (one per group for a beam), by where: `device` = taken from "
+    "the ids the step chose, `host` = chosen on the host from the "
+    "step's logits (sampling, beams, a model that hands out no ids)")
 _M_STEP_FAIL = _metrics.counter(
     "decode_step_failures_total",
     "decode/verify dispatches that raised, and prefills or page copies "
@@ -276,6 +282,12 @@ class DecodeSession:
       (affects sampling/beam log-prob handling)
     - ``prefill_bucket(prompt_len) -> int``: rows the full-prompt
       prefill pads to; it and the pad go on the ``decode.prefill`` span
+    - logits from ``decode`` / ``verify_chunk`` that carry ``ids``
+      (``model.StepLogits``: the step's own greedy choice, on the
+      host, the logits still on the device): a greedy slot's token is
+      taken from them, and the logits are read only when a live slot
+      samples or belongs to a beam; logits without ``ids`` are chosen
+      from on the host, slot by slot
     """
 
     def __init__(self, model, max_slots: int = 8,
@@ -421,7 +433,6 @@ class DecodeSession:
         _M_STEP_SEC.observe(time.perf_counter() - t0)
         _M_STEPS.inc()
         _M_SLOT_STEPS.inc(len(active_idx))
-        logits = np.asarray(logits)
         for i, buf in enumerate(self._states):
             buf[...] = np.asarray(new_states[i])
         if self.model.grows_kv:
@@ -434,10 +445,19 @@ class DecodeSession:
         _M_ACTIVE.set(self.active)
         return len(active_idx)
 
-    def _sample(self, active_idx: List[int], logits: np.ndarray) -> None:
-        """The per-slot end of a tick: expiry, the next token (argmax or
-        the slot's sampler) on the host, emission, eviction."""
+    def _sample(self, active_idx: List[int], logits) -> None:
+        """The per-slot end of a tick: expiry, the next token, emission,
+        eviction.  A greedy slot takes the token the step chose on the
+        device where the logits carry ``ids``; a slot that samples and
+        a beam group index the logits, which brings them to the host,
+        and choose there (as every slot does without ``ids``)."""
         now = time.monotonic()
+        ids = getattr(logits, "ids", None)
+        if ids is None:
+            logits = np.asarray(logits)
+        else:
+            ids = ids.tolist()
+        on_device = on_host = 0
         groups_seen = set()
         for i in active_idx:
             slot = self._slots[i]
@@ -454,13 +474,23 @@ class DecodeSession:
                     continue
                 self._group_select(
                     g, logits[np.asarray(g.slot_idx, np.intp)])
+                on_host += 1
                 continue
             if slot.req.expired(now):
                 self._evict(i, "deadline",
                             TimeoutError("generation deadline expired"))
                 continue
-            tok = self._choose(slot, logits[i])
+            if ids is not None and not slot.req.temperature:
+                tok = ids[i]
+                on_device += 1
+            else:
+                tok = self._choose(slot, logits[i])
+                on_host += 1
             self._emit_token(i, tok)
+        if on_device:
+            _M_CHOICE.inc(on_device, where="device")
+        if on_host:
+            _M_CHOICE.inc(on_host, where="host")
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive the session until every queued request finishes (the
@@ -660,19 +690,24 @@ class DecodeSession:
         _M_STEP_SEC.observe(time.perf_counter() - t0)
         _M_STEPS.inc()
         _M_SLOT_STEPS.inc(len(active_idx))
-        logits = np.asarray(logits)                     # (S, k, V)
+        ids = getattr(logits, "ids", None)              # (S, k)
+        if ids is None:
+            logits = np.asarray(logits)                 # (S, k, V)
         for i, buf in enumerate(self._states):
             if new_states:
                 buf[...] = np.asarray(new_states[i])
         with span("decode.sample"):
             now = time.monotonic()
+            chosen = 0
             for i in active_idx:
                 slot = self._slots[i]
                 if slot.req.expired(now):
                     self._evict(i, "deadline", TimeoutError(
                         "generation deadline expired"))
                     continue
-                target = np.argmax(logits[i], axis=-1)      # (k,)
+                target = (np.argmax(logits[i], axis=-1) if ids is None
+                          else ids[i])                      # (k,)
+                chosen += 1
                 emitted, accepted = accept_greedy(drafts[i], target)
                 observe_chunk(k - 1, accepted, k)
                 # rows of [prev] + accepted drafts are real; later rows
@@ -683,6 +718,9 @@ class DecodeSession:
                     self._emit_token(i, tok)
                     if self._slots[i] is not slot:          # eos / budget
                         break
+            if chosen:
+                _M_CHOICE.inc(chosen,
+                              where="host" if ids is None else "device")
         _M_ACTIVE.set(self.active)
         return len(active_idx)
 

@@ -65,6 +65,15 @@ def _planned_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _assert_step_outputs(compiled, slots, vocab):
+    """The decode step hands out float32 logits (S, V) first and the
+    greedy choice it made of them last: int32 (S,), what the host reads
+    every tick in the logits' place."""
+    out = jax.tree.leaves(compiled.out_info)
+    assert (out[0].shape, out[0].dtype) == ((slots, vocab), jnp.float32)
+    assert (out[-1].shape, out[-1].dtype) == ((slots,), jnp.int32)
+
+
 def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
                            undonated_plan):
     """A program ``(params, k_pool, v_pool, ...) -> (logits, k_pool,
@@ -219,6 +228,9 @@ def test_cerebras_decode_step_writes_and_reads_its_pools_in_place(
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, P), jnp.int32), sds((S,), jnp.int32),
         sds((S,), jnp.int32), heads=H, page_size=pg).compile()
+    _assert_step_outputs(compiled, S, 50257)
+    planned = _planned_bytes(compiled)
+    assert planned == 9_314_695_168, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 4,
         CEREBRAS_STEP_PLAN_UNDONATED)
@@ -324,8 +336,9 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
         params, pool, pool, sds((S, P), jnp.int32), sds((S,), jnp.int32),
         sds((S,), jnp.int32), heads=shape[3], page_size=g["page_size"],
         block=block).compile()
+    _assert_step_outputs(compiled, S, cfg["vocab_size"])
     planned = _planned_bytes(compiled)
-    assert planned == 10_367_797_760, planned
+    assert planned == 10_367_830_528, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 2,
         OLMOE_STEP_PLAN_UNDONATED)

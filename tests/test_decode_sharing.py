@@ -475,3 +475,245 @@ def test_sampling_seed_determinism():
 
     assert run(42) == run(42)               # same seed, same tokens
     assert m.allocator.pages_in_use == 0
+
+
+# ---------------------------------------------------------------------------
+# the token choice on the device (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+BLOCKS = ["gpt2", "olmoe"]
+
+
+def _choice_lm(block, seed=3):
+    """A toy model over the paged skeleton with the GPT-2 block or a
+    tiny OLMoE block (float32, so that a session equals the dense
+    oracle token for token)."""
+    if block == "gpt2":
+        return _mk(seed=seed)
+    from paddle_tpu.models.olmoe import OlmoeLM
+
+    return OlmoeLM(seed=seed, vocab=61, d_model=32, num_heads=4,
+                   num_layers=2, num_experts=4, experts_per_tok=2,
+                   expert_width=16, max_len=64, num_pages=64, page_size=8,
+                   pages_per_seq=8, dtype="float32", eos_id=0)
+
+
+def _head_columns(lm):
+    """(name of the head's weight, the axis its vocabulary lies on)."""
+    return ("emb", 0) if "lm_head" not in lm.params else ("lm_head", 1)
+
+
+def _run_step(lm, op, slots=4, live=(1, 3)):
+    """Seat a prompt in each ``live`` slot and run one decode step or
+    one 3-token verify chunk over ``slots`` slots -> (the step's logits
+    as the model hands them out, a function that runs the same step
+    again)."""
+    tables = np.zeros((slots, lm.pages_per_seq), np.int32)
+    lens = np.ones((slots,), np.int64)
+    for n, s in enumerate(live):
+        prompt = PROMPT[:5 + 3 * n]
+        pages = lm.allocator.alloc(lm.context_pages(prompt, 4))
+        ctx, _, _ = lm.prefill(prompt, pages)
+        tables[s], lens[s] = lm.pool_table(pages), ctx
+    width = 1 if op == "decode" else 3
+    tokens = np.random.RandomState(0).randint(1, lm.vocab, (slots, width))
+
+    def step():
+        call = lm.decode if op == "decode" else lm.verify_chunk
+        return call(tokens.astype(np.int64), [], tables, lens)[0]
+
+    return step
+
+
+@pytest.mark.parametrize("op", ["decode", "verify"])
+@pytest.mark.parametrize("block", BLOCKS)
+def test_step_ids_are_the_argmax_of_the_same_steps_logits(block, op):
+    """What the step chose on the device is what ``np.argmax`` chooses
+    on the host from the logits of the same step, in every slot, the
+    inactive ones (null table) included."""
+    lm = _choice_lm(block)
+    logits = _run_step(lm, op)()
+    want = (4,) if op == "decode" else (4, 3)
+    assert logits.ids.dtype == np.int32 and logits.ids.shape == want
+    assert isinstance(logits.ids, np.ndarray)
+    assert logits.shape == want + (lm.vocab,)
+    host = np.asarray(logits)
+    assert host.dtype == np.float32 and host.shape == logits.shape
+    np.testing.assert_array_equal(logits.ids, np.argmax(host, axis=-1))
+    np.testing.assert_array_equal(logits[1], host[1])
+
+
+@pytest.mark.parametrize("op", ["decode", "verify"])
+@pytest.mark.parametrize("block", BLOCKS)
+def test_step_tie_goes_to_the_lower_index(block, op):
+    """Two vocabulary entries with the same head column score the same
+    to the bit: the step's choice is the lower of the two, as
+    ``np.argmax``'s, whichever of them held the maximum before."""
+    import jax.numpy as jnp
+
+    lm = _choice_lm(block)
+    step = _run_step(lm, op)
+    first = step().ids
+    name, axis = _head_columns(lm)
+    head = np.array(lm.params[name])
+    won = int(first[1] if op == "decode" else first[1, 0])
+    twins = [t for t in (won - 2, won + 2) if 0 <= t < lm.vocab]
+    for twin in twins:
+        w = head.copy()
+        if axis == 0:
+            w[twin] = w[won]
+        else:
+            w[:, twin] = w[:, won]
+        lm.params = {**lm.params, name: jnp.asarray(w)}
+        logits = step()
+        host = np.asarray(logits)
+        row = host[1] if op == "decode" else host[1, 0]
+        assert row[twin] == row[won] == row.max()
+        got = int(logits.ids[1] if op == "decode" else logits.ids[1, 0])
+        assert got == min(twin, won)
+        np.testing.assert_array_equal(logits.ids, np.argmax(host, axis=-1))
+
+
+def _choice_counts():
+    from paddle_tpu.decode.session import _M_CHOICE
+
+    return {w: _M_CHOICE.value(where=w) for w in ("device", "host")}
+
+
+def _count_host_reads(monkeypatch):
+    """-> a list that grows by one each time a step's logits are
+    brought to the host."""
+    from paddle_tpu.decode.model import StepLogits
+
+    reads, real = [], StepLogits.__array__
+
+    def spy(self, *a, **kw):
+        reads.append(self.shape)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(StepLogits, "__array__", spy)
+    return reads
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("block", BLOCKS)
+def test_greedy_session_takes_every_token_from_the_device(
+        block, spec, monkeypatch):
+    """A session whose requests are all greedy streams the dense
+    oracle's tokens, counts every choice of a step as the device's and
+    never brings a step's logits to the host."""
+    from paddle_tpu.decode.session import DecodeRequest, DecodeSession
+    from paddle_tpu.decode.spec import NgramDraft
+
+    lm = _choice_lm(block, seed=5)
+    prompts = [PROMPT, [2, 3, 4, 5, 6], [9, 8, 7, 1, 2, 3, 4]]
+    oracles = [lm.dense_greedy(p, 10) for p in prompts]
+    reads = _count_host_reads(monkeypatch)
+    before = _choice_counts()
+    kw = dict(spec_draft=NgramDraft(), spec_k=4) if spec else {}
+    sess = DecodeSession(lm, max_slots=4, **kw)
+    reqs = [sess.submit(DecodeRequest(list(p), max_new_tokens=10))
+            for p in prompts]
+    sess.run(500)
+    assert [r.result(5) for r in reqs] == oracles
+    after = _choice_counts()
+    assert after["host"] == before["host"]
+    chosen = after["device"] - before["device"]
+    # the first token of each request is the prefill's
+    step_tokens = sum(len(o) - 1 for o in oracles)
+    if spec:        # one choice a verified chunk, which emits 1..k tokens
+        assert 0 < chosen <= step_tokens
+    else:
+        assert chosen == step_tokens
+    assert reads == []
+    assert lm.allocator.pages_in_use == 0
+
+
+def _host_path(lm):
+    """``lm`` behind a wrapper that hands the session its steps' logits
+    as plain host arrays (shifted by nothing), without the device's
+    choice: the parent's path, in which every slot chooses on the
+    host."""
+    return _ShiftedLogits(lm, shift=0.0)
+
+
+def _mixed_batch(model):
+    """Two greedy requests, one seeded sampling request and one beam
+    group through one session of 6 slots -> what each request got."""
+    from paddle_tpu.decode.session import (BeamRequest, DecodeRequest,
+                                           DecodeSession)
+
+    sess = DecodeSession(model, max_slots=6)
+    reqs = [
+        DecodeRequest(list(PROMPT), max_new_tokens=9),
+        DecodeRequest(list(PROMPT[:6]), max_new_tokens=9, temperature=0.8,
+                      top_k=7, seed=1234),
+        BeamRequest(list(PROMPT[2:9]), beam_size=3, max_new_tokens=7),
+        DecodeRequest([2, 3, 4, 5, 6], max_new_tokens=9),
+    ]
+    for r in reqs:
+        sess.submit(r)
+    sess.run(500)
+    for r in reqs:
+        r.wait(5)
+        assert r.error is None, r.error
+    return [list(r.tokens) for r in reqs], reqs[2].beams
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+def test_mixed_batch_gives_each_request_the_host_paths_tokens(
+        block, monkeypatch):
+    """Greedy slots beside a sampling slot and a beam group: the greedy
+    ones take the device's ids and equal the dense oracle; the sampling
+    slot and the beam group read the logits and get, for the same seed,
+    exactly what ``model.decode`` + the host path give them."""
+    lm = _choice_lm(block, seed=7)
+    want, want_beams = _mixed_batch(_host_path(lm))
+    assert lm.allocator.pages_in_use == 0
+    reads = _count_host_reads(monkeypatch)
+    before = _choice_counts()
+    got, beams = _mixed_batch(lm)
+    after = _choice_counts()
+    assert got == want
+    assert beams == want_beams and len(beams) >= 1
+    assert got[0] == lm.dense_greedy(PROMPT, 9)
+    assert got[3] == lm.dense_greedy([2, 3, 4, 5, 6], 9)
+    # greedy: every token but the prefill's; sampling: likewise, on the
+    # host; the beam group: one choice a step, on the host
+    assert after["device"] - before["device"] == 8 + 8
+    assert after["host"] - before["host"] >= 8 + 1
+    assert reads and all(shape == (6, lm.vocab) for shape in reads)
+    assert lm.allocator.pages_in_use == 0
+
+
+def test_logits_without_ids_are_chosen_from_on_the_host():
+    """A model whose ``decode`` hands out bare logits is served as
+    before: every choice is the host's."""
+    from paddle_tpu.decode.session import DecodeRequest, DecodeSession
+
+    lm = _mk(seed=5)
+    want = lm.dense_greedy(PROMPT, 8)
+    before = _choice_counts()
+    sess = DecodeSession(_host_path(lm), max_slots=2)
+    req = sess.submit(DecodeRequest(list(PROMPT), max_new_tokens=8))
+    sess.run(300)
+    assert req.result(5) == want
+    after = _choice_counts()
+    assert after["device"] == before["device"]
+    assert after["host"] - before["host"] == 7
+
+
+def test_step_logits_come_to_the_host_once_and_only_when_read():
+    """``StepLogits``: the ids are on the host from the start; the
+    logits are a device array until indexed or converted, and what
+    comes then is the array ``np.asarray`` gives of it."""
+    import jax
+
+    lm = _mk(seed=2)
+    logits = _run_step(lm, "decode")()
+    assert isinstance(logits._dev, jax.Array)
+    host = np.asarray(logits)
+    assert host.dtype == np.float32
+    assert np.asarray(logits, np.float64).dtype == np.float64
+    np.testing.assert_array_equal(logits[np.asarray([3, 1])], host[[3, 1]])
+    np.testing.assert_array_equal(np.argmax(logits[1]), logits.ids[1])
