@@ -1,7 +1,8 @@
 """GenerationEngine: the serving front over a DecodeSession.
 
-One background stepper thread drives the session's admit->decode->evict
-tick whenever work exists; HTTP handler threads submit requests and
+One background stepper thread drives the session's tick whenever work
+exists (collect -> decide -> sweep, admit, copy-on-write -> dispatch ->
+deliver: ``session.py``); HTTP handler threads submit requests and
 stream tokens through per-request callbacks.  Admission refusals
 (``AdmissionRefused``: pool can never fit the request, or the wait
 queue is full) surface to the caller — serving maps them to 503, and a
@@ -161,6 +162,13 @@ class GenerationEngine:
     # -- lifecycle ----------------------------------------------------------
 
     def _stepper(self) -> None:
+        """The one thread that calls ``session.step()``.  Over a model
+        that steps in two halves a tick dispatches step k+1 before it
+        delivers step k's tokens, so the handler threads those tokens
+        wake take the interpreter lock while the device computes, and
+        this thread next lets go of it in the following tick's collect,
+        waiting for that step.  Between ticks a step may be in flight:
+        the session is not idle then, and ``fail_all`` drops it."""
         while not self._stop.is_set():
             if self.session.idle():
                 # the device is idle because no request is there

@@ -26,7 +26,11 @@ fixed-shape function of ``(pools, page_tables, lens, tokens)`` — batch
 composition churn never re-traces.  It also chooses each slot's greedy
 token on the device: the host is handed ``(S,)`` ids, and the
 vocabulary-wide logits stay on the device unless somebody reads them
-(``StepLogits``).
+(``StepLogits``).  And it hands on what the next step reads: its ids
+are the next tokens, ``lens + live`` the next lengths, and the tables
+it was given stay where they are, so a step can be entered with what
+the device already holds (``StepInFlight.next``) and is called in two
+halves, ``step_dispatch`` and ``step_collect``.
 
 Every program here takes both pools DONATED and hands back the same
 buffers: a layer's new rows are one scatter into the pool seen flat
@@ -207,6 +211,23 @@ class StepLogits:
         return np.asarray(self)[idx]
 
 
+class StepInFlight:
+    """A step between its two halves: dispatched, its results not yet
+    waited for.  ``next`` maps ``tokens`` / ``tables`` / ``lens`` to
+    what the device holds of them for the step after this one (a decode
+    step's ids, the tables it was given, ``lens + live``); any of them
+    can be handed to the next ``step_dispatch`` in place of the host's
+    array, which then uploads nothing for it.  None after a verify
+    step, whose accepted counts only the host knows."""
+
+    __slots__ = ("next", "_pools_in", "_logits", "_ids", "_report")
+
+    def __init__(self, pools_in, logits, ids, report, next_inputs):
+        self._pools_in = pools_in
+        self._logits, self._ids, self._report = logits, ids, report
+        self.next = next_inputs
+
+
 class PagedDecoderLM:
     """The paged skeleton of a decoder-only LM behind ``/generate``:
     page allocator and tables, the bucketed prefill, suffix prefill,
@@ -239,14 +260,17 @@ class PagedDecoderLM:
         self.v_pool = jnp.zeros(shape, dtype)
 
     @contextlib.contextmanager
-    def _donating(self):
+    def _donating(self, pools_in=None):
         """Round a call of a program that is given both pools DONATED
         and the wait for its results (a failure on the device shows at
         the wait, not at the dispatch).  A call that failed before it
         consumed the pools leaves them as they were.  One that failed
         after has lost every page's rows: both pools are made anew,
-        and ``PoolsLost`` tells the session."""
-        k_in, v_in = self.k_pool, self.v_pool
+        and ``PoolsLost`` tells the session.  A step's wait comes in a
+        later call than its dispatch (``step_collect``) and is rounded
+        again, with the pools the dispatch was handed (``pools_in``);
+        no program runs between the two."""
+        k_in, v_in = pools_in or (self.k_pool, self.v_pool)
         try:
             yield
         except BaseException as exc:
@@ -377,40 +401,65 @@ class PagedDecoderLM:
         whose ``ids`` are (S, k).  Rollback of rejected
         rows is the caller's business: stale K/V past ``lens`` is
         unreachable through the length mask."""
-        return self._step(_verify_step, tokens, tables, lens)
+        return self.step_collect(
+            self._dispatch(_verify_step, tokens, tables, lens))
 
     def decode(self, tokens: np.ndarray, states, tables: np.ndarray,
                lens: np.ndarray):
         """-> (logits (S, V) as ``StepLogits``, whose ``ids`` are (S,),
-        new states)."""
-        return self._step(_decode_step, tokens[:, 0], tables, lens)
+        new states).  The one-shot call: the two halves back to back,
+        with everything uploaded."""
+        return self.step_collect(
+            self.step_dispatch(tokens, states, tables, lens))
 
-    def _step(self, jitted, tokens, tables, lens):
-        """One jitted step over every slot, in the three parts a tick
-        pays for on the host: the upload of tables, lengths and tokens,
-        the dispatch (returns before the device is done), and the wait
-        for the device plus the copy of what the host always wants of
-        it: the ids the step chose and the block's report.  The logits
-        stay where they are (``StepLogits``)."""
-        with span("decode.upload"):
-            tables = jnp.asarray(tables.astype(np.int32))
-            lens = jnp.asarray(lens.astype(np.int32))
-            tokens = jnp.asarray(tokens.astype(np.int32))
-        with self._donating():
+    def step_dispatch(self, tokens, states, tables, lens) -> StepInFlight:
+        """The first half of ``decode``: upload what the host hands in
+        and dispatch the step; returns before the device is done.  Each
+        of ``tokens`` (S, 1), ``tables`` and ``lens`` is the host's
+        numpy array, or what the previous step's ``next`` holds of it
+        on the device: a steady tick hands in all three of those and
+        uploads nothing.  No other program of this model may be called
+        until ``step_collect`` has been."""
+        if isinstance(tokens, np.ndarray):
+            tokens = tokens[:, 0]
+        return self._dispatch(_decode_step, tokens, tables, lens)
+
+    def step_collect(self, step: StepInFlight):
+        """The second half: wait for the device and for the copy of
+        what the host always wants of a step, the ids it chose and the
+        block's report -> (``StepLogits``, new states).  The logits
+        stay where they are.  A failure on the device shows here."""
+        with self._donating(step._pools_in):
+            with span("decode.logits_to_host"):
+                ids = np.asarray(step._ids)
+                self._observe("decode", step._report)
+                return StepLogits(step._logits, ids), []
+
+    def _dispatch(self, jitted, tokens, tables, lens) -> StepInFlight:
+        """Dispatch one jitted step over every slot.  What comes as a
+        numpy array is uploaded first (``decode.upload``, written only
+        when something is)."""
+        inputs = (tables, lens, tokens)
+        if any(isinstance(a, np.ndarray) for a in inputs):
+            with span("decode.upload"):
+                tables, lens, tokens = (
+                    jnp.asarray(a.astype(np.int32))
+                    if isinstance(a, np.ndarray) else a for a in inputs)
+        pools_in = (self.k_pool, self.v_pool)
+        with self._donating(pools_in):
             with span("decode.dispatch"):
-                logits, self.k_pool, self.v_pool, report, ids = jitted(
-                    self.params, self.k_pool, self.v_pool, tables, lens,
-                    tokens, heads=self.heads, page_size=self.page_size,
+                logits, self.k_pool, self.v_pool, report, ids, *more = jitted(
+                    self.params, *pools_in, tables, lens, tokens,
+                    heads=self.heads, page_size=self.page_size,
                     block=self.block)
-                # queued behind the step, so the wait below ends with
+                # queued behind the step, so the collect's wait ends with
                 # both on the host and asks the device for nothing more
                 ids.copy_to_host_async()
                 if report is not None:
                     report.copy_to_host_async()
-            with span("decode.logits_to_host"):
-                ids = np.asarray(ids)
-                self._observe("decode", report)
-                return StepLogits(logits, ids), []
+        handed_on = ({"tokens": ids, "tables": tables, "lens": more[0]}
+                     if more else None)
+        return StepInFlight(pools_in, logits, ids, report, handed_on)
 
 
 class TinyDecoderLM(PagedDecoderLM):
@@ -550,7 +599,10 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
     """One token for every slot: append K/V into pages, attend over the
     page tables.  Fixed-shape in every argument — compiled once.
     -> (logits (S, V), both pools, the layers' reports, the greedy
-    choice of every slot (S,) int32, inactive slots included)."""
+    choice of every slot (S,) int32, inactive slots included, and the
+    lengths the next step starts from: ``lens + 1`` where the slot is
+    live, so that a step can follow this one on the device's own ids
+    and lengths)."""
     S = tokens.shape[0]
     x = block.embed(params, tokens, lens)                   # (S, d)
     # flat pool row each slot's new KV lands in: its page at
@@ -571,4 +623,4 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
         reports.append(report)
     logits = block.head(params, x)
     return (logits, k_pool, v_pool, _stack_reports(reports),
-            _greedy_ids(logits))
+            _greedy_ids(logits), lens + live.astype(lens.dtype))

@@ -1,21 +1,36 @@
 """DecodeSession: continuous batching at token granularity.
 
-The session owns ``max_slots`` fixed batch lanes.  Every scheduler tick
-(``step()``):
+The session owns ``max_slots`` fixed batch lanes and runs ONE
+fixed-shape decode step over all of them a tick — inactive lanes ride
+along masked (their page tables point at the reserved null page), so
+the compiled program's shapes never change as the batch composition
+churns.  A tick (``step()``) over a model that steps in two halves
+(``step_dispatch`` / ``step_collect``: the paged skeleton,
+``PagedDecoderLM``) is ordered so that the host's part runs under the
+device's step:
 
-1. **Admit**: pending requests claim open slots while the page pool can
-   hold their whole context (prompt + every token they may generate —
-   reserved up front, so a running sequence can never hit mid-flight
-   exhaustion).  Admission runs the model's prefill and writes the
-   context into freshly allocated pages.
-2. **Decode**: ONE fixed-shape step over all ``max_slots`` lanes —
-   inactive lanes ride along masked (their page tables point at the
-   reserved null page), so the compiled program's shapes never change
-   as the batch composition churns and the executor compile cache hits
-   every step.
-3. **Evict**: finished sequences (EOS or token budget) leave their
-   slot, their pages return to the allocator free list, and their
-   waiter is notified.
+1. **collect**: wait for the ids of the step that is in flight, if one
+   is.
+2. **decide**: per live slot, from the ids: deadline, the next token,
+   whether the sequence ends (EOS or token budget).  A slot that ends
+   is cleared here — its lane's table row nulled, its pages back on the
+   allocator's free list — so a sequence that ended at step *k* is
+   never live in step *k+1*.  Nothing is emitted yet.
+3. **sweep, admit, copy-on-write**: pending requests claim open slots
+   while the page pool can hold their whole context (prompt + every
+   token they may generate — reserved up front, so a running sequence
+   can never hit mid-flight exhaustion).  Admission runs the model's
+   prefill, on an idle device, and emits the first token at once.
+4. **dispatch** step *k+1*.  Its inputs are what the device already
+   holds (the last step's ids and lengths, the tables): a steady tick
+   uploads nothing, and a tick in which the host changed a row uploads
+   the arrays that row is in (``_StepInputs``).
+5. **deliver**: emit step *k*'s tokens to their requests and finish the
+   requests that ended in 2, while the device computes step *k+1*.
+
+A model without the two halves (``PagedSeq2SeqModel``, a wrapper that
+overrides ``decode``) runs ``decode`` whole where 4 is, followed by 1,
+2 and 5 at once; so does a speculative tick with ``verify_chunk``.
 
 The model behind the session is pluggable (``PagedSeq2SeqModel`` for
 v1 beam_search specs, ``TinyDecoderLM`` for transformer self-attention
@@ -59,7 +74,20 @@ _M_REFUSED = _metrics.counter(
     "decode_admission_refused_total",
     "generation requests refused at admission, by reason")
 _M_STEP_SEC = _metrics.histogram(
-    "decode_step_seconds", "wall time per batched decode step")
+    "decode_step_seconds",
+    "one batched decode step from its dispatch to its ids on the host "
+    "(over a model that steps in two halves that is two ticks' time: "
+    "the delivery of the step before runs inside it)")
+_M_STEP_INPUTS = _metrics.counter(
+    "decode_step_inputs_total",
+    "decode and verify steps dispatched, by where their tables, lengths "
+    "and tokens came from: `resident` = all three were what the device "
+    "already held, `uploaded` = the host refreshed at least one")
+_M_DELIVERIES = _metrics.counter(
+    "decode_deliveries_total",
+    "deliveries of one step's tokens to their requests, by what the "
+    "device was doing meanwhile: `step` = the next step was in flight, "
+    "`nothing` = no step was")
 _M_PREFILL_SEC = _metrics.histogram(
     "decode_prefill_seconds", "wall time per sequence prefill (admission)")
 _M_TTFT = _metrics.histogram(
@@ -250,6 +278,111 @@ class _BeamGroup:
         self.selects = 0                # beam_select calls consumed
 
 
+class _StepInputs:
+    """What a decode step reads of every lane — page tables, lengths,
+    the token to feed — twice: the host's mirror (numpy, exact whenever
+    no step is in flight) and, over a model that steps in two halves,
+    what the device holds since the last dispatch (``resident``:
+    ``StepInFlight.next``, opaque here).  ``stale`` names the arrays in
+    which the host has changed a row that the device's copy lacks:
+    every write goes through a method that says so, except what a step
+    itself produces (its ids as the next tokens, the live lanes'
+    lengths plus one: ``from_step``).  The device-resident slot state of the session is this
+    object and nothing else."""
+
+    NAMES = ("tokens", "tables", "lens")
+    __slots__ = NAMES + ("stale", "resident", "_pad")
+
+    def __init__(self, slots: int, pages_per_seq: int, pad_token: int):
+        self.tokens = np.full((slots, 1), pad_token, np.int64)
+        self.tables = np.zeros((slots, pages_per_seq), np.int32)  # null page
+        self.lens = np.ones((slots,), np.int64)
+        self.stale = set(self.NAMES)
+        self.resident = None
+        self._pad = pad_token
+
+    def seat(self, i: int, table, length: int, token: int) -> None:
+        """Lane ``i`` gets a sequence (an admission, a beam reorder)."""
+        self.tables[i], self.lens[i], self.tokens[i, 0] = table, length, token
+        self.stale.update(self.NAMES)
+
+    def clear(self, i: int, token: Optional[int] = None) -> None:
+        """Lane ``i`` rides along masked from the next step on."""
+        self.seat(i, 0, 1, self._pad if token is None else token)
+
+    def retable(self, i: int, table) -> None:
+        self.tables[i] = table
+        self.stale.add("tables")
+
+    def token(self, i: int, token: int, from_step: bool = False) -> None:
+        """Lane ``i``'s next input: ``from_step`` when it is the id the
+        landed step itself chose, which the device then holds too; else
+        the host chose it (sampling; a verified chunk's last token)."""
+        self.tokens[i, 0] = token
+        if not from_step:
+            self.stale.add("tokens")
+
+    def length(self, i: int, length: int, from_step: bool = False) -> None:
+        """Lane ``i``'s length: ``from_step`` when it is the landed
+        step's plus one for a live lane, which the device has added
+        too; else only the host knows it (a verified chunk's accepted
+        part)."""
+        self.lens[i] = length
+        if not from_step:
+            self.stale.add("lens")
+
+    def forget(self) -> None:
+        """The device's copies are not to be trusted (a step failed, or
+        was dropped in flight): the next dispatch uploads all three."""
+        self.resident = None
+
+    def for_dispatch(self):
+        """-> (tokens, tables, lens, any uploaded): for each the mirror
+        where the device's copy is stale or missing, else the
+        device's."""
+        if self.resident is None:
+            self.stale.update(self.NAMES)
+        picked = [getattr(self, n) if n in self.stale else self.resident[n]
+                  for n in self.NAMES]
+        return (*picked, bool(self.stale))
+
+    def dispatched(self, handed_on) -> None:
+        """A step took what ``for_dispatch`` gave; ``handed_on`` is what
+        the device holds for the one after it."""
+        self.resident = handed_on
+        self.stale.clear()
+
+
+class _Outbox:
+    """What one tick's token choices owe the requests: filled where the
+    tokens are decided, paid by ``_deliver``, in order.  ``events`` are
+    ``(request, token, None)`` to emit and ``(request, None, (reason,
+    error))`` to finish; the counts are the registry's, bumped at the
+    delivery; ``decided`` says that a landed step's choices are in
+    here."""
+
+    __slots__ = ("events", "tokens", "on_device", "on_host", "decided")
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self) -> None:
+        self.events: List[tuple] = []
+        self.tokens = self.on_device = self.on_host = 0
+        self.decided = False
+
+
+class _Flight:
+    """The step in flight: the model's handle, the lanes that were live
+    when it was dispatched (fixed until it lands: no row changes under
+    a step in flight) and when that was."""
+
+    __slots__ = ("step", "lanes", "t0")
+
+    def __init__(self, step, lanes: List[int], t0: float):
+        self.step, self.lanes, self.t0 = step, lanes, t0
+
+
 class DecodeSession:
     """Token-granularity continuous batching over a paged model.
 
@@ -288,6 +421,18 @@ class DecodeSession:
       taken from them, and the logits are read only when a live slot
       samples or belongs to a beam; logits without ``ids`` are chosen
       from on the host, slot by slot
+    - ``step_dispatch(tokens, states, tables, lens) -> step`` and
+      ``step_collect(step) -> (logits, new_states)``, defined by the
+      model's class: ``decode`` in two halves, the first returning
+      before the device is done.  The tick then dispatches step *k+1*
+      before it delivers step *k*'s tokens (the order in this module's
+      docstring).  ``step.next`` maps ``tokens`` / ``tables`` /
+      ``lens`` to what the device holds of them for the step after; the
+      session hands those back in place of its numpy arrays wherever it
+      has changed no row since, so a steady tick uploads nothing.
+      Looked up on the type, as Python looks up its own protocols: a
+      wrapper that forwards attributes and overrides ``decode`` is
+      served through its ``decode``, whole, in the old order
     """
 
     def __init__(self, model, max_slots: int = 8,
@@ -314,12 +459,14 @@ class DecodeSession:
         self._pending: List[DecodeRequest] = []
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
         S = self.max_slots
-        P = model.pages_per_seq
-        self._tokens = np.full((S, 1), model.bos_id, np.int64)
-        self._tables = np.full((S, P), 0, np.int32)   # null page
-        self._lens = np.ones((S,), np.int64)
+        self._inputs = _StepInputs(S, model.pages_per_seq, model.bos_id)
         self._states = [np.zeros((S,) + tuple(shape), dtype)
                         for shape, dtype in model.state_specs]
+        self._two_halves = all(
+            callable(getattr(type(model), half, None))
+            for half in ("step_dispatch", "step_collect"))
+        self._flight: Optional[_Flight] = None
+        self._outbox = _Outbox()
 
     @property
     def prefix_cache(self):
@@ -375,21 +522,24 @@ class DecodeSession:
 
     def idle(self) -> bool:
         with self._lock:
-            return not self._pending and all(s is None
-                                             for s in self._slots)
+            return (not self._pending and self._flight is None
+                    and all(s is None for s in self._slots))
 
     # -- scheduler tick -----------------------------------------------------
 
     def step(self) -> int:
-        """One tick: admit -> decode -> evict.  Returns the number of
-        slots that were active during the decode dispatch (0 = idle,
-        nothing dispatched).  A decode dispatch that *raises* is
-        contained (``_contain_step_failure``): the slots that were in
-        the batch are evicted — first offense requeued to retry from
-        scratch, second offense quarantined with 503 ``step_failed`` —
-        and the stepper thread lives on.  So is a copy-on-write split
-        that lost the model's pools (``PoolsLost``; a step or a prefill
-        that did contains it where it is called)."""
+        """One tick: collect -> decide -> sweep, admit, copy-on-write ->
+        dispatch -> deliver (the module's docstring; a model without
+        the two halves decodes whole where the dispatch is and is
+        collected, decided and delivered at once).  Returns the number
+        of slots that were live in the step this tick dispatched (0 =
+        nothing dispatched).  A step that *raises*, at its dispatch or
+        at its collect, is contained (``_contain_step_failure``): the
+        slots that were in the batch are evicted — first offense
+        requeued to retry from scratch, second offense quarantined with
+        503 ``step_failed`` — and the stepper thread lives on.  So is a
+        copy-on-write split that lost the model's pools (``PoolsLost``;
+        a step or a prefill that did contains it where it is called)."""
         live = [s.req.rid for s in self._slots if s is not None]
         with span("decode.tick", active=len(live),
                   waiting=len(self._pending),
@@ -399,8 +549,13 @@ class DecodeSession:
             except PoolsLost as exc:
                 self._contain_step_failure([], exc)
                 return 0
+            finally:
+                self._deliver()
+                _M_ACTIVE.set(self.active)
 
     def _tick(self) -> int:
+        if self._flight is not None:
+            self._collect()
         with span("decode.sweep"):
             self._sweep_cancelled()
             self._sweep_expired()
@@ -409,6 +564,7 @@ class DecodeSession:
         if not active_idx:
             return 0
         if self._spec_draft is not None and self._spec_ready(active_idx):
+            self._deliver()         # the draft reads what was emitted
             return self._spec_step(active_idx)
         if self.model.grows_kv:
             # the step writes each live slot's next KV row: split any
@@ -422,44 +578,85 @@ class DecodeSession:
                           if self._slots[i] is not None]
             if not active_idx:
                 return 0
+        inputs = self._inputs
         t0 = time.perf_counter()
         try:
             with span("decode.step"):
-                logits, new_states = self.model.decode(
-                    self._tokens, self._states, self._tables, self._lens)
+                if self._two_halves:
+                    tokens, tables, lens, uploaded = inputs.for_dispatch()
+                    step = self.model.step_dispatch(
+                        tokens, self._states, tables, lens)
+                else:
+                    uploaded = True
+                    landed = self.model.decode(
+                        inputs.tokens, self._states, inputs.tables,
+                        inputs.lens)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
-        _M_STEP_SEC.observe(time.perf_counter() - t0)
-        _M_STEPS.inc()
-        _M_SLOT_STEPS.inc(len(active_idx))
-        for i, buf in enumerate(self._states):
-            buf[...] = np.asarray(new_states[i])
-        if self.model.grows_kv:
-            for i in active_idx:
-                if not self._slots[i].dead:
-                    self._slots[i].ctx_len += 1
-                    self._lens[i] = self._slots[i].ctx_len
-        with span("decode.sample"):
-            self._sample(active_idx, logits)
-        _M_ACTIVE.set(self.active)
+        _M_STEP_INPUTS.inc(source="uploaded" if uploaded else "resident")
+        if self._two_halves:
+            inputs.dispatched(step.next)
+            self._flight = _Flight(step, active_idx, t0)
+        else:
+            self._landed(active_idx, *landed, t0=t0)
         return len(active_idx)
 
-    def _sample(self, active_idx: List[int], logits) -> None:
-        """The per-slot end of a tick: expiry, the next token, emission,
-        eviction.  A greedy slot takes the token the step chose on the
-        device where the logits carry ``ids``; a slot that samples and
-        a beam group index the logits, which brings them to the host,
-        and choose there (as every slot does without ``ids``)."""
+    def _collect(self) -> None:
+        """The step in flight lands: its ids reach the host and its
+        token choices go into the outbox.  A failure on the device shows
+        here, and is contained over the lanes the step was dispatched
+        with."""
+        flight, self._flight = self._flight, None
+        try:
+            landed = self.model.step_collect(flight.step)
+        except BaseException as exc:  # noqa: BLE001 - contained per slot
+            self._contain_step_failure(flight.lanes, exc)
+            return
+        self._landed(flight.lanes, *landed, t0=flight.t0)
+
+    def _landed(self, lanes: List[int], logits, new_states, *, t0: float,
+                drafts: Optional[dict] = None) -> None:
+        """A step's results are on the host: count it, take its states
+        and decide its tokens."""
+        _M_STEP_SEC.observe(time.perf_counter() - t0)
+        _M_STEPS.inc()
+        _M_SLOT_STEPS.inc(len(lanes))
+        for buf, new in zip(self._states, new_states):
+            buf[...] = np.asarray(new)
+        if self.model.grows_kv and drafts is None:
+            for i in lanes:
+                slot = self._slots[i]
+                if not slot.dead:
+                    slot.ctx_len += 1
+                    self._inputs.length(i, slot.ctx_len, from_step=True)
+        self._outbox.decided = True
+        with span("decode.sample"):
+            self._decide(lanes, logits, drafts)
+
+    def _decide(self, lanes: List[int], logits,
+                drafts: Optional[dict] = None) -> None:
+        """The token choice of one landed step, lane by lane: expiry,
+        the next token(s), and whether the sequence ends; a slot that
+        ends is cleared here, before the next dispatch.  Emission and
+        the requests' finish wait in the outbox for ``_deliver``.  A
+        greedy slot takes the token the step chose on the device where
+        the logits carry ``ids``; a slot that samples and a beam group
+        index the logits, which brings them to the host, and choose
+        there (as every slot does without ``ids``).  With ``drafts`` (a
+        verified chunk) a slot emits its accepted draft tokens and the
+        target's correction: rows of [prev] + accepted drafts are real,
+        later rows are speculative garbage the length mask never
+        reaches."""
         now = time.monotonic()
+        out = self._outbox
         ids = getattr(logits, "ids", None)
         if ids is None:
             logits = np.asarray(logits)
-        else:
+        elif drafts is None:
             ids = ids.tolist()
-        on_device = on_host = 0
         groups_seen = set()
-        for i in active_idx:
+        for i in lanes:
             slot = self._slots[i]
             if slot is None:
                 continue
@@ -470,27 +667,61 @@ class DecodeSession:
                 groups_seen.add(id(g))
                 if g.req.expired(now):
                     self._finish_group(g, "deadline", TimeoutError(
-                        "generation deadline expired"))
+                        "generation deadline expired"), out)
                     continue
                 self._group_select(
-                    g, logits[np.asarray(g.slot_idx, np.intp)])
-                on_host += 1
+                    g, logits[np.asarray(g.slot_idx, np.intp)], out)
+                out.on_host += 1
                 continue
             if slot.req.expired(now):
                 self._evict(i, "deadline",
-                            TimeoutError("generation deadline expired"))
+                            TimeoutError("generation deadline expired"), out)
                 continue
-            if ids is not None and not slot.req.temperature:
-                tok = ids[i]
-                on_device += 1
+            on_device = ids is not None and not slot.req.temperature
+            if drafts is not None:
+                target = (ids[i] if on_device
+                          else np.argmax(logits[i], axis=-1))       # (k,)
+                toks, accepted = accept_greedy(drafts[i], target)
+                observe_chunk(len(drafts[i]), accepted, len(drafts[i]) + 1)
+                slot.ctx_len += 1 + accepted
+                self._inputs.length(i, slot.ctx_len)
+            elif on_device:
+                toks = [ids[i]]
             else:
-                tok = self._choose(slot, logits[i])
-                on_host += 1
-            self._emit_token(i, tok)
-        if on_device:
-            _M_CHOICE.inc(on_device, where="device")
-        if on_host:
-            _M_CHOICE.inc(on_host, where="host")
+                toks = [self._choose(slot, logits[i])]
+            if on_device:
+                out.on_device += 1
+            else:
+                out.on_host += 1
+            for tok in toks:
+                self._emit_token(i, tok, out,
+                                 fed_by_step=on_device and drafts is None)
+                if self._slots[i] is not slot:          # eos / budget
+                    break
+
+    def _deliver(self) -> None:
+        """Pay what the outbox holds of a decided step, in order: each
+        token to its request (``on_token`` wakes an HTTP handler, which then
+        wants the interpreter lock), each finish, and the registry's
+        counts.  Over a model that steps in two halves this runs after
+        the next step's dispatch, under the device's step."""
+        out = self._outbox
+        if not out.decided:
+            return
+        with span("decode.deliver"):
+            for req, tok, finish in out.events:
+                if finish is not None:
+                    req._finish(*finish)
+                elif not req.done:      # swept since the token was chosen
+                    req._emit(tok)
+            if out.tokens:
+                _M_TOKENS.inc(out.tokens)
+            if out.on_device:
+                _M_CHOICE.inc(out.on_device, where="device")
+            if out.on_host:
+                _M_CHOICE.inc(out.on_host, where="host")
+            _M_DELIVERIES.inc(under="step" if self._flight else "nothing")
+        out.reset()
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive the session until every queued request finishes (the
@@ -552,23 +783,22 @@ class DecodeSession:
                     err = AdmissionRefused(
                         "pool_exhausted",
                         "no free page for a copy-on-write split")
-                    if slot.group is not None:
-                        self._finish_group(slot.group, "error", err)
-                    else:
-                        self._evict(i, "error", err)
+                    self._evict(i, "error", err)
                     return False
         if changed:
-            self._tables[i] = self.model.pool_table(slot.pages)
+            self._inputs.retable(i, self.model.pool_table(slot.pages))
         return True
 
     # -- beam groups --------------------------------------------------------
 
-    def _group_select(self, g: _BeamGroup, dist: np.ndarray) -> None:
+    def _group_select(self, g: _BeamGroup, dist: np.ndarray,
+                      out: Optional[_Outbox] = None) -> None:
         """One beam bookkeeping step for a group: run the shared oracle
         selection over the members' distributions, then reorder the
         sibling slots — each surviving hypothesis forks its parent's
         pages (CoW) and inherits its states; dropped hypotheses release
-        theirs."""
+        theirs.  With ``out`` the request's finish and the token count
+        wait there for the delivery."""
         dist = np.asarray(dist, np.float64)
         if not getattr(self.model, "emits_probs", False):
             # beam_select scores log-probabilities: raw logits must be
@@ -581,11 +811,14 @@ class DecodeSession:
         sel = beam_select(dist, g.scores,
                           g.alive, g.seqs, self.model.eos_id, g.k)
         if sel is None:
-            self._finish_group(g, "eos")
+            self._finish_group(g, "eos", out=out)
             return
         g.scores, g.seqs, g.alive, rows, toks = sel
         g.selects += 1
-        _M_TOKENS.inc(int(g.alive.sum()))
+        if out is None:
+            _M_TOKENS.inc(int(g.alive.sum()))
+        else:
+            out.tokens += int(g.alive.sum())
         slots = [self._slots[si] for si in g.slot_idx]
         old_pages = [s.pages for s in slots]
         ctx_snap = [s.ctx_len for s in slots]
@@ -605,40 +838,48 @@ class DecodeSession:
             slot.dead = not bool(g.alive[j])
             if slot.dead:
                 slot.ctx_len = 1
-                self._tables[si] = 0
-                self._lens[si] = 1
-                self._tokens[si, 0] = self.model.eos_id
+                self._inputs.clear(si, self.model.eos_id)
             else:
                 slot.ctx_len = ctx_snap[rows[j]]
-                self._tables[si] = self.model.pool_table(slot.pages)
-                self._lens[si] = slot.ctx_len
-                self._tokens[si, 0] = toks[j]
+                self._inputs.seat(si, self.model.pool_table(slot.pages),
+                                  slot.ctx_len, toks[j])
             for bi, buf in enumerate(self._states):
                 buf[si] = state_snap[bi][rows[j]]
         if not g.alive.any() or g.selects >= g.req.max_new_tokens:
-            self._finish_group(g, "eos" if not g.alive.any() else "length")
+            self._finish_group(g, "eos" if not g.alive.any() else "length",
+                               out=out)
+
+    def _vacate(self, i: int) -> None:
+        """Lane ``i`` loses its sequence: masked from the next step on,
+        its pages back with the allocator.  The request is not told."""
+        slot, self._slots[i] = self._slots[i], None
+        self._inputs.clear(i)
+        if slot.pages:
+            self.model.allocator.free(slot.pages)
+            slot.pages = []
+
+    def _finish(self, req: DecodeRequest, reason: str,
+                error: Optional[BaseException],
+                out: Optional[_Outbox]) -> None:
+        """Now, or — from the token choice — with the delivery."""
+        if out is None:
+            req._finish(reason, error)
+        else:
+            out.events.append((req, None, (reason, error)))
 
     def _finish_group(self, g: _BeamGroup, reason: str,
-                      error: Optional[BaseException] = None) -> None:
+                      error: Optional[BaseException] = None,
+                      out: Optional[_Outbox] = None) -> None:
         for si in g.slot_idx:
-            slot = self._slots[si]
-            if slot is None:
-                continue
-            self._slots[si] = None
-            self._tables[si] = 0
-            self._lens[si] = 1
-            self._tokens[si, 0] = self.model.bos_id
-            if slot.pages:
-                self.model.allocator.free(slot.pages)
-                slot.pages = []
+            if self._slots[si] is not None:
+                self._vacate(si)
         if error is None:
             order = np.argsort(-g.scores)
             g.req.beams = [(float(g.scores[i]), list(g.seqs[i]))
                            for i in order if np.isfinite(g.scores[i])]
             g.req.tokens = (list(g.req.beams[0][1])
                             if g.req.beams else [])
-        g.req._finish(reason, error)
-        _M_ACTIVE.set(self.active)
+        self._finish(g.req, reason, error, out)
 
     # -- speculative decoding -----------------------------------------------
 
@@ -662,9 +903,12 @@ class DecodeSession:
         slot, one chunked verify step scores all of them, and each slot
         emits the accepted prefix + the target's correction token —
         token-identical to the greedy path.  Rejected rows stay in the
-        pages but ``lens`` never reaches them (rollback = truncation)."""
+        pages but ``lens`` never reaches them (rollback = truncation).
+        ``verify_chunk`` is one whole call on the host's arrays, so the
+        step is collected, decided and delivered in this tick."""
         k = self.spec_k
         S = self.max_slots
+        inputs = self._inputs
         tokens = np.full((S, k), self.model.bos_id, np.int64)
         drafts = {}
         for i in list(active_idx):
@@ -674,7 +918,7 @@ class DecodeSession:
             ids = [int(t) for t in slot.req.prompt] + slot.req.tokens
             d = [int(t) for t in self._spec_draft.propose(ids, k - 1)]
             drafts[i] = d
-            tokens[i, 0] = self._tokens[i, 0]
+            tokens[i, 0] = inputs.tokens[i, 0]
             tokens[i, 1:] = d
         active_idx = [i for i in active_idx if i in drafts]
         if not active_idx:
@@ -682,59 +926,35 @@ class DecodeSession:
         t0 = time.perf_counter()
         try:
             with span("decode.step", chunk=k):
-                logits, new_states = self.model.verify_chunk(
-                    tokens, self._states, self._tables, self._lens)
+                landed = self.model.verify_chunk(
+                    tokens, self._states, inputs.tables, inputs.lens)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
-        _M_STEP_SEC.observe(time.perf_counter() - t0)
-        _M_STEPS.inc()
-        _M_SLOT_STEPS.inc(len(active_idx))
-        ids = getattr(logits, "ids", None)              # (S, k)
-        if ids is None:
-            logits = np.asarray(logits)                 # (S, k, V)
-        for i, buf in enumerate(self._states):
-            if new_states:
-                buf[...] = np.asarray(new_states[i])
-        with span("decode.sample"):
-            now = time.monotonic()
-            chosen = 0
-            for i in active_idx:
-                slot = self._slots[i]
-                if slot.req.expired(now):
-                    self._evict(i, "deadline", TimeoutError(
-                        "generation deadline expired"))
-                    continue
-                target = (np.argmax(logits[i], axis=-1) if ids is None
-                          else ids[i])                      # (k,)
-                chosen += 1
-                emitted, accepted = accept_greedy(drafts[i], target)
-                observe_chunk(k - 1, accepted, k)
-                # rows of [prev] + accepted drafts are real; later rows
-                # are speculative garbage the length mask never reaches
-                slot.ctx_len += 1 + accepted
-                self._lens[i] = slot.ctx_len
-                for tok in emitted:
-                    self._emit_token(i, tok)
-                    if self._slots[i] is not slot:          # eos / budget
-                        break
-            if chosen:
-                _M_CHOICE.inc(chosen,
-                              where="host" if ids is None else "device")
-        _M_ACTIVE.set(self.active)
+        _M_STEP_INPUTS.inc(source="uploaded")
+        self._landed(active_idx, *landed, t0=t0, drafts=drafts)
         return len(active_idx)
 
-    def _emit_token(self, i: int, tok: int) -> None:
+    def _emit_token(self, i: int, tok: int, out: Optional[_Outbox] = None,
+                    fed_by_step: bool = False) -> None:
+        """Slot ``i`` produced ``tok``: it goes to the request (now, or
+        with ``out``'s delivery), and the slot ends or takes it as its
+        next input — ``fed_by_step`` when it is the id the step itself
+        chose, which the device then holds too."""
         slot = self._slots[i]
-        slot.req._emit(tok)
-        slot.new_tokens += 1
-        _M_TOKENS.inc()
-        if tok == self.model.eos_id:
-            self._evict(i, "eos")
-        elif slot.new_tokens >= slot.req.max_new_tokens:
-            self._evict(i, "length")
+        if out is None:
+            slot.req._emit(tok)
+            _M_TOKENS.inc()
         else:
-            self._tokens[i, 0] = tok
+            out.events.append((slot.req, tok, None))
+            out.tokens += 1
+        slot.new_tokens += 1
+        if tok == self.model.eos_id:
+            self._evict(i, "eos", out=out)
+        elif slot.new_tokens >= slot.req.max_new_tokens:
+            self._evict(i, "length", out=out)
+        else:
+            self._inputs.token(i, tok, from_step=fed_by_step)
 
     def _contain_step_failure(self, active_idx: List[int],
                               exc: BaseException) -> None:
@@ -753,6 +973,10 @@ class DecodeSession:
         sequence, whichever program failed, and drops the prefix index:
         no page holds the rows it was filled with."""
         _M_STEP_FAIL.inc()
+        # what a landed step chose goes out before its requests are
+        # sent back to start again
+        self._deliver()
+        self._inputs.forget()
         if isinstance(exc, PoolsLost):
             active_idx = [i for i, s in enumerate(self._slots)
                           if s is not None]
@@ -787,13 +1011,7 @@ class DecodeSession:
                 continue
             # evict without finishing: the request restarts from an
             # empty generation at its next admission
-            self._slots[i] = None
-            self._tables[i] = 0
-            self._lens[i] = 1
-            self._tokens[i, 0] = self.model.bos_id
-            if slot.pages:
-                self.model.allocator.free(slot.pages)
-                slot.pages = []
+            self._vacate(i)
             req.tokens = []
             requeue.append(req)
         if requeue:
@@ -898,9 +1116,8 @@ class DecodeSession:
     def _place(self, i: int, slot: _Slot, ctx_len: int,
                state_rows) -> None:
         self._slots[i] = slot
-        self._tables[i] = self.model.pool_table(slot.pages)
-        self._lens[i] = ctx_len
-        self._tokens[i, 0] = self.model.bos_id
+        self._inputs.seat(i, self.model.pool_table(slot.pages), ctx_len,
+                          self.model.bos_id)
         for buf, row in zip(self._states, state_rows):
             buf[i] = row
 
@@ -987,24 +1204,22 @@ class DecodeSession:
             self._group_select(g, np.repeat(row, g.k, axis=0))
 
     def _evict(self, i: int, reason: str,
-               error: Optional[BaseException] = None) -> None:
+               error: Optional[BaseException] = None,
+               out: Optional[_Outbox] = None) -> None:
         slot = self._slots[i]
         if slot is not None and slot.group is not None:
             # a beam member never leaves alone: the hypotheses share
             # one request, so the whole group goes
-            self._finish_group(slot.group, reason, error)
+            self._finish_group(slot.group, reason, error, out)
             return
-        self._slots[i] = None
-        self._tables[i] = 0
-        self._lens[i] = 1
-        self._tokens[i, 0] = self.model.bos_id
-        if slot.pages:
-            self.model.allocator.free(slot.pages)
-            slot.pages = []
-        slot.req._finish(reason, error)
+        self._vacate(i)
+        self._finish(slot.req, reason, error, out)
 
     def fail_all(self, exc: BaseException) -> None:
-        """Shutdown: fail every live and queued request."""
+        """Shutdown: fail every live and queued request; a step in
+        flight is dropped, its results never read."""
+        self._flight = None
+        self._inputs.forget()
         with self._lock:
             pending, self._pending = self._pending, []
         for req in pending:

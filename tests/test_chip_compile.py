@@ -66,12 +66,14 @@ def _planned_bytes(compiled):
 
 
 def _assert_step_outputs(compiled, slots, vocab):
-    """The decode step hands out float32 logits (S, V) first and the
-    greedy choice it made of them last: int32 (S,), what the host reads
-    every tick in the logits' place."""
+    """The decode step hands out float32 logits (S, V) first and, last,
+    what the next step is entered with on the device: the greedy choice
+    it made of them, int32 (S,), which the host also reads every tick
+    in the logits' place, and the lengths it leaves, int32 (S,)."""
     out = jax.tree.leaves(compiled.out_info)
     assert (out[0].shape, out[0].dtype) == ((slots, vocab), jnp.float32)
-    assert (out[-1].shape, out[-1].dtype) == ((slots,), jnp.int32)
+    for handed_on in out[-2:]:
+        assert (handed_on.shape, handed_on.dtype) == ((slots,), jnp.int32)
 
 
 def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
@@ -230,7 +232,7 @@ def test_cerebras_decode_step_writes_and_reads_its_pools_in_place(
         sds((S,), jnp.int32), heads=H, page_size=pg).compile()
     _assert_step_outputs(compiled, S, 50257)
     planned = _planned_bytes(compiled)
-    assert planned == 9_314_695_168, planned
+    assert planned == 9_314_695_680, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 4,
         CEREBRAS_STEP_PLAN_UNDONATED)
@@ -338,7 +340,7 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
         block=block).compile()
     _assert_step_outputs(compiled, S, cfg["vocab_size"])
     planned = _planned_bytes(compiled)
-    assert planned == 10_367_830_528, planned
+    assert planned == 10_367_895_552, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 2,
         OLMOE_STEP_PLAN_UNDONATED)

@@ -717,3 +717,144 @@ def test_step_logits_come_to_the_host_once_and_only_when_read():
     assert np.asarray(logits, np.float64).dtype == np.float64
     np.testing.assert_array_equal(logits[np.asarray([3, 1])], host[[3, 1]])
     np.testing.assert_array_equal(np.argmax(logits[1]), logits.ids[1])
+
+
+# ---------------------------------------------------------------------------
+# the tick's new order gives the parent's tokens (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+# what the parent commit (453e211) streamed for these requests; greedy
+# and speculative streams have the dense oracle instead
+PARENT_SAMPLED = [[56, 40, 40, 52, 31, 55, 52, 54, 19, 22],
+                  [39, 57, 49, 14, 18, 56, 0],      # ends on the EOS
+                  [3, 3, 3, 3, 3, 3, 3, 3, 3, 3]]
+PARENT_BEAMS = [(-25.036277770996094, [53] * 7),
+                (-25.228370666503906, [6] + [53] * 6),
+                (-25.4178466796875, [53] * 6 + [42])]
+PARENT_BESIDE_THE_BEAM = [3, 3, 3, 26, 26, 26, 26, 26, 26]
+
+
+def _in_flight_case(what):
+    """Two greedy requests; the first is cancelled, or its deadline
+    passes, while the step that computes its third token is in flight.
+    It ends there, with a prefix of its oracle stream; its neighbour's
+    stream is untouched and every page comes back."""
+    import time
+
+    from paddle_tpu.decode.session import DecodeRequest, DecodeSession
+
+    lm = _mk(seed=5)
+    sess = DecodeSession(lm, max_slots=2)
+    a = sess.submit(DecodeRequest(list(PROMPT), max_new_tokens=9))
+    b = sess.submit(DecodeRequest([2, 3, 4, 5, 6], max_new_tokens=9))
+    sess.step(), sess.step()
+    assert sess._flight is not None and len(a.tokens) == 2
+    if what == "cancel":
+        a.cancel()
+    else:
+        a.deadline = time.monotonic() - 1.0
+    sess.run(100)
+    assert b.result(0) == lm.dense_greedy([2, 3, 4, 5, 6], 9)
+    assert a.done and a.tokens == lm.dense_greedy(PROMPT, 9)[:2]
+    if what == "cancel":
+        assert a.finish_reason == "cancelled"
+    else:
+        with pytest.raises(TimeoutError):
+            a.result(0)
+    assert lm.allocator.pages_in_use == 0
+
+
+@pytest.mark.parametrize("case", [
+    "greedy", "sampling_and_eos_mid_stream", "beam", "prefix_cache_hit",
+    "speculative", "seq2seq", "cancel_in_flight", "deadline_in_flight"])
+def test_the_new_tick_order_gives_the_parents_tokens(case):
+    from paddle_tpu.decode.prefix import PrefixCache
+    from paddle_tpu.decode.session import (BeamRequest, DecodeRequest,
+                                           DecodeSession)
+    from paddle_tpu.decode.spec import NgramDraft
+
+    def run(sess, reqs):
+        for r in reqs:
+            sess.submit(r)
+        sess.run(500)
+        for r in reqs:
+            assert r.wait(5) and r.error is None, r.error
+        assert sess._flight is None and sess.idle()
+        return [list(r.tokens) for r in reqs]
+
+    if case in ("cancel_in_flight", "deadline_in_flight"):
+        return _in_flight_case(case.split("_")[0])
+    if case == "seq2seq":
+        # a model without the two halves: ``decode`` whole, old order
+        from demos.seq2seq.gen_config import make_beam_gen
+        from paddle_tpu.decode.engine import GenerationEngine
+        from paddle_tpu.executor import Scope
+        from paddle_tpu.generation import SequenceGenerator
+
+        class _Params:
+            scope = Scope()
+
+        oracle = SequenceGenerator(make_beam_gen(beam_size=1, max_length=7),
+                                   _Params)
+        engine = GenerationEngine.for_seq2seq(
+            make_beam_gen(beam_size=1, max_length=7), _Params, num_pages=24,
+            page_size=8, pages_per_seq=2, max_slots=2, max_new_tokens=7)
+        try:
+            assert not engine.session._two_halves
+            srcs = [[4, 7, 2], [3, 9, 5, 6], [2, 2, 11, 8, 1]]
+            reqs = [engine.submit(s) for s in srcs]
+            assert [r.result(300) for r in reqs] == \
+                [oracle.generate_greedy([s]) for s in srcs]
+            assert engine.model.allocator.pages_in_use == 0
+        finally:
+            engine.stop()
+        return
+    if case == "greedy":
+        lm = _mk(seed=5)
+        prompts = [PROMPT, [2, 3, 4, 5, 6], [9, 8, 7, 1, 2, 3, 4]]
+        got = run(DecodeSession(lm, max_slots=2),     # 3 requests, 2 lanes
+                  [DecodeRequest(list(p), max_new_tokens=10)
+                   for p in prompts])
+        assert got == [lm.dense_greedy(p, 10) for p in prompts]
+    elif case == "sampling_and_eos_mid_stream":
+        lm = _mk(seed=11)
+        got = run(DecodeSession(lm, max_slots=4), [
+            DecodeRequest(list(PROMPT), max_new_tokens=10, temperature=0.9,
+                          top_k=5, seed=42),
+            DecodeRequest(list(PROMPT[:7]), max_new_tokens=10,
+                          temperature=1.3, seed=7),
+            DecodeRequest(list(PROMPT[2:]), max_new_tokens=10)])
+        assert got == PARENT_SAMPLED
+        assert got[1][-1] == lm.eos_id and len(got[1]) < 10
+    elif case == "beam":
+        lm = _mk(seed=7)
+        beam = BeamRequest(list(PROMPT[2:9]), beam_size=3, max_new_tokens=7)
+        got = run(DecodeSession(lm, max_slots=6),
+                  [beam, DecodeRequest(list(PROMPT), max_new_tokens=9)])
+        assert got[1] == PARENT_BESIDE_THE_BEAM == lm.dense_greedy(PROMPT, 9)
+        assert [t for _, t in beam.beams] == [t for _, t in PARENT_BEAMS]
+        for (gs, _), (ws, _) in zip(beam.beams, PARENT_BEAMS):
+            assert abs(gs - ws) < 1e-4
+    elif case == "prefix_cache_hit":
+        lm = _mk(seed=3)
+        cache = PrefixCache(lm.allocator, lm.page_size, capacity_pages=16)
+        sess = DecodeSession(lm, max_slots=4, prefix_cache=cache)
+        first = run(sess, [DecodeRequest(list(PROMPT), max_new_tokens=8)])
+        again = run(sess, [
+            DecodeRequest(list(PROMPT), max_new_tokens=8),
+            DecodeRequest(list(PROMPT[:9]) + [7, 7], max_new_tokens=8)])
+        assert cache.hits == 2
+        assert first[0] == again[0] == lm.dense_greedy(PROMPT, 8)
+        assert again[1] == lm.dense_greedy(PROMPT[:9] + [7, 7], 8)
+        cache.clear()
+    else:
+        lm = _mk(seed=5)
+        prompts = [PROMPT, [2, 3, 4, 5, 6], [9, 8, 7, 1, 2, 3, 4]]
+        # 13 tokens: the last ticks have no room for a chunk of 4 and
+        # fall back to the plain step, dispatched in two halves
+        got = run(DecodeSession(lm, max_slots=4, spec_draft=NgramDraft(),
+                                spec_k=4),
+                  [DecodeRequest(list(p), max_new_tokens=13)
+                   for p in prompts])
+        assert got == [lm.dense_greedy(p, 13) for p in prompts]
+    assert lm.allocator.pages_in_use == 0

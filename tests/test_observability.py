@@ -972,17 +972,36 @@ def test_generate_spans_share_one_rid_and_counters_move(toy_gen_server):
     ticks = {e["args"]["id"]: e for e in ev["decode.tick"]}
     assert admit["args"]["parent"] in ticks
     # the ticks after the admitting one name the request among their
-    # slots' rids, and each holds one decode step in its three parts
+    # slots' rids.  A step is spread over two ticks: its dispatch (and
+    # its upload, when the host changed a row: here only the step after
+    # the admission) under ``decode.step`` in one, the wait for its ids
+    # (``decode.logits_to_host``) and the token choice
+    # (``decode.sample``) at the top of the next, and the delivery of
+    # what it chose (``decode.deliver``) at that tick's end, after the
+    # next step's dispatch
     later = [t for t in ticks.values() if t["ts"] > admit["ts"]]
     assert later and all(str(rid) in t["args"]["rids"].split(",")
                          for t in later)
     steps = {e["args"]["id"]: e for e in ev["decode.step"]}
+    assert len(steps) == 3                       # 4 tokens, 1 prefill
     assert all(s["args"]["parent"] in ticks for s in steps.values())
-    for name in ("decode.upload", "decode.dispatch",
-                 "decode.logits_to_host"):
-        assert len(ev[name]) == len(steps)
+    assert len(ev["decode.dispatch"]) == 3 and len(ev["decode.upload"]) == 1
+    for name in ("decode.upload", "decode.dispatch"):
         assert all(e["args"]["parent"] in steps for e in ev[name])
-    assert len(ev["decode.sample"]) == len(steps) == 3   # 4 tokens, 1 prefill
+    for name in ("decode.logits_to_host", "decode.sample", "decode.deliver"):
+        assert len(ev[name]) == 3
+        assert all(e["args"]["parent"] in ticks for e in ev[name]), name
+    dispatched = {e["args"]["parent"]: e for e in ev["decode.step"]}
+    delivers = sorted(ev["decode.deliver"], key=lambda e: e["ts"])
+    for deliver in delivers[:2]:    # the last has no step to run under
+        step = dispatched[deliver["args"]["parent"]]
+        assert step["ts"] + step["dur"] <= deliver["ts"]
+    assert delivers[2]["args"]["parent"] not in dispatched
+    for wait, sample in zip(
+            sorted(ev["decode.logits_to_host"], key=lambda e: e["ts"]),
+            sorted(ev["decode.sample"], key=lambda e: e["ts"])):
+        assert wait["args"]["parent"] == sample["args"]["parent"]
+        assert admit["args"]["parent"] != wait["args"]["parent"]
     after = _registry_totals(*counters)
     assert after["decode_queue_wait_seconds"] == \
         before["decode_queue_wait_seconds"] + 1

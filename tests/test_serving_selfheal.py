@@ -419,10 +419,23 @@ def test_decode_request_failing_twice_is_quarantined_503():
 # -- a donated program that fails has consumed its pools (ISSUE 27) ---------
 
 
-def _lose_pools_on_next_call(monkeypatch, name):
+class _LostAtTheWait:
+    """In place of a step's ids: the dispatch went through, the wait
+    for the result is where the device's failure shows."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("injected: the device halted")
+
+
+def _lose_pools_on_next_call(monkeypatch, name, at="call"):
     """``decode/model.py``'s jitted program ``name`` fails on its next
     call after consuming the pools it was given, as a donated call that
-    fails on the device does; later calls run."""
+    fails on the device does; later calls run.  ``at="wait"`` (the
+    decode step): the call returns, as a dispatch does, and the failure
+    shows when the step's ids are waited for."""
     from paddle_tpu.decode import model as dm
 
     real, failed = getattr(dm, name), []
@@ -431,6 +444,10 @@ def _lose_pools_on_next_call(monkeypatch, name):
         if not failed:
             failed.append(True)
             first = 0 if name == "_copy_pools_page" else 1
+            if at == "wait":
+                logits, k, v, report, _, *more = real(*args, **kw)
+                assert all(p.is_deleted() for p in args[first:first + 2])
+                return (logits, k, v, report, _LostAtTheWait(), *more)
             for pool in args[first:first + 2]:
                 pool.delete()
             raise RuntimeError("injected: the device halted")
@@ -447,19 +464,24 @@ def _paged_lm(seed):
                          seed=seed)
 
 
-@pytest.mark.parametrize("program", ["_decode_step", "_prefill_bucket",
-                                     "_prefill_chunk", "_copy_pools_page"])
+@pytest.mark.parametrize("program", [
+    "_decode_step", "_decode_step:wait", "_decode_step:wait+admission",
+    "_prefill_bucket", "_prefill_chunk", "_copy_pools_page"])
 def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     """The pools are made anew and counted once; every seated sequence
     goes back by the strike rule and completes from a fresh prefill with
     the oracle's tokens; the prefix index is dropped, so no later hit is
-    served from a page whose rows are gone; every page comes back."""
+    served from a page whose rows are gone; every page comes back.  A
+    decode step fails at its dispatch or, ``:wait``, at the collect a
+    tick later — there also with a request that arrived while the step
+    was in flight and is admitted in the tick that contains it."""
     from paddle_tpu.decode import model as dm
     from paddle_tpu.decode.paged_kv import PoolsLost
     from paddle_tpu.decode.prefix import PrefixCache
     from paddle_tpu.decode.session import (AdmissionRefused, BeamRequest,
                                            DecodeRequest, DecodeSession)
 
+    program, _, at = program.partition(":")
     lm = _paged_lm(11)
     cache = PrefixCache(lm.allocator, lm.page_size)
     sess = DecodeSession(lm, max_slots=4, prefix_cache=cache)
@@ -487,9 +509,18 @@ def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     if program in victims:
         sess.step()                                # B and C are seated
         victim = sess.submit(victims[program])
-    _lose_pools_on_next_call(monkeypatch, program)
+    _lose_pools_on_next_call(monkeypatch, program,
+                             at="wait" if at else "call")
     old = (lm.k_pool, lm.v_pool)
+    late = None
+    if at.endswith("admission"):
+        sess.step()                     # the doomed step is in flight
+        assert old[0].is_deleted() and sess._flight is not None
+        late = sess.submit(DecodeRequest(p_c + [2], max_new_tokens=4))
+        want_late = lm.dense_greedy(p_c + [2], 4)
     sess.run(max_steps=100)
+    if late is not None:
+        assert late.result(0) == want_late and late.step_failures == 0
 
     assert rebuilds() == n0 + 1
     assert all(pool.is_deleted() for pool in old)
