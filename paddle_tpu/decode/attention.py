@@ -36,10 +36,17 @@ _F32 = jnp.float32
 _NEG_INF = -1e30  # matches flash_attention: finite, avoids inf-inf NaN
 
 
-def fits(page_size: int, num_heads: int, head_dim: int) -> bool:
-    """Shapes the kernel's block layout supports."""
-    return (page_size % 8 == 0 and head_dim % 8 == 0
-            and head_dim <= 256 and num_heads >= 1)
+def fits(page_size: int, num_heads: int, head_dim: int,
+         kv_heads: int = None) -> bool:
+    """Shapes the kernels' block layouts support.  ``kv_heads`` (None:
+    ``num_heads``) is the K/V heads a page holds; where it is fewer
+    than the query heads (grouped heads) it has to divide them, and the
+    blocks are the K/V heads' wide."""
+    ok = (page_size % 8 == 0 and head_dim % 8 == 0
+          and head_dim <= 256 and num_heads >= 1)
+    if kv_heads in (None, num_heads):
+        return ok
+    return ok and kv_heads >= 1 and num_heads % kv_heads == 0
 
 
 # The never-tuned guesses ISSUE 16 names: one slot per grid step, slot
@@ -51,10 +58,14 @@ DEFAULT_CONFIG = {"slots_per_block": 1, "slot_semantics": "parallel"}
 
 
 def block_ok(num_slots: int, num_heads: int, head_dim: int,
-             slots_per_block: int) -> bool:
+             slots_per_block: int, kv_heads: int = None) -> bool:
     """Validity of an explicit slot block at an actual shape: grid
-    divisibility plus the (sb, H, D) f32 scratch staying tiny."""
+    divisibility plus the (sb, H, D) f32 scratch staying tiny.  The
+    grouped kernel (``kv_heads`` fewer than ``num_heads``) takes one
+    slot a grid step and nothing else."""
     sb = slots_per_block
+    if kv_heads not in (None, num_heads) and sb != 1:
+        return False
     return (1 <= sb <= num_slots and num_slots % sb == 0
             and sb * num_heads * (head_dim + 2) * 4 <= 2 * 1024 * 1024)
 
@@ -329,9 +340,13 @@ def ragged_paged_attention_chunk_reference(q, k_pages, v_pages,
 
 
 def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, scale, page, npp, T):
+                      m_scr, l_scr, acc_scr, *, scale, page, npp, T, G=1):
     """Chunked variant of ``_rpa_kernel``: the q block holds the slot's
-    whole T-token chunk; masking offsets the length limit per row."""
+    whole T-token chunk; masking offsets the length limit per row.
+    With ``G`` > 1 (grouped heads) the q block's ``T * G`` rows are the
+    chunk's rows times the G query heads that read each of the block's
+    K/V heads, row ``t * G + g`` at the chunk's row ``t``: the group
+    rides the one K/V page, read once."""
     s = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -347,7 +362,7 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     # that contribute to no query row and skip their math + DMA
     @pl.when(p * page < seq_len + T)
     def _page():
-        q = q_ref[0].astype(_F32)                       # (T, H, D)
+        q = q_ref[0].astype(_F32)                       # (T * G, H, D)
         k = k_ref[0].astype(_F32)                       # (page, H, D)
         v = v_ref[0].astype(_F32)
         # scores (H, T, page): batch over H, contract D
@@ -357,6 +372,8 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=_F32) * scale
         t_pos = p * page + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
         row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        if G > 1:
+            row = row // G
         sc = jnp.where(t_pos < seq_len + row + 1, sc, _NEG_INF)
         m_prev = m_scr[...]                             # (H, T, 1)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
@@ -376,13 +393,12 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = jnp.swapaxes(acc_scr[...] / l, 0, 1).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
-                                 scale=None, interpret: bool = False):
-    """Pallas chunked ragged paged-attention (same contract as
-    ``ragged_paged_attention_chunk_reference``): one grid step per
-    (slot, page), the whole T-token chunk resident in the q/o blocks."""
-    S, T, H, D = q.shape
+def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
+                G):
+    """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
+    one grid step per (slot, page), the whole chunk resident in the q/o
+    blocks."""
+    S, TG, H, D = q.shape
     page = k_pages.shape[1]
     P = page_tables.shape[1]
     if scale is None:
@@ -391,31 +407,188 @@ def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
         num_scalar_prefetch=2,
         grid=(S, P),
         in_specs=[
-            pl.BlockSpec((1, T, H, D), lambda s, p, pt, ln: (s, 0, 0, 0)),
+            pl.BlockSpec((1, TG, H, D), lambda s, p, pt, ln: (s, 0, 0, 0)),
             pl.BlockSpec((1, page, H, D),
                          lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
             pl.BlockSpec((1, page, H, D),
                          lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, H, D),
+        out_specs=pl.BlockSpec((1, TG, H, D),
                                lambda s, p, pt, ln: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, T, 1), _F32),     # running max
-            pltpu.VMEM((H, T, 1), _F32),     # running normalizer
-            pltpu.VMEM((H, T, D), _F32),     # output accumulator
+            pltpu.VMEM((H, TG, 1), _F32),     # running max
+            pltpu.VMEM((H, TG, 1), _F32),     # running normalizer
+            pltpu.VMEM((H, TG, D), _F32),     # output accumulator
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_rpa_chunk_kernel, scale=scale, page=page,
-                          npp=P, T=T),
+    kernel = functools.partial(_rpa_chunk_kernel, scale=scale, page=page,
+                               npp=P, T=TG // G, G=G)
+    call = dict(
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, TG, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name="ragged_paged_attention_chunk",
-        interpret=interpret,
-    )(page_tables.astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+        interpret=interpret)
+    args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
+            q, k_pages, v_pages)
+    # one kernel under two names: a trace tells the grouped call apart
+    if G > 1:
+        return pl.pallas_call(
+            kernel, name="ragged_paged_attention_gqa", **call)(*args)
+    return pl.pallas_call(
+        kernel, name="ragged_paged_attention_chunk", **call)(*args)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
+                                 scale=None, interpret: bool = False):
+    """Pallas chunked ragged paged-attention (same contract as
+    ``ragged_paged_attention_chunk_reference``)."""
+    return _chunk_call(q, k_pages, v_pages, page_tables, lens, scale,
+                       interpret, 1)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query pages: Hq query heads on Hkv K/V heads
+# ---------------------------------------------------------------------------
+
+
+def ragged_paged_attention_gqa_reference(q, k_pages, v_pages, page_tables,
+                                         lens, scale=None):
+    """The chunk reference on grouped heads: q (S, T, Hq, D); k/v_pages
+    (N, page, Hkv, D), query head ``i`` reading K/V head ``i // (Hq //
+    Hkv)``; ``lens`` the rows before the chunk -> (S, T, Hq, D)."""
+    S, T, Hq, D = q.shape
+    page, Hkv = k_pages.shape[1:3]
+    G = Hq // Hkv
+    P = page_tables.shape[1]
+    if scale is None:
+        scale = D ** -0.5
+    k = k_pages[page_tables].reshape(S, P * page, Hkv, D).astype(_F32)
+    v = v_pages[page_tables].reshape(S, P * page, Hkv, D).astype(_F32)
+    qg = q.astype(_F32).reshape(S, T, Hkv, G, D)
+    s = jnp.einsum("sjhgd,sthd->sjhgt", qg, k) * scale
+    limit = lens.reshape(-1, 1) + jnp.arange(T)[None, :] + 1     # (S, T)
+    mask = jnp.arange(P * page)[None, None, :] < limit[:, :, None]
+    s = jnp.where(mask[:, :, None, None, :], s, _NEG_INF)
+    out = jnp.einsum("sjhgt,sthd->sjhgd", jax.nn.softmax(s, axis=-1), v)
+    return out.reshape(S, T, Hq, D).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def ragged_paged_attention_gqa(q, k_pages, v_pages, page_tables, lens,
+                               scale=None, interpret: bool = False):
+    """Pallas ragged paged attention on grouped heads (same contract as
+    ``ragged_paged_attention_gqa_reference``): the chunk kernel with
+    the G query heads of each K/V head laid out as further rows of the
+    chunk, so a page is read once for all Hq heads and the bytes read
+    are the Hkv heads', whatever Hq is.  A decode step is the chunk of
+    one row."""
+    S, T, Hq, D = q.shape
+    Hkv = k_pages.shape[2]
+    G = Hq // Hkv
+    # (S, T, Hkv, G, D) -> (S, T * G, Hkv, D): outside the kernel, on
+    # S * T * Hq * D numbers
+    rows = jnp.moveaxis(q.reshape(S, T, Hkv, G, D), 3, 2).reshape(
+        S, T * G, Hkv, D)
+    out = _chunk_call(rows, k_pages, v_pages, page_tables, lens, scale,
+                      interpret, G)
+    return jnp.moveaxis(out.reshape(S, T, G, Hkv, D), 2, 3).reshape(
+        S, T, Hq, D)
+
+
+def _grouped(q, k_pages) -> bool:
+    return q.shape[-2] != k_pages.shape[2]
+
+
+def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale):
+    """Dispatcher of the grouped path: a chunk (S, T, Hq, D) after
+    ``lens`` cached rows."""
+    from paddle_tpu import pallas as pk
+
+    Hq, D = q.shape[-2:]
+    page, Hkv = k_pages.shape[1:3]
+    if pk.dispatch("ragged_paged_attention_gqa",
+                   pk.policy(fits(page, Hq, D, Hkv), True)):
+        return ragged_paged_attention_gqa(
+            q, k_pages, v_pages, page_tables, lens, scale=scale,
+            interpret=pk.interpret_mode())
+    return ragged_paged_attention_gqa_reference(
+        q, k_pages, v_pages, page_tables, lens, scale=scale)
+
+
+def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
+    """Attention of a window layer over its per-sequence ring, plain
+    XLA (a ring is a few hundred rows a slot: the gather IS the read).
+
+    q (S, T, Hq, D) at absolute positions ``pos`` (S, T); k/v_ring
+    (S, R, page, Hkv, D): ring slot ``r`` holds the newest page ``pi``
+    with ``pi % R == r``, already written up to the chunk's last row.
+    A key is seen when it is the query's own row or one of the
+    ``window - 1`` before it.  Which page a ring slot holds is reckoned
+    per query row from its own position, so within a chunk of up to
+    ``page`` rows a slot that a later row has begun to overwrite still
+    reads as the old page for an earlier row: the overwritten rows are
+    older than that row's window."""
+    S, T, Hq, D = q.shape
+    R, Hkv = k_ring.shape[1], k_ring.shape[3]
+    G = Hq // Hkv
+    k = k_ring.reshape(S, R * page, Hkv, D).astype(_F32)
+    v = v_ring.reshape(S, R * page, Hkv, D).astype(_F32)
+    qg = q.astype(_F32).reshape(S, T, Hkv, G, D)
+    s = jnp.einsum("sjhgd,sthd->sjhgt", qg, k) * (D ** -0.5)
+    slot = jnp.arange(R * page, dtype=jnp.int32) // page
+    off = jnp.arange(R * page, dtype=jnp.int32) % page
+    newest = (pos // page)[:, :, None]                         # (S, T, 1)
+    held = newest - (newest - slot) % R
+    k_pos = held * page + off                                  # (S, T, R*pg)
+    back = pos[:, :, None] - k_pos
+    seen = (k_pos >= 0) & (back >= 0) & (back < window)
+    s = jnp.where(seen[:, :, None, None, :], s, _NEG_INF)
+    out = jnp.einsum("sjhgt,sthd->sjhgd", jax.nn.softmax(s, axis=-1), v)
+    return out.reshape(S, T, Hq, D).astype(q.dtype)
+
+
+def banded_prefill_attention(q, k, v, window: int):
+    """Causal attention of one contiguous prompt in which a row sees
+    itself and the ``window - 1`` rows before it: q (T, Hq, D), k/v
+    (T, Hkv, D) -> (T, Hq, D).  Blocks of ``window`` queries against
+    their own and the previous block of keys: the scores are (T, Hq, 2
+    * window), never T x T.  A prompt that is not whole blocks (only
+    the eager oracle's) takes the masked dense form."""
+    T, Hq, D = q.shape
+    Hkv = k.shape[1]
+    G, W = Hq // Hkv, window
+    scale = D ** -0.5
+    if T % W:
+        t = jnp.arange(T)
+        back = t[:, None] - t[None, :]
+        s = jnp.einsum("qhgd,khd->hgqk",
+                       q.astype(_F32).reshape(T, Hkv, G, D),
+                       k.astype(_F32)) * scale
+        s = jnp.where((back >= 0) & (back < W), s, _NEG_INF)
+        out = jnp.einsum("hgqk,khd->qhgd", jax.nn.softmax(s, axis=-1),
+                         v.astype(_F32))
+        return out.reshape(T, Hq, D).astype(q.dtype)
+    nb = T // W
+    qb = q.reshape(nb, W, Hkv, G, D)
+
+    def with_previous(x):
+        xb = x.reshape(nb, W, Hkv, D)
+        prev = jnp.concatenate([jnp.zeros_like(xb[:1]), xb[:-1]], axis=0)
+        return jnp.concatenate([prev, xb], axis=1)          # (nb, 2W, ..)
+
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, with_previous(k),
+                   preferred_element_type=_F32) * scale
+    i = jnp.arange(W)[:, None]
+    j = jnp.arange(2 * W)[None, :]
+    band = (j > i) & (j <= i + W)                            # (W, 2W)
+    first = (jnp.arange(nb) > 0)[:, None, None] | (j >= W)   # (nb, W, 2W)
+    s = jnp.where((band & first)[:, None, None], s, _NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", pr, with_previous(v),
+                     preferred_element_type=_F32)
+    return out.reshape(T, Hq, D).astype(q.dtype)
 
 
 def _use_kernel(kernel: str, page_size: int, H: int, D: int) -> bool:
@@ -433,6 +606,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
     """Dispatcher for the chunked step (mirrors ``paged_attention``)."""
     from paddle_tpu import pallas as pk
 
+    if _grouped(q, k_pages):
+        return _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale)
     S, T, H, D = q.shape
     if _use_kernel("ragged_paged_attention_chunk", k_pages.shape[1], H, D):
         return ragged_paged_attention_chunk(
@@ -447,6 +622,10 @@ def paged_attention(q, k_pages, v_pages, page_tables, lens, scale=None):
     jit-embeddable, identical contract (see ``_use_kernel``)."""
     from paddle_tpu import pallas as pk
 
+    if _grouped(q, k_pages):
+        # the chunk of one row after ``lens - 1`` cached rows
+        return _paged_gqa(q[:, None], k_pages, v_pages, page_tables,
+                          lens - 1, scale)[:, 0]
     S, H, D = q.shape
     if _use_kernel("ragged_paged_attention", k_pages.shape[1], H, D):
         return ragged_paged_attention(
@@ -466,7 +645,9 @@ def dense_prefill_attention(q, k, v, causal: bool = True):
     (T, H, D) -> (T, H, D).  Reuses the flash-attention forward when its
     block layout fits the shape (the separately-compiled dense-prefill
     program of the prefill/decode split); otherwise the plain jnp
-    softmax path — prompts are short where flash does not fit."""
+    softmax path — prompts are short where flash does not fit.  K and V
+    of fewer heads than q (grouped heads) are repeated to q's: a prompt's
+    K/V rows are a few MB, and the flash kernel stays the one it is."""
     from paddle_tpu import pallas as pk
     from paddle_tpu.pallas import flash_attention as fa
 
@@ -474,6 +655,9 @@ def dense_prefill_attention(q, k, v, causal: bool = True):
     qb = jnp.moveaxis(q, 1, 0)            # (H, T, D) = (BH, S, D)
     kb = jnp.moveaxis(k, 1, 0)
     vb = jnp.moveaxis(v, 1, 0)
+    if kb.shape[0] != H:
+        kb = jnp.repeat(kb, H // kb.shape[0], axis=0)
+        vb = jnp.repeat(vb, H // vb.shape[0], axis=0)
     if pk.dispatch("prefill_flash_attention",
                    pk.policy(fa.fits(1, H, T, D), True)):
         out = fa.flash_attention(qb, kb, vb, causal=causal,

@@ -154,6 +154,11 @@ class GenerationEngine:
             "beam_max": self.beam_max,
             "speculative": self.session._spec_draft is not None,
         }
+        rows_of = getattr(self.model, "cache_rows", None)
+        if rows_of is not None:
+            # K/V rows resident by kind of cache (full layers, rings)
+            out["cache_rows"] = rows_of(
+                [s.ctx_len for s in self.session._slots if s is not None])
         cache = self.session.prefix_cache
         if cache is not None:
             out["prefix_cache"] = cache.stats()

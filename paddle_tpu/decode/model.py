@@ -7,8 +7,12 @@ ONCE over a *block*: pure functions of (parameters, rows, positions)
 for the embedding, the pre-attention norm and q/k/v, the
 post-attention projection, the feed-forward and the head.  The GPT-2
 block (``Gpt2Block``, served as ``TinyDecoderLM``) is defined here;
-OLMoE's lives in ``paddle_tpu/models/olmoe.py``.  The jitted programs
-take the block as a static argument.
+OLMoE's lives in ``paddle_tpu/models/olmoe.py``, K-EXAONE's in
+``paddle_tpu/models/exaone_moe.py``.  The jitted programs take the
+block as a static argument.  A block also says how its layers keep
+their rows (``PageRunCache``: every layer every row, in one page run a
+sequence; K-EXAONE's window layers keep a bounded ring each, beside a
+full layer, in the same pool).
 
 Prefill is ONE jitted program per length *bucket* (the shared pow2
 ladder of ``pallas/tuning/bucket.py``, from 64 up to the sequence
@@ -117,8 +121,48 @@ def _ln(x, scale):
     return (x - m) * jax.lax.rsqrt(v + 1e-5) * scale
 
 
+class PageRunCache:
+    """The cache side of a block, as the programs below ask for it, for
+    a model whose every layer keeps every row of a sequence in the one
+    page run the session gave it: pools ``(L, N, pg, H, dh)``, layer
+    ``li`` reached by moving the same table by ``li * N``.  A block
+    whose layers differ in what they keep (``models/exaone_moe.py``:
+    window layers on rings beside a full layer) defines these four
+    itself."""
+
+    def layer(self, li):
+        """The block as layer ``li`` has it: every layer the same."""
+        return self
+
+    def prompt_attention(self, q, k, v):
+        """Attention of one whole prompt, (T, heads, dh) each."""
+        return dense_prefill_attention(q, k, v, causal=True)
+
+    def store_prompt(self, pool, rows, flat):
+        """``pool`` with a prompt's ``rows`` (L, T, H, dh) written:
+        row ``i`` of every layer at flat pool row ``flat[i]``."""
+        L, N, pg, H, dh = pool.shape
+        return (pool.reshape(L, N * pg, H, dh).at[:, flat]
+                .set(rows.astype(pool.dtype)).reshape(L, N, pg, H, dh))
+
+    def cached_attention(self, k_pool, v_pool, li, q, k, v, flat, tables,
+                         lens):
+        """Layer ``li`` of a step: the new K/V rows written at the flat
+        rows ``flat`` of the donated pools, then ``q`` attending over
+        the slots' pages -> (the heads' outputs, shaped as ``q``, both
+        pools).  ``q`` (S, H, dh) is a decode step's, one row a slot
+        after ``lens`` cached rows; (S, T, H, dh) a chunk's."""
+        H, dh = k_pool.shape[3:]
+        k_pool = _write_rows(k_pool, li, flat, k.reshape(-1, H, dh))
+        v_pool = _write_rows(v_pool, li, flat, v.reshape(-1, H, dh))
+        pages = _layer_pages(k_pool, v_pool, li, tables)
+        a = (paged_attention(q, *pages, lens + 1) if q.ndim == 3
+             else paged_chunk_attention(q, *pages, lens))
+        return a, k_pool, v_pool
+
+
 @dataclasses.dataclass(frozen=True)
-class Gpt2Block:
+class Gpt2Block(PageRunCache):
     """The GPT-2 block as the paged skeleton below takes a block: pure
     functions of (parameters, rows, positions), hashable so that the
     jitted programs take it as a static argument.  ``rows`` have any
@@ -175,13 +219,14 @@ def _dense_blocks(block, params, tokens, heads, live):
     pos = jnp.arange(T, dtype=jnp.int32)
     x = block.embed(params, tokens, slice(0, T))
     ks, vs, reports = [], [], []
-    for lp in params["layers"]:
-        q, k, v = block.qkv(lp, x, pos, heads)
+    for li, lp in enumerate(params["layers"]):
+        lb = block.layer(li)
+        q, k, v = lb.qkv(lp, x, pos, heads)
         ks.append(k)
         vs.append(v)
-        a = dense_prefill_attention(q, k, v, causal=True)
-        x = block.attn_out(lp, x, a.reshape(T, -1))
-        x, report = block.mlp(lp, x, live)
+        a = lb.prompt_attention(q, k, v)
+        x = lb.attn_out(lp, x, a.reshape(T, -1))
+        x, report = lb.mlp(lp, x, live)
         reports.append(report)
     return x, jnp.stack(ks), jnp.stack(vs), _stack_reports(reports)
 
@@ -251,6 +296,8 @@ class PagedDecoderLM:
         self.max_len = int(max_len)
         self.page_size = int(page_size)
         self.pages_per_seq = int(pages_per_seq)
+        # rows one sequence can hold: its page run's
+        self.seq_rows = self.pages_per_seq * self.page_size
         self.bos_id, self.eos_id = int(bos_id), int(eos_id)
 
     def _make_pools(self, num_pages: int, dtype) -> None:
@@ -328,7 +375,7 @@ class PagedDecoderLM:
         the next power of two from 64 (or the page size) up, capped at
         what one sequence can hold — a model smaller than 64 rows has
         that capacity as its one bucket."""
-        cap = min(self.max_len, self.pages_per_seq * self.page_size)
+        cap = min(self.max_len, self.seq_rows)
         if not 0 < n <= cap:
             raise ValueError(
                 f"a prompt of {n} tokens is outside 1..{cap}, the rows "
@@ -370,12 +417,7 @@ class PagedDecoderLM:
         bucket = self.prefill_bucket(T)
         toks = np.zeros((bucket,), np.int32)
         toks[:T] = prompt
-        # the flat pool row of each bucket row: the table is null past
-        # the sequence's pages, so those rows scribble on page 0, as
-        # inactive decode slots do
-        rows = np.arange(bucket)
-        flat = (self.pool_table(pages)[rows // self.page_size]
-                * self.page_size + rows % self.page_size).astype(np.int32)
+        flat = self._prompt_rows(pages, bucket, T)
         with self._donating():
             logits, self.k_pool, self.v_pool, report = _prefill_bucket(
                 self.params, self.k_pool, self.v_pool, toks, flat,
@@ -385,6 +427,19 @@ class PagedDecoderLM:
         _M_PREFILL_TOKENS.inc(T)
         _M_PREFILL_PADDED.inc(bucket)
         return T, [], logits
+
+    def _prompt_rows(self, pages, bucket: int, n: int) -> np.ndarray:
+        """The flat pool row of each bucket row of an ``n``-token
+        prompt: the table is null past the sequence's pages, so those
+        rows scribble on page 0, as inactive decode slots do."""
+        rows = np.arange(bucket)
+        return (self.pool_table(pages)[rows // self.page_size]
+                * self.page_size + rows % self.page_size).astype(np.int32)
+
+    def cache_rows(self, lens) -> dict:
+        """K/V rows resident for sequences of ``lens`` rows, by kind of
+        cache, summed over layers: every layer keeps every row."""
+        return {"full": int(np.sum(lens)) * self.layers}
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page across both pools (the CoW split)."""
@@ -513,11 +568,8 @@ def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
     _M_PREFILL_PROGRAMS.inc(bucket=str(tokens.shape[0]))   # at trace
     live = jnp.arange(tokens.shape[0], dtype=jnp.int32) < n
     x, ks, vs, report = _dense_blocks(block, params, tokens, heads, live)
-    L, N, pg, H, dh = k_pool.shape
-    k_pool = (k_pool.reshape(L, N * pg, H, dh).at[:, flat]
-              .set(ks.astype(k_pool.dtype)).reshape(L, N, pg, H, dh))
-    v_pool = (v_pool.reshape(L, N * pg, H, dh).at[:, flat]
-              .set(vs.astype(v_pool.dtype)).reshape(L, N, pg, H, dh))
+    k_pool = block.store_prompt(k_pool, ks, flat)
+    v_pool = block.store_prompt(v_pool, vs, flat)
     last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1)
     return block.head(params, last)[0], k_pool, v_pool, report
 
@@ -543,14 +595,12 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
     lens1 = cached_len[None] if jnp.ndim(cached_len) == 0 else cached_len
     reports = []
     for li, lp in enumerate(params["layers"]):
-        q, k, v = block.qkv(lp, x, pos, heads)
-        k_pool = _write_rows(k_pool, li, flat, k)
-        v_pool = _write_rows(v_pool, li, flat, v)
-        a = paged_chunk_attention(
-            q[None], *_layer_pages(k_pool, v_pool, li, table[None]),
-            lens1)[0]
-        x = block.attn_out(lp, x, a.reshape(Ts, -1))
-        x, report = block.mlp(lp, x, None)       # every suffix row is real
+        lb = block.layer(li)
+        q, k, v = lb.qkv(lp, x, pos, heads)
+        a, k_pool, v_pool = lb.cached_attention(
+            k_pool, v_pool, li, q[None], k, v, flat, table[None], lens1)
+        x = lb.attn_out(lp, x, a[0].reshape(Ts, -1))
+        x, report = lb.mlp(lp, x, None)          # every suffix row is real
         reports.append(report)
     return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
 
@@ -570,7 +620,6 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
     the chunked kernel.  Fixed-shape per (S, k) — compiled once.
     Outputs as ``_decode_step``'s, the ids (S, k)."""
     S, T = tokens.shape
-    H, dh = k_pool.shape[3:]
     pos = lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (S, T)
     x = block.embed(params, tokens, pos)                    # (S, T, d)
     flat = (jnp.take_along_axis(tables, pos // page_size, axis=1)
@@ -579,13 +628,12 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
     live = jnp.broadcast_to(tables[:, :1] > 0, (S, T))
     reports = []
     for li, lp in enumerate(params["layers"]):
-        q, k, v = block.qkv(lp, x, pos, heads)
-        k_pool = _write_rows(k_pool, li, flat, k.reshape(S * T, H, dh))
-        v_pool = _write_rows(v_pool, li, flat, v.reshape(S * T, H, dh))
-        a = paged_chunk_attention(
-            q, *_layer_pages(k_pool, v_pool, li, tables), lens)
-        x = block.attn_out(lp, x, a.reshape(S, T, -1))
-        x, report = block.mlp(lp, x, live)
+        lb = block.layer(li)
+        q, k, v = lb.qkv(lp, x, pos, heads)
+        a, k_pool, v_pool = lb.cached_attention(
+            k_pool, v_pool, li, q, k, v, flat, tables, lens)
+        x = lb.attn_out(lp, x, a.reshape(S, T, -1))
+        x, report = lb.mlp(lp, x, live)
         reports.append(report)
     logits = block.head(params, x)
     return (logits, k_pool, v_pool, _stack_reports(reports),
@@ -613,13 +661,12 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
     live = tables[:, 0] > 0
     reports = []
     for li, lp in enumerate(params["layers"]):
-        q, k, v = block.qkv(lp, x, lens, heads)
-        k_pool = _write_rows(k_pool, li, flat, k)
-        v_pool = _write_rows(v_pool, li, flat, v)
-        a = paged_attention(
-            q, *_layer_pages(k_pool, v_pool, li, tables), lens + 1)
-        x = block.attn_out(lp, x, a.reshape(S, -1))
-        x, report = block.mlp(lp, x, live)
+        lb = block.layer(li)
+        q, k, v = lb.qkv(lp, x, lens, heads)
+        a, k_pool, v_pool = lb.cached_attention(
+            k_pool, v_pool, li, q, k, v, flat, tables, lens)
+        x = lb.attn_out(lp, x, a.reshape(S, -1))
+        x, report = lb.mlp(lp, x, live)
         reports.append(report)
     logits = block.head(params, x)
     return (logits, k_pool, v_pool, _stack_reports(reports),

@@ -57,6 +57,11 @@ _M_ACTIVE = _metrics.gauge(
     "decode_active_slots", "sequences currently decoding in the session")
 _M_WAITING = _metrics.gauge(
     "decode_waiting_requests", "admitted-but-queued generation requests")
+_M_CACHE_ROWS = _metrics.gauge(
+    "decode_cache_rows",
+    "K/V rows resident for the seated sequences, summed over layers, by "
+    "kind of cache: `full` = layers that keep every row, `window` = "
+    "layers on bounded rings (the model's `cache_rows`)")
 _M_STEPS = _metrics.counter(
     "decode_steps_total", "fixed-shape decode steps dispatched")
 _M_SLOT_STEPS = _metrics.counter(
@@ -413,6 +418,15 @@ class DecodeSession:
       enables speculative decoding
     - ``emits_probs``: decode returns distributions, not raw logits
       (affects sampling/beam log-prob handling)
+    - ``vocab``: the ids the model holds are ``0..vocab - 1`` (a slice
+      of the vocabulary where the rest is on other chips); ``submit``
+      raises ValueError (a 400 at the front) for a prompt id outside
+    - ``supports_fork`` False: a sequence's pages cannot be aliased (a
+      model that keeps window layers on per-sequence rings); a beam
+      request is refused as ``beam_unsupported``
+    - ``cache_rows(lens) -> {kind: rows}``: K/V rows resident for
+      sequences of those lengths, by kind of cache; gauged every tick
+      as ``decode_cache_rows{kind}``
     - ``prefill_bucket(prompt_len) -> int``: rows the full-prompt
       prefill pads to; it and the pad go on the ``decode.prefill`` span
     - logits from ``decode`` / ``verify_chunk`` that carry ``ids``
@@ -483,6 +497,23 @@ class DecodeSession:
             raise AdmissionRefused(
                 "spec_mode", "a speculative session verifies greedy "
                 "chunks; sampling and beam search are not available")
+        vocab = getattr(self.model, "vocab", None)
+        if vocab is not None and not all(
+                0 <= t < vocab for t in req.prompt
+                if isinstance(t, (int, np.integer))):
+            # a 400 at the front: the ids this model holds are 0..vocab-1
+            # (a slice of the vocabulary where the rest is on other chips)
+            raise ValueError(
+                f"prompt holds a token id outside 0..{vocab - 1}, the "
+                "ids this model holds")
+        if isinstance(req, BeamRequest) and not getattr(
+                self.model, "supports_fork", True):
+            _M_REFUSED.inc(reason="beam_unsupported")
+            raise AdmissionRefused(
+                "beam_unsupported",
+                "beam search forks a sequence's pages; this model keeps "
+                "window layers on per-sequence rings, which a fork would "
+                "have to copy (not supported yet)")
         if isinstance(req, BeamRequest) and req.beam_size > self.max_slots:
             _M_REFUSED.inc(reason="beam_too_wide")
             raise AdmissionRefused(
@@ -552,6 +583,14 @@ class DecodeSession:
             finally:
                 self._deliver()
                 _M_ACTIVE.set(self.active)
+                self._gauge_cache_rows()
+
+    def _gauge_cache_rows(self) -> None:
+        rows_of = getattr(self.model, "cache_rows", None)
+        if rows_of is not None:
+            lens = [s.ctx_len for s in self._slots if s is not None]
+            for kind, rows in rows_of(lens).items():
+                _M_CACHE_ROWS.set(rows, kind=kind)
 
     def _tick(self) -> int:
         if self._flight is not None:

@@ -1,13 +1,23 @@
 """A routed (sparse mixture-of-experts) feed-forward layer.
 
 ``routed_experts`` is the whole layer as one pure function of the rows
-and the stacked expert weights: a float32 router softmax, top-k, a
-stable sort of the ``rows x k`` assignments by expert, ONE grouped GEMM
-per projection over the sorted rows (``jax.lax.ragged_dot``: on a TPU
-the compiler lowers it to a grouped-matmul kernel that reads only the
-experts that were hit; it is never 64 masked dense matmuls), un-sort,
-weighted sum.  Fixed shapes: every row routes, whatever it holds; the
-``live`` mask only decides which rows the returned load counts.
+and the stacked expert weights: float32 router scores by the block's
+scoring rule (``softmax_scores``: OLMoE's; ``sigmoid_scores``: the
+DeepSeek-V3 router K-EXAONE uses), top-k, a stable sort of the ``rows x
+k`` assignments by expert, ONE grouped GEMM per projection over the
+sorted rows (``jax.lax.ragged_dot``: on a TPU the compiler lowers it to
+a grouped-matmul kernel that reads only the experts that were hit; it
+is never 64 masked dense matmuls), un-sort, weighted sum.  Fixed
+shapes: every row routes, whatever it holds; the ``live`` mask only
+decides which rows the returned load counts.
+
+The router always scores its full width.  A chip of an expert-parallel
+deployment is told which experts it holds (``held``: the first and the
+count of a contiguous range of the router's columns) and computes only
+its own experts' part of each row's sum: the assignments that went to
+experts held elsewhere sort behind the held ones, belong to no group of
+the grouped GEMM and weigh nothing.  What the other chips would add is
+not computed and nothing stands in for them.
 
 The four ``jax.named_scope``s (``moe_router``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine``) put every instruction of the layer
@@ -35,48 +45,101 @@ _M_EXPERTS_HIT = _metrics.counter(
     "moe_experts_hit_total",
     "experts with at least one live row, summed over layers and calls: "
     "the expert weights a call had to read")
+_M_ELSEWHERE = _metrics.counter(
+    "moe_assignments_elsewhere_total",
+    "(row, expert) assignments of live rows whose chosen expert this "
+    "chip does not hold (another chip of the expert-parallel group "
+    "does), summed over layers; 0 where every expert is held")
 _M_LOAD_MAX = _metrics.counter(
     "moe_expert_load_max_total",
     "live rows of the busiest expert, summed over layers and calls; "
     "over moe_assignments_total / experts it is how uneven routing was")
 
 
-def count_load(phase: str, load: np.ndarray) -> None:
-    """Feed the registry from one call's (layers, experts) load."""
+def count_load(phase: str, load: np.ndarray, elsewhere: int = 0) -> None:
+    """Feed the registry from one call's (layers, held experts) load
+    and its count of assignments that went to experts held elsewhere."""
     _M_ASSIGNMENTS.inc(int(load.sum()), phase=phase)
     _M_EXPERTS_HIT.inc(int((load > 0).sum()), phase=phase)
     _M_LOAD_MAX.inc(int(load.max(axis=-1).sum()), phase=phase)
+    if elsewhere:
+        _M_ELSEWHERE.inc(int(elsewhere), phase=phase)
 
 
-def route(m, wr, top_k: int):
+def softmax_scores(logits):
+    """OLMoE's rule.  Of the router's logits (R, E) -> (what ranks the
+    experts, what weighs them, the weights of the chosen (R, k) values
+    of the latter): the softmax over all experts for both, taken as it
+    is (not renormalised)."""
+    p = jax.nn.softmax(logits, axis=-1)
+    return p, p, lambda chosen: chosen
+
+
+def sigmoid_scores(bias, scale: float):
+    """The DeepSeek-V3 rule, as a function of the layer's selection
+    ``bias`` (E,): ``s = sigmoid(logits)``; experts are ranked by ``s +
+    bias`` and weighed by the unbiased ``s``, a row's weights being
+    ``scale * s / sum over its k chosen`` (all of them, wherever they
+    are held)."""
+    def rule(logits):
+        s = jax.nn.sigmoid(logits)
+        return s + bias.astype(_F32), s, lambda chosen: (
+            scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True))
+    return rule
+
+
+def route(m, wr, top_k: int, scores=softmax_scores):
     """Router of rows ``m`` (R, d) over ``wr`` (d, E): the float32
-    softmax over all experts, its ``top_k`` largest per row (a tie goes
-    to the lower expert index), the weights as the softmax gave them
-    (not renormalised) -> (weights (R, k) f32, experts (R, k) int32)."""
+    scores of all experts by the rule ``scores``, the ``top_k`` largest
+    per row of what it ranks by (a tie goes to the lower expert index),
+    and the weights it makes of what it weighs by -> (weights (R, k)
+    f32, experts (R, k) int32)."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(m, wr, preferred_element_type=_F32)
-        p = jax.nn.softmax(logits, axis=-1)
+        rank_by, weigh_by, weights_of = scores(logits)
     with jax.named_scope("moe_dispatch"):
-        return jax.lax.top_k(p, top_k)
+        top, idx = jax.lax.top_k(rank_by, top_k)
+        if weigh_by is not rank_by:
+            top = jnp.take_along_axis(weigh_by, idx, axis=-1)
+        return weights_of(top), idx
 
 
-def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None):
-    """``sum_e p_e * W_down,e( silu(W_gate,e m) * W_up,e m )`` over each
-    row's ``top_k`` experts.
+def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
+                   scores=softmax_scores, held=None):
+    """``sum_e w_e * W_down,e( silu(W_gate,e m) * W_up,e m )`` over
+    those of each row's ``top_k`` experts that are held here.
 
-    m (R, d); wr (d, E); w_gate, w_up (E, d, f); w_down (E, f, d);
-    ``live`` (R,) bool or None (all rows) -> (y (R, d) float32, load
-    (E,) int32: assignments per expert over the live rows)."""
+    m (R, d); wr (d, E); w_gate, w_up (C, d, f); w_down (C, f, d), the
+    C experts ``held = (first, C)`` names of the router's E (None: all
+    of them, C == E); ``live`` (R,) bool or None (all rows) -> (y
+    (R, d) float32, load (C,) int32: assignments per held expert over
+    the live rows, elsewhere () int32: the live rows' assignments to
+    experts not held).
+
+    The grouped GEMMs run over all R * k sorted rows: a row's k choices
+    can all be held here, at prefill as at decode, so no smaller static
+    bound is exact; the rows behind the last group are no group's and
+    are zeroed before the sum."""
     R, d = m.shape
     E = wr.shape[1]
-    w, idx = route(m, wr, top_k)
+    first, C = held or (0, E)
+    partial = (first, C) != (0, E)
+    w, idx = route(m, wr, top_k, scores)
     with jax.named_scope("moe_dispatch"):
         expert_of = idx.reshape(-1)                          # (R*k,)
+        if partial:
+            here = (idx >= first) & (idx < first + C)
+            w = jnp.where(here, w, 0.0)
+            # experts held elsewhere sort behind the last held one and
+            # index past the (C,) counts, where a scatter drops them
+            expert_of = jnp.where(here, idx - first, C).reshape(-1)
         order = jnp.argsort(expert_of, stable=True)
         xs = m[order // top_k]                               # sorted rows
-        sizes = jnp.zeros((E,), jnp.int32).at[expert_of].add(1)
-        load = sizes if live is None else jnp.zeros((E,), jnp.int32).at[
+        sizes = jnp.zeros((C,), jnp.int32).at[expert_of].add(1)
+        load = sizes if live is None else jnp.zeros((C,), jnp.int32).at[
             expert_of].add(jnp.repeat(live.astype(jnp.int32), top_k))
+        elsewhere = (R if live is None else jnp.sum(live)) * top_k \
+            - jnp.sum(load)
     with jax.named_scope("moe_experts"):
         g = jax.lax.ragged_dot(xs, w_gate, sizes,
                                preferred_element_type=_F32)
@@ -86,7 +149,10 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None):
         ys = jax.lax.ragged_dot(h, w_down, sizes,
                                 preferred_element_type=_F32)
     with jax.named_scope("moe_combine"):
+        if partial:
+            in_a_group = jnp.arange(R * top_k) < jnp.sum(sizes)
+            ys = jnp.where(in_a_group[:, None], ys, 0.0)
         back = jnp.zeros((R * top_k,), jnp.int32).at[order].set(
             jnp.arange(R * top_k, dtype=jnp.int32))
         y = jnp.einsum("rk,rkd->rd", w, ys[back].reshape(R, top_k, d))
-    return y, load
+    return y, load, elsewhere

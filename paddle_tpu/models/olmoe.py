@@ -25,7 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.decode.model import PagedDecoderLM
+from paddle_tpu.decode.model import PagedDecoderLM, PageRunCache
 from paddle_tpu.models import moe
 
 _F32 = jnp.float32
@@ -59,7 +59,7 @@ def _mm(x, w):
 
 
 @dataclasses.dataclass(frozen=True)
-class OlmoeBlock:
+class OlmoeBlock(PageRunCache):
     """See ``decode/model.py:Gpt2Block`` for the contract."""
 
     eps: float = 1e-5
@@ -91,7 +91,7 @@ class OlmoeBlock:
     def mlp(self, lp, x, live):
         """The routed layer; reports the (E,) assignments per expert
         over the live rows."""
-        y, load = moe.routed_experts(
+        y, load, _ = moe.routed_experts(
             self.router_rows(lp, x), lp["wr"], lp["w_gate"], lp["w_up"],
             lp["w_down"], top_k=self.top_k,
             live=None if live is None else live.reshape(-1))

@@ -4,11 +4,13 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Four kernel families, nine ``pl.pallas_call``s: the fused
+Four kernel families, ten ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill) and
-ragged paged attention (``decode/attention.py``, 3).
+ragged paged attention (``decode/attention.py``, 3 kernels under 4
+names: the chunk kernel is also called on grouped heads, Hq query heads
+on Hkv K/V heads, as ``ragged_paged_attention_gqa``).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 

@@ -1,0 +1,74 @@
+#!/usr/bin/env python3
+"""Are the paged skeleton's programs what they were?  Dump the compiled
+text (XLA:CPU, toy size) of `_decode_step`, `_verify_step`,
+`_prefill_chunk` and `_prefill_bucket` for `Gpt2Block` and `OlmoeBlock`
+with the metadata dropped (op_name / source lines, and the file and
+function tables at the head of the text), one file a program:
+
+    JAX_PLATFORMS=cpu PYTHONPATH=<tree A> python scripts/program_identity.py /tmp/a
+    JAX_PLATFORMS=cpu PYTHONPATH=<tree B> python scripts/program_identity.py /tmp/b
+    diff -r /tmp/a /tmp/b        # empty: the same programs
+
+Run it from this file in both cases (PYTHONPATH picks the tree), in
+processes with the same XLA flags: a refactor that moves code between
+functions changes only what is dropped here.  It cannot see the TPU
+lowering of a Pallas kernel; compare the kernels' jaxprs for that.
+"""
+
+import os
+import re
+import sys
+
+import numpy as np
+
+import jax.numpy as jnp
+
+
+def strip(text):
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    head, rest = text.split("\n", 1)
+    if "StackFrames" in rest:      # FileNames .. StackFrames tables
+        rest = rest[rest.index("StackFrames"):]
+        rest = rest[rest.index("\n\n"):]
+    return head + rest
+
+
+def dump(out, name, model, dm):
+    S, P = 4, model.pages_per_seq
+    kw = dict(heads=model.heads, page_size=model.page_size,
+              block=model.block)
+    pools = (model.params, model.k_pool, model.v_pool)
+    tables, lens = np.zeros((S, P), np.int32), np.zeros((S,), np.int32)
+    lowered = {
+        "decode": dm._decode_step.lower(
+            *pools, tables, lens, np.zeros((S,), np.int32), **kw),
+        "verify": dm._verify_step.lower(
+            *pools, tables, lens, np.zeros((S, 3), np.int32), **kw),
+        "chunk": dm._prefill_chunk.lower(
+            *pools, jnp.zeros((P,), jnp.int32), np.int32(8),
+            jnp.zeros((5,), jnp.int32), **kw),
+        "bucket": dm._prefill_bucket.lower(
+            *pools, np.zeros((64,), np.int32), np.zeros((64,), np.int32),
+            np.int32(3), heads=model.heads, block=model.block),
+    }
+    for key, low in lowered.items():
+        with open(os.path.join(out, f"{name}.{key}.txt"), "w") as f:
+            f.write(strip(low.compile().as_text()))
+
+
+def main(out):
+    from paddle_tpu.decode import model as dm
+    from paddle_tpu.models.olmoe import OlmoeLM
+
+    os.makedirs(out, exist_ok=True)
+    dump(out, "gpt2", dm.TinyDecoderLM(max_len=64), dm)
+    dump(out, "olmoe", OlmoeLM(
+        vocab=96, d_model=32, num_heads=4, num_layers=2, num_experts=8,
+        experts_per_tok=2, expert_width=16, max_len=64, num_pages=32,
+        page_size=8, pages_per_seq=8, dtype="float32"), dm)
+    print("tree", os.path.dirname(os.path.dirname(dm.__file__)), "dumped",
+          len(os.listdir(out)), "programs to", out)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1])

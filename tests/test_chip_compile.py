@@ -77,7 +77,7 @@ def _assert_step_outputs(compiled, slots, vocab):
 
 
 def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
-                           undonated_plan):
+                           undonated_plan, scatters=None):
     """A program ``(params, k_pool, v_pool, ...) -> (logits, k_pool,
     v_pool, ...)`` compiled for the chip: both pools are aliased input
     to output, and no instruction's result has as many elements as a
@@ -121,7 +121,9 @@ def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
                 and called.group(1) in scatter_roots)):
             stray.append((name, op))
     assert not stray, stray
-    assert n_scatters == 2 * pool_shape[0]        # K and V, every layer
+    # K and V, every layer (``scatters``: of a pool that is not one
+    # slab a layer)
+    assert n_scatters == (scatters or 2 * pool_shape[0])
     return text
 
 
@@ -159,6 +161,25 @@ def test_ragged_paged_attention_chunk_compiles(one_chip):
         ((S, P), jnp.int32), ((S,), jnp.int32))
     assert MARKER in text
     assert all("ragged_paged_attention_chunk/" in op
+               for op in _kernel_op_names(text))
+
+
+@pytest.mark.parametrize("T", [1, 4], ids=["step", "chunk"])
+def test_ragged_paged_attention_gqa_compiles(one_chip, T):
+    """64 query heads on 8 K/V heads of 128 over 128-row bf16 pages:
+    K-EXAONE's full layer at the serving shape (64 slots, 36 pages a
+    sequence)."""
+    from paddle_tpu.decode import attention as A
+
+    S, Hq, Hkv, D, page, N, P, dt = 64, 64, 8, 128, 128, 3073, 36, \
+        jnp.bfloat16
+    assert A.fits(page, Hq, D, Hkv)
+    text = _compiled_text(
+        A.ragged_paged_attention_gqa, one_chip,
+        ((S, T, Hq, D), dt), ((N, page, Hkv, D), dt),
+        ((N, page, Hkv, D), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
+    assert MARKER in text
+    assert all("ragged_paged_attention_gqa/" in op
                for op in _kernel_op_names(text))
 
 
@@ -373,6 +394,118 @@ def test_olmoe_suffix_prefill_writes_and_reads_its_pools_in_place(
     chunk = [op for op in _kernel_op_names(text)
              if "ragged_paged_attention_chunk" in op]
     assert len(chunk) == L and all("_prefill_chunk" in op for op in chunk)
+
+
+def _exaone_cell(one_chip, monkeypatch):
+    """The ``k-exaone-236b-a23b`` generate configuration at its real
+    sizes, as shapes on the described chip, built as its gen_config
+    builds the model: (cfg, params, pool, pool shape, block, table
+    width, sds)."""
+    import functools
+    import json
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.models import exaone_moe as ex
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)
+    g, L = cfg["generate"], cfg["num_hidden_layers"]
+    dtype = jnp.dtype(g["dtype"])
+    types = tuple(cfg["layer_types"][:L])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(
+            ex.init_params, jax.random.key(0), vocab=cfg["vocab_size"],
+            d=cfg["hidden_size"], heads=cfg["num_attention_heads"],
+            kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            dense_width=cfg["intermediate_size"],
+            expert_width=cfg["moe_intermediate_size"],
+            router_width=cfg["num_experts_published"],
+            held=cfg["num_experts"],
+            moe_layers=tuple(t == "sparse"
+                             for t in cfg["mlp_layer_types"][:L]),
+            dtype=dtype)))
+    ring = cfg["sliding_window"] // g["page_size"] + 1
+    block = ex.ExaoneMoeBlock(
+        layer_types=types, kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"], window=cfg["sliding_window"],
+        top_k=cfg["num_experts_per_tok"],
+        scale=cfg["routed_scaling_factor"], held=(0, cfg["num_experts"]),
+        full_pages=g["pages_per_seq"], ring_pages=ring)
+    width = g["pages_per_seq"] + ring * sum(t == ex.SLIDING for t in types)
+    shape = (1, g["num_pages"], g["page_size"],
+             cfg["num_key_value_heads"], cfg["head_dim"])
+    assert g["num_pages"] == g["slots"] * width + 1
+    return cfg, params, sds(shape, dtype), shape, block, width, sds
+
+
+def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
+                                                       monkeypatch):
+    """The decode step of the ``k-exaone-236b-a23b`` configuration at
+    its real sizes (layer 0 + 6, 16 held experts of 2,048 beside a
+    shared one, 64 heads on 8, 3,073 bf16 pages of 128 rows, 64 slots):
+    ONE grouped-heads kernel (the full layer's; the six rings are plain
+    XLA), the grouped GEMMs three a routed layer over 16 groups, 14
+    in-place scatters into the two donated pools and nothing else of a
+    pool's size (no slab, no reshaped copy), and a plan of weights +
+    pools + 24 MB."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, shape, block, width, sds = _exaone_cell(
+        one_chip, monkeypatch)
+    g, L, S = cfg["generate"], cfg["num_hidden_layers"], 64
+    compiled = dm._decode_step.lower(
+        params, pool, pool, sds((S, width), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.int32),
+        heads=cfg["num_attention_heads"], page_size=g["page_size"],
+        block=block).compile()
+    _assert_step_outputs(compiled, S, cfg["vocab_size"])
+    assert _planned_bytes(compiled) == 12_082_319_360
+    text = _assert_pools_in_place(
+        compiled, len(jax.tree.leaves(params)), shape, 2, float("inf"),
+        scatters=2 * L)
+    ops = _kernel_op_names(text)
+    gqa = [op for op in ops if "ragged_paged_attention" in op]
+    assert len(gqa) == 1 and "_decode_step)/attn_full/" in gqa[0]
+    assert "ragged_paged_attention_gqa" in gqa[0]
+    assert sum(op == "ragged-dot-none" for op in ops) == 3 * (L - 1)
+    for scope in ("attn_window", "moe_shared", "moe_router",
+                  "moe_dispatch", "moe_experts", "moe_combine"):
+        assert f"jit(_decode_step)/{scope}/" in text, scope
+
+
+def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch):
+    """The 4,096-row prefill bucket (the longest the cell's traffic
+    sends): the plan fits the chip beside 10.45 GB of weights and 1.61
+    GB of pools, both pools are aliased, the full layer runs the flash
+    kernel on 64 repeated heads and the six sliding layers run banded
+    in plain XLA (no T x T scores: they would be 4.3 GB a layer)."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, shape, block, width, sds = _exaone_cell(
+        one_chip, monkeypatch)
+    L, bucket = cfg["num_hidden_layers"], 4096
+    compiled = dm._prefill_bucket.lower(
+        params, pool, pool, sds((bucket,), jnp.int32),
+        sds((L, bucket), jnp.int32), sds((), jnp.int32),
+        heads=cfg["num_attention_heads"], block=block).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * math.prod(shape) * 2
+    planned = _planned_bytes(compiled)
+    assert planned == 13_979_091_456, planned
+    assert planned < 15.75e9
+    flash = [op for op in _kernel_op_names(compiled.as_text())
+             if not op.startswith("ragged-dot")]
+    assert len(flash) == 1 and "_prefill_bucket)/attn_full/" in flash[0]
+    assert "flash_attention_fwd" in flash[0]
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

@@ -106,7 +106,7 @@ def test_routed_layer_matches_dense_all_experts(kind):
     wg, wu = (rng.randn(E, d, f).astype(np.float32) * 0.3 for _ in "gu")
     wd = rng.randn(E, f, d).astype(np.float32) * 0.3
     live = np.arange(R) % 3 != 0
-    y, load = moe.routed_experts(jnp.asarray(m), wr, wg, wu, wd, top_k=k,
+    y, load, _ = moe.routed_experts(jnp.asarray(m), wr, wg, wu, wd, top_k=k,
                                  live=jnp.asarray(live))
     p = jax.nn.softmax(jnp.asarray(m @ wr), axis=-1)
     mask = ref.top_k_mask(p, k)
