@@ -332,9 +332,10 @@ class PagedDecoderLM:
                 f"({type(exc).__name__}: {exc}); both were made anew, "
                 "empty") from exc
 
-    def _observe(self, phase: str, report) -> None:
+    def _observe(self, phase: str, report, rows: int) -> None:
         """What the layers reported of one prefill or step (None for a
-        block that reports nothing), on the host."""
+        block that reports nothing) over the ``rows`` rows its program
+        handed each layer, on the host."""
 
     # -- dense forward (prefill + test oracle) ------------------------------
 
@@ -412,7 +413,7 @@ class PagedDecoderLM:
                     toks[cached_len:], heads=self.heads,
                     page_size=self.page_size, block=self.block)
                 logits = np.asarray(logits[-1])
-                self._observe("prefill", report)
+                self._observe("prefill", report, T - cached_len)
             return T, [], logits
         bucket = self.prefill_bucket(T)
         toks = np.zeros((bucket,), np.int32)
@@ -423,7 +424,7 @@ class PagedDecoderLM:
                 self.params, self.k_pool, self.v_pool, toks, flat,
                 np.int32(T), heads=self.heads, block=self.block)
             logits = np.asarray(logits)
-            self._observe("prefill", report)
+            self._observe("prefill", report, bucket)
         _M_PREFILL_TOKENS.inc(T)
         _M_PREFILL_PADDED.inc(bucket)
         return T, [], logits
@@ -487,7 +488,7 @@ class PagedDecoderLM:
         with self._donating(step._pools_in):
             with span("decode.logits_to_host"):
                 ids = np.asarray(step._ids)
-                self._observe("decode", step._report)
+                self._observe("decode", step._report, ids.size)
                 return StepLogits(step._logits, ids), []
 
     def _dispatch(self, jitted, tokens, tables, lens) -> StepInFlight:

@@ -319,6 +319,7 @@ class ExaoneMoeLM(PagedDecoderLM):
             dtype=dtype)
         self._routed = [i for i, t in enumerate(mlp_layer_types)
                         if t == "sparse"]
+        self._router_width = int(num_experts_published)
         self._make_pools(num_pages, dtype)
 
     def _make_pools(self, num_pages, dtype):
@@ -329,9 +330,11 @@ class ExaoneMoeLM(PagedDecoderLM):
         self.k_pool = jnp.zeros(shape, dtype)
         self.v_pool = jnp.zeros(shape, dtype)
 
-    def _observe(self, phase, report):
+    def _observe(self, phase, report, rows):
         report = np.asarray(report)[self._routed]      # (routed, held + 1)
-        moe.count_load(phase, report[:, :-1], int(report[:, -1].sum()))
+        path = moe.expert_path(rows, self.block.top_k, self._router_width)
+        moe.count_load(phase, report[:, :-1], path,
+                       int(report[:, -1].sum()))
 
     # -- pages: the full run, then a ring a sliding layer --------------------
 

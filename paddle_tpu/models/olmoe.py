@@ -152,5 +152,7 @@ class OlmoeLM(PagedDecoderLM):
             expert_width=int(expert_width), dtype=dtype)
         self._make_pools(num_pages, dtype)
 
-    def _observe(self, phase, report):
-        moe.count_load(phase, np.asarray(report))
+    def _observe(self, phase, report, rows):
+        load = np.asarray(report)                       # (layers, experts)
+        moe.count_load(phase, load, moe.expert_path(
+            rows, self.block.top_k, load.shape[1]))

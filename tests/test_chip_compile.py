@@ -127,6 +127,28 @@ def _assert_pools_in_place(compiled, n_param_leaves, pool_shape, itemsize,
     return text
 
 
+def _assert_experts_read_where_they_lie(text, experts, d, f):
+    """A program whose rows take the dense pass over the experts
+    (``models/moe.py:expert_path``): no grouped-GEMM custom call, and
+    nothing of the size of a layer's stacked gate, up or down matrices
+    but the parameters and their bitcasts, so no transposed or copied
+    weight: each matrix is streamed once from where it lies."""
+    assert "ragged-dot" not in text
+    stray = []
+    for line in text.splitlines():
+        r = _RESULT.match(line)
+        if not r or not r.group(2):
+            continue
+        if math.prod(map(int, r.group(2).split(","))) != experts * d * f:
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if not (r.group(3) in ("parameter", "bitcast") or (
+                r.group(3) == "fusion" and called
+                and called.group(1).startswith("bitcast_fusion"))):
+            stray.append((r.group(1), r.group(3)))
+    assert not stray, stray
+
+
 # (slots, heads, head_dim, page, pool pages, pages/seq, dtype): a real
 # decode batch, and the /generate model chip_smoke.py serves
 RPA_REAL = (64, 16, 128, 16, 2048, 32, jnp.bfloat16)
@@ -344,8 +366,12 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
     """The decode step of the ``olmoe-1b-7b`` generate configuration at
     its real sizes (8 layers, 64 experts of 1,024, bf16 weights and
     1,537 pages of 32 bf16 rows, 32 slots): the rpa kernel takes bf16
-    pages at (32, 16, 128), ``jax.lax.ragged_dot`` becomes the chip's
-    grouped-matmul kernel (three a layer, not 64 dense matmuls), both
+    pages at (32, 16, 128); the step's 32 rows take the dense pass, so
+    the experts ARE 64 masked dense matmuls a projection, batched into
+    one: at four rows an expert that reads the same bytes faster than
+    the chip's grouped-matmul kernel (``models/moe.py:expert_path``; a
+    prefill bucket over its threshold keeps ``jax.lax.ragged_dot``),
+    and no expert matrix is transposed or copied on its way; both
     donated pools are aliased and written and read in place, and the
     plan fits the chip.  The plan is pinned here as a literal: the
     configuration's ``planned_bytes`` is the undonated step's (PR 26)
@@ -361,17 +387,51 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
         block=block).compile()
     _assert_step_outputs(compiled, S, cfg["vocab_size"])
     planned = _planned_bytes(compiled)
-    assert planned == 10_367_895_552, planned
+    assert planned == 10_367_236_608 < 15.75e9, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 2,
         OLMOE_STEP_PLAN_UNDONATED)
     ops = _kernel_op_names(text)
-    rpa = [op for op in ops if "ragged_paged_attention" in op]
-    assert len(rpa) == L and all("_decode_step" in op for op in rpa)
-    assert sum(op == "ragged-dot-none" for op in ops) == 3 * L
+    assert len(ops) == L and all(
+        "_decode_step" in op and "ragged_paged_attention" in op
+        for op in ops)
+    _assert_experts_read_where_they_lie(
+        text, cfg["num_experts"], cfg["hidden_size"],
+        cfg["intermediate_size"])
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
         assert f"jit(_decode_step)/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("bucket", [256, 2048])
+def test_olmoe_prefill_bucket_takes_the_path_of_its_rows(
+        one_chip, monkeypatch, bucket):
+    """The expert layers of the ``olmoe-1b-7b`` cell's prefill programs
+    follow ``models/moe.py:expert_path``: the 2,048-row bucket (the
+    cell's longest) keeps one grouped GEMM a projection a layer, a
+    256-row bucket streams the experts as the decode step does; both
+    fit the chip."""
+    from paddle_tpu.decode import model as dm
+    from paddle_tpu.models import moe
+
+    cfg, params, pool, shape, block, sds = _olmoe_cell(one_chip, monkeypatch)
+    L = cfg["num_hidden_layers"]
+    compiled = dm._prefill_bucket.lower(
+        params, pool, pool, sds((bucket,), jnp.int32),
+        sds((bucket,), jnp.int32), sds((), jnp.int32), heads=shape[3],
+        block=block).compile()
+    assert _planned_bytes(compiled) < 15.75e9
+    text = compiled.as_text()
+    grouped = sum(op == "ragged-dot-none" for op in _kernel_op_names(text))
+    k, E = cfg["num_experts_per_tok"], cfg["num_experts"]
+    if moe.expert_path(bucket, k, E) == "grouped":
+        assert grouped == 3 * L
+    else:
+        _assert_experts_read_where_they_lie(
+            text, cfg["num_experts"], cfg["hidden_size"],
+            cfg["intermediate_size"])
+    assert {moe.expert_path(b, k, E) for b in (256, 2048)} == {
+        "dense", "grouped"}
 
 
 def test_olmoe_suffix_prefill_writes_and_reads_its_pools_in_place(
@@ -453,10 +513,12 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
     its real sizes (layer 0 + 6, 16 held experts of 2,048 beside a
     shared one, 64 heads on 8, 3,073 bf16 pages of 128 rows, 64 slots):
     ONE grouped-heads kernel (the full layer's; the six rings are plain
-    XLA), the grouped GEMMs three a routed layer over 16 groups, 14
+    XLA) and no other custom call: the step's 64 rows go through the
+    16 held experts of a routed layer as batched matmuls that read
+    each matrix once where it lies (``models/moe.py:expert_path``), 14
     in-place scatters into the two donated pools and nothing else of a
     pool's size (no slab, no reshaped copy), and a plan of weights +
-    pools + 24 MB."""
+    pools + 20 MB."""
     from paddle_tpu.decode import model as dm
 
     cfg, params, pool, shape, block, width, sds = _exaone_cell(
@@ -468,15 +530,17 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block).compile()
     _assert_step_outputs(compiled, S, cfg["vocab_size"])
-    assert _planned_bytes(compiled) == 12_082_319_360
+    planned = _planned_bytes(compiled)
+    assert planned == 12_078_473_216 < 15.75e9, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 2, float("inf"),
         scatters=2 * L)
-    ops = _kernel_op_names(text)
-    gqa = [op for op in ops if "ragged_paged_attention" in op]
+    gqa = _kernel_op_names(text)
     assert len(gqa) == 1 and "_decode_step)/attn_full/" in gqa[0]
     assert "ragged_paged_attention_gqa" in gqa[0]
-    assert sum(op == "ragged-dot-none" for op in ops) == 3 * (L - 1)
+    _assert_experts_read_where_they_lie(
+        text, cfg["num_experts"], cfg["hidden_size"],
+        cfg["moe_intermediate_size"])
     for scope in ("attn_window", "moe_shared", "moe_router",
                   "moe_dispatch", "moe_experts", "moe_combine"):
         assert f"jit(_decode_step)/{scope}/" in text, scope
@@ -502,10 +566,12 @@ def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch):
     planned = _planned_bytes(compiled)
     assert planned == 13_979_091_456, planned
     assert planned < 15.75e9
-    flash = [op for op in _kernel_op_names(compiled.as_text())
-             if not op.startswith("ragged-dot")]
+    ops = _kernel_op_names(compiled.as_text())
+    flash = [op for op in ops if not op.startswith("ragged-dot")]
     assert len(flash) == 1 and "_prefill_bucket)/attn_full/" in flash[0]
     assert "flash_attention_fwd" in flash[0]
+    # thousands of rows: the experts keep the grouped GEMM
+    assert sum(op == "ragged-dot-none" for op in ops) == 3 * (L - 1)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

@@ -166,12 +166,19 @@ def _toy_layer(rng, R=23, d=16, E=16, f=12):
     return m, wr, b, wg, wu, wd
 
 
-def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
+# moe.expert_path at 16 experts top-3 or top-4: 23 rows take the dense
+# pass, rows past DENSE_MAX_ROWS the grouped GEMM
+BOTH_PATHS = pytest.mark.parametrize(
+    "R", [23, moe.DENSE_MAX_ROWS + 23], ids=["dense", "grouped"])
+
+
+@BOTH_PATHS
+def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(R):
     """The share test: each of 8 chips routes over all 16 experts and
     computes its own 2; their routed parts plus the shared expert once
     add up to the reference's whole layer (``held`` = all)."""
     rng = np.random.RandomState(11)
-    m, wr, b, wg, wu, wd = _toy_layer(rng)
+    m, wr, b, wg, wu, wd = _toy_layer(rng, R=R)
     d, f = m.shape[1], wg.shape[2]
     ws = [rng.randn(*s).astype(np.float32) * 0.3
           for s in ((d, f), (d, f), (f, d))]
@@ -201,9 +208,11 @@ def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
     assert elsewhere == 7 * live.sum() * k
 
 
-def test_a_share_is_its_own_experts_part_of_the_reference():
+@BOTH_PATHS
+def test_a_share_is_its_own_experts_part_of_the_reference(R):
     rng = np.random.RandomState(12)
-    m, wr, b, wg, wu, wd = _toy_layer(rng)
+    m, wr, b, wg, wu, wd = _toy_layer(rng, R=R)
+    assert moe.expert_path(R, 4, 16) == ("dense" if R == 23 else "grouped")
     held = (5, 6)
     sl = slice(5, 11)
     y, load, away = moe.routed_experts(
@@ -222,6 +231,67 @@ def test_a_share_is_its_own_experts_part_of_the_reference():
     # experts sum to the scale, the bias nowhere in them
     np.testing.assert_allclose(np.asarray(weight).sum(axis=1), 2.5,
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("tie", [False, True], ids=["random", "tie"])
+def test_a_share_is_the_same_sum_on_both_paths(tie):
+    """A share's rows as one call of many rows (the grouped GEMM) and
+    as two calls of few (the dense pass), with a live mask, with rows
+    none of whose choices is held here (they get exactly nothing,
+    either way) and with two held experts that tie on every row."""
+    rng = np.random.RandomState(13)
+    R = moe.DENSE_MAX_ROWS + 23
+    m, wr, b, wg, wu, wd = _toy_layer(rng, R=R)
+    if tie:
+        wr[:, 7], b[7] = wr[:, 6], b[6]
+    held, sl, k = (5, 4), slice(5, 9), 3
+    live = np.arange(R) % 4 != 0
+    half = R // 2
+
+    def share(rows):
+        return moe.routed_experts(
+            jnp.asarray(m[rows]), wr, wg[sl], wu[sl], wd[sl], top_k=k,
+            live=jnp.asarray(live[rows]),
+            scores=moe.sigmoid_scores(jnp.asarray(b), 2.5), held=held)
+
+    assert (moe.expert_path(R, k, 16), moe.expert_path(R - half, k, 16)) == (
+        "grouped", "dense")
+    y, load, away = share(slice(None))
+    (y0, load0, away0), (y1, load1, away1) = (
+        share(slice(half)), share(slice(half, None)))
+    np.testing.assert_allclose(np.concatenate([y0, y1]), y, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_array_equal(load0 + load1, load)
+    assert int(away0) + int(away1) == int(away)
+    _, mask = ref._router(jnp.asarray(wr), jnp.asarray(b), jnp.asarray(m),
+                          top_k=k, scale=2.5, ablate=None)
+    mask = np.asarray(mask)
+    none_held = ~mask[:, sl].any(axis=1)
+    assert 0 < none_held[:half].sum() and 0 < none_held[half:].sum()
+    for got in (y, np.concatenate([y0, y1])):
+        assert not np.asarray(got)[none_held].any()
+        assert np.asarray(got)[~none_held].any(axis=1).all()
+    np.testing.assert_array_equal(load, mask[live][:, sl].sum(axis=0))
+    if tie:                 # never expert 7 without expert 6
+        assert not np.any(mask[:, 7] & ~mask[:, 6])
+        assert mask[:, 7].any()
+
+
+def test_expert_path_counter_counts_the_routed_layers(model):
+    def counts():
+        return {(v["labels"]["path"], v["labels"]["phase"]): v["value"]
+                for v in metrics.snapshot().get(
+                    "moe_expert_path_total", {"values": []})["values"]}
+
+    before = counts()
+    _through_the_caches(model, _prompt(9, seed=6), [3, 4])
+    after = counts()
+    routed = 3              # of the four layers; layer 0 is dense
+    # the 64-row bucket; two steps of S = 4 rows, too few to hit most
+    # of the 16 experts with three choices each
+    assert {key: after[key] - before.get(key, 0) for key in after
+            if after[key] != before.get(key, 0)} == {
+        ("dense", "prefill"): routed, ("grouped", "decode"): 2 * routed}
 
 
 def test_counters_count_held_experts_and_what_went_elsewhere(model):

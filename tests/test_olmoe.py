@@ -97,17 +97,35 @@ def _router(kind, rng, d, E):
     return wr
 
 
-@pytest.mark.parametrize("kind", ["random", "tie", "one_expert"])
-def test_routed_layer_matches_dense_all_experts(kind):
+# moe.expert_path at 8 experts top-3: rows too few to hit most experts
+# and rows past DENSE_MAX_ROWS take the grouped GEMM, those between the
+# dense pass
+PATH_OF_ROWS = {4: "grouped", 19: "dense", moe.DENSE_MAX_ROWS + 19: "grouped"}
+MANY = max(PATH_OF_ROWS)
+
+
+def _toy_layer(kind, R):
     rng = np.random.RandomState(3)
-    R, d, E, f, k = 19, 16, 8, 12, 3
+    d, E, f = 16, 8, 12
     m = np.abs(rng.randn(R, d)).astype(np.float32)
+    if kind == "one_expert":
+        m[:, 0] += 1.0                # no row too small for expert 3
     wr = _router(kind, rng, d, E)
     wg, wu = (rng.randn(E, d, f).astype(np.float32) * 0.3 for _ in "gu")
     wd = rng.randn(E, f, d).astype(np.float32) * 0.3
+    return m, wr, wg, wu, wd
+
+
+@pytest.mark.parametrize("R", list(PATH_OF_ROWS),
+                         ids=["few_grouped", "dense", "many_grouped"])
+@pytest.mark.parametrize("kind", ["random", "tie", "one_expert"])
+def test_routed_layer_matches_dense_all_experts(kind, R):
+    k = 3
+    m, wr, wg, wu, wd = _toy_layer(kind, R)
+    assert moe.expert_path(R, k, wr.shape[1]) == PATH_OF_ROWS[R]
     live = np.arange(R) % 3 != 0
     y, load, _ = moe.routed_experts(jnp.asarray(m), wr, wg, wu, wd, top_k=k,
-                                 live=jnp.asarray(live))
+                                    live=jnp.asarray(live))
     p = jax.nn.softmax(jnp.asarray(m @ wr), axis=-1)
     mask = ref.top_k_mask(p, k)
     want = ref.experts(jnp.asarray(m), mask, p, jnp.asarray(wg),
@@ -120,6 +138,83 @@ def test_routed_layer_matches_dense_all_experts(kind):
         assert not np.any(np.asarray(mask)[:, 5] & ~np.asarray(mask)[:, 2])
     if kind == "one_expert":
         assert int(load[3]) == live.sum()
+
+
+@pytest.mark.parametrize("kind", ["random", "tie", "one_expert"])
+def test_the_two_paths_compute_the_same_sum(kind):
+    """The same rows as one call of many rows (the grouped GEMM) and as
+    two calls of few (the dense pass): a row's sum does not depend on
+    the rows beside it."""
+    m, wr, wg, wu, wd = _toy_layer(kind, MANY)
+    live = np.arange(MANY) % 3 != 0
+    half = MANY // 2
+    assert moe.expert_path(MANY - half, 3, wr.shape[1]) == "dense"
+
+    def layer(rows):
+        return moe.routed_experts(
+            jnp.asarray(m[rows]), wr, wg, wu, wd, top_k=3,
+            live=jnp.asarray(live[rows]))
+
+    y, load, away = layer(slice(None))
+    (y0, load0, away0), (y1, load1, away1) = (
+        layer(slice(half)), layer(slice(half, None)))
+    np.testing.assert_allclose(np.concatenate([y0, y1]), y, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_array_equal(load0 + load1, load)
+    assert int(away) == int(away0) == int(away1) == 0
+
+
+def test_expert_path_is_one_interval_of_the_row_count():
+    """The rule: the two cells' decode steps (32 slots over 64 experts,
+    64 over 128, top-8) take the dense pass, every prefill bucket of
+    their ladders over the threshold the grouped GEMM, and so does a
+    step of so few slots that most experts get no row; in the row count
+    the dense pass has one interval."""
+    from paddle_tpu.pallas.tuning.bucket import bucket_ladder
+
+    cells = [(32, 8, 64), (64, 8, 128)]             # (slots, top-k, E)
+    ladder = [b for b in bucket_ladder(4096) + (4608,) if b >= 128]
+    over = [b for b in ladder if b > moe.DENSE_MAX_ROWS]
+    assert over[0] == 2 * moe.DENSE_MAX_ROWS and len(over) >= 3
+    for slots, k, E in cells:
+        assert moe.expert_path(slots, k, E) == "dense"
+        assert all(moe.expert_path(b, k, E) == "grouped" for b in over)
+        assert all(moe.expert_path(b, k, E) == "dense"
+                   for b in ladder if b not in over)
+        assert moe.expert_path(4, k, E) == "grouped"
+        paths = [moe.expert_path(r, k, E) for r in range(1, 5000)]
+        dense = [r for r, p in enumerate(paths, 1) if p == "dense"]
+        first = -(-moe.DENSE_MIN_PER_EXPERT * E // k)
+        assert dense == list(range(first, moe.DENSE_MAX_ROWS + 1))
+        assert set(paths) == {"dense", "grouped"}
+
+
+def _path_counts(snap):
+    return {(v["labels"]["path"], v["labels"]["phase"]): v["value"]
+            for v in snap.get("moe_expert_path_total",
+                              {"values": []})["values"]}
+
+
+def test_expert_path_counter_counts_a_routed_layer_a_call(model):
+    """``moe_expert_path_total``: one a routed layer a call, under the
+    rule's answer for the call's rows and the call's phase."""
+    def moved(before):
+        after = _path_counts(metrics.snapshot())
+        return {key: after[key] - before.get(key, 0) for key in after
+                if after[key] != before.get(key, 0)}
+
+    L = SIZES["num_layers"]
+    before = _path_counts(metrics.snapshot())
+    _, pages = _decode_rows(model, _prompt(21), _prompt(3, seed=1))
+    model.allocator.free(pages)
+    # one prefill in the 64-row bucket; three steps of S = 4 rows, too
+    # few to hit most of the 8 experts with two choices each
+    assert moved(before) == {("dense", "prefill"): L,
+                             ("grouped", "decode"): 3 * L}
+    before = _path_counts(metrics.snapshot())
+    model._observe("prefill", np.ones((L, 8), np.int32),
+                   2 * moe.DENSE_MAX_ROWS)
+    assert moved(before) == {("grouped", "prefill"): L}
 
 
 # -- prefill and decode through the paged cache -----------------------------
