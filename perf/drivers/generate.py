@@ -41,8 +41,9 @@ def _generate(address, prompt, max_tokens):
 
 def warm_prefill(model, traffic, say):
     """Every (prompt length, token budget) the traffic sends, prefilled
-    once: ``prefill`` runs op by op and compiles each op per prompt
-    length and per page count."""
+    once: a prompt runs as its length bucket's one jitted program
+    (PR 25), so this compiles one program a bucket the traffic uses
+    and the further calls find it."""
     t0 = time.perf_counter()
     for T, _ in traffic["prompt_lengths"]:
         for budget, _ in traffic["max_tokens"]:
@@ -158,8 +159,8 @@ def client_metrics(out):
     failed = [r for r in sent if not r["complete"]]
     tokens = sum(stats.in_window(r["stamps"], t_open, t_close)
                  for r in recs)
-    ttft = [(r["stamps"][0] - r["t_send"]) * 1e3 for r in recs
-            if r["stamps"] and r["t_send"] >= t_open]
+    first = [r for r in recs if r["stamps"] and r["t_send"] >= t_open]
+    ttft = [(r["stamps"][0] - r["t_send"]) * 1e3 for r in first]
     itl = [(b - a) * 1e3 for r in recs
            for a, b in zip(r["stamps"], r["stamps"][1:])
            if t_open <= b < t_close]
@@ -180,8 +181,57 @@ def client_metrics(out):
             "failures": [f"{r['status']} {r.get('error')}"
                          for r in failed][:5],
             "tokens": tokens, "window_s": t_close - t_open,
-            "ttft_ms": ttft, "itl_ms": itl, "kv_rows": rows,
+            "ttft_ms": ttft, "sent_s": [r["t_send"] - t_open for r in first],
+            "itl_ms": itl, "kv_rows": rows,
             "drain_s": out["end"] - t_close}
+
+
+CONVOY_WITHIN_S = 0.005
+
+
+def client_report(cm, out, say):
+    """The generate cells' end-to-end numbers from ``client_metrics``'s
+    reduction, with the log lines every generate driver prints.  Two
+    times to first token may be judged: the mean of the middle half of
+    the window's requests (``stats.interquartile_mean``) and the 95th
+    percentile of all of them.  The median, ``n`` and the share of the
+    requests sent within 5 ms after another client's (a convoy: they
+    are seated one after another in one tick, each behind the prefills
+    before it) go with them: ``run.py`` prints what BENCHMARK.json does
+    not list for the cell under the result line's ``beside``."""
+    if not cm["ttft_ms"] or cm["tokens"] <= 0:
+        raise SystemExit("perf: no request produced a token in the window")
+    ttft = cm["ttft_ms"]
+    e2e = {"gen_tokens_per_s": stats.rate(cm["tokens"], out["open"],
+                                          out["close"]),
+           "gen_ttft_mid_ms": stats.interquartile_mean(ttft),
+           "gen_ttft_p95_ms": stats.percentile(ttft, 0.95),
+           "gen_ttft_median_ms": stats.median(ttft),
+           "gen_ttft_n": len(ttft),
+           "gen_convoy_share": stats.follower_share(cm["sent_s"],
+                                                    CONVOY_WITHIN_S)}
+    say(f"{cm['attempted']} requests, {cm['tokens']} tokens in "
+        f"{cm['window_s']:.3f}s; ttft mid {e2e['gen_ttft_mid_ms']} ms "
+        f"median {e2e['gen_ttft_median_ms']} ms p95 "
+        f"{e2e['gen_ttft_p95_ms']} ms mean {sum(ttft) / len(ttft)} ms "
+        f"(n={len(ttft)}); sent within 5 ms after another "
+        f"{e2e['gen_convoy_share']:.4f}; "
+        f"itl median "
+        f"{stats.median(cm['itl_ms']) if cm['itl_ms'] else None} ms "
+        f"(n={len(cm['itl_ms'])}); drain after the window "
+        f"{cm['drain_s']:.1f}s; longest silence of all streams "
+        f"{cm['longest_silence_s'][0]:.3f}s, "
+        f"{cm['longest_silence_s'][1]:.1f}s into the window")
+    say("requests as sent [send - open s, prompt, budget, ttft ms]: "
+        + json.dumps([[round(r["t_send"] - out["open"], 4), r["prompt_len"],
+                       r["max_tokens"],
+                       round((r["stamps"][0] - r["t_send"]) * 1e3, 2)
+                       if r["stamps"] else None]
+                      for r in sorted(
+                          (r for r in out["records"]
+                           if r["t_send"] is not None),
+                          key=lambda r: r["t_send"])]))
+    return e2e
 
 
 def run(ctx):
@@ -234,12 +284,9 @@ def run(ctx):
 
         seconds = (min(ctx["seconds"], float(traffic["trace_seconds"]))
                    if ctx["trace"] else ctx["seconds"])
-        ramp = float(traffic["ramp_seconds"])
-        spec = {"address": srv.address, "loop": traffic["loop"],
-                "clients": traffic["clients"], "seconds": seconds,
-                "ramp_seconds": ramp,
-                "seed": ctx["seed"], "vocab": model.vocab,
-                "deal": traffic["deal"]}
+        spec = loadgen.spec_of(traffic, srv.address, seconds, ctx["seed"],
+                               model.vocab)
+        ramp = spec["ramp_seconds"]
         with tempfile.NamedTemporaryFile("w", suffix=".json",
                                          delete=False) as f:
             json.dump(spec, f)
@@ -282,30 +329,7 @@ def run(ctx):
     if cm["failed"]:
         say(f"{cm['failed']} of {cm['attempted']} requests failed: "
             f"{cm['failures']}")
-    if not cm["ttft_ms"] or cm["tokens"] <= 0:
-        raise SystemExit("perf: no request produced a token in the window")
-    e2e = {"gen_tokens_per_s": stats.rate(cm["tokens"], out["open"],
-                                          out["close"]),
-           "gen_ttft_p50_ms": stats.median(cm["ttft_ms"])}
-    say(f"{cm['attempted']} requests, {cm['tokens']} tokens in "
-        f"{cm['window_s']:.3f}s; ttft median "
-        f"{e2e['gen_ttft_p50_ms']} ms p95 "
-        f"{stats.percentile(cm['ttft_ms'], 0.95)} ms "
-        f"(n={len(cm['ttft_ms'])}); itl median "
-        f"{stats.median(cm['itl_ms']) if cm['itl_ms'] else None} ms "
-        f"(n={len(cm['itl_ms'])}); drain after the window "
-        f"{cm['drain_s']:.1f}s; longest silence of all streams "
-        f"{cm['longest_silence_s'][0]:.3f}s, "
-        f"{cm['longest_silence_s'][1]:.1f}s into the window")
-    say("requests as sent [send - open s, prompt, budget, ttft ms]: "
-        + json.dumps([[round(r["t_send"] - out["open"], 3), r["prompt_len"],
-                       r["max_tokens"],
-                       round((r["stamps"][0] - r["t_send"]) * 1e3, 1)
-                       if r["stamps"] else None]
-                      for r in sorted(
-                          (r for r in out["records"]
-                           if r["t_send"] is not None),
-                          key=lambda r: r["t_send"])]))
+    e2e = client_report(cm, out, say)
     itemsize = np.dtype(model.k_pool.dtype).itemsize
     record = {
         "correct": correct, "attempted": cm["attempted"],

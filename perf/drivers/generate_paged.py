@@ -25,8 +25,9 @@ import time
 
 import numpy as np
 
-from perf.drivers.generate import _generate, client_metrics, instrument
-from perf.harness import loadgen, modules, runtime, stats, trace as tr
+from perf.drivers.generate import (_generate, client_metrics,
+                                   client_report, instrument)
+from perf.harness import loadgen, modules, runtime, trace as tr
 from perf.harness.flops import kv_read_bytes
 
 
@@ -263,11 +264,9 @@ def run(ctx):
 
         seconds = (min(ctx["seconds"], float(traffic["trace_seconds"]))
                    if ctx["trace"] else ctx["seconds"])
-        ramp = float(traffic["ramp_seconds"])
-        spec = {"address": srv.address, "loop": traffic["loop"],
-                "clients": traffic["clients"], "seconds": seconds,
-                "ramp_seconds": ramp, "seed": ctx["seed"],
-                "vocab": model.vocab, "deal": traffic["deal"]}
+        spec = loadgen.spec_of(traffic, srv.address, seconds, ctx["seed"],
+                               model.vocab)
+        ramp = spec["ramp_seconds"]
         with tempfile.NamedTemporaryFile("w", suffix=".json",
                                          delete=False) as f:
             json.dump(spec, f)
@@ -308,20 +307,7 @@ def run(ctx):
     if cm["failed"]:
         say(f"{cm['failed']} of {cm['attempted']} requests failed: "
             f"{cm['failures']}")
-    if not cm["ttft_ms"] or cm["tokens"] <= 0:
-        raise SystemExit("perf: no request produced a token in the window")
-    e2e = {"gen_tokens_per_s": stats.rate(cm["tokens"], out["open"],
-                                          out["close"]),
-           "gen_ttft_p50_ms": stats.median(cm["ttft_ms"])}
-    say(f"{cm['attempted']} requests, {cm['tokens']} tokens in "
-        f"{cm['window_s']:.3f}s; ttft median {e2e['gen_ttft_p50_ms']} ms "
-        f"p95 {stats.percentile(cm['ttft_ms'], 0.95)} ms "
-        f"(n={len(cm['ttft_ms'])}); itl median "
-        f"{stats.median(cm['itl_ms']) if cm['itl_ms'] else None} ms "
-        f"(n={len(cm['itl_ms'])}); drain after the window "
-        f"{cm['drain_s']:.1f}s; longest silence of all streams "
-        f"{cm['longest_silence_s'][0]:.3f}s, "
-        f"{cm['longest_silence_s'][1]:.1f}s into the window")
+    e2e = client_report(cm, out, say)
     facts["requests_in_window"] = cm["attempted"]
     record = {
         "correct": correct, "attempted": cm["attempted"],

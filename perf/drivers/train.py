@@ -220,7 +220,7 @@ def run(ctx):
     env.pop("state0", None)
 
     log_period = int(traffic["log_period"])
-    reads = []
+    reads, read_at = [], []
 
     def loop(seconds):
         """Steps until ``seconds`` have passed, then the block that
@@ -236,6 +236,7 @@ def run(ctx):
             if steps % log_period == 0:
                 with spans.span("perf.loss_read"):
                     reads.append(float(np.asarray(last)))
+                read_at.append(time.perf_counter())
             if time.perf_counter() - t_open >= seconds:
                 break
         with spans.span("perf.block_until_ready"):
@@ -260,7 +261,7 @@ def run(ctx):
     compiled_text = {"step": compiled.as_text()} if ctx["trace"] else {}
     del compiled
     spans.seconds.clear()
-    del reads[:]
+    del reads[:], read_at[:]
 
     seconds = (min(ctx["seconds"], float(traffic["trace_seconds"]))
                if ctx["trace"] else ctx["seconds"])
@@ -282,6 +283,13 @@ def run(ctx):
     rate = stats.rate(steps * built["work_per_step"], t_open, t_close)
     say(f"{steps} steps in {window_s:.3f}s: {rate:.1f} "
         f"{built['work_unit']}/s; losses read {reads[:3]}..{reads[-1:]}")
+    # a run that reads low: one long period is a stall, all of them
+    # longer is a slower device (a label for the log, no metric)
+    periods = np.diff([t_open] + read_at)
+    if len(periods):
+        say(f"{log_period} steps from loss read to loss read: median "
+            f"{np.median(periods):.4f}s, longest {periods.max():.4f}s "
+            f"(period {int(periods.argmax()) + 1} of {len(periods)})")
 
     record = {
         "correct": correct, "attempted": steps,

@@ -1,9 +1,10 @@
 """Reduction of a jax profiler trace (``*.xplane.pb``) to numbers.
 
-Read with ``jax.profiler.ProfileData`` alone (``benchmark/xprof.py``
-had the right idea and needed TensorFlow's protobufs); the category
-rules are copied from it.  Everything after ``load`` works on plain
-tuples, so the arithmetic is tested on small hand-made traces.
+Read with ``jax.profiler.ProfileData`` alone (``benchmark/xprof.py``,
+which PR 28 deleted, had the right idea and needed TensorFlow's
+protobufs); the category rules are copied from it.  Everything after
+``load`` works on plain tuples, so the arithmetic is tested on small
+hand-made traces.
 
 A trace is reduced inside a *window*: the host span named
 ``WINDOW_SPAN`` that the driver writes round the traced loop.  Device
@@ -243,18 +244,24 @@ def top_ops(trace, n=10, plane=None, categories=None):
             + [[k, t / 1e9] for k, t in ops])
 
 
-def idle_gaps(trace, n=5, plane=None, span_prefix="perf."):
+SPAN_PREFIXES = ("perf.", "decode.", "executor.", "serving.")
+
+
+def idle_gaps(trace, n=5, plane=None, span_prefixes=SPAN_PREFIXES):
     """[[what the host was doing, seconds]] for the ``n`` longest gaps
     between device ops inside the window: each gap is named after the
-    innermost host span (name starting with ``span_prefix``, the
-    window span excepted) that covers at least half of it."""
+    innermost host span (name starting with one of ``span_prefixes``:
+    the runner's and the program's own, so a gap inside a tick reads
+    ``decode.logits_to_host`` or ``decode.admit`` and not the tick
+    round it; the window span excepted) that covers at least half of
+    it."""
     lo, hi = window(trace)
     plane = plane or sorted(trace["devices"])[0]
     ran = clip(union((ev[1], ev[1] + ev[2])
                      for ev in trace["devices"][plane]), lo, hi)
     gaps = sorted(subtract([(lo, hi)], ran), key=lambda g: g[0] - g[1])[:n]
     spans = [(nm, s, s + d) for _, nm, s, d in trace["host"]
-             if nm.startswith(span_prefix) and nm != WINDOW_SPAN]
+             if nm.startswith(tuple(span_prefixes)) and nm != WINDOW_SPAN]
     out = []
     for gs, ge in gaps:
         over = [(nm, min(e, ge) - max(s, gs), e - s) for nm, s, e in spans

@@ -83,6 +83,13 @@ def load_reader(metric):
     """``perf/layer_metrics/<metric>.py``, loaded by path (a metric's
     name may hold dots)."""
     path = os.path.join(HERE, "layer_metrics", metric + ".py")
+    if not os.path.exists(path) and "." in metric:
+        # one reader serves several names: ``<reader>.<tag>`` reads as
+        # ``<reader>`` where no file of its own exists (a quantity whose
+        # cells report different end-to-end metrics is listed once for
+        # each, and the arithmetic is one)
+        path = os.path.join(HERE, "layer_metrics",
+                            metric.rsplit(".", 1)[0] + ".py")
     spec = importlib.util.spec_from_file_location(
         "perf_layer_metric_" + metric.replace(".", "_").replace("-", "_"),
         path)
@@ -154,6 +161,8 @@ def main(argv=None):
             out["metrics"][m["name"]] = {"value": values[m["name"]],
                                          "unit": m["unit"]}
     out["device"] = dev
+    out["beside"] = {k: v for k, v in values.items()
+                     if k not in out["metrics"]}
     runtime.say(f"facts: {record['facts']}")
     print(json.dumps(out), flush=True)
     return 0

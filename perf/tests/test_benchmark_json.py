@@ -80,7 +80,10 @@ def test_every_entry_resolves_to_its_files(bench):
                 and w["name"] in m.get("workloads", cells)]
         assert mine, f"{w['name']} reports no end-to-end metric"
     for m in bench["per_layer"]:
-        assert os.path.exists(_perf("layer_metrics", m["name"] + ".py"))
+        # ``<reader>.<tag>`` reads as ``<reader>`` where it has no
+        # file of its own (``run.load_reader``)
+        assert any(os.path.exists(_perf("layer_metrics", n + ".py"))
+                   for n in (m["name"], m["name"].rsplit(".", 1)[0])), m
         assert m["moves"] in e2e
         where = set(m.get("workloads", cells))
         assert where <= cells
@@ -90,3 +93,16 @@ def test_every_entry_resolves_to_its_files(bench):
                    for m in bench["per_layer"])
     used = {w["config"] for w in bench["workloads"]}
     assert used == set(configs)
+
+
+def test_one_reader_serves_several_names():
+    from perf import run
+
+    base, tagged = (run.load_reader(n) for n in (
+        "decode_itl_p95_ms", "decode_itl_p95_ms.some-tag"))
+    record = {"client": {"itl_ms": [1.0, 2.0, 3.0, 40.0]}}
+    assert tagged(record) == base(record) > 3.0
+    # a name with a file of its own keeps it
+    assert run.load_reader("exec_host_ms.img").__module__.endswith("_img")
+    with pytest.raises(FileNotFoundError):
+        run.load_reader("no_such_metric.tag")

@@ -72,6 +72,31 @@ def test_closed_loop_counts_attempted_and_failed(server):
     assert one["prompt_len"] + 1 <= cm["kv_rows"]
 
 
+def test_staggered_first_sends(server):
+    """Client i first sends when client i - 1 has read the k-th token
+    of its first answer, whatever the seed."""
+    spec = {"address": "127.0.0.1:%d" % server.server_address[1],
+            "clients": 4, "seconds": 0.2, "ramp_seconds": 0.3,
+            "stagger_tokens": 3, "seed": 7, "vocab": 50,
+            "deal": [[8, 5], [9, 5], [10, 5], [11, 5]]}
+    edges, records = loadgen.closed_loop(spec, go=lambda: None)
+    first = [min((r for r in records if r["client"] == c),
+                 key=lambda r: r["t_send"]) for c in range(4)]
+    for a, b in zip(first, first[1:]):
+        assert b["t_send"] >= a["stamps"][2]      # after its third token
+    assert edges["open"] == pytest.approx(edges["go"] + 0.3)
+
+
+def test_spec_carries_the_traffic_parameters():
+    traffic = dict(TRAFFIC, loop="closed", clients=4, ramp_seconds=10,
+                   stagger_tokens=3)
+    spec = loadgen.spec_of(traffic, "h:1", 30.0, 2 ** 31 + 11, 100)
+    assert (spec["stagger_tokens"], spec["ramp_seconds"]) == (3, 10.0)
+    assert spec["deal"] == TRAFFIC["deal"] and spec["seed"] == 2 ** 31 + 11
+    del traffic["stagger_tokens"]
+    assert loadgen.spec_of(traffic, "h:1", 1, 0, 9)["stagger_tokens"] == 0
+
+
 TRAFFIC = {"prompt_lengths": [[64, 2], [128, 1]],
            "max_tokens": [[32, 1], [64, 2]],
            "deal": [[64, 64], [128, 32], [64, 64]]}

@@ -137,3 +137,30 @@ ENTRY %main (a: bf16[8,8]) -> bf16[8,8] {
     assert cats == {"custom-call.61": "kernel",
                     "fusion.435": "conv/matmul fusion"}
     assert tr.bare("%fusion.435 = bf16[8,8] fusion(...)") == "fusion.435"
+
+
+def test_idle_gaps_take_the_programs_own_spans():
+    """A gap inside a tick is named after the innermost span of any of
+    the prefixes, the program's own included, not after the runner's
+    span round the tick."""
+    trace = {
+        "devices": {"/device:TPU:0": [("fusion.1", 1000.0, 1000.0, {}),
+                                      ("fusion.2", 5000.0, 1000.0, {}),
+                                      ("fusion.3", 8000.0, 2000.0, {})]},
+        "host": [("main", "perf.window", 1000.0, 9000.0),
+                 ("stepper", "perf.engine_step", 1500.0, 8000.0),
+                 ("stepper", "decode.tick", 1600.0, 7800.0),
+                 ("stepper", "decode.logits_to_host", 1900.0, 3200.0),
+                 ("stepper", "decode.admit", 6100.0, 1800.0),
+                 ("stepper", "decode.prefill", 7500.0, 300.0),
+                 ("handler", "serving.generate", 0.0, 20000.0),
+                 ("other", "python.gc", 2000.0, 3000.0)]}
+    # gaps: 2000..5000 (3000 ns) and 6000..8000 (2000 ns)
+    assert tr.idle_gaps(trace, n=2) == [
+        ["decode.logits_to_host", pytest.approx(3000e-9)],
+        ["decode.admit", pytest.approx(2000e-9)]]
+    # the runner's spans alone: what the label was before
+    assert [g[0] for g in tr.idle_gaps(trace, n=2, span_prefixes=("perf.",))
+            ] == ["perf.engine_step", "perf.engine_step"]
+    assert tr.idle_gaps(trace, n=1, span_prefixes=("none.",)) == [
+        ["host: no span", pytest.approx(3000e-9)]]
