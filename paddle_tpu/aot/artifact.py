@@ -32,6 +32,7 @@ slower, never wrong.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import io
 import json
@@ -122,6 +123,27 @@ def _entry_id(program_fp: str, sig: str, fetch_names) -> str:
     h.update(b"\x00")
     h.update(json.dumps(list(fetch_names)).encode())
     return h.hexdigest()[:24]
+
+
+@contextlib.contextmanager
+def compiled_afresh():
+    """Compile inside the block without jax's persistent compilation
+    cache.  An executable that XLA:CPU loaded from that cache serializes
+    into a payload whose functions are gone: the artifact loads, and
+    fails at its first run (``NOT_FOUND: Function ... not found``).  So
+    what an export serializes is compiled here and now.  jax decides
+    once whether it uses the cache, hence the two resets."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 class ArtifactWriter:

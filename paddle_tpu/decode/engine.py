@@ -162,6 +162,9 @@ class GenerationEngine:
         cache = self.session.prefix_cache
         if cache is not None:
             out["prefix_cache"] = cache.stats()
+        # the last ticks that took far longer than their kind does, each
+        # with its seconds by phase and what it was doing
+        out["slow_ticks"] = self.session.slow_ticks
         return out
 
     # -- lifecycle ----------------------------------------------------------

@@ -68,7 +68,7 @@ from paddle_tpu.decode.attention import (
 )
 from paddle_tpu.decode.paged_kv import PageAllocator, PoolsLost
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.observability.events import span
+from paddle_tpu.observability.events import phase
 from paddle_tpu.pallas.tuning.bucket import bucket_dim
 
 _M_PREFILL_TOKENS = _metrics.counter(
@@ -486,7 +486,7 @@ class PagedDecoderLM:
         block's report -> (``StepLogits``, new states).  The logits
         stay where they are.  A failure on the device shows here."""
         with self._donating(step._pools_in):
-            with span("decode.logits_to_host"):
+            with phase("decode.logits_to_host"):
                 ids = np.asarray(step._ids)
                 self._observe("decode", step._report, ids.size)
                 return StepLogits(step._logits, ids), []
@@ -497,13 +497,13 @@ class PagedDecoderLM:
         when something is)."""
         inputs = (tables, lens, tokens)
         if any(isinstance(a, np.ndarray) for a in inputs):
-            with span("decode.upload"):
+            with phase("decode.upload"):
                 tables, lens, tokens = (
                     jnp.asarray(a.astype(np.int32))
                     if isinstance(a, np.ndarray) else a for a in inputs)
         pools_in = (self.k_pool, self.v_pool)
         with self._donating(pools_in):
-            with span("decode.dispatch"):
+            with phase("decode.dispatch"):
                 logits, self.k_pool, self.v_pool, report, ids, *more = jitted(
                     self.params, *pools_in, tables, lens, tokens,
                     heads=self.heads, page_size=self.page_size,

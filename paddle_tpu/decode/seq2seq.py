@@ -30,6 +30,7 @@ from typing import Any, List, Sequence, Tuple
 import numpy as np
 
 from paddle_tpu.decode.paged_kv import PagedPool
+from paddle_tpu.observability.events import phase
 
 
 class PagedSeq2SeqModel:
@@ -177,15 +178,22 @@ class PagedSeq2SeqModel:
 
     def decode(self, tokens: np.ndarray, states: List[np.ndarray],
                tables: np.ndarray, lens: np.ndarray):
-        """One fixed-shape decode step over every slot."""
-        feed = {"@dec_word": tokens, "@dec_pool": self.pool.data,
-                "@dec_ptab": tables.astype(np.int64),
-                "@dec_ctx_len": lens}
-        for name, buf in zip(self._state_names, states):
-            feed[name] = buf
-        outs = self._exe.run(
-            self._step_main, feed=feed,
-            fetch_list=[self._probs_var] + self._new_state_vars,
-            scope=self._scope)
-        probs = np.asarray(outs[0]).reshape(tokens.shape[0], -1)
-        return probs, [np.asarray(o) for o in outs[1:]]
+        """One fixed-shape decode step over every slot.  The tick's
+        phases as the paged skeleton writes them: the feed is the
+        upload, ``exe.run`` the dispatch (it fetches, so here the wait
+        for the device is inside it) and the outputs' conversion the
+        collect."""
+        with phase("decode.upload"):
+            feed = {"@dec_word": tokens, "@dec_pool": self.pool.data,
+                    "@dec_ptab": tables.astype(np.int64),
+                    "@dec_ctx_len": lens}
+            for name, buf in zip(self._state_names, states):
+                feed[name] = buf
+        with phase("decode.dispatch"):
+            outs = self._exe.run(
+                self._step_main, feed=feed,
+                fetch_list=[self._probs_var] + self._new_state_vars,
+                scope=self._scope)
+        with phase("decode.logits_to_host"):
+            probs = np.asarray(outs[0]).reshape(tokens.shape[0], -1)
+            return probs, [np.asarray(o) for o in outs[1:]]

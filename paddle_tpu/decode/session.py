@@ -32,6 +32,16 @@ A model without the two halves (``PagedSeq2SeqModel``, a wrapper that
 overrides ``decode``) runs ``decode`` whole where 4 is, followed by 1,
 2 and 5 at once; so does a speculative tick with ``verify_chunk``.
 
+**The tick keeps its own account, in every run** (``TickAccount``).
+The statement that opens a phase's span (``observability.phase``, here
+and in the model's ``step_collect`` / ``step_dispatch``) also adds its
+seconds to the account, so a phase's counter and its span have the
+same two edges; ``step()`` flushes the account to the registry once a
+tick (seconds by phase, admissions a tick, the slot-seconds an
+admission held the seated slots still) and a tick that took far longer
+than its kind does is counted, kept (``slow_ticks``, in ``/health``)
+and logged with what it was doing.
+
 The model behind the session is pluggable (``PagedSeq2SeqModel`` for
 v1 beam_search specs, ``TinyDecoderLM`` for transformer self-attention
 KV); ``generation.py``'s greedy path is the exact dense oracle the
@@ -40,7 +50,10 @@ parity tests pin this against.
 
 from __future__ import annotations
 
+import collections
+import gc
 import itertools
+import logging
 import threading
 import time
 from typing import Callable, List, Optional
@@ -51,7 +64,10 @@ from paddle_tpu.decode.paged_kv import PoolExhausted, PoolsLost, cow_split
 from paddle_tpu.decode.spec import accept_greedy, observe_chunk
 from paddle_tpu.generation import beam_select
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.observability.events import span
+from paddle_tpu.observability.events import (PhaseAccount, phase, span,
+                                             straddling_phase)
+
+_LOG = logging.getLogger(__name__)
 
 _M_ACTIVE = _metrics.gauge(
     "decode_active_slots", "sequences currently decoding in the session")
@@ -112,6 +128,198 @@ _M_STEP_FAIL = _metrics.counter(
 _M_CANCELLED = _metrics.counter(
     "decode_cancelled_total",
     "generation requests cancelled by their consumer (pages freed)")
+
+
+# -- the tick's account ------------------------------------------------------
+
+# ``phase`` label of ``decode_tick_seconds_total`` -> the span whose
+# statement charges it, in a tick's order.  ``prefill`` and
+# ``first_token`` lie inside ``admit`` and are kept beside it, never
+# summed with it; ``upload`` and ``dispatch`` lie inside ``decode.step``,
+# which is no phase.  Two more labels have no statement: ``other`` is the
+# ``decode.tick`` span less its top-level phases, and ``between`` (from
+# the end of one tick to the start of the next while the session is not
+# idle: the stepper waiting for the interpreter lock) lies outside the
+# tick.
+PHASE_SPANS = {
+    "between": "decode.between",
+    "collect": "decode.logits_to_host",
+    "decide": "decode.sample",
+    "sweep": "decode.sweep",
+    "admit": "decode.admit",
+    "prefill": "decode.prefill",
+    "first_token": "decode.first_token",
+    "cow": "decode.cow",
+    "upload": "decode.upload",
+    "dispatch": "decode.dispatch",
+    "deliver": "decode.deliver",
+}
+_NESTED = ("prefill", "first_token")
+
+# A tick (with the ``between`` before it) is slow when it lasts longer
+# than both: SLOW_TICK_FACTOR times the running mean of the ticks of its
+# kind (plain, or admitting), and SLOW_TICK_FLOOR_S.  Set from the chip's
+# runs (PERF.md, PR 37): the K-EXAONE cell's longest sound tick read
+# 0.437 s (two long prompts seated in one tick, the longer admission
+# 0.222 s) beside admitting ticks of ~0.09 s, so 8 x is 0.7 s there; its
+# plain ticks and every tick of the other two cells (13-65 ms) are
+# judged by the floor; the silences to be caught lasted 1.2-2.5 s.
+SLOW_TICK_FACTOR = 8.0
+SLOW_TICK_FLOOR_S = 0.6
+SLOW_TICKS_KEPT = 16
+_MEAN_OVER_TICKS = 64       # what a kind's running mean forgets over
+_ADMISSIONS_TOP = 4         # decode_tick_admissions_total's last bucket, "4+"
+
+_GC = [0.0, 0.0]    # seconds in garbage collections; start of the open one
+
+
+def _on_gc(when, info):
+    # a collection stops every thread of the interpreter: the seconds
+    # of those that fell inside a slow tick go on its record
+    if when == "start":
+        _GC[1] = time.perf_counter()
+    else:
+        _GC[0] += time.perf_counter() - _GC[1]
+
+
+gc.callbacks.append(_on_gc)
+
+
+class TickAccount:
+    """What one tick owes the registry, filled while the tick runs and
+    flushed once at its end: seconds by phase (``phases``: charged by
+    the ``phase`` statements that run while it is open on the thread),
+    the admissions it seated, the slot-seconds those held the seated
+    slots still, where its step's inputs came from and under what its
+    deliveries ran.  Also the judge of a slow tick.  The families live
+    in ``registry`` (the process's unless given: the overhead probe
+    gives its own)."""
+
+    def __init__(self, registry=None):
+        reg = registry or _metrics.REGISTRY
+        self.labels = (*PHASE_SPANS, "other")
+        self.phases = PhaseAccount(("decode.tick", *PHASE_SPANS.values()))
+        self._top = [i for i, label in enumerate(PHASE_SPANS)
+                     if label not in _NESTED + ("between",)]
+        self._m_seconds = reg.counter(
+            "decode_tick_seconds_total",
+            "seconds of the stepper's ticks by phase (the span that bears "
+            "the phase's name charges it; `prefill` and `first_token` are "
+            "inside `admit`; `other` = the tick less its top-level phases; "
+            "`between` = from one tick's end to the next one's start while "
+            "the session is not idle), and by whether the tick seated a "
+            "request (`admitting`)")
+        self._m_ticks = reg.counter(
+            "decode_ticks_total",
+            "ticks of the stepper, by whether they seated a request")
+        self._m_admissions = reg.counter(
+            "decode_tick_admissions_total",
+            "ticks by the number of requests they seated (`n`: 0, 1, 2, 3, "
+            "4+): admissions a tick, and the depth of a convoy")
+        self._m_stalled = reg.counter(
+            "decode_admit_stalled_slot_seconds_total",
+            "for every admission, its seconds times the slots that were "
+            "seated and live before it: slot-time in which a sequence "
+            "produced nothing because another request was prefilled")
+        self._m_slot_seconds = reg.counter(
+            "decode_slot_seconds_total",
+            "every tick's seconds (with the `between` before it) times "
+            "the slots live at its end")
+        self._m_inputs = reg.counter(_M_STEP_INPUTS.name, _M_STEP_INPUTS.help)
+        self._m_deliveries = reg.counter(_M_DELIVERIES.name,
+                                         _M_DELIVERIES.help)
+        self._m_slow = reg.counter(
+            "decode_slow_ticks_total",
+            "ticks that lasted over SLOW_TICK_FACTOR times the running "
+            "mean of their kind and over SLOW_TICK_FLOOR_S, by the phase "
+            "that took most of them (each is in /health's "
+            "generation.slow_ticks and in the log)")
+        key = _metrics.label_key
+        self._k_seconds = {a: [key(phase=label, admitting=a)
+                               for label in self.labels] for a in "01"}
+        self._k_ticks = {a: key(admitting=a) for a in "01"}
+        self._k_admissions = [key(n=n) for n in ("0", "1", "2", "3", "4+")]
+        self._k_inputs = [key(source="resident"), key(source="uploaded")]
+        self._k_under = [key(under="step"), key(under="nothing")]
+        self.slow_ticks: collections.deque = collections.deque(
+            maxlen=SLOW_TICKS_KEPT)
+        self._mean = {"0": None, "1": None}     # seconds, by `admitting`
+        self._reset()
+
+    def _reset(self) -> None:
+        self.admissions: List[dict] = []    # the seated ones' span args
+        self.stalled = 0.0
+        self.inputs = [0, 0]                # steps: resident, uploaded
+        self.under = [0, 0]                 # deliveries: step, nothing
+
+    def admitted(self, admit: phase, live_before: int, seated: bool) -> None:
+        """One ``decode.admit`` ended: whatever came of it, the slots
+        that were seated stood still for its seconds."""
+        self.stalled += admit.seconds * live_before
+        if seated:
+            self.admissions.append(admit.args)
+
+    def flush(self, at: float, live: int, active: int, waiting: int,
+              in_flight: bool, gc_seconds: float) -> None:
+        """The tick that started at ``at`` (``perf_counter``) is over:
+        its lines go to the registry, each family under one acquire of
+        its lock, and the account starts anew."""
+        tick, *secs = self.phases.take()
+        secs.append(max(tick - sum(secs[i] for i in self._top), 0.0))
+        total = tick + secs[0]              # with the `between` before it
+        n = len(self.admissions)
+        admitting = "1" if n else "0"
+        self._m_seconds.inc_many(
+            [kv for kv in zip(self._k_seconds[admitting], secs) if kv[1]])
+        self._m_ticks.inc_many(((self._k_ticks[admitting], 1),))
+        self._m_admissions.inc_many(
+            ((self._k_admissions[min(n, _ADMISSIONS_TOP)], 1),))
+        if self.stalled:
+            self._m_stalled.inc(self.stalled)
+        if live:
+            self._m_slot_seconds.inc(total * live)
+        for family, keys, counts in (
+                (self._m_inputs, self._k_inputs, self.inputs),
+                (self._m_deliveries, self._k_under, self.under)):
+            if counts[0] or counts[1]:
+                family.inc_many([kv for kv in zip(keys, counts) if kv[1]])
+        mean = self._mean[admitting]
+        if total > SLOW_TICK_FLOOR_S and (
+                mean is None or total > SLOW_TICK_FACTOR * mean):
+            self._slow(at, total, secs, active, waiting, in_flight,
+                       gc_seconds)
+        else:   # a slow tick is not what ticks of its kind take
+            self._mean[admitting] = (total if mean is None else
+                                     mean + (total - mean) / _MEAN_OVER_TICKS)
+        self._reset()
+
+    def _slow(self, at, total, secs, active, waiting, in_flight,
+              gc_seconds) -> None:
+        by = dict(zip(self.labels, secs))
+        worst = max((label for label in by if label not in _NESTED),
+                    key=by.get)
+        self._m_slow.inc(phase=worst)
+        record = {
+            "at": at, "seconds": total, "phase": worst,
+            "phases": {label: v for label, v in by.items() if v},
+            "active": active, "waiting": waiting,
+            "admissions": [{k: a.get(k) for k in
+                            ("prompt_len", "bucket", "cached_len")}
+                           for a in self.admissions],
+            "in_flight": in_flight,
+            "uploaded": bool(self.inputs[1]) if sum(self.inputs) else None,
+            "gc_seconds": gc_seconds}
+        self.slow_ticks.append(record)
+        _LOG.warning(
+            "decode.slow_tick at=%.6f seconds=%.6f phase=%s %s active=%d "
+            "waiting=%d admitted=%d admissions=%s in_flight=%d uploaded=%s "
+            "gc_s=%.6f", at, total, worst,
+            " ".join(f"{label}_s={v:.6f}" for label, v in by.items() if v),
+            active, waiting, len(record["admissions"]),
+            ",".join("{prompt_len}/{bucket}/{cached_len}".format(**a)
+                     for a in record["admissions"]) or "-",
+            in_flight, "-" if record["uploaded"] is None
+            else int(record["uploaded"]), gc_seconds)
 
 
 _RIDS = itertools.count(1)
@@ -481,6 +689,9 @@ class DecodeSession:
             for half in ("step_dispatch", "step_collect"))
         self._flight: Optional[_Flight] = None
         self._outbox = _Outbox()
+        self._account = TickAccount()
+        self._between: Optional[phase] = None   # open from a tick's end on
+        self._gc_mark = _GC[0]
 
     @property
     def prefix_cache(self):
@@ -556,6 +767,13 @@ class DecodeSession:
             return (not self._pending and self._flight is None
                     and all(s is None for s in self._slots))
 
+    @property
+    def slow_ticks(self) -> List[dict]:
+        """The last ``SLOW_TICKS_KEPT`` slow ticks, oldest first, each
+        with when it started (``at``, on ``time.perf_counter``), its
+        seconds by phase and what it was doing (``TickAccount._slow``)."""
+        return list(self._account.slow_ticks)
+
     # -- scheduler tick -----------------------------------------------------
 
     def step(self) -> int:
@@ -571,19 +789,47 @@ class DecodeSession:
         503 ``step_failed`` — and the stepper thread lives on.  So is a
         copy-on-write split that lost the model's pools (``PoolsLost``;
         a step or a prefill that did contains it where it is called)."""
+        account = self._account
+        account.phases.open()
+        if self._between is None:       # idle since the last tick
+            self._gc_mark = _GC[0]
+        self._close_between()
         live = [s.req.rid for s in self._slots if s is not None]
-        with span("decode.tick", active=len(live),
-                  waiting=len(self._pending),
-                  rids=",".join(map(str, live))):
-            try:
-                return self._tick()
-            except PoolsLost as exc:
-                self._contain_step_failure([], exc)
-                return 0
-            finally:
-                self._deliver()
-                _M_ACTIVE.set(self.active)
-                self._gauge_cache_rows()
+        waiting, in_flight = len(self._pending), self._flight is not None
+        tick = phase("decode.tick", active=len(live), waiting=waiting,
+                     rids=",".join(map(str, live)))
+        try:
+            with tick:
+                try:
+                    return self._tick()
+                except PoolsLost as exc:
+                    self._contain_step_failure([], exc)
+                    return 0
+                finally:
+                    self._deliver()
+                    _M_ACTIVE.set(self.active)
+                    self._gauge_cache_rows()
+        finally:
+            if not self.idle():
+                self._between = straddling_phase("decode.between")
+                self._between.__enter__()
+            # collections since the `between` before this tick opened
+            gc_mark, self._gc_mark = self._gc_mark, _GC[0]
+            account.flush(tick.t0, self._live(), len(live), waiting,
+                          in_flight, self._gc_mark - gc_mark)
+            account.phases.close()
+
+    def _close_between(self) -> None:
+        """The ``decode.between`` span that the last tick left open ends
+        here: at the next tick's start (it then charges that tick's
+        account), or when the session is failed."""
+        between, self._between = self._between, None
+        if between is not None:
+            between.__exit__(None, None, None)
+
+    def _live(self) -> int:
+        """Slots that hold a sequence which still produces tokens."""
+        return sum(1 for s in self._slots if s is not None and not s.dead)
 
     def _gauge_cache_rows(self) -> None:
         rows_of = getattr(self.model, "cache_rows", None)
@@ -595,7 +841,7 @@ class DecodeSession:
     def _tick(self) -> int:
         if self._flight is not None:
             self._collect()
-        with span("decode.sweep"):
+        with phase("decode.sweep"):
             self._sweep_cancelled()
             self._sweep_expired()
         self._admit()
@@ -608,7 +854,7 @@ class DecodeSession:
         if self.model.grows_kv:
             # the step writes each live slot's next KV row: split any
             # page shared with a fork / the prefix cache first
-            with span("decode.cow"):
+            with phase("decode.cow"):
                 for i in active_idx:
                     if (self._slots[i] is not None
                             and not self._slots[i].dead):
@@ -633,7 +879,7 @@ class DecodeSession:
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
-        _M_STEP_INPUTS.inc(source="uploaded" if uploaded else "resident")
+        self._account.inputs[bool(uploaded)] += 1
         if self._two_halves:
             inputs.dispatched(step.next)
             self._flight = _Flight(step, active_idx, t0)
@@ -670,7 +916,7 @@ class DecodeSession:
                     slot.ctx_len += 1
                     self._inputs.length(i, slot.ctx_len, from_step=True)
         self._outbox.decided = True
-        with span("decode.sample"):
+        with phase("decode.sample"):
             self._decide(lanes, logits, drafts)
 
     def _decide(self, lanes: List[int], logits,
@@ -747,7 +993,7 @@ class DecodeSession:
         out = self._outbox
         if not out.decided:
             return
-        with span("decode.deliver"):
+        with phase("decode.deliver"):
             for req, tok, finish in out.events:
                 if finish is not None:
                     req._finish(*finish)
@@ -759,7 +1005,7 @@ class DecodeSession:
                 _M_CHOICE.inc(out.on_device, where="device")
             if out.on_host:
                 _M_CHOICE.inc(out.on_host, where="host")
-            _M_DELIVERIES.inc(under="step" if self._flight else "nothing")
+            self._account.under[self._flight is None] += 1
         out.reset()
 
     def run(self, max_steps: Optional[int] = None) -> None:
@@ -970,7 +1216,7 @@ class DecodeSession:
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
-        _M_STEP_INPUTS.inc(source="uploaded")
+        self._account.inputs[1] += 1
         self._landed(active_idx, *landed, t0=t0, drafts=drafts)
         return len(active_idx)
 
@@ -1133,7 +1379,8 @@ class DecodeSession:
                 n = _prompt_len(req.prompt)
                 bucket = self.model.prefill_bucket(n)
                 args = {"bucket": bucket, "pad": bucket - n}
-            with span("decode.prefill", rid=req.rid, **args):
+                admit_span.set(bucket=bucket)
+            with phase("decode.prefill", rid=req.rid, **args):
                 if cached_len:
                     ctx_len, state_rows, first_logits = self.model.prefill(
                         req.prompt, pages, cached_len=cached_len)
@@ -1170,23 +1417,26 @@ class DecodeSession:
                 _M_WAITING.set(len(self._pending))
             if req is None:
                 return
-            with span("decode.admit", rid=req.rid,
-                      prompt_len=_prompt_len(req.prompt)) as admit_span:
+            live = self._live()
+            with phase("decode.admit", rid=req.rid,
+                       prompt_len=_prompt_len(req.prompt)) as admit_span:
                 seated = self._admit_one(req, frees, admit_span)
-            if not seated:
+            self._account.admitted(admit_span, live, bool(seated))
+            if seated is None:
                 return
 
     def _admit_one(self, req: DecodeRequest, frees: List[int],
-                   admit_span) -> bool:
-        """Seat one popped request: prefill, place, first token.  False
-        when slots or pages are busy with live sequences and the request
-        went back to the head of the queue (an evict next tick frees
-        them: not a refusal, that happens at submit); a request that
-        failed is finished and counts as handled."""
+                   admit_span) -> Optional[bool]:
+        """Seat one popped request: prefill, place, first token -> True.
+        None when slots or pages are busy with live sequences and the
+        request went back to the head of the queue (an evict next tick
+        frees them: not a refusal, that happens at submit); False for a
+        request that failed: it is finished, and the next may be
+        tried."""
         popped_at = time.monotonic()
         if isinstance(req, BeamRequest) and len(frees) < req.beam_size:
             self._requeue_head(req)
-            return False
+            return None
         need = self.model.context_pages(req.prompt, req.max_new_tokens)
         try:
             got = self._prefill_with_cache(req, need, admit_span)
@@ -1194,15 +1444,15 @@ class DecodeSession:
             _M_REFUSED.inc(reason="pool_exhausted")
             req._finish("error", AdmissionRefused("pool_exhausted",
                                                   str(e)))
-            return True
+            return False
         except BaseException as e:
             if isinstance(e, PoolsLost):
                 self._contain_step_failure([], e)
             req._finish("error", e)
-            return True
+            return False
         if got is None:
             self._requeue_head(req)
-            return False
+            return None
         if req.admitted_at is None:
             # a request that a failed step sent back is seated again,
             # and has waited once
@@ -1216,7 +1466,7 @@ class DecodeSession:
             self._place(frees[0], _Slot(req, pages, ctx_len),
                         ctx_len, state_rows)
             if first_logits is not None:
-                with span("decode.first_token", rid=req.rid):
+                with phase("decode.first_token", rid=req.rid):
                     tok = self._choose(self._slots[frees[0]],
                                        np.asarray(first_logits))
                     self._emit_token(frees[0], tok)
@@ -1258,6 +1508,7 @@ class DecodeSession:
         """Shutdown: fail every live and queued request; a step in
         flight is dropped, its results never read."""
         self._flight = None
+        self._close_between()
         self._inputs.forget()
         with self._lock:
             pending, self._pending = self._pending, []

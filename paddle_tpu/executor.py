@@ -637,7 +637,9 @@ class Executor:
         rest = (np.int64(self._seed_for_step(program)),) \
             if compiled.uses_rng else ()
         try:
-            executable = compiled.fn.lower(state, feed_vals, *rest).compile()
+            lowered = compiled.fn.lower(state, feed_vals, *rest)
+            with mod.artifact.compiled_afresh():
+                executable = lowered.compile()
             meta = writer.add(
                 program_fp=fp,
                 feed_sig=_feed_signature(feed_vals),

@@ -11,7 +11,10 @@ The instrument panel for everything the ROADMAP wants measured:
   writes a span: a ``jax.profiler.TraceAnnotation`` (in a jax profile,
   on the device trace's clock) and, while ``recording()`` is on, a
   Chrome-trace event with id/parent/args in the bounded
-  ``GLOBAL_EVENTS`` ring (``paddle stats --trace``, ``GET /trace``).
+  ``GLOBAL_EVENTS`` ring (``paddle stats --trace``, ``GET /trace``);
+  ``phase`` is a ``span`` whose seconds also go to the ``PhaseAccount``
+  open on the thread (the decode tick's own account, flushed to the
+  registry once a tick: ``measure_tick_account_overhead``).
 - device-side naming — the executor's jitted step is ``jit_paddle_step``
   and every Pallas kernel has a ``name=``; ``flags trace_ops=1`` also
   wraps each op's lowering in ``jax.named_scope`` (executor.py).
@@ -43,6 +46,8 @@ from paddle_tpu.observability.metrics import (  # noqa: F401
 from paddle_tpu.observability.events import (  # noqa: F401
     EventRecorder,
     GLOBAL_EVENTS,
+    PhaseAccount,
+    phase,
     recording,
     span,
 )
@@ -91,6 +96,60 @@ def measure_step_overhead(iters: int = 2000) -> float:
         steps.observe(1e-3, program="fingerprint0", cached="hit")
         fetched.inc(4096, program="fingerprint0")
     return (time.perf_counter() - t0) / iters
+
+
+def measure_tick_account_overhead(iters: int = 2000) -> float:
+    """Seconds the decode tick's account (``decode/session.py``:
+    ``TickAccount``) adds to one tick, with nobody recording: a tick of
+    every phase with one admission, written with ``phase`` under an
+    open account and flushed into a private registry, less the same
+    tick as it was written before the account was kept (plain ``span``s
+    and the two counters a tick already incremented).  Asserted under a
+    budget in the tests: the account is on in every run."""
+    from paddle_tpu.decode.session import PHASE_SPANS, TickAccount
+
+    reg = MetricsRegistry()
+    account = TickAccount(reg)
+    inputs = reg.counter("overhead_probe_step_inputs_total")
+    deliveries = reg.counter("overhead_probe_deliveries_total")
+    flat = [PHASE_SPANS[label] for label in (
+        "collect", "decide", "sweep", "cow", "upload", "dispatch",
+        "deliver")]
+
+    def tick(stmt, i):
+        with stmt("decode.between"):
+            pass
+        with stmt("decode.tick", active=16, waiting=0, rids="1,2,3") as t:
+            with stmt("decode.admit", rid=i, prompt_len=64) as admit:
+                with stmt("decode.prefill", rid=i, bucket=64, pad=0):
+                    pass
+                with stmt("decode.first_token", rid=i):
+                    pass
+            for name in flat:
+                with stmt(name):
+                    pass
+        return t, admit
+
+    def per_tick(accounted):
+        t0 = time.perf_counter()
+        for i in range(iters):
+            if accounted:
+                account.phases.open()
+                t, admit = tick(phase, i)
+                account.admitted(admit, 15, True)
+                account.inputs[0] += 1
+                account.under[0] += 1
+                account.flush(t.t0, 16, 16, 0, True, 0.0)
+                account.phases.close()
+            else:
+                tick(span, i)
+                inputs.inc(source="resident")
+                deliveries.inc(under="step")
+        return (time.perf_counter() - t0) / iters
+
+    per_tick(True)      # both paths warm before either is timed
+    before = per_tick(False)
+    return per_tick(True) - before
 
 
 def measure_span_overhead(iters: int = 20000) -> dict:

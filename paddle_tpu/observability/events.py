@@ -21,6 +21,14 @@ has two sinks:
 A profile's timestamps count from the profile's start, so ring and
 profile are aligned by content, not by clock: with both sinks on the
 same span is in both.
+
+``phase(name, **args)`` is the same statement for a span whose seconds
+the program also keeps by itself, in every run: it reads
+``time.perf_counter`` once at each edge, gives the ring those two
+readings and adds their difference to the ``PhaseAccount`` that is open
+on the thread (``PhaseAccount.open``; none open: a span and no more).
+The decode tick's phases are written so (``decode/session.py``), and
+flushed from the account into the registry once a tick.
 """
 
 from __future__ import annotations
@@ -131,6 +139,7 @@ GLOBAL_EVENTS = EventRecorder()
 
 _SPAN_IDS = itertools.count(1)       # next() is atomic under the GIL
 _OPEN = threading.local()            # .stack: ids of this thread's open spans
+#                                      .account: the PhaseAccount open on it
 
 
 class span:
@@ -152,28 +161,105 @@ class span:
     def __enter__(self):
         self._ann = TraceAnnotation(self.name, **self.args)
         self._ann.__enter__()
-        if GLOBAL_EVENTS.enabled:
-            try:
-                stack = _OPEN.stack
-            except AttributeError:
-                stack = _OPEN.stack = []
-            sid = next(_SPAN_IDS)
-            self._open = (sid, stack[-1] if stack else 0,
-                          GLOBAL_EVENTS.now(), stack)
-            stack.append(sid)
-        else:
-            self._open = None
+        self._open = (self._ring_open(time.perf_counter())
+                      if GLOBAL_EVENTS.enabled else None)
         return self
 
     def __exit__(self, *exc):
         if self._open is not None:
-            sid, parent, t0, stack = self._open
-            stack.remove(sid)
-            GLOBAL_EVENTS._complete(
-                self.name, t0, GLOBAL_EVENTS.now() - t0, "paddle",
-                dict(self.args, id=sid, parent=parent))
+            self._ring_close(time.perf_counter())
         self._ann.__exit__(*exc)
         return False
+
+    def _ring_open(self, t0: float) -> tuple:
+        """The span is open in the ring since ``t0`` (``perf_counter``)."""
+        try:
+            stack = _OPEN.stack
+        except AttributeError:
+            stack = _OPEN.stack = []
+        sid = next(_SPAN_IDS)
+        stack.append(sid)
+        return sid, stack[-2] if len(stack) > 1 else 0, t0, stack
+
+    def _ring_close(self, t1: float) -> None:
+        sid, parent, t0, stack = self._open
+        if stack is not None:
+            stack.remove(sid)
+        GLOBAL_EVENTS._complete(
+            self.name, t0 - GLOBAL_EVENTS._t0, t1 - t0, "paddle",
+            dict(self.args, id=sid, parent=parent))
+
+
+class PhaseAccount:
+    """Seconds by span name, summed over the ``phase`` statements that
+    ran on the thread while the account was open there: the counter
+    twin of those spans, kept whether or not anything records them.
+    ``names`` are the spans it keeps (a ``phase`` of another name is a
+    span and no more); the owner reads ``seconds`` and zeroes it with
+    ``take``."""
+
+    __slots__ = ("index", "seconds")
+
+    def __init__(self, names):
+        self.index = {name: i for i, name in enumerate(names)}
+        self.seconds = [0.0] * len(self.index)
+
+    def open(self) -> None:
+        """Phases on this thread charge this account from now on."""
+        _OPEN.account = self
+
+    @staticmethod
+    def close() -> None:
+        _OPEN.account = None
+
+    def take(self) -> List[float]:
+        """The seconds by name, in ``names``' order, since the last
+        ``take``."""
+        taken, self.seconds = self.seconds, [0.0] * len(self.seconds)
+        return taken
+
+
+class phase(span):
+    """``span`` whose seconds also go to the thread's open
+    ``PhaseAccount``: one ``perf_counter`` reading at each edge serves
+    the ring and the account, so a phase's counter and its ring span
+    agree to the last bit, and the profile's annotation lies round
+    both.  ``seconds`` is the duration once the block has ended."""
+
+    __slots__ = ("t0", "seconds")
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = t0 = time.perf_counter()
+        self._open = self._ring_open(t0) if GLOBAL_EVENTS.enabled else None
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
+        account = getattr(_OPEN, "account", None)
+        if account is not None:
+            i = account.index.get(self.name)
+            if i is not None:
+                account.seconds[i] += self.seconds
+        if self._open is not None:
+            self._ring_close(t1)
+        self._ann.__exit__(*exc)
+        return False
+
+
+class straddling_phase(phase):
+    """A ``phase`` that one call opens and a later call closes (the
+    wait between two decode ticks), so it is not nested in anything:
+    the ring keeps it as a root and it is never on the thread's stack
+    of open spans, where one that is never closed (a session dropped
+    while not idle) would pass for the parent of every later span."""
+
+    __slots__ = ()
+
+    def _ring_open(self, t0: float) -> tuple:
+        return next(_SPAN_IDS), 0, t0, None
 
 
 @contextlib.contextmanager

@@ -48,6 +48,13 @@ def _label_key(labels: Dict[str, Any]) -> _LabelKey:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
 
 
+def label_key(**labels) -> _LabelKey:
+    """A label set as the families key their children: built once by a
+    writer that increments the same children every time
+    (``Counter.inc_many``)."""
+    return _label_key(labels)
+
+
 def _fmt_num(v: float) -> str:
     if isinstance(v, float) and v == int(v) and abs(v) < 1e15:
         return str(int(v))
@@ -97,6 +104,17 @@ class Counter(_Metric):
         key = _label_key(labels)
         with self._lock:
             self._children[key] = self._children.get(key, 0.0) + amount
+
+    def inc_many(self, amounts) -> None:
+        """``inc`` of several children under one acquire of the lock:
+        ``amounts`` is ``[(label_key(...), amount)]``, the keys built
+        ahead (the decode tick's flush: a dozen children a tick)."""
+        children = self._children
+        with self._lock:
+            for key, amount in amounts:
+                if amount < 0:
+                    raise ValueError(f"counter {self.name} cannot decrease")
+                children[key] = children.get(key, 0.0) + amount
 
     def value(self, **labels) -> float:
         with self._lock:

@@ -151,6 +151,11 @@ _M_FIRST_WRITE_LAG = _metrics.histogram(
     "serving_generate_first_write_lag_seconds",
     "a streamed /generate request's first token: emitted by the decode "
     "stepper to written on the socket by the handler thread")
+_M_SUBMIT_LAG = _metrics.histogram(
+    "serving_generate_submit_lag_seconds",
+    "a /generate request's way through the front: from the handler's "
+    "entry to the decode engine holding the request (the end of "
+    "`serving.submit`): body parse, tenant admission, the submit")
 # GET /trace records for at most this long: the ring is bounded, the
 # handler thread sleeps meanwhile
 TRACE_MAX_SECONDS = 60.0
@@ -412,6 +417,7 @@ class InferenceServer:
                                  + data + b"\r\n")
 
             def _handle_generate(self, raw_body: bytes) -> None:
+                self._entered = time.perf_counter()
                 from paddle_tpu.decode.session import next_rid
 
                 if server._generator is None:
@@ -421,6 +427,10 @@ class InferenceServer:
                 rid = next_rid()
                 with span("serving.generate", rid=rid) as gen_span:
                     self._generate(raw_body, rid, gen_span)
+
+            def _submitted(self) -> None:
+                """The engine holds this handler's request."""
+                _M_SUBMIT_LAG.observe(time.perf_counter() - self._entered)
 
             def _generate(self, raw_body: bytes, rid: int,
                           gen_span) -> None:
@@ -479,6 +489,7 @@ class InferenceServer:
                             req = server._generator.submit_beam(
                                 src, beam_size=beam, max_new_tokens=budget,
                                 deadline=deadline, rid=rid)
+                        self._submitted()
                         ids = req.result(timeout)
                         self._reply(200, {
                             "ids": ids,
@@ -495,6 +506,7 @@ class InferenceServer:
                                 temperature=payload.get("temperature"),
                                 top_k=payload.get("top_k"),
                                 seed=payload.get("seed"), rid=rid)
+                        self._submitted()
                         ids = req.result(timeout)
                         self._reply(200, {
                             "ids": ids,
@@ -540,6 +552,7 @@ class InferenceServer:
                         temperature=payload.get("temperature"),
                         top_k=payload.get("top_k"),
                         seed=payload.get("seed"), rid=rid)
+                self._submitted()
                 if deadline is not None:
                     # hold the 200 until the stream actually starts:
                     # a request that dies of its deadline before its

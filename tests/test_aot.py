@@ -92,6 +92,36 @@ def test_roundtrip_bit_identical(artifact_dir):
     assert np.array_equal(outs[1], ref[1])
 
 
+def test_export_with_the_compile_cache_warm_loads_and_runs(tmp_path):
+    """An executable XLA:CPU got from jax's persistent compile cache
+    serializes into a payload whose functions are gone: it loads, and
+    fails at its first run (``NOT_FOUND: Function ... not found``).  So
+    an export compiles its step itself; the second export here finds the
+    first one's entry in the cache (every compile is kept: the floor is
+    brought to 0 s) and its artifact must still run."""
+    import jax
+
+    floor = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, floor)
+    jax.config.update(floor, 0.0)
+    try:
+        ref = None
+        for turn in ("cold", "warm"):
+            art = str(tmp_path / turn)
+            writer = ArtifactWriter(art)
+            with aot.capture(writer):
+                _, ref = _run_steps(_program(scale=7.0), steps=2)
+            writer.finish()
+            store = ArtifactStore(art)
+            exe, outs = _run_steps(_program(scale=7.0), store=store, steps=2)
+            assert store.results == {"loaded": 1}, turn
+            assert exe.compile_counts == {"jit": 0, "aot": 1}
+            assert np.array_equal(outs[0], ref[0]), turn
+            assert np.array_equal(outs[1], ref[1]), turn
+    finally:
+        jax.config.update(floor, was)
+
+
 def test_donation_restored_under_aot(artifact_dir):
     """Donation through the loaded executable (with the persistent XLA
     compile cache on, as the test conftest sets it): export and load
