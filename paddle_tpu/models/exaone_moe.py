@@ -332,9 +332,8 @@ class ExaoneMoeLM(PagedDecoderLM):
 
     def _observe(self, phase, report, rows):
         report = np.asarray(report)[self._routed]      # (routed, held + 1)
-        path = moe.expert_path(rows, self.block.top_k, self._router_width)
-        moe.count_load(phase, report[:, :-1], path,
-                       int(report[:, -1].sum()))
+        moe.count_load(phase, report[:, :-1], rows, self.block.top_k,
+                       self._router_width, int(report[:, -1].sum()))
 
     # -- pages: the full run, then a ring a sliding layer --------------------
 

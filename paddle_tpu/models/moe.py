@@ -10,10 +10,13 @@ from the call's static shape at trace time (no option selects one):
 - ``grouped``, a call of many rows (a prefill bucket over
   ``DENSE_MAX_ROWS``) or of so few that most experts get none (a step
   of a few slots): a stable sort of the ``rows x k`` assignments by
-  expert, ONE grouped GEMM per projection over the sorted rows
+  expert, ONE grouped GEMM per projection over sorted rows
   (``jax.lax.ragged_dot``: on a TPU the compiler lowers it to a
   grouped-matmul kernel that reads only the experts that were hit),
-  un-sort, weighted sum.
+  and the weighted sum of each row's results.  How many sorted rows a
+  pass takes is ``grouped_block_rows`` of the static shape: all ``rows
+  x k`` when every expert is held, a quarter of that on a chip that
+  holds 16 of 128 (below).
 - ``dense``, a call in between (a decode step of tens of slots, a
   verify chunk, a short bucket): every row through every held expert
   as matmuls batched over the experts, the routing weight (0 where a
@@ -27,19 +30,29 @@ from the call's static shape at trace time (no option selects one):
   under the stream.
 
 Fixed shapes: every row routes, whatever it holds; the ``live`` mask
-only decides which rows the returned load counts.
+decides which rows the returned load counts, and on the grouped path of
+a chip that holds part of the experts also which rows are computed (a
+row that is not live, a bucket's padding, gets 0 there and no live row
+reads it).
 
 The router always scores its full width.  A chip of an expert-parallel
 deployment is told which experts it holds (``held``: the first and the
 count of a contiguous range of the router's columns) and computes only
 its own experts' part of each row's sum: the assignments that went to
-experts held elsewhere weigh nothing (grouped: they sort behind the
-held ones and belong to no group of the grouped GEMM).  What the other
-chips would add is not computed and nothing stands in for them.
+experts held elsewhere weigh nothing.  On the grouped path they sort
+behind the held ones, and the gather, the grouped GEMMs and the sum run
+over the held ones alone, in blocks of ``grouped_block_rows`` sorted
+rows (about twice what even routing sends to this chip) and as many
+blocks as the data needs: one under any routing near even, ``rows x k``
+over the block's rows when every row chooses only experts held here, so
+the sum is exact for any routing and its cost follows what is held
+(PERF.md section 6, PR 38).  What the other chips would add is not
+computed and nothing stands in for them.
 
 The four ``jax.named_scope``s (``moe_router``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine``) put every instruction of the layer
-under a name in the compiled program's ``op_name``.
+under a name in the compiled program's ``op_name`` (inside a share's
+loop over blocks as ``.../while/body/moe_experts/...``).
 """
 
 from __future__ import annotations
@@ -79,17 +92,44 @@ _M_PATH = _metrics.counter(
     "through every held expert) or \"grouped\" (the grouped GEMM)")
 
 
-def count_load(phase: str, load: np.ndarray, path: str,
-               elsewhere: int = 0) -> None:
+_M_GROUPED_ROWS = _metrics.counter(
+    "moe_grouped_rows_total",
+    "sorted assignments of the routed layers run on the grouped path, "
+    "summed over layers: rows=\"assigned\" (live rows' assignments to "
+    "experts held here: some group's) and rows=\"computed\" (what the "
+    "grouped GEMMs ran over: blocks x the block's rows); assigned over "
+    "computed is how full the blocks were")
+_M_GROUPED_BLOCKS = _metrics.counter(
+    "moe_grouped_blocks_total",
+    "blocks of sorted assignments the grouped path ran, summed over "
+    "layers; over the routed layers run grouped (moe_expert_path_total) "
+    "it says how often one block was not enough")
+
+
+def count_load(phase: str, load: np.ndarray, rows: int, top_k: int,
+               experts: int, elsewhere: int = 0) -> None:
     """Feed the registry from one call's (layers, held experts) load,
-    the ``path`` its shape had the layers take (``expert_path``) and
-    its count of assignments that went to experts held elsewhere."""
+    the call's static shape (``rows`` each choosing ``top_k`` of the
+    router's ``experts``: what ``expert_path`` and ``grouped_block_rows``
+    chose by) and its count of assignments that went to experts held
+    elsewhere."""
+    path = expert_path(rows, top_k, experts)
+    assigned = int(load.sum())
     _M_PATH.inc(load.shape[0], path=path, phase=phase)
-    _M_ASSIGNMENTS.inc(int(load.sum()), phase=phase)
+    _M_ASSIGNMENTS.inc(assigned, phase=phase)
     _M_EXPERTS_HIT.inc(int((load > 0).sum()), phase=phase)
     _M_LOAD_MAX.inc(int(load.max(axis=-1).sum()), phase=phase)
     if elsewhere:
         _M_ELSEWHERE.inc(int(elsewhere), phase=phase)
+    if path == "grouped":
+        # what the device's loop did, from the load it handed back
+        # (every expert held: one block of rows x top_k a layer)
+        block_rows = grouped_block_rows(rows, top_k, load.shape[1], experts)
+        blocks = int(grouped_blocks(load.sum(axis=-1), block_rows).sum())
+        _M_GROUPED_BLOCKS.inc(blocks, phase=phase)
+        _M_GROUPED_ROWS.inc(assigned, rows="assigned", phase=phase)
+        _M_GROUPED_ROWS.inc(blocks * block_rows, rows="computed",
+                            phase=phase)
 
 
 def softmax_scores(logits):
@@ -166,33 +206,102 @@ def expert_path(rows: int, top_k: int, experts: int) -> str:
             else "grouped")
 
 
-def _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down, top_k,
-                     partial):
-    """The sum over sorted assignments: a stable sort of the ``R x k``
-    assignments by expert, one grouped GEMM a projection over the
-    sorted rows, un-sort, weighted sum.  The grouped GEMMs run over all
-    R * k sorted rows: a row's k choices can all be held here, so no
-    smaller static bound is exact; the rows behind the last group are
-    no group's and are zeroed before the sum."""
-    R, d = m.shape
-    with jax.named_scope("moe_dispatch"):
-        order = jnp.argsort(expert_of, stable=True)
-        xs = m[order // top_k]                               # sorted rows
+# The grouped GEMM's kernel on a TPU walks the sorted rows in tiles of
+# this many (PERF.md section 6, PR 34): a block of sorted assignments is
+# a whole number of them.
+GROUPED_ROW_TILE = 256
+
+
+def grouped_block_rows(rows: int, top_k: int, held: int, experts: int) -> int:
+    """How many sorted assignments one pass of the grouped GEMMs takes
+    on a chip that holds ``held`` of the router's ``experts``: about
+    twice what even routing sends here, ``2 rows top_k held / experts``,
+    rounded up to the kernel's row tile and never over ``rows x
+    top_k``, which is what it is when every expert is held.  A function
+    of the call's static shape alone, like ``expert_path``."""
+    full = rows * top_k
+    want = -(-2 * full * held // experts)
+    return min(full, -(-want // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
+
+
+def grouped_blocks(held_assignments, block_rows: int):
+    """Blocks a grouped call runs for ``held_assignments`` sorted rows
+    (a number or an array of them): the loop's trip count, computed the
+    same way on the host by ``count_load``."""
+    return -(-held_assignments // block_rows)
+
+
+def _grouped_gemms(xs, sizes, w_gate, w_up, w_down):
+    """Each expert's SwiGLU over its group of the sorted rows ``xs``
+    (``sizes`` rows a group, in order; rows behind the last group are
+    no group's and what comes out for them means nothing) -> float32."""
     with jax.named_scope("moe_experts"):
         g = jax.lax.ragged_dot(xs, w_gate, sizes,
                                preferred_element_type=_F32)
         u = jax.lax.ragged_dot(xs, w_up, sizes,
                                preferred_element_type=_F32)
-        h = (jax.nn.silu(g) * u).astype(m.dtype)
-        ys = jax.lax.ragged_dot(h, w_down, sizes,
-                                preferred_element_type=_F32)
+        h = (jax.nn.silu(g) * u).astype(xs.dtype)
+        return jax.lax.ragged_dot(h, w_down, sizes,
+                                  preferred_element_type=_F32)
+
+
+def _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down, top_k):
+    """Every expert held: a stable sort of the ``R x k`` assignments by
+    expert, one grouped GEMM a projection over all of the sorted rows
+    (each is some group's), un-sort, weighted sum."""
+    R, d = m.shape
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(expert_of, stable=True)
+        xs = m[order // top_k]                               # sorted rows
+    ys = _grouped_gemms(xs, sizes, w_gate, w_up, w_down)
     with jax.named_scope("moe_combine"):
-        if partial:
-            in_a_group = jnp.arange(R * top_k) < jnp.sum(sizes)
-            ys = jnp.where(in_a_group[:, None], ys, 0.0)
         back = jnp.zeros((R * top_k,), jnp.int32).at[order].set(
             jnp.arange(R * top_k, dtype=jnp.int32))
         return jnp.einsum("rk,rkd->rd", w, ys[back].reshape(R, top_k, d))
+
+
+def _grouped_held_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
+                          top_k, block_rows):
+    """Part of the experts held: the same sum over the assignments that
+    are some held expert's group, and over nothing else.  Those sort
+    first (``expert_of`` is C for the others); they are taken in blocks
+    of ``block_rows`` sorted rows, as many blocks as the data needs
+    (``grouped_blocks``: one, under routing anywhere near even), so the
+    result is exact for any routing, a call whose rows all choose held
+    experts included, at a cost that follows what is held.  A block
+    gathers its ``(B, d)`` rows, runs the three grouped GEMMs with the
+    group sizes clipped to the block, and adds its weighted ``(B, d)``
+    result into the ``(R, d)`` output by row: nothing of ``R x k`` rows
+    by ``d`` columns exists."""
+    R, d = m.shape
+    B, n = block_rows, R * top_k
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(expert_of, stable=True)
+        # whole blocks: a dynamic slice past the end would be moved back
+        order = jnp.pad(order, (0, -n % B))
+        ends = jnp.cumsum(sizes)
+        starts, held = ends - sizes, ends[-1]
+        w_flat = w.reshape(-1)
+
+    def block(b, y):
+        lo = b * B
+        with jax.named_scope("moe_dispatch"):
+            mine = jax.lax.dynamic_slice(order, (lo,), (B,))
+            row = mine // top_k
+            in_a_group = lo + jnp.arange(B, dtype=jnp.int32) < held
+            of_block = (jnp.clip(ends, lo, lo + B)
+                        - jnp.clip(starts, lo, lo + B))
+            xs = m[row]
+        ys = _grouped_gemms(xs, of_block, w_gate, w_up, w_down)
+        with jax.named_scope("moe_combine"):
+            # the rows behind the last group are no group's: whatever
+            # the kernel left there is not read
+            ys = jnp.where(in_a_group[:, None], w_flat[mine][:, None] * ys,
+                           0.0)
+            return y.at[row].add(ys)
+
+    return jax.lax.fori_loop(0, grouped_blocks(held, B), block,
+                             jnp.zeros((R, d), _F32))
 
 
 def _dense_experts(m, w, expert_of, w_gate, w_up, w_down, top_k):
@@ -232,28 +341,41 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
     experts not held).
 
     ``expert_path`` of the call's shape says which of the two ways
-    computes the sum."""
+    computes the sum, and ``grouped_block_rows`` over how many sorted
+    assignments at a time the grouped one runs."""
     R, d = m.shape
     E = wr.shape[1]
     first, C = held or (0, E)
     partial = (first, C) != (0, E)
+    path = expert_path(R, top_k, E)
+    # part of the experts held, grouped: a row that is not live (a
+    # bucket's padding right of the prompt; no live row reads it) is no
+    # group's, as if it had chosen experts held elsewhere
+    live_groups = partial and path == "grouped" and live is not None
     w, idx = route(m, wr, top_k, scores)
     with jax.named_scope("moe_dispatch"):
         expert_of = idx.reshape(-1)                          # (R*k,)
         if partial:
             here = (idx >= first) & (idx < first + C)
+            if live_groups:
+                here &= live[:, None]
             w = jnp.where(here, w, 0.0)
             # experts held elsewhere sort behind the last held one and
             # index past the (C,) counts, where a scatter drops them
             expert_of = jnp.where(here, idx - first, C).reshape(-1)
         sizes = jnp.zeros((C,), jnp.int32).at[expert_of].add(1)
-        load = sizes if live is None else jnp.zeros((C,), jnp.int32).at[
-            expert_of].add(jnp.repeat(live.astype(jnp.int32), top_k))
+        load = sizes if live is None or live_groups else jnp.zeros(
+            (C,), jnp.int32).at[expert_of].add(
+                jnp.repeat(live.astype(jnp.int32), top_k))
         elsewhere = (R if live is None else jnp.sum(live)) * top_k \
             - jnp.sum(load)
-    if expert_path(R, top_k, E) == "dense":
+    if path == "dense":
         y = _dense_experts(m, w, expert_of, w_gate, w_up, w_down, top_k)
+    elif partial:
+        y = _grouped_held_experts(
+            m, w, expert_of, sizes, w_gate, w_up, w_down, top_k,
+            grouped_block_rows(R, top_k, C, E))
     else:
         y = _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
-                             top_k, partial)
+                             top_k)
     return y, load, elsewhere

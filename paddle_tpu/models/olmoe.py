@@ -154,5 +154,4 @@ class OlmoeLM(PagedDecoderLM):
 
     def _observe(self, phase, report, rows):
         load = np.asarray(report)                       # (layers, experts)
-        moe.count_load(phase, load, moe.expert_path(
-            rows, self.block.top_k, load.shape[1]))
+        moe.count_load(phase, load, rows, self.block.top_k, load.shape[1])

@@ -546,17 +546,28 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
         assert f"jit(_decode_step)/{scope}/" in text, scope
 
 
-def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch):
+@pytest.mark.parametrize("bucket, plan, parents_plan", [
+    (4096, 12_948_948_480, 13_979_091_456),
+    (4608, 13_052_315_136, 14_382_459_904)])
+def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch,
+                                                    bucket, plan,
+                                                    parents_plan):
     """The 4,096-row prefill bucket (the longest the cell's traffic
-    sends): the plan fits the chip beside 10.45 GB of weights and 1.61
-    GB of pools, both pools are aliased, the full layer runs the flash
-    kernel on 64 repeated heads and the six sliding layers run banded
-    in plain XLA (no T x T scores: they would be 4.3 GB a layer)."""
+    sends) and the 4,608-row one (a sequence's capacity): the plan fits
+    the chip beside 10.45 GB of weights and 1.61 GB of pools, both
+    pools are aliased, the full layer runs the flash kernel on 64
+    repeated heads and the six sliding layers run banded in plain XLA
+    (no T x T scores: they would be 4.3 GB a layer).  The routed layers
+    of a chip that holds 16 of 128 experts run their grouped GEMMs over
+    blocks of 2 x bucket sorted assignments (``moe.grouped_block_rows``)
+    and hold nothing of 8 x bucket rows by the model's width, which is
+    why the plans lie under the ones of PR 37 (``parents_plan``)."""
     from paddle_tpu.decode import model as dm
+    from paddle_tpu.models import moe
 
     cfg, params, pool, shape, block, width, sds = _exaone_cell(
         one_chip, monkeypatch)
-    L, bucket = cfg["num_hidden_layers"], 4096
+    L, k = cfg["num_hidden_layers"], cfg["num_experts_per_tok"]
     compiled = dm._prefill_bucket.lower(
         params, pool, pool, sds((bucket,), jnp.int32),
         sds((L, bucket), jnp.int32), sds((), jnp.int32),
@@ -564,9 +575,14 @@ def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 2 * math.prod(shape) * 2
     planned = _planned_bytes(compiled)
-    assert planned == 13_979_091_456, planned
-    assert planned < 15.75e9
-    ops = _kernel_op_names(compiled.as_text())
+    assert planned == plan <= parents_plan < 15.75e9, planned
+    text = compiled.as_text()
+    assert moe.grouped_block_rows(
+        bucket, k, cfg["num_experts"], cfg["num_experts_published"]) \
+        == 2 * bucket
+    assert re.search(rf"\[{2 * bucket},{cfg['hidden_size']}\]", text)
+    assert not re.search(rf"\[{k * bucket},{cfg['hidden_size']}\]", text)
+    ops = _kernel_op_names(text)
     flash = [op for op in ops if not op.startswith("ragged-dot")]
     assert len(flash) == 1 and "_prefill_bucket)/attn_full/" in flash[0]
     assert "flash_attention_fwd" in flash[0]

@@ -13,6 +13,7 @@ reads 1.3e-3, every other one 1e-2 to 0.7.
 """
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -189,6 +190,7 @@ def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(R):
         {n: jnp.asarray(v) for n, v in lp.items()}, jnp.asarray(m),
         top_k=k, scale=scale, held=(0, 16), ablate=None)
     live = np.arange(m.shape[0]) % 4 != 0
+    grouped = moe.expert_path(R, k, 16) == "grouped"
     total = ref._swiglu(jnp.asarray(m), *map(jnp.asarray, ws))
     loads, elsewhere = [], 0
     for rank in range(8):
@@ -202,7 +204,11 @@ def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(R):
         loads.append(np.asarray(load))
         elsewhere += int(away)
         assert int(away) + int(load.sum()) == live.sum() * k
-    assert ref.rel_rms(total, whole) < 1e-5
+        # grouped, part of the experts held: a row that is not live is
+        # no group's and gets nothing
+        assert not (grouped and np.asarray(y)[~live].any())
+    rows = live if grouped else slice(None)
+    assert ref.rel_rms(total[rows], whole[rows]) < 1e-5
     np.testing.assert_array_equal(np.concatenate(loads),
                                   np.asarray(mask)[live].sum(axis=0))
     assert elsewhere == 7 * live.sum() * k
@@ -259,8 +265,10 @@ def test_a_share_is_the_same_sum_on_both_paths(tie):
     y, load, away = share(slice(None))
     (y0, load0, away0), (y1, load1, away1) = (
         share(slice(half)), share(slice(half, None)))
-    np.testing.assert_allclose(np.concatenate([y0, y1]), y, rtol=1e-5,
-                               atol=1e-6)
+    # the grouped GEMM of a share leaves out the rows that are not live
+    assert not np.asarray(y)[~live].any()
+    np.testing.assert_allclose(np.concatenate([y0, y1])[live],
+                               np.asarray(y)[live], rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(load0 + load1, load)
     assert int(away0) + int(away1) == int(away)
     _, mask = ref._router(jnp.asarray(wr), jnp.asarray(b), jnp.asarray(m),
@@ -270,11 +278,119 @@ def test_a_share_is_the_same_sum_on_both_paths(tie):
     assert 0 < none_held[:half].sum() and 0 < none_held[half:].sum()
     for got in (y, np.concatenate([y0, y1])):
         assert not np.asarray(got)[none_held].any()
-        assert np.asarray(got)[~none_held].any(axis=1).all()
+        assert np.asarray(got)[~none_held & live].any(axis=1).all()
     np.testing.assert_array_equal(load, mask[live][:, sl].sum(axis=0))
     if tie:                 # never expert 7 without expert 6
         assert not np.any(mask[:, 7] & ~mask[:, 6])
         assert mask[:, 7].any()
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((512, 8, 16, 128), 2 * 512),       # K-EXAONE's buckets: a quarter
+    ((4096, 8, 16, 128), 2 * 4096),
+    ((4608, 8, 16, 128), 2 * 4608),
+    ((4, 8, 16, 128), 32),              # a step of few slots: all of it
+    ((2048, 8, 64, 64), 2048 * 8),      # every expert held: rows x k
+    ((300, 3, 8, 16), 900),             # half of them held: twice is all
+    ((279, 3, 2, 16), 256),             # rounded up to the GEMM's row tile
+    ((279, 3, 6, 16), 768)],
+    ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_a_block_is_twice_the_even_share_in_whole_tiles(shape, want):
+    rows, k, held, experts = shape
+    B = moe.grouped_block_rows(rows, k, held, experts)
+    assert B == want <= rows * k
+    assert B == rows * k or (B % moe.GROUPED_ROW_TILE == 0
+                             and B >= 2 * rows * k * held / experts)
+    assert [int(moe.grouped_blocks(n, B)) for n in (0, 1, B, B + 1)] == [
+        0, 1, 1, 2]
+
+
+def _routed_to(choices, rng, E=16):
+    """Rows whose router logits single out the experts named in
+    ``choices`` (R, k): the router is the identity, so a row's logits
+    are the row, 6 at its chosen experts over noise elsewhere."""
+    choices = np.asarray(choices)
+    m = (rng.randn(len(choices), E) * 0.1).astype(np.float32)
+    np.put_along_axis(m, choices, 6.0 + rng.rand(*choices.shape), axis=1)
+    return m.astype(np.float32), np.eye(E, dtype=np.float32)
+
+
+def _held_share(m, wr, w3, held, k, live=None):
+    """(y, load, elsewhere) of the layer told it holds ``held``, and
+    the reference's sum over those experts with its routing mask."""
+    wg, wu, wd = w3
+    sl = slice(held[0], held[0] + held[1])
+    b = jnp.zeros((wr.shape[1],), jnp.float32)
+    got = moe.routed_experts(
+        jnp.asarray(m), wr, wg[sl], wu[sl], wd[sl], top_k=k,
+        live=None if live is None else jnp.asarray(live),
+        scores=moe.sigmoid_scores(b, 2.5), held=held)
+    weight, mask = ref._router(jnp.asarray(wr), b, jnp.asarray(m), top_k=k,
+                               scale=2.5, ablate=None)
+    want = ref.held_experts(jnp.asarray(m), weight, held, wg[sl], wu[sl],
+                            wd[sl])
+    return got, want, np.asarray(mask)
+
+
+def test_rows_that_all_choose_held_experts_take_more_than_one_block():
+    """The case no static bound under ``R x k`` covers: every row's k
+    choices are held here.  The loop runs as many blocks as that takes
+    and the sum is the reference's."""
+    rng = np.random.RandomState(21)
+    R, k, held = moe.DENSE_MAX_ROWS + 23, 3, (5, 6)
+    choices = np.stack([rng.permutation(6)[:k] + 5 for _ in range(R)])
+    m, wr = _routed_to(choices, rng)
+    w3 = _toy_layer(rng)[3:]
+    B = moe.grouped_block_rows(R, k, held[1], 16)
+    assert moe.expert_path(R, k, 16) == "grouped"
+    assert B < R * k and moe.grouped_blocks(R * k, B) == 2
+    (y, load, away), want, mask = _held_share(m, wr, w3, held, k)
+    assert int(load.sum()) == R * k and int(away) == 0
+    assert mask[:, 5:11].sum() == R * k
+    assert ref.rel_rms(y, want) < 1e-5
+
+
+@pytest.mark.parametrize("past", [0, 1], ids=["on_the_edge", "one_past"])
+def test_held_assignments_on_a_block_edge_and_one_past_it(past):
+    """Exactly one block's worth of held assignments runs one block,
+    one more runs two (the second holds one row of a group that began
+    in the first); both are the reference's sum."""
+    rng = np.random.RandomState(22)
+    R, k, held = moe.DENSE_MAX_ROWS + 23, 3, (0, 2)
+    B = moe.grouped_block_rows(R, k, held[1], 16)
+    assert B == moe.GROUPED_ROW_TILE
+    choices = np.tile([5, 6, 7], (R, 1))
+    choices[:B // 2] = [0, 1, 9]            # two held assignments a row
+    choices[B // 2: B // 2 + past] = [0, 8, 9]       # one
+    rng.shuffle(choices)
+    m, wr = _routed_to(choices, rng)
+    (y, load, away), want, mask = _held_share(
+        m, wr, _toy_layer(rng)[3:], held, k)
+    np.testing.assert_array_equal(load, [B // 2 + past, B // 2])
+    assert moe.grouped_blocks(int(load.sum()), B) == 1 + past
+    assert int(away) == R * k - B - past
+    assert ref.rel_rms(y, want) < 1e-5
+    assert not np.asarray(y)[~mask[:, :2].any(axis=1)].any()
+
+
+def test_padding_right_of_the_rows_changes_no_real_row_and_no_load():
+    """A bucket's rows right of the prompt are not ``live``: on the
+    grouped path of a share they belong to no group, the real rows'
+    sums are the unpadded call's and so are the load and the count of
+    what went elsewhere."""
+    rng = np.random.RandomState(23)
+    n, k, held = moe.DENSE_MAX_ROWS + 44, 3, (5, 4)
+    m, wr, _, *w3 = _toy_layer(rng, R=2 * n)
+    assert moe.expert_path(n, k, 16) == moe.expert_path(2 * n, k, 16) \
+        == "grouped"
+    (y, load, away), want, _ = _held_share(m[:n], wr, w3, held, k)
+    (yp, loadp, awayp), _, _ = _held_share(m, wr, w3, held, k,
+                                           live=np.arange(2 * n) < n)
+    assert ref.rel_rms(y, want) < 1e-5
+    np.testing.assert_allclose(np.asarray(yp)[:n], y, rtol=1e-5, atol=1e-6)
+    assert not np.asarray(yp)[n:].any()
+    np.testing.assert_array_equal(loadp, load)
+    assert int(awayp) == int(away)
 
 
 def test_expert_path_counter_counts_the_routed_layers(model):
@@ -313,6 +429,61 @@ def test_counters_count_held_experts_and_what_went_elsewhere(model):
         away = delta("moe_assignments_elsewhere_total", phase)
         assert held + away == rows * k * routed     # live rows only
         assert 0 < held < rows * k * routed
+
+
+def _grouped_counters(phase="prefill"):
+    snap = metrics.snapshot()
+
+    def at(name, **labels):
+        return sum(v["value"] for v in snap.get(
+            name, {"values": []})["values"]
+            if all(v["labels"].get(a) == b for a, b in labels.items()))
+    return {"assigned": at("moe_grouped_rows_total", rows="assigned",
+                           phase=phase),
+            "computed": at("moe_grouped_rows_total", rows="computed",
+                           phase=phase),
+            "blocks": at("moe_grouped_blocks_total", phase=phase)}
+
+
+def test_counters_count_the_grouped_blocks_and_how_full_they_were():
+    """``count_load`` on the host, from the (layers, held) load a
+    prefill hands back: what is held and live is ``assigned``, blocks x
+    the block's rows is ``computed``, and a layer whose held
+    assignments pass a block counts the blocks the device's loop ran.
+    The dense pass counts nothing here."""
+    rows, k, E, C = 512, 8, 128, 16
+    B = moe.grouped_block_rows(rows, k, C, E)
+    assert B == 2 * rows
+    load = np.zeros((3, C), np.int32)
+    load[0, :4] = 100               # 400 of 1,024: one block
+    load[1, 0], load[1, 5] = B, 1   # one past the edge: two
+    before = _grouped_counters()    # layer 2 holds nothing: no block
+    moe.count_load("prefill", load, rows, k, E, elsewhere=7)
+    after = _grouped_counters()
+    assert {n: after[n] - before[n] for n in after} == {
+        "assigned": 400 + B + 1, "computed": 3 * B, "blocks": 3}
+    moe.count_load("prefill", load[:, :4], 64, 2, 16)      # dense
+    assert _grouped_counters() == after
+    # every expert held: one block of rows x top_k a layer
+    moe.count_load("prefill", load[:1], rows, k, C)
+    every = _grouped_counters()
+    assert {n: every[n] - after[n] for n in after} == {
+        "assigned": 400, "computed": rows * k, "blocks": 1}
+
+
+def test_a_toy_step_on_the_grouped_path_counts_its_blocks(model):
+    before = _grouped_counters("decode"), _grouped_counters()
+    _through_the_caches(model, _prompt(9, seed=8), [3, 4])
+    after = _grouped_counters("decode"), _grouped_counters()
+    routed, k = 3, model.block.top_k
+    B = moe.grouped_block_rows(S, k, 4, 16)
+    assert B == S * k           # 2 x 4 x 3 x 4 / 16 = 6, a tile is more
+    got = {n: after[0][n] - before[0][n] for n in after[0]}
+    # two steps of one live slot: at most k held assignments a layer
+    assert 0 < got["assigned"] <= 2 * routed * k
+    assert got["blocks"] <= 2 * routed
+    assert got["computed"] == got["blocks"] * B
+    assert after[1] == before[1]            # the 64-row bucket ran dense
 
 
 # -- two lifetimes in one allocator ------------------------------------------
@@ -449,4 +620,7 @@ def test_named_scopes_place_attention_and_the_shared_expert(model):
         for scope in ("attn_full", "attn_window", "moe_shared",
                       "moe_router", "moe_dispatch", "moe_experts",
                       "moe_combine"):
-            assert f"{program})/{scope}/" in text, (program, scope)
+            # (a toy step of 4 slots runs its experts grouped, inside
+            # the loop over blocks of held assignments)
+            assert re.search(rf"{program}\)/(while/body/)?{scope}/", text), (
+                program, scope)

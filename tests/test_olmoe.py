@@ -164,6 +164,50 @@ def test_the_two_paths_compute_the_same_sum(kind):
     assert int(away) == int(away0) == int(away1) == 0
 
 
+def _primitives(jaxpr):
+    """(primitive name, shapes of its operands) of every equation,
+    nested jaxprs (a loop's body, a pjit) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name,
+                    [tuple(v.aval.shape) for v in eqn.invars]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _primitives(sub)
+    return out
+
+
+@pytest.mark.parametrize("held", [None, (2, 2)], ids=["all", "a_share"])
+def test_with_every_expert_held_the_grouped_path_is_one_pass(held):
+    """Every expert held: the grouped path traces what it traced before
+    a share learned to run over its own assignments alone: no loop, no
+    branch, three grouped GEMMs over all ``R x k`` sorted rows, the
+    un-sort and the weighted sum over k (its lowered text is the
+    parent's, letter for letter: checked once, PR 38).  A share of the
+    same layer loops over blocks and holds nothing of ``R x k`` rows by
+    ``d`` columns."""
+    k = 3
+    m, wr, wg, wu, wd = _toy_layer("random", MANY)
+    sl = slice(None) if held is None else slice(held[0], sum(held))
+    live = jnp.arange(MANY) % 3 != 0
+    prims = _primitives(jax.make_jaxpr(
+        lambda m, live: moe.routed_experts(
+            m, wr, wg[sl], wu[sl], wd[sl], top_k=k, live=live, held=held))(
+                jnp.asarray(m), live).jaxpr)
+    names = [n for n, _ in prims]
+    gemm_rows = [shapes[0][0] for n, shapes in prims
+                 if n == "ragged_dot_general"]
+    wide = [shapes for _, shapes in prims for shape in shapes
+            if len(shape) >= 2 and shape[0] == MANY * k
+            and shape[-1] == m.shape[1]]
+    if held is None:
+        assert not {"while", "cond"} & set(names)
+        assert gemm_rows == [MANY * k] * 3 and wide
+    else:
+        B = moe.grouped_block_rows(MANY, k, held[1], wr.shape[1])
+        assert names.count("while") == 1 and "cond" not in names
+        assert B < MANY * k and gemm_rows == [B] * 3 and not wide
+
+
 def test_expert_path_is_one_interval_of_the_row_count():
     """The rule: the two cells' decode steps (32 slots over 64 experts,
     64 over 128, top-8) take the dense pass, every prefill bucket of

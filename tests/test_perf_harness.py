@@ -4,7 +4,8 @@
 here, in tier-1: the judged statistic of the time to first token, the
 convoy share, the idle gaps' names, the traffic files' co-prime
 budgets, the load generator's token-clocked starts (PR 36's cases,
-imported and not copied), and the readers of the decode tick's own
+imported and not copied), the expert layer's prefill readers (PR 38's
+cases, imported too), and the readers of the decode tick's own
 account (PR 37) against a registry pair recorded from a session and
 against spans laid over the device plane of the trace recorded on the
 chip (``perf/tests/data``).
@@ -17,7 +18,8 @@ import pytest
 
 pytest.register_assert_rewrite(
     "perf.tests.test_stats", "perf.tests.test_trace",
-    "perf.tests.test_traffic", "perf.tests.test_loadgen")
+    "perf.tests.test_traffic", "perf.tests.test_loadgen",
+    "perf.tests.test_moe_prefill_readers")
 
 from perf.harness import program_spans as ps  # noqa: E402
 from perf.harness import tick_account as ta  # noqa: E402
@@ -25,6 +27,10 @@ from perf.harness import trace as tr  # noqa: E402
 from perf.run import load_reader  # noqa: E402
 from perf.tests.test_loadgen import (  # noqa: E402,F401
     server, test_staggered_first_sends)
+from perf.tests.test_moe_prefill_readers import (  # noqa: E402,F401
+    test_moe_grouped_fill_is_assigned_over_computed,
+    test_moe_prefill_ms_counts_the_loops_body_and_not_the_loop,
+    test_moe_prefill_ms_reads_nothing_without_a_trace_or_the_layer)
 from perf.tests.test_stats import (  # noqa: E402,F401
     test_follower_share_counts_sends_in_a_convoy,
     test_interquartile_mean_does_not_sit_on_a_gap,
