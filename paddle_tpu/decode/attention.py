@@ -41,12 +41,31 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     """Shapes the kernels' block layouts support.  ``kv_heads`` (None:
     ``num_heads``) is the K/V heads a page holds; where it is fewer
     than the query heads (grouped heads) it has to divide them, and the
-    blocks are the K/V heads' wide."""
+    blocks are the K/V heads' wide.
+
+    What it does not look at is how many heads a page holds.  At 30
+    heads of 128 over 128-row bfloat16 pages (PR 39's probe on a v5e)
+    ``ragged_paged_attention`` and the flash prefill compile and run
+    right ALONE; inside a step the compiler lays a donated pool of 30
+    heads out with the heads outside the page's rows (30 is not a
+    multiple of the 16 rows a bfloat16 tile packs) and copies the whole
+    pool to the kernel's row-major layout before every layer's call.
+    A model whose head count is not a multiple of the tile's rows
+    stores its pages at ``storage_heads`` and pads q, k and v to it."""
     ok = (page_size % 8 == 0 and head_dim % 8 == 0
           and head_dim <= 256 and num_heads >= 1)
     if kv_heads in (None, num_heads):
         return ok
     return ok and kv_heads >= 1 and num_heads % kv_heads == 0
+
+
+def storage_heads(num_heads: int, dtype) -> int:
+    """The head count a page of ``dtype`` is stored at: ``num_heads``
+    rounded up to the rows one (rows, 128) tile of the dtype packs (8
+    of 4 bytes, 16 of 2), so that the pool's row-major layout has no
+    padding and the compiler keeps it (``fits``)."""
+    rows = 32 // jnp.dtype(dtype).itemsize
+    return -(-int(num_heads) // rows) * rows
 
 
 # The never-tuned guesses ISSUE 16 names: one slot per grid step, slot

@@ -156,9 +156,16 @@ class GenerationEngine:
         }
         rows_of = getattr(self.model, "cache_rows", None)
         if rows_of is not None:
-            # K/V rows resident by kind of cache (full layers, rings)
-            out["cache_rows"] = rows_of(
-                [s.ctx_len for s in self.session._slots if s is not None])
+            # resident by kind of cache (full layers, rings, state)
+            lens = [s.ctx_len for s in self.session._slots if s is not None]
+            out["cache_rows"] = rows_of(lens)
+            bytes_of = getattr(self.model, "cache_bytes", None)
+            if bytes_of is not None:
+                out["cache_bytes"] = bytes_of(lens)
+        if hasattr(alloc, "state_entries"):
+            # the second resource: one entry a seated sequence
+            out["state_entries_total"] = alloc.state_entries - 1
+            out["state_entries_free"] = alloc.free_entries
         cache = self.session.prefix_cache
         if cache is not None:
             out["prefix_cache"] = cache.stats()

@@ -9,10 +9,13 @@ post-attention projection, the feed-forward and the head.  The GPT-2
 block (``Gpt2Block``, served as ``TinyDecoderLM``) is defined here;
 OLMoE's lives in ``paddle_tpu/models/olmoe.py``, K-EXAONE's in
 ``paddle_tpu/models/exaone_moe.py``.  The jitted programs take the
-block as a static argument.  A block also says how its layers keep
-their rows (``PageRunCache``: every layer every row, in one page run a
-sequence; K-EXAONE's window layers keep a bounded ring each, beside a
-full layer, in the same pool).
+block as a static argument.  A block also says how a layer mixes
+tokens and what it keeps of them (``PageRunCache``: attention, every
+layer every row, in one page run a sequence; K-EXAONE's window layers
+keep a bounded ring each, beside a full layer, in the same pool;
+Olmo-Hybrid's linear-attention layers keep a recurrent state a
+sequence in buffers of their own, ``extra``, beside the pages of its
+full layers: ``paddle_tpu/models/olmo_hybrid.py``).
 
 Prefill is ONE jitted program per length *bucket* (the shared pow2
 ladder of ``pallas/tuning/bucket.py``, from 64 up to the sequence
@@ -36,12 +39,13 @@ it was given stay where they are, so a step can be entered with what
 the device already holds (``StepInFlight.next``) and is called in two
 halves, ``step_dispatch`` and ``step_collect``.
 
-Every program here takes both pools DONATED and hands back the same
-buffers: a layer's new rows are one scatter into the pool seen flat
-(``_write_rows``), and the kernels read the whole pool through page
-tables moved by the layer's offset (``_layer_pages``), so no program
-produces a value of a pool's or a layer slab's size.  A donated call
-that fails on the device has consumed the pools: ``_donating``.
+Every program here takes the cache's buffers DONATED (both pools, and
+the block's ``extra`` ones) and hands back the same buffers: a layer's
+new rows are one scatter into the pool seen flat (``_write_rows``), and
+the kernels read the whole pool through page tables moved by the
+layer's offset (``_layer_pages``), so no program produces a value of a
+pool's or a layer slab's size.  A donated call that fails on the device
+has consumed them all: ``_donating``.
 
 Weights are randomly initialized from a seed: these models exist to
 prove the kernel + session mechanics (tests pin the paged decode
@@ -54,7 +58,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -121,14 +125,70 @@ def _ln(x, scale):
     return (x - m) * jax.lax.rsqrt(v + 1e-5) * scale
 
 
+class Addressing(NamedTuple):
+    """Where the rows of a step go and what they see, as the skeleton
+    reckons it for every model: the flat row of the page run each new
+    row lands in, the slots' table rows (a block may keep more in a row
+    than the page run: rings, a state entry) and the rows each slot had
+    cached before the step."""
+
+    flat: jax.Array
+    tables: jax.Array
+    lens: jax.Array
+
+
 class PageRunCache:
     """The cache side of a block, as the programs below ask for it, for
     a model whose every layer keeps every row of a sequence in the one
     page run the session gave it: pools ``(L, N, pg, H, dh)``, layer
-    ``li`` reached by moving the same table by ``li * N``.  A block
-    whose layers differ in what they keep (``models/exaone_moe.py``:
-    window layers on rings beside a full layer) defines these four
-    itself."""
+    ``li`` reached by moving the same table by ``li * N``.
+
+    The programs ask a layer for its token mixer (``prompt_mixer`` over
+    a whole prompt, ``mixer`` over a step's rows) and thread the block's
+    cache through it: the two K/V pools and whatever further buffers the
+    block keeps (``extra``), all donated.  The default mixer is
+    attention over the page run: ``qkv``, the three calls below,
+    ``attn_out``.  A block whose layers differ in what they keep
+    defines the three itself (``models/exaone_moe.py``: window layers
+    on rings beside a full layer); one whose layers mix tokens another
+    way defines the mixers (``models/olmo_hybrid.py``: a recurrent
+    state a sequence beside the pages of its full layers)."""
+
+    def prompt_mixer(self, lp, x, pos, heads, live):
+        """Layer ``self.at`` over one whole prompt ``x`` (T, d) ->
+        (the rows after the mixer's residual, what the layer keeps of
+        the prompt for ``store_prompts``).  ``live`` (T,) bool or None:
+        the rows that are not the bucket's padding, which causal
+        attention need not be told."""
+        q, k, v = self.qkv(lp, x, pos, heads)
+        a = self.prompt_attention(q, k, v)
+        return self.attn_out(lp, x, a.reshape(x.shape[0], -1)), (k, v)
+
+    def store_prompts(self, cache, kept, where):
+        """``cache`` with what every layer ``kept`` of one prompt
+        written at ``where`` (the model's ``_prompt_rows``)."""
+        k_pool, v_pool = cache
+        ks = jnp.stack([k for k, _ in kept])
+        vs = jnp.stack([v for _, v in kept])
+        return (self.store_prompt(k_pool, ks, where),
+                self.store_prompt(v_pool, vs, where))
+
+    def mixer(self, lp, x, pos, cache, li, addr, heads, lone=False):
+        """Layer ``li`` of a step over the rows ``x`` ((S, d) a decode
+        step's, (S, T, d) a chunk's; ``lone``: (T, d), one sequence's
+        suffix, ``addr`` its table row and length) -> (the rows after
+        the mixer's residual, the cache's buffers written in place)."""
+        q, k, v = self.qkv(lp, x, pos, heads)
+        if lone:
+            a, k_pool, v_pool = self.cached_attention(
+                *cache, li, q[None], k, v, addr.flat, addr.tables[None],
+                addr.lens)
+            a = a[0]
+        else:
+            a, k_pool, v_pool = self.cached_attention(
+                *cache, li, q, k, v, addr.flat, addr.tables, addr.lens)
+        return (self.attn_out(lp, x, a.reshape(x.shape[:-1] + (-1,))),
+                (k_pool, v_pool))
 
     def layer(self, li):
         """The block as layer ``li`` has it: every layer the same."""
@@ -212,23 +272,20 @@ def _stack_reports(reports):
 
 def _dense_blocks(block, params, tokens, heads, live):
     """The dense causal forward over (T,) tokens up to the head: the
-    last block's output (T, d), per-layer K/V rows (L, T, heads, dh)
-    and the layers' reports.  Pure: the eager oracle and the jitted
-    prefill both run it."""
+    last block's output (T, d), what each layer keeps of the prompt
+    (attention: its K/V rows (T, heads, dh)) and the layers' reports.
+    Pure: the eager oracle and the jitted prefill both run it."""
     T = tokens.shape[0]
     pos = jnp.arange(T, dtype=jnp.int32)
     x = block.embed(params, tokens, slice(0, T))
-    ks, vs, reports = [], [], []
+    kept, reports = [], []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        q, k, v = lb.qkv(lp, x, pos, heads)
-        ks.append(k)
-        vs.append(v)
-        a = lb.prompt_attention(q, k, v)
-        x = lb.attn_out(lp, x, a.reshape(T, -1))
+        x, keep = lb.prompt_mixer(lp, x, pos, heads, live)
+        kept.append(keep)
         x, report = lb.mlp(lp, x, live)
         reports.append(report)
-    return x, jnp.stack(ks), jnp.stack(vs), _stack_reports(reports)
+    return x, kept, _stack_reports(reports)
 
 
 class StepLogits:
@@ -300,37 +357,49 @@ class PagedDecoderLM:
         self.seq_rows = self.pages_per_seq * self.page_size
         self.bos_id, self.eos_id = int(bos_id), int(eos_id)
 
+    # the block's cache buffers beyond the two K/V pools (a recurrent
+    # state's), donated to every program with them
+    extra_pools: tuple = ()
+
     def _make_pools(self, num_pages: int, dtype) -> None:
         self.allocator = PageAllocator(num_pages)
         shape = (self.layers, num_pages, self.page_size, self.heads, self.dh)
         self.k_pool = jnp.zeros(shape, dtype)
         self.v_pool = jnp.zeros(shape, dtype)
 
+    def _cache(self) -> tuple:
+        """Every buffer of the cache, as the programs are handed them."""
+        return (self.k_pool, self.v_pool, *self.extra_pools)
+
+    def _set_cache(self, k_pool, v_pool, *extra) -> None:
+        self.k_pool, self.v_pool, self.extra_pools = k_pool, v_pool, extra
+
     @contextlib.contextmanager
     def _donating(self, pools_in=None):
-        """Round a call of a program that is given both pools DONATED
-        and the wait for its results (a failure on the device shows at
-        the wait, not at the dispatch).  A call that failed before it
-        consumed the pools leaves them as they were.  One that failed
-        after has lost every page's rows: both pools are made anew,
-        and ``PoolsLost`` tells the session.  A step's wait comes in a
-        later call than its dispatch (``step_collect``) and is rounded
-        again, with the pools the dispatch was handed (``pools_in``);
-        no program runs between the two."""
-        k_in, v_in = pools_in or (self.k_pool, self.v_pool)
+        """Round a call of a program that is given the cache's buffers
+        DONATED and the wait for its results (a failure on the device
+        shows at the wait, not at the dispatch).  A call that failed
+        before it consumed them leaves them as they were.  One that
+        failed after has lost every page's rows and every state entry:
+        all the buffers are made anew together, and ``PoolsLost`` tells
+        the session.  A step's wait comes in a later call than its
+        dispatch (``step_collect``) and is rounded again, with the
+        buffers the dispatch was handed (``pools_in``); no program runs
+        between the two."""
+        pools_in = pools_in or self._cache()
         try:
             yield
         except BaseException as exc:
-            if not (k_in.is_deleted() or v_in.is_deleted()):
-                self.k_pool, self.v_pool = k_in, v_in
+            if not any(p.is_deleted() for p in pools_in):
+                self._set_cache(*pools_in)
                 raise
-            self.k_pool = jnp.zeros(k_in.shape, k_in.dtype)
-            self.v_pool = jnp.zeros(v_in.shape, v_in.dtype)
+            self._set_cache(*(jnp.zeros(p.shape, p.dtype)
+                              for p in pools_in))
             _M_POOL_REBUILDS.inc()
             raise PoolsLost(
-                "a program failed after it had consumed the K/V pools "
-                f"({type(exc).__name__}: {exc}); both were made anew, "
-                "empty") from exc
+                "a program failed after it had consumed the cache's "
+                f"pools ({type(exc).__name__}: {exc}); all were made "
+                "anew, empty") from exc
 
     def _observe(self, phase: str, report, rows: int) -> None:
         """What the layers reported of one prefill or step (None for a
@@ -342,9 +411,11 @@ class PagedDecoderLM:
     def _forward(self, tokens: jnp.ndarray):
         """Full dense causal forward over (T,) tokens -> (logits (T, V),
         per-layer K/V rows (L, T, heads, dh))."""
-        x, ks, vs, _ = _dense_blocks(self.block, self.params, tokens,
-                                     self.heads, None)
-        return self.block.head(self.params, x), ks, vs
+        x, kept, _ = _dense_blocks(self.block, self.params, tokens,
+                                   self.heads, None)
+        return (self.block.head(self.params, x),
+                jnp.stack([k for k, _ in kept]),
+                jnp.stack([v for _, v in kept]))
 
     def dense_greedy(self, prompt: Sequence[int],
                      max_new_tokens: int) -> List[int]:
@@ -407,11 +478,13 @@ class PagedDecoderLM:
                     f"of page_size strictly inside the {T}-token prompt")
             table = self.pool_table(pages)
             with self._donating():
-                logits, self.k_pool, self.v_pool, report = _prefill_chunk(
+                logits, k_pool, v_pool, report, extra = _prefill_chunk(
                     self.params, self.k_pool, self.v_pool,
                     jnp.asarray(table), np.int32(cached_len),
                     toks[cached_len:], heads=self.heads,
-                    page_size=self.page_size, block=self.block)
+                    page_size=self.page_size, block=self.block,
+                    extra=self.extra_pools)
+                self._set_cache(k_pool, v_pool, *extra)
                 logits = np.asarray(logits[-1])
                 self._observe("prefill", report, T - cached_len)
             return T, [], logits
@@ -420,9 +493,11 @@ class PagedDecoderLM:
         toks[:T] = prompt
         flat = self._prompt_rows(pages, bucket, T)
         with self._donating():
-            logits, self.k_pool, self.v_pool, report = _prefill_bucket(
+            logits, k_pool, v_pool, report, extra = _prefill_bucket(
                 self.params, self.k_pool, self.v_pool, toks, flat,
-                np.int32(T), heads=self.heads, block=self.block)
+                np.int32(T), heads=self.heads, block=self.block,
+                extra=self.extra_pools)
+            self._set_cache(k_pool, v_pool, *extra)
             logits = np.asarray(logits)
             self._observe("prefill", report, bucket)
         _M_PREFILL_TOKENS.inc(T)
@@ -501,13 +576,14 @@ class PagedDecoderLM:
                 tables, lens, tokens = (
                     jnp.asarray(a.astype(np.int32))
                     if isinstance(a, np.ndarray) else a for a in inputs)
-        pools_in = (self.k_pool, self.v_pool)
+        pools_in = self._cache()
         with self._donating(pools_in):
             with phase("decode.dispatch"):
-                logits, self.k_pool, self.v_pool, report, ids, *more = jitted(
-                    self.params, *pools_in, tables, lens, tokens,
+                logits, k_pool, v_pool, report, ids, *more, extra = jitted(
+                    self.params, *pools_in[:2], tables, lens, tokens,
                     heads=self.heads, page_size=self.page_size,
-                    block=self.block)
+                    block=self.block, extra=pools_in[2:])
+                self._set_cache(k_pool, v_pool, *extra)
                 # queued behind the step, so the collect's wait ends with
                 # both on the host and asks the device for nothing more
                 ids.copy_to_host_async()
@@ -557,22 +633,25 @@ def _layer_pages(k_pool, v_pool, li, tables):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block"),
-                   donate_argnums=(1, 2))
+                   donate_argnums=(1, 2), donate_argnames=("extra",))
 def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
-                    block=GPT2):
+                    block=GPT2, extra=()):
     """The whole prefill of one prompt padded to ``tokens.shape[0]``
     rows: the dense forward, K/V row ``i`` of every layer scattered to
     pool row ``flat[i]`` of the donated pools, and the logits of row
     ``n - 1`` (the 50k-wide head runs on that row alone).  Its shape
     depends on the bucket only, not on the prompt's length or pages.
-    Rows from ``n`` on are padding: not ``live`` to the block."""
+    Rows from ``n`` on are padding: not ``live`` to the block.
+    ``extra``: the block's cache buffers beyond the two pools, donated
+    with them and handed back last, as by every program here."""
     _M_PREFILL_PROGRAMS.inc(bucket=str(tokens.shape[0]))   # at trace
     live = jnp.arange(tokens.shape[0], dtype=jnp.int32) < n
-    x, ks, vs, report = _dense_blocks(block, params, tokens, heads, live)
-    k_pool = block.store_prompt(k_pool, ks, flat)
-    v_pool = block.store_prompt(v_pool, vs, flat)
+    x, kept, report = _dense_blocks(block, params, tokens, heads, live)
+    k_pool, v_pool, *extra = block.store_prompts(
+        (k_pool, v_pool, *extra), kept, flat)
     last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1)
-    return block.head(params, last)[0], k_pool, v_pool, report
+    return (block.head(params, last)[0], k_pool, v_pool, report,
+            tuple(extra))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -582,9 +661,9 @@ def _copy_pools_page(k_pool, v_pool, src, dst):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
-                   donate_argnums=(1, 2))
+                   donate_argnums=(1, 2), donate_argnames=("extra",))
 def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
-                   heads, page_size, block=GPT2):
+                   heads, page_size, block=GPT2, extra=()):
     """Suffix prefill over cached pages: the suffix's Ts tokens are one
     chunk at positions cached_len..cached_len+Ts-1; attention sees the
     cached prefix rows plus the causal part of the suffix itself.
@@ -594,16 +673,15 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
     x = block.embed(params, tokens, pos)                    # (Ts, d)
     flat = table[pos // page_size] * page_size + pos % page_size
     lens1 = cached_len[None] if jnp.ndim(cached_len) == 0 else cached_len
+    cache, addr = (k_pool, v_pool, *extra), Addressing(flat, table, lens1)
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        q, k, v = lb.qkv(lp, x, pos, heads)
-        a, k_pool, v_pool = lb.cached_attention(
-            k_pool, v_pool, li, q[None], k, v, flat, table[None], lens1)
-        x = lb.attn_out(lp, x, a[0].reshape(Ts, -1))
+        x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads, lone=True)
         x, report = lb.mlp(lp, x, None)          # every suffix row is real
         reports.append(report)
-    return block.head(params, x), k_pool, v_pool, _stack_reports(reports)
+    return (block.head(params, x), *cache[:2], _stack_reports(reports),
+            tuple(cache[2:]))
 
 
 def _greedy_ids(logits):
@@ -613,9 +691,9 @@ def _greedy_ids(logits):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
-                   donate_argnums=(1, 2))
+                   donate_argnums=(1, 2), donate_argnames=("extra",))
 def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
-                 heads, page_size, block=GPT2):
+                 heads, page_size, block=GPT2, extra=()):
     """k tokens for every slot in one step (the speculative verify):
     append all k K/V rows, attend with per-row causal offsets through
     the chunked kernel.  Fixed-shape per (S, k) — compiled once.
@@ -627,24 +705,22 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
             * page_size + pos % page_size).reshape(-1)      # (S*T,)
     # an inactive slot holds the null table: its rows are not live
     live = jnp.broadcast_to(tables[:, :1] > 0, (S, T))
+    cache, addr = (k_pool, v_pool, *extra), Addressing(flat, tables, lens)
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        q, k, v = lb.qkv(lp, x, pos, heads)
-        a, k_pool, v_pool = lb.cached_attention(
-            k_pool, v_pool, li, q, k, v, flat, tables, lens)
-        x = lb.attn_out(lp, x, a.reshape(S, T, -1))
+        x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads)
         x, report = lb.mlp(lp, x, live)
         reports.append(report)
     logits = block.head(params, x)
-    return (logits, k_pool, v_pool, _stack_reports(reports),
-            _greedy_ids(logits))
+    return (logits, *cache[:2], _stack_reports(reports),
+            _greedy_ids(logits), tuple(cache[2:]))
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
-                   donate_argnums=(1, 2))
+                   donate_argnums=(1, 2), donate_argnames=("extra",))
 def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
-                 heads, page_size, block=GPT2):
+                 heads, page_size, block=GPT2, extra=()):
     """One token for every slot: append K/V into pages, attend over the
     page tables.  Fixed-shape in every argument — compiled once.
     -> (logits (S, V), both pools, the layers' reports, the greedy
@@ -660,15 +736,14 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
     flat = (tables[jnp.arange(S), lens // page_size] * page_size
             + lens % page_size)                            # (S,)
     live = tables[:, 0] > 0
+    cache, addr = (k_pool, v_pool, *extra), Addressing(flat, tables, lens)
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        q, k, v = lb.qkv(lp, x, lens, heads)
-        a, k_pool, v_pool = lb.cached_attention(
-            k_pool, v_pool, li, q, k, v, flat, tables, lens)
-        x = lb.attn_out(lp, x, a.reshape(S, -1))
+        x, cache = lb.mixer(lp, x, lens, cache, li, addr, heads)
         x, report = lb.mlp(lp, x, live)
         reports.append(report)
     logits = block.head(params, x)
-    return (logits, k_pool, v_pool, _stack_reports(reports),
-            _greedy_ids(logits), lens + live.astype(lens.dtype))
+    return (logits, *cache[:2], _stack_reports(reports),
+            _greedy_ids(logits), lens + live.astype(lens.dtype),
+            tuple(cache[2:]))

@@ -29,6 +29,10 @@ _M_PAGE_REFS = _metrics.gauge(
 _M_PAGES_SHARED = _metrics.gauge(
     "decode_pages_shared", "pages with refcount > 1 (aliased by forks, "
     "beams, or the prefix cache)")
+_M_STATE_ENTRIES = _metrics.gauge(
+    "decode_state_entries",
+    "state entries of the cache manager (one a seated sequence of a "
+    "model with recurrent layers), by state: in_use, free")
 _M_COW_COPIES = _metrics.counter(
     "decode_cow_copies_total",
     "shared pages copied before a write (copy-on-write splits)")
@@ -151,6 +155,96 @@ class PageAllocator:
                 freed.append(p)
         _M_PAGE_FREES.inc(len(freed))
         self._set_gauges()
+        return freed
+
+
+class CacheManager(PageAllocator):
+    """Pages and, beside them, state entries: the second resource of a
+    model whose recurrent layers keep a state of fixed size a sequence
+    (``models/olmo_hybrid.py``), from the one object the session asks.
+
+    A sequence's reservation of ``n`` units is ``n - 1`` pages and ONE
+    state entry (the model's ``context_pages`` counts the entry in, as
+    a model with rings counts its rings' pages in).  ``alloc(n)`` hands
+    out the pages' ids followed by the entry's, which is
+    ``num_pages + e`` for entry ``e`` of ``1 .. state_entries - 1``: one
+    id space, so that ``free`` takes back whatever list it is given, in
+    any order, and a table row can hold both.  Entry 0 is the null
+    entry, as page 0 is the null page: what an inactive slot's row
+    names.  All or nothing: with too few pages or no entry free,
+    ``can_alloc`` is false, ``alloc`` raises ``PoolExhausted`` and
+    takes neither, and the request waits.  An entry is one sequence's:
+    ``fork`` of a list that holds one raises."""
+
+    def __init__(self, num_pages: int, state_entries: int):
+        super().__init__(num_pages)
+        if state_entries < 2:
+            raise ValueError("need at least 2 state entries (entry 0 is "
+                             "reserved)")
+        self.state_entries = int(state_entries)
+        self._free_entries: List[int] = list(
+            range(self.state_entries - 1, 0, -1))
+        self.gauge_entries()
+
+    @property
+    def free_entries(self) -> int:
+        return len(self._free_entries)
+
+    @property
+    def entries_in_use(self) -> int:
+        return self.state_entries - 1 - len(self._free_entries)
+
+    def entry_of(self, ids: Sequence[int]) -> int:
+        """The state entry among a sequence's ``ids`` (0: none)."""
+        held = [int(i) - self.num_pages for i in ids
+                if int(i) >= self.num_pages]
+        if len(held) > 1:
+            raise ValueError(f"{len(held)} state entries in one sequence")
+        return held[0] if held else self.NULL_PAGE
+
+    def pages_of(self, ids: Sequence[int]) -> List[int]:
+        return [int(i) for i in ids if int(i) < self.num_pages]
+
+    def gauge_entries(self) -> None:
+        """``decode_state_entries{state}`` as it stands (the session
+        sets it every tick; ``alloc`` and ``free`` when it changes)."""
+        _M_STATE_ENTRIES.set(self.entries_in_use, state="in_use")
+        _M_STATE_ENTRIES.set(self.free_entries, state="free")
+
+    def _set_gauges(self) -> None:
+        super()._set_gauges()
+        self.gauge_entries()
+
+    def can_alloc(self, n: int) -> bool:
+        return bool(self._free_entries) and super().can_alloc(n - 1)
+
+    def alloc(self, n: int) -> List[int]:
+        if n < 2:
+            raise ValueError("a sequence holds a page at least, and an "
+                             "entry")
+        if not self._free_entries:
+            raise PoolExhausted(
+                f"no state entry free of {self.state_entries - 1} usable")
+        pages = super().alloc(n - 1)
+        entry = self._free_entries.pop()
+        self.gauge_entries()
+        return pages + [self.num_pages + entry]
+
+    def fork(self, pages: Sequence[int]) -> List[int]:
+        if self.entry_of(pages):
+            raise ValueError("a state entry is one sequence's: it cannot "
+                             "be forked")
+        return super().fork(pages)
+
+    def free(self, pages: Sequence[int]) -> List[int]:
+        entry = self.entry_of(pages)
+        if entry and not (0 < entry < self.state_entries
+                          and entry not in self._free_entries):
+            raise ValueError(f"double free / bad state entry {entry}")
+        freed = super().free(self.pages_of(pages))
+        if entry:
+            self._free_entries.append(entry)
+            self.gauge_entries()
         return freed
 
 

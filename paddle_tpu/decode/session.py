@@ -77,7 +77,13 @@ _M_CACHE_ROWS = _metrics.gauge(
     "decode_cache_rows",
     "K/V rows resident for the seated sequences, summed over layers, by "
     "kind of cache: `full` = layers that keep every row, `window` = "
-    "layers on bounded rings (the model's `cache_rows`)")
+    "layers on bounded rings, `state` = recurrent layers' entries, one "
+    "a sequence a layer whatever its length (the model's `cache_rows`)")
+_M_CACHE_BYTES = _metrics.gauge(
+    "decode_cache_bytes",
+    "bytes resident for the seated sequences by kind of cache (the "
+    "model's `cache_bytes`): `full` = the K/V rows of layers that keep "
+    "every row, `state` = the recurrent layers' state entries")
 _M_STEPS = _metrics.counter(
     "decode_steps_total", "fixed-shape decode steps dispatched")
 _M_SLOT_STEPS = _metrics.counter(
@@ -634,7 +640,14 @@ class DecodeSession:
       request is refused as ``beam_unsupported``
     - ``cache_rows(lens) -> {kind: rows}``: K/V rows resident for
       sequences of those lengths, by kind of cache; gauged every tick
-      as ``decode_cache_rows{kind}``
+      as ``decode_cache_rows{kind}``; ``cache_bytes(lens)`` likewise
+      as ``decode_cache_bytes{kind}``
+    - ``allocator`` may be a ``CacheManager``: a sequence's
+      reservation (``context_pages`` units) is then its pages and one
+      state entry, taken and given back together; a request waits
+      while either is short
+    - ``supports_verify`` False: the model cannot roll a speculative
+      chunk back (a recurrent state); a draft is not used
     - ``prefill_bucket(prompt_len) -> int``: rows the full-prompt
       prefill pads to; it and the pad go on the ``decode.prefill`` span
     - logits from ``decode`` / ``verify_chunk`` that carry ``ids``
@@ -672,6 +685,7 @@ class DecodeSession:
         # verifies the whole chunk in one step (needs verify_chunk)
         self._spec_draft = (spec_draft
                             if hasattr(model, "verify_chunk")
+                            and getattr(model, "supports_verify", True)
                             and getattr(model, "grows_kv", False)
                             else None)
         self.spec_k = int(spec_k)
@@ -723,8 +737,9 @@ class DecodeSession:
             raise AdmissionRefused(
                 "beam_unsupported",
                 "beam search forks a sequence's pages; this model keeps "
-                "window layers on per-sequence rings, which a fork would "
-                "have to copy (not supported yet)")
+                "a cache beside them that is one sequence's (window "
+                "layers' rings, recurrent layers' state), which a fork "
+                "would have to copy (not supported yet)")
         if isinstance(req, BeamRequest) and req.beam_size > self.max_slots:
             _M_REFUSED.inc(reason="beam_too_wide")
             raise AdmissionRefused(
@@ -837,6 +852,13 @@ class DecodeSession:
             lens = [s.ctx_len for s in self._slots if s is not None]
             for kind, rows in rows_of(lens).items():
                 _M_CACHE_ROWS.set(rows, kind=kind)
+            bytes_of = getattr(self.model, "cache_bytes", None)
+            if bytes_of is not None:
+                for kind, n in bytes_of(lens).items():
+                    _M_CACHE_BYTES.set(n, kind=kind)
+            gauge = getattr(self.model.allocator, "gauge_entries", None)
+            if gauge is not None:
+                gauge()
 
     def _tick(self) -> int:
         if self._flight is not None:

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Are the paged skeleton's programs what they were?  Dump the compiled
 text (XLA:CPU, toy size) of `_decode_step`, `_verify_step`,
-`_prefill_chunk` and `_prefill_bucket` for `Gpt2Block` and `OlmoeBlock`
-with the metadata dropped (op_name / source lines, and the file and
+`_prefill_chunk` and `_prefill_bucket` for `Gpt2Block`, `OlmoeBlock` and
+`ExaoneMoeBlock` with the metadata dropped (op_name / source lines, and the file and
 function tables at the head of the text), one file a program:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<tree A> python scripts/program_identity.py /tmp/a
@@ -48,7 +48,8 @@ def dump(out, name, model, dm):
             *pools, jnp.zeros((P,), jnp.int32), np.int32(8),
             jnp.zeros((5,), jnp.int32), **kw),
         "bucket": dm._prefill_bucket.lower(
-            *pools, np.zeros((64,), np.int32), np.zeros((64,), np.int32),
+            *pools, np.zeros((64,), np.int32),
+            model._prompt_rows(model.allocator.alloc(P)[:P], 64, 3),
             np.int32(3), heads=model.heads, block=model.block),
     }
     for key, low in lowered.items():
@@ -58,6 +59,7 @@ def dump(out, name, model, dm):
 
 def main(out):
     from paddle_tpu.decode import model as dm
+    from paddle_tpu.models.exaone_moe import ExaoneMoeLM
     from paddle_tpu.models.olmoe import OlmoeLM
 
     os.makedirs(out, exist_ok=True)
@@ -65,6 +67,13 @@ def main(out):
     dump(out, "olmoe", OlmoeLM(
         vocab=96, d_model=32, num_heads=4, num_layers=2, num_experts=8,
         experts_per_tok=2, expert_width=16, max_len=64, num_pages=32,
+        page_size=8, pages_per_seq=8, dtype="float32"), dm)
+    dump(out, "exaone", ExaoneMoeLM(
+        vocab=96, d_model=32, num_heads=4, num_kv_heads=2, head_dim=8,
+        layer_types=("sliding_attention",) * 2 + ("full_attention",),
+        mlp_layer_types=("dense", "sparse", "sparse"), sliding_window=8,
+        dense_width=48, expert_width=16, num_experts_published=8,
+        held_experts=(2, 4), experts_per_tok=2, max_len=64, num_pages=64,
         page_size=8, pages_per_seq=8, dtype="float32"), dm)
     print("tree", os.path.dirname(os.path.dirname(dm.__file__)), "dumped",
           len(os.listdir(out)), "programs to", out)

@@ -590,6 +590,189 @@ def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch,
     assert sum(op == "ragged-dot-none" for op in ops) == 3 * (L - 1)
 
 
+def _hybrid_cell(one_chip, monkeypatch):
+    """The ``olmo-hybrid-7b`` generate configuration at its real sizes,
+    as shapes on the described chip, built as its gen_config builds the
+    model: (cfg, params, (k_pool, v_pool), (state_pool, conv_pool),
+    block, table width, sds)."""
+    import functools
+    import json
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode.attention import storage_heads
+    from paddle_tpu.models import olmo_hybrid as oh
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        cfg = json.load(f)
+    g, L = cfg["generate"], cfg["num_hidden_layers"]
+    dtype = jnp.dtype(g["dtype"])
+    types = tuple(cfg["layer_types"][:L])
+    H = cfg["num_attention_heads"]
+    dh = cfg["hidden_size"] // H
+    Hl, dk, dv = (cfg["linear_num_key_heads"], cfg["linear_key_head_dim"],
+                  cfg["linear_value_head_dim"])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(
+            oh.init_params, jax.random.key(0), vocab=cfg["vocab_size"],
+            d=cfg["hidden_size"], heads=H, head_dim=dh, layer_types=types,
+            width=cfg["intermediate_size"], lin_heads=Hl, d_k=dk, d_v=dv,
+            conv=cfg["linear_conv_kernel_dim"], dtype=dtype)))
+    block = oh.OlmoHybridBlock(
+        layer_types=types, head_dim=dh, lin_heads=Hl, d_k=dk, d_v=dv,
+        eps=cfg["rms_norm_eps"], full_pages=g["pages_per_seq"])
+    full = sum(t == oh.FULL for t in types)
+    assert storage_heads(H, dtype) == 32 and oh.stored_key_width(dk) == 128
+    pool = sds((full, g["num_pages"], g["page_size"], 32, dh), dtype)
+    E = g["state_entries"]
+    assert E == g["slots"] + 1
+    extra = (sds((L - full, E, Hl, dv, 128), jnp.float32),
+             sds((L - full, E, cfg["linear_conv_kernel_dim"] - 1,
+                  Hl * (2 * dk + dv)), dtype))
+    return cfg, params, pool, extra, block, g["pages_per_seq"] + 1, sds
+
+
+# what may hold as many elements as a pool: the pool's parameter (the
+# entry's, a loop body's), its bitcasts, a loop's tuple element, and the
+# in-place writes: a scatter, a dynamic-update-slice, and the fusion
+# whose root one is
+_IN_PLACE = ("parameter", "bitcast", "get-tuple-element", "scatter",
+             "dynamic-update-slice")
+
+
+def _pool_sized_strays(text, sizes):
+    """[(instruction, op, which pool)] of the instructions with as many
+    elements as one of ``sizes`` ({elements: name}) that are neither a
+    view of the pool nor an in-place write to it."""
+    roots, cur = set(), None
+    lines = text.splitlines()
+    for line in lines:
+        c = _COMPUTATION.match(line)
+        if c and " = " not in line.split("(")[0]:
+            cur = c.group(1)
+        elif "ROOT" in line and (" scatter(" in line
+                                 or " dynamic-update-slice(" in line):
+            roots.add(cur)
+    stray = []
+    for line in lines:
+        r = _RESULT.match(line)
+        if not r or not r.group(2):
+            continue
+        which = sizes.get(math.prod(map(int, r.group(2).split(","))))
+        if which is None:
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if not (r.group(3) in _IN_PLACE or (
+                r.group(3) == "fusion" and called
+                and called.group(1) in roots)):
+            stray.append((r.group(1), r.group(3), which))
+    return stray
+
+
+def _hybrid_sizes(pool, extra):
+    return {math.prod(pool.shape): "kv", math.prod(pool.shape[1:]): "kv slab",
+            math.prod(extra[0].shape): "state",
+            math.prod(extra[1].shape): "conv"}
+
+
+def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
+                                                            monkeypatch):
+    """The decode step of the ``olmo-hybrid-7b`` configuration at its
+    real sizes (12 linear + 4 full layers, 447 bf16 pages of 128 rows
+    at 32 stored heads, 49 state entries, 48 slots): the four cache
+    buffers are aliased input to output; the four full layers run the
+    paged kernel under ``attn_full`` and write their rows by 8
+    scatters; every linear layer's loop over the slots reads and writes
+    an entry where it lies (dynamic-update-slices), and nothing else
+    has a pool's size but two layout copies of the 41 MB conv pool, at
+    the step's two ends; the plan is arguments + 70 MB."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, extra, block, width, sds = _hybrid_cell(
+        one_chip, monkeypatch)
+    g, S = cfg["generate"], cfg["generate"]["slots"]
+    compiled = dm._decode_step.lower(
+        params, pool, pool, sds((S, width), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.int32),
+        heads=cfg["num_attention_heads"], page_size=g["page_size"],
+        block=block, extra=extra).compile()
+    out = jax.tree.leaves(compiled.out_info)
+    assert (out[0].shape, out[0].dtype) == ((S, cfg["vocab_size"]),
+                                            jnp.float32)
+    assert [o.shape for o in out[-2:]] == [e.shape for e in extra]
+    m = compiled.memory_analysis()
+    buffers = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in (pool, pool) + extra)
+    assert m.alias_size_in_bytes >= buffers
+    planned = _planned_bytes(compiled)
+    assert planned == HYBRID_PLANS["decode"] < 15.0e9, planned
+    text = compiled.as_text()
+    stray = _pool_sized_strays(text, _hybrid_sizes(pool, extra))
+    assert sorted(s[1:] for s in stray) == [
+        ("copy", "conv"), ("copy", "conv"), ("copy-done", "conv")], stray
+    assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
+    rpa = _kernel_op_names(text)
+    assert len(rpa) == 4 and all(
+        "_decode_step)/attn_full/" in op and "ragged_paged_attention/" in op
+        for op in rpa)
+    for scope in ("lin_attn_state", "lin_attn_conv"):
+        assert re.search(
+            rf"jit\(_decode_step\)/lin_attn/while/body/(\w+/)?{scope}/",
+            text), scope
+
+
+# memory_analysis() for a described v5e: arguments + outputs +
+# temporaries - aliased, at the configuration's 447 pages
+HYBRID_PLANS = {"decode": 13_820_392_448, 4096: 14_851_249_664,
+                4608: 14_992_017_408}
+
+
+@pytest.mark.parametrize("bucket", [4096, 4608])
+def test_hybrid_top_prefill_fits_beside_weights_states_and_pages(
+        one_chip, monkeypatch, bucket):
+    """The 4,096-row prefill bucket (the longest the cell's traffic
+    sends) and the 4,608-row one (a sequence's capacity): the plan fits
+    the chip beside 8.2 GB of weights, 3.75 GB of pages and 1.78 GB of
+    states (the 4,608-row plan is what ``num_pages`` was chosen by, and
+    the configuration's ``planned_bytes``); all four buffers are
+    aliased; the four full layers run the flash kernel at 30 heads; the
+    entry is written whole by one dynamic-update-slice a pool, the
+    pages by two scatters, and nothing else has a pool's size."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, extra, block, width, sds = _hybrid_cell(
+        one_chip, monkeypatch)
+    compiled = dm._prefill_bucket.lower(
+        params, pool, pool, sds((bucket,), jnp.int32),
+        (sds((bucket,), jnp.int32), sds((), jnp.int32)),
+        sds((), jnp.int32), heads=cfg["num_attention_heads"], block=block,
+        extra=extra).compile()
+    m = compiled.memory_analysis()
+    buffers = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in (pool, pool) + extra)
+    assert m.alias_size_in_bytes >= buffers
+    planned = _planned_bytes(compiled)
+    assert planned == HYBRID_PLANS[bucket] < 15.0e9, planned
+    if bucket == 4608:
+        assert planned == cfg["generate"]["planned_bytes"]
+    text = compiled.as_text()
+    assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
+    flash = _kernel_op_names(text)
+    assert len(flash) == 4 and all(
+        "_prefill_bucket)/attn_full/" in op and "flash_attention_fwd" in op
+        for op in flash)
+    for scope in ("lin_attn/lin_attn_scan", "lin_attn/lin_attn_conv"):
+        assert f"jit(_prefill_bucket)/{scope}/" in text, scope
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_attention_compiles(one_chip, grad):
     from paddle_tpu.pallas.flash_attention import flash_attention
