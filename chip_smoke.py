@@ -10,7 +10,10 @@ entry points, in ONE process on one TPU v5e:
           (flash attention fwd+bwd in the transformer LM, the fused LSTM,
           the narrow-row softmax), each checked present in the step's
           program and compared with the same step under
-          ``pallas.enable(False)``;
+          ``pallas.enable(False)``; and a hybrid's decode step (three
+          Gated-DeltaNet layers and a full one at the published head
+          shapes), whose state entries the ``gated_delta_step`` kernel
+          advances, against the same step's loop over the slots;
 - serve   the HTTP server exactly as ``paddle serve`` builds it:
           ``/health``, ``/predict`` on a ResNet-50 inference export, and
           ``/generate`` over the paged-KV decode engine vs the same
@@ -55,6 +58,12 @@ SIZES = {
     # benchmark/run.py's "lstm" row (h=256)
     "lstm": dict(B=64, T=100, emb=512, hidden=256, steps=3),
     "softmax": dict(rows=4096, cols=256, steps=3),
+    # one period of Olmo-Hybrid at the published head shapes (30 linear
+    # heads of d_k 96, d_v 192; full heads of 128), the widths shrunk
+    "hybrid": dict(slots=8, steps=3, model=dict(
+        vocab=4096, d_model=1024, num_heads=8, head_dim=128,
+        intermediate_size=2048, max_len=512, num_pages=40, page_size=32,
+        pages_per_seq=4, state_entries=9)),
     "serve": dict(image=(3, 224, 224), classes=1000, batches=(1, 3, 8),
                   gen_requests=6, gen_slots=4, gen_tokens=16),
     # global batch 256 over dp=4; the hybrid runs S=2048 so each sp=2
@@ -293,6 +302,82 @@ def _kernel_case(name, kernel, build, feed, steps, tol):
         f"(tol {tol})")
 
 
+def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
+    """A hybrid's decode step (three linear layers, then a full one)
+    with the state kernel dispatched and again under
+    ``pallas.enable(False)``, both from the same seeded weights, pages
+    and state entries, the same tokens forced: the dispatch counter
+    moved once a linear layer; the first layer's entries, whose inputs
+    are the forced tokens' alone and so the same rows on both sides,
+    agree within ``state_tol`` (relative RMS: float32 rounding, only
+    the kernel differs); the logits and the later layers' entries, fed
+    through bf16 casts of what came before, within ``tol``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.models.olmo_hybrid import FULL, LINEAR, OlmoHybridLM
+
+    S, types = size["slots"], (LINEAR, LINEAR, LINEAR, FULL)
+    runs = {}
+    for mode in ("auto", "off"):
+        pk.enable(mode)
+        jax.clear_caches()  # dispatch is decided at trace time
+        m = OlmoHybridLM(seed=seed, layer_types=types, **size["model"])
+        keys = jax.random.split(jax.random.key(seed), 4)
+        m.k_pool, m.v_pool, states, tails = (
+            jax.random.normal(k, p.shape, jnp.float32).astype(p.dtype)
+            for k, p in zip(keys, m._cache()))
+        # entries as a prefill leaves them: zero beyond the key width
+        m.extra_pools = (
+            states * (jnp.arange(states.shape[-1]) < m.block.d_k), tails)
+        # all slots but the last seated, each on a page run and on an
+        # entry of its own, out of order
+        tables = np.zeros((S, m.pages_per_seq), np.int32)
+        entries = 1 + np.random.RandomState(seed).permutation(S - 1)
+        for s in range(S - 1):
+            tables[s, :m.full_pages] = 1 + s * m.full_pages + np.arange(
+                m.full_pages)
+            tables[s, m.full_pages] = entries[s]
+        lens = np.where(np.arange(S) < S - 1, 40 + 3 * np.arange(S), 0)
+        before = _counter("pallas_dispatch_total", kernel="gated_delta_step",
+                          path=EXPECT["kernel_path"])
+        rows = []
+        for step in range(size["steps"]):
+            tokens = np.random.RandomState(seed + step).randint(
+                2, m.vocab, (S, 1))
+            logits, _ = m.decode(tokens, [], tables,
+                                 (lens + step).astype(np.int32))
+            rows.append(np.asarray(logits)[:S - 1])
+        if mode == "auto":
+            ran = _counter("pallas_dispatch_total", kernel="gated_delta_step",
+                           path=EXPECT["kernel_path"]) - before
+            assert ran == types.count(LINEAR), (
+                f"gated_delta_step: {ran} {EXPECT['kernel_path']} "
+                "dispatches in one traced step, not one a linear layer")
+            say(f"  kernel gated_delta_step: {int(ran)} "
+                f"{EXPECT['kernel_path']} dispatch(es) in the step")
+        live = np.unique(tables[:S - 1, m.full_pages])
+        runs[mode] = (np.stack(rows), np.asarray(m.state_pool)[:, live])
+        assert np.isfinite(runs[mode][0]).all(), "hybrid: non-finite logits"
+    pk.enable("auto")
+
+    def rel_rms(a, b):
+        return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+    (logits, states), (logits_off, states_off) = runs["auto"], runs["off"]
+    worst = rel_rms(logits, logits_off)
+    first = rel_rms(states[0], states_off[0])
+    later = rel_rms(states[1:], states_off[1:])
+    assert first <= state_tol and max(worst, later) <= tol, (
+        f"hybrid step: kernel vs XLA slot loop: first layer's entries rel "
+        f"RMS {first:.2e} (tol {state_tol}), later layers' {later:.2e}, "
+        f"logits {worst:.2e} (tol {tol})")
+    say(f"  hybrid step: kernel vs pallas.enable(False) first layer's "
+        f"entries rel RMS {first:.2e} (tol {state_tol}), later layers' "
+        f"{later:.2e}, logits {worst:.2e} (tol {tol})")
+
+
 def phase_kernels(sizes, seed):
     import jax.numpy as jnp
 
@@ -363,6 +448,7 @@ def phase_kernels(sizes, seed):
     _kernel_case("softmax", "softmax", build_softmax, feed, m["steps"],
                  tol=1e-3)
     amp.enable(False)
+    _hybrid_step_case(sizes["hybrid"], seed)
 
 
 # -- phase: serve --------------------------------------------------------

@@ -22,9 +22,12 @@ with the state ``S`` (d_v, d_k) zero before row 0.  Computed two ways:
 over a prompt **chunked** (``chunked_gated_delta``: inside a chunk of
 64 rows matmuls and one unit-lower-triangular solve, the state carried
 chunk to chunk; no loop over rows), and over a decode step's rows one
-token on each slot's state (``step_gated_delta``).  The plain
-recurrence, row by row, is the benchmark's reference
-(``perf/reference/olmo_hybrid_block.py``).
+token on each slot's state: by ONE Pallas call a layer where
+``pallas.use_gated_delta_step`` says so (``pallas/gated_delta.py``:
+the slots' entries scalar-prefetched, each read once and written once
+where it lies), else slot by slot in XLA over ``step_gated_delta``, the
+kernel's reference.  The plain recurrence, row by row, is the
+benchmark's reference (``perf/reference/olmo_hybrid_block.py``).
 
 A **full** layer: q, k, v of 30 heads of 128, an RMSNorm over the whole
 q and the whole k projection (OLMo's), causal softmax, no positional
@@ -58,6 +61,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import pallas as pk
 from paddle_tpu.decode.attention import storage_heads
 from paddle_tpu.decode.model import (
     PagedDecoderLM,
@@ -67,6 +71,7 @@ from paddle_tpu.decode.model import (
 from paddle_tpu.decode.paged_kv import CacheManager
 from paddle_tpu.models.exaone_moe import swiglu
 from paddle_tpu.models.olmoe import _mm, rms_norm
+from paddle_tpu.pallas.gated_delta import gated_delta_step
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -105,9 +110,13 @@ def stored_key_width(d_k: int) -> int:
     """The key width a state entry is stored at: ``d_k`` rounded up to
     the chip's 128 lanes, zeros beyond ``d_k``.  The tiles would pad a
     row of 96 floats to 128 anyway; stored so, the pool's row-major
-    layout has no padding, the compiler keeps it, and a slot's entry is
-    one contiguous block (at (30, 192, 96) it laid the entries out
-    innermost and sliced the whole pool for a step's gather)."""
+    layout has no padding, the compiler keeps it, a slot's entry is one
+    contiguous block (at (30, 192, 96) it laid the entries out
+    innermost and sliced the whole pool for a step's gather) and the
+    step kernel's blocks of it are whole tiles (its ``fits()`` asks
+    for a multiple of 128).  A quarter of what a step moves is this
+    padding: four heads' 96 keys packed into 384 lanes would store
+    none (PERF.md section 7)."""
     return -(-int(d_k) // LANES) * LANES
 
 
@@ -196,10 +205,9 @@ def step_gated_delta(q, k, v, g, beta, state):
     """One row on a state (any leading shape: a slot's, or the slots'):
     ``q``, ``k`` (..., H, d_k), ``v`` (..., H, d_v), ``g``, ``beta``
     (..., H), ``state`` (..., H, d_v, d_k) -> (o (..., H, d_v), the new
-    state).  Multiply-reduces, float32: the
-    step is bound by the state's bytes, and ``o = S_t q`` is taken from
-    the old state's two products, so the old state is read once for
-    both."""
+    state).  Multiply-reduces, float32; ``o = S_t q`` is taken from the
+    old state's two products.  ``pallas/gated_delta.py`` is this, in
+    this order, on blocks of the pool in VMEM."""
     alpha = jnp.exp(g)[..., None]                             # (S, H, 1)
     Sk = jnp.sum(state * k[..., None, :], axis=-1)            # (S, H, d_v)
     Sq = jnp.sum(state * q[..., None, :], axis=-1)
@@ -384,23 +392,66 @@ class OlmoHybridBlock(PageRunCache):
         # (a bitcast); an inactive slot's is the null entry 0
         at = self.index_in_kind * E + addr.tables[:, self.full_pages]
         w = lp["w_conv"].astype(_F32)
-        wide = state_pool.shape[-1]              # the keys as stored
+        advance = (self._advance_by_kernel if pk.use_gated_delta_step(
+            state_pool.dtype, *state_pool.shape[2:])
+            else self._advance_slot_by_slot)
+        with jax.named_scope("lin_attn"):
+            o, states, tails = advance(
+                w, state_pool.reshape((Ll * E,) + state_pool.shape[2:]),
+                conv_pool.reshape((Ll * E,) + conv_pool.shape[2:]),
+                at, z, g, beta)
+            y = self._gated_norm(lp, o, gate)
+        return (self._lin_out(lp, x, y),
+                (k_pool, v_pool, states.reshape(state_pool.shape),
+                 tails.reshape(conv_pool.shape)))
+
+    def _conv_row(self, w, tails, e, z_s):
+        """A slot's conv: its row after the three its entry ``e`` keeps
+        -> (the conv's output row, the tails with the entry's moved on
+        one row, in place)."""
+        with jax.named_scope("lin_attn_conv"):
+            rows = jnp.concatenate(
+                [jax.lax.dynamic_index_in_dim(tails, e, 0, False),
+                 z_s[None]])
+            zc = jax.nn.silu(jnp.sum(rows.astype(_F32) * w, axis=0))
+            tails = jax.lax.dynamic_update_index_in_dim(
+                tails, rows[1:], e, 0)
+        return zc, tails
+
+    def _advance_by_kernel(self, w, states, tails, at, z, g, beta):
+        """The step's rows on the slots' entries (``at``, in the pools
+        seen flat) -> (o (S, H, d_v), the pools).  The conv in a loop
+        over the slots that carries the tails alone (a tail is 69 KB;
+        gathered and scattered, the compiler re-lays the whole 41 MB
+        pool out around every layer), then every slot's state advanced
+        where it lies by one call of ``pallas/gated_delta.py``."""
+        def one_slot(tails, slot):
+            zc, tails = self._conv_row(w, tails, *slot)
+            return tails, zc
+
+        tails, zc = jax.lax.scan(one_slot, tails, (at, z))
+        q, k, v = self._split(zc)
+        with jax.named_scope("lin_attn_state"):
+            wide = states.shape[-1]                  # the keys as stored
+            o, states = gated_delta_step(
+                states, at, _pad_last(q, wide), _pad_last(k, wide), v, g,
+                beta, interpret=pk.interpret_mode())
+        return o, states, tails
+
+    def _advance_slot_by_slot(self, w, states, tails, at, z, g, beta):
+        """The same in plain XLA, the kernel's reference and what runs
+        where it does not fit: one loop over the slots for both pools."""
+        wide = states.shape[-1]
 
         def one_slot(pools, slot):
             """One slot's entry read, advanced by its row and written
-            back where it lies.  Slot by slot: a gather of the step's
-            entries is lowered as slices of the whole pool (a 2.9 MB
-            entry is no row to the compiler), and a slot at a time
-            each entry moves once each way, in place."""
+            back where it lies.  Slot by slot: XLA lowers a gather of
+            the step's entries as slices of the whole pool (a 2.9 MB
+            entry is no row to the compiler); a slot at a time each
+            entry moves in place, read twice and written once."""
             states, tails = pools
             e, z_s, g_s, beta_s = slot
-            with jax.named_scope("lin_attn_conv"):
-                rows = jnp.concatenate(
-                    [jax.lax.dynamic_index_in_dim(tails, e, 0, False),
-                     z_s[None]])
-                zc = jax.nn.silu(jnp.sum(rows.astype(_F32) * w, axis=0))
-                tails = jax.lax.dynamic_update_index_in_dim(
-                    tails, rows[1:], e, 0)
+            zc, tails = self._conv_row(w, tails, e, z_s)
             q, k, v = self._split(zc)
             with jax.named_scope("lin_attn_state"):
                 o, new = step_gated_delta(
@@ -410,16 +461,9 @@ class OlmoHybridBlock(PageRunCache):
                     states, new, e, 0)
             return (states, tails), o
 
-        with jax.named_scope("lin_attn"):
-            (states, tails), o = jax.lax.scan(
-                one_slot,
-                (state_pool.reshape((Ll * E,) + state_pool.shape[2:]),
-                 conv_pool.reshape((Ll * E,) + conv_pool.shape[2:])),
-                (at, z, g, beta))
-            y = self._gated_norm(lp, o, gate)
-        return (self._lin_out(lp, x, y),
-                (k_pool, v_pool, states.reshape(state_pool.shape),
-                 tails.reshape(conv_pool.shape)))
+        (states, tails), o = jax.lax.scan(one_slot, (states, tails),
+                                          (at, z, g, beta))
+        return o, states, tails
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -4,13 +4,15 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Four kernel families, ten ``pl.pallas_call``s: the fused
+Five kernel families, eleven ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
-also run by ring attention's chunks and by the decoder's prefill) and
+also run by ring attention's chunks and by the decoder's prefill),
 ragged paged attention (``decode/attention.py``, 3 kernels under 4
 names: the chunk kernel is also called on grouped heads, Hq query heads
-on Hkv K/V heads, as ``ragged_paged_attention_gqa``).
+on Hkv K/V heads, as ``ragged_paged_attention_gqa``) and the gated
+delta rule's one-token step over a decode step's state entries
+(``gated_delta.py``, 1).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -18,11 +20,12 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   accepts the shape and its threshold below holds: the LSTM at
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
-  decode kernels (ragged paged attention, prefill flash attention)
-  have no threshold.  All three thresholds come from an earlier setup.
-  Flash attention at S=2048 and the decode kernels are what the LM and
-  generate cells run; the LSTM's and the softmax's thresholds are not
-  re-measured on this chip and no cell runs them.
+  decode kernels (ragged paged attention, prefill flash attention,
+  the gated delta step) have no threshold.  All three thresholds come
+  from an earlier setup.  Flash attention at S=2048 and the decode
+  kernels are what the LM and generate cells run; the LSTM's and the
+  softmax's thresholds are not re-measured on this chip and no cell
+  runs them.
 - ``on``: every kernel wherever ``fits()`` holds (tests force kernels
   at toy shapes with ``enable(True, interpret=True)``).
 - ``off``: the jnp/XLA lowerings only (the reference ``chip_smoke.py``
@@ -135,6 +138,17 @@ def use_flash_attention(bh: int, s_q: int, s_k: int, d: int) -> bool:
 
     return dispatch("flash_attention", policy(
         _f.fits(1, bh, s_q, d) and s_q == s_k, s_q >= FLASH_MIN_SEQ))
+
+
+def use_gated_delta_step(state_dtype, heads: int, d_v: int,
+                         wide: int) -> bool:
+    """A hybrid's decode step advances its slots' state entries by the
+    kernel wherever ``fits()`` holds (the decode kernels' rule: no
+    threshold), else slot by slot in XLA."""
+    from paddle_tpu.pallas import gated_delta as _g
+
+    return dispatch("gated_delta_step", policy(
+        _g.fits(state_dtype, heads, d_v, wide), True))
 
 
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402

@@ -690,10 +690,14 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     at 32 stored heads, 49 state entries, 48 slots): the four cache
     buffers are aliased input to output; the four full layers run the
     paged kernel under ``attn_full`` and write their rows by 8
-    scatters; every linear layer's loop over the slots reads and writes
-    an entry where it lies (dynamic-update-slices), and nothing else
-    has a pool's size but two layout copies of the 41 MB conv pool, at
-    the step's two ends; the plan is arguments + 70 MB."""
+    scatters; every linear layer advances the slots' states by ONE
+    ``gated_delta_step`` call under ``lin_attn/lin_attn_state``, the
+    pool its in-place operand, and its conv in a loop over the slots
+    that carries the tails alone; nothing else has a pool's size but
+    two layout copies of the 41 MB conv pool, at the step's two ends
+    (a layout copy of the 1.73 GB state pool before a custom call is
+    what the K/V pools met at 30 heads); the plan is arguments +
+    70 MB."""
     from paddle_tpu.decode import model as dm
 
     cfg, params, pool, extra, block, width, sds = _hybrid_cell(
@@ -719,19 +723,27 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     assert sorted(s[1:] for s in stray) == [
         ("copy", "conv"), ("copy", "conv"), ("copy-done", "conv")], stray
     assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
-    rpa = _kernel_op_names(text)
-    assert len(rpa) == 4 and all(
-        "_decode_step)/attn_full/" in op and "ragged_paged_attention/" in op
-        for op in rpa)
-    for scope in ("lin_attn_state", "lin_attn_conv"):
-        assert re.search(
-            rf"jit\(_decode_step\)/lin_attn/while/body/(\w+/)?{scope}/",
-            text), scope
+    kernels = _kernel_op_names(text)
+    rpa = [op for op in kernels if "ragged_paged_attention/" in op]
+    assert len(rpa) == 4 and all("_decode_step)/attn_full/" in op
+                                 for op in rpa)
+    step = [op for op in kernels if "gated_delta_step/" in op]
+    assert len(step) == 12 and len(kernels) == 16
+    assert all("_decode_step)/lin_attn/lin_attn_state/" in op for op in step)
+    # each writes the pool it was given (operand 6) as its output 1
+    assert sum("gated_delta_step/" in ln
+               and "output_to_operand_aliasing={{1}: (6, {})}" in ln
+               for ln in text.splitlines()) == 12
+    # the state pool is read and written by the kernel alone, no loop
+    assert not re.search(r"/while/body/(\w+/)?lin_attn_state/", text)
+    assert re.search(
+        r"jit\(_decode_step\)/lin_attn/while/body/(\w+/)?lin_attn_conv/",
+        text)
 
 
 # memory_analysis() for a described v5e: arguments + outputs +
 # temporaries - aliased, at the configuration's 447 pages
-HYBRID_PLANS = {"decode": 13_820_392_448, 4096: 14_851_249_664,
+HYBRID_PLANS = {"decode": 13_819_715_072, 4096: 14_851_249_664,
                 4608: 14_992_017_408}
 
 

@@ -3,6 +3,9 @@ Gated-DeltaNet layers whose recurrent state lives in a state entry a
 sequence beside the K/V pages of the full layers, in one cache manager.
 CPU, float32, toy widths with d_k != d_v and two periods of (linear x3,
 full); the plain reference is ``perf/reference/olmo_hybrid_block.py``.
+A decode step advances the states slot by slot in XLA here (off a TPU
+the kernels are not dispatched); the cases that take ``step_path`` run
+once more through ``pallas/gated_delta.py`` interpreted.
 """
 
 import numpy as np
@@ -11,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import pallas as pk
 from paddle_tpu.decode import model as dm
 from paddle_tpu.decode.paged_kv import CacheManager, PoolExhausted, PoolsLost
 from paddle_tpu.decode.session import (AdmissionRefused, BeamRequest,
@@ -28,6 +32,8 @@ SIZES = dict(vocab=96, d_model=32, num_heads=4, head_dim=8,
              linear_key_head_dim=6, linear_value_head_dim=10,
              max_len=256, num_pages=40, page_size=8, pages_per_seq=32,
              state_entries=5, dtype="float32")
+# d_v a whole tile of 8 rows: what the step kernel's fits() asks
+KERNEL_SIZES = {**SIZES, "linear_value_head_dim": 16}
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -39,6 +45,20 @@ def _highest():
 @pytest.fixture(scope="module")
 def model():
     return OlmoHybridLM(seed=3, **SIZES)
+
+
+@pytest.fixture(params=["loop", "kernel"])
+def step_path(request):
+    """How a decode step advances the states -> (the sizes of a model
+    that takes that path, the path ``pallas_dispatch_total`` counts)."""
+    if request.param == "loop":
+        yield SIZES, "reference"
+        return
+    pk.enable(True, interpret=True)
+    try:
+        yield KERNEL_SIZES, "interpret"
+    finally:
+        pk.enable("auto", interpret=False)
 
 
 def _prompt(n, seed=0):
@@ -165,9 +185,12 @@ def decoded(model):
 
 
 def test_prefill_then_decode_through_both_caches_match_the_reference(
-        model, decoded):
-    ids, rows, got = decoded
-    want = _reference(model, ids, rows)
+        step_path):
+    model = OlmoHybridLM(seed=3, **step_path[0])
+    prompt, tokens = _prompt(70, 1), _prompt(6, 2)
+    got = _through_the_cache(model, prompt, tokens)
+    want = _reference(model, prompt + tokens,
+                      list(range(len(prompt) - 1, len(prompt) + len(tokens))))
     assert ref.rel_rms(got, want) < 1e-5
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -236,19 +259,21 @@ def test_a_conv_tail_of_a_short_prompt_is_zeros_before_row_0(model):
     assert not tail[:, 0].any() and tail[:, 1:].any()
 
 
-def test_a_reused_entry_equals_a_fresh_one():
+def test_a_reused_entry_equals_a_fresh_one(step_path):
     """The LIFO free list hands the second sequence the first's entry
     and pages; the prefill writes the entry whole, so its logits are
     those of a fresh model."""
+    sizes = step_path[0]
     first, second, tokens = _prompt(90, 20), _prompt(9, 21), _prompt(4, 22)
-    used = OlmoHybridLM(seed=3, **SIZES)
+    used = OlmoHybridLM(seed=3, **sizes)
     _through_the_cache(used, first, tokens)
     again = _through_the_cache(used, second, tokens)
-    fresh = _through_the_cache(OlmoHybridLM(seed=3, **SIZES), second, tokens)
+    fresh = _through_the_cache(OlmoHybridLM(seed=3, **sizes), second, tokens)
     np.testing.assert_array_equal(again, fresh)
 
 
-def test_inactive_slots_touch_only_entry_0(model):
+def test_inactive_slots_touch_only_entry_0(step_path):
+    model = OlmoHybridLM(seed=3, **step_path[0])
     prompt = _prompt(12, 30)
     ids = model.allocator.alloc(model.context_pages(prompt, 2))
     entry = model.allocator.entry_of(ids)
@@ -266,6 +291,28 @@ def test_inactive_slots_touch_only_entry_0(model):
     changed = {e for e in range(after.shape[1])
                if not np.array_equal(before[:, e], after[:, e])}
     assert changed == {0, entry}
+
+
+def test_a_traced_step_counts_one_dispatch_a_linear_layer(step_path):
+    """``pallas_dispatch_total{kernel="gated_delta_step"}``: which way
+    the states are advanced is decided once a linear layer while a
+    step's program is traced (a slot count no other case traces)."""
+    sizes, path = step_path
+    model = OlmoHybridLM(seed=3, **sizes)
+    cache, S = model._cache(), 3
+
+    def counts():
+        return {p: pk._M_DISPATCH.value(kernel="gated_delta_step", path=p)
+                for p in ("compiled", "interpret", "reference")}
+
+    before = counts()
+    dm._decode_step.lower(
+        model.params, *cache[:2], np.zeros((S, model.pages_per_seq), np.int32),
+        np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+        heads=model.heads, page_size=model.page_size, block=model.block,
+        extra=cache[2:])
+    moved = {p: n - before[p] for p, n in counts().items() if n != before[p]}
+    assert moved == {path: TYPES.count(LINEAR)}
 
 
 def test_pages_are_stored_at_whole_tiles_of_heads(model):

@@ -269,6 +269,72 @@ def test_ring_attention_flash_chunks_match_jnp(rng):
 
 
 
+# (entries, H, d_v, d_k, the entry of each slot, what the rows are)
+_DELTA_CASES = {
+    "toy": (7, 4, 16, 6, (3, 5, 6), "random"),
+    "published-block": (3, 2, 192, 96, (2, 1), "random"),
+    "out-of-order": (9, 3, 8, 5, (8, 2, 7, 1, 4), "random"),
+    "two-slots-on-entry-0": (6, 2, 16, 6, (0, 4, 0, 2), "random"),
+    "g0-beta0": (5, 2, 16, 6, (4, 1), "padding"),
+}
+
+
+@pytest.mark.parametrize("case", _DELTA_CASES)
+def test_gated_delta_step_is_the_step_on_the_gathered_entries(rng, case):
+    """``gated_delta_step`` against ``step_gated_delta`` on the entries
+    the slots address (keys stored at 128 lanes, zero beyond ``d_k``;
+    beta in (0, 2)): ``o`` and the new entries within 1e-5, every entry
+    no slot addresses bit-identical.  Slots on the null entry 0 write
+    it in no order: it stays finite and the other slots' are right.
+    A row with ``g = 0, beta = 0`` leaves its entry as it was, to the
+    bit."""
+    from paddle_tpu.models.olmo_hybrid import step_gated_delta
+    from paddle_tpu.pallas import gated_delta as gd
+
+    N, H, dv, dk, at, rows = _DELTA_CASES[case]
+    S, wide = len(at), 128
+    at = np.asarray(at, np.int32)
+
+    def keys(*shape):
+        x = np.zeros(shape + (wide,), np.float32)
+        x[..., :dk] = rng.randn(*shape, dk)
+        return x
+
+    pool = keys(N, H, dv)
+    q, k = keys(S, H), keys(S, H)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * dk ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.randn(S, H, dv).astype(np.float32)
+    g = -rng.uniform(0.001, 0.3, (S, H)).astype(np.float32)
+    beta = rng.uniform(0.0, 2.0, (S, H)).astype(np.float32)
+    if rows == "padding":
+        g, beta = np.zeros_like(g), np.zeros_like(beta)
+    assert gd.fits(pool.dtype, H, dv, wide)
+    o, new = gd.gated_delta_step(jnp.asarray(pool), at, q, k, v, g, beta,
+                                 interpret=True)
+    want_o, want_new = step_gated_delta(q, k, v, g, beta, pool[at])
+    o, new = np.asarray(o), np.asarray(new)
+    live = at != 0
+    np.testing.assert_allclose(o[live], np.asarray(want_o)[live], atol=1e-5)
+    np.testing.assert_allclose(new[at[live]], np.asarray(want_new)[live],
+                               atol=1e-5)
+    untouched = np.setdiff1d(np.arange(N), at)
+    np.testing.assert_array_equal(new[untouched], pool[untouched])
+    assert np.isfinite(new[0]).all() and not new[..., dk:].any()
+    if rows == "padding":
+        np.testing.assert_array_equal(new, pool)
+
+
+def test_gated_delta_step_fits_whole_tiles_of_float32():
+    from paddle_tpu.pallas import gated_delta as gd
+
+    assert gd.fits(jnp.float32, 30, 192, 128)
+    assert gd.head_block(30, 192, 128) == 15
+    assert gd.head_block(4, 16, 128) == 4
+    assert not gd.fits(jnp.bfloat16, 30, 192, 128)       # a bf16 state
+    assert not gd.fits(jnp.float32, 30, 192, 96)         # keys not padded
+    assert not gd.fits(jnp.float32, 30, 12, 128)         # d_v off the tile
+
 
 # ---------------------------------------------------------------------------
 # which path runs: the rules of pallas/__init__.py and the sites that
@@ -299,6 +365,8 @@ def _decide(kernel, shape):
         return ra._use_flash_chunks(*shape)
     if kernel.startswith("ragged_paged_attention"):
         return da._use_kernel(kernel, *shape)
+    if kernel == "gated_delta_step":
+        return pk.use_gated_delta_step(*shape)
     assert kernel == "prefill_flash_attention"
     x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
     # a new function each time: eval_shape caches a function's trace
@@ -352,6 +420,16 @@ _POLICY_CASES = (
        ("prefill_flash_attention", (64, 2, 8), "auto", True, False,
         "reference"),
        ("prefill_flash_attention", (128, 2, 8), "off", True, False,
+        "reference"),
+       ("gated_delta_step", ("float32", 30, 192, 128), "auto", True, False,
+        "compiled"),
+       ("gated_delta_step", ("float32", 4, 16, 128), "auto", False, True,
+        "interpret"),
+       ("gated_delta_step", ("float32", 30, 192, 128), "auto", False, False,
+        "reference"),
+       ("gated_delta_step", ("float32", 4, 10, 128), "on", True, False,
+        "reference"),
+       ("gated_delta_step", ("float32", 30, 192, 128), "off", True, False,
         "reference")])
 
 
