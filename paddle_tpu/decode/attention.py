@@ -51,7 +51,16 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     multiple of the 16 rows a bfloat16 tile packs) and copies the whole
     pool to the kernel's row-major layout before every layer's call.
     A model whose head count is not a multiple of the tile's rows
-    stores its pages at ``storage_heads`` and pads q, k and v to it."""
+    stores its pages at ``storage_heads`` and pads q, k and v to it.
+
+    Nor at how wide a head is.  Pages of 8 K/V heads of 64 (PR 41's
+    probe, ``tests/test_chip_compile.py``): a row of 64 lanes is padded
+    to the tile's 128 (a pool twice its bytes) and the pools are copied
+    to that layout around the kernel's calls (3.6 GB of temporaries
+    beside 1 GB of K/V).  A model with heads of 64 stores two of them
+    side by side in a row of 128 lanes and runs the grouped kernel on
+    that (``models/granite_hybrid.py:heads_a_row``): read and written
+    in place, nothing padded."""
     ok = (page_size % 8 == 0 and head_dim % 8 == 0
           and head_dim <= 256 and num_heads >= 1)
     if kv_heads in (None, num_heads):

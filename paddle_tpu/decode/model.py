@@ -13,9 +13,10 @@ block as a static argument.  A block also says how a layer mixes
 tokens and what it keeps of them (``PageRunCache``: attention, every
 layer every row, in one page run a sequence; K-EXAONE's window layers
 keep a bounded ring each, beside a full layer, in the same pool;
-Olmo-Hybrid's linear-attention layers keep a recurrent state a
-sequence in buffers of their own, ``extra``, beside the pages of its
-full layers: ``paddle_tpu/models/olmo_hybrid.py``).
+a hybrid's recurrent layers keep a state a sequence in buffers of
+their own, ``extra``, beside the pages of its attention layers:
+``decode/state_entry.py``, under ``models/olmo_hybrid.py`` and
+``models/granite_hybrid.py``).
 
 Prefill is ONE jitted program per length *bucket* (the shared pow2
 ladder of ``pallas/tuning/bucket.py``, from 64 up to the sequence
@@ -151,8 +152,8 @@ class PageRunCache:
     ``attn_out``.  A block whose layers differ in what they keep
     defines the three itself (``models/exaone_moe.py``: window layers
     on rings beside a full layer); one whose layers mix tokens another
-    way defines the mixers (``models/olmo_hybrid.py``: a recurrent
-    state a sequence beside the pages of its full layers)."""
+    way defines the mixers (``decode/state_entry.py``: a recurrent
+    state a sequence beside the pages of the attention layers)."""
 
     def prompt_mixer(self, lp, x, pos, heads, live):
         """Layer ``self.at`` over one whole prompt ``x`` (T, d) ->

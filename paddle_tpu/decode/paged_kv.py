@@ -161,7 +161,7 @@ class PageAllocator:
 class CacheManager(PageAllocator):
     """Pages and, beside them, state entries: the second resource of a
     model whose recurrent layers keep a state of fixed size a sequence
-    (``models/olmo_hybrid.py``), from the one object the session asks.
+    (``decode/state_entry.py``), from the one object the session asks.
 
     A sequence's reservation of ``n`` units is ``n - 1`` pages and ONE
     state entry (the model's ``context_pages`` counts the entry in, as

@@ -1,0 +1,266 @@
+"""What a hybrid needs of the paged skeleton (``decode/model.py``): a
+model whose *recurrent* layers keep one state a sequence, whatever its
+length, beside the K/V pages of its attention layers.  Two models stand
+on it: ``models/olmo_hybrid.py`` (the gated delta rule three layers of
+four) and ``models/granite_hybrid.py`` (Mamba-2 nine layers of ten).
+
+Two resources a sequence, from the one cache manager
+(``decode/paged_kv.py:CacheManager``): its page run, which the
+attention layers alone write (pools ``(attention layers, N, pg, ...)``),
+and ONE state entry: every recurrent layer's state and the last rows
+that layer's conv saw (pools ``(recurrent layers, entries, ...)``, entry
+0 the null entry that inactive slots address).  Its table row is the
+page run's columns, then the entry.  The cache is ``(k_pool, v_pool,
+state_pool, conv_pool)``, all four donated to every program.
+
+What needs a state as it stood at an earlier row is refused by name
+(``UnsupportedOverState``): a prefill over cached pages, a fork, the
+speculative verify.
+
+``StateEntryCache`` is the block's side (which layer is which, its slab
+of its kind's pools, a slot's entry, the prompt's states and tails
+written whole over the entry); ``StateEntryLM`` the model's (the
+reservation, the table row, the gauges' rows and bytes, the refusals).
+A model says how its own layers mix tokens and how a page holds its
+heads.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.decode.model import (
+    PagedDecoderLM,
+    PageRunCache,
+    _dense_blocks,
+)
+from paddle_tpu.decode.paged_kv import CacheManager
+
+_F32 = jnp.float32
+
+
+class UnsupportedOverState(RuntimeError):
+    """Asked of a model with recurrent layers what needs their state as
+    it stood at a row that is not the sequence's last: a prefill over a
+    shared prefix, a fork, the speculative verify's rollback.  Only the
+    newest state is kept (ROADMAP R7)."""
+
+
+def _pad_axis(x, axis, width):
+    """``x`` with zeros appended along ``axis`` up to ``width``."""
+    pads = [(0, 0)] * x.ndim
+    pads[axis] = (0, width - x.shape[axis])
+    return jnp.pad(x, pads) if pads[axis][1] else x
+
+
+def _pad_last(x, width):
+    return _pad_axis(x, -1, width)
+
+
+def _rows_before(z, taps):
+    """``z`` (T, C) with the ``taps - 1`` zero rows before row 0."""
+    return jnp.concatenate([jnp.zeros((taps - 1, z.shape[1]), z.dtype), z])
+
+
+def causal_conv(z, w):
+    """Depthwise causal conv of ``z`` (T, C) with taps ``w`` (K, C):
+    row t is ``sum_j w[j] z[t - (K - 1) + j]``, zeros before row 0."""
+    K, T = w.shape[0], z.shape[0]
+    zp = _rows_before(z, K)
+    return sum(zp[j:j + T].astype(_F32) * w[j].astype(_F32)
+               for j in range(K))
+
+
+def conv_tail(z, taps, n):
+    """Rows ``n - (taps - 1) .. n - 1`` of ``z`` (T, C), zeros before
+    row 0: what a conv of ``taps`` taps has to keep of a prompt of ``n``
+    real rows (the bucket's padding after them is left out)."""
+    return jax.lax.dynamic_slice_in_dim(_rows_before(z, taps), n, taps - 1)
+
+
+class StateEntryCache(PageRunCache):
+    """The cache side of a hybrid's block, a frozen dataclass with the
+    fields ``layer_types``, ``full_pages`` (the table columns of the page
+    run) and ``at`` (the layer this view of the block is) and the class
+    attribute ``recurrent_kind`` (the ``layer_types`` entry of a layer
+    that keeps a state; every other layer is attention over the page
+    run)."""
+
+    recurrent_kind = ""
+
+    def layer(self, li):
+        return dataclasses.replace(self, at=li)
+
+    @property
+    def recurrent(self) -> bool:
+        return self.layer_types[self.at] == self.recurrent_kind
+
+    @property
+    def index_in_kind(self) -> int:
+        """This layer's index among the layers of its own kind: its
+        slab of that kind's pools."""
+        kind = self.layer_types[self.at]
+        return sum(t == kind for t in self.layer_types[:self.at])
+
+    def entries_of(self, state_pool, addr):
+        """Each slot's entry in this layer's slab of the state and conv
+        pools seen flat (a bitcast); an inactive slot's is the null
+        entry 0."""
+        E = state_pool.shape[1]
+        return self.index_in_kind * E + addr.tables[:, self.full_pages]
+
+    def prompt_attention(self, q, k, v):
+        with jax.named_scope("attn_full"):
+            return super().prompt_attention(q, k, v)
+
+    def store_prompts(self, cache, kept, where):
+        """``where``: (the page run's flat rows (T,), the state entry).
+        The attention layers' K/V rows as every paged model's; each
+        recurrent layer's final state and conv tail written whole over
+        the entry, so that a reused entry needs no reset."""
+        flat, entry = where
+        k_pool, v_pool, state_pool, conv_pool = cache
+        rec = [t == self.recurrent_kind for t in self.layer_types]
+        full = [kv for kv, r in zip(kept, rec) if not r]
+        lin = [sc for sc, r in zip(kept, rec) if r]
+        k_pool, v_pool = super().store_prompts((k_pool, v_pool), full, flat)
+        states = _pad_last(jnp.stack([s for s, _ in lin]),
+                           state_pool.shape[-1]).astype(state_pool.dtype)
+        tails = jnp.stack([c for _, c in lin]).astype(conv_pool.dtype)
+        return (k_pool, v_pool, state_pool.at[:, entry].set(states),
+                conv_pool.at[:, entry].set(tails))
+
+    def mixer(self, lp, x, pos, cache, li, addr, heads, lone=False):
+        if lone or x.ndim != 2:
+            raise UnsupportedOverState(
+                "a chunk of rows a sequence (a suffix prefill, the "
+                "speculative verify) would need the state between them")
+        if not self.recurrent:
+            x, kv = super().mixer(lp, x, pos, cache[:2], li, addr, heads)
+            return x, kv + tuple(cache[2:])
+        return self.recurrent_step(lp, x, cache, addr)
+
+    def recurrent_step(self, lp, x, cache, addr):
+        """A recurrent layer over a decode step's rows ``x`` (S, d), one
+        token on each slot's entry -> (the rows after the mixer's
+        residual, the four pools written in place)."""
+        raise NotImplementedError
+
+
+class StateEntryLM(PagedDecoderLM):
+    """The model's side: a subclass sets ``block`` and ``params``, calls
+    ``_count_layers`` and then ``_make_pools``.
+
+    The constructor's ``pages_per_seq`` is the page run's pages (kept as
+    ``full_pages``); the attribute, which the session sizes its table
+    rows by, counts the state entry's column too, and
+    ``context_pages`` counts the entry as one unit of the reservation
+    (``CacheManager``)."""
+
+    supports_prefix_cache = False     # no state is kept at a prefix's end
+    supports_fork = False             # nor copied for a beam's siblings
+    supports_verify = False           # nor rolled back past rejected rows
+
+    def _count_layers(self, layer_types, recurrent_kind):
+        recurrent = sum(t == recurrent_kind for t in layer_types)
+        if not 0 < recurrent < len(layer_types):
+            raise ValueError("a hybrid holds layers of both kinds")
+        self.full_pages = self.pages_per_seq
+        self.pages_per_seq = self.full_pages + 1
+        self.linear_layers = recurrent
+        self.full_layers = self.layers - recurrent
+
+    def _make_pools(self, num_pages, dtype, state_entries, page_heads,
+                    state_shape, tail_shape):
+        """``page_heads``: (the heads a page's row is stored as, their
+        width); ``state_shape``: a layer's state as stored, float32;
+        ``tail_shape``: what a layer's conv keeps, in ``dtype``."""
+        self.allocator = CacheManager(num_pages, state_entries)
+        shape = (self.full_layers, num_pages, self.page_size, *page_heads)
+        self.k_pool = jnp.zeros(shape, dtype)
+        self.v_pool = jnp.zeros(shape, dtype)
+        self.extra_pools = (
+            jnp.zeros((self.linear_layers, state_entries, *state_shape),
+                      _F32),
+            jnp.zeros((self.linear_layers, state_entries, *tail_shape),
+                      dtype))
+
+    @property
+    def state_pool(self):
+        return self.extra_pools[0]
+
+    @property
+    def conv_pool(self):
+        return self.extra_pools[1]
+
+    def _forward(self, tokens):
+        """-> (logits (T, V), what each layer keeps of the prompt: an
+        attention layer's K/V rows, a recurrent layer's final state and
+        conv tail, None)."""
+        x, kept, _ = _dense_blocks(self.block, self.params, tokens,
+                                   self.heads, None)
+        return self.block.head(self.params, x), kept, None
+
+    # -- the reservation: the page run, then the entry -----------------------
+
+    def context_pages(self, prompt, max_new_tokens: int) -> int:
+        return super().context_pages(prompt, max_new_tokens) + 1
+
+    def pool_table(self, pages) -> np.ndarray:
+        run = self.allocator.pages_of(pages)
+        t = np.zeros((self.pages_per_seq,), np.int32)
+        t[:len(run)] = run
+        t[self.full_pages] = self.allocator.entry_of(pages)
+        return t
+
+    def _prompt_rows(self, pages, bucket: int, n: int):
+        """(the page run's flat row of each bucket row, as every paged
+        model has them; the sequence's state entry)."""
+        table = self.pool_table(pages)
+        rows = np.arange(bucket)
+        flat = (table[rows // self.page_size] * self.page_size
+                + rows % self.page_size).astype(np.int32)
+        return flat, np.int32(table[self.full_pages])
+
+    def entry_bytes(self) -> int:
+        """Bytes of one state entry, all recurrent layers: the states
+        and the conv tails."""
+        return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize * p.shape[0]
+                   for p in self.extra_pools)
+
+    def cache_rows(self, lens) -> dict:
+        """What is resident for sequences of ``lens`` rows, by kind of
+        cache, summed over the layers of the kind: an attention layer
+        holds every row; a recurrent layer one state a sequence,
+        whatever its length."""
+        return {"full": int(np.sum(lens)) * self.full_layers,
+                "state": len(lens) * self.linear_layers}
+
+    def cache_bytes(self, lens) -> dict:
+        row = 2 * self.stored_heads * self.dh * self.k_pool.dtype.itemsize
+        return {"full": int(np.sum(lens)) * self.full_layers * row,
+                "state": len(lens) * self.entry_bytes()}
+
+    # -- refused by name -----------------------------------------------------
+
+    def prefill(self, prompt, pages, cached_len: int = 0):
+        if cached_len:
+            raise UnsupportedOverState(
+                "a prefill over cached pages needs the recurrent layers' "
+                "state as it stood at the cached length; it is not kept")
+        return super().prefill(prompt, pages)
+
+    def copy_page(self, src: int, dst: int) -> None:
+        raise UnsupportedOverState(
+            "a copy-on-write split follows a fork, which would have to "
+            "copy the sequence's state entry; this model refuses it")
+
+    def verify_chunk(self, tokens, states, tables, lens):
+        raise UnsupportedOverState(
+            "a speculative verify writes k rows into the state and may "
+            "reject some: the state before them is not kept")

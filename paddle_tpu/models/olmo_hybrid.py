@@ -41,7 +41,10 @@ and the last three rows that layer's conv saw (pools ``(linear layers,
 entries, ...)``, entry 0 the null entry).  Its table row is the page
 run's columns, then the entry.  What needs a state as it stood at an
 earlier row is refused by name (``UnsupportedOverState``): a prefill
-over cached pages, a fork, the speculative verify.
+over cached pages, a fork, the speculative verify.  All of that is
+``decode/state_entry.py``'s, shared with ``models/granite_hybrid.py``;
+this file says how its own layers mix tokens and how a page and an
+entry hold them.
 
 Matmul operands in the weights' dtype (bfloat16 as served), float32
 accumulation, residual stream, norms, gates, decay and state; the rows
@@ -63,12 +66,15 @@ import jax.numpy as jnp
 
 from paddle_tpu import pallas as pk
 from paddle_tpu.decode.attention import storage_heads
-from paddle_tpu.decode.model import (
-    PagedDecoderLM,
-    PageRunCache,
-    _dense_blocks,
+from paddle_tpu.decode.state_entry import (  # noqa: F401  (re-exported)
+    StateEntryCache,
+    StateEntryLM,
+    UnsupportedOverState,
+    _pad_axis,
+    _pad_last,
+    causal_conv,
+    conv_tail,
 )
-from paddle_tpu.decode.paged_kv import CacheManager
 from paddle_tpu.models.exaone_moe import swiglu
 from paddle_tpu.models.olmoe import _mm, rms_norm
 from paddle_tpu.pallas.gated_delta import gated_delta_step
@@ -80,30 +86,12 @@ CHUNK = 64
 L2_EPS = 1e-6
 
 
-class UnsupportedOverState(RuntimeError):
-    """Asked of a model with recurrent layers what needs their state as
-    it stood at a row that is not the sequence's last: a prefill over a
-    shared prefix, a fork, the speculative verify's rollback.  Only the
-    newest state is kept (ROADMAP R7)."""
-
-
 def l2_normalize(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
                              + L2_EPS)
 
 
 LANES = 128
-
-
-def _pad_axis(x, axis, width):
-    """``x`` with zeros appended along ``axis`` up to ``width``."""
-    pads = [(0, 0)] * x.ndim
-    pads[axis] = (0, width - x.shape[axis])
-    return jnp.pad(x, pads) if pads[axis][1] else x
-
-
-def _pad_last(x, width):
-    return _pad_axis(x, -1, width)
 
 
 def stored_key_width(d_k: int) -> int:
@@ -123,20 +111,6 @@ def stored_key_width(d_k: int) -> int:
 def _pad_heads(x, heads):
     """``x`` (..., H, dh) with zero heads appended up to ``heads``."""
     return _pad_axis(x, -2, heads)
-
-
-def _rows_before(z, taps):
-    """``z`` (T, C) with the ``taps - 1`` zero rows before row 0."""
-    return jnp.concatenate([jnp.zeros((taps - 1, z.shape[1]), z.dtype), z])
-
-
-def causal_conv(z, w):
-    """Depthwise causal conv of ``z`` (T, C) with taps ``w`` (K, C):
-    row t is ``sum_j w[j] z[t - (K - 1) + j]``, zeros before row 0."""
-    K, T = w.shape[0], z.shape[0]
-    zp = _rows_before(z, K)
-    return sum(zp[j:j + T].astype(_F32) * w[j].astype(_F32)
-               for j in range(K))
 
 
 def chunked_gated_delta(q, k, v, g, beta, state, chunk=CHUNK):
@@ -218,15 +192,16 @@ def step_gated_delta(q, k, v, g, beta, state):
 
 
 @dataclasses.dataclass(frozen=True)
-class OlmoHybridBlock(PageRunCache):
+class OlmoHybridBlock(StateEntryCache):
     """See ``decode/model.py:Gpt2Block`` for the block's contract and
-    ``PageRunCache`` for the mixers: a full layer's is that class's
-    over the page run's columns of the table and the pools of the full
-    layers alone; a linear layer's is the gated delta rule over the
-    sequence's state entry.  The cache is ``(k_pool, v_pool,
-    state_pool, conv_pool)``.  ``at``: the layer this view of the block
-    is (``layer``)."""
+    ``decode/state_entry.py:StateEntryCache`` for the cache side: a
+    full layer's mixer is ``PageRunCache``'s over the page run's
+    columns of the table and the pools of the full layers alone; a
+    linear layer's is the gated delta rule over the sequence's state
+    entry.  The cache is ``(k_pool, v_pool, state_pool, conv_pool)``.
+    ``at``: the layer this view of the block is (``layer``)."""
 
+    recurrent_kind = LINEAR
     layer_types: tuple = (LINEAR, LINEAR, LINEAR, FULL)
     head_dim: int = 128
     lin_heads: int = 30
@@ -235,20 +210,6 @@ class OlmoHybridBlock(PageRunCache):
     eps: float = 1e-6
     full_pages: int = 36         # table columns of the page run
     at: int = 0
-
-    def layer(self, li):
-        return dataclasses.replace(self, at=li)
-
-    @property
-    def linear(self) -> bool:
-        return self.layer_types[self.at] == LINEAR
-
-    @property
-    def index_in_kind(self) -> int:
-        """This layer's index among the layers of its own kind: its
-        slab of that kind's pools."""
-        kind = self.layer_types[self.at]
-        return sum(t == kind for t in self.layer_types[:self.at])
 
     # -- the block ----------------------------------------------------------
 
@@ -311,10 +272,6 @@ class OlmoHybridBlock(PageRunCache):
 
     # -- the cache side of a full layer ---------------------------------------
 
-    def prompt_attention(self, q, k, v):
-        with jax.named_scope("attn_full"):
-            return super().prompt_attention(q, k, v)
-
     def store_prompt(self, pool, rows, flat):
         return super().store_prompt(pool, _pad_heads(rows, pool.shape[3]),
                                     flat)
@@ -336,7 +293,7 @@ class OlmoHybridBlock(PageRunCache):
     # -- the mixers ---------------------------------------------------------
 
     def prompt_mixer(self, lp, x, pos, heads, live):
-        if not self.linear:
+        if not self.recurrent:
             return super().prompt_mixer(lp, x, pos, heads, live)
         T = x.shape[0]
         z, gate, g, beta = self._projections(lp, x)
@@ -349,10 +306,7 @@ class OlmoHybridBlock(PageRunCache):
         with jax.named_scope("lin_attn"):
             with jax.named_scope("lin_attn_conv"):
                 zc = jax.nn.silu(causal_conv(z, lp["w_conv"]))
-                K = lp["w_conv"].shape[0]
-                # rows n-(K-1) .. n-1 of z, zeros before row 0
-                tail = jax.lax.dynamic_slice_in_dim(_rows_before(z, K), n,
-                                                    K - 1)
+                tail = conv_tail(z, lp["w_conv"].shape[0], n)
             q, k, v = self._split(zc)
             with jax.named_scope("lin_attn_scan"):
                 o, state = chunked_gated_delta(
@@ -361,36 +315,11 @@ class OlmoHybridBlock(PageRunCache):
             y = self._gated_norm(lp, o, gate)
         return self._lin_out(lp, x, y), (state, tail)
 
-    def store_prompts(self, cache, kept, where):
-        """``where``: (the page run's flat rows (T,), the state entry).
-        The full layers' K/V rows as every paged model's; each linear
-        layer's final state and conv tail written whole over the entry,
-        so that a reused entry needs no reset."""
-        flat, entry = where
-        k_pool, v_pool, state_pool, conv_pool = cache
-        full = [kv for kv, t in zip(kept, self.layer_types) if t == FULL]
-        lin = [sc for sc, t in zip(kept, self.layer_types) if t == LINEAR]
-        k_pool, v_pool = super().store_prompts((k_pool, v_pool), full, flat)
-        states = _pad_last(jnp.stack([s for s, _ in lin]),
-                           state_pool.shape[-1]).astype(state_pool.dtype)
-        tails = jnp.stack([c for _, c in lin]).astype(conv_pool.dtype)
-        return (k_pool, v_pool, state_pool.at[:, entry].set(states),
-                conv_pool.at[:, entry].set(tails))
-
-    def mixer(self, lp, x, pos, cache, li, addr, heads, lone=False):
-        if lone or x.ndim != 2:
-            raise UnsupportedOverState(
-                "a chunk of rows a sequence (a suffix prefill, the "
-                "speculative verify) would need the state between them")
-        if not self.linear:
-            x, kv = super().mixer(lp, x, pos, cache[:2], li, addr, heads)
-            return x, kv + tuple(cache[2:])
+    def recurrent_step(self, lp, x, cache, addr):
         k_pool, v_pool, state_pool, conv_pool = cache
         z, gate, g, beta = self._projections(lp, x)
         Ll, E = state_pool.shape[:2]
-        # the slot's entry in this layer's slab of the pools seen flat
-        # (a bitcast); an inactive slot's is the null entry 0
-        at = self.index_in_kind * E + addr.tables[:, self.full_pages]
+        at = self.entries_of(state_pool, addr)
         w = lp["w_conv"].astype(_F32)
         advance = (self._advance_by_kernel if pk.use_gated_delta_step(
             state_pool.dtype, *state_pool.shape[2:])
@@ -512,20 +441,11 @@ def init_params(key, *, vocab, d, heads, head_dim, layer_types, width,
     return params
 
 
-class OlmoHybridLM(PagedDecoderLM):
+class OlmoHybridLM(StateEntryLM):
     """The layers one pipeline stage holds of Olmo-Hybrid over the paged
     skeleton: what ``make_decode_model()`` returns
-    (``perf/configs/olmo-hybrid-7b.gen_config.py``).
-
-    The constructor's ``pages_per_seq`` is the page run's pages (kept as
-    ``full_pages``); the attribute, which the session sizes its table
-    rows by, counts the state entry's column too, and
-    ``context_pages`` counts the entry as one unit of the reservation
-    (``CacheManager``)."""
-
-    supports_prefix_cache = False     # no state is kept at a prefix's end
-    supports_fork = False             # nor copied for a beam's siblings
-    supports_verify = False           # nor rolled back past rejected rows
+    (``perf/configs/olmo-hybrid-7b.gen_config.py``).  The reservation,
+    the table row and the refusals are ``decode/state_entry.py``'s."""
 
     def __init__(self, vocab: int = 100352, d_model: int = 3840,
                  num_heads: int = 30, head_dim: int = 128,
@@ -547,13 +467,8 @@ class OlmoHybridLM(PagedDecoderLM):
         if linear_num_key_heads != linear_num_value_heads:
             raise ValueError("more value heads than key heads is not "
                              "laid out: one state a head")
-        if not (LINEAR in layer_types and FULL in layer_types):
-            raise ValueError("a hybrid holds layers of both kinds")
         self.dh = int(head_dim)
-        self.full_pages = self.pages_per_seq
-        self.pages_per_seq = self.full_pages + 1
-        self.full_layers = sum(t == FULL for t in layer_types)
-        self.linear_layers = self.layers - self.full_layers
+        self._count_layers(layer_types, LINEAR)
         self.block = OlmoHybridBlock(
             layer_types=layer_types, head_dim=self.dh,
             lin_heads=int(linear_num_key_heads),
@@ -567,95 +482,11 @@ class OlmoHybridLM(PagedDecoderLM):
             width=int(intermediate_size), lin_heads=self.block.lin_heads,
             d_k=self.block.d_k, d_v=self.block.d_v, conv=self.conv_taps,
             dtype=dtype)
-        self._make_pools(num_pages, dtype, int(state_entries))
-
-    def _make_pools(self, num_pages, dtype, state_entries):
         b = self.block
-        self.allocator = CacheManager(num_pages, state_entries)
         # a page's heads as stored: 30 of bfloat16 are stored as 32
         self.stored_heads = storage_heads(self.heads, dtype)
-        shape = (self.full_layers, num_pages, self.page_size,
-                 self.stored_heads, self.dh)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
-        self.extra_pools = (
-            jnp.zeros((self.linear_layers, state_entries, b.lin_heads,
-                       b.d_v, stored_key_width(b.d_k)), _F32),
-            jnp.zeros((self.linear_layers, state_entries,
-                       self.conv_taps - 1,
-                       b.lin_heads * (2 * b.d_k + b.d_v)), dtype))
-
-    @property
-    def state_pool(self):
-        return self.extra_pools[0]
-
-    @property
-    def conv_pool(self):
-        return self.extra_pools[1]
-
-    def _forward(self, tokens):
-        """-> (logits (T, V), what each layer keeps of the prompt: a
-        full layer's K/V rows, a linear layer's final state and conv
-        tail, None)."""
-        x, kept, _ = _dense_blocks(self.block, self.params, tokens,
-                                   self.heads, None)
-        return self.block.head(self.params, x), kept, None
-
-    # -- the reservation: the page run, then the entry -----------------------
-
-    def context_pages(self, prompt, max_new_tokens: int) -> int:
-        return super().context_pages(prompt, max_new_tokens) + 1
-
-    def pool_table(self, pages) -> np.ndarray:
-        run = self.allocator.pages_of(pages)
-        t = np.zeros((self.pages_per_seq,), np.int32)
-        t[:len(run)] = run
-        t[self.full_pages] = self.allocator.entry_of(pages)
-        return t
-
-    def _prompt_rows(self, pages, bucket: int, n: int):
-        """(the page run's flat row of each bucket row, as every paged
-        model has them; the sequence's state entry)."""
-        table = self.pool_table(pages)
-        rows = np.arange(bucket)
-        flat = (table[rows // self.page_size] * self.page_size
-                + rows % self.page_size).astype(np.int32)
-        return flat, np.int32(table[self.full_pages])
-
-    def entry_bytes(self) -> int:
-        """Bytes of one state entry, all linear layers: the states and
-        the conv tails."""
-        return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize * p.shape[0]
-                   for p in self.extra_pools)
-
-    def cache_rows(self, lens) -> dict:
-        """What is resident for sequences of ``lens`` rows, by kind of
-        cache, summed over the layers of the kind: a full layer holds
-        every row; a linear layer one state a sequence, whatever its
-        length."""
-        return {"full": int(np.sum(lens)) * self.full_layers,
-                "state": len(lens) * self.linear_layers}
-
-    def cache_bytes(self, lens) -> dict:
-        row = 2 * self.stored_heads * self.dh * self.k_pool.dtype.itemsize
-        return {"full": int(np.sum(lens)) * self.full_layers * row,
-                "state": len(lens) * self.entry_bytes()}
-
-    # -- refused by name -----------------------------------------------------
-
-    def prefill(self, prompt, pages, cached_len: int = 0):
-        if cached_len:
-            raise UnsupportedOverState(
-                "a prefill over cached pages needs the linear layers' "
-                "state as it stood at the cached length; it is not kept")
-        return super().prefill(prompt, pages)
-
-    def copy_page(self, src: int, dst: int) -> None:
-        raise UnsupportedOverState(
-            "a copy-on-write split follows a fork, which would have to "
-            "copy the sequence's state entry; this model refuses it")
-
-    def verify_chunk(self, tokens, states, tables, lens):
-        raise UnsupportedOverState(
-            "a speculative verify writes k rows into the state and may "
-            "reject some: the state before them is not kept")
+        self._make_pools(
+            num_pages, dtype, int(state_entries),
+            (self.stored_heads, self.dh),
+            (b.lin_heads, b.d_v, stored_key_width(b.d_k)),
+            (self.conv_taps - 1, b.lin_heads * (2 * b.d_k + b.d_v)))

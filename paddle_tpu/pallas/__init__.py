@@ -4,15 +4,16 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Five kernel families, eleven ``pl.pallas_call``s: the fused
+Six kernel families, twelve ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
 ragged paged attention (``decode/attention.py``, 3 kernels under 4
 names: the chunk kernel is also called on grouped heads, Hq query heads
-on Hkv K/V heads, as ``ragged_paged_attention_gqa``) and the gated
+on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
-(``gated_delta.py``, 1).
+(``gated_delta.py``, 1) and Mamba-2's on the same layout
+(``ssd_step.py``, 1).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -21,11 +22,11 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
-  the gated delta step) have no threshold.  All three thresholds come
-  from an earlier setup.  Flash attention at S=2048 and the decode
-  kernels are what the LM and generate cells run; the LSTM's and the
-  softmax's thresholds are not re-measured on this chip and no cell
-  runs them.
+  the gated delta and SSD steps) have no threshold.  All three
+  thresholds come from an earlier setup.  Flash attention at S=2048
+  and the decode kernels are what the LM and generate cells run; the
+  LSTM's and the softmax's thresholds are not re-measured on this chip
+  and no cell runs them.
 - ``on``: every kernel wherever ``fits()`` holds (tests force kernels
   at toy shapes with ``enable(True, interpret=True)``).
 - ``off``: the jnp/XLA lowerings only (the reference ``chip_smoke.py``
@@ -149,6 +150,16 @@ def use_gated_delta_step(state_dtype, heads: int, d_v: int,
 
     return dispatch("gated_delta_step", policy(
         _g.fits(state_dtype, heads, d_v, wide), True))
+
+
+def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:
+    """A state-space layer's decode step over entries ``(rows, d_state,
+    lanes)``, by the same rule as ``use_gated_delta_step``: the kernel
+    wherever ``fits()`` holds, else gathered and scattered in XLA."""
+    from paddle_tpu.pallas import ssd_step as _s
+
+    return dispatch("ssd_step", policy(
+        _s.fits(state_dtype, rows, d_state, lanes), True))
 
 
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402

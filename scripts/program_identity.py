@@ -1,9 +1,11 @@
 #!/usr/bin/env python3
 """Are the paged skeleton's programs what they were?  Dump the compiled
 text (XLA:CPU, toy size) of `_decode_step`, `_verify_step`,
-`_prefill_chunk` and `_prefill_bucket` for `Gpt2Block`, `OlmoeBlock` and
-`ExaoneMoeBlock` with the metadata dropped (op_name / source lines, and the file and
-function tables at the head of the text), one file a program:
+`_prefill_chunk` and `_prefill_bucket` for `Gpt2Block`, `OlmoeBlock`,
+`ExaoneMoeBlock` and `OlmoHybridBlock` (the step and the bucket alone:
+a model over state entries refuses the other two) with the metadata
+dropped (op_name / source lines, and the file and function tables at
+the head of the text), one file a program:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<tree A> python scripts/program_identity.py /tmp/a
     JAX_PLATFORMS=cpu PYTHONPATH=<tree B> python scripts/program_identity.py /tmp/b
@@ -36,22 +38,24 @@ def strip(text):
 def dump(out, name, model, dm):
     S, P = 4, model.pages_per_seq
     kw = dict(heads=model.heads, page_size=model.page_size,
-              block=model.block)
+              block=model.block, extra=model.extra_pools)
     pools = (model.params, model.k_pool, model.v_pool)
     tables, lens = np.zeros((S, P), np.int32), np.zeros((S,), np.int32)
     lowered = {
         "decode": dm._decode_step.lower(
             *pools, tables, lens, np.zeros((S,), np.int32), **kw),
-        "verify": dm._verify_step.lower(
-            *pools, tables, lens, np.zeros((S, 3), np.int32), **kw),
-        "chunk": dm._prefill_chunk.lower(
-            *pools, jnp.zeros((P,), jnp.int32), np.int32(8),
-            jnp.zeros((5,), jnp.int32), **kw),
         "bucket": dm._prefill_bucket.lower(
             *pools, np.zeros((64,), np.int32),
-            model._prompt_rows(model.allocator.alloc(P)[:P], 64, 3),
-            np.int32(3), heads=model.heads, block=model.block),
+            model._prompt_rows(model.allocator.alloc(P), 64, 3),
+            np.int32(3), heads=model.heads, block=model.block,
+            extra=model.extra_pools),
     }
+    if getattr(model, "supports_verify", True):
+        lowered["verify"] = dm._verify_step.lower(
+            *pools, tables, lens, np.zeros((S, 3), np.int32), **kw)
+        lowered["chunk"] = dm._prefill_chunk.lower(
+            *pools, jnp.zeros((P,), jnp.int32), np.int32(8),
+            jnp.zeros((5,), jnp.int32), **kw)
     for key, low in lowered.items():
         with open(os.path.join(out, f"{name}.{key}.txt"), "w") as f:
             f.write(strip(low.compile().as_text()))
@@ -60,6 +64,7 @@ def dump(out, name, model, dm):
 def main(out):
     from paddle_tpu.decode import model as dm
     from paddle_tpu.models.exaone_moe import ExaoneMoeLM
+    from paddle_tpu.models.olmo_hybrid import FULL, LINEAR, OlmoHybridLM
     from paddle_tpu.models.olmoe import OlmoeLM
 
     os.makedirs(out, exist_ok=True)
@@ -75,6 +80,13 @@ def main(out):
         dense_width=48, expert_width=16, num_experts_published=8,
         held_experts=(2, 4), experts_per_tok=2, max_len=64, num_pages=64,
         page_size=8, pages_per_seq=8, dtype="float32"), dm)
+    dump(out, "olmo_hybrid", OlmoHybridLM(
+        vocab=96, d_model=32, num_heads=4, head_dim=8,
+        layer_types=(LINEAR, LINEAR, LINEAR, FULL) * 2,
+        intermediate_size=48, linear_num_key_heads=4,
+        linear_num_value_heads=4, linear_key_head_dim=6,
+        linear_value_head_dim=10, max_len=64, num_pages=40, page_size=8,
+        pages_per_seq=8, state_entries=5, dtype="float32"), dm)
     print("tree", os.path.dirname(os.path.dirname(dm.__file__)), "dumped",
           len(os.listdir(out)), "programs to", out)
 

@@ -367,6 +367,8 @@ def _decide(kernel, shape):
         return da._use_kernel(kernel, *shape)
     if kernel == "gated_delta_step":
         return pk.use_gated_delta_step(*shape)
+    if kernel == "ssd_step":
+        return pk.use_ssd_step(*shape)
     assert kernel == "prefill_flash_attention"
     x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
     # a new function each time: eval_shape caches a function's trace
@@ -430,6 +432,17 @@ _POLICY_CASES = (
        ("gated_delta_step", ("float32", 4, 10, 128), "on", True, False,
         "reference"),
        ("gated_delta_step", ("float32", 30, 192, 128), "off", True, False,
+        "reference"),
+       # entries (rows of heads, state size, lanes)
+       ("ssd_step", ("float32", 32, 128, 128), "auto", True, False,
+        "compiled"),
+       ("ssd_step", ("float32", 1, 128, 128), "auto", False, True,
+        "interpret"),
+       ("ssd_step", ("float32", 32, 128, 128), "auto", False, False,
+        "reference"),
+       ("ssd_step", ("float32", 64, 128, 64), "on", True, False,
+        "reference"),
+       ("ssd_step", ("float32", 32, 128, 128), "off", True, False,
         "reference")])
 
 

@@ -5,7 +5,8 @@ here, in tier-1: the judged statistic of the time to first token, the
 convoy share, the idle gaps' names, the traffic files' co-prime
 budgets, the load generator's token-clocked starts (PR 36's cases,
 imported and not copied), the expert layer's prefill readers (PR 38's
-cases, imported too), and the readers of the decode tick's own
+cases, imported too), the state-space layer's readers and the new
+cell's entries (PR 41's), and the readers of the decode tick's own
 account (PR 37) against a registry pair recorded from a session and
 against spans laid over the device plane of the trace recorded on the
 chip (``perf/tests/data``).
@@ -19,7 +20,8 @@ import pytest
 pytest.register_assert_rewrite(
     "perf.tests.test_stats", "perf.tests.test_trace",
     "perf.tests.test_traffic", "perf.tests.test_loadgen",
-    "perf.tests.test_moe_prefill_readers")
+    "perf.tests.test_moe_prefill_readers", "perf.tests.test_ssm_readers",
+    "perf.tests.test_granite_cell")
 
 from perf.harness import program_spans as ps  # noqa: E402
 from perf.harness import tick_account as ta  # noqa: E402
@@ -31,6 +33,16 @@ from perf.tests.test_moe_prefill_readers import (  # noqa: E402,F401
     test_moe_grouped_fill_is_assigned_over_computed,
     test_moe_prefill_ms_counts_the_loops_body_and_not_the_loop,
     test_moe_prefill_ms_reads_nothing_without_a_trace_or_the_layer)
+from perf.tests.test_granite_cell import (  # noqa: E402,F401
+    test_correct_holds_the_attention_layers_and_the_state,
+    test_the_cell_is_appended_where_it_reports,
+    test_the_configuration_is_the_catalogs_row_uncut,
+    test_the_longest_request_fits_the_rows_a_sequence_holds,
+    test_the_traffic_is_the_issues_letter_for_letter)
+from perf.tests.test_ssm_readers import (  # noqa: E402,F401
+    test_a_program_without_the_scopes_reads_nothing,
+    test_sizes_and_the_algorithms_counts,
+    test_the_four_readers_arithmetic)
 from perf.tests.test_stats import (  # noqa: E402,F401
     test_follower_share_counts_sends_in_a_convoy,
     test_interquartile_mean_does_not_sit_on_a_gap,
