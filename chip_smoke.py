@@ -12,8 +12,9 @@ entry points, in ONE process on one TPU v5e:
           program and compared with the same step under
           ``pallas.enable(False)``; and a hybrid's decode step (three
           Gated-DeltaNet layers and a full one at the published head
-          shapes), whose state entries the ``gated_delta_step`` kernel
-          advances, against the same step's loop over the slots;
+          shapes), whose state entries the ``conv_step`` and
+          ``gated_delta_step`` kernels advance, against the same step's
+          loop over the slots;
 - serve   the HTTP server exactly as ``paddle serve`` builds it:
           ``/health``, ``/predict`` on a ResNet-50 inference export, and
           ``/generate`` over the paged-KV decode engine vs the same
@@ -304,13 +305,14 @@ def _kernel_case(name, kernel, build, feed, steps, tol):
 
 def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
     """A hybrid's decode step (three linear layers, then a full one)
-    with the state kernel dispatched and again under
+    with the conv's and the state's kernels dispatched and again under
     ``pallas.enable(False)``, both from the same seeded weights, pages
     and state entries, the same tokens forced: the dispatch counter
-    moved once a linear layer; the first layer's entries, whose inputs
-    are the forced tokens' alone and so the same rows on both sides,
-    agree within ``state_tol`` (relative RMS: float32 rounding, only
-    the kernel differs); the logits and the later layers' entries, fed
+    moved once a linear layer for each kernel; the first layer's
+    entries, whose inputs are the forced tokens' alone and so the same
+    rows on both sides, agree within ``state_tol`` (relative RMS:
+    float32 rounding, only the kernel differs) and the rows its conv
+    keeps bit for bit; the logits and the later layers' entries, fed
     through bf16 casts of what came before, within ``tol``."""
     import jax
     import jax.numpy as jnp
@@ -340,8 +342,12 @@ def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
                 m.full_pages)
             tables[s, m.full_pages] = entries[s]
         lens = np.where(np.arange(S) < S - 1, 40 + 3 * np.arange(S), 0)
-        before = _counter("pallas_dispatch_total", kernel="gated_delta_step",
-                          path=EXPECT["kernel_path"])
+        def dispatched():
+            return {k: _counter("pallas_dispatch_total", kernel=k,
+                                path=EXPECT["kernel_path"])
+                    for k in ("conv_step", "gated_delta_step")}
+
+        before = dispatched()
         rows = []
         for step in range(size["steps"]):
             tokens = np.random.RandomState(seed + step).randint(
@@ -350,22 +356,29 @@ def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
                                  (lens + step).astype(np.int32))
             rows.append(np.asarray(logits)[:S - 1])
         if mode == "auto":
-            ran = _counter("pallas_dispatch_total", kernel="gated_delta_step",
-                           path=EXPECT["kernel_path"]) - before
-            assert ran == types.count(LINEAR), (
-                f"gated_delta_step: {ran} {EXPECT['kernel_path']} "
-                "dispatches in one traced step, not one a linear layer")
-            say(f"  kernel gated_delta_step: {int(ran)} "
-                f"{EXPECT['kernel_path']} dispatch(es) in the step")
+            for kernel, n in dispatched().items():
+                ran = n - before[kernel]
+                assert ran == types.count(LINEAR), (
+                    f"{kernel}: {ran} {EXPECT['kernel_path']} dispatches "
+                    "in one traced step, not one a linear layer")
+                say(f"  kernel {kernel}: {int(ran)} "
+                    f"{EXPECT['kernel_path']} dispatch(es) in the step")
         live = np.unique(tables[:S - 1, m.full_pages])
-        runs[mode] = (np.stack(rows), np.asarray(m.state_pool)[:, live])
+        runs[mode] = (np.stack(rows), np.asarray(m.state_pool)[:, live],
+                      np.asarray(m.conv_pool.astype(jnp.float32))[:, live])
         assert np.isfinite(runs[mode][0]).all(), "hybrid: non-finite logits"
     pk.enable("auto")
 
     def rel_rms(a, b):
         return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
 
-    (logits, states), (logits_off, states_off) = runs["auto"], runs["off"]
+    (logits, states, tails), (logits_off, states_off, tails_off) = (
+        runs["auto"], runs["off"])
+    # the conv's kernel only moves rows: the first layer's, whose
+    # inputs are the same on both sides, are the slot loop's bit for bit
+    assert (tails[0] == tails_off[0]).all() and tails[0].any(), (
+        "hybrid step: conv_step left other rows in the first layer's "
+        "entries than the slot loop")
     worst = rel_rms(logits, logits_off)
     first = rel_rms(states[0], states_off[0])
     later = rel_rms(states[1:], states_off[1:])

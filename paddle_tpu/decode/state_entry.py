@@ -34,12 +34,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import pallas as pk
 from paddle_tpu.decode.model import (
     PagedDecoderLM,
     PageRunCache,
     _dense_blocks,
 )
 from paddle_tpu.decode.paged_kv import CacheManager
+from paddle_tpu.pallas.conv_step import LANES, conv_step
 
 _F32 = jnp.float32
 
@@ -81,6 +83,49 @@ def conv_tail(z, taps, n):
     row 0: what a conv of ``taps`` taps has to keep of a prompt of ``n``
     real rows (the bucket's padding after them is left out)."""
     return jax.lax.dynamic_slice_in_dim(_rows_before(z, taps), n, taps - 1)
+
+
+def tail_shape(taps, channels):
+    """What a layer's conv keeps of a sequence, as an entry stores it:
+    the ``taps - 1`` rows one after another, in rows of 128 lanes where
+    the channels are whole lanes (an entry is then a block
+    ``pallas/conv_step.py`` takes whole, and nothing is padded: a layer's
+    entries as rows of one slab, or three rows an entry, are no whole
+    tiles), else ``(taps - 1, channels)``.  The same bytes in the same
+    order either way: a reshape of ``conv_tail``'s rows."""
+    kept = taps - 1
+    return ((kept * channels // LANES, LANES) if channels % LANES == 0
+            else (kept, channels))
+
+
+def step_conv(kept, row, w, b=None):
+    """A decode step's conv: the step's ``row`` (..., C) after the
+    ``taps - 1`` rows an entry keeps, ``kept`` (..., taps - 1, C), both
+    in the weights' dtype; taps ``w`` (taps, C), bias ``b`` (C,) or None
+    -> (``silu(sum_j w[j] rows[j] (+ b))`` float32, the rows the entry
+    keeps now: ``rows[1:]``).  Any leading shape: a slot's, or the
+    slots'.  ``pallas/conv_step.py`` is this, in this order, on the
+    entries where they lie."""
+    rows = jnp.concatenate([kept, row[..., None, :]], axis=-2)
+    acc = jnp.sum(rows.astype(_F32) * w.astype(_F32), axis=-2)
+    if b is not None:
+        acc = acc + b.astype(_F32)
+    return jax.nn.silu(acc), rows[..., 1:, :]
+
+
+def conv_over_entries(tails, at, row, w, b=None):
+    """A recurrent layer's conv over a decode step's rows ``row`` (S, C),
+    each after what its slot's entry ``at`` keeps in the tail pool seen
+    flat, ``tails`` (entries, ``*tail_shape``) -> (the conv's output
+    rows (S, C) float32, the pool with those entries moved on one row,
+    in place).  By ONE ``pallas/conv_step.py`` call where
+    ``pallas.use_conv_step`` says so, else gathered, advanced by
+    ``step_conv`` and scattered in XLA, the kernel's reference."""
+    (taps, C), S = w.shape, row.shape[0]
+    if pk.use_conv_step(tails.dtype, tails.shape[1:], row.dtype, taps, C):
+        return conv_step(tails, at, row, w, b, interpret=pk.interpret_mode())
+    out, kept = step_conv(tails[at].reshape(S, taps - 1, C), row, w, b)
+    return out, tails.at[at].set(kept.reshape((S,) + tails.shape[1:]))
 
 
 class StateEntryCache(PageRunCache):
@@ -131,7 +176,8 @@ class StateEntryCache(PageRunCache):
         k_pool, v_pool = super().store_prompts((k_pool, v_pool), full, flat)
         states = _pad_last(jnp.stack([s for s, _ in lin]),
                            state_pool.shape[-1]).astype(state_pool.dtype)
-        tails = jnp.stack([c for _, c in lin]).astype(conv_pool.dtype)
+        tails = jnp.stack([c for _, c in lin]).astype(
+            conv_pool.dtype).reshape((len(lin),) + conv_pool.shape[2:])
         return (k_pool, v_pool, state_pool.at[:, entry].set(states),
                 conv_pool.at[:, entry].set(tails))
 

@@ -30,10 +30,14 @@ one token on each slot's state: by ONE Pallas call a layer where
 ``pallas.use_ssd_step`` says so (``pallas/ssd_step.py``: the slots'
 entries scalar-prefetched, each read once and written once where it
 lies), else gathered, advanced by ``step_ssd`` and scattered in XLA,
-the kernel's reference.  The conv's tails (a row of ``3 x 4,352``
-channels an entry) are taken and put back by the same entry index, a
-row gather and a row scatter of the layer's slab of the tail pool: no
-loop over the slots.  The plain recurrence, row by row, is the benchmark's
+the kernel's reference.  The conv before it runs over the step's rows
+and the three rows of 4,352 channels each slot's entry keeps, by the
+same entry index: ONE ``pallas/conv_step.py`` call a layer over the
+tail pool where it lies (an entry ``(102, 128)``, its kept rows one
+after another in rows of lanes: ``state_entry.tail_shape``), else
+gathered, advanced and scattered in XLA
+(``state_entry.conv_over_entries``); no loop over the slots.  The
+plain recurrence, row by row, is the benchmark's
 reference (``perf/reference/granite_hybrid_block.py``).
 
 An **attention** layer: 32 query heads on 8 K/V heads of 64, no bias,
@@ -80,7 +84,9 @@ from paddle_tpu.decode.state_entry import (  # noqa: F401  (re-exported)
     StateEntryLM,
     UnsupportedOverState,
     causal_conv,
+    conv_over_entries,
     conv_tail,
+    tail_shape,
 )
 from paddle_tpu.models.exaone_moe import swiglu
 from paddle_tpu.models.olmoe import _mm, rms_norm
@@ -338,7 +344,7 @@ class GraniteHybridBlock(StateEntryCache):
             with jax.named_scope("ssm_conv"):
                 xc = jax.nn.silu(causal_conv(xBC, lp["w_conv"])
                                  + lp["b_conv"].astype(_F32))
-                tail = conv_tail(xBC, lp["w_conv"].shape[0], n).reshape(-1)
+                tail = conv_tail(xBC, lp["w_conv"].shape[0], n)
             xs, B, C = self._split(xc)
             with jax.named_scope("ssm_scan"):
                 y, state = chunked_ssd(
@@ -354,19 +360,9 @@ class GraniteHybridBlock(StateEntryCache):
         at = self.entries_of(state_pool, addr)
         with jax.named_scope("ssm"):
             with jax.named_scope("ssm_conv"):
-                # the slots' tails: rows of this layer's slab of the
-                # tail pool, taken and put back where they lie by the
-                # entry index (seen flat, a pool of 65 bfloat16 rows a
-                # layer is re-laid out: 65 is no whole tile of 16)
-                mine = (self.index_in_kind, addr.tables[:, self.full_pages])
-                rows = jnp.concatenate(
-                    [conv_pool[mine].reshape(S, -1, xBC.shape[-1]),
-                     xBC[:, None]], axis=1)
-                xc = jax.nn.silu(
-                    jnp.sum(rows.astype(_F32) * lp["w_conv"].astype(_F32),
-                            axis=1) + lp["b_conv"].astype(_F32))
-                conv_pool = conv_pool.at[mine].set(
-                    rows[:, 1:].reshape(S, -1))
+                xc, tails = conv_over_entries(
+                    conv_pool.reshape((-1,) + conv_pool.shape[2:]), at,
+                    xBC, lp["w_conv"], lp["b_conv"])
             xs, B, C = self._split(xc)
             with jax.named_scope("ssm_state"):
                 states = state_pool.reshape((-1,) + state_pool.shape[2:])
@@ -389,7 +385,8 @@ class GraniteHybridBlock(StateEntryCache):
                         pack_state(new, self.state_pack))
             y = self._gated_norm(lp, y, xs, z)
         return self._out(lp, x, y), (
-            k_pool, v_pool, states.reshape(state_pool.shape), conv_pool)
+            k_pool, v_pool, states.reshape(state_pool.shape),
+            tails.reshape(conv_pool.shape))
 
 
 # The standard deviation of a q or k row's numbers, whatever the width:
@@ -536,4 +533,4 @@ class GraniteHybridLM(StateEntryLM):
             num_pages, dtype, int(state_entries),
             (self.kv_heads // pack, pack * self.dh),
             (H // state_pack, N, state_pack * P),
-            ((self.conv_taps - 1) * (H * P + 2 * N),))
+            tail_shape(self.conv_taps, H * P + 2 * N))

@@ -25,9 +25,11 @@ chunk to chunk; no loop over rows), and over a decode step's rows one
 token on each slot's state: by ONE Pallas call a layer where
 ``pallas.use_gated_delta_step`` says so (``pallas/gated_delta.py``:
 the slots' entries scalar-prefetched, each read once and written once
-where it lies), else slot by slot in XLA over ``step_gated_delta``, the
-kernel's reference.  The plain recurrence, row by row, is the
-benchmark's reference (``perf/reference/olmo_hybrid_block.py``).
+where it lies, after ONE ``pallas/conv_step.py`` call over the rows
+the same entries keep for the conv), else slot by slot in XLA over
+``step_conv`` and ``step_gated_delta``, the kernels' references.  The
+plain recurrence, row by row, is the benchmark's reference
+(``perf/reference/olmo_hybrid_block.py``).
 
 A **full** layer: q, k, v of 30 heads of 128, an RMSNorm over the whole
 q and the whole k projection (OLMo's), causal softmax, no positional
@@ -73,7 +75,10 @@ from paddle_tpu.decode.state_entry import (  # noqa: F401  (re-exported)
     _pad_axis,
     _pad_last,
     causal_conv,
+    conv_over_entries,
     conv_tail,
+    step_conv,
+    tail_shape,
 )
 from paddle_tpu.models.exaone_moe import swiglu
 from paddle_tpu.models.olmoe import _mm, rms_norm
@@ -318,47 +323,30 @@ class OlmoHybridBlock(StateEntryCache):
     def recurrent_step(self, lp, x, cache, addr):
         k_pool, v_pool, state_pool, conv_pool = cache
         z, gate, g, beta = self._projections(lp, x)
-        Ll, E = state_pool.shape[:2]
         at = self.entries_of(state_pool, addr)
-        w = lp["w_conv"].astype(_F32)
         advance = (self._advance_by_kernel if pk.use_gated_delta_step(
             state_pool.dtype, *state_pool.shape[2:])
             else self._advance_slot_by_slot)
         with jax.named_scope("lin_attn"):
             o, states, tails = advance(
-                w, state_pool.reshape((Ll * E,) + state_pool.shape[2:]),
-                conv_pool.reshape((Ll * E,) + conv_pool.shape[2:]),
+                lp["w_conv"],
+                state_pool.reshape((-1,) + state_pool.shape[2:]),
+                conv_pool.reshape((-1,) + conv_pool.shape[2:]),
                 at, z, g, beta)
             y = self._gated_norm(lp, o, gate)
         return (self._lin_out(lp, x, y),
                 (k_pool, v_pool, states.reshape(state_pool.shape),
                  tails.reshape(conv_pool.shape)))
 
-    def _conv_row(self, w, tails, e, z_s):
-        """A slot's conv: its row after the three its entry ``e`` keeps
-        -> (the conv's output row, the tails with the entry's moved on
-        one row, in place)."""
-        with jax.named_scope("lin_attn_conv"):
-            rows = jnp.concatenate(
-                [jax.lax.dynamic_index_in_dim(tails, e, 0, False),
-                 z_s[None]])
-            zc = jax.nn.silu(jnp.sum(rows.astype(_F32) * w, axis=0))
-            tails = jax.lax.dynamic_update_index_in_dim(
-                tails, rows[1:], e, 0)
-        return zc, tails
-
     def _advance_by_kernel(self, w, states, tails, at, z, g, beta):
         """The step's rows on the slots' entries (``at``, in the pools
-        seen flat) -> (o (S, H, d_v), the pools).  The conv in a loop
-        over the slots that carries the tails alone (a tail is 69 KB;
-        gathered and scattered, the compiler re-lays the whole 41 MB
-        pool out around every layer), then every slot's state advanced
-        where it lies by one call of ``pallas/gated_delta.py``."""
-        def one_slot(tails, slot):
-            zc, tails = self._conv_row(w, tails, *slot)
-            return tails, zc
-
-        tails, zc = jax.lax.scan(one_slot, tails, (at, z))
+        seen flat) -> (o (S, H, d_v), the pools).  The conv over the
+        rows the entries keep, then every slot's state advanced, each
+        by one call over its pool where it lies
+        (``pallas/conv_step.py`` through ``conv_over_entries``,
+        ``pallas/gated_delta.py``): no loop over the slots."""
+        with jax.named_scope("lin_attn_conv"):
+            zc, tails = conv_over_entries(tails, at, z, w)
         q, k, v = self._split(zc)
         with jax.named_scope("lin_attn_state"):
             wide = states.shape[-1]                  # the keys as stored
@@ -368,8 +356,9 @@ class OlmoHybridBlock(StateEntryCache):
         return o, states, tails
 
     def _advance_slot_by_slot(self, w, states, tails, at, z, g, beta):
-        """The same in plain XLA, the kernel's reference and what runs
-        where it does not fit: one loop over the slots for both pools."""
+        """The same in plain XLA, the kernels' reference and what runs
+        where the state kernel does not fit: one loop over the slots
+        for both pools."""
         wide = states.shape[-1]
 
         def one_slot(pools, slot):
@@ -380,7 +369,12 @@ class OlmoHybridBlock(StateEntryCache):
             entry moves in place, read twice and written once."""
             states, tails = pools
             e, z_s, g_s, beta_s = slot
-            zc, tails = self._conv_row(w, tails, e, z_s)
+            with jax.named_scope("lin_attn_conv"):
+                kept = jax.lax.dynamic_index_in_dim(tails, e, 0, False)
+                zc, kept = step_conv(kept.reshape(w.shape[0] - 1, -1), z_s,
+                                     w)
+                tails = jax.lax.dynamic_update_index_in_dim(
+                    tails, kept.reshape(tails.shape[1:]), e, 0)
             q, k, v = self._split(zc)
             with jax.named_scope("lin_attn_state"):
                 o, new = step_gated_delta(
@@ -489,4 +483,5 @@ class OlmoHybridLM(StateEntryLM):
             num_pages, dtype, int(state_entries),
             (self.stored_heads, self.dh),
             (b.lin_heads, b.d_v, stored_key_width(b.d_k)),
-            (self.conv_taps - 1, b.lin_heads * (2 * b.d_k + b.d_v)))
+            tail_shape(self.conv_taps,
+                       b.lin_heads * (2 * b.d_k + b.d_v)))

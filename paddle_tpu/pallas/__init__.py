@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Six kernel families, twelve ``pl.pallas_call``s: the fused
+Seven kernel families, thirteen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -12,8 +12,9 @@ ragged paged attention (``decode/attention.py``, 3 kernels under 4
 names: the chunk kernel is also called on grouped heads, Hq query heads
 on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
-(``gated_delta.py``, 1) and Mamba-2's on the same layout
-(``ssd_step.py``, 1).
+(``gated_delta.py``, 1), Mamba-2's on the same layout
+(``ssd_step.py``, 1) and the depthwise conv before either, over the
+same entries' kept rows (``conv_step.py``, 1).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -22,7 +23,7 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
-  the gated delta and SSD steps) have no threshold.  All three
+  the gated delta, SSD and conv steps) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
   LSTM's and the softmax's thresholds are not re-measured on this chip
@@ -160,6 +161,18 @@ def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:
 
     return dispatch("ssd_step", policy(
         _s.fits(state_dtype, rows, d_state, lanes), True))
+
+
+def use_conv_step(pool_dtype, entry_shape, row_dtype, taps: int,
+                  channels: int) -> bool:
+    """A recurrent layer's conv over a decode step's rows and the rows
+    its slots' entries keep, by the same rule: the kernel wherever
+    ``fits()`` holds (the tail pool's dtype and an entry's shape as
+    stored), else gathered and scattered in XLA."""
+    from paddle_tpu.pallas import conv_step as _c
+
+    return dispatch("conv_step", policy(
+        _c.fits(pool_dtype, entry_shape, row_dtype, taps, channels), True))
 
 
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402

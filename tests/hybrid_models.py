@@ -19,7 +19,7 @@ class Hybrid:
     name: str
     cls: type
     ref: object              # the plain reference's module
-    kernel: str              # the step kernel pallas_dispatch_total names
+    kernel: str              # the state kernel pallas_dispatch_total names
     recurrent: str           # the layer_types entry that keeps a state
     sizes: dict              # off a TPU: the XLA path of a decode step
     kernel_sizes: dict       # sizes the step kernel's fits() accepts
@@ -54,8 +54,10 @@ _GRANITE = dict(vocab=96, d_model=32, num_heads=4, num_kv_heads=2,
 
 OLMO = Hybrid("olmo", oh.OlmoHybridLM, olmo_hybrid_block,
               "gated_delta_step", oh.LINEAR, _OLMO,
-              # d_v a whole tile of 8 rows: what the kernel's fits() asks
-              {**_OLMO, "linear_value_head_dim": 16},
+              # d_v a whole tile of 8 rows, and the conv's 4 x (2 x 8 +
+              # 16) channels a row of lanes: what the kernels' fits() ask
+              {**_OLMO, "linear_key_head_dim": 8,
+               "linear_value_head_dim": 16},
               ("lin_heads", "d_k", "d_v"))
 GRANITE = Hybrid("granite", gh.GraniteHybridLM, granite_hybrid_block,
                  "ssd_step", gh.MAMBA, _GRANITE, _GRANITE,
@@ -128,3 +130,33 @@ def lowered_texts(m, slots=4, bucket=64):
             (np.zeros((bucket,), np.int32), np.int32(0)), np.int32(3),
             heads=m.heads, block=m.block,
             extra=cache[2:]).as_text(debug_info=True)}
+
+
+def steps_by(h, kernels, ids, tokens):
+    """Prefill + the tokens teacher-forced, by the step's Pallas kernels
+    interpreted (the state's and ``conv_step``) or by the XLA paths
+    alone, each traced afresh -> (the logits rows, the tail pool as the
+    steps leave it, how many times ``conv_step`` was dispatched by
+    path)."""
+    import jax
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.observability import metrics
+
+    fam = metrics.REGISTRY.get("pallas_dispatch_total")
+
+    def count():
+        return {path: fam.value(kernel="conv_step", path=path)
+                for path in ("interpret", "reference")}
+
+    before = count()
+    pk.enable(bool(kernels), interpret=bool(kernels))
+    jax.clear_caches()          # the mode is no part of a program's key
+    try:
+        m = h.make(kernel=True)
+        rows = through_the_cache(m, ids, tokens)
+        tails = np.asarray(m.conv_pool)
+    finally:
+        pk.enable("auto", interpret=False)
+        jax.clear_caches()
+    return rows, tails, {p: n - before[p] for p, n in count().items()}

@@ -600,6 +600,7 @@ def _hybrid_cell(one_chip, monkeypatch):
 
     from paddle_tpu import pallas as pk
     from paddle_tpu.decode.attention import storage_heads
+    from paddle_tpu.decode.state_entry import tail_shape
     from paddle_tpu.models import olmo_hybrid as oh
 
     monkeypatch.setitem(pk._STATE, "mode", "on")
@@ -634,9 +635,11 @@ def _hybrid_cell(one_chip, monkeypatch):
     pool = sds((full, g["num_pages"], g["page_size"], 32, dh), dtype)
     E = g["state_entries"]
     assert E == g["slots"] + 1
+    # an entry's kept rows one after another in rows of lanes
+    tail = tail_shape(cfg["linear_conv_kernel_dim"], Hl * (2 * dk + dv))
+    assert tail == (270, 128)
     extra = (sds((L - full, E, Hl, dv, 128), jnp.float32),
-             sds((L - full, E, cfg["linear_conv_kernel_dim"] - 1,
-                  Hl * (2 * dk + dv)), dtype))
+             sds((L - full, E, *tail), dtype))
     return cfg, params, pool, extra, block, g["pages_per_seq"] + 1, sds
 
 
@@ -691,13 +694,14 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     buffers are aliased input to output; the four full layers run the
     paged kernel under ``attn_full`` and write their rows by 8
     scatters; every linear layer advances the slots' states by ONE
-    ``gated_delta_step`` call under ``lin_attn/lin_attn_state``, the
-    pool its in-place operand, and its conv in a loop over the slots
-    that carries the tails alone; nothing else has a pool's size but
-    two layout copies of the 41 MB conv pool, at the step's two ends
-    (a layout copy of the 1.73 GB state pool before a custom call is
-    what the K/V pools met at 30 heads); the plan is arguments +
-    70 MB."""
+    ``gated_delta_step`` call under ``lin_attn/lin_attn_state`` after
+    ONE ``conv_step`` call under ``lin_attn/lin_attn_conv`` over the
+    rows the same entries keep, each pool its kernel's in-place
+    operand, no loop over the slots; nothing else has a pool's size
+    (the slot loop's tail pool met two layout copies of its 41 MB at
+    the step's two ends; a layout copy of the 1.73 GB state pool before
+    a custom call is what the K/V pools met at 30 heads); the plan is
+    arguments + 70 MB."""
     from paddle_tpu.decode import model as dm
 
     cfg, params, pool, extra, block, width, sds = _hybrid_cell(
@@ -719,32 +723,33 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     planned = _planned_bytes(compiled)
     assert planned == HYBRID_PLANS["decode"] < 15.0e9, planned
     text = compiled.as_text()
-    stray = _pool_sized_strays(text, _hybrid_sizes(pool, extra))
-    assert sorted(s[1:] for s in stray) == [
-        ("copy", "conv"), ("copy", "conv"), ("copy-done", "conv")], stray
+    assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
     assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
     kernels = _kernel_op_names(text)
     rpa = [op for op in kernels if "ragged_paged_attention/" in op]
     assert len(rpa) == 4 and all("_decode_step)/attn_full/" in op
                                  for op in rpa)
     step = [op for op in kernels if "gated_delta_step/" in op]
-    assert len(step) == 12 and len(kernels) == 16
+    conv = [op for op in kernels if "conv_step/" in op]
+    assert len(step) == len(conv) == 12 and len(kernels) == 28
     assert all("_decode_step)/lin_attn/lin_attn_state/" in op for op in step)
-    # each writes the pool it was given (operand 6) as its output 1
-    assert sum("gated_delta_step/" in ln
-               and "output_to_operand_aliasing={{1}: (6, {})}" in ln
-               for ln in text.splitlines()) == 12
-    # the state pool is read and written by the kernel alone, no loop
-    assert not re.search(r"/while/body/(\w+/)?lin_attn_state/", text)
-    assert re.search(
-        r"jit\(_decode_step\)/lin_attn/while/body/(\w+/)?lin_attn_conv/",
-        text)
+    # the conv's kernel under its own scope, outside the state's
+    assert all("_decode_step)/lin_attn/lin_attn_conv/" in op for op in conv)
+    # each writes the pool it was given as its output 1: the states
+    # operand 6, the tails operand 3 (entries, rows, taps, pool)
+    for name, operand in (("gated_delta_step/", 6), ("conv_step/", 3)):
+        aliased = f"output_to_operand_aliasing={{{{1}}: ({operand}, {{}})}}"
+        assert sum(name in ln and aliased in ln
+                   for ln in text.splitlines()) == 12, name
+    # both pools are read and written by the kernels alone, no loop
+    # over the slots anywhere in a linear layer
+    assert not re.search(r"/lin_attn/while/", text)
 
 
 # memory_analysis() for a described v5e: arguments + outputs +
 # temporaries - aliased, at the configuration's 447 pages
-HYBRID_PLANS = {"decode": 13_819_715_072, 4096: 14_851_249_664,
-                4608: 14_992_017_408}
+HYBRID_PLANS = {"decode": 13_760_889_344, 4096: 14_845_293_056,
+                4608: 14_986_060_800}
 
 
 @pytest.mark.parametrize("bucket", [4096, 4608])
@@ -753,8 +758,9 @@ def test_hybrid_top_prefill_fits_beside_weights_states_and_pages(
     """The 4,096-row prefill bucket (the longest the cell's traffic
     sends) and the 4,608-row one (a sequence's capacity): the plan fits
     the chip beside 8.2 GB of weights, 3.75 GB of pages and 1.78 GB of
-    states (the 4,608-row plan is what ``num_pages`` was chosen by, and
-    the configuration's ``planned_bytes``); all four buffers are
+    states (the 4,608-row plan is what ``num_pages`` was chosen by; the
+    configuration's ``planned_bytes`` is that plan with the tail pool
+    of three rows an entry, 6 MB more); all four buffers are
     aliased; the four full layers run the flash kernel at 30 heads; the
     entry is written whole by one dynamic-update-slice a pool, the
     pages by two scatters, and nothing else has a pool's size."""
@@ -774,7 +780,9 @@ def test_hybrid_top_prefill_fits_beside_weights_states_and_pages(
     planned = _planned_bytes(compiled)
     assert planned == HYBRID_PLANS[bucket] < 15.0e9, planned
     if bucket == 4608:
-        assert planned == cfg["generate"]["planned_bytes"]
+        # the configuration's figure dates from the tail pool of three
+        # rows an entry, which the chip padded: 6 MB over since PR 42
+        assert 0 <= cfg["generate"]["planned_bytes"] - planned < 8 << 20
     text = compiled.as_text()
     assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
     flash = _kernel_op_names(text)
@@ -795,6 +803,7 @@ def _granite_cell(one_chip, monkeypatch, pack=None):
     import json
 
     from paddle_tpu import pallas as pk
+    from paddle_tpu.decode.state_entry import tail_shape
     from paddle_tpu.models import granite_hybrid as gh
 
     monkeypatch.setitem(pk._STATE, "mode", "on")
@@ -846,13 +855,14 @@ def _granite_cell(one_chip, monkeypatch, pack=None):
     extra = (sds((L - full, E, Hm // state_pack, N, state_pack * P),
                  jnp.float32),
              sds((L - full, E,
-                  (cfg["mamba_d_conv"] - 1) * (Hm * P + 2 * N)), dtype))
+                  *tail_shape(cfg["mamba_d_conv"], Hm * P + 2 * N)), dtype))
+    assert extra[1].shape[2:] == (102, 128)
     return cfg, params, pool, extra, block, g["pages_per_seq"] + 1, sds
 
 
 # memory_analysis() for a described v5e: arguments + outputs +
 # temporaries - aliased, at the configuration's 961 pages
-GRANITE_PLANS = {"decode": 12_453_352_448, 1920: 12_690_633_216}
+GRANITE_PLANS = {"decode": 12_448_998_912, 1920: 12_684_793_856}
 
 
 def test_granite_decode_step_moves_states_tails_and_pages_in_place(
@@ -862,9 +872,10 @@ def test_granite_decode_step_moves_states_tails_and_pages_in_place(
     128 rows of two 64-wide heads a 128-lane row, 65 state entries, 64
     slots): the four cache buffers are aliased input to output; every
     mamba layer advances the slots' states by ONE ``ssd_step`` call
-    under ``ssm/ssm_state``, the pool its in-place operand, and takes
-    and puts back the conv tails by a row gather and ONE in-place
-    scatter of the tail pool seen flat, with no loop over the slots;
+    under ``ssm/ssm_state`` after ONE ``conv_step`` call under
+    ``ssm/ssm_conv`` over the rows the same entries keep, each pool its
+    kernel's in-place operand, with no gather, no scatter and no loop
+    over the slots;
     the four attention layers run the grouped paged kernel on the
     packed pages under ``attn_full`` and write their rows by 8
     scatters; nothing else has a pool's size (this is the probe that
@@ -892,26 +903,27 @@ def test_granite_decode_step_moves_states_tails_and_pages_in_place(
     assert planned == GRANITE_PLANS["decode"] < 15.0e9, planned
     text = compiled.as_text()
     assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
-    # K and V a full layer; the tails a mamba layer (the compiler
-    # writes one layer's in two)
-    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
-    tails = sum(f"bf16[{','.join(map(str, extra[1].shape))}]" in ln
-                for ln in scatters)
-    assert len(scatters) - tails == 8 and 36 <= tails <= 37, len(scatters)
+    # K and V a full layer, and no other scatter: the tails move by
+    # the conv's kernel
+    assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
     kernels = _kernel_op_names(text)
     gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
     assert len(gqa) == 4 and all("_decode_step)/attn_full/" in op
                                  for op in gqa)
     step = [op for op in kernels if "ssd_step/" in op]
-    assert len(step) == 36 and len(kernels) == 40
+    conv = [op for op in kernels if "conv_step/" in op]
+    assert len(step) == len(conv) == 36 and len(kernels) == 76
     assert all("_decode_step)/ssm/ssm_state/" in op for op in step)
-    # each writes the pool it was given (operand 5) as its output 1
-    assert sum("ssd_step/" in ln
-               and "output_to_operand_aliasing={{1}: (5, {})}" in ln
-               for ln in text.splitlines()) == 36
+    # the conv's kernel under its own scope, outside the state's
+    assert all("_decode_step)/ssm/ssm_conv/" in op for op in conv)
+    # each writes the pool it was given as its output 1: the states
+    # operand 5, the tails operand 4 (entries, rows, taps, bias, pool)
+    for name, operand in (("ssd_step/", 5), ("conv_step/", 4)):
+        aliased = f"output_to_operand_aliasing={{{{1}}: ({operand}, {{}})}}"
+        assert sum(name in ln and aliased in ln
+                   for ln in text.splitlines()) == 36, name
     # no loop over the slots anywhere in a mamba layer
     assert not re.search(r"/ssm/while/", text)
-    assert "jit(_decode_step)/ssm/ssm_conv/" in text
     # the tied head contracts the embedding where it lies
     emb = cfg["vocab_size"] * cfg["hidden_size"]
     assert not [s for s in _pool_sized_strays(text, {emb: "emb"})
@@ -948,8 +960,9 @@ def test_granite_top_prefill_fits_beside_weights_states_and_pages(
     traffic's 1,200-row prompt runs in it): the plan fits the chip
     beside 6.38 GB of weights, 4.97 GB of states and 1.01 GB of pages
     (961: 64 sequences of 15 pages and the null page, all that the 65
-    state entries can ever seat; the plan is the configuration's
-    ``planned_bytes``); all four buffers are aliased; the four
+    state entries can ever seat; the configuration's
+    ``planned_bytes`` is this plan with the tail pool of a row an
+    entry, 6 MB more); all four buffers are aliased; the four
     attention layers run the flash kernel at heads of 64; the entry is
     written whole by one dynamic-update-slice a pool, the pages by two
     scatters, and nothing else has a pool's size."""
@@ -969,7 +982,9 @@ def test_granite_top_prefill_fits_beside_weights_states_and_pages(
     assert m.alias_size_in_bytes >= buffers
     planned = _planned_bytes(compiled)
     assert planned == GRANITE_PLANS[bucket] < 15.0e9, planned
-    assert planned == cfg["generate"]["planned_bytes"]
+    # the configuration's figure dates from the tail pool of a row an
+    # entry, 65 rows a slab padded to 80: 6 MB over since PR 42
+    assert 0 <= cfg["generate"]["planned_bytes"] - planned < 8 << 20
     g = cfg["generate"]
     assert g["num_pages"] == g["slots"] * g["pages_per_seq"] + 1
     text = compiled.as_text()
@@ -998,6 +1013,32 @@ def test_ssd_step_compiles(one_chip):
     assert ssd.head_block(R, N, lanes) == 16
     assert [op.split("/")[-2] for op in _kernel_op_names(text)] == [
         "ssd_step"]
+
+
+@pytest.mark.parametrize("channels, slots, bias", [
+    (4352, 64, True), (11520, 48, False)], ids=["granite", "olmo_hybrid"])
+def test_conv_step_compiles(one_chip, channels, slots, bias):
+    """The kernel alone at both cells' shapes, bfloat16: a slot a grid
+    step on an entry of 3 x C / 128 rows of lanes (102 / 270: tap j
+    starts at no tile's edge), the pool aliased."""
+    from paddle_tpu.decode.state_entry import tail_shape
+    from paddle_tpu.pallas import conv_step as cs
+
+    bf, E = jnp.bfloat16, slots + 1
+    entry = tail_shape(4, channels)
+    assert cs.fits(bf, entry, bf, 4, channels)
+    shapes = [((E, *entry), bf), ((slots,), jnp.int32),
+              ((slots, channels), bf), ((4, channels), bf)]
+    if bias:
+        shapes.append(((channels,), bf))
+    text = _compiled_text(
+        lambda pool, at, row, w, b=None: cs.conv_step(pool, at, row, w, b),
+        one_chip, *shapes)
+    assert [op.split("/")[-2] for op in _kernel_op_names(text)] == [
+        "conv_step"]
+    pool_operand = 4 if bias else 3
+    assert (f"output_to_operand_aliasing={{{{1}}: ({pool_operand}, {{}})}}"
+            in text)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

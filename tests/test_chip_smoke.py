@@ -21,11 +21,12 @@ TOY = {
     "transformer": dict(B=1, S=1024, D=128, L=1, V=64, steps=2),
     "lstm": dict(B=8, T=5, emb=16, hidden=128, steps=2),
     "softmax": dict(rows=512, cols=128, steps=2),
-    # d_v 16: whole tiles of 8 rows, so the step kernel fits
+    # d_v 16: whole tiles of 8 rows, and 4 x (2 x 8 + 16) channels a
+    # row of lanes, so both step kernels fit
     "hybrid": dict(slots=4, steps=2, model=dict(
         vocab=96, d_model=32, num_heads=4, head_dim=8, intermediate_size=48,
         linear_num_key_heads=4, linear_num_value_heads=4,
-        linear_key_head_dim=6, linear_value_head_dim=16, max_len=64,
+        linear_key_head_dim=8, linear_value_head_dim=16, max_len=64,
         num_pages=16, page_size=8, pages_per_seq=4, state_entries=5,
         dtype="float32")),
     "serve": dict(image=(3, 32, 32), classes=10, batches=(1, 3, 4),

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from hybrid_models import GRANITE, lowered_texts, prompt, reference
-from hybrid_models import through_the_cache
+from hybrid_models import steps_by, through_the_cache
 from paddle_tpu import pallas as pk
 from paddle_tpu.decode.attention import ragged_paged_attention_gqa_reference
 from paddle_tpu.models import granite_hybrid as gh
@@ -45,10 +45,12 @@ def step_path(request):
         yield
         return
     pk.enable(True, interpret=True)
+    jax.clear_caches()          # the mode is no part of a program's key
     try:
         yield
     finally:
         pk.enable("auto", interpret=False)
+        jax.clear_caches()
 
 
 # -- the recurrence -----------------------------------------------------------
@@ -212,6 +214,28 @@ def test_the_entry_after_16_steps_is_the_entry_one_prefill_leaves(
     assert ref.rel_rms(low, want) > 1e-3
 
 
+def test_eight_steps_by_the_kernels_are_the_xla_paths_steps():
+    """A prefill + 8 decode steps with the step's kernels interpreted
+    (``conv_step``, ``ssd_step``) against the same under
+    ``pallas.enable(False)``: the conv's tails, written by the prefill's
+    ``conv_tail`` and carried across the steps, are only moved, so the
+    first recurrent layer's are bit-identical in every entry but the
+    null one (later layers' rows inherit float32 rounding); the logits
+    agree to it; the dispatch counter says which path each trace of the
+    step took, once a recurrent layer."""
+    ids, tokens = prompt(70, 1), prompt(8, 2)
+    by_kernel, tails, took = steps_by(GRANITE, True, ids, tokens)
+    by_xla, want_tails, took_xla = steps_by(GRANITE, False, ids, tokens)
+    assert took == {"interpret": 9, "reference": 0}
+    assert took_xla == {"interpret": 0, "reference": 9}
+    assert by_kernel.shape[0] == 9
+    np.testing.assert_allclose(by_kernel, by_xla, atol=2e-6)
+    # the first recurrent layer's rows come of the embedding alone
+    np.testing.assert_array_equal(tails[0, 1:], want_tails[0, 1:])
+    np.testing.assert_allclose(tails[:, 1:], want_tails[:, 1:], atol=2e-6)
+    assert tails[:, 1:].any()
+
+
 @pytest.mark.parametrize("n", [1, 3, 8, 63, 64, 65, 127, 128, 129, 200])
 def test_prompt_lengths_round_a_chunk_and_a_bucket(model, n):
     ids, tokens = prompt(n, n), prompt(3, n + 1)
@@ -274,12 +298,15 @@ def test_bucket_padding_leaves_state_and_conv_tail_untouched(model):
     assert state.shape[1:] == (1, 128, 128)      # two heads a row of lanes
     for i, (want_state, want_tail) in enumerate(rec):
         np.testing.assert_allclose(state[i], want_state, atol=1e-5)
-        np.testing.assert_allclose(tail[i], want_tail, atol=1e-6)
+        # the kept rows one after another in rows of lanes
+        assert tail[i].shape == (9, 128) and want_tail.shape == (3, 384)
+        np.testing.assert_allclose(tail[i].reshape(3, 384), want_tail,
+                                   atol=1e-6)
 
 
 def test_a_conv_tail_of_a_short_prompt_is_zeros_before_row_0(model):
     """The tail is three rows of the conv's 2 x 64 + 2 x 128 channels,
-    stored flat."""
+    stored one after another in rows of 128 lanes."""
     ids = prompt(2, 12)
     pages = model.allocator.alloc(model.context_pages(ids, 0))
     entry = model.allocator.entry_of(pages)

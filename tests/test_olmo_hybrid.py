@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from hybrid_models import OLMO, lowered_texts, through_the_cache
+from hybrid_models import OLMO, lowered_texts, steps_by, through_the_cache
 from hybrid_models import prompt as _prompt
 from hybrid_models import reference as _reference
 from paddle_tpu import pallas as pk
@@ -143,6 +143,30 @@ def test_prefill_then_decode_through_both_caches_match_the_reference(
                       list(range(len(prompt) - 1, len(prompt) + len(tokens))))
     assert ref.rel_rms(got, want) < 1e-5
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_eight_steps_by_the_kernels_are_the_xla_paths_steps():
+    """A prefill + 8 decode steps with the step's kernels interpreted
+    (``conv_step``, ``gated_delta_step``) against the same under
+    ``pallas.enable(False)``: the conv's tails, written by the prefill's
+    ``conv_tail`` and carried across the steps, are only moved, so the
+    first recurrent layer's are bit-identical in every entry but the
+    null one (later layers' rows inherit float32 rounding); the logits
+    agree to it; the dispatch counter says which path each trace of the
+    step took, once a recurrent layer."""
+    ids, tokens = _prompt(70, 1), _prompt(8, 2)
+    by_kernel, tails, took = steps_by(OLMO, True, ids, tokens)
+    by_xla, want_tails, took_xla = steps_by(OLMO, False, ids, tokens)
+    assert took == {"interpret": 6, "reference": 0}
+    # the slot loop is the other path here: it asks nothing of the
+    # conv's kernel
+    assert took_xla == {"interpret": 0, "reference": 0}
+    assert by_kernel.shape[0] == 9
+    np.testing.assert_allclose(by_kernel, by_xla, atol=2e-5)
+    # the first recurrent layer's rows come of the embedding alone
+    np.testing.assert_array_equal(tails[0, 1:], want_tails[0, 1:])
+    np.testing.assert_allclose(tails[:, 1:], want_tails[:, 1:], atol=2e-6)
+    assert tails[:, 1:].any()
 
 
 @pytest.mark.parametrize("n", [1, 3, 8, 63, 64, 65, 130])

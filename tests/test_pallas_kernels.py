@@ -369,6 +369,9 @@ def _decide(kernel, shape):
         return pk.use_gated_delta_step(*shape)
     if kernel == "ssd_step":
         return pk.use_ssd_step(*shape)
+    if kernel == "conv_step":
+        dtype, taps, channels, entry = shape
+        return pk.use_conv_step(dtype, entry, dtype, taps, channels)
     assert kernel == "prefill_flash_attention"
     x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
     # a new function each time: eval_shape caches a function's trace
@@ -443,6 +446,21 @@ _POLICY_CASES = (
        ("ssd_step", ("float32", 64, 128, 64), "on", True, False,
         "reference"),
        ("ssd_step", ("float32", 32, 128, 128), "off", True, False,
+        "reference"),
+       # the tail pool's dtype, taps, channels, an entry as stored
+       ("conv_step", ("bfloat16", 4, 4352, (102, 128)), "auto", True, False,
+        "compiled"),
+       ("conv_step", ("bfloat16", 4, 11520, (270, 128)), "auto", True,
+        False, "compiled"),
+       ("conv_step", ("float32", 4, 384, (9, 128)), "auto", False, True,
+        "interpret"),
+       ("conv_step", ("bfloat16", 4, 4352, (102, 128)), "auto", False,
+        False, "reference"),
+       ("conv_step", ("float32", 4, 112, (3, 112)), "on", True, False,
+        "reference"),
+       ("conv_step", ("bfloat16", 4, 4352, (13056,)), "on", True, False,
+        "reference"),
+       ("conv_step", ("bfloat16", 4, 4352, (102, 128)), "off", True, False,
         "reference")])
 
 

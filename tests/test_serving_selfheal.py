@@ -200,7 +200,8 @@ def test_replica_death_requeues_inflight_and_respawns(model_dir):
         assert replica_mod._M_DEATHS.value(cause="injected") == 1
         assert replica_mod._M_REQUEUED.value() >= 1
         assert _wait_for(lambda: len(srv._pool.replicas) == 2)
-        assert replica_mod._M_RESTARTS.value() >= 1
+        # counted after the replica is in the pool, outside its lock
+        assert _wait_for(lambda: replica_mod._M_RESTARTS.value() >= 1)
         health = _get_json(srv.address, "/health")
         assert health["status"] == "ok"
         assert health["self_healing"]["pool"]["live"] == 2
