@@ -14,7 +14,8 @@ entry points, in ONE process on one TPU v5e:
           Gated-DeltaNet layers and a full one at the published head
           shapes), whose state entries the ``conv_step`` and
           ``gated_delta_step`` kernels advance, against the same step's
-          loop over the slots;
+          loop over the slots, and its 4,096-row prefill, whose linear
+          layers run ``gated_delta_chunked``, against the XLA form;
 - serve   the HTTP server exactly as ``paddle serve`` builds it:
           ``/health``, ``/predict`` on a ResNet-50 inference export, and
           ``/generate`` over the paged-KV decode engine vs the same
@@ -64,7 +65,10 @@ SIZES = {
     "hybrid": dict(slots=8, steps=3, model=dict(
         vocab=4096, d_model=1024, num_heads=8, head_dim=128,
         intermediate_size=2048, max_len=512, num_pages=40, page_size=32,
-        pages_per_seq=4, state_entries=9)),
+        pages_per_seq=4, state_entries=9),
+        # the same period's prefill over the cell's longest bucket
+        prefill=dict(rows=4096, max_len=4608, num_pages=40, page_size=128,
+                     pages_per_seq=36)),
     "serve": dict(image=(3, 224, 224), classes=1000, batches=(1, 3, 8),
                   gen_requests=6, gen_slots=4, gen_tokens=16),
     # global batch 256 over dp=4; the hybrid runs S=2048 so each sp=2
@@ -255,6 +259,10 @@ def phase_train(size, seed):
     amp.enable(False)
 
 
+def _rel_rms(a, b):
+    return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+
 def _peak_gb(dev):
     stats = dev.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
@@ -369,9 +377,6 @@ def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
         assert np.isfinite(runs[mode][0]).all(), "hybrid: non-finite logits"
     pk.enable("auto")
 
-    def rel_rms(a, b):
-        return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
-
     (logits, states, tails), (logits_off, states_off, tails_off) = (
         runs["auto"], runs["off"])
     # the conv's kernel only moves rows: the first layer's, whose
@@ -379,15 +384,77 @@ def _hybrid_step_case(size, seed, tol=2e-2, state_tol=1e-5):
     assert (tails[0] == tails_off[0]).all() and tails[0].any(), (
         "hybrid step: conv_step left other rows in the first layer's "
         "entries than the slot loop")
-    worst = rel_rms(logits, logits_off)
-    first = rel_rms(states[0], states_off[0])
-    later = rel_rms(states[1:], states_off[1:])
+    worst = _rel_rms(logits, logits_off)
+    first = _rel_rms(states[0], states_off[0])
+    later = _rel_rms(states[1:], states_off[1:])
     assert first <= state_tol and max(worst, later) <= tol, (
         f"hybrid step: kernel vs XLA slot loop: first layer's entries rel "
         f"RMS {first:.2e} (tol {state_tol}), later layers' {later:.2e}, "
         f"logits {worst:.2e} (tol {tol})")
     say(f"  hybrid step: kernel vs pallas.enable(False) first layer's "
         f"entries rel RMS {first:.2e} (tol {state_tol}), later layers' "
+        f"{later:.2e}, logits {worst:.2e} (tol {tol})")
+
+
+def _hybrid_prefill_case(size, seed, tol=2e-2, state_tol=1e-5):
+    """The same period's prefill of one bucket of ``rows`` rows with
+    the kernels dispatched and again under ``pallas.enable(False)``,
+    the same seeded weights and ids: the dispatch counter moved once a
+    linear layer for ``gated_delta_chunked``; the first layer's state
+    as the prefill leaves it in the entry, whose inputs are the
+    embeddings alone and so the same rows on both sides, agrees with
+    ``chunked_gated_delta``'s within ``state_tol`` (relative RMS:
+    float32 rounding, only the kernel differs); the last row's logits
+    and the later layers' states, fed through bf16 casts of what came
+    before (and through the flash kernel where it runs), within
+    ``tol``."""
+    import jax
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.models.olmo_hybrid import FULL, LINEAR, OlmoHybridLM
+
+    types = (LINEAR, LINEAR, LINEAR, FULL)
+    cut = dict(size["prefill"])
+    rows = cut.pop("rows")
+    ids = np.random.RandomState(seed).randint(
+        2, size["model"]["vocab"], rows).tolist()
+    runs = {}
+    for mode in ("auto", "off"):
+        pk.enable(mode)
+        jax.clear_caches()  # dispatch is decided at trace time
+        m = OlmoHybridLM(seed=seed, layer_types=types,
+                         **{**size["model"], **cut})
+        before = _counter("pallas_dispatch_total",
+                          kernel="gated_delta_chunked",
+                          path=EXPECT["kernel_path"])
+        pages = m.allocator.alloc(m.context_pages(ids, 0))
+        t0 = time.perf_counter()
+        _, _, last = m.prefill(ids, pages)
+        last = np.asarray(last)
+        first = time.perf_counter() - t0
+        ran = _counter("pallas_dispatch_total", kernel="gated_delta_chunked",
+                       path=EXPECT["kernel_path"]) - before
+        assert ran == (types.count(LINEAR) if mode == "auto" else 0), (
+            f"gated_delta_chunked: {ran} {EXPECT['kernel_path']} dispatches "
+            f"in one traced {rows}-row bucket under pallas={mode}")
+        say(f"  hybrid prefill pallas={mode}: {rows} rows, compile + run "
+            f"{first:.1f}s, gated_delta_chunked {int(ran)} "
+            f"{EXPECT['kernel_path']} dispatch(es)")
+        entry = m.allocator.entry_of(pages)
+        runs[mode] = (last, np.asarray(m.state_pool[:, entry]))
+        assert np.isfinite(last).all(), "hybrid prefill: non-finite logits"
+    pk.enable("auto")
+
+    (logits, states), (logits_off, states_off) = runs["auto"], runs["off"]
+    first = _rel_rms(states[0], states_off[0])
+    later = _rel_rms(states[1:], states_off[1:])
+    worst = _rel_rms(logits, logits_off)
+    assert first <= state_tol and max(worst, later) <= tol, (
+        f"hybrid prefill: kernel vs XLA chunked form: first layer's state "
+        f"rel RMS {first:.2e} (tol {state_tol}), later layers' {later:.2e}, "
+        f"logits {worst:.2e} (tol {tol})")
+    say(f"  hybrid prefill: kernel vs pallas.enable(False) first layer's "
+        f"state rel RMS {first:.2e} (tol {state_tol}), later layers' "
         f"{later:.2e}, logits {worst:.2e} (tol {tol})")
 
 
@@ -462,6 +529,7 @@ def phase_kernels(sizes, seed):
                  tol=1e-3)
     amp.enable(False)
     _hybrid_step_case(sizes["hybrid"], seed)
+    _hybrid_prefill_case(sizes["hybrid"], seed)
 
 
 # -- phase: serve --------------------------------------------------------

@@ -18,18 +18,24 @@ width ``d_v`` (``u``: the layer's input rows):
     S_t = alpha_t S_{t-1} + beta_t (v_t - alpha_t S_{t-1} k_t) k_t^T
     o_t = S_t q_t;  y_t = W_o [RMSNorm_{d_v}(o_t) * silu(W_g u)]
 
-with the state ``S`` (d_v, d_k) zero before row 0.  Computed two ways:
-over a prompt **chunked** (``chunked_gated_delta``: inside a chunk of
-64 rows matmuls and one unit-lower-triangular solve, the state carried
-chunk to chunk; no loop over rows), and over a decode step's rows one
-token on each slot's state: by ONE Pallas call a layer where
-``pallas.use_gated_delta_step`` says so (``pallas/gated_delta.py``:
-the slots' entries scalar-prefetched, each read once and written once
-where it lies, after ONE ``pallas/conv_step.py`` call over the rows
-the same entries keep for the conv), else slot by slot in XLA over
-``step_conv`` and ``step_gated_delta``, the kernels' references.  The
-plain recurrence, row by row, is the benchmark's reference
-(``perf/reference/olmo_hybrid_block.py``).
+with the state ``S`` (d_v, d_k) zero before row 0.  Computed two ways,
+each by a Pallas kernel where ``paddle_tpu.pallas`` says so and by its
+XLA reference elsewhere.  Over a prompt **chunked**: inside a chunk
+matmuls and one unit-lower-triangular solve, the state carried chunk to
+chunk, no loop over rows; by ONE ``pallas/gated_delta_chunked.py`` call
+a layer where ``pallas.use_gated_delta_chunked`` says so (a bucket of
+whole 128-row chunks: the state stays in VMEM across a head's chunks
+and the solve is an inverse built by doubling, all matmuls), else by
+``chunked_gated_delta`` (chunks of 64: for all chunks at once the
+products and ``solve_triangular``, then a ``lax.scan`` over the
+chunks).  Over a decode step's rows one token on each slot's state: by
+ONE Pallas call a layer where ``pallas.use_gated_delta_step`` says so
+(``pallas/gated_delta.py``: the slots' entries scalar-prefetched, each
+read once and written once where it lies, after ONE
+``pallas/conv_step.py`` call over the rows the same entries keep for
+the conv), else slot by slot in XLA over ``step_conv`` and
+``step_gated_delta``.  The plain recurrence, row by row, is the
+benchmark's reference (``perf/reference/olmo_hybrid_block.py``).
 
 A **full** layer: q, k, v of 30 heads of 128, an RMSNorm over the whole
 q and the whole k projection (OLMo's), causal softmax, no positional
@@ -83,6 +89,7 @@ from paddle_tpu.decode.state_entry import (  # noqa: F401  (re-exported)
 from paddle_tpu.models.exaone_moe import swiglu
 from paddle_tpu.models.olmoe import _mm, rms_norm
 from paddle_tpu.pallas.gated_delta import gated_delta_step
+from paddle_tpu.pallas.gated_delta_chunked import gated_delta_chunked
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -314,9 +321,13 @@ class OlmoHybridBlock(StateEntryCache):
                 tail = conv_tail(z, lp["w_conv"].shape[0], n)
             q, k, v = self._split(zc)
             with jax.named_scope("lin_attn_scan"):
-                o, state = chunked_gated_delta(
-                    q, k, v, g, beta,
-                    jnp.zeros((self.lin_heads, self.d_v, self.d_k), _F32))
+                state = jnp.zeros((self.lin_heads, self.d_v, self.d_k), _F32)
+                if pk.use_gated_delta_chunked(state.dtype, T, *state.shape):
+                    o, state = gated_delta_chunked(
+                        q, k, v, g, beta, state,
+                        interpret=pk.interpret_mode())
+                else:
+                    o, state = chunked_gated_delta(q, k, v, g, beta, state)
             y = self._gated_norm(lp, o, gate)
         return self._lin_out(lp, x, y), (state, tail)
 

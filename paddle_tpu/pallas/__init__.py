@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Seven kernel families, thirteen ``pl.pallas_call``s: the fused
+Eight kernel families, fourteen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -13,8 +13,10 @@ names: the chunk kernel is also called on grouped heads, Hq query heads
 on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
 (``gated_delta.py``, 1), Mamba-2's on the same layout
-(``ssd_step.py``, 1) and the depthwise conv before either, over the
-same entries' kept rows (``conv_step.py``, 1).
+(``ssd_step.py``, 1), the depthwise conv before either, over the
+same entries' kept rows (``conv_step.py``, 1), and the gated delta rule
+chunked over a prefill bucket's rows, the state in VMEM from chunk to
+chunk (``gated_delta_chunked.py``, 1).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -23,7 +25,8 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
-  the gated delta, SSD and conv steps) have no threshold.  All three
+  the gated delta, SSD and conv steps, the chunked gated delta rule of
+  a hybrid's prefill) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
   LSTM's and the softmax's thresholds are not re-measured on this chip
@@ -151,6 +154,18 @@ def use_gated_delta_step(state_dtype, heads: int, d_v: int,
 
     return dispatch("gated_delta_step", policy(
         _g.fits(state_dtype, heads, d_v, wide), True))
+
+
+def use_gated_delta_chunked(state_dtype, rows: int, heads: int, d_v: int,
+                            d_k: int) -> bool:
+    """A hybrid's prefill runs the gated delta rule over a bucket's
+    ``rows`` by the kernel wherever ``fits()`` holds (a float32 state, a
+    bucket of whole chunks), else chunked in XLA
+    (``models/olmo_hybrid.py:chunked_gated_delta``, its reference)."""
+    from paddle_tpu.pallas import gated_delta_chunked as _g
+
+    return dispatch("gated_delta_chunked", policy(
+        _g.fits(state_dtype, rows, heads, d_v, d_k), True))
 
 
 def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:

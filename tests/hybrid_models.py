@@ -112,24 +112,30 @@ def through_the_cache(m, ids, tokens, slots=4, slot=2):
     return np.stack(rows)
 
 
-def lowered_texts(m, slots=4, bucket=64):
+def lowered_texts(m, slots=4, bucket=64, platforms=None):
     """The lowered text, with scopes, of the model's decode step and of
-    one prefill bucket."""
+    one prefill bucket; ``platforms``: what to lower for instead of the
+    backend (``("tpu",)``: kernels as custom calls, nothing runs)."""
     from paddle_tpu.decode import model as dm
 
     cache = m._cache()
+
+    def text(program, *args, **kw):
+        return program.trace(*args, **kw).lower(
+            lowering_platforms=platforms).as_text(debug_info=True)
+
     return {
-        "_decode_step": dm._decode_step.lower(
-            m.params, *cache[:2],
+        "_decode_step": text(
+            dm._decode_step, m.params, *cache[:2],
             np.zeros((slots, m.pages_per_seq), np.int32),
             np.zeros((slots,), np.int32), np.zeros((slots,), np.int32),
             heads=m.heads, page_size=m.page_size, block=m.block,
-            extra=cache[2:]).as_text(debug_info=True),
-        "_prefill_bucket": dm._prefill_bucket.lower(
-            m.params, *cache[:2], np.zeros((bucket,), np.int32),
+            extra=cache[2:]),
+        "_prefill_bucket": text(
+            dm._prefill_bucket, m.params, *cache[:2],
+            np.zeros((bucket,), np.int32),
             (np.zeros((bucket,), np.int32), np.int32(0)), np.int32(3),
-            heads=m.heads, block=m.block,
-            extra=cache[2:]).as_text(debug_info=True)}
+            heads=m.heads, block=m.block, extra=cache[2:])}
 
 
 def steps_by(h, kernels, ids, tokens):

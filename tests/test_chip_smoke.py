@@ -28,7 +28,10 @@ TOY = {
         linear_num_key_heads=4, linear_num_value_heads=4,
         linear_key_head_dim=8, linear_value_head_dim=16, max_len=64,
         num_pages=16, page_size=8, pages_per_seq=4, state_entries=5,
-        dtype="float32")),
+        dtype="float32"),
+        # two chunks of the prefill's kernel
+        prefill=dict(rows=256, max_len=320, num_pages=48, page_size=8,
+                     pages_per_seq=40)),
     "serve": dict(image=(3, 32, 32), classes=10, batches=(1, 3, 4),
                   gen_requests=4, gen_slots=4, gen_tokens=8),
 }

@@ -261,3 +261,19 @@ def test_named_scopes_place_the_linear_layers(model):
                   "attn_full/"):
         assert scope in text["_prefill_bucket"], scope
     assert "lin_attn_scan/" not in text["_decode_step"]
+    # the XLA form's scan over the chunks is a loop under the scope ...
+    assert "/lin_attn_scan/while" in text["_prefill_bucket"]
+    # ... and with the kernel on (lowered for a TPU, nothing runs) a
+    # 128-row bucket holds the kernel's custom call there, once a
+    # linear layer, its relayout beside it, and no loop
+    pk.enable(True, interpret=False)
+    jax.clear_caches()          # the mode is no part of a program's key
+    try:
+        on = lowered_texts(OLMO.make(kernel=True), bucket=128,
+                           platforms=("tpu",))["_prefill_bucket"]
+    finally:
+        pk.enable("auto", interpret=False)
+        jax.clear_caches()
+    call = "/lin_attn/lin_attn_scan/gated_delta_chunked/pallas_call"
+    assert call in on and "tpu_custom_call" in on
+    assert "/lin_attn_scan/while" not in on

@@ -369,6 +369,8 @@ def _decide(kernel, shape):
         return pk.use_gated_delta_step(*shape)
     if kernel == "ssd_step":
         return pk.use_ssd_step(*shape)
+    if kernel == "gated_delta_chunked":
+        return pk.use_gated_delta_chunked(*shape)
     if kernel == "conv_step":
         dtype, taps, channels, entry = shape
         return pk.use_conv_step(dtype, entry, dtype, taps, channels)
@@ -436,6 +438,21 @@ _POLICY_CASES = (
         "reference"),
        ("gated_delta_step", ("float32", 30, 192, 128), "off", True, False,
         "reference"),
+       # a bucket's rows, then the state (heads, d_v, d_k)
+       ("gated_delta_chunked", ("float32", 4096, 30, 192, 96), "auto", True,
+        False, "compiled"),
+       ("gated_delta_chunked", ("float32", 128, 30, 192, 96), "auto", True,
+        False, "compiled"),
+       ("gated_delta_chunked", ("float32", 128, 4, 16, 8), "auto", False,
+        True, "interpret"),
+       ("gated_delta_chunked", ("float32", 4096, 30, 192, 96), "auto", False,
+        False, "reference"),
+       ("gated_delta_chunked", ("float32", 64, 30, 192, 96), "on", True,
+        False, "reference"),
+       ("gated_delta_chunked", ("bfloat16", 4096, 30, 192, 96), "on", True,
+        False, "reference"),
+       ("gated_delta_chunked", ("float32", 4096, 30, 192, 96), "off", True,
+        False, "reference"),
        # entries (rows of heads, state size, lanes)
        ("ssd_step", ("float32", 32, 128, 128), "auto", True, False,
         "compiled"),
