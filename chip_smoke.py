@@ -970,7 +970,7 @@ def main(argv=None):
     from paddle_tpu.observability import metrics
 
     snap = metrics.snapshot()
-    for fam in ("pallas_dispatch_total", "tuning_db_lookup_total",
+    for fam in ("pallas_dispatch_total",
                 "executor_aot_export_skipped_total",
                 "executor_donation_analysis_failed_total"):
         vals = {",".join(f"{k}={w}" for k, w in sorted(v["labels"].items())):

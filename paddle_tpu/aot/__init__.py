@@ -12,7 +12,7 @@ but still traces and lowers), this path serializes whole executables
 through ``jax.experimental.serialize_executable`` with the donation mask
 pinned in the manifest and re-proved at load: a boot neither traces nor
 compiles, and donation stays active on artifact-booted replicas.  Any mismatch — version skew, device kind,
-tuning-DB drift, fingerprint drift, corrupt payload, donation drift —
+fingerprint drift, corrupt payload, donation drift —
 is a loud JIT fallback counted in ``aot_load_total{result}``: slower,
 never wrong.
 

@@ -14,9 +14,6 @@ make a stored executable wrong or slow to reuse:
 
 - jax / jaxlib versions, backend platform and device kind (an XLA
   binary is not portable across any of these);
-- the Pallas tuning-DB digest (a re-tuned kernel config changes the
-  lowering, so stale artifacts must re-export, not silently serve the
-  old schedule);
 - compile-context flags (amp, pallas mode, interpret, trace_ops) —
   the same bits that key the executor cache;
 - per entry: the donation mask the analyzer proved at export time.
@@ -50,7 +47,7 @@ EXEC_DIR = "executables"
 _M_AOT_LOAD = _metrics.counter(
     "aot_load_total",
     "artifact-store lookups by outcome: loaded, or rejected_* (version "
-    "skew / device / tuning-db / flags / fingerprint / bucket / corrupt "
+    "skew / device / flags / fingerprint / bucket / corrupt "
     "/ donation drift) — every rejection is a loud JIT fallback")
 _M_AOT_EXPORT = _metrics.counter(
     "aot_export_total",
@@ -79,24 +76,6 @@ def environment_fingerprint(backend: Optional[str] = None) -> Dict[str, str]:
         "platform": devs[0].platform,
         "device_kind": devs[0].device_kind,
     }
-
-
-def tuning_db_digest() -> str:
-    """Content hash of the process-active Pallas tuning database.
-
-    Kernel dispatch consults the DB at trace time, so two exports under
-    different DBs can embed different schedules for the same program —
-    the digest makes that visible to the load-side match."""
-    try:
-        from paddle_tpu.pallas.tuning import get_db
-
-        entries = get_db().entries
-    except Exception:  # pragma: no cover - tuning import must not kill AOT
-        return "unavailable"
-    if not entries:
-        return "empty"
-    blob = json.dumps(entries, sort_keys=True, separators=(",", ":"))
-    return hashlib.sha256(blob.encode()).hexdigest()
 
 
 def flags_fingerprint() -> Dict[str, Any]:
@@ -204,7 +183,6 @@ class ArtifactWriter:
         doc = {
             "schema": SCHEMA,
             "env": environment_fingerprint(self.backend),
-            "tuning_db": tuning_db_digest(),
             "flags": flags_fingerprint(),
             "entries": sorted(self.entries.values(),
                               key=lambda e: e["id"]),
@@ -223,7 +201,7 @@ class ArtifactWriter:
 class ArtifactStore:
     """Read side of an artifact directory.
 
-    Store-level pins (schema, versions, device, tuning DB, flags) are
+    Store-level pins (schema, versions, device, flags) are
     validated once at open; a mismatch poisons the store — every lookup
     then counts its ``rejected_<reason>`` and falls back to JIT.
     Entry-level problems (unknown fingerprint, missing bucket, corrupt
@@ -270,10 +248,6 @@ class ArtifactStore:
             if env.get(k) != here[k]:
                 self._warn(f"{k} {env.get(k)!r} != running {here[k]!r}")
                 return "device"
-        if doc.get("tuning_db") != tuning_db_digest():
-            self._warn("tuning DB drifted since export (re-run "
-                       "`paddle compile` after `paddle tune`)")
-            return "tuning_db"
         if doc.get("flags") != flags_fingerprint():
             self._warn(f"compile-context flags {doc.get('flags')!r} != "
                        f"running {flags_fingerprint()!r}")

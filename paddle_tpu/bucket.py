@@ -1,11 +1,10 @@
-"""Shape bucketing shared by the kernel autotuner and the serving
-bucketer.
+"""The power-of-two shape ladder shared by the serving bucketer and the
+decode engine's prefill buckets.
 
-One tuned config should cover a *bucket* of shapes, not a single point,
-for the same reason the serving engine coalesces requests into
-power-of-two batch buckets (serving/batching.py): a static-shape
-compiler wants a small closed set of programs, and a tuning database
-wants a small closed set of keys.  Both layers round through THIS
+A static-shape compiler wants a small closed set of programs: the
+serving engine coalesces requests into power-of-two batch buckets
+(serving/batching.py) and the paged decoder pads a prompt to a
+power-of-two length bucket (decode/model.py).  Both round through THIS
 module so their ladders can never drift apart.
 
 Pure python, no jax/numpy imports — serving imports this at module
@@ -14,7 +13,7 @@ load.
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Tuple
 
 
 def bucket_dim(n: int) -> int:
@@ -25,11 +24,6 @@ def bucket_dim(n: int) -> int:
     if n <= 1:
         return 1
     return 1 << (n - 1).bit_length()
-
-
-def bucket_shape(shape: Sequence[int]) -> Tuple[int, ...]:
-    """Round every dimension up the power-of-two ladder."""
-    return tuple(bucket_dim(int(d)) for d in shape)
 
 
 def bucket_ladder(max_value: int) -> Tuple[int, ...]:

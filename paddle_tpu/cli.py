@@ -47,12 +47,6 @@ Subcommands:
       (static program verification — paddle_tpu/analysis; exits nonzero
        on error diagnostics.  --audit-registry checks op-metadata
        coverage against the checked-in baseline)
-  paddle tune [--kernel=softmax,flash_attention,...] [--shapes=RxC;...]
-              [--budget=N] [--reps=N] [--output=PATH] [--smoke]
-      (Pallas kernel autotuner — paddle_tpu/pallas/tuning: measure tile
-       configs over each kernel family's valid space and persist the
-       winners into the checked-in tuning database that dispatch
-       consults; --smoke runs tiny shapes in interpret mode)
   paddle stats [--json] [--run=script.py] [--file=telemetry.json]
                [--url=http://host:port] [--trace=out.json]
       (snapshot the telemetry registry — paddle_tpu/observability — as
@@ -562,12 +556,6 @@ def cmd_stats(argv):
     return 0
 
 
-def cmd_tune(argv):
-    from paddle_tpu.pallas.tuning.tune import main as tune_main
-
-    return tune_main(argv)
-
-
 COMMANDS = {
     "train": cmd_train,
     "version": cmd_version,
@@ -576,7 +564,6 @@ COMMANDS = {
     "serve": cmd_serve,
     "lint": cmd_lint,
     "stats": cmd_stats,
-    "tune": cmd_tune,
     "pserver": cmd_pserver,
     "master": cmd_master,
     "coord": cmd_coord,

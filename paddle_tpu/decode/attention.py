@@ -77,47 +77,6 @@ def storage_heads(num_heads: int, dtype) -> int:
     return -(-int(num_heads) // rows) * rows
 
 
-# The never-tuned guesses ISSUE 16 names: one slot per grid step, slot
-# dim megacore-parallel.  The tuning DB (pallas/tuning) overrides both:
-# ``slots_per_block`` > 1 amortizes grid-step overhead by sweeping sb
-# slots' pages inside one resident q/o block; ``slot_semantics`` picks
-# the megacore split for the slot dimension.
-DEFAULT_CONFIG = {"slots_per_block": 1, "slot_semantics": "parallel"}
-
-
-def block_ok(num_slots: int, num_heads: int, head_dim: int,
-             slots_per_block: int, kv_heads: int = None) -> bool:
-    """Validity of an explicit slot block at an actual shape: grid
-    divisibility plus the (sb, H, D) f32 scratch staying tiny.  The
-    grouped kernel (``kv_heads`` fewer than ``num_heads``) takes one
-    slot a grid step and nothing else."""
-    sb = slots_per_block
-    if kv_heads not in (None, num_heads) and sb != 1:
-        return False
-    return (1 <= sb <= num_slots and num_slots % sb == 0
-            and sb * num_heads * (head_dim + 2) * 4 <= 2 * 1024 * 1024)
-
-
-def _resolve_config(S, P, page, H, D, dtype, slots_per_block=None,
-                    slot_semantics=None):
-    if slots_per_block is None or slot_semantics is None:
-        from paddle_tpu.pallas import tuning
-
-        cfg = tuning.lookup("ragged_paged_attention", (S, P, page, H, D),
-                            dtype) or {}
-        if slots_per_block is None:
-            slots_per_block = cfg.get("slots_per_block")
-        if slot_semantics is None:
-            slot_semantics = cfg.get("slot_semantics")
-    sb = slots_per_block or DEFAULT_CONFIG["slots_per_block"]
-    if not block_ok(S, H, D, sb):
-        sb = DEFAULT_CONFIG["slots_per_block"]
-    sem = slot_semantics
-    if sem not in ("parallel", "arbitrary"):
-        sem = DEFAULT_CONFIG["slot_semantics"]
-    return sb, sem
-
-
 # ---------------------------------------------------------------------------
 # reference (jnp): the oracle + off-TPU fallback
 # ---------------------------------------------------------------------------
@@ -212,100 +171,24 @@ def _rpa_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _rpa_kernel_blocked(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, scale, page, npp, sb):
-    """Slot-blocked variant: grid ``(S // sb, sb * P)``.  The inner
-    dimension sweeps all sb * P (slot, page) pairs of one block while
-    the q and output blocks stay resident; each slot owns one row of
-    the (sb, H, *) scratch.  With sb == 1 this is the same schedule as
-    ``_rpa_kernel`` — that case keeps the original kernel."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    r = j // npp                        # slot within this block
-    p = j % npp                         # page index for that slot
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[pl.ds(r, 1)] = jnp.full((1,) + m_scr.shape[1:], _NEG_INF,
-                                      m_scr.dtype)
-        l_scr[pl.ds(r, 1)] = jnp.zeros((1,) + l_scr.shape[1:], l_scr.dtype)
-        acc_scr[pl.ds(r, 1)] = jnp.zeros((1,) + acc_scr.shape[1:],
-                                         acc_scr.dtype)
-
-    seq_len = lens_ref[i * sb + r]
-
-    @pl.when(p * page < seq_len)
-    def _page():
-        m_new, l_new, acc_new = _page_update(
-            q_ref[pl.ds(r, 1)][0], k_ref[0], v_ref[0],
-            m_scr[pl.ds(r, 1)][0], l_scr[pl.ds(r, 1)][0],
-            acc_scr[pl.ds(r, 1)][0], p * page, seq_len, scale)
-        m_scr[pl.ds(r, 1)] = m_new[None]
-        l_scr[pl.ds(r, 1)] = l_new[None]
-        acc_scr[pl.ds(r, 1)] = acc_new[None]
-
-    @pl.when(p == npp - 1)
-    def _finish():
-        l = l_scr[pl.ds(r, 1)][0]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[pl.ds(r, 1)] = (acc_scr[pl.ds(r, 1)][0] / l)[None].astype(
-            o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "scale", "interpret", "slots_per_block", "slot_semantics"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
-                           scale=None, interpret: bool = False,
-                           slots_per_block: int = None,
-                           slot_semantics: str = None):
+                           scale=None, interpret: bool = False):
     """Pallas ragged paged-attention decode step.
 
     Same contract as the reference: q (S, H, D), pools (N, page, H, D),
-    page_tables (S, P), lens (S,) -> (S, H, D).  ``slots_per_block`` /
-    ``slot_semantics`` default from the tuning DB (missing entry = the
-    historical single-slot parallel schedule).
+    page_tables (S, P), lens (S,) -> (S, H, D).  One slot a grid step,
+    the slot dimension ``parallel``: sweeping 2, 4 or 8 slots' pages
+    under one resident q/o block, and ``arbitrary`` for it, read the
+    same or slower at the cells' shapes (PERF.md §6, PR 44).
     """
     S, H, D = q.shape
     page = k_pages.shape[1]
     P = page_tables.shape[1]
     if scale is None:
         scale = D ** -0.5
-    sb, sem = _resolve_config(S, P, page, H, D, q.dtype.name,
-                              slots_per_block, slot_semantics)
     ptab = page_tables.astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
-
-    if sb > 1:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(S // sb, sb * P),
-            in_specs=[
-                pl.BlockSpec((sb, H, D), lambda i, j, pt, ln: (i, 0, 0)),
-                pl.BlockSpec((1, page, H, D),
-                             lambda i, j, pt, ln:
-                             (pt[i * sb + j // P, j % P], 0, 0, 0)),
-                pl.BlockSpec((1, page, H, D),
-                             lambda i, j, pt, ln:
-                             (pt[i * sb + j // P, j % P], 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((sb, H, D),
-                                   lambda i, j, pt, ln: (i, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((sb, H, 1), _F32),
-                pltpu.VMEM((sb, H, 1), _F32),
-                pltpu.VMEM((sb, H, D), _F32),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_rpa_kernel_blocked, scale=scale, page=page,
-                              npp=P, sb=sb),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=(sem, "arbitrary")),
-            name="ragged_paged_attention_blocked",
-            interpret=interpret,
-        )(ptab, lens32, q, k_pages, v_pages)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # page table + lens land in SMEM
@@ -331,7 +214,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(sem, "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         name="ragged_paged_attention",
         interpret=interpret,
     )(ptab, lens32, q, k_pages, v_pages)

@@ -19,7 +19,7 @@ their own, ``extra``, beside the pages of its attention layers:
 ``models/granite_hybrid.py``).
 
 Prefill is ONE jitted program per length *bucket* (the shared pow2
-ladder of ``pallas/tuning/bucket.py``, from 64 up to the sequence
+ladder of ``paddle_tpu/bucket.py``, from 64 up to the sequence
 capacity).  The prompt is padded on the right to its bucket, the
 program runs the dense causal forward (``dense_prefill_attention`` —
 the flash-attention path when the bucket's shape fits), scatters the
@@ -66,6 +66,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.bucket import bucket_dim
 from paddle_tpu.decode.attention import (
     dense_prefill_attention,
     paged_attention,
@@ -74,7 +75,6 @@ from paddle_tpu.decode.attention import (
 from paddle_tpu.decode.paged_kv import PageAllocator, PoolsLost
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.observability.events import phase
-from paddle_tpu.pallas.tuning.bucket import bucket_dim
 
 _M_PREFILL_TOKENS = _metrics.counter(
     "decode_prefill_tokens_total",

@@ -4,11 +4,11 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Eight kernel families, fourteen ``pl.pallas_call``s: the fused
-whole-sequence LSTM (``lstm.py``, 2), the row softmax (``softmax.py``,
+Eight kernel families, twelve ``pl.pallas_call``s: the fused
+whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
-ragged paged attention (``decode/attention.py``, 3 kernels under 4
+ragged paged attention (``decode/attention.py``, 2 kernels under 3
 names: the chunk kernel is also called on grouped heads, Hq query heads
 on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
@@ -35,6 +35,14 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   at toy shapes with ``enable(True, interpret=True)``).
 - ``off``: the jnp/XLA lowerings only (the reference ``chip_smoke.py``
   compares each kernel against).
+
+A kernel's tile is an argument or a constant of its own module, set
+from or held against a sweep on the chip that ``PERF.md`` §6 records
+(the step kernels' ``BLOCK_BYTES``, the chunked rule's ``CHUNK`` and
+``HEAD_BLOCK``, flash attention's ``BLOCK_PREF``, which PR 44's sweep
+found beaten; the softmax's ``BLOCK_ROWS`` is, like its threshold, from
+an earlier setup): no kernel entry point consults anything else, and a
+shape that wants another tile gets a rule here.
 
 Off a TPU the kernels run under ``interpret=True`` for numerics tests.
 On a TPU backend a kernel that dispatches runs compiled or raises —

@@ -40,16 +40,18 @@ _F32 = jnp.float32
 _NEG_INF = -1e30  # large-but-finite: avoids inf-inf NaNs in corrections
 
 
-def _pick_block(s: int, pref: int = 512) -> int:
-    b = min(pref, s)
+# blk_q / blk_k default to the largest power-of-two divisor of S up to
+# this.  Not the best on this chip: at the LM training cell's shape the
+# sweep read (1024, 1024) 30% under it forward, 17% forward + backward
+# (PERF.md §6, PR 44; ROADMAP S3 has the claim to make with it)
+BLOCK_PREF = 512
+
+
+def _pick_block(s: int) -> int:
+    b = min(BLOCK_PREF, s)
     while b > 8 and s % b != 0:
         b //= 2
     return b if s % b == 0 else 0
-
-
-# the guessed block preference the tuning DB (pallas/tuning) overrides:
-# blk_q/blk_k default to _pick_block(S, 512)
-DEFAULT_CONFIG = {"blk_pref": 512}
 
 
 def _blocks_ok(S: int, Sk: int, D: int, blk_q: int, blk_k: int) -> bool:
@@ -62,15 +64,9 @@ def _blocks_ok(S: int, Sk: int, D: int, blk_q: int, blk_k: int) -> bool:
     return resident <= 12 * 1024 * 1024
 
 
-def _resolve_blocks(BH, S, Sk, D, dtype, blk_q=None, blk_k=None):
-    """Tuned (blk_q, blk_k) from the DB when valid at this shape, else
-    the historical ``_pick_block`` preference."""
-    if blk_q is None or blk_k is None:
-        from paddle_tpu.pallas import tuning
-
-        cfg = tuning.lookup("flash_attention", (BH, S, Sk, D), dtype) or {}
-        blk_q = blk_q or cfg.get("blk_q")
-        blk_k = blk_k or cfg.get("blk_k")
+def _resolve_blocks(S, Sk, D, blk_q=None, blk_k=None):
+    """An explicit (blk_q, blk_k) where it is valid at this shape, else
+    the ``_pick_block`` preference."""
     blk_q = blk_q or _pick_block(S)
     blk_k = blk_k or _pick_block(Sk)
     if not _blocks_ok(S, Sk, D, blk_q, blk_k):
@@ -146,8 +142,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, scale: float,
                     blk_k: int = None):
     BH, S, D = q.shape
     Sk = k.shape[1]
-    blk_q, blk_k = _resolve_blocks(BH, S, Sk, D, q.dtype.name,
-                                   blk_q, blk_k)
+    blk_q, blk_k = _resolve_blocks(S, Sk, D, blk_q, blk_k)
     nq, nk = S // blk_q, Sk // blk_k
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -278,9 +273,10 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
                     interpret: bool = False, dlse=None):
     BH, S, D = q.shape
     Sk = k.shape[1]
-    # the same resolved blocks as the forward: lse is saved reshaped to
-    # (BH, nq, blk_q), so fwd and bwd must agree on blk_q
-    blk_q, blk_k = _resolve_blocks(BH, S, Sk, D, q.dtype.name)
+    # the default blocks, as the forward it differentiates ran with
+    # (only ``_flash_fwd_impl`` takes a pair, and nothing differentiates
+    # that); lse arrives flat (BH, S) and is reshaped to these
+    blk_q, blk_k = _resolve_blocks(S, Sk, D)
     nq, nk = S // blk_q, Sk // blk_k
     delta = jnp.sum(do.astype(_F32) * o.astype(_F32), axis=-1)  # (BH, S)
     if dlse is not None:

@@ -30,7 +30,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-
 def _lstm_kernel(xp_ref, w_ref, b_ref, h0_ref, c0_ref,
                  hs_ref, cs_ref, gates_ref, h_s, c_s):
     t = pl.program_id(0)
@@ -40,12 +39,6 @@ def _lstm_kernel(xp_ref, w_ref, b_ref, h0_ref, c0_ref,
         h_s[:] = h0_ref[:].astype(jnp.float32)
         c_s[:] = c0_ref[:].astype(jnp.float32)
 
-    _lstm_step_body(xp_ref, w_ref, b_ref, hs_ref, cs_ref, gates_ref,
-                    h_s, c_s)
-
-
-def _lstm_step_body(xp_ref, w_ref, b_ref, hs_ref, cs_ref, gates_ref,
-                    h_s, c_s):
     xt = xp_ref[0].astype(jnp.float32)          # (B, 4H)
     gates = xt + jnp.dot(h_s[:].astype(w_ref.dtype), w_ref[:],
                          preferred_element_type=jnp.float32)
@@ -72,79 +65,10 @@ def fits(b, h, vmem_budget=10 * 1024 * 1024) -> bool:
     return resident <= vmem_budget
 
 
-def block_ok(b: int, h: int, bb: int) -> bool:
-    """Validity of an explicit batch block: grid divisibility, sublane
-    alignment, and the per-block working set under the VMEM budget."""
-    return bb >= 8 and bb % 8 == 0 and b % bb == 0 and fits(bb, h)
-
-
-def _resolve_block_b(t, b, h, dtype):
-    """Tuned batch block from the tuning DB (``None`` = the historical
-    whole-batch grid, which stays the default on a miss)."""
-    from paddle_tpu.pallas import tuning
-
-    cfg = tuning.lookup("lstm", (t, b, h), dtype) or {}
-    bb = cfg.get("block_b")
-    if bb and bb != b and block_ok(b, h, bb):
-        return bb
-    return None
-
-
-def _lstm_kernel_blocked(xp_ref, w_ref, b_ref, h0_ref, c0_ref,
-                         hs_ref, cs_ref, gates_ref, h_s, c_s):
-    """The same fused step on a ``(B/bb, T)`` grid: each batch block
-    sweeps the whole sequence with its own resident (h, c) scratch.
-    With bb == B this is exactly the ``(T,)`` kernel; smaller blocks
-    trade x-block residency for state/gates VMEM headroom."""
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        h_s[:] = h0_ref[:].astype(jnp.float32)
-        c_s[:] = c0_ref[:].astype(jnp.float32)
-
-    _lstm_step_body(xp_ref, w_ref, b_ref, hs_ref, cs_ref, gates_ref,
-                    h_s, c_s)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
-def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False,
-                   block_b: int = None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _lstm_seq_impl(xproj, w, bias, h0, c0, interpret: bool = False):
     T, B, H4 = xproj.shape
     H = H4 // 4
-    if block_b is None:
-        block_b = _resolve_block_b(T, B, H, xproj.dtype.name)
-    if block_b is not None and not block_ok(B, H, block_b):
-        block_b = None
-    if block_b is not None and block_b != B:
-        bb = block_b
-        return pl.pallas_call(
-            _lstm_kernel_blocked,
-            grid=(B // bb, T),
-            in_specs=[
-                pl.BlockSpec((1, bb, H4), lambda i, t: (t, i, 0)),
-                pl.BlockSpec((H, H4), lambda i, t: (0, 0)),
-                pl.BlockSpec((1, H4), lambda i, t: (0, 0)),
-                pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
-                pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bb, H), lambda i, t: (t, i, 0)),
-                pl.BlockSpec((1, bb, H), lambda i, t: (t, i, 0)),
-                pl.BlockSpec((1, bb, H4), lambda i, t: (t, i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((T, B, H), xproj.dtype),
-                jax.ShapeDtypeStruct((T, B, H), xproj.dtype),
-                jax.ShapeDtypeStruct((T, B, H4), xproj.dtype),
-            ],
-            scratch_shapes=[pltpu.VMEM((bb, H), jnp.float32),
-                            pltpu.VMEM((bb, H), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary")),
-            name="lstm_fused_blocked",
-            interpret=interpret,
-        )(xproj, w, bias.reshape(1, H4), h0, c0)
     return pl.pallas_call(
         _lstm_kernel,
         grid=(T,),

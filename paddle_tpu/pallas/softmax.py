@@ -18,35 +18,31 @@ def _softmax_kernel(x_ref, o_ref):
     o_ref[:] = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
-# the guessed row block the tuning DB (pallas/tuning) overrides
-DEFAULT_CONFIG = {"block_rows": 256}
+# rows a grid step; from an earlier setup, not re-measured on this chip
+BLOCK_ROWS = 256
 
 
 def fits(rows, cols, block_rows=None, itemsize=4) -> bool:
     # VMEM budget: in block + out block + fp32 temps must coexist in
     # ~16MB/core; cap a block's footprint at 2MB so 4-5 live copies fit
-    block_rows = block_rows or DEFAULT_CONFIG["block_rows"]
+    block_rows = block_rows or BLOCK_ROWS
     block_bytes = block_rows * cols * max(itemsize, 4)
     return (rows % block_rows == 0 and cols % 128 == 0
             and block_bytes <= 2 * 1024 * 1024)
 
 
-def _resolve_block_rows(rows, cols, dtype, block_rows):
-    if block_rows is not None:
+def _resolve_block_rows(rows, cols, block_rows):
+    """An explicit block where it fits this shape, else the default."""
+    if block_rows is not None and fits(rows, cols, block_rows):
         return block_rows
-    from paddle_tpu.pallas import tuning
-
-    cfg = tuning.lookup("softmax", (rows, cols), dtype) or {}
-    got = cfg.get("block_rows", DEFAULT_CONFIG["block_rows"])
-    if cfg and not fits(rows, cols, got):
-        got = DEFAULT_CONFIG["block_rows"]  # bucket-valid != shape-valid
-    return got
+    return BLOCK_ROWS
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def softmax(x, block_rows: int = None, interpret: bool = False):
-    """Unset ``block_rows`` resolves through the tuning DB, falling
-    back to ``DEFAULT_CONFIG`` — an explicit arg always wins."""
+    """Row softmax of a 2-D ``x``, ``block_rows`` rows a grid step
+    (``BLOCK_ROWS`` when unset or when the explicit block does not fit
+    the shape)."""
     return _softmax_impl(x, block_rows, interpret)
 
 
@@ -67,7 +63,7 @@ softmax.defvjp(_softmax_fwd, _softmax_bwd)
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _softmax_impl(x, block_rows: int = None, interpret: bool = False):
     rows, cols = x.shape
-    block_rows = _resolve_block_rows(rows, cols, x.dtype.name, block_rows)
+    block_rows = _resolve_block_rows(rows, cols, block_rows)
     assert fits(rows, cols, block_rows), x.shape
     return pl.pallas_call(
         _softmax_kernel,

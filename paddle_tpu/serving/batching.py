@@ -61,8 +61,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu import bucket as _bucket
 from paddle_tpu.observability import metrics as _metrics
-from paddle_tpu.pallas.tuning import bucket as _bucket
 
 _M_QUEUE_WAIT = _metrics.histogram(
     "serving_queue_wait_seconds",
@@ -249,9 +249,8 @@ class TenantRegistry:
 def next_bucket(rows: int) -> int:
     """Smallest power-of-two >= rows (the padded batch dim).
 
-    Delegates to the ladder shared with the kernel autotuner
-    (pallas/tuning/bucket.py) so serving batch buckets and tuning-DB
-    shape buckets can never drift apart.
+    Delegates to the ladder shared with the decode engine's prefill
+    buckets (paddle_tpu/bucket.py) so the two can never drift apart.
     """
     return _bucket.bucket_dim(rows)
 

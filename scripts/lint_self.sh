@@ -48,12 +48,11 @@ echo "== paddle stats: telemetry registry smoke"
 $PADDLE stats --json > /dev/null
 $PADDLE stats > /dev/null
 
-echo "== ruff: analysis + observability + distributed fault-tolerance + serving + decode + tuning + aot"
+echo "== ruff: analysis + observability + distributed fault-tolerance + serving + decode + aot"
 if command -v ruff >/dev/null 2>&1; then
     ruff check paddle_tpu/analysis/ paddle_tpu/observability/ \
         paddle_tpu/distributed/elastic.py paddle_tpu/distributed/retry.py \
-        paddle_tpu/serving/ paddle_tpu/decode/ \
-        paddle_tpu/pallas/tuning/ paddle_tpu/aot/ \
+        paddle_tpu/serving/ paddle_tpu/decode/ paddle_tpu/aot/ \
         benchmark/serving_bench.py benchmark/decode_bench.py \
         benchmark/serving_chaos_bench.py benchmark/coldstart_bench.py
 else
@@ -122,19 +121,6 @@ assert doc["spec"]["tokens_identical"], \
     "speculative decode is not token-identical to greedy"
 assert doc["spec"]["speculative"]["proposed"] > 0, \
     "spec smoke proposed no draft tokens"
-EOF
-
-echo "== paddle tune: smoke (autotuner enumerate/measure/persist/dispatch)"
-$PADDLE tune --kernel=softmax --smoke --output=/tmp/tune_smoke_db.json \
-    > /dev/null
-python - <<'EOF'
-import json
-db = json.load(open("/tmp/tune_smoke_db.json"))
-assert db["schema"] == "paddle_tpu.tuning_db.v1", db["schema"]
-assert db["entries"], "tune smoke recorded no entries"
-art = json.load(open("/tmp/tune_smoke_db.telemetry.json"))
-assert art["schema"] == "paddle_tpu.tune.v1", art["schema"]
-assert art["results"], "tune smoke recorded no results"
 EOF
 
 echo "lint_self OK"

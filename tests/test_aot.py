@@ -3,7 +3,7 @@ restored under the loaded executable, and the rejection taxonomy.
 
 The contract under test: an artifact-booted executor is **bit-identical**
 to JIT and keeps buffer donation active; ANY manifest mismatch (version
-skew, model drift, tuning-DB drift, corrupt payload) is a loud JIT
+skew, model drift, corrupt payload) is a loud JIT
 fallback — the right `rejected_*` reason lands in
 ``aot_load_total{result}`` / ``store.results`` and the answer is still
 bit-identical, never wrong.
@@ -272,14 +272,21 @@ def test_fingerprint_drift_rejected(artifact_dir):
     assert np.array_equal(outs[0], ref[0])
 
 
-def test_tuning_db_drift_rejected(artifact_dir):
+def test_stale_tuning_db_field_ignored(artifact_dir):
+    """A manifest exported while the tuning DB's digest was pinned
+    still loads: the field is no pin any more."""
     art, ref = artifact_dir
 
-    def drift(doc):
+    def stale(doc):
         doc["tuning_db"] = "deadbeef" * 8
 
-    _edit_manifest(art, drift)
-    _assert_jit_fallback(ArtifactStore(art), "rejected_tuning_db", ref)
+    _edit_manifest(art, stale)
+    store = ArtifactStore(art)
+    assert store.poisoned is None
+    exe, outs = _run_steps(_program(), store=store, steps=2)
+    assert exe.compile_counts == {"jit": 0, "aot": 1}
+    assert store.results == {"loaded": 1}
+    assert np.array_equal(outs[0], ref[0])
 
 
 def test_truncated_payload_rejected(artifact_dir):

@@ -150,27 +150,28 @@ def _assert_experts_read_where_they_lie(text, experts, d, f):
 
 
 # (slots, heads, head_dim, page, pool pages, pages/seq, dtype): a real
-# decode batch, and the /generate model chip_smoke.py serves
+# decode batch, the /generate model chip_smoke.py serves, and the steps
+# of the two cells that run this kernel (Cerebras f32, OLMoE bf16)
 RPA_REAL = (64, 16, 128, 16, 2048, 32, jnp.bfloat16)
 RPA_TOY = (4, 4, 8, 8, 64, 8, jnp.float32)
+RPA_CEREBRAS = (16, 16, 128, 32, 641, 40, jnp.float32)
+RPA_OLMOE = (32, 16, 128, 32, 2049, 64, jnp.bfloat16)
 
 
-@pytest.mark.parametrize("slots_per_block", [1, 4], ids=["plain", "blocked"])
-@pytest.mark.parametrize("shape", [RPA_REAL, RPA_TOY], ids=["real", "toy"])
-def test_ragged_paged_attention_compiles(one_chip, shape, slots_per_block):
+@pytest.mark.parametrize(
+    "shape", [RPA_REAL, RPA_TOY, RPA_CEREBRAS, RPA_OLMOE],
+    ids=["real", "toy", "cerebras-step", "olmoe-step"])
+def test_ragged_paged_attention_compiles(one_chip, shape):
     from paddle_tpu.decode import attention as A
 
     S, H, D, page, N, P, dt = shape
     text = _compiled_text(
-        lambda q, k, v, pt, ln: A.ragged_paged_attention(
-            q, k, v, pt, ln, slots_per_block=slots_per_block,
-            slot_semantics="parallel"),
+        A.ragged_paged_attention,
         one_chip, ((S, H, D), dt), ((N, page, H, D), dt),
         ((N, page, H, D), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
     assert MARKER in text
-    want = ("ragged_paged_attention_blocked/" if slots_per_block > 1
-            else "ragged_paged_attention/")
-    assert all(want in op for op in _kernel_op_names(text))
+    assert all("ragged_paged_attention/" in op
+               for op in _kernel_op_names(text))
 
 
 def test_ragged_paged_attention_chunk_compiles(one_chip):

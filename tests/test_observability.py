@@ -865,10 +865,9 @@ def test_every_pallas_call_has_a_name():
                     else:
                         unnamed.append(f"{path}:{node.lineno}")
     assert not unnamed
-    assert len(names) == 14 and len(set(names)) == len(names)
+    assert len(names) == 12 and len(set(names)) == len(names)
     assert {"flash_attention_fwd", "flash_attention_bwd_dq",
             "flash_attention_bwd_dkv", "ragged_paged_attention",
-            "ragged_paged_attention_blocked",
             "ragged_paged_attention_chunk",
             "ragged_paged_attention_gqa",
             "gated_delta_step", "ssd_step", "conv_step",

@@ -214,7 +214,7 @@ def test_expert_path_is_one_interval_of_the_row_count():
     their ladders over the threshold the grouped GEMM, and so does a
     step of so few slots that most experts get no row; in the row count
     the dense pass has one interval."""
-    from paddle_tpu.pallas.tuning.bucket import bucket_ladder
+    from paddle_tpu.bucket import bucket_ladder
 
     cells = [(32, 8, 64), (64, 8, 128)]             # (slots, top-k, E)
     ladder = [b for b in bucket_ladder(4096) + (4608,) if b >= 128]

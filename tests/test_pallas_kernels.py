@@ -12,11 +12,19 @@ from paddle_tpu import pallas as pk
 from paddle_tpu.pallas.softmax import softmax
 
 
-def test_softmax_kernel_numerics(rng):
+@pytest.mark.parametrize("block_rows", [None, 64, 192],
+                         ids=["default", "rows64", "invalid-falls-back"])
+def test_softmax_kernel_numerics(rng, block_rows):
+    """The tile is the default or the explicit argument; a block that
+    does not divide the rows (192 of 512) falls back to the default."""
+    from paddle_tpu.pallas import softmax as sm
+
     x = rng.randn(512, 256).astype("float32")
-    got = np.asarray(softmax(jnp.asarray(x), interpret=True))
+    got = np.asarray(softmax(jnp.asarray(x), block_rows, True))
     e = np.exp(x - x.max(-1, keepdims=True))
     np.testing.assert_allclose(got, e / e.sum(-1, keepdims=True), atol=1e-6)
+    want = {None: sm.BLOCK_ROWS, 64: 64, 192: sm.BLOCK_ROWS}[block_rows]
+    assert sm._resolve_block_rows(512, 256, block_rows) == want
 
 
 def test_op_lowering_uses_pallas_and_trains(rng):
@@ -149,16 +157,26 @@ def _attn_ref(q, k, v, causal):
     return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
 
 
+@pytest.mark.parametrize("blocks", [None, (128, 128), (192, 128)],
+                         ids=["default", "b128", "invalid-falls-back"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_fwd(rng, causal):
-    from paddle_tpu.pallas.flash_attention import flash_attention
+def test_flash_attention_fwd(rng, causal, blocks):
+    """The blocks are ``_pick_block``'s or the explicit pair; a pair
+    that does not divide S (192 of 256) falls back to the default."""
+    from paddle_tpu.pallas import flash_attention as fa
 
     q, k, v = (jnp.asarray(rng.randn(2, 256, 64).astype("float32"))
                for _ in range(3))
     with jax.default_matmul_precision("highest"):
-        out = flash_attention(q, k, v, causal, None, True)
+        if blocks is None:
+            out = fa.flash_attention(q, k, v, causal, None, True)
+        else:
+            out, _ = fa._flash_fwd_impl(q, k, v, causal, 64 ** -0.5, True,
+                                        *blocks)
         ref = _attn_ref(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    want = (128, 128) if blocks == (128, 128) else (256, 256)
+    assert fa._resolve_blocks(256, 256, 64, *(blocks or ())) == want
 
 
 @pytest.mark.parametrize("causal", [False, True])
