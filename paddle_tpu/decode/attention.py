@@ -60,7 +60,15 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     beside 1 GB of K/V).  A model with heads of 64 stores two of them
     side by side in a row of 128 lanes and runs the grouped kernel on
     that (``models/granite_hybrid.py:heads_a_row``): read and written
-    in place, nothing padded."""
+    in place, nothing padded.
+
+    Nor do these kernels take a latent layer's pages: one row a token
+    whose 576 numbers are the key and whose first 512 are the value, K
+    and V from one buffer at ``D > 256``.  That is
+    ``pallas/latent_attention.py`` (PR 45), and its layout probe found
+    the same as the two above: a pool of 576 lanes is laid out at 640
+    and the kernel's page copy refused, so the rows are stored at 640
+    (``models/kanana_mla.py:row_width``)."""
     ok = (page_size % 8 == 0 and head_dim % 8 == 0
           and head_dim <= 256 and num_heads >= 1)
     if kv_heads in (None, num_heads):

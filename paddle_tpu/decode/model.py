@@ -13,6 +13,9 @@ block as a static argument.  A block also says how a layer mixes
 tokens and what it keeps of them (``PageRunCache``: attention, every
 layer every row, in one page run a sequence; K-EXAONE's window layers
 keep a bounded ring each, beside a full layer, in the same pool;
+a latent layer keeps ONE compressed row a token in the place of K and V
+heads, the first pool alone, and defines both mixers itself:
+``models/kanana_mla.py``;
 a hybrid's recurrent layers keep a state a sequence in buffers of
 their own, ``extra``, beside the pages of its attention layers:
 ``decode/state_entry.py``, under ``models/olmo_hybrid.py`` and

@@ -78,12 +78,15 @@ _M_CACHE_ROWS = _metrics.gauge(
     "K/V rows resident for the seated sequences, summed over layers, by "
     "kind of cache: `full` = layers that keep every row, `window` = "
     "layers on bounded rings, `state` = recurrent layers' entries, one "
-    "a sequence a layer whatever its length (the model's `cache_rows`)")
+    "a sequence a layer whatever its length, `latent` = latent layers' "
+    "rows, one compressed row a token a layer in the place of K and V "
+    "heads (the model's `cache_rows`)")
 _M_CACHE_BYTES = _metrics.gauge(
     "decode_cache_bytes",
     "bytes resident for the seated sequences by kind of cache (the "
     "model's `cache_bytes`): `full` = the K/V rows of layers that keep "
-    "every row, `state` = the recurrent layers' state entries")
+    "every row, `state` = the recurrent layers' state entries, `latent` "
+    "= the latent rows as stored")
 _M_STEPS = _metrics.counter(
     "decode_steps_total", "fixed-shape decode steps dispatched")
 _M_SLOT_STEPS = _metrics.counter(

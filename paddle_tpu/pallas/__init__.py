@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Eight kernel families, twelve ``pl.pallas_call``s: the fused
+Nine kernel families, thirteen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -14,9 +14,17 @@ on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
 (``gated_delta.py``, 1), Mamba-2's on the same layout
 (``ssd_step.py``, 1), the depthwise conv before either, over the
-same entries' kept rows (``conv_step.py``, 1), and the gated delta rule
+same entries' kept rows (``conv_step.py``, 1), the gated delta rule
 chunked over a prefill bucket's rows, the state in VMEM from chunk to
-chunk (``gated_delta_chunked.py``, 1).
+chunk (``gated_delta_chunked.py``, 1), and absorbed latent attention
+over paged latent rows, every head on the one stored row, which is key
+and value both (``latent_attention.py``, 1: a grid step a slot, the
+slot's live pages walked by a dynamic loop and copied by hand through a
+double buffer, where the ragged kernels take a grid step a table
+column).  The latent pool's rows are stored at 640 lanes for the 576
+the algorithm needs: at 576 the chip's compiler lays the pool out at
+640 anyway and refuses the kernel's page copy ("slice shape must be
+aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -26,7 +34,7 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
   the gated delta, SSD and conv steps, the chunked gated delta rule of
-  a hybrid's prefill) have no threshold.  All three
+  a hybrid's prefill, latent paged attention) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
   LSTM's and the softmax's thresholds are not re-measured on this chip
@@ -196,6 +204,20 @@ def use_conv_step(pool_dtype, entry_shape, row_dtype, taps: int,
 
     return dispatch("conv_step", policy(
         _c.fits(pool_dtype, entry_shape, row_dtype, taps, channels), True))
+
+
+def use_latent_paged_attention(pool_dtype, page_size: int, rows: int,
+                               width: int, v_width: int) -> bool:
+    """A latent layer's decode step (and a verify chunk of a few rows)
+    attends over the slots' pages of latent rows by the kernel wherever
+    ``fits()`` holds (rows of whole 128-lane tiles, a q block of ``rows``
+    = chunk rows x heads that stays resident), by the decode kernels'
+    rule: no threshold; else gathered in XLA
+    (``latent_paged_attention_reference``)."""
+    from paddle_tpu.pallas import latent_attention as _l
+
+    return dispatch("latent_paged_attention", policy(
+        _l.fits(pool_dtype, page_size, rows, width, v_width), True))
 
 
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402

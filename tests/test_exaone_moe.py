@@ -174,16 +174,22 @@ BOTH_PATHS = pytest.mark.parametrize(
 
 
 @BOTH_PATHS
-def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(R):
+@pytest.mark.parametrize("k, scale, shared", [(3, 2.5, 1), (6, 2.448, 2)],
+                         ids=["top3_one_shared", "top6_two_shared"])
+def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(
+        R, k, scale, shared):
     """The share test: each of 8 chips routes over all 16 experts and
     computes its own 2; their routed parts plus the shared expert once
-    add up to the reference's whole layer (``held`` = all)."""
+    add up to the reference's whole layer (``held`` = all).  Two shapes
+    of the one layer: K-EXAONE's (3 of 16 at 2.5, a shared expert of
+    the routed width) and the latent model's (``models/kanana_mla.py``:
+    6 at 2.448, the shared expert two routed widths wide)."""
     rng = np.random.RandomState(11)
     m, wr, b, wg, wu, wd = _toy_layer(rng, R=R)
-    d, f = m.shape[1], wg.shape[2]
+    d, f = m.shape[1], wg.shape[2] * shared
     ws = [rng.randn(*s).astype(np.float32) * 0.3
           for s in ((d, f), (d, f), (f, d))]
-    k, scale, C = 3, 2.5, 2
+    C = 2
     lp = {"wr": wr, "b": b, "w_gate": wg, "w_up": wu, "w_down": wd,
           "ws_gate": ws[0], "ws_up": ws[1], "ws_down": ws[2]}
     whole, mask = ref.feed_forward(

@@ -865,13 +865,47 @@ def test_every_pallas_call_has_a_name():
                     else:
                         unnamed.append(f"{path}:{node.lineno}")
     assert not unnamed
-    assert len(names) == 12 and len(set(names)) == len(names)
+    assert len(names) == 13 and len(set(names)) == len(names)
     assert {"flash_attention_fwd", "flash_attention_bwd_dq",
             "flash_attention_bwd_dkv", "ragged_paged_attention",
             "ragged_paged_attention_chunk",
             "ragged_paged_attention_gqa",
             "gated_delta_step", "ssd_step", "conv_step",
-            "gated_delta_chunked"} <= set(names)
+            "gated_delta_chunked", "latent_paged_attention"} <= set(names)
+
+
+def test_the_latent_layers_names():
+    """What the latent layer adds to the tracing (PR 45): the kernel's
+    dispatch under its own name, the causal-pairs counter the prefill
+    share reads, the ``latent`` kind of the cache gauges, and the four
+    device-side scopes, spelled as the readers of ``perf/harness/
+    latent.py`` spell them."""
+    import inspect
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.models import kanana_mla as km
+    from perf.harness import latent
+
+    pk.use_latent_paged_attention("bfloat16", 128, 32, 640, 512)
+    fam = obs.metrics.REGISTRY.get("pallas_dispatch_total")
+    assert any(v["labels"].get("kernel") == "latent_paged_attention"
+               for v in fam.snapshot()["values"])
+    assert obs.metrics.REGISTRY.get(latent.PAIRS_COUNTER) is not None
+    model = km.KananaMlaLM(
+        vocab=32, d_model=16, num_heads=2, num_layers=2,
+        qk_nope_head_dim=4, qk_rope_head_dim=4, v_head_dim=4,
+        kv_lora_rank=8, dense_width=16, expert_width=8,
+        num_experts_published=4, held_experts=(0, 2), experts_per_tok=2,
+        max_len=16, num_pages=4, page_size=8, pages_per_seq=2,
+        dtype="float32")
+    assert list(model.cache_rows([3])) == list(model.cache_bytes([3])) \
+        == ["latent"]
+    src = inspect.getsource(km)
+    for scope in ("attn_latent", "attn_latent_down", "attn_latent_absorb",
+                  "attn_latent_expand"):
+        assert f'jax.named_scope("{scope}")' in src, scope
+    assert latent.ANY_SCOPE == "/attn_latent/"
+    assert latent.DECODE_KERNEL == "latent_paged_attention"
 
 
 def test_the_program_writes_spans_through_span_only():

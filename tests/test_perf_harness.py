@@ -6,7 +6,8 @@ convoy share, the idle gaps' names, the traffic files' co-prime
 budgets, the load generator's token-clocked starts (PR 36's cases,
 imported and not copied), the expert layer's prefill readers (PR 38's
 cases, imported too), the state-space layer's readers and the new
-cell's entries (PR 41's), and the readers of the decode tick's own
+cell's entries (PR 41's), the latent layer's readers and its cell's
+entries (PR 45's), and the readers of the decode tick's own
 account (PR 37) against a registry pair recorded from a session and
 against spans laid over the device plane of the trace recorded on the
 chip (``perf/tests/data``).
@@ -21,7 +22,8 @@ pytest.register_assert_rewrite(
     "perf.tests.test_stats", "perf.tests.test_trace",
     "perf.tests.test_traffic", "perf.tests.test_loadgen",
     "perf.tests.test_moe_prefill_readers", "perf.tests.test_ssm_readers",
-    "perf.tests.test_granite_cell")
+    "perf.tests.test_granite_cell", "perf.tests.test_kanana_cell",
+    "perf.tests.test_latent_readers")
 
 from perf.harness import program_spans as ps  # noqa: E402
 from perf.harness import tick_account as ta  # noqa: E402
@@ -33,12 +35,18 @@ from perf.tests.test_moe_prefill_readers import (  # noqa: E402,F401
     test_moe_grouped_fill_is_assigned_over_computed,
     test_moe_prefill_ms_counts_the_loops_body_and_not_the_loop,
     test_moe_prefill_ms_reads_nothing_without_a_trace_or_the_layer)
+from perf.tests import test_granite_cell as _granite_cell  # noqa: E402
+from perf.tests import test_kanana_cell as _kanana_cell  # noqa: E402
+from perf.tests import test_latent_readers as _latent_readers  # noqa: E402
 from perf.tests.test_granite_cell import (  # noqa: E402,F401
     test_correct_holds_the_attention_layers_and_the_state,
-    test_the_cell_is_appended_where_it_reports,
     test_the_configuration_is_the_catalogs_row_uncut,
     test_the_longest_request_fits_the_rows_a_sequence_holds,
     test_the_traffic_is_the_issues_letter_for_letter)
+from perf.tests.test_kanana_cell import (  # noqa: E402,F401
+    test_correct_holds_every_ablation_and_both_precisions,
+    test_every_catalog_key_is_uncut_but_the_three_in_reduced,
+    test_the_long_prompts_are_spread_and_the_longest_request_fits)
 from perf.tests.test_ssm_readers import (  # noqa: E402,F401
     test_a_program_without_the_scopes_reads_nothing,
     test_sizes_and_the_algorithms_counts,
@@ -67,6 +75,45 @@ ACCOUNT_READERS = [
     "decode_slow_ticks"]
 IDLE_READERS = ["gen_idle_ids_arrival_share", "gen_idle_dispatch_share",
                 "gen_idle_seat_share"]
+
+
+def _as_left_with(bench, cells, metrics):
+    """``BENCHMARK.json`` as the PR that brought its first ``cells``
+    cells and ``metrics`` per-layer metrics left it: what later PRs
+    appended (cells, metrics, the cells' names at the end of lists)
+    taken off.  A later PR only appends, so this is that PR's file."""
+    gone = {w["name"] for w in bench["workloads"][cells:]}
+
+    def without(entry):
+        if "workloads" not in entry:
+            return entry
+        return {**entry, "workloads": [w for w in entry["workloads"]
+                                       if w not in gone]}
+
+    return {**bench, "workloads": bench["workloads"][:cells],
+            "end_to_end": [without(m) for m in bench["end_to_end"]],
+            "per_layer": [without(m) for m in bench["per_layer"][:metrics]]}
+
+
+def test_the_cell_is_appended_where_it_reports(monkeypatch):
+    """PR 41's case (it holds its cell to be the LAST of 8, which the
+    next appended cell ends; a PR that adds a cell edits no file under
+    ``perf/``) on the benchmark as PR 41 left it."""
+    monkeypatch.setattr(_granite_cell, "BENCH",
+                        _as_left_with(_granite_cell.BENCH, 8, 69))
+    _granite_cell.test_the_cell_is_appended_where_it_reports()
+
+
+# PR 45's cases whose names PR 41's already have in this module
+@pytest.mark.parametrize("case", [
+    _kanana_cell.test_the_cell_is_appended_where_it_reports,
+    _kanana_cell.test_the_traffic_is_the_issues_letter_for_letter,
+    _latent_readers.test_a_program_without_the_scopes_reads_nothing,
+    _latent_readers.test_sizes_and_the_algorithms_counts,
+    _latent_readers.test_the_four_readers_arithmetic],
+    ids=lambda f: f.__module__.rsplit(".", 1)[1] + "." + f.__name__)
+def test_the_latent_cell_and_its_readers(case):
+    case()
 
 
 def test_the_harness_names_the_phases_as_the_session_does():
