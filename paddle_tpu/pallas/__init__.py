@@ -47,9 +47,8 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
 A kernel's tile is an argument or a constant of its own module, set
 from or held against a sweep on the chip that ``PERF.md`` §6 records
 (the step kernels' ``BLOCK_BYTES``, the chunked rule's ``CHUNK`` and
-``HEAD_BLOCK``, flash attention's ``BLOCK_PREF``, which PR 44's sweep
-found beaten; the softmax's ``BLOCK_ROWS`` is, like its threshold, from
-an earlier setup): no kernel entry point consults anything else, and a
+``HEAD_BLOCK``, flash attention's ``BLOCK_PREF``; the softmax's
+``BLOCK_ROWS`` is, like its threshold, from an earlier setup): no kernel entry point consults anything else, and a
 shape that wants another tile gets a rule here.
 
 Off a TPU the kernels run under ``interpret=True`` for numerics tests.

@@ -10,7 +10,14 @@ attention.  TPU design:
   running max ``m``, normalizer ``l`` and output accumulator live in
   VMEM scratch across the K sweep, so the ``S x S`` score matrix never
   exists in HBM — the same VMEM-residency trick as ``pallas/lstm.py``.
-  Scores/accumulation in f32 on the MXU regardless of input dtype.
+  The two products whose operands are both loaded from refs (``q k^T``
+  and, in the backward, ``do v^T``) take them as stored, so bfloat16
+  operands reach the MXU as bfloat16 and float32 ones as float32; every
+  product accumulates in float32, and the softmax's statistics, the
+  output accumulator, ``lse`` and ``delta`` are float32 whatever the
+  input dtype.  (On this chip that is no faster than widening first:
+  Mosaic runs a float32 product at its default precision as one
+  bfloat16 pass, PERF.md §6, PR 46.)
   Causal masking skips the strictly-upper K blocks' FLOPs entirely and
   element-masks the diagonal blocks.
 - backward: two kernels (the standard split): ``dq`` accumulates over
@@ -38,49 +45,92 @@ from jax.experimental.pallas import tpu as pltpu
 
 _F32 = jnp.float32
 _NEG_INF = -1e30  # large-but-finite: avoids inf-inf NaNs in corrections
+_NT = (((1,), (1,)), ((), ()))   # a b^T: contract both operands' lanes
+_TN = (((0,), (0,)), ((), ()))   # a^T b: contract both operands' rows
 
 
-# blk_q / blk_k default to the largest power-of-two divisor of S up to
-# this.  Not the best on this chip: at the LM training cell's shape the
-# sweep read (1024, 1024) 30% under it forward, 17% forward + backward
-# (PERF.md §6, PR 44; ROADMAP S3 has the claim to make with it)
-BLOCK_PREF = 512
+# blk_q / blk_k default to the largest power-of-two divisors of S / Sk
+# up to this that the kernel can hold (``_resident``).  From the sweep
+# on the chip (PERF.md §6, PR 46): (1024, 1024) reads 6-36% under
+# (512, 512) forward at every shape a cell runs from 1,024 rows up, a
+# 1,024-row bucket run as one block included, and 15% under it forward
+# + backward at the LM step's shape; (512, 1024) and (1024, 512) lie
+# between or behind.
+BLOCK_PREF = 1024
+
+# what Mosaic lets one kernel call hold of this chip's VMEM unless told
+# otherwise (16 MiB, its default scoped limit on a v5e) less 1 MiB: at
+# float32 heads of 256 lanes ``_resident`` reads up to 0.75 MiB under
+# what the compiler counts
+VMEM_BUDGET = 15 * 1024 * 1024
+
+# Mosaic keeps none of s, p, dp and ds whole: bisecting the limit it is
+# given (a described v5e) reads, beside the blocks and the scratch,
+# 1.25-1.6 float32 (blk_q, blk_k) tiles in the forward kernel and
+# 0.75-1.2 in the two backward ones (PERF.md §6, PR 46)
+_TILES_IN_FLIGHT = {"fwd": 2.0, "dq": 1.5, "dkv": 1.5}
+KERNELS = tuple(_TILES_IN_FLIGHT)
 
 
-def _pick_block(s: int) -> int:
-    b = min(BLOCK_PREF, s)
+def _pick_block(s: int, pref: int = BLOCK_PREF) -> int:
+    b = min(pref, s)
     while b > 8 and s % b != 0:
         b //= 2
     return b if s % b == 0 else 0
 
 
-def _blocks_ok(S: int, Sk: int, D: int, blk_q: int, blk_k: int) -> bool:
-    """Validity of an explicit (blk_q, blk_k) pair at an actual shape:
-    divisibility plus the same VMEM residency model as ``fits``."""
+def _resident(kernel: str, blk_q: int, blk_k: int, D: int,
+              itemsize: int) -> int:
+    """Bytes of VMEM one grid step of ``kernel`` holds: every block it
+    reads or writes twice (the pipeline's two buffers) at the operands'
+    itemsize and a head laid out in whole 128-lane tiles, its float32
+    scratch (a ``(rows, 1)`` column takes a whole tile of lanes), and
+    the ``(blk_q, blk_k)`` float32 temporaries in flight."""
+    lanes = -(-D // 128) * 128
+    q_blk, k_blk = blk_q * lanes * itemsize, blk_k * lanes * itemsize
+    stats = 2 * 8 * blk_q * 4             # an lse / delta row, two buffers
+    if kernel == "fwd":                    # q k v -> o, lse; acc, m, l
+        blocks = 2 * (2 * q_blk + 2 * k_blk) + stats
+        scratch = blk_q * lanes * 4 + 2 * blk_q * 128 * 4
+    elif kernel == "dq":                   # q k v do lse delta -> dq; acc
+        blocks = 2 * (3 * q_blk + 2 * k_blk) + 2 * stats
+        scratch = blk_q * lanes * 4
+    else:                                  # ... -> dk, dv; their two accs
+        blocks = 2 * (2 * q_blk + 4 * k_blk) + 2 * stats
+        scratch = 2 * blk_k * lanes * 4
+    tiles = int(_TILES_IN_FLIGHT[kernel] * blk_q * blk_k * 4)
+    return blocks + scratch + tiles
+
+
+def _blocks_ok(S: int, Sk: int, D: int, blk_q: int, blk_k: int,
+               itemsize: int = 4, kernel: str = "fwd") -> bool:
+    """Validity of a (blk_q, blk_k) pair at an actual shape: whole
+    blocks of 128 rows and up that ``kernel`` can hold."""
     if blk_q < 128 or blk_k < 128 or S % blk_q or Sk % blk_k:
         return False
-    resident = (blk_q + 2 * blk_k) * D * 2 + blk_q * D * 4 \
-        + blk_q * blk_k * 4
-    return resident <= 12 * 1024 * 1024
+    return _resident(kernel, blk_q, blk_k, D, itemsize) <= VMEM_BUDGET
 
 
-def _resolve_blocks(S, Sk, D, blk_q=None, blk_k=None):
+def _resolve_blocks(S, Sk, D, itemsize=4, blk_q=None, blk_k=None,
+                    kernel="fwd"):
     """An explicit (blk_q, blk_k) where it is valid at this shape, else
-    the ``_pick_block`` preference."""
-    blk_q = blk_q or _pick_block(S)
-    blk_k = blk_k or _pick_block(Sk)
-    if not _blocks_ok(S, Sk, D, blk_q, blk_k):
-        blk_q, blk_k = _pick_block(S), _pick_block(Sk)
-    return blk_q, blk_k
+    the largest default pair ``kernel`` can hold ((0, 0) if none)."""
+    pref, default = BLOCK_PREF, (0, 0)
+    while pref >= 128 and not default[0]:
+        pair = _pick_block(S, pref), _pick_block(Sk, pref)
+        if _blocks_ok(S, Sk, D, *pair, itemsize, kernel):
+            default = pair
+        pref //= 2
+    pair = blk_q or default[0], blk_k or default[1]
+    return pair if _blocks_ok(S, Sk, D, *pair, itemsize, kernel) else default
 
 
 def fits(B: int, H: int, S: int, D: int) -> bool:
-    blk = _pick_block(S)
-    if blk < 128 or D > 256 or D % 8 != 0:
+    """Whether all three kernels have a block pair at this shape, at
+    float32 operands, the widest (narrower ones only take larger pairs)."""
+    if D > 256 or D % 8 != 0:
         return False
-    # VMEM: q,k,v blocks + f32 acc + scores
-    resident = blk * D * 2 * 3 + blk * D * 4 + blk * blk * 4
-    return resident <= 12 * 1024 * 1024
+    return all(_resolve_blocks(S, S, D, kernel=k)[0] for k in KERNELS)
 
 
 # ---------------------------------------------------------------------------
@@ -105,9 +155,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(run)
     def _block():
-        q = q_ref[0].astype(_F32)
-        k = k_ref[0].astype(_F32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
                                 preferred_element_type=_F32) * scale
         if causal:
             q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32,
@@ -142,7 +190,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, scale: float,
                     blk_k: int = None):
     BH, S, D = q.shape
     Sk = k.shape[1]
-    blk_q, blk_k = _resolve_blocks(S, Sk, D, blk_q, blk_k)
+    blk_q, blk_k = _resolve_blocks(S, Sk, D, q.dtype.itemsize, blk_q, blk_k)
     nq, nk = S // blk_q, Sk // blk_k
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -198,9 +246,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(run)
     def _block():
-        q = q_ref[0].astype(_F32)
-        k = k_ref[0].astype(_F32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
                                 preferred_element_type=_F32) * scale
         if causal:
             q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32,
@@ -210,9 +256,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         lse_col = lse_ref[0, pl.ds(qi, 1), :].reshape(-1, 1)
         p = jnp.exp(s - lse_col)
-        dp = jax.lax.dot_general(
-            do_ref[0].astype(_F32), v_ref[0].astype(_F32),
-            (((1,), (1,)), ((), ())), preferred_element_type=_F32)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
+                                 preferred_element_type=_F32)
         ds = p * (dp - delta_ref[0, pl.ds(qi, 1), :].reshape(-1, 1)) * scale
         acc_scr[...] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
@@ -240,9 +285,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _block():
-        q = q_ref[0].astype(_F32)
-        k = k_ref[0].astype(_F32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
                                 preferred_element_type=_F32) * scale
         if causal:
             q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32,
@@ -252,15 +295,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         lse_col = lse_ref[0, pl.ds(qi, 1), :].reshape(-1, 1)
         p = jnp.exp(s - lse_col)                      # (blk_q, blk_k)
-        do = do_ref[0].astype(_F32)
+        # p and ds are computed float32 values: their products with do
+        # and q keep float32 operands (which this chip's MXU rounds to
+        # bfloat16 at the default precision anyway: PERF.md §7)
         dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=_F32)
-        dp = jax.lax.dot_general(do, v_ref[0].astype(_F32),
-                                 (((1,), (1,)), ((), ())),
+            p, do_ref[0].astype(_F32), _TN, preferred_element_type=_F32)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
                                  preferred_element_type=_F32)
         ds = p * (dp - delta_ref[0, pl.ds(qi, 1), :].reshape(-1, 1)) * scale
         dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=_F32)
+            ds, q_ref[0].astype(_F32), _TN, preferred_element_type=_F32)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -273,19 +317,23 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
                     interpret: bool = False, dlse=None):
     BH, S, D = q.shape
     Sk = k.shape[1]
-    # the default blocks, as the forward it differentiates ran with
-    # (only ``_flash_fwd_impl`` takes a pair, and nothing differentiates
-    # that); lse arrives flat (BH, S) and is reshaped to these
-    blk_q, blk_k = _resolve_blocks(S, Sk, D)
-    nq, nk = S // blk_q, Sk // blk_k
     delta = jnp.sum(do.astype(_F32) * o.astype(_F32), axis=-1)  # (BH, S)
     if dlse is not None:
         # joint (out, lse) cotangent: d lse/d s = p, so the lse
         # cotangent folds into the delta term of ds = p*(dp - delta)
         delta = delta - dlse.astype(_F32)
-    lse3 = lse.reshape(BH, nq, blk_q)
-    delta3 = delta.reshape(BH, nq, blk_q)
 
+    def blocked(kernel):
+        """A kernel's default pair (only ``_flash_fwd_impl`` takes one,
+        and nothing differentiates that), its grid's two counts, and
+        lse and delta, flat (BH, S), reshaped to its q blocks."""
+        blk_q, blk_k = _resolve_blocks(S, Sk, D, q.dtype.itemsize,
+                                       kernel=kernel)
+        nq = S // blk_q
+        return (blk_q, blk_k, nq, Sk // blk_k,
+                lse.reshape(BH, nq, blk_q), delta.reshape(BH, nq, blk_q))
+
+    blk_q, blk_k, nq, nk, lse3, delta3 = blocked("dq")
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k, nk=nk),
@@ -307,6 +355,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal: bool, scale: float,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3)
 
+    blk_q, blk_k, nq, nk, lse3, delta3 = blocked("dkv")
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k, nq=nq),

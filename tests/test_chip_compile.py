@@ -1278,19 +1278,21 @@ def test_conv_step_compiles(one_chip, channels, slots, bias):
             in text)
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
-def test_flash_attention_compiles(one_chip, grad):
+def _flash_fwd(q, k, v):
     from paddle_tpu.pallas.flash_attention import flash_attention
 
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, True)
+    return flash_attention(q, k, v, True)
 
-    def bwd(q, k, v):
-        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
-                        argnums=(0, 1, 2))(q, k, v)
 
+def _flash_bwd(q, k, v):
+    return jax.grad(lambda *a: _flash_fwd(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles(one_chip, grad):
     qkv = [((384, 1024, 128), jnp.bfloat16)] * 3
-    text = _compiled_text(bwd if grad else fwd, one_chip, *qkv)
+    text = _compiled_text(_flash_bwd if grad else _flash_fwd, one_chip, *qkv)
     assert text.count(MARKER) >= (2 if grad else 1)
     # forward and backward can be told apart by the kernels' own names,
     # beside the jitted wrappers' that flash_attn_ms_per_step matches
@@ -1303,6 +1305,33 @@ def test_flash_attention_compiles(one_chip, grad):
     assert {op.split("/")[-2] for op in bwd_ops} == (
         {"flash_attention_bwd_dq", "flash_attention_bwd_dkv"} if grad
         else set())
+
+
+@pytest.mark.parametrize("shape, dtype, grad", [
+    ((64, 2048, 128), jnp.bfloat16, True),
+    ((32, 8192, 192), jnp.bfloat16, False),
+    ((16, 1024, 128), jnp.float32, False),
+], ids=["lm-train", "latent-prefill-8192", "cerebras-prefill-1024-f32"])
+def test_flash_attention_compiles_at_the_cells_shapes(one_chip, shape, dtype,
+                                                      grad):
+    """The pair the residency model admits at the shapes a cell runs
+    compiles (PR 46: the model counts the operands' itemsize, a head's
+    whole lanes and every kernel's own blocks): the LM step's forward +
+    backward, the latent cell's top bucket at heads of 192, and the
+    Cerebras generate cell's float32 1,024-row bucket."""
+    from paddle_tpu.pallas import flash_attention as fa
+
+    _, S, D = shape
+    item = jnp.dtype(dtype).itemsize
+    for kernel in fa.KERNELS:
+        pair = fa._resolve_blocks(S, S, D, item, kernel=kernel)
+        assert fa._blocks_ok(S, S, D, *pair, item, kernel), (kernel, pair)
+    text = _compiled_text(_flash_bwd if grad else _flash_fwd, one_chip,
+                          *[(shape, dtype)] * 3)
+    names = sorted(op.split("/")[-2] for op in _kernel_op_names(text))
+    assert names == (["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                      "flash_attention_fwd"] if grad
+                     else ["flash_attention_fwd"])
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])

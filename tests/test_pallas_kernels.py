@@ -157,48 +157,152 @@ def _attn_ref(q, k, v, causal):
     return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
 
 
+def _qkv(rng, dtype):
+    return [jnp.asarray(rng.randn(2, 256, 64).astype("float32")).astype(dtype)
+            for _ in range(3)]
+
+
+def _widened(ts):
+    return [t.astype(jnp.float32) for t in ts]
+
+
+# a bfloat16 result against a float32 reference on the same values: the
+# output's own rounding (8 bits of mantissa) and no more than two of them
+_BF16_TOL = 2.0 ** -7
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("blocks", [None, (128, 128), (192, 128)],
                          ids=["default", "b128", "invalid-falls-back"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_fwd(rng, causal, blocks):
+def test_flash_attention_fwd(rng, causal, blocks, dtype):
     """The blocks are ``_pick_block``'s or the explicit pair; a pair
-    that does not divide S (192 of 256) falls back to the default."""
+    that does not divide S (192 of 256) falls back to the default.
+    bfloat16 operands go to the products as stored: the output is the
+    float32 reference's on the same values to the output's rounding, and
+    ``lse`` (float32 out) is what the widened operands give: every
+    product of two bfloat16 numbers is exact in float32."""
     from paddle_tpu.pallas import flash_attention as fa
 
-    q, k, v = (jnp.asarray(rng.randn(2, 256, 64).astype("float32"))
-               for _ in range(3))
+    q, k, v = _qkv(rng, dtype)
     with jax.default_matmul_precision("highest"):
         if blocks is None:
-            out = fa.flash_attention(q, k, v, causal, None, True)
+            out, lse = fa.flash_attention_with_lse(q, k, v, causal, None,
+                                                   True)
         else:
-            out, _ = fa._flash_fwd_impl(q, k, v, causal, 64 ** -0.5, True,
-                                        *blocks)
-        ref = _attn_ref(q, k, v, causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+            out, lse = fa._flash_fwd_impl(q, k, v, causal, 64 ** -0.5, True,
+                                          *blocks)
+        ref = _attn_ref(*_widened((q, k, v)), causal)
+        _, lse_wide = fa._flash_fwd_impl(*_widened((q, k, v)), causal,
+                                         64 ** -0.5, True, *(blocks or ()))
+    assert out.dtype == q.dtype and lse.dtype == jnp.float32
+    tol = 1e-5 if dtype == "float32" else _BF16_TOL
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_wide),
+                               atol=1e-5)
     want = (128, 128) if blocks == (128, 128) else (256, 256)
-    assert fa._resolve_blocks(256, 256, 64, *(blocks or ())) == want
+    assert fa._resolve_blocks(256, 256, 64, q.dtype.itemsize,
+                              *(blocks or ())) == want
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(rng, causal):
+def test_flash_attention_grads(rng, causal, dtype):
+    """bfloat16: the three gradients against the float32 reference's on
+    the same values, to the gradients' own bfloat16 rounding."""
     from paddle_tpu.pallas.flash_attention import flash_attention
 
-    q, k, v = (jnp.asarray(rng.randn(2, 256, 64).astype("float32"))
-               for _ in range(3))
+    q, k, v = _qkv(rng, dtype)
 
     with jax.default_matmul_precision("highest"):
         def loss_k(q, k, v):
-            return jnp.sum(jnp.cos(flash_attention(q, k, v, causal, None,
-                                                   True)))
+            out = flash_attention(q, k, v, causal, None, True)
+            return jnp.sum(jnp.cos(out.astype(jnp.float32)))
 
         def loss_r(q, k, v):
             return jnp.sum(jnp.cos(_attn_ref(q, k, v, causal)))
 
         got = jax.grad(loss_k, (0, 1, 2))(q, k, v)
-        want = jax.grad(loss_r, (0, 1, 2))(q, k, v)
+        want = jax.grad(loss_r, (0, 1, 2))(*_widened((q, k, v)))
     for a, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                   atol=2e-3, rtol=1e-3)
+        assert a.dtype == q.dtype
+        a, w = np.asarray(a, np.float32), np.asarray(w)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, w, atol=2e-3, rtol=1e-3)
+        else:
+            # the forward's rounded output moves the cotangent too
+            np.testing.assert_allclose(
+                a, w, atol=2 * _BF16_TOL * np.abs(w).max(), rtol=_BF16_TOL)
+
+
+@pytest.mark.parametrize("S, D, itemsize, want", [
+    (2048, 128, 2, (1024, 1024)),    # the LM step: the sweep's pair
+    (8192, 192, 2, (1024, 1024)),    # heads of 192 laid out at 256 lanes
+    (1024, 128, 4, (1024, 1024)),    # a float32 1,024-row bucket: one block
+    (1536, 128, 2, (512, 512)),      # 1,024 does not divide: the next one
+    (4608, 128, 2, (512, 512)),      # K-EXAONE's top bucket, 9 x 512
+    (1280, 128, 4, (256, 256)),      # Cerebras generate's capacity
+    (2048, 256, 4, (512, 512)),      # float32 heads of 256: 1,024 is too much
+    (2048, 256, 2, (1024, 1024)),    # ... the same heads in bfloat16 are not
+    (384, 64, 2, (384, 384)),        # shorter than the preference: one block
+    (1000, 64, 2, (1000, 1000)),
+    (2056, 64, 2, (0, 0)),           # no divisor of 128 rows and up
+], ids=lambda v: str(v).replace(" ", ""))
+def test_flash_block_rule(S, D, itemsize, want):
+    """``_resolve_blocks`` with no explicit pair: per kernel the largest
+    power-of-two divisors up to ``BLOCK_PREF`` that ``_resident`` admits
+    at the operands' itemsize; an explicit pair the model refuses falls
+    back to it; ``fits`` is whether every kernel has one."""
+    from paddle_tpu.pallas import flash_attention as fa
+
+    assert fa.BLOCK_PREF == 1024
+    for kernel in fa.KERNELS:
+        assert fa._resolve_blocks(S, S, D, itemsize, kernel=kernel) == want
+        assert fa._resolve_blocks(S, S, D, itemsize, 4096, 4096,
+                                  kernel=kernel) == want
+    assert fa.fits(1, 8, S, D) == bool(want[0])
+    if want[0]:
+        assert fa._pick_block(S) >= want[0]
+
+
+# what Mosaic needs for one call, MiB: the least ``vmem_limit_bytes`` at
+# which each kernel compiles for a described v5e, found by bisection to
+# half a MiB (PERF.md §6, PR 46): D, itemsize, pair, fwd, dq, dkv
+_VMEM_NEED = [
+    (128, 2, (1024, 1024), 9.5, 7.0, 9.0),
+    (128, 2, (512, 512), 3.0, 2.0, 3.0),
+    (128, 2, (2048, 1024), 17.5, 13.5, 16.0),
+    (128, 4, (1024, 1024), 11.5, 10.0, 10.5),
+    (128, 4, (512, 1024), 7.0, 5.5, 7.0),
+    (128, 4, (2048, 1024), 21.5, 19.0, 17.5),
+    (256, 2, (1024, 1024), 12.5, 9.0, 12.0),
+    (256, 2, (1024, 512), 9.0, 6.0, 6.0),
+    (256, 4, (1024, 1024), 16.5, 16.5, 15.5),
+    (256, 4, (512, 512), 7.0, 5.5, 5.5),
+    (64, 2, (1024, 1024), 7.5, 6.0, 7.5),
+]
+
+
+@pytest.mark.parametrize("D, itemsize, pair, fwd, dq, dkv", _VMEM_NEED,
+                         ids=lambda v: str(v).replace(" ", ""))
+def test_flash_residency_bounds_what_the_compiler_needs(D, itemsize, pair,
+                                                         fwd, dq, dkv):
+    """``_resident`` counts the operands' itemsize and each kernel's own
+    blocks: it is at or over what the compiler needed for every measured
+    (shape, pair), so a pair it admits compiles, and it refuses nothing
+    that needs under three quarters of the limit."""
+    from paddle_tpu.pallas import flash_attention as fa
+
+    mib = 2.0 ** 20
+    for kernel, need in zip(fa.KERNELS, (fwd, dq, dkv)):
+        model = fa._resident(kernel, *pair, D, itemsize)
+        assert model > (need - 0.5) * mib, (kernel, model / mib)
+        ok = fa._blocks_ok(2048, 2048, D, *pair, itemsize, kernel)
+        assert not (ok and need > 16), kernel
+        assert ok or need > 12, kernel
+        if itemsize == 2:
+            assert model < fa._resident(kernel, *pair, D, 4)
 
 
 def test_flash_attention_via_attention_op(rng):
