@@ -10,9 +10,10 @@ from the call's static shape at trace time (no option selects one):
 - ``grouped``, a call of many rows (a prefill bucket over
   ``DENSE_MAX_ROWS``) or of so few that most experts get none (a step
   of a few slots): a stable sort of the ``rows x k`` assignments by
-  expert, ONE grouped GEMM per projection over sorted rows
-  (``jax.lax.ragged_dot``: on a TPU the compiler lowers it to a
-  grouped-matmul kernel that reads only the experts that were hit),
+  expert, the grouped GEMMs over sorted rows (``pallas/grouped_gemm.py``
+  on a TPU: gate and up in one call, then down, each streaming the
+  matrices of the experts that were hit once; ``jax.lax.ragged_dot``,
+  its reference, a projection elsewhere and under ``pallas.enable(False)``),
   and the weighted sum of each row's results.  How many sorted rows a
   pass takes is ``grouped_block_rows`` of the static shape: all ``rows
   x k`` when every expert is held, a quarter of that on a chip that
@@ -24,10 +25,10 @@ from the call's static shape at trace time (no option selects one):
   dense matmuls, and that is the faster way to read the same bytes:
   such a call's rows hit nearly every held expert, so either way reads
   all of their weights once, and a plain matmul streams them at 84-87%
-  of the chip's bandwidth where the grouped GEMM's 256-row tiles,
-  holding about four real rows each, reach 37-62% (PERF.md section 6,
-  PR 34).  The extra arithmetic (C / k times the grouped pass's) hides
-  under the stream.
+  of the chip's bandwidth where a grouped GEMM, whose row tiles hold
+  about four real rows each at such a size, does not (PERF.md section
+  6, PRs 34 and 47).  The extra arithmetic (C / k times the grouped
+  pass's) hides under the stream.
 
 Fixed shapes: every row routes, whatever it holds; the ``live`` mask
 decides which rows the returned load counts, and on the grouped path of
@@ -62,7 +63,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import pallas as _pallas
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.pallas import grouped_gemm as _gg
 
 _F32 = jnp.float32
 
@@ -193,9 +196,11 @@ def expert_path(rows: int, top_k: int, experts: int) -> str:
     expert: ``6 rows C d f`` FLOPs under ``3 C d f itemsize`` bytes, a
     ratio that does not depend on C, d or f.  The grouped GEMM reads
     only the experts that were hit and does ``top_k / C`` of that
-    arithmetic, but its 256-row tiles hold a handful of real rows each
-    until a call has thousands, and it takes 1.5 to 3 times the time
-    its bytes need.  So the dense pass is the faster one between two
+    arithmetic, but its row tiles hold a handful of real rows each
+    until a call has thousands, its sort, gather and scatter-add cost
+    what the dense pass has no need of, and it takes 1.3 to 1.5 times
+    the time its bytes need (``ragged_dot`` took 2 to 4 times, PERF.md
+    section 6, PR 47).  So the dense pass is the faster one between two
     edges: the rows must be many enough to hit nearly every expert
     anyway (a step of a few slots reads a few experts, and should), and
     few enough for its arithmetic to hide under the stream (up to some
@@ -206,10 +211,11 @@ def expert_path(rows: int, top_k: int, experts: int) -> str:
             else "grouped")
 
 
-# The grouped GEMM's kernel on a TPU walks the sorted rows in tiles of
-# this many (PERF.md section 6, PR 34): a block of sorted assignments is
-# a whole number of them.
-GROUPED_ROW_TILE = 256
+# A block of sorted assignments is a whole number of the grouped GEMM
+# kernel's row tiles, in pairs: the 256 rows the blocks were cut to when
+# ``ragged_dot``'s tile set them (PR 38), kept so that no block's size
+# moved when the kernel's 128 rows took over (PERF.md section 6, PR 47).
+GROUPED_ROW_TILE = 2 * _gg.ROW_TILE
 
 
 def grouped_block_rows(rows: int, top_k: int, held: int, experts: int) -> int:
@@ -234,15 +240,22 @@ def grouped_blocks(held_assignments, block_rows: int):
 def _grouped_gemms(xs, sizes, w_gate, w_up, w_down):
     """Each expert's SwiGLU over its group of the sorted rows ``xs``
     (``sizes`` rows a group, in order; rows behind the last group are
-    no group's and what comes out for them means nothing) -> float32."""
+    no group's and what comes out for them means nothing) -> float32.
+    Float32 products of the operands as stored, ``h`` rounded to the
+    rows' dtype in between, by the kernel or by its reference."""
+    _, d, f = w_gate.shape
     with jax.named_scope("moe_experts"):
-        g = jax.lax.ragged_dot(xs, w_gate, sizes,
-                               preferred_element_type=_F32)
-        u = jax.lax.ragged_dot(xs, w_up, sizes,
-                               preferred_element_type=_F32)
+        if _pallas.use_grouped_gemm(xs.dtype, w_gate.dtype, xs.shape[0],
+                                    d, f):
+            # one walk over the sorted rows for both calls
+            kw = dict(walk=_gg.visits(sizes, xs.shape[0], _gg.ROW_TILE),
+                      interpret=_pallas.interpret_mode())
+            h = _gg.gate_up(xs, w_gate, w_up, sizes, **kw)
+            return _gg.grouped_gemm(h, w_down, sizes, **kw)
+        g = _gg.grouped_gemm_reference(xs, w_gate, sizes)
+        u = _gg.grouped_gemm_reference(xs, w_up, sizes)
         h = (jax.nn.silu(g) * u).astype(xs.dtype)
-        return jax.lax.ragged_dot(h, w_down, sizes,
-                                  preferred_element_type=_F32)
+        return _gg.grouped_gemm_reference(h, w_down, sizes)
 
 
 def _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down, top_k):

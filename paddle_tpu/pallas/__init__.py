@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Nine kernel families, thirteen ``pl.pallas_call``s: the fused
+Ten kernel families, fifteen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -24,7 +24,15 @@ double buffer, where the ragged kernels take a grid step a table
 column).  The latent pool's rows are stored at 640 lanes for the 576
 the algorithm needs: at 576 the chip's compiler lays the pool out at
 640 anyway and refuses the kernel's page copy ("slice shape must be
-aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).
+aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).  The tenth is the grouped GEMM
+of a routed layer's experts over a prefill bucket's sorted rows
+(``grouped_gemm.py``, 2, one kernel body: ``grouped_gemm`` and, two
+matrices a visit and ``silu(g) * u`` written, ``grouped_gemm_gate_up``):
+a grid step a (row tile, expert) pair that overlaps, each hit expert's
+matrix copied by hand through a double buffer one expert ahead, so read
+once; its row tile is ``grouped_gemm.ROW_TILE`` (the MXU's 128 rows,
+near the rows a group holds), its column block the widest that stays
+resident, and a call of fewer rows than a tile is ``ragged_dot``'s.
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -34,7 +42,8 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
   the gated delta, SSD and conv steps, the chunked gated delta rule of
-  a hybrid's prefill, latent paged attention) have no threshold.  All three
+  a hybrid's prefill, latent paged attention, the experts' grouped
+  GEMM) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
   LSTM's and the softmax's thresholds are not re-measured on this chip
@@ -47,7 +56,8 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
 A kernel's tile is an argument or a constant of its own module, set
 from or held against a sweep on the chip that ``PERF.md`` §6 records
 (the step kernels' ``BLOCK_BYTES``, the chunked rule's ``CHUNK`` and
-``HEAD_BLOCK``, flash attention's ``BLOCK_PREF``; the softmax's
+``HEAD_BLOCK``, flash attention's ``BLOCK_PREF``, the grouped GEMM's
+``ROW_TILE``, ``COL_CHUNK`` and ``VMEM_BUDGET``; the softmax's
 ``BLOCK_ROWS`` is, like its threshold, from an earlier setup): no kernel entry point consults anything else, and a
 shape that wants another tile gets a rule here.
 
@@ -217,6 +227,19 @@ def use_latent_paged_attention(pool_dtype, page_size: int, rows: int,
 
     return dispatch("latent_paged_attention", policy(
         _l.fits(pool_dtype, page_size, rows, width, v_width), True))
+
+
+def use_grouped_gemm(row_dtype, w_dtype, rows: int, d: int, f: int) -> bool:
+    """A routed layer's three grouped GEMMs over ``rows`` sorted rows of
+    width ``d`` (experts of ``d x f``: gate and up together, then down)
+    by the kernel wherever ``fits()`` holds both ways round,
+    by the decode kernels' rule: no threshold; else
+    ``jax.lax.ragged_dot`` (``grouped_gemm_reference``)."""
+    from paddle_tpu.pallas import grouped_gemm as _g
+
+    return dispatch("grouped_gemm", policy(
+        _g.fits(row_dtype, w_dtype, rows, d, f)
+        and _g.fits(row_dtype, w_dtype, rows, f, d), True))
 
 
 from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402

@@ -865,8 +865,9 @@ def test_every_pallas_call_has_a_name():
                     else:
                         unnamed.append(f"{path}:{node.lineno}")
     assert not unnamed
-    assert len(names) == 13 and len(set(names)) == len(names)
-    assert {"flash_attention_fwd", "flash_attention_bwd_dq",
+    assert len(names) == 15 and len(set(names)) == len(names)
+    assert {"grouped_gemm", "grouped_gemm_gate_up",
+            "flash_attention_fwd", "flash_attention_bwd_dq",
             "flash_attention_bwd_dkv", "ragged_paged_attention",
             "ragged_paged_attention_chunk",
             "ragged_paged_attention_gqa",

@@ -496,6 +496,9 @@ def _decide(kernel, shape):
     if kernel == "conv_step":
         dtype, taps, channels, entry = shape
         return pk.use_conv_step(dtype, entry, dtype, taps, channels)
+    if kernel == "grouped_gemm":
+        dtype, rows, d, f = shape
+        return pk.use_grouped_gemm(dtype, dtype, rows, d, f)
     assert kernel == "prefill_flash_attention"
     x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
     # a new function each time: eval_shape caches a function's trace
@@ -600,6 +603,26 @@ _POLICY_CASES = (
        ("conv_step", ("bfloat16", 4, 4352, (13056,)), "on", True, False,
         "reference"),
        ("conv_step", ("bfloat16", 4, 4352, (102, 128)), "off", True, False,
+        "reference"),
+       # sorted rows of a block, then an expert's (d, f): the three MoE
+       # cells' prefill blocks, a few-slot step, the tests' toy widths
+       ("grouped_gemm", ("bfloat16", 2304, 6144, 2048), "auto", True, False,
+        "compiled"),
+       ("grouped_gemm", ("bfloat16", 4096, 2048, 1024), "auto", True, False,
+        "compiled"),
+       ("grouped_gemm", ("bfloat16", 1024, 2048, 768), "auto", True, False,
+        "compiled"),
+       ("grouped_gemm", ("bfloat16", 32, 6144, 2048), "auto", True, False,
+        "reference"),
+       ("grouped_gemm", ("float32", 640, 128, 128), "auto", False, True,
+        "interpret"),
+       ("grouped_gemm", ("bfloat16", 2304, 6144, 2048), "auto", False, False,
+        "reference"),
+       ("grouped_gemm", ("float32", 825, 16, 12), "on", True, False,
+        "reference"),
+       ("grouped_gemm", ("float32", 600, 128, 128), "on", True, False,
+        "reference"),
+       ("grouped_gemm", ("bfloat16", 2304, 6144, 2048), "off", True, False,
         "reference")])
 
 
