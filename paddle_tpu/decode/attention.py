@@ -62,6 +62,18 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     that (``models/granite_hybrid.py:heads_a_row``): read and written
     in place, nothing padded.
 
+    Nor does a page have to keep its heads inside its rows.  Ten
+    stored heads of 128 (PR 48's probe: five K/V pairs of 64-wide heads
+    twice over): 4, 8 or a multiple of 16 heads are whole tiles of a
+    bfloat16 page's (rows, heads), ten are not, the compiler lays the
+    donated pool out with the heads outermost as at 30, and a decode
+    step of eight reading layers planned 5.6 GB of copies of a 2.1 GB
+    pool; sixteen stored heads would store and read 1.6 times the
+    bytes.  A page stored ``(heads, rows, 128)`` is whole tiles
+    whatever the head count, and it is the shape the grouped kernel's
+    two batched dots take as it lies: ``heads_major``
+    (``models/phi4_flash.py``).
+
     Nor do these kernels take a latent layer's pages: one row a token
     whose 576 numbers are the key and whose first 512 are the value, K
     and V from one buffer at ``D > 256``.  That is
@@ -259,13 +271,15 @@ def ragged_paged_attention_chunk_reference(q, k_pages, v_pages,
 
 
 def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, scale, page, npp, T, G=1):
+                      m_scr, l_scr, acc_scr, *, scale, page, npp, T, G=1,
+                      heads_major=False):
     """Chunked variant of ``_rpa_kernel``: the q block holds the slot's
     whole T-token chunk; masking offsets the length limit per row.
     With ``G`` > 1 (grouped heads) the q block's ``T * G`` rows are the
     chunk's rows times the G query heads that read each of the block's
     K/V heads, row ``t * G + g`` at the chunk's row ``t``: the group
-    rides the one K/V page, read once."""
+    rides the one K/V page, read once.  ``heads_major``: a page is
+    stored (H, page, D), as the two batched dots take it."""
     s = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -284,10 +298,11 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(_F32)                       # (T * G, H, D)
         k = k_ref[0].astype(_F32)                       # (page, H, D)
         v = v_ref[0].astype(_F32)
+        if not heads_major:
+            k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
         # scores (H, T, page): batch over H, contract D
         sc = jax.lax.dot_general(
-            jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
-            (((2,), (2,)), ((0,), (0,))),
+            jnp.swapaxes(q, 0, 1), k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=_F32) * scale
         t_pos = p * page + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
         row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -302,7 +317,7 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[...] = m_new
         # (H, T, page) x (H, page, D) batched over H -> (H, T, D)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            pr, jnp.swapaxes(v, 0, 1), (((2,), (1,)), ((0,), (0,))),
+            pr, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=_F32)
 
     @pl.when(p == npp - 1)
@@ -313,12 +328,12 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
-                G):
+                G, heads_major=False):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
     one grid step per (slot, page), the whole chunk resident in the q/o
-    blocks."""
+    blocks.  A page is (page, H, D), or (H, page, D) ``heads_major``."""
     S, TG, H, D = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2 if heads_major else 1]
     P = page_tables.shape[1]
     if scale is None:
         scale = D ** -0.5
@@ -327,9 +342,9 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
         grid=(S, P),
         in_specs=[
             pl.BlockSpec((1, TG, H, D), lambda s, p, pt, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1, page, H, D),
+            pl.BlockSpec((1,) + k_pages.shape[1:],
                          lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
-            pl.BlockSpec((1, page, H, D),
+            pl.BlockSpec((1,) + v_pages.shape[1:],
                          lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, TG, H, D),
@@ -341,7 +356,8 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
         ],
     )
     kernel = functools.partial(_rpa_chunk_kernel, scale=scale, page=page,
-                               npp=P, T=TG // G, G=G)
+                               npp=P, T=TG // G, G=G,
+                               heads_major=heads_major)
     call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, TG, H, D), q.dtype),
@@ -373,10 +389,15 @@ def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
 
 
 def ragged_paged_attention_gqa_reference(q, k_pages, v_pages, page_tables,
-                                         lens, scale=None):
+                                         lens, scale=None,
+                                         heads_major: bool = False):
     """The chunk reference on grouped heads: q (S, T, Hq, D); k/v_pages
-    (N, page, Hkv, D), query head ``i`` reading K/V head ``i // (Hq //
-    Hkv)``; ``lens`` the rows before the chunk -> (S, T, Hq, D)."""
+    (N, page, Hkv, D), or (N, Hkv, page, D) ``heads_major``; query head
+    ``i`` reading K/V head ``i // (Hq // Hkv)``; ``lens`` the rows
+    before the chunk -> (S, T, Hq, D)."""
+    if heads_major:
+        k_pages, v_pages = (jnp.swapaxes(k_pages, 1, 2),
+                            jnp.swapaxes(v_pages, 1, 2))
     S, T, Hq, D = q.shape
     page, Hkv = k_pages.shape[1:3]
     G = Hq // Hkv
@@ -394,9 +415,11 @@ def ragged_paged_attention_gqa_reference(q, k_pages, v_pages, page_tables,
     return out.reshape(S, T, Hq, D).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "heads_major"))
 def ragged_paged_attention_gqa(q, k_pages, v_pages, page_tables, lens,
-                               scale=None, interpret: bool = False):
+                               scale=None, interpret: bool = False,
+                               heads_major: bool = False):
     """Pallas ragged paged attention on grouped heads (same contract as
     ``ragged_paged_attention_gqa_reference``): the chunk kernel with
     the G query heads of each K/V head laid out as further rows of the
@@ -404,14 +427,14 @@ def ragged_paged_attention_gqa(q, k_pages, v_pages, page_tables, lens,
     are the Hkv heads', whatever Hq is.  A decode step is the chunk of
     one row."""
     S, T, Hq, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[1 if heads_major else 2]
     G = Hq // Hkv
     # (S, T, Hkv, G, D) -> (S, T * G, Hkv, D): outside the kernel, on
     # S * T * Hq * D numbers
     rows = jnp.moveaxis(q.reshape(S, T, Hkv, G, D), 3, 2).reshape(
         S, T * G, Hkv, D)
     out = _chunk_call(rows, k_pages, v_pages, page_tables, lens, scale,
-                      interpret, G)
+                      interpret, G, heads_major)
     return jnp.moveaxis(out.reshape(S, T, G, Hkv, D), 2, 3).reshape(
         S, T, Hq, D)
 
@@ -420,25 +443,30 @@ def _grouped(q, k_pages) -> bool:
     return q.shape[-2] != k_pages.shape[2]
 
 
-def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale):
+def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale,
+               heads_major=False):
     """Dispatcher of the grouped path: a chunk (S, T, Hq, D) after
     ``lens`` cached rows."""
     from paddle_tpu import pallas as pk
 
     Hq, D = q.shape[-2:]
     page, Hkv = k_pages.shape[1:3]
+    if heads_major:
+        page, Hkv = Hkv, page
     if pk.dispatch("ragged_paged_attention_gqa",
                    pk.policy(fits(page, Hq, D, Hkv), True)):
         return ragged_paged_attention_gqa(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
-            interpret=pk.interpret_mode())
+            interpret=pk.interpret_mode(), heads_major=heads_major)
     return ragged_paged_attention_gqa_reference(
-        q, k_pages, v_pages, page_tables, lens, scale=scale)
+        q, k_pages, v_pages, page_tables, lens, scale=scale,
+        heads_major=heads_major)
 
 
 def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
     """Attention of a window layer over its per-sequence ring, plain
-    XLA (a ring is a few hundred rows a slot: the gather IS the read).
+    XLA (a ring is a few pages a slot, 256 rows at a window of 128 and
+    640 at 512: the gather IS the read).
 
     q (S, T, Hq, D) at absolute positions ``pos`` (S, T); k/v_ring
     (S, R, page, Hkv, D): ring slot ``r`` holds the newest page ``pi``
@@ -536,15 +564,19 @@ def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
         q, k_pages, v_pages, page_tables, lens, scale=scale)
 
 
-def paged_attention(q, k_pages, v_pages, page_tables, lens, scale=None):
+def paged_attention(q, k_pages, v_pages, page_tables, lens, scale=None,
+                    heads_major: bool = False):
     """Dispatcher: the Pallas kernel or the jnp reference — both
-    jit-embeddable, identical contract (see ``_use_kernel``)."""
+    jit-embeddable, identical contract (see ``_use_kernel``).
+    ``heads_major``: the pages are stored (N, Hkv, page, D), a head's
+    rows together (grouped heads only: ``fits`` says which head counts
+    the row-major page cannot hold in place)."""
     from paddle_tpu import pallas as pk
 
-    if _grouped(q, k_pages):
+    if heads_major or _grouped(q, k_pages):
         # the chunk of one row after ``lens - 1`` cached rows
         return _paged_gqa(q[:, None], k_pages, v_pages, page_tables,
-                          lens - 1, scale)[:, 0]
+                          lens - 1, scale, heads_major)[:, 0]
     S, H, D = q.shape
     if _use_kernel("ragged_paged_attention", k_pages.shape[1], H, D):
         return ragged_paged_attention(

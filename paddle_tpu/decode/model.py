@@ -19,7 +19,11 @@ heads, the first pool alone, and defines both mixers itself:
 a hybrid's recurrent layers keep a state a sequence in buffers of
 their own, ``extra``, beside the pages of its attention layers:
 ``decode/state_entry.py``, under ``models/olmo_hybrid.py`` and
-``models/granite_hybrid.py``).
+``models/granite_hybrid.py``; a decoder-hybrid-decoder has layers that
+keep NOTHING: they read another layer's page run, or an activation an
+earlier layer hands on inside the program, and its prefill goes on with
+the prompt's last row alone from the layer after which no other row
+reaches a cache: ``models/phi4_flash.py``).
 
 Prefill is ONE jitted program per length *bucket* (the shared pow2
 ladder of ``paddle_tpu/bucket.py``, from 64 up to the sequence
@@ -158,12 +162,17 @@ class PageRunCache:
     way defines the mixers (``decode/state_entry.py``: a recurrent
     state a sequence beside the pages of the attention layers)."""
 
-    def prompt_mixer(self, lp, x, pos, heads, live):
+    def prompt_mixer(self, lp, x, pos, heads, live, kept=(), last=None):
         """Layer ``self.at`` over one whole prompt ``x`` (T, d) ->
         (the rows after the mixer's residual, what the layer keeps of
         the prompt for ``store_prompts``).  ``live`` (T,) bool or None:
         the rows that are not the bucket's padding, which causal
-        attention need not be told."""
+        attention need not be told.  ``kept``: what the layers before
+        this one keep, for a layer that reads ANOTHER layer's cache or
+        an activation it hands on; ``last``: the one row whose logits
+        are wanted, or None for all: a layer from which on no other row
+        reaches a cache may hand back that row alone, (1, d), and the
+        layers after it get it (``models/phi4_flash.py``)."""
         q, k, v = self.qkv(lp, x, pos, heads)
         a = self.prompt_attention(q, k, v)
         return self.attn_out(lp, x, a.reshape(x.shape[0], -1)), (k, v)
@@ -274,21 +283,30 @@ def _stack_reports(reports):
     return None if reports[0] is None else jnp.stack(reports)
 
 
-def _dense_blocks(block, params, tokens, heads, live):
+def _dense_blocks(block, params, tokens, heads, live, last=None):
     """The dense causal forward over (T,) tokens up to the head: the
-    last block's output (T, d), what each layer keeps of the prompt
+    last block's output, what each layer keeps of the prompt
     (attention: its K/V rows (T, heads, dh)) and the layers' reports.
-    Pure: the eager oracle and the jitted prefill both run it."""
+    The output is every row's (T, d), or with ``last`` (a prefill's row
+    ``n - 1``) that row's alone (1, d): a block may then stop the other
+    rows at the layer after which they reach no cache
+    (``PageRunCache.prompt_mixer``), and the layers below it run on the
+    one row.  Pure: the eager oracle and the jitted prefill both run
+    it."""
     T = tokens.shape[0]
     pos = jnp.arange(T, dtype=jnp.int32)
     x = block.embed(params, tokens, slice(0, T))
     kept, reports = [], []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        x, keep = lb.prompt_mixer(lp, x, pos, heads, live)
+        x, keep = lb.prompt_mixer(lp, x, pos, heads, live, kept, last)
+        if x.shape[0] != pos.shape[0]:
+            pos, live = last[None], None     # row ``last`` alone goes on
         kept.append(keep)
         x, report = lb.mlp(lp, x, live)
         reports.append(report)
+    if last is not None and x.shape[0] != 1:
+        x = jax.lax.dynamic_slice_in_dim(x, last, 1)
     return x, kept, _stack_reports(reports)
 
 
@@ -641,19 +659,21 @@ def _layer_pages(k_pool, v_pool, li, tables):
 def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
                     block=GPT2, extra=()):
     """The whole prefill of one prompt padded to ``tokens.shape[0]``
-    rows: the dense forward, K/V row ``i`` of every layer scattered to
-    pool row ``flat[i]`` of the donated pools, and the logits of row
-    ``n - 1`` (the 50k-wide head runs on that row alone).  Its shape
-    depends on the bucket only, not on the prompt's length or pages.
+    rows: the dense forward (of every layer on every row, or of a block
+    that stops half-way down, on row ``n - 1`` alone from the layer it
+    says), what each layer keeps written to the donated pools (K/V row
+    ``i`` at pool row ``flat[i]``), and the logits of row ``n - 1`` (the
+    vocabulary-wide head runs on that row alone).  Its shape depends on
+    the bucket only, not on the prompt's length or pages.
     Rows from ``n`` on are padding: not ``live`` to the block.
     ``extra``: the block's cache buffers beyond the two pools, donated
     with them and handed back last, as by every program here."""
     _M_PREFILL_PROGRAMS.inc(bucket=str(tokens.shape[0]))   # at trace
     live = jnp.arange(tokens.shape[0], dtype=jnp.int32) < n
-    x, kept, report = _dense_blocks(block, params, tokens, heads, live)
+    last, kept, report = _dense_blocks(block, params, tokens, heads, live,
+                                       n - 1)
     k_pool, v_pool, *extra = block.store_prompts(
         (k_pool, v_pool, *extra), kept, flat)
-    last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1)
     return (block.head(params, last)[0], k_pool, v_pool, report,
             tuple(extra))
 
@@ -748,6 +768,8 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
         x, report = lb.mlp(lp, x, live)
         reports.append(report)
     logits = block.head(params, x)
+    # an activation a layer hands to later ones rides behind the buffers
+    # in the tuple the mixers thread: the buffers alone are handed back
     return (logits, *cache[:2], _stack_reports(reports),
             _greedy_ids(logits), lens + live.astype(lens.dtype),
-            tuple(cache[2:]))
+            tuple(cache[2:2 + len(extra)]))

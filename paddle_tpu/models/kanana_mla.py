@@ -246,7 +246,7 @@ class KananaMlaBlock(PageRunCache):
 
     # -- the cache side -----------------------------------------------------
 
-    def prompt_mixer(self, lp, x, pos, heads, live):
+    def prompt_mixer(self, lp, x, pos, heads, live, kept=(), last=None):
         """One whole prompt, expanded -> (rows after the residual, the
         stored rows (T, width))."""
         with jax.named_scope("attn_latent"):

@@ -304,7 +304,7 @@ class OlmoHybridBlock(StateEntryCache):
 
     # -- the mixers ---------------------------------------------------------
 
-    def prompt_mixer(self, lp, x, pos, heads, live):
+    def prompt_mixer(self, lp, x, pos, heads, live, kept=(), last=None):
         if not self.recurrent:
             return super().prompt_mixer(lp, x, pos, heads, live)
         T = x.shape[0]

@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Ten kernel families, fifteen ``pl.pallas_call``s: the fused
+Eleven kernel families, sixteen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -13,7 +13,9 @@ names: the chunk kernel is also called on grouped heads, Hq query heads
 on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
 delta rule's one-token step over a decode step's state entries
 (``gated_delta.py``, 1), Mamba-2's on the same layout
-(``ssd_step.py``, 1), the depthwise conv before either, over the
+(``ssd_step.py``, 1), Mamba-1's, whose decay is as large as the state
+and is made in VMEM (``s6_step.py``, 1), the depthwise conv before
+any of them, over the
 same entries' kept rows (``conv_step.py``, 1), the gated delta rule
 chunked over a prefill bucket's rows, the state in VMEM from chunk to
 chunk (``gated_delta_chunked.py``, 1), and absorbed latent attention
@@ -24,7 +26,7 @@ double buffer, where the ragged kernels take a grid step a table
 column).  The latent pool's rows are stored at 640 lanes for the 576
 the algorithm needs: at 576 the chip's compiler lays the pool out at
 640 anyway and refuses the kernel's page copy ("slice shape must be
-aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).  The tenth is the grouped GEMM
+aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).  The last is the grouped GEMM
 of a routed layer's experts over a prefill bucket's sorted rows
 (``grouped_gemm.py``, 2, one kernel body: ``grouped_gemm`` and, two
 matrices a visit and ``silu(g) * u`` written, ``grouped_gemm_gate_up``):
@@ -41,7 +43,7 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
-  the gated delta, SSD and conv steps, the chunked gated delta rule of
+  the gated delta, SSD, S6 and conv steps, the chunked gated delta rule of
   a hybrid's prefill, latent paged attention, the experts' grouped
   GEMM) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
@@ -201,6 +203,15 @@ def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:
 
     return dispatch("ssd_step", policy(
         _s.fits(state_dtype, rows, d_state, lanes), True))
+
+
+def use_s6_step(state_dtype, d_state: int, channels: int) -> bool:
+    """A Mamba-1 layer's decode step over entries ``(d_state,
+    channels)``, by the same rule as ``use_ssd_step``."""
+    from paddle_tpu.pallas import s6_step as _s
+
+    return dispatch("s6_step", policy(
+        _s.fits(state_dtype, d_state, channels), True))
 
 
 def use_conv_step(pool_dtype, entry_shape, row_dtype, taps: int,

@@ -1264,6 +1264,208 @@ def test_kanana_top_prefill_fits_beside_weights_and_latent_rows(
     _assert_grouped_gemm_kernel(text, L - 1, looped=True)
 
 
+# -- a decoder-hybrid-decoder (PR 48) -----------------------------------------
+
+# memory_analysis() of the two programs at the configuration's 7,041
+# pages: what perf/configs/phi-4-mini-flash-reasoning.json records
+PHI4_PLANS = {"decode": 12_723_929_088, 12288: 14_930_227_200}
+PHI4_PARAMS = 3_852_562_944
+
+
+def _phi4_cell(one_chip, monkeypatch):
+    """The ``phi-4-mini-flash-reasoning`` generate configuration at its
+    real sizes, as shapes on the described chip, built as its gen_config
+    builds the model: (cfg, params, K/V pool, (state_pool, conv_pool),
+    block, table width, sds)."""
+    import functools
+    import json
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode.state_entry import tail_shape
+    from paddle_tpu.models import phi4_flash as pf
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        cfg = json.load(f)
+    g, L, sizes = cfg["generate"], cfg["num_hidden_layers"], \
+        cfg["assumed_sizes"]
+    assert cfg["reduced"] == [] and L == 32
+    dtype = jnp.dtype(g["dtype"])
+    types = pf.layer_kinds(L, cfg["mb_per_layer"])
+    H, KV, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                cfg["hidden_size"])
+    dh, C, N = sizes["head_dim"], sizes["mamba_expand"] * d, \
+        sizes["mamba_d_state"]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(
+            pf.init_params, jax.random.key(0), vocab=cfg["vocab_size"],
+            d=d, heads=H, kv_heads=KV, head_dim=dh, layer_types=types,
+            width=cfg["intermediate_size"], d_inner=C, d_state=N,
+            dt_rank=sizes["mamba_dt_rank"], conv=sizes["mamba_d_conv"],
+            dtype=dtype)))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == PHI4_PARAMS
+    rings = sum(t == pf.WINDOW for t in types)
+    ring_pages = cfg["sliding_window"] // g["page_size"] + 1
+    assert (rings, ring_pages) == (8, g["ring_pages"]) == (8, 5)
+    block = pf.Phi4FlashBlock(
+        layer_types=types, kv_heads=KV, head_dim=dh,
+        window=cfg["sliding_window"], d_inner=C, d_state=N,
+        dt_rank=sizes["mamba_dt_rank"], eps=cfg["layer_norm_eps"],
+        full_pages=g["pages_per_seq"], ring_pages=ring_pages)
+    # a K/V PAIR a stored row of 128 lanes, a page's ten stored heads
+    # outside its rows: whole tiles whatever the head count
+    pool = sds((1, g["num_pages"], KV // 2, g["page_size"], 2 * dh), dtype)
+    E, mamba = g["state_entries"], sum(t == pf.MAMBA for t in types)
+    assert E == g["slots"] + 1 and mamba == 9
+    extra = (sds((mamba, E, N, C), jnp.float32),
+             sds((mamba, E, *tail_shape(sizes["mamba_d_conv"], C)), dtype))
+    assert extra[1].shape[2:] == (120, 128)
+    width = g["pages_per_seq"] + rings * ring_pages + 1
+    return cfg, params, pool, extra, block, width, sds
+
+
+def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
+        one_chip, monkeypatch):
+    """The decode step of the ``phi-4-mini-flash-reasoning``
+    configuration at its real sizes (7,041 bf16 pages of 10 stored heads
+    x 128 rows x 128 lanes, 65 state entries of nine (16, 5,120) float32
+    states, 64 slots, a table row of 96 + 40 + 1 columns): the four
+    cache buffers are aliased input to output and the plan is the
+    arguments + 142 MB; every Mamba-1 layer advances the slots' states
+    by ONE ``s6_step`` call under ``ssm/ssm_state`` (the pool its
+    in-place operand) after ONE ``conv_step`` call under
+    ``ssm/ssm_conv``; the page run's owner and the seven cross layers
+    run the grouped paged kernel on the heads-major pages under
+    ``attn_shared``, EIGHT calls, and only TWO scatters lie under it:
+    the owner's K and V row; a cross layer writes nothing.  Nothing has
+    a pool's size but the pools (this is the probe that chose the
+    layout: with the ten heads inside a page's rows, ``(N, 128, 10,
+    128)``, the same step planned 5.6 GB of copies of the pool, 1.6
+    times its bytes each)."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, extra, block, width, sds = _phi4_cell(
+        one_chip, monkeypatch)
+    g, S = cfg["generate"], cfg["generate"]["slots"]
+    assert width == 137
+    compiled = dm._decode_step.lower(
+        params, pool, pool, sds((S, width), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.int32),
+        heads=cfg["num_attention_heads"], page_size=g["page_size"],
+        block=block, extra=extra).compile()
+    out = jax.tree.leaves(compiled.out_info)
+    assert (out[0].shape, out[0].dtype) == ((S, cfg["vocab_size"]),
+                                            jnp.float32)
+    # the buffers alone are handed back: what layer 16 hands the GMUs
+    # is no output
+    assert [o.shape for o in out[-2:]] == [e.shape for e in extra]
+    m = compiled.memory_analysis()
+    buffers = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in (pool, pool) + extra)
+    assert m.alias_size_in_bytes >= buffers
+    planned = _planned_bytes(compiled)
+    assert planned == PHI4_PLANS["decode"] < 15.0e9, planned
+    assert m.temp_size_in_bytes < 160 << 20
+    text = compiled.as_text()
+    # the 18 MB tail pool is small enough that the compiler moves it to
+    # fast memory and back round the conv kernels (copy-start / -done to
+    # S(1)): no layout copy, and not held here
+    sizes = _hybrid_sizes(pool, extra)
+    del sizes[math.prod(extra[1].shape)]
+    assert not _pool_sized_strays(text, sizes)
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    assert sum("/attn_shared/" in ln for ln in scatters) == 2
+    assert all("/attn_shared/" in ln or "/attn_window/" in ln
+               for ln in scatters)
+    kernels = _kernel_op_names(text)
+    gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
+    assert len(gqa) == 8 and all("_decode_step)/attn_shared/" in op
+                                 for op in gqa)
+    step = [op for op in kernels if "s6_step/" in op]
+    conv = [op for op in kernels if "conv_step/" in op]
+    assert len(step) == len(conv) == 9 and len(kernels) == 26
+    assert all("_decode_step)/ssm/ssm_state/" in op for op in step)
+    assert all("_decode_step)/ssm/ssm_conv/" in op for op in conv)
+    # each writes the pool it was given as its output 1: the states
+    # operand 6 (entries, dt, x, A, B, C, pool), the tails operand 4
+    for name, operand in (("s6_step/", 6), ("conv_step/", 4)):
+        aliased = f"output_to_operand_aliasing={{{{1}}: ({operand}, {{}})}}"
+        assert sum(name in ln and aliased in ln
+                   for ln in text.splitlines()) == 9, name
+    assert not re.search(r"/ssm/while/", text)
+    for scope in ("attn_window", "gmu"):
+        assert f"jit(_decode_step)/{scope}/" in text, scope
+
+
+def test_phi4_top_prefill_fits_beside_weights_states_rings_and_the_run(
+        one_chip, monkeypatch):
+    """The 12,288-row prefill bucket (a sequence's capacity; the
+    traffic's 10,500-row prompts run in it): the plan, 14.93 GB, is the
+    configuration's ``planned_bytes`` and fits 15.0 GB beside 7.71 GB of
+    weights, 4.61 GB of pages and 0.21 GB of state entries; all four
+    buffers are aliased; the selective scan materialises no ``rows x
+    5,120 x 16`` tensor (4 GB at this bucket): it is a loop under
+    ``ssm/ssm_scan`` whose body holds a state; the layers from the full
+    one on run on ONE row (no 12,288-row instruction lies under ``gmu``,
+    and the only ones under ``attn_shared`` are the K/V rows'); and
+    nothing else has a pool's size."""
+    from paddle_tpu.decode import model as dm
+
+    cfg, params, pool, extra, block, width, sds = _phi4_cell(
+        one_chip, monkeypatch)
+    bucket = 12288
+    compiled = dm._prefill_bucket.lower(
+        params, pool, pool, sds((bucket,), jnp.int32),
+        (sds((9, bucket), jnp.int32), sds((), jnp.int32)),
+        sds((), jnp.int32), heads=cfg["num_attention_heads"], block=block,
+        extra=extra).compile()
+    m = compiled.memory_analysis()
+    buffers = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in (pool, pool) + extra)
+    assert m.alias_size_in_bytes >= buffers
+    planned = _planned_bytes(compiled)
+    assert planned == PHI4_PLANS[bucket] < 15.0e9, planned
+    assert cfg["generate"]["planned_bytes"] == planned
+    text = compiled.as_text()
+    assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
+    assert "12288,16,5120" not in text and "12288,5120,16" not in text
+    assert re.search(r"_prefill_bucket\)/ssm/ssm_scan/while", text)
+    for scope in ("ssm/ssm_conv", "attn_window", "attn_shared", "gmu"):
+        assert f"jit(_prefill_bucket)/{scope}/" in text, scope
+    for ln in text.splitlines():
+        if "/gmu/" in ln:
+            assert "12288" not in ln.split("metadata")[0], ln
+
+
+def test_s6_step_compiles(one_chip):
+    """The kernel alone at the published entry (state 16 down, 5,120
+    channels along the lanes) float32, 64 slots on 65 entries of one
+    layer: one block a slot, the pool aliased."""
+    from paddle_tpu.pallas import s6_step as s6
+
+    S, E, N, C = 64, 65, 16, 5120
+    text = _compiled_text(
+        lambda pool, at, dt, x, A, B, Cc: s6.s6_step(pool, at, dt, x, A,
+                                                     B, Cc),
+        one_chip, ((E, N, C), jnp.float32), ((S,), jnp.int32),
+        ((S, C), jnp.float32), ((S, C), jnp.float32),
+        ((N, C), jnp.float32), ((S, N), jnp.float32),
+        ((S, N), jnp.float32))
+    assert s6.channel_block(N, C) == C
+    assert [op.split("/")[-2] for op in _kernel_op_names(text)] == [
+        "s6_step"]
+    assert "output_to_operand_aliasing={{1}: (6, {})}" in text
+
+
 def test_ssd_step_compiles(one_chip):
     """The kernel alone at the published entry (64 heads of 64
     channels, state 128: 32 rows of two heads, 128 down, 128 lanes)
@@ -1283,11 +1485,13 @@ def test_ssd_step_compiles(one_chip):
 
 
 @pytest.mark.parametrize("channels, slots, bias", [
-    (4352, 64, True), (11520, 48, False)], ids=["granite", "olmo_hybrid"])
+    (4352, 64, True), (11520, 48, False), (5120, 64, True)],
+    ids=["granite", "olmo_hybrid", "phi4_flash"])
 def test_conv_step_compiles(one_chip, channels, slots, bias):
     """The kernel alone at both cells' shapes, bfloat16: a slot a grid
     step on an entry of 3 x C / 128 rows of lanes (102 / 270: tap j
-    starts at no tile's edge), the pool aliased."""
+    starts at no tile's edge; 120 at 5,120 channels, where it does), the
+    pool aliased."""
     from paddle_tpu.decode.state_entry import tail_shape
     from paddle_tpu.pallas import conv_step as cs
 

@@ -7,7 +7,8 @@ budgets, the load generator's token-clocked starts (PR 36's cases,
 imported and not copied), the expert layer's prefill readers (PR 38's
 cases, imported too), the state-space layer's readers and the new
 cell's entries (PR 41's), the latent layer's readers and its cell's
-entries (PR 45's), and the readers of the decode tick's own
+entries (PR 45's), the decoder-hybrid-decoder's cell, its readers and
+its rehearsal (PR 48's), and the readers of the decode tick's own
 account (PR 37) against a registry pair recorded from a session and
 against spans laid over the device plane of the trace recorded on the
 chip (``perf/tests/data``).
@@ -23,7 +24,7 @@ pytest.register_assert_rewrite(
     "perf.tests.test_traffic", "perf.tests.test_loadgen",
     "perf.tests.test_moe_prefill_readers", "perf.tests.test_ssm_readers",
     "perf.tests.test_granite_cell", "perf.tests.test_kanana_cell",
-    "perf.tests.test_latent_readers")
+    "perf.tests.test_latent_readers", "perf.tests.test_phi4_flash_cell")
 
 from perf.harness import program_spans as ps  # noqa: E402
 from perf.harness import tick_account as ta  # noqa: E402
@@ -38,6 +39,7 @@ from perf.tests.test_moe_prefill_readers import (  # noqa: E402,F401
 from perf.tests import test_granite_cell as _granite_cell  # noqa: E402
 from perf.tests import test_kanana_cell as _kanana_cell  # noqa: E402
 from perf.tests import test_latent_readers as _latent_readers  # noqa: E402
+from perf.tests import test_phi4_flash_cell as _phi4_cell  # noqa: E402
 from perf.tests.test_granite_cell import (  # noqa: E402,F401
     test_correct_holds_the_attention_layers_and_the_state,
     test_the_configuration_is_the_catalogs_row_uncut,
@@ -104,15 +106,37 @@ def test_the_cell_is_appended_where_it_reports(monkeypatch):
     _granite_cell.test_the_cell_is_appended_where_it_reports()
 
 
-# PR 45's cases whose names PR 41's already have in this module
+_CASE_ID = lambda f: f.__module__.rsplit(".", 1)[1] + "." + f.__name__  # noqa: E731,E501
+
+
+# PR 45's cases whose names PR 41's already have in this module; the one
+# that holds its cell to be the LAST of 9 sees the benchmark as PR 45
+# left it
 @pytest.mark.parametrize("case", [
     _kanana_cell.test_the_cell_is_appended_where_it_reports,
     _kanana_cell.test_the_traffic_is_the_issues_letter_for_letter,
     _latent_readers.test_a_program_without_the_scopes_reads_nothing,
     _latent_readers.test_sizes_and_the_algorithms_counts,
-    _latent_readers.test_the_four_readers_arithmetic],
-    ids=lambda f: f.__module__.rsplit(".", 1)[1] + "." + f.__name__)
-def test_the_latent_cell_and_its_readers(case):
+    _latent_readers.test_the_four_readers_arithmetic], ids=_CASE_ID)
+def test_the_latent_cell_and_its_readers(case, monkeypatch):
+    monkeypatch.setattr(_kanana_cell, "BENCH",
+                        _as_left_with(_kanana_cell.BENCH, 9, 73))
+    case()
+
+
+# PR 48's: the decoder-hybrid-decoder's cell (the last of 10 today)
+@pytest.mark.parametrize("case", [
+    _phi4_cell.test_the_traffic_is_the_issues_letter_for_letter,
+    _phi4_cell.test_the_long_prompts_are_spread_and_the_budgets_dealt_alike,
+    _phi4_cell.test_the_configuration_is_the_catalogs_row_uncut,
+    _phi4_cell.test_the_cell_is_appended_where_it_reports,
+    _phi4_cell.test_correct_holds_every_ablation_and_the_state,
+    _phi4_cell.test_sizes_and_the_algorithms_counts,
+    _phi4_cell.test_the_readers_arithmetic,
+    _phi4_cell.test_a_program_without_the_scopes_or_the_counters_reads_nothing,  # noqa: E501
+    _phi4_cell.test_the_cell_rehearses_traced_and_reads_every_new_metric],
+    ids=_CASE_ID)
+def test_the_decoder_hybrid_decoder_cell_and_its_readers(case):
     case()
 
 
