@@ -270,16 +270,71 @@ def ragged_paged_attention_chunk_reference(q, k_pages, v_pages,
     return out.astype(q.dtype)
 
 
+def _ring_held(newest, r, R: int):
+    """The page that ring column ``r`` of ``R`` holds once page
+    ``newest`` is the newest written: the newest ``pi <= newest`` with
+    ``pi % R == r``; negative while the ring has not reached the
+    column."""
+    return newest - (newest + R - r) % R
+
+
+def ring_column_seen(lens, T: int, r, page: int, R: int, window: int):
+    """Whether any row of a chunk (``T <= page`` rows at positions
+    ``lens .. lens + T - 1``) sees a key of ring column ``r``: the
+    window kernel's page guard, on scalars (ints, arrays or traced).
+    The chunk's rows lie on one page or on two; a column is reckoned
+    for each from the oldest row there, which looks furthest back.  A
+    column no row sees: the ring has not reached it, or it holds the
+    oldest page and the window ends on that page's edge."""
+    def seen(first, newest):
+        held = _ring_held(newest, r, R)
+        return (held >= 0) & (first - (held * page + page - 1) < window)
+
+    n0 = lens // page
+    if T == 1:
+        return seen(lens, n0)
+    n1 = (lens + T - 1) // page
+    return seen(lens, n0) | seen(jnp.maximum(lens, n1 * page), n1)
+
+
+def _ring_seen(shape, lens, r, page: int, R: int, T: int, G: int,
+               window: int):
+    """The window kernel's mask over ring column ``r``'s scores, ``shape``
+    = (H, T * G, page): row ``t * G + g`` stands at ``lens + t`` and
+    sees the key at ``held * page + lane`` when it is its own or one of
+    the ``window - 1`` before it.  Rows from the next page's edge on
+    (``T <= page``: at most one edge) reckon the column as that page
+    leaves it, as ``ring_window_attention`` does a row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    n0 = lens // page
+    base = _ring_held(n0, r, R) * page
+    pos = lens
+    if T > 1:
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        pos = lens + (row // G if G > 1 else row)
+        base = jnp.where(pos >= (n0 + 1) * page,
+                         _ring_held(n0 + 1, r, R) * page, base)
+    back = pos - (base + lane)
+    return (base >= 0) & (back >= 0) & (back < window)
+
+
 def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                       m_scr, l_scr, acc_scr, *, scale, page, npp, T, G=1,
-                      heads_major=False):
+                      heads_major=False, window=None):
     """Chunked variant of ``_rpa_kernel``: the q block holds the slot's
     whole T-token chunk; masking offsets the length limit per row.
     With ``G`` > 1 (grouped heads) the q block's ``T * G`` rows are the
     chunk's rows times the G query heads that read each of the block's
     K/V heads, row ``t * G + g`` at the chunk's row ``t``: the group
     rides the one K/V page, read once.  ``heads_major``: a page is
-    stored (H, page, D), as the two batched dots take it."""
+    stored (H, page, D), as the two batched dots take it.
+
+    ``window``: the table's ``npp`` columns are a window layer's ring
+    (``ring_window_attention``'s contract and its arithmetic: which
+    page a column holds and where its keys stand are reckoned from the
+    rows' positions, on scalars and one iota a page) and a row sees its
+    own key and the ``window - 1`` before it.  The columns are walked
+    as they lie: a softmax does not mind the order."""
     s = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -291,9 +346,15 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     seq_len = lens_ref[s]
 
-    # last chunk row reaches position seq_len + T - 1: pages wholly past
-    # that contribute to no query row and skip their math + DMA
-    @pl.when(p * page < seq_len + T)
+    if window is None:
+        # last chunk row reaches position seq_len + T - 1: pages wholly
+        # past that contribute to no query row and skip their math + DMA
+        live = p * page < seq_len + T
+    else:
+        # so does a ring column no row of the chunk sees a key of
+        live = ring_column_seen(seq_len, T, p, page, npp, window)
+
+    @pl.when(live)
     def _page():
         q = q_ref[0].astype(_F32)                       # (T * G, H, D)
         k = k_ref[0].astype(_F32)                       # (page, H, D)
@@ -304,11 +365,16 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         sc = jax.lax.dot_general(
             jnp.swapaxes(q, 0, 1), k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=_F32) * scale
-        t_pos = p * page + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-        row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        if G > 1:
-            row = row // G
-        sc = jnp.where(t_pos < seq_len + row + 1, sc, _NEG_INF)
+        if window is None:
+            t_pos = p * page + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 2)
+            row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            if G > 1:
+                row = row // G
+            sc = jnp.where(t_pos < seq_len + row + 1, sc, _NEG_INF)
+        else:
+            sc = jnp.where(_ring_seen(sc.shape, seq_len, p, page, npp, T,
+                                      G, window), sc, _NEG_INF)
         m_prev = m_scr[...]                             # (H, T, 1)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
         pr = jnp.exp(sc - m_new)                        # (H, T, page)
@@ -328,24 +394,36 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
-                G, heads_major=False):
+                G, heads_major=False, window=None):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
     one grid step per (slot, page), the whole chunk resident in the q/o
-    blocks.  A page is (page, H, D), or (H, page, D) ``heads_major``."""
+    blocks.  A page is (page, H, D), or (H, page, D) ``heads_major``.
+    ``window``: the table is a ring's columns, ``lens`` the position of
+    the chunk's first row."""
     S, TG, H, D = q.shape
     page = k_pages.shape[2 if heads_major else 1]
     P = page_tables.shape[1]
     if scale is None:
         scale = D ** -0.5
+    if window is None:
+        def page_of(s, p, pt, ln):
+            return (pt[s, p], 0, 0, 0)
+    else:
+        def page_of(s, p, pt, ln):
+            # a column no row sees names the slot's newest page, which
+            # one does: no id of a column the ring has not reached is
+            # read, and an index repeated from the step before costs no
+            # copy
+            seen = ring_column_seen(ln[s], TG // G, p, page, P, window)
+            return (pt[s, jnp.where(seen, p, (ln[s] // page) % P)],
+                    0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, P),
         in_specs=[
             pl.BlockSpec((1, TG, H, D), lambda s, p, pt, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1,) + k_pages.shape[1:],
-                         lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
-            pl.BlockSpec((1,) + v_pages.shape[1:],
-                         lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
+            pl.BlockSpec((1,) + k_pages.shape[1:], page_of),
+            pl.BlockSpec((1,) + v_pages.shape[1:], page_of),
         ],
         out_specs=pl.BlockSpec((1, TG, H, D),
                                lambda s, p, pt, ln: (s, 0, 0, 0)),
@@ -357,7 +435,7 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
     )
     kernel = functools.partial(_rpa_chunk_kernel, scale=scale, page=page,
                                npp=P, T=TG // G, G=G,
-                               heads_major=heads_major)
+                               heads_major=heads_major, window=window)
     call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, TG, H, D), q.dtype),
@@ -366,7 +444,11 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
         interpret=interpret)
     args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
             q, k_pages, v_pages)
-    # one kernel under two names: a trace tells the grouped call apart
+    # one kernel under three names: a trace tells the grouped call and
+    # the ring's apart
+    if window is not None:
+        return pl.pallas_call(
+            kernel, name="ring_paged_attention", **call)(*args)
     if G > 1:
         return pl.pallas_call(
             kernel, name="ragged_paged_attention_gqa", **call)(*args)
@@ -426,6 +508,14 @@ def ragged_paged_attention_gqa(q, k_pages, v_pages, page_tables, lens,
     chunk, so a page is read once for all Hq heads and the bytes read
     are the Hkv heads', whatever Hq is.  A decode step is the chunk of
     one row."""
+    return _grouped_call(q, k_pages, v_pages, page_tables, lens, scale,
+                         interpret, heads_major)
+
+
+def _grouped_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
+                  heads_major, window=None):
+    """The chunk kernel on grouped heads: over a page run's table
+    columns, or over a ring's under ``window``."""
     S, T, Hq, D = q.shape
     Hkv = k_pages.shape[1 if heads_major else 2]
     G = Hq // Hkv
@@ -434,9 +524,28 @@ def ragged_paged_attention_gqa(q, k_pages, v_pages, page_tables, lens,
     rows = jnp.moveaxis(q.reshape(S, T, Hkv, G, D), 3, 2).reshape(
         S, T * G, Hkv, D)
     out = _chunk_call(rows, k_pages, v_pages, page_tables, lens, scale,
-                      interpret, G, heads_major)
+                      interpret, G, heads_major, window)
     return jnp.moveaxis(out.reshape(S, T, G, Hkv, D), 2, 3).reshape(
         S, T, Hq, D)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret",
+                                             "heads_major"))
+def ring_paged_attention(q, k_pages, v_pages, ring_tables, lens, window,
+                         scale=None, interpret: bool = False,
+                         heads_major: bool = False):
+    """Pallas attention of a window layer over its rings where they lie
+    in the pool: q (S, T, Hq, D), a chunk of ``T <= page`` rows a slot
+    whose first stands at position ``lens`` (S,); k/v_pages (N, page,
+    Hkv, D), or (N, Hkv, page, D) ``heads_major``, already written up to
+    the chunk's last row; ``ring_tables`` (S, R) the ring's table
+    columns, column ``r`` holding the newest page ``pi`` with ``pi % R
+    == r`` -> (S, T, Hq, D).  ``ring_window_attention`` on the gathered
+    rings is its reference: the same rows under the same mask, the
+    grouped chunk kernel reading a page once at the width it is stored
+    in, nothing gathered, turned or widened outside VMEM."""
+    return _grouped_call(q, k_pages, v_pages, ring_tables, lens, scale,
+                         interpret, heads_major, window)
 
 
 def _grouped(q, k_pages) -> bool:
@@ -463,10 +572,46 @@ def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale,
         heads_major=heads_major)
 
 
+def paged_ring_attention(q, k_pages, v_pages, ring_tables, pos, window: int,
+                         heads_major: bool = False):
+    """Dispatcher of a window layer's cached attention: q (S, T, Hq, D)
+    at positions ``pos`` (S, T), a chunk's one after another; the pages
+    as ``paged_attention`` takes them and ``ring_tables`` (S, R) the
+    ring's table columns.  Pages stored heads-major are read where they
+    lie by the kernel (``ring_paged_attention``); row-major pages are
+    gathered for ``ring_window_attention``, whose gather XLA fuses into
+    the scores' product.  Read on the chip (PERF.md section 6, PR 50):
+    K-EXAONE's six row-major rings of 256 rows took 0.80 ms a step
+    gathered and 0.90 through the kernel, Phi-4-mini-flash's eight
+    heads-major rings of 640 rows 8.1 gathered (a gather, a transpose
+    of the copy and a widening of both before the products) and 3.4
+    through the kernel.  The layout is what the caller states for its
+    pool; there is no threshold."""
+    from paddle_tpu import pallas as pk
+
+    T, Hq, D = q.shape[1:]
+    page, Hkv = k_pages.shape[1:3]
+    if heads_major:
+        page, Hkv = Hkv, page
+    if pk.dispatch("ring_paged_attention", pk.policy(
+            heads_major and T <= page and fits(page, Hq, D, Hkv), True)):
+        return ring_paged_attention(
+            q, k_pages, v_pages, ring_tables, pos[:, 0], window,
+            interpret=pk.interpret_mode(), heads_major=True)
+    k_ring, v_ring = k_pages[ring_tables], v_pages[ring_tables]
+    if heads_major:
+        # a ring's pages as rows of heads
+        k_ring, v_ring = jnp.swapaxes(k_ring, 2, 3), jnp.swapaxes(v_ring, 2, 3)
+    return ring_window_attention(q, k_ring, v_ring, pos, window, page)
+
+
 def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
     """Attention of a window layer over its per-sequence ring, plain
-    XLA (a ring is a few pages a slot, 256 rows at a window of 128 and
-    640 at 512: the gather IS the read).
+    XLA, on a gathered copy of the ring (a few pages a slot, 256 rows
+    at a window of 128 and 640 at 512).  Of row-major pages the gather
+    IS the read: XLA fuses it into the scores' product.  Of heads-major
+    pages it is a copy that is then turned and widened, and
+    ``paged_ring_attention`` takes the kernel, whose oracle this is.
 
     q (S, T, Hq, D) at absolute positions ``pos`` (S, T); k/v_ring
     (S, R, page, Hkv, D): ring slot ``r`` holds the newest page ``pi``

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.decode.attention import (
     banded_prefill_attention,
-    ring_window_attention,
+    paged_ring_attention,
 )
 from paddle_tpu.decode.model import (
     PagedDecoderLM,
@@ -201,10 +201,11 @@ class ExaoneMoeBlock(PageRunCache):
             k_pool = _write_rows(k_pool, 0, rows, k.reshape(-1, H, dh))
             v_pool = _write_rows(v_pool, 0, rows, v.reshape(-1, H, dh))
             # the pool seen as pages is a bitcast (``k_pool[0]`` would
-            # be a slab the size of the pool); the gather is the read
+            # be a slab the size of the pool); of these row-major pages
+            # the gather is the read
             k_pages, v_pages, _ = _layer_pages(k_pool, v_pool, 0, ring)
-            a = ring_window_attention(qc, k_pages[ring], v_pages[ring],
-                                      pos, self.window, pg)
+            a = paged_ring_attention(qc, k_pages, v_pages, ring, pos,
+                                     self.window)
         return (a[:, 0] if step else a), k_pool, v_pool
 
 

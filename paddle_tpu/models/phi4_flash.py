@@ -121,7 +121,7 @@ from paddle_tpu.decode.attention import (
     banded_prefill_attention,
     dense_prefill_attention,
     paged_attention,
-    ring_window_attention,
+    paged_ring_attention,
 )
 from paddle_tpu.decode.state_entry import (
     StateEntryCache,
@@ -523,10 +523,9 @@ class Phi4FlashBlock(StateEntryCache):
                     + pos % pg).reshape(-1)
             k_pool = write_rows(k_pool, rows, k)
             v_pool = write_rows(v_pool, rows, v)
-            # the gather is the read; a ring's pages as rows of heads
-            a = ring_window_attention(
-                q[:, None], jnp.swapaxes(k_pool[0][ring], 2, 3),
-                jnp.swapaxes(v_pool[0][ring], 2, 3), pos, self.window, pg)
+            # the ring's pages are read where they lie in the pool
+            a = paged_ring_attention(q[:, None], k_pool[0], v_pool[0], ring,
+                                     pos, self.window, heads_major=True)
         return a[:, 0], k_pool, v_pool
 
     def _shared_step(self, k_pool, v_pool, q, k, v, addr):

@@ -4,13 +4,18 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Eleven kernel families, sixteen ``pl.pallas_call``s: the fused
+Eleven kernel families, seventeen ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
-ragged paged attention (``decode/attention.py``, 2 kernels under 3
+ragged paged attention (``decode/attention.py``, 2 kernels under 4
 names: the chunk kernel is also called on grouped heads, Hq query heads
-on Hkv K/V heads, as ``ragged_paged_attention_gqa``), the gated
+on Hkv K/V heads, as ``ragged_paged_attention_gqa``, and, under a
+window mask reckoned from positions, over a window layer's ring of
+pages where they lie in the pool, as ``ring_paged_attention``: taken
+for pages stored heads-major, ``decode/attention.py:
+paged_ring_attention``; of row-major pages XLA fuses the gather of a
+ring into the scores' product and the kernel read slower), the gated
 delta rule's one-token step over a decode step's state entries
 (``gated_delta.py``, 1), Mamba-2's on the same layout
 (``ssd_step.py``, 1), Mamba-1's, whose decay is as large as the state

@@ -47,6 +47,15 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _ring_dispatches():
+    """``pallas_dispatch_total{kernel="ring_paged_attention"}`` by path:
+    one decision a window layer a traced program."""
+    from paddle_tpu import pallas as pk
+
+    return {p: pk._M_DISPATCH.value(kernel="ring_paged_attention", path=p)
+            for p in ("compiled", "interpret", "reference")}
+
+
 def _kernel_op_names(text):
     """The op_name of every Pallas custom call of a compiled program:
     what a trace reduction finds a kernel's device events by."""
@@ -218,6 +227,32 @@ def test_ragged_paged_attention_gqa_compiles(one_chip, T):
     assert MARKER in text
     assert all("ragged_paged_attention_gqa/" in op
                for op in _kernel_op_names(text))
+
+
+@pytest.mark.parametrize("T", [1, 4], ids=["step", "chunk"])
+def test_ring_paged_attention_compiles(one_chip, T):
+    """A window layer's rings at the Phi-4-mini-flash serving shape: 64
+    slots of 40 query heads on 10 stored heads of 128, heads-major bf16
+    pages of 128 rows among 7,041, rings of five pages under a window of
+    512; the decode step's row and a chunk of four.  The custom call
+    carries the ring kernel's own name and not the grouped one's, which
+    ``perf/layer_metrics/attn_full_roofline.py`` counts against the
+    full layers' bytes."""
+    import functools
+
+    from paddle_tpu.decode import attention as A
+
+    S, Hq, Hkv, D, page, N, R, window, dt = 64, 40, 10, 128, 128, 7041, \
+        5, 512, jnp.bfloat16
+    assert A.fits(page, Hq, D, Hkv)
+    text = _compiled_text(
+        functools.partial(A.ring_paged_attention, window=window,
+                          heads_major=True), one_chip,
+        ((S, T, Hq, D), dt), ((N, Hkv, page, D), dt),
+        ((N, Hkv, page, D), dt), ((S, R), jnp.int32), ((S,), jnp.int32))
+    ops = _kernel_op_names(text)
+    assert len(ops) == 1 and "ring_paged_attention/" in ops[0]
+    assert "ragged_paged_attention_gqa" not in ops[0]
 
 
 def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
@@ -540,11 +575,16 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
     cfg, params, pool, shape, block, width, sds = _exaone_cell(
         one_chip, monkeypatch)
     g, L, S = cfg["generate"], cfg["num_hidden_layers"], 64
+    before = _ring_dispatches()
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block).compile()
+    # the six sliding layers' row-major rings stay on the gathered form
+    after = _ring_dispatches()
+    assert {p: after[p] - before[p] for p in after} == {
+        "compiled": 0, "interpret": 0, "reference": 6}
     _assert_step_outputs(compiled, S, cfg["vocab_size"])
     planned = _planned_bytes(compiled)
     assert planned == 12_078_473_216 < 15.75e9, planned
@@ -1267,8 +1307,10 @@ def test_kanana_top_prefill_fits_beside_weights_and_latent_rows(
 # -- a decoder-hybrid-decoder (PR 48) -----------------------------------------
 
 # memory_analysis() of the two programs at the configuration's 7,041
-# pages: what perf/configs/phi-4-mini-flash-reasoning.json records
-PHI4_PLANS = {"decode": 12_723_929_088, 12288: 14_930_227_200}
+# pages: the bucket's is what perf/configs/phi-4-mini-flash-reasoning.json
+# records; the step's was 12,723,929,088 (and is in that file's
+# ``planned_how``) while the rings were gathered, turned and widened
+PHI4_PLANS = {"decode": 12_623_595_520, 12288: 14_930_227_200}
 PHI4_PARAMS = 3_852_562_944
 
 
@@ -1340,13 +1382,17 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
     x 128 rows x 128 lanes, 65 state entries of nine (16, 5,120) float32
     states, 64 slots, a table row of 96 + 40 + 1 columns): the four
     cache buffers are aliased input to output and the plan is the
-    arguments + 142 MB; every Mamba-1 layer advances the slots' states
+    arguments + 42 MB; every Mamba-1 layer advances the slots' states
     by ONE ``s6_step`` call under ``ssm/ssm_state`` (the pool its
     in-place operand) after ONE ``conv_step`` call under
     ``ssm/ssm_conv``; the page run's owner and the seven cross layers
     run the grouped paged kernel on the heads-major pages under
     ``attn_shared``, EIGHT calls, and only TWO scatters lie under it:
-    the owner's K and V row; a cross layer writes nothing.  Nothing has
+    the owner's K and V row; a cross layer writes nothing; each of the
+    eight window layers writes its row (two scatters) and reads its
+    ring's five pages where they lie by ONE ``ring_paged_attention``
+    call under ``attn_window``, with no gathered copy of a ring beside
+    it.  Nothing has
     a pool's size but the pools (this is the probe that chose the
     layout: with the ten heads inside a page's rows, ``(N, 128, 10,
     128)``, the same step planned 5.6 GB of copies of the pool, 1.6
@@ -1357,11 +1403,15 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
         one_chip, monkeypatch)
     g, S = cfg["generate"], cfg["generate"]["slots"]
     assert width == 137
+    before = _ring_dispatches()
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block, extra=extra).compile()
+    after = _ring_dispatches()
+    assert {p: after[p] - before[p] for p in after} == {
+        "compiled": 8, "interpret": 0, "reference": 0}
     out = jax.tree.leaves(compiled.out_info)
     assert (out[0].shape, out[0].dtype) == ((S, cfg["vocab_size"]),
                                             jnp.float32)
@@ -1374,7 +1424,7 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
     assert m.alias_size_in_bytes >= buffers
     planned = _planned_bytes(compiled)
     assert planned == PHI4_PLANS["decode"] < 15.0e9, planned
-    assert m.temp_size_in_bytes < 160 << 20
+    assert m.temp_size_in_bytes < 64 << 20
     text = compiled.as_text()
     # the 18 MB tail pool is small enough that the compiler moves it to
     # fast memory and back round the conv kernels (copy-start / -done to
@@ -1390,9 +1440,16 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
     gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
     assert len(gqa) == 8 and all("_decode_step)/attn_shared/" in op
                                  for op in gqa)
+    ring = [op for op in kernels if "ring_paged_attention/" in op]
+    assert len(ring) == 8 and all("_decode_step)/attn_window/" in op
+                                  for op in ring)
+    assert sum("/attn_window/" in ln for ln in scatters) == 16
+    # a slot's ring is 5 pages of 10 heads x 128 rows: no gathered copy
+    assert not re.search(r"\[64,5,10,128,128\]|\[64,5,128,10,128\]"
+                         r"|\[64,640,10,128\]", text)
     step = [op for op in kernels if "s6_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
-    assert len(step) == len(conv) == 9 and len(kernels) == 26
+    assert len(step) == len(conv) == 9 and len(kernels) == 34
     assert all("_decode_step)/ssm/ssm_state/" in op for op in step)
     assert all("_decode_step)/ssm/ssm_conv/" in op for op in conv)
     # each writes the pool it was given as its output 1: the states

@@ -592,6 +592,45 @@ def test_grouped_dispatch_counts_its_path():
     assert pk.mode() == "auto"
 
 
+def test_the_decode_step_keeps_its_row_major_rings_on_the_gathered_form():
+    """Pages of 8 rows (pages the paged kernels fit): with the kernels
+    on, interpreted, the full layer runs the grouped kernel and every
+    sliding layer's ring still takes ``ring_window_attention`` on a
+    gathered copy: of row-major pages the gather is the read
+    (``paged_ring_attention``).  One decision a sliding layer a traced
+    step, all ``reference``, and the logits are the plain path's."""
+    from paddle_tpu import pallas as pk
+
+    counter = metrics.REGISTRY.get("pallas_dispatch_total")
+
+    def counted(kernel):
+        return {p: counter.value(kernel=kernel, path=p)
+                for p in ("compiled", "interpret", "reference")}
+
+    sizes = {**SIZES, "page_size": 8, "sliding_window": 16,
+             "pages_per_seq": 8}
+    prompt, tokens = _prompt(T_PROMPT, 5), _prompt(N_DECODED, 6)
+    sliding = sum(t == SLIDING for t in LAYERS)
+    jax.clear_caches()
+    want = _through_the_caches(ExaoneMoeLM(seed=3, **sizes), prompt, tokens)
+    ring0, gqa0 = counted("ring_paged_attention"), \
+        counted("ragged_paged_attention_gqa")
+    pk.enable(True, interpret=True)
+    jax.clear_caches()          # the mode is no part of a program's key
+    try:
+        got = _through_the_caches(ExaoneMoeLM(seed=3, **sizes), prompt,
+                                  tokens)
+    finally:
+        pk.enable("auto", interpret=False)
+        jax.clear_caches()
+    ring1, gqa1 = counted("ring_paged_attention"), \
+        counted("ragged_paged_attention_gqa")
+    assert {p: ring1[p] - ring0[p] for p in ring1} == {
+        "compiled": 0, "interpret": 0, "reference": sliding}
+    assert gqa1["interpret"] - gqa0["interpret"] == len(LAYERS) - sliding
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
 def test_banded_prefill_attention_is_the_masked_dense_form():
     rng = np.random.RandomState(4)
     T, Hq, Hkv, D, W = 32, 4, 2, 8, 8

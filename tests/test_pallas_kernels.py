@@ -499,6 +499,19 @@ def _decide(kernel, shape):
     if kernel == "grouped_gemm":
         dtype, rows, d, f = shape
         return pk.use_grouped_gemm(dtype, dtype, rows, d, f)
+    if kernel == "ring_paged_attention":
+        heads_major, T, page, Hq, Hkv, D = shape
+        S, R, N = 4, 5, 21
+        pages = jax.ShapeDtypeStruct(
+            (N, Hkv, page, D) if heads_major else (N, page, Hkv, D),
+            jnp.bfloat16)
+        jax.eval_shape(                                     # trace only
+            lambda q, k, v, ring, pos: da.paged_ring_attention(
+                q, k, v, ring, pos, 4 * page, heads_major=heads_major),
+            jax.ShapeDtypeStruct((S, T, Hq, D), jnp.bfloat16), pages, pages,
+            jax.ShapeDtypeStruct((S, R), jnp.int32),
+            jax.ShapeDtypeStruct((S, T), jnp.int32))
+        return None
     assert kernel == "prefill_flash_attention"
     x = jax.ShapeDtypeStruct(shape, jnp.float32)     # (T, H, D); trace only
     # a new function each time: eval_shape caches a function's trace
@@ -545,6 +558,25 @@ _POLICY_CASES = (
         "reference"),
        ("ragged_paged_attention_chunk", (16, 16, 128), "off", True, False,
         "reference"),
+       # a window layer's ring (pages heads-major?, chunk rows, page, Hq,
+       # Hkv, D): the layout the caller states decides, nothing else:
+       # Phi-4-mini-flash's pages, K-EXAONE's, a chunk, one over a page
+       ("ring_paged_attention", (True, 1, 128, 40, 10, 128), "auto", True,
+        False, "compiled"),
+       ("ring_paged_attention", (False, 1, 128, 64, 8, 128), "auto", True,
+        False, "reference"),
+       ("ring_paged_attention", (False, 1, 128, 64, 8, 128), "on", True,
+        False, "reference"),
+       ("ring_paged_attention", (True, 4, 128, 40, 10, 128), "auto", True,
+        False, "compiled"),
+       ("ring_paged_attention", (True, 129, 128, 40, 10, 128), "on", True,
+        False, "reference"),
+       ("ring_paged_attention", (True, 1, 8, 4, 2, 8), "auto", False, True,
+        "interpret"),
+       ("ring_paged_attention", (True, 1, 128, 40, 10, 128), "auto", False,
+        False, "reference"),
+       ("ring_paged_attention", (True, 1, 128, 40, 10, 128), "off", True,
+        False, "reference"),
        ("prefill_flash_attention", (128, 2, 8), "auto", True, False,
         "compiled"),
        ("prefill_flash_attention", (128, 2, 8), "auto", False, True,

@@ -278,6 +278,42 @@ def test_the_grouped_kernel_takes_pages_with_their_heads_outside():
 # -- through the caches -------------------------------------------------------
 
 
+def _ring_dispatches():
+    counter = metrics.REGISTRY.get("pallas_dispatch_total")
+    return {p: counter.value(kernel="ring_paged_attention", path=p)
+            for p in ("compiled", "interpret", "reference")}
+
+
+def test_the_decode_step_reads_its_rings_by_the_window_kernel():
+    """Pages of 8 rows are pages the paged kernels fit (the toy's 4 are
+    not): 21 prompt rows and 30 steps, twice round a ring of three
+    pages under a window of 16, with the kernels on (interpreted) and
+    off give the same logits at the grouped kernel's tolerance, and a
+    traced step decides once a window layer: the kernel for these
+    heads-major pages, the gathered reference with the kernels off."""
+    sizes = dict(page_size=8, sliding_window=16, pages_per_seq=16)
+    ids, tokens = prompt(21, 11), prompt(30, 12)
+    jax.clear_caches()
+    before = _ring_dispatches()
+    plain = make(**sizes)
+    assert (plain.rings, plain.ring_pages) == (2, 3)
+    want, _ = through_the_cache(plain, ids, tokens)
+    off = _ring_dispatches()
+    assert off["reference"] - before["reference"] == plain.rings
+    pk.enable(True, interpret=True)
+    jax.clear_caches()          # the mode is no part of a program's key
+    try:
+        got, _ = through_the_cache(make(**sizes), ids, tokens)
+    finally:
+        pk.enable("auto", interpret=False)
+        jax.clear_caches()
+    on = _ring_dispatches()
+    assert {p: on[p] - off[p] for p in on} == {
+        "compiled": 0, "interpret": plain.rings, "reference": 0}
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert ref.rel_rms(got, want) < 2e-5
+
+
 def test_dense_forward_is_the_reference(model):
     ids = prompt(37)
     logits, _, _ = model._forward(jnp.asarray(ids, jnp.int32))
