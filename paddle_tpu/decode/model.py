@@ -209,7 +209,8 @@ class PageRunCache:
 
     def prompt_attention(self, q, k, v):
         """Attention of one whole prompt, (T, heads, dh) each."""
-        return dense_prefill_attention(q, k, v, causal=True)
+        with jax.named_scope("attn_full"):
+            return dense_prefill_attention(q, k, v, causal=True)
 
     def store_prompt(self, pool, rows, flat):
         """``pool`` with a prompt's ``rows`` (L, T, H, dh) written:
@@ -226,11 +227,12 @@ class PageRunCache:
         pools).  ``q`` (S, H, dh) is a decode step's, one row a slot
         after ``lens`` cached rows; (S, T, H, dh) a chunk's."""
         H, dh = k_pool.shape[3:]
-        k_pool = _write_rows(k_pool, li, flat, k.reshape(-1, H, dh))
-        v_pool = _write_rows(v_pool, li, flat, v.reshape(-1, H, dh))
-        pages = _layer_pages(k_pool, v_pool, li, tables)
-        a = (paged_attention(q, *pages, lens + 1) if q.ndim == 3
-             else paged_chunk_attention(q, *pages, lens))
+        with jax.named_scope("attn_full"):
+            k_pool = _write_rows(k_pool, li, flat, k.reshape(-1, H, dh))
+            v_pool = _write_rows(v_pool, li, flat, v.reshape(-1, H, dh))
+            pages = _layer_pages(k_pool, v_pool, li, tables)
+            a = (paged_attention(q, *pages, lens + 1) if q.ndim == 3
+                 else paged_chunk_attention(q, *pages, lens))
         return a, k_pool, v_pool
 
 
@@ -295,15 +297,18 @@ def _dense_blocks(block, params, tokens, heads, live, last=None):
     it."""
     T = tokens.shape[0]
     pos = jnp.arange(T, dtype=jnp.int32)
-    x = block.embed(params, tokens, slice(0, T))
+    with jax.named_scope("blk_embed"):
+        x = block.embed(params, tokens, slice(0, T))
     kept, reports = [], []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        x, keep = lb.prompt_mixer(lp, x, pos, heads, live, kept, last)
+        with jax.named_scope("blk_mixer"):
+            x, keep = lb.prompt_mixer(lp, x, pos, heads, live, kept, last)
         if x.shape[0] != pos.shape[0]:
             pos, live = last[None], None     # row ``last`` alone goes on
         kept.append(keep)
-        x, report = lb.mlp(lp, x, live)
+        with jax.named_scope("blk_mlp"):
+            x, report = lb.mlp(lp, x, live)
         reports.append(report)
     if last is not None and x.shape[0] != 1:
         x = jax.lax.dynamic_slice_in_dim(x, last, 1)
@@ -469,12 +474,27 @@ class PagedDecoderLM:
         the next power of two from 64 (or the page size) up, capped at
         what one sequence can hold — a model smaller than 64 rows has
         that capacity as its one bucket."""
-        cap = min(self.max_len, self.seq_rows)
+        cap = self.prefill_cap
         if not 0 < n <= cap:
             raise ValueError(
                 f"a prompt of {n} tokens is outside 1..{cap}, the rows "
                 "one sequence of this model can hold")
         return min(max(bucket_dim(n), 64, self.page_size), cap)
+
+    @property
+    def prefill_cap(self) -> int:
+        """The ladder's top bucket: the rows one sequence can hold."""
+        return min(self.max_len, self.seq_rows)
+
+    def packed_prefill_rows(self, n: int) -> int:
+        """Rows the ladder would compute for ``n`` prompt rows laid end
+        to end in as few programs as hold them: top buckets while they
+        fill one, then the rest's own bucket.  No program packs prompts
+        so; the tick's account sets it beside the rows its admissions
+        did run (``decode_admit_tick_rows_total``)."""
+        whole, rest = divmod(n, self.prefill_cap)
+        return whole * self.prefill_cap + (
+            self.prefill_bucket(rest) if rest else 0)
 
     def prefill(self, prompt: Sequence[int], pages: Sequence[int],
                 cached_len: int = 0):
@@ -507,7 +527,8 @@ class PagedDecoderLM:
                     page_size=self.page_size, block=self.block,
                     extra=self.extra_pools)
                 self._set_cache(k_pool, v_pool, *extra)
-                logits = np.asarray(logits[-1])
+                with phase("decode.prefill_wait"):
+                    logits = np.asarray(logits[-1])
                 self._observe("prefill", report, T - cached_len)
             return T, [], logits
         bucket = self.prefill_bucket(T)
@@ -520,7 +541,10 @@ class PagedDecoderLM:
                 np.int32(T), heads=self.heads, block=self.block,
                 extra=self.extra_pools)
             self._set_cache(k_pool, v_pool, *extra)
-            logits = np.asarray(logits)
+            # the program's run and the row's copy to the host: what of
+            # `decode.prefill` a device program covers
+            with phase("decode.prefill_wait"):
+                logits = np.asarray(logits)
             self._observe("prefill", report, bucket)
         _M_PREFILL_TOKENS.inc(T)
         _M_PREFILL_PADDED.inc(bucket)
@@ -672,10 +696,12 @@ def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
     live = jnp.arange(tokens.shape[0], dtype=jnp.int32) < n
     last, kept, report = _dense_blocks(block, params, tokens, heads, live,
                                        n - 1)
-    k_pool, v_pool, *extra = block.store_prompts(
-        (k_pool, v_pool, *extra), kept, flat)
-    return (block.head(params, last)[0], k_pool, v_pool, report,
-            tuple(extra))
+    with jax.named_scope("blk_store"):
+        k_pool, v_pool, *extra = block.store_prompts(
+            (k_pool, v_pool, *extra), kept, flat)
+    with jax.named_scope("blk_head"):
+        logits = block.head(params, last)[0]
+    return logits, k_pool, v_pool, report, tuple(extra)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -694,18 +720,23 @@ def _prefill_chunk(params, k_pool, v_pool, table, cached_len, tokens, *,
     Retraces per suffix length (the full-prompt prefill does not)."""
     Ts = tokens.shape[0]
     pos = cached_len + jnp.arange(Ts, dtype=jnp.int32)
-    x = block.embed(params, tokens, pos)                    # (Ts, d)
+    with jax.named_scope("blk_embed"):
+        x = block.embed(params, tokens, pos)                # (Ts, d)
     flat = table[pos // page_size] * page_size + pos % page_size
     lens1 = cached_len[None] if jnp.ndim(cached_len) == 0 else cached_len
     cache, addr = (k_pool, v_pool, *extra), Addressing(flat, table, lens1)
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads, lone=True)
-        x, report = lb.mlp(lp, x, None)          # every suffix row is real
+        with jax.named_scope("blk_mixer"):
+            x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads,
+                                lone=True)
+        with jax.named_scope("blk_mlp"):
+            x, report = lb.mlp(lp, x, None)      # every suffix row is real
         reports.append(report)
-    return (block.head(params, x), *cache[:2], _stack_reports(reports),
-            tuple(cache[2:]))
+    with jax.named_scope("blk_head"):
+        logits = block.head(params, x)
+    return (logits, *cache[:2], _stack_reports(reports), tuple(cache[2:]))
 
 
 def _greedy_ids(logits):
@@ -724,7 +755,8 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
     Outputs as ``_decode_step``'s, the ids (S, k)."""
     S, T = tokens.shape
     pos = lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (S, T)
-    x = block.embed(params, tokens, pos)                    # (S, T, d)
+    with jax.named_scope("blk_embed"):
+        x = block.embed(params, tokens, pos)                # (S, T, d)
     flat = (jnp.take_along_axis(tables, pos // page_size, axis=1)
             * page_size + pos % page_size).reshape(-1)      # (S*T,)
     # an inactive slot holds the null table: its rows are not live
@@ -733,12 +765,16 @@ def _verify_step(params, k_pool, v_pool, tables, lens, tokens, *,
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads)
-        x, report = lb.mlp(lp, x, live)
+        with jax.named_scope("blk_mixer"):
+            x, cache = lb.mixer(lp, x, pos, cache, li, addr, heads)
+        with jax.named_scope("blk_mlp"):
+            x, report = lb.mlp(lp, x, live)
         reports.append(report)
-    logits = block.head(params, x)
-    return (logits, *cache[:2], _stack_reports(reports),
-            _greedy_ids(logits), tuple(cache[2:]))
+    with jax.named_scope("blk_head"):
+        logits = block.head(params, x)
+        ids = _greedy_ids(logits)
+    return (logits, *cache[:2], _stack_reports(reports), ids,
+            tuple(cache[2:]))
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "page_size", "block"),
@@ -753,7 +789,8 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
     live, so that a step can follow this one on the device's own ids
     and lengths)."""
     S = tokens.shape[0]
-    x = block.embed(params, tokens, lens)                   # (S, d)
+    with jax.named_scope("blk_embed"):
+        x = block.embed(params, tokens, lens)               # (S, d)
     # flat pool row each slot's new KV lands in: its page at
     # lens // page_size, offset lens % page_size.  Inactive slots hold
     # the null table -> they scribble on reserved page 0, harmlessly.
@@ -764,12 +801,15 @@ def _decode_step(params, k_pool, v_pool, tables, lens, tokens, *,
     reports = []
     for li, lp in enumerate(params["layers"]):
         lb = block.layer(li)
-        x, cache = lb.mixer(lp, x, lens, cache, li, addr, heads)
-        x, report = lb.mlp(lp, x, live)
+        with jax.named_scope("blk_mixer"):
+            x, cache = lb.mixer(lp, x, lens, cache, li, addr, heads)
+        with jax.named_scope("blk_mlp"):
+            x, report = lb.mlp(lp, x, live)
         reports.append(report)
-    logits = block.head(params, x)
+    with jax.named_scope("blk_head"):
+        logits = block.head(params, x)
+        ids = _greedy_ids(logits)
     # an activation a layer hands to later ones rides behind the buffers
     # in the tuple the mixers thread: the buffers alone are handed back
-    return (logits, *cache[:2], _stack_reports(reports),
-            _greedy_ids(logits), lens + live.astype(lens.dtype),
-            tuple(cache[2:2 + len(extra)]))
+    return (logits, *cache[:2], _stack_reports(reports), ids,
+            lens + live.astype(lens.dtype), tuple(cache[2:2 + len(extra)]))

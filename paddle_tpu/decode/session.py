@@ -37,10 +37,10 @@ The statement that opens a phase's span (``observability.phase``, here
 and in the model's ``step_collect`` / ``step_dispatch``) also adds its
 seconds to the account, so a phase's counter and its span have the
 same two edges; ``step()`` flushes the account to the registry once a
-tick (seconds by phase, admissions a tick, the slot-seconds an
-admission held the seated slots still) and a tick that took far longer
-than its kind does is counted, kept (``slow_ticks``, in ``/health``)
-and logged with what it was doing.
+tick (seconds by phase, admissions a tick and by prefill bucket, the
+slot-seconds an admission held the seated slots still) and a tick that
+took far longer than its kind does is counted, kept (``slow_ticks``, in
+``/health``) and logged with what it was doing.
 
 The model behind the session is pluggable (``PagedSeq2SeqModel`` for
 v1 beam_search specs, ``TinyDecoderLM`` for transformer self-attention
@@ -143,9 +143,12 @@ _M_CANCELLED = _metrics.counter(
 
 # ``phase`` label of ``decode_tick_seconds_total`` -> the span whose
 # statement charges it, in a tick's order.  ``prefill`` and
-# ``first_token`` lie inside ``admit`` and are kept beside it, never
-# summed with it; ``upload`` and ``dispatch`` lie inside ``decode.step``,
-# which is no phase.  Two more labels have no statement: ``other`` is the
+# ``first_token`` lie inside ``admit``, and ``prefill_wait`` inside
+# ``prefill`` (the model's ``prefill`` writes it: the wait for the
+# logits' row, so the program's run; ``prefill`` less it is the host's
+# part of a prefill): all three are kept beside ``admit``, never summed
+# with it; ``upload`` and ``dispatch`` lie inside ``decode.step``, which
+# is no phase.  Two more labels have no statement: ``other`` is the
 # ``decode.tick`` span less its top-level phases, and ``between`` (from
 # the end of one tick to the start of the next while the session is not
 # idle: the stepper waiting for the interpreter lock) lies outside the
@@ -157,13 +160,14 @@ PHASE_SPANS = {
     "sweep": "decode.sweep",
     "admit": "decode.admit",
     "prefill": "decode.prefill",
+    "prefill_wait": "decode.prefill_wait",
     "first_token": "decode.first_token",
     "cow": "decode.cow",
     "upload": "decode.upload",
     "dispatch": "decode.dispatch",
     "deliver": "decode.deliver",
 }
-_NESTED = ("prefill", "first_token")
+_NESTED = ("prefill", "prefill_wait", "first_token")
 
 # A tick (with the ``between`` before it) is slow when it lasts longer
 # than both: SLOW_TICK_FACTOR times the running mean of the ticks of its
@@ -198,14 +202,18 @@ class TickAccount:
     """What one tick owes the registry, filled while the tick runs and
     flushed once at its end: seconds by phase (``phases``: charged by
     the ``phase`` statements that run while it is open on the thread),
-    the admissions it seated, the slot-seconds those held the seated
-    slots still, where its step's inputs came from and under what its
-    deliveries ran.  Also the judge of a slow tick.  The families live
-    in ``registry`` (the process's unless given: the overhead probe
-    gives its own)."""
+    the admissions it seated, by prefill bucket (how many, the
+    slot-seconds they held the seated slots still, on real rows and on
+    padding), where its step's inputs came from and under what
+    its deliveries ran.  Also the judge of a slow tick.  The families
+    live in ``registry`` (the process's unless given: the overhead probe
+    gives its own).  ``packed_rows``: the model's
+    ``packed_prefill_rows``, or None for a model without a bucket
+    ladder."""
 
-    def __init__(self, registry=None):
+    def __init__(self, registry=None, packed_rows=None):
         reg = registry or _metrics.REGISTRY
+        self._packed_rows = packed_rows
         self.labels = (*PHASE_SPANS, "other")
         self.phases = PhaseAccount(("decode.tick", *PHASE_SPANS.values()))
         self._top = [i for i, label in enumerate(PHASE_SPANS)
@@ -214,7 +222,8 @@ class TickAccount:
             "decode_tick_seconds_total",
             "seconds of the stepper's ticks by phase (the span that bears "
             "the phase's name charges it; `prefill` and `first_token` are "
-            "inside `admit`; `other` = the tick less its top-level phases; "
+            "inside `admit`, `prefill_wait` inside `prefill`; `other` = "
+            "the tick less its top-level phases; "
             "`between` = from one tick's end to the next one's start while "
             "the session is not idle), and by whether the tick seated a "
             "request (`admitting`)")
@@ -225,11 +234,29 @@ class TickAccount:
             "decode_tick_admissions_total",
             "ticks by the number of requests they seated (`n`: 0, 1, 2, 3, "
             "4+): admissions a tick, and the depth of a convoy")
+        # by `bucket`: the rows of the prefill program the admission
+        # ran, "suffix" for a prefill over cached pages, "none" for one
+        # that ran no bucketed program (a request sent back to the
+        # queue; a model without a ladder)
+        self._m_admitted = reg.counter(
+            "decode_admissions_total",
+            "requests seated, by the prefill `bucket` they ran")
         self._m_stalled = reg.counter(
             "decode_admit_stalled_slot_seconds_total",
             "for every admission, its seconds times the slots that were "
             "seated and live before it: slot-time in which a sequence "
-            "produced nothing because another request was prefilled")
+            "produced nothing because another request was prefilled, "
+            "by `bucket` and, if a prefill's seconds go by its rows, by "
+            "the `kind` of rows they went on: `pad` a seated admission's "
+            "slot-seconds times pad rows over bucket rows, `real` the "
+            "rest")
+        self._m_tick_rows = reg.counter(
+            "decode_admit_tick_rows_total",
+            "once an admitting tick, by `kind`: `run` the bucket rows "
+            "its admissions computed, `packed` the rows the model's "
+            "ladder would compute for the same prompts laid end to end, "
+            "where those are fewer (a counterfactual: no program packs "
+            "prompts so)")
         self._m_slot_seconds = reg.counter(
             "decode_slot_seconds_total",
             "every tick's seconds (with the `between` before it) times "
@@ -250,6 +277,9 @@ class TickAccount:
         self._k_admissions = [key(n=n) for n in ("0", "1", "2", "3", "4+")]
         self._k_inputs = [key(source="resident"), key(source="uploaded")]
         self._k_under = [key(under="step"), key(under="nothing")]
+        # a bucket -> its keys: admissions, stalled on real rows, on pad
+        self._k_bucket: dict = {}
+        self._k_tick_rows = [key(kind="run"), key(kind="packed")]
         self.slow_ticks: collections.deque = collections.deque(
             maxlen=SLOW_TICKS_KEPT)
         self._mean = {"0": None, "1": None}     # seconds, by `admitting`
@@ -257,16 +287,41 @@ class TickAccount:
 
     def _reset(self) -> None:
         self.admissions: List[dict] = []    # the seated ones' span args
-        self.stalled = 0.0
+        # this tick's lines by bucket, as `inc_many` takes them: the
+        # requests seated, the slot-seconds their admissions held the
+        # seated slots still
+        self.seated: List[tuple] = []
+        self.stalled: List[tuple] = []
+        self.run = self.rows = 0    # the seated ones' buckets, prompts
         self.inputs = [0, 0]                # steps: resident, uploaded
         self.under = [0, 0]                 # deliveries: step, nothing
 
     def admitted(self, admit: phase, live_before: int, seated: bool) -> None:
         """One ``decode.admit`` ended: whatever came of it, the slots
         that were seated stood still for its seconds."""
-        self.stalled += admit.seconds * live_before
+        args = admit.args
+        bucket = args.get("bucket")
+        label = "suffix" if args.get("cached_len") else bucket or "none"
+        keys = self._k_bucket.get(label)
+        if keys is None:
+            key = _metrics.label_key
+            keys = self._k_bucket[label] = (
+                key(bucket=str(label)), key(bucket=str(label), kind="real"),
+                key(bucket=str(label), kind="pad"))
+        stood = admit.seconds * live_before
         if seated:
-            self.admissions.append(admit.args)
+            self.admissions.append(args)
+            self.seated.append((keys[0], 1))
+            if bucket:
+                rows = args["prompt_len"]
+                self.run += bucket
+                self.rows += rows
+                if stood and rows < bucket:
+                    on_pad = stood * (1 - rows / bucket)
+                    self.stalled.append((keys[2], on_pad))
+                    stood -= on_pad
+        if stood:
+            self.stalled.append((keys[1], stood))
 
     def flush(self, at: float, live: int, active: int, waiting: int,
               in_flight: bool, gc_seconds: float) -> None:
@@ -283,8 +338,10 @@ class TickAccount:
         self._m_ticks.inc_many(((self._k_ticks[admitting], 1),))
         self._m_admissions.inc_many(
             ((self._k_admissions[min(n, _ADMISSIONS_TOP)], 1),))
+        if n:
+            self._flush_admissions()
         if self.stalled:
-            self._m_stalled.inc(self.stalled)
+            self._m_stalled.inc_many(self.stalled)
         if live:
             self._m_slot_seconds.inc(total * live)
         for family, keys, counts in (
@@ -301,6 +358,18 @@ class TickAccount:
             self._mean[admitting] = (total if mean is None else
                                      mean + (total - mean) / _MEAN_OVER_TICKS)
         self._reset()
+
+    def _flush_admissions(self) -> None:
+        """The seated requests by bucket, and what the bucketed ones
+        ran beside what they would packed."""
+        self._m_admitted.inc_many(self.seated)
+        if self.run and self._packed_rows is not None:
+            # a packer would leave alone a tick whose prompts, laid end
+            # to end, land in a larger bucket than their own add up to
+            k_run, k_packed = self._k_tick_rows
+            self._m_tick_rows.inc_many((
+                (k_run, self.run),
+                (k_packed, min(self.run, self._packed_rows(self.rows)))))
 
     def _slow(self, at, total, secs, active, waiting, in_flight,
               gc_seconds) -> None:
@@ -706,7 +775,8 @@ class DecodeSession:
             for half in ("step_dispatch", "step_collect"))
         self._flight: Optional[_Flight] = None
         self._outbox = _Outbox()
-        self._account = TickAccount()
+        self._account = TickAccount(
+            packed_rows=getattr(model, "packed_prefill_rows", None))
         self._between: Optional[phase] = None   # open from a tick's end on
         self._gc_mark = _GC[0]
 

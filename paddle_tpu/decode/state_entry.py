@@ -159,10 +159,6 @@ class StateEntryCache(PageRunCache):
         E = state_pool.shape[1]
         return self.index_in_kind * E + addr.tables[:, self.full_pages]
 
-    def prompt_attention(self, q, k, v):
-        with jax.named_scope("attn_full"):
-            return super().prompt_attention(q, k, v)
-
     def store_prompts(self, cache, kept, where):
         """``where``: (the page run's flat rows (T,), the state entry).
         The attention layers' K/V rows as every paged model's; each

@@ -166,8 +166,7 @@ class ExaoneMoeBlock(PageRunCache):
         if self.sliding:
             with jax.named_scope("attn_window"):
                 return banded_prefill_attention(q, k, v, self.window)
-        with jax.named_scope("attn_full"):
-            return super().prompt_attention(q, k, v)
+        return super().prompt_attention(q, k, v)       # under ``attn_full``
 
     def store_prompt(self, pool, rows, flat):
         """``rows`` (L, T, Hkv, dh) at the flat pool rows ``flat``
@@ -183,11 +182,10 @@ class ExaoneMoeBlock(PageRunCache):
         """``flat`` (the page run's rows, which the skeleton reckons
         for every model) is the full layer's.  A sliding layer writes
         at its ring's rows and reads its ring alone."""
-        if not self.sliding:
-            with jax.named_scope("attn_full"):
-                return super().cached_attention(
-                    k_pool, v_pool, 0, q, k, v, flat,
-                    tables[:, :self.full_pages], lens)
+        if not self.sliding:                            # under ``attn_full``
+            return super().cached_attention(
+                k_pool, v_pool, 0, q, k, v, flat,
+                tables[:, :self.full_pages], lens)
         pg, H, dh = k_pool.shape[2:]
         step = q.ndim == 3
         qc = q[:, None] if step else q                       # (S, T, ..)

@@ -295,11 +295,10 @@ class OlmoHybridBlock(StateEntryCache):
         it with zero heads, whose outputs are dropped), and the table's
         first columns are the page run."""
         H, Hs = q.shape[-2], k_pool.shape[3]
-        with jax.named_scope("attn_full"):
-            a, k_pool, v_pool = super().cached_attention(
-                k_pool, v_pool, self.index_in_kind, _pad_heads(q, Hs),
-                _pad_heads(k, Hs), _pad_heads(v, Hs), flat,
-                tables[:, :self.full_pages], lens)
+        a, k_pool, v_pool = super().cached_attention(   # under ``attn_full``
+            k_pool, v_pool, self.index_in_kind, _pad_heads(q, Hs),
+            _pad_heads(k, Hs), _pad_heads(v, Hs), flat,
+            tables[:, :self.full_pages], lens)
         return a[..., :H, :], k_pool, v_pool
 
     # -- the mixers ---------------------------------------------------------

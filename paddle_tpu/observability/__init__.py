@@ -101,15 +101,18 @@ def measure_step_overhead(iters: int = 2000) -> float:
 def measure_tick_account_overhead(iters: int = 2000) -> float:
     """Seconds the decode tick's account (``decode/session.py``:
     ``TickAccount``) adds to one tick, with nobody recording: a tick of
-    every phase with one admission, written with ``phase`` under an
-    open account and flushed into a private registry, less the same
-    tick as it was written before the account was kept (plain ``span``s
-    and the two counters a tick already incremented).  Asserted under a
-    budget in the tests: the account is on in every run."""
+    every phase with one admission (a 50-row prompt in a bucket of 64
+    beside 15 live slots, so every by-bucket line of the flush is
+    written), written with ``phase`` under an open account and flushed
+    into a private registry, less the same tick as it was written
+    before the account was kept (plain ``span``s and the two counters a
+    tick already incremented).  Asserted under a budget in the tests:
+    the account is on in every run."""
     from paddle_tpu.decode.session import PHASE_SPANS, TickAccount
 
     reg = MetricsRegistry()
-    account = TickAccount(reg)
+    account = TickAccount(
+        reg, packed_rows=lambda rows: max(64, 1 << (rows - 1).bit_length()))
     inputs = reg.counter("overhead_probe_step_inputs_total")
     deliveries = reg.counter("overhead_probe_deliveries_total")
     flat = [PHASE_SPANS[label] for label in (
@@ -120,9 +123,11 @@ def measure_tick_account_overhead(iters: int = 2000) -> float:
         with stmt("decode.between"):
             pass
         with stmt("decode.tick", active=16, waiting=0, rids="1,2,3") as t:
-            with stmt("decode.admit", rid=i, prompt_len=64) as admit:
-                with stmt("decode.prefill", rid=i, bucket=64, pad=0):
-                    pass
+            with stmt("decode.admit", rid=i, prompt_len=50,
+                      bucket=64) as admit:
+                with stmt("decode.prefill", rid=i, bucket=64, pad=14):
+                    with stmt("decode.prefill_wait"):
+                        pass
                 with stmt("decode.first_token", rid=i):
                     pass
             for name in flat:

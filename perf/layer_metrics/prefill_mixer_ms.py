@@ -1,0 +1,12 @@
+"""Decode engine: device time of a bucketed prefill's instructions
+under the skeleton's ``blk_mixer`` scope (every layer's token mixer:
+norm, projections, rotation, attention or the recurrence, residual; the
+mechanisms' own scopes lie under it), all layers, per run of
+``jit__prefill_bucket``, in ms."""
+
+from perf.harness import skeleton as sk
+
+
+def read(record):
+    return sk.part_ms(record, sk.PREFILL_PROGRAMS, sk.PREFILL_MODULE,
+                      ["mixer"])

@@ -56,6 +56,13 @@ def _ring_dispatches():
             for p in ("compiled", "interpret", "reference")}
 
 
+def _under(scope):
+    """``scope`` behind the skeleton's own (PR 51: ``decode/model.py``
+    names its call sites, outermost): a feed-forward's mechanism lies
+    under ``blk_mlp``, a mixer's under ``blk_mixer``."""
+    return ("blk_mlp/" if scope.startswith("moe_") else "blk_mixer/") + scope
+
+
 def _kernel_op_names(text):
     """The op_name of every Pallas custom call of a compiled program:
     what a trace reduction finds a kernel's device events by."""
@@ -71,7 +78,8 @@ def _assert_grouped_gemm_kernel(text, layers, looped):
     ops = [op for op in _kernel_op_names(text) if "grouped_gemm" in op]
     assert len(ops) == 2 * layers, ops
     under = "/while/body/moe_experts/" if looped else "/moe_experts/"
-    assert all("_prefill_bucket)/" in op and under in op for op in ops), ops
+    assert all("_prefill_bucket)/blk_mlp/" in op and under in op
+               for op in ops), ops
     assert sum("grouped_gemm_gate_up" in op for op in ops) == layers
     assert "ragged-dot" not in text
 
@@ -450,7 +458,7 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
         cfg["intermediate_size"])
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
-        assert f"jit(_decode_step)/{scope}/" in text, scope
+        assert f"jit(_decode_step)/{_under(scope)}/" in text, scope
 
 
 @pytest.mark.parametrize("bucket", [256, 2048])
@@ -592,14 +600,14 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
         compiled, len(jax.tree.leaves(params)), shape, 2, float("inf"),
         scatters=2 * L)
     gqa = _kernel_op_names(text)
-    assert len(gqa) == 1 and "_decode_step)/attn_full/" in gqa[0]
+    assert len(gqa) == 1 and "_decode_step)/blk_mixer/attn_full/" in gqa[0]
     assert "ragged_paged_attention_gqa" in gqa[0]
     _assert_experts_read_where_they_lie(
         text, cfg["num_experts"], cfg["hidden_size"],
         cfg["moe_intermediate_size"])
     for scope in ("attn_window", "moe_shared", "moe_router",
                   "moe_dispatch", "moe_experts", "moe_combine"):
-        assert f"jit(_decode_step)/{scope}/" in text, scope
+        assert f"jit(_decode_step)/{_under(scope)}/" in text, scope
 
 
 @pytest.mark.parametrize("bucket, plan, parents_plan", [
@@ -643,7 +651,8 @@ def test_exaone_top_prefill_fits_beside_the_weights(one_chip, monkeypatch,
     assert not re.search(rf"\[{k * bucket},{cfg['hidden_size']}\]", text)
     ops = _kernel_op_names(text)
     flash = [op for op in ops if "grouped_gemm" not in op]
-    assert len(flash) == 1 and "_prefill_bucket)/attn_full/" in flash[0]
+    assert len(flash) == 1
+    assert "_prefill_bucket)/blk_mixer/attn_full/" in flash[0]
     assert "flash_attention_fwd" in flash[0]
     # thousands of rows: the experts keep the grouped GEMM
     _assert_grouped_gemm_kernel(text, L - 1, looped=True)
@@ -786,14 +795,16 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
     kernels = _kernel_op_names(text)
     rpa = [op for op in kernels if "ragged_paged_attention/" in op]
-    assert len(rpa) == 4 and all("_decode_step)/attn_full/" in op
+    assert len(rpa) == 4 and all("_decode_step)/blk_mixer/attn_full/" in op
                                  for op in rpa)
     step = [op for op in kernels if "gated_delta_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
     assert len(step) == len(conv) == 12 and len(kernels) == 28
-    assert all("_decode_step)/lin_attn/lin_attn_state/" in op for op in step)
+    assert all("_decode_step)/blk_mixer/lin_attn/lin_attn_state/" in op
+               for op in step)
     # the conv's kernel under its own scope, outside the state's
-    assert all("_decode_step)/lin_attn/lin_attn_conv/" in op for op in conv)
+    assert all("_decode_step)/blk_mixer/lin_attn/lin_attn_conv/" in op
+               for op in conv)
     # each writes the pool it was given as its output 1: the states
     # operand 6, the tails operand 3 (entries, rows, taps, pool)
     for name, operand in (("gated_delta_step/", 6), ("conv_step/", 3)):
@@ -851,11 +862,11 @@ def test_hybrid_top_prefill_fits_beside_weights_states_and_pages(
     flash = [op for op in kernels if "flash_attention_fwd" in op]
     scan = [op for op in kernels if "gated_delta_chunked/" in op]
     assert len(flash) == 4 and len(scan) == 12 and len(kernels) == 16
-    assert all("_prefill_bucket)/attn_full/" in op for op in flash)
-    assert all("_prefill_bucket)/lin_attn/lin_attn_scan/" in op
+    assert all("_prefill_bucket)/blk_mixer/attn_full/" in op for op in flash)
+    assert all("_prefill_bucket)/blk_mixer/lin_attn/lin_attn_scan/" in op
                for op in scan)
     assert not re.search(r"/lin_attn_scan/while", text)
-    assert "jit(_prefill_bucket)/lin_attn/lin_attn_conv/" in text
+    assert "jit(_prefill_bucket)/blk_mixer/lin_attn/lin_attn_conv/" in text
 
 
 def test_gated_delta_chunked_compiles(one_chip):
@@ -990,14 +1001,14 @@ def test_granite_decode_step_moves_states_tails_and_pages_in_place(
     assert sum(" scatter(" in ln for ln in text.splitlines()) == 8
     kernels = _kernel_op_names(text)
     gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
-    assert len(gqa) == 4 and all("_decode_step)/attn_full/" in op
+    assert len(gqa) == 4 and all("_decode_step)/blk_mixer/attn_full/" in op
                                  for op in gqa)
     step = [op for op in kernels if "ssd_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
     assert len(step) == len(conv) == 36 and len(kernels) == 76
-    assert all("_decode_step)/ssm/ssm_state/" in op for op in step)
+    assert all("_decode_step)/blk_mixer/ssm/ssm_state/" in op for op in step)
     # the conv's kernel under its own scope, outside the state's
-    assert all("_decode_step)/ssm/ssm_conv/" in op for op in conv)
+    assert all("_decode_step)/blk_mixer/ssm/ssm_conv/" in op for op in conv)
     # each writes the pool it was given as its output 1: the states
     # operand 5, the tails operand 4 (entries, rows, taps, bias, pool)
     for name, operand in (("ssd_step/", 5), ("conv_step/", 4)):
@@ -1073,10 +1084,11 @@ def test_granite_top_prefill_fits_beside_weights_states_and_pages(
     assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
     flash = _kernel_op_names(text)
     assert len(flash) == 4 and all(
-        "_prefill_bucket)/attn_full/" in op and "flash_attention_fwd" in op
+        "_prefill_bucket)/blk_mixer/attn_full/" in op
+        and "flash_attention_fwd" in op
         for op in flash)
     for scope in ("ssm/ssm_scan", "ssm/ssm_conv"):
-        assert f"jit(_prefill_bucket)/{scope}/" in text, scope
+        assert f"jit(_prefill_bucket)/{_under(scope)}/" in text, scope
 
 
 # -- latent attention (PR 45) -------------------------------------------------
@@ -1233,7 +1245,7 @@ def test_kanana_decode_step_reads_the_latent_rows_in_place(one_chip,
                for ln in text.splitlines()) in (L, L + 1)
     ops = _kernel_op_names(text)
     assert len(ops) == L
-    assert all("_decode_step)/attn_latent/" in op
+    assert all("_decode_step)/blk_mixer/attn_latent/" in op
                and "latent_paged_attention" in op for op in ops)
     # the 64 rows take the dense pass over the 16 held experts; no
     # matrix of theirs is transposed or copied (the compiler prefetches
@@ -1248,7 +1260,7 @@ def test_kanana_decode_step_reads_the_latent_rows_in_place(one_chip,
                   "attn_latent/attn_latent_absorb", "moe_shared",
                   "moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
-        assert f"jit(_decode_step)/{scope}/" in text, scope
+        assert f"jit(_decode_step)/{_under(scope)}/" in text, scope
     assert "attn_latent_expand" not in text
 
 
@@ -1295,10 +1307,11 @@ def test_kanana_top_prefill_fits_beside_weights_and_latent_rows(
     flash = [op for op in ops if "flash_attention_fwd" in op
              or "_flash_fwd_impl" in op]
     assert len(flash) == L and all(
-        "_prefill_bucket)/attn_latent/" in op for op in flash)
+        "_prefill_bucket)/blk_mixer/attn_latent/" in op for op in flash)
     assert not [op for op in ops if "latent_paged_attention" in op]
     for scope in ("attn_latent_down", "attn_latent_expand"):
-        assert f"_prefill_bucket)/attn_latent/{scope}/" in text, scope
+        assert (f"_prefill_bucket)/blk_mixer/attn_latent/{scope}/"
+                in text), scope
     assert "attn_latent_absorb" not in text
     # thousands of rows: the experts keep the grouped GEMM
     _assert_grouped_gemm_kernel(text, L - 1, looped=True)
@@ -1438,10 +1451,10 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
                for ln in scatters)
     kernels = _kernel_op_names(text)
     gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
-    assert len(gqa) == 8 and all("_decode_step)/attn_shared/" in op
+    assert len(gqa) == 8 and all("_decode_step)/blk_mixer/attn_shared/" in op
                                  for op in gqa)
     ring = [op for op in kernels if "ring_paged_attention/" in op]
-    assert len(ring) == 8 and all("_decode_step)/attn_window/" in op
+    assert len(ring) == 8 and all("_decode_step)/blk_mixer/attn_window/" in op
                                   for op in ring)
     assert sum("/attn_window/" in ln for ln in scatters) == 16
     # a slot's ring is 5 pages of 10 heads x 128 rows: no gathered copy
@@ -1450,8 +1463,8 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
     step = [op for op in kernels if "s6_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
     assert len(step) == len(conv) == 9 and len(kernels) == 34
-    assert all("_decode_step)/ssm/ssm_state/" in op for op in step)
-    assert all("_decode_step)/ssm/ssm_conv/" in op for op in conv)
+    assert all("_decode_step)/blk_mixer/ssm/ssm_state/" in op for op in step)
+    assert all("_decode_step)/blk_mixer/ssm/ssm_conv/" in op for op in conv)
     # each writes the pool it was given as its output 1: the states
     # operand 6 (entries, dt, x, A, B, C, pool), the tails operand 4
     for name, operand in (("s6_step/", 6), ("conv_step/", 4)):
@@ -1460,7 +1473,7 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
                    for ln in text.splitlines()) == 9, name
     assert not re.search(r"/ssm/while/", text)
     for scope in ("attn_window", "gmu"):
-        assert f"jit(_decode_step)/{scope}/" in text, scope
+        assert f"jit(_decode_step)/{_under(scope)}/" in text, scope
 
 
 def test_phi4_top_prefill_fits_beside_weights_states_rings_and_the_run(
@@ -1495,9 +1508,9 @@ def test_phi4_top_prefill_fits_beside_weights_states_rings_and_the_run(
     text = compiled.as_text()
     assert not _pool_sized_strays(text, _hybrid_sizes(pool, extra))
     assert "12288,16,5120" not in text and "12288,5120,16" not in text
-    assert re.search(r"_prefill_bucket\)/ssm/ssm_scan/while", text)
+    assert re.search(r"_prefill_bucket\)/blk_mixer/ssm/ssm_scan/while", text)
     for scope in ("ssm/ssm_conv", "attn_window", "attn_shared", "gmu"):
-        assert f"jit(_prefill_bucket)/{scope}/" in text, scope
+        assert f"jit(_prefill_bucket)/{_under(scope)}/" in text, scope
     for ln in text.splitlines():
         if "/gmu/" in ln:
             assert "12288" not in ln.split("metadata")[0], ln

@@ -410,11 +410,13 @@ def test_named_scopes_place_the_latent_mixer_and_the_experts(model):
     for program, lowered in texts.items():
         text = lowered.as_text(debug_info=True)
         for scope in inside[program]:
-            assert re.search(rf"{program}\)/attn_latent/{scope}/", text), (
+            assert re.search(
+                rf"{program}\)/blk_mixer/attn_latent/{scope}/", text), (
                 program, scope)
         for scope in ("moe_shared", "moe_router", "moe_dispatch",
                       "moe_experts", "moe_combine"):
-            assert re.search(rf"{program}\)/(while/body/)?{scope}/", text), (
+            assert re.search(
+                rf"{program}\)/blk_mlp/(while/body/)?{scope}/", text), (
                 program, scope)
         other = set(absorbed + expanded) - set(inside[program])
         for scope in other:

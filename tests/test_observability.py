@@ -1126,3 +1126,131 @@ def test_trace_endpoint_records_for_n_seconds(toy_gen_server, tmp_path,
     capsys.readouterr()
     with open(out) as f:
         assert "traceEvents" in json.load(f)
+
+
+# ---------------------------------------------------------------------------
+# The skeleton's five scopes (ISSUE 51): every program that runs a block
+# ---------------------------------------------------------------------------
+
+
+def _toy(name):
+    """The block's model at the toy size its own test file runs it at."""
+    if name == "gpt2":
+        from paddle_tpu.decode.model import TinyDecoderLM
+
+        return TinyDecoderLM(seed=3)
+    if name == "olmoe":
+        from paddle_tpu.models.olmoe import OlmoeLM
+        from tests.test_olmoe import SIZES
+
+        return OlmoeLM(seed=5, **SIZES)
+    if name == "exaone_moe":
+        from paddle_tpu.models.exaone_moe import ExaoneMoeLM
+        from tests.test_exaone_moe import SIZES
+
+        return ExaoneMoeLM(seed=3, **SIZES)
+    if name == "kanana_mla":
+        from paddle_tpu.models.kanana_mla import KananaMlaLM
+        from tests.test_kanana_mla import SIZES
+
+        return KananaMlaLM(seed=3, **SIZES)
+    if name == "phi4_flash":
+        from tests.test_phi4_flash import make
+
+        return make()
+    from tests import hybrid_models
+
+    return {"olmo_hybrid": hybrid_models.OLMO,
+            "granite_hybrid": hybrid_models.GRANITE}[name].make()
+
+
+# what a layer's mixer and feed-forward name of their own, under the
+# skeleton's scope: (both programs, the prefill alone, the step alone)
+_MOE = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+_MECHANISMS = {
+    "gpt2": ({"blk_mixer": ("attn_full",)}, {}, {}),
+    "olmoe": ({"blk_mixer": ("attn_full",), "blk_mlp": _MOE}, {}, {}),
+    "exaone_moe": ({"blk_mixer": ("attn_full", "attn_window"),
+                    "blk_mlp": _MOE + ("moe_shared",)}, {}, {}),
+    # off its kernels the step advances the slots in a loop, whose body
+    # the lowered text outlines (``lin_attn_conv`` / ``lin_attn_state``
+    # head its names there; compiled, they read
+    # ``.../blk_mixer/lin_attn/while/body/lin_attn_conv/``)
+    "olmo_hybrid": ({"blk_mixer": ("attn_full", "lin_attn")},
+                    {"blk_mixer": ("lin_attn/lin_attn_conv",
+                                   "lin_attn/lin_attn_scan")}, {}),
+    "granite_hybrid": ({"blk_mixer": ("attn_full", "ssm/ssm_conv")},
+                       {"blk_mixer": ("ssm/ssm_scan",)},
+                       {"blk_mixer": ("ssm/ssm_state",)}),
+    "kanana_mla": ({"blk_mixer": ("attn_latent/attn_latent_down",),
+                    "blk_mlp": _MOE + ("moe_shared",)},
+                   {"blk_mixer": ("attn_latent/attn_latent_expand",)},
+                   {"blk_mixer": ("attn_latent/attn_latent_absorb",)}),
+    "phi4_flash": ({"blk_mixer": ("attn_window", "attn_shared", "gmu",
+                                  "ssm/ssm_conv")},
+                   {"blk_mixer": ("ssm/ssm_scan",)},
+                   {"blk_mixer": ("ssm/ssm_state",)}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_MECHANISMS))
+def test_every_program_carries_the_skeletons_scopes(name):
+    """The lowered text of a bucket's prefill and of the decode step
+    holds the skeleton's scopes outermost (``blk_embed``, ``blk_mixer``,
+    ``blk_mlp``, ``blk_head``; the prefill ``blk_store`` too), and what
+    the block names of its own lies under them: the benchmark's readers
+    match ``/scope/`` inside an ``op_name``, so an outer scope moves
+    none of them, and the new ones split a program by the skeleton's
+    parts."""
+    import re
+
+    from paddle_tpu.decode import model as dm
+
+    model = _toy(name)
+    S, n = 3, 11
+    cache = model._cache()
+    tables = np.zeros((S, model.pages_per_seq), np.int32)
+    zeros = np.zeros((S,), np.int32)
+    step = dm._decode_step.lower(
+        model.params, *cache[:2], tables, zeros, zeros, heads=model.heads,
+        page_size=model.page_size, block=model.block,
+        extra=cache[2:]).as_text(debug_info=True)
+    bucket = model.prefill_bucket(n)
+    pages = model.allocator.alloc(model.context_pages(list(range(n)), 4))
+    try:
+        where = model._prompt_rows(pages, bucket, n)
+    finally:
+        model.allocator.free(pages)
+    prefill = dm._prefill_bucket.lower(
+        model.params, *cache[:2], np.zeros((bucket,), np.int32), where,
+        np.int32(n), heads=model.heads, block=model.block,
+        extra=cache[2:]).as_text(debug_info=True)
+
+    both, in_prefill, in_step = _MECHANISMS[name]
+    for program, text, own in (("_prefill_bucket", prefill, in_prefill),
+                               ("_decode_step", step, in_step)):
+        skeleton = ["blk_embed", "blk_mixer", "blk_mlp", "blk_head"]
+        if program == "_prefill_bucket":
+            skeleton.append("blk_store")
+        names = set(re.findall(r'"jit\(%s\)/([^"]*)"' % program, text))
+        assert names, program
+        for scope in skeleton:
+            assert any(op.startswith(scope + "/") for op in names), (
+                program, scope)
+        # one level, outermost: no skeleton scope lies inside another
+        assert not [op for op in names
+                    if re.search(r"/blk_(embed|mixer|mlp|head|store)/",
+                                 "/" + op.split("/", 1)[-1])], program
+        for outer in ("blk_mixer", "blk_mlp"):
+            for inner in both.get(outer, ()) + own.get(outer, ()):
+                # a loop's body lies a ``while/body`` deeper
+                rx = re.compile(r"^%s/(.*/)?%s/" % (
+                    outer, inner.replace("/", "/(.*/)?")))
+                assert any(rx.match(op) for op in names), (
+                    program, outer, inner)
+                # and nowhere else: no mechanism outside its part
+                last = inner.rsplit("/", 1)[-1]
+                assert not [op for op in names
+                            if "/%s/" % last in "/" + op
+                            and not op.startswith(outer + "/")], (
+                    program, inner)

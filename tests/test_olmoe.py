@@ -374,7 +374,8 @@ def test_load_counters_ignore_padding_and_inactive_slots(model):
 
 def test_named_scopes_place_the_routed_layer(model):
     """Every program that runs the block carries the four moe_ scopes
-    in its op_names (what the benchmark's readers match)."""
+    in its op_names (what the benchmark's readers match), under the
+    skeleton's ``blk_mlp`` since PR 51."""
     from paddle_tpu.decode import model as dm
 
     text = dm._decode_step.lower(
@@ -385,7 +386,7 @@ def test_named_scopes_place_the_routed_layer(model):
         block=model.block).as_text(debug_info=True)
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
-        assert f"_decode_step)/{scope}/" in text, scope
+        assert f"_decode_step)/blk_mlp/{scope}/" in text, scope
 
 
 # -- the GPT-2 block through the same skeleton -------------------------------

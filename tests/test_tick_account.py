@@ -76,9 +76,15 @@ def _value(name, **labels):
     return obs.REGISTRY.get(name).value(**labels)
 
 
+def _summed(name, **labels):
+    """A family summed over its children that hold ``labels``."""
+    fam = obs.REGISTRY.get(name)
+    return sum(v["value"] for v in fam.snapshot()["values"]
+               if all(v["labels"].get(k) == w for k, w in labels.items()))
+
+
 def _slow_ticks_counted():
-    fam = obs.REGISTRY.get("decode_slow_ticks_total")
-    return sum(v["value"] for v in fam.snapshot()["values"])
+    return _summed("decode_slow_ticks_total")
 
 
 def _spanned(events, name):
@@ -163,9 +169,11 @@ def test_admissions_a_tick_and_the_slot_seconds_they_cost():
              "decode_slot_seconds_total")
 
     def snap():
+        # the stalled slot-seconds are kept by bucket since PR 51: their
+        # sum over the buckets is what the family read before
         return ({n: _value(names[0], n=n) for n in ("0", "1", "2", "3",
                                                     "4+")},
-                _value(names[1]), _value(names[2]))
+                _summed(names[1]), _value(names[2]))
 
     seen = [0]
 
@@ -216,6 +224,118 @@ def test_admissions_a_tick_and_the_slot_seconds_they_cost():
         # the steps' inputs and the deliveries ride the same flush
         assert _value("decode_step_inputs_total", source="uploaded") >= 2
     ring.clear()
+
+
+def _moved(snap0, name):
+    """A family's rise since ``snap0``, by its labels' values (one
+    label: the value itself)."""
+    was = {tuple(u["labels"].items()): u["value"]
+           for u in snap0.get(name, {"values": []})["values"]}
+    out = {}
+    for v in obs.REGISTRY.get(name).snapshot()["values"]:
+        rise = v["value"] - was.get(tuple(v["labels"].items()), 0.0)
+        if rise:
+            label = tuple(v["labels"][k] for k in sorted(v["labels"]))
+            out[label[0] if len(label) == 1 else label] = rise
+    return out
+
+
+def test_an_admitting_tick_flushes_the_prefills_wait_and_its_buckets():
+    """ISSUE 51: one tick of the GPT-2 toy seats a 70-row prompt (bucket
+    128) and a 20-row one (bucket 64) beside one live slot; then one
+    seats a prompt whose first 16 rows the prefix cache holds."""
+    from paddle_tpu.decode.model import TinyDecoderLM
+    from paddle_tpu.decode.prefix import PrefixCache
+
+    lm = TinyDecoderLM(seed=3, max_len=256, num_pages=128, pages_per_seq=32)
+    sess = DecodeSession(lm, max_slots=4,
+                         prefix_cache=PrefixCache(lm.allocator, lm.page_size))
+    long = [2 + i % 50 for i in range(70)]
+    sess.submit(DecodeRequest([5, 7, 9, 11], max_new_tokens=40))
+    sess.step(), sess.step()            # one slot live, nothing waiting
+    fams = ("decode_admissions_total",
+            "decode_admit_stalled_slot_seconds_total",
+            "decode_admit_tick_rows_total")
+    with obs.recording() as ring:
+        snap0 = obs.snapshot()
+        before = {label: _counted(label) for label in PHASE_SPANS}
+        stalled0 = _summed(fams[1])
+        sess.submit(DecodeRequest(long, max_new_tokens=4))
+        sess.submit(DecodeRequest([60 - i for i in range(20)],
+                                  max_new_tokens=4))
+        sess.step()
+        events = ring.events()
+        moved = {label: _counted(label) - before[label]
+                 for label in PHASE_SPANS}
+        got = {name: _moved(snap0, name) for name in fams}
+        stalled = _summed(fams[1]) - stalled0
+
+        # a suffix over cached pages, in a tick of its own
+        snap1 = obs.snapshot()
+        sess.submit(DecodeRequest(long[:16] + [60, 61, 62],
+                                  max_new_tokens=4))
+        sess.step()
+        suffix = {name: _moved(snap1, name) for name in fams}
+    ring.clear()
+
+    # the wait: the sum of its spans, a nested label beside `prefill`,
+    # which holds it, and `admit`, which holds that
+    waits = [e for e in events if e["name"] == "decode.prefill_wait"]
+    assert len(waits) == 2
+    assert 0 < moved["prefill_wait"] == pytest.approx(
+        _spanned(events, "decode.prefill_wait"), rel=1e-6)
+    assert moved["prefill_wait"] < moved["prefill"] <= moved["admit"]
+    by_id = {e["args"]["id"]: e for e in events}
+    assert {by_id[e["args"]["parent"]]["name"] for e in waits} == {
+        "decode.prefill"}
+    # ... and `IN_TICK` still tiles the tick without it
+    assert sum(moved[label] for label in IN_TICK if label != "other") <= (
+        _spanned(events, "decode.tick"))
+
+    admits = {e["args"]["bucket"]: e["dur"] / 1e6 for e in events
+              if e["name"] == "decode.admit"}
+    assert sorted(admits) == [64, 128]
+    assert got["decode_admissions_total"] == {"128": 1, "64": 1}
+    # one slot was live before the first, two before the second: the
+    # slot-seconds go by the bucket's rows, real and padding, and over
+    # its children the family reads what it read before it had labels
+    assert got[fams[1]] == pytest.approx(
+        {("128", "real"): admits[128] * 70 / 128,
+         ("128", "pad"): admits[128] * 58 / 128,
+         ("64", "real"): 2 * admits[64] * 20 / 64,
+         ("64", "pad"): 2 * admits[64] * 44 / 64}, rel=1e-6)
+    assert stalled == pytest.approx(admits[128] + 2 * admits[64], rel=1e-6)
+    # 128 + 64 rows ran; the 90 real ones end to end fit one bucket of 128
+    assert got["decode_admit_tick_rows_total"] == {"run": 192, "packed": 128}
+
+    assert suffix["decode_admissions_total"] == {"suffix": 1}
+    assert list(suffix[fams[1]]) == [("suffix", "real")]
+    assert suffix["decode_admit_tick_rows_total"] == {}
+
+
+def test_packing_is_left_alone_where_it_would_cost_rows():
+    """500 + 60 rows run 512 + 64; laid end to end they are 560, which
+    the ladder rounds to 1,024: a packer would not, and the account
+    counts the tick at what it ran."""
+    reg = obs.MetricsRegistry()
+    from paddle_tpu.decode.model import TinyDecoderLM
+
+    lm = TinyDecoderLM(seed=3, max_len=1024, num_pages=8, pages_per_seq=128)
+    assert lm.packed_prefill_rows(560) == 1024
+    assert lm.packed_prefill_rows(1024 + 70) == 1024 + 128
+    account = TickAccount(reg, packed_rows=lm.packed_prefill_rows)
+    for n in (500, 60):
+        with phase("decode.admit", prompt_len=n,
+                   bucket=lm.prefill_bucket(n)) as admit:
+            pass
+        account.admitted(admit, 0, True)
+    account.flush(0.0, 2, 2, 0, False, 0.0)
+    rows = reg.get("decode_admit_tick_rows_total")
+    assert (rows.value(kind="run"), rows.value(kind="packed")) == (576, 576)
+    assert reg.get("decode_admissions_total").value(bucket="512") == 1
+    # nobody was seated before them: nothing stood still
+    assert reg.get("decode_admit_stalled_slot_seconds_total").snapshot()[
+        "values"] == []
 
 
 def test_four_or_more_admissions_share_the_last_bucket():
