@@ -179,7 +179,10 @@ class PageRunCache:
 
     def store_prompts(self, cache, kept, where):
         """``cache`` with what every layer ``kept`` of one prompt
-        written at ``where`` (the model's ``_prompt_rows``)."""
+        written at ``where`` (the model's ``_prompt_rows``: here the
+        page run's flat row of each bucket row; a block that stores
+        another way takes what its model reckons, pages for
+        ``models/phi4_flash.py``)."""
         k_pool, v_pool = cache
         ks = jnp.stack([k for k, _ in kept])
         vs = jnp.stack([v for _, v in kept])
@@ -685,10 +688,13 @@ def _prefill_bucket(params, k_pool, v_pool, tokens, flat, n, *, heads,
     """The whole prefill of one prompt padded to ``tokens.shape[0]``
     rows: the dense forward (of every layer on every row, or of a block
     that stops half-way down, on row ``n - 1`` alone from the layer it
-    says), what each layer keeps written to the donated pools (K/V row
-    ``i`` at pool row ``flat[i]``), and the logits of row ``n - 1`` (the
-    vocabulary-wide head runs on that row alone).  Its shape depends on
-    the bucket only, not on the prompt's length or pages.
+    says), what each layer keeps written to the donated pools at
+    ``flat`` (the model's ``_prompt_rows``: K/V row ``i`` at the flat
+    pool row ``flat[i]``, or whatever else the block's
+    ``store_prompts`` takes as its ``where``), and the logits of row
+    ``n - 1`` (the vocabulary-wide head runs on that row alone).  Its
+    shape depends on the bucket only, not on the prompt's length or
+    pages.
     Rows from ``n`` on are padding: not ``live`` to the block.
     ``extra``: the block's cache buffers beyond the two pools, donated
     with them and handed back last, as by every program here."""

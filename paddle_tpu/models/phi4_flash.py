@@ -96,6 +96,21 @@ and layer ``half + 1`` needs to attend for the last row only (its K/V
 are stored for every row): told which row that is (``last``), the block
 goes on from layer ``half + 1`` with that row alone.
 
+**What a prompt leaves in the pools is written page by page.**  A
+bucket is whole pages, a ring keeps whole pages (prompt page ``p`` in
+column ``p % ring_pages``) and the run is whole pages, so no row of a
+prompt needs an address of its own.  A window layer keeps, of the
+bucket-long K/V it attended over, the pages its ring holds alone (the
+prompt's last ``ring_pages``, cut at the first of them: ``_ring_of``);
+the rows before them, which no later row sees, are dropped where they
+were made.  ``store_prompts`` turns what the nine layers kept to the
+pool's page form and writes it by ONE scatter a pool over the page
+axis, a page (``heads x rows x 128`` contiguous numbers) an update;
+the host names a pool page a kept page (``Phi4FlashLM._prompt_rows``),
+the null page for a bucket's pages past the sequence's own and a
+ring's past a short prompt's.  The decode step writes one row a slot a
+layer (``write_rows``: a (row, head) a scatter update, 640 a layer).
+
 What a state or a ring cannot do is refused by name: a prefill over
 cached pages, a fork, the speculative verify
 (``decode/state_entry.py:UnsupportedOverState``).
@@ -145,6 +160,13 @@ _M_PREFILL_ROWS = _metrics.counter(
     "`cross` = the layers that read another layer's, the rows the "
     "bucket's program handed them (one when the prefill stops half-way "
     "down, every bucket row when it does not)")
+_M_STORED_PAGES = _metrics.counter(
+    "decode_prefill_stored_pages_total",
+    "pages (a page's K and its V) a decoder-hybrid-decoder's bucketed "
+    "prefills wrote to the pools, by where they went: `run` = a "
+    "sequence's page run, `ring` = its window layers' rings, `null` = "
+    "the null page (a bucket's pages past the sequence's own, a ring's "
+    "past a short prompt's)")
 _M_SHARED_READS = _metrics.counter(
     "decode_shared_run_reads_total",
     "reads of the one page run by a decode step's layers: its owner's "
@@ -235,13 +257,46 @@ def write_rows(pool, flat, rows):
     flat rows ``flat`` (R,) = page * pg + offset of a pool seen page by
     page: head ``h`` of row ``i`` lands in row ``flat[i] % pg`` of head
     ``h`` of page ``flat[i] // pg``.  One scatter into the pool's own
-    (donated) buffer, seen (N * H * pg, D)."""
+    (donated) buffer, seen (N * H * pg, D): an update a (row, head),
+    for a decode step's row a slot (a prompt goes in by
+    ``write_pages``)."""
     _, N, H, pg, D = pool.shape
     at = ((flat // pg)[:, None] * H + jnp.arange(H, dtype=flat.dtype)
           ) * pg + (flat % pg)[:, None]
     return (pool.reshape(N * H * pg, D).at[at.reshape(-1)]
             .set(rows.reshape(-1, D).astype(pool.dtype))
             .reshape(pool.shape))
+
+
+def prompt_len(T, live, last):
+    """How many of a bucket's ``T`` rows are the prompt's: told its last
+    row (a prefill's ``n - 1``) or which rows are ``live``; all of them
+    told neither."""
+    if last is not None:
+        return last + 1
+    return T if live is None else jnp.sum(live.astype(jnp.int32))
+
+
+def whole_pages(rows, pg):
+    """``rows`` (T, H, D) with zero rows after them up to a whole page:
+    every bucket of the ladder is whole pages; a top bucket that
+    ``max_len`` cut inside a page ends with rows that ``lens`` hides and
+    decode overwrites, as a bucket's own padding does."""
+    pad = -rows.shape[0] % pg
+    return rows if not pad else jnp.pad(rows, ((0, pad), (0, 0), (0, 0)))
+
+
+def write_pages(pool, ids, rows):
+    """``pool`` (1, N, H, pg, D) with ``rows`` (P * pg, H, D) written as
+    P whole pages at the pages ``ids`` (P,): turned to the pool's page
+    form ``(P, H, pg, D)`` and scattered over the pool's page axis, ONE
+    update of ``H x pg x D`` contiguous numbers a page, into the pool's
+    own (donated) buffer.  A page named twice (the null page) keeps
+    either."""
+    _, N, H, pg, D = pool.shape
+    pages = jnp.swapaxes(rows.reshape(-1, pg, H, D), 1, 2)
+    return (pool.reshape(N, H, pg, D).at[ids]
+            .set(pages.astype(pool.dtype)).reshape(pool.shape))
 
 
 def last_row_attention(q, k, v, pos):
@@ -266,7 +321,8 @@ class Phi4FlashBlock(StateEntryCache):
     ``kv_heads`` and ``head_dim`` as published (20 of 64); a page's row
     holds them two side by side (10 of 128).  ``full_pages``: the table
     columns of the page run; ``ring_pages``: of one window layer's
-    ring; the entry's column comes after the rings'."""
+    ring; the entry's column comes after the rings'.  ``page_size``:
+    a page's rows, which a window layer cuts a prompt's ring by."""
 
     recurrent_kind = MAMBA
     layer_types: tuple = layer_kinds(32)
@@ -279,6 +335,7 @@ class Phi4FlashBlock(StateEntryCache):
     eps: float = 1e-5
     full_pages: int = 96
     ring_pages: int = 5
+    page_size: int = 128
     at: int = 0
 
     @property
@@ -388,6 +445,21 @@ class Phi4FlashBlock(StateEntryCache):
             jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0)))
         return jnp.moveaxis(a, 0, 1).reshape(T, H, D)
 
+    def _ring_of(self, k, v, n):
+        """What a window layer keeps of an ``n``-row prompt: the pages
+        its ring holds, the prompt's last ``ring_pages`` (those of the
+        bucket, where it has fewer), cut at the first of them; the
+        bucket-long K/V die here.  Page ``j`` of the cut is the prompt's
+        page ``first + j``, ``first = max((n - 1) // pg - (ring_pages -
+        1), 0)``, as ``Phi4FlashLM._prompt_rows`` reckons it on the
+        host: the cut's shape depends on the bucket alone."""
+        pg, R = self.page_size, self.ring_pages
+        keep = min(R, -(-k.shape[0] // pg)) * pg
+        first = jnp.maximum((n - 1) // pg - (R - 1), 0) * pg
+        return tuple(jax.lax.dynamic_slice_in_dim(whole_pages(rows, pg),
+                                                  first, keep)
+                     for rows in (k, v))
+
     # -- a Mamba layer's pieces ---------------------------------------------
 
     def _in_proj(self, lp, x):
@@ -429,7 +501,8 @@ class Phi4FlashBlock(StateEntryCache):
         if kind == WINDOW:
             with jax.named_scope("attn_window"):
                 a = self._window_prompt(q, k, v)
-            return self.attn_out(lp, x, a), (k, v)
+            return self.attn_out(lp, x, a), self._ring_of(
+                k, v, prompt_len(x.shape[0], live, last))
         if kind == CROSS:
             k, v = kept[self.owner]
         if kind == FULL and last is not None:
@@ -447,7 +520,7 @@ class Phi4FlashBlock(StateEntryCache):
     def _mamba_prompt(self, lp, x, live, last):
         T = x.shape[0]
         xin, z = self._in_proj(lp, x)
-        n = T if live is None else jnp.sum(live.astype(jnp.int32))
+        n = prompt_len(T, live, last)
         with jax.named_scope("ssm"):
             with jax.named_scope("ssm_conv"):
                 xc = jax.nn.silu(causal_conv(xin, lp["w_conv"])
@@ -470,27 +543,28 @@ class Phi4FlashBlock(StateEntryCache):
         return self._gate_out(lp, x, y, z), keep
 
     def store_prompts(self, cache, kept, where):
-        """``where``: (the flat pool row of each bucket row for every
-        layer that owns K/V, the window layers' rings and the run, in
-        layer order; the state entry).  A window layer keeps its last
-        ring of the prompt, the run every row; each Mamba layer's final
-        state and conv tail written whole over the entry."""
-        flat, entry = where
+        """``where``: (a row a layer that owns K/V, the window layers
+        and then the run's owner, ``bucket`` wide: in its first columns
+        the pool page of each page the layer keeps of the prompt, a
+        ring's pages as ``_ring_of`` cut them, the run's a page a page
+        of the bucket, as the model's ``_prompt_rows`` reckons them; the
+        state entry).  Written page by page, one update a page a pool;
+        each Mamba layer's final state and conv tail written whole over
+        the entry."""
+        ids, entry = where
         k_pool, v_pool, state_pool, conv_pool = cache
-        own = [kv for kv, t in zip(kept, self.layer_types)
-               if t in (WINDOW, FULL)]
+        pg = k_pool.shape[3]
+        ks, vs = zip(*[[whole_pages(rows, pg) for rows in kv]
+                       for kv, t in zip(kept, self.layer_types)
+                       if t in (WINDOW, FULL)])
         lin = [sc for sc, t in zip(kept, self.layer_types) if t == MAMBA]
-
-        def stored(pool, rows):
-            rows = jnp.stack(rows)
-            return write_rows(pool, flat.reshape(-1),
-                              rows.reshape((-1,) + rows.shape[2:]))
-
+        ids = jnp.concatenate([ids[i, :k.shape[0] // pg]
+                               for i, k in enumerate(ks)])
         states = jnp.stack([s[0] for s in lin]).astype(state_pool.dtype)
         tails = jnp.stack([s[1] for s in lin]).astype(
             conv_pool.dtype).reshape((len(lin),) + conv_pool.shape[2:])
-        return (stored(k_pool, [k for k, _ in own]),
-                stored(v_pool, [v for _, v in own]),
+        return (write_pages(k_pool, ids, jnp.concatenate(ks)),
+                write_pages(v_pool, ids, jnp.concatenate(vs)),
                 state_pool.at[:, entry].set(states),
                 conv_pool.at[:, entry].set(tails))
 
@@ -708,7 +782,7 @@ class Phi4FlashLM(StateEntryLM):
             head_dim=self.dh, window=int(sliding_window), d_inner=d_inner,
             d_state=int(mamba_d_state), dt_rank=int(mamba_dt_rank),
             eps=float(layer_norm_eps), full_pages=self.full_pages,
-            ring_pages=self.ring_pages)
+            ring_pages=self.ring_pages, page_size=self.page_size)
         dtype = jnp.dtype(dtype)
         self.conv_taps = int(mamba_d_conv)
         self.params = init_params(
@@ -773,28 +847,37 @@ class Phi4FlashLM(StateEntryLM):
         return t
 
     def _prompt_rows(self, pages, bucket: int, n: int):
-        """((layers that own K/V, bucket): the flat pool row of each
-        bucket row, the window layers' and then the run's, as
-        ``models/exaone_moe.py`` reckons them: a ring keeps the last
-        ``ring_pages`` pages of the prompt and sends the rows before
-        them, which no later row sees, to the null page with the
-        padding; the sequence's state entry)."""
+        """Where an ``n``-row prompt's bucket goes, page by page: ((layers
+        that own K/V, bucket): in the first columns of a layer's row the
+        pool page of each page the bucket's program stores of it, the
+        window layers' and then the run's, as
+        ``Phi4FlashBlock.store_prompts`` takes them; the sequence's
+        state entry).  A ring keeps the prompt's last ``ring_pages``
+        pages, page ``p`` in its column ``p % ring_pages`` (as
+        ``models/exaone_moe.py`` lays a ring out): the program cuts them
+        at the first, ``max(last - ring_pages + 1, 0)``, and of a prompt
+        with fewer the cut's pages past the last go to the null page, as
+        the run's pages past the table's do.  The rows before the ring,
+        which no later row sees, go nowhere.  The array keeps a column a
+        bucket row, most of them unread: it is the shape the benchmark's
+        driver lowers a bucket's program with
+        (``perf/drivers/generate_dhd.py:compiled_texts``)."""
         table = self.pool_table(pages)
         pg, R = self.page_size, self.ring_pages
-        rows = np.arange(bucket)
-        page_of = rows // pg
+        P = -(-bucket // pg)
         last = (n - 1) // pg
-        in_ring = (page_of > last - R) & (page_of <= last)
-        flat = np.zeros((self.rings + 1, bucket), np.int32)
-        for i in range(self.rings):
-            at = self.full_pages + i * R
-            flat[i] = np.where(in_ring, table[at:at + R][page_of % R],
-                               0) * pg + rows % pg
-        flat[self.rings] = np.where(
-            page_of < self.full_pages,
-            table[np.minimum(page_of, self.full_pages - 1)], 0) * pg \
-            + rows % pg
-        return flat, np.int32(table[self.block.entry_at])
+        held = max(last - R + 1, 0) + np.arange(min(R, P))
+        cols = (self.full_pages + R * np.arange(self.rings)[:, None]
+                + held % R)
+        ring = np.where(held <= last, table[cols], 0)
+        run = table[:P]         # a bucket is no longer than the run
+        ids = np.zeros((self.rings + 1, bucket), np.int32)
+        ids[:self.rings, :held.size], ids[self.rings, :P] = ring, run
+        rings, runs = np.count_nonzero(ring), np.count_nonzero(run)
+        _M_STORED_PAGES.inc(rings, kind="ring")
+        _M_STORED_PAGES.inc(runs, kind="run")
+        _M_STORED_PAGES.inc(ring.size + P - rings - runs, kind="null")
+        return ids, np.int32(table[self.block.entry_at])
 
     def cache_rows(self, lens) -> dict:
         """What is resident for sequences of ``lens`` rows, by kind of

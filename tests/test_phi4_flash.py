@@ -391,6 +391,196 @@ def test_the_half_way_prefill_is_the_all_rows_prefill(model, n):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
+# -- what a prompt leaves in the pools, page by page ---------------------------
+
+
+def _rowwise_store(m, cache, rows, states, pages, bucket, n):
+    """The store as it was before it went page by page, the oracle:
+    every bucket row of every layer that owns K/V scattered a (row,
+    head) at a time (``write_rows``), a window layer's rows before its
+    ring to the null page with the padding.  A bucket that ``max_len``
+    cut inside a page it takes with zero rows after it up to the page:
+    rows that ``lens`` hides, whatever they hold."""
+    table = m.pool_table(pages)
+    pg, R = m.page_size, m.ring_pages
+    rows = [tuple(jnp.pad(r, ((0, -r.shape[0] % pg), (0, 0), (0, 0)))
+                  for r in kv) for kv in rows]
+    bucket = rows[0][0].shape[0]
+    at = np.arange(bucket)
+    page_of = at // pg
+    last = (n - 1) // pg
+    in_ring = (page_of > last - R) & (page_of <= last)
+    flat = np.zeros((m.rings + 1, bucket), np.int32)
+    for i in range(m.rings):
+        col = m.full_pages + i * R
+        flat[i] = np.where(in_ring, table[col:col + R][page_of % R],
+                           0) * pg + at % pg
+    flat[m.rings] = np.where(
+        page_of < m.full_pages,
+        table[np.minimum(page_of, m.full_pages - 1)], 0) * pg + at % pg
+    entry = table[m.block.entry_at]
+    k_pool, v_pool, state_pool, conv_pool = cache
+
+    def stored(pool, which):
+        stack = jnp.stack([kv[which] for kv in rows])
+        return pf.write_rows(pool, jnp.asarray(flat.reshape(-1)),
+                             stack.reshape((-1,) + stack.shape[2:]))
+
+    return (stored(k_pool, 0), stored(v_pool, 1),
+            state_pool.at[:, entry].set(jnp.stack([s for s, _ in states])),
+            conv_pool.at[:, entry].set(jnp.stack(
+                [t for _, t in states]).reshape(
+                    (len(states),) + conv_pool.shape[2:])))
+
+
+# (what the case is, the model's sizes beside SIZES, prompt lengths);
+# rings of 3 pages of 4 rows unless the sizes say otherwise
+_STORES = [
+    ("shorter than a page", {}, [1, 3]),
+    ("shorter than a ring", {}, [4, 5, 8, 11]),
+    ("exactly a ring", {}, [12]),
+    # last % ring_pages of every value, a page's first and last row
+    ("wraps the ring", {}, [13, 16, 17, 20, 21, 24, 25, 63, 64]),
+    ("the second bucket", {}, [65, 100, 127, 128]),
+    ("a bucket of fewer pages than a ring",
+     dict(page_size=32, sliding_window=64, pages_per_seq=4, num_pages=40),
+     [1, 31, 33, 64, 65, 128]),
+    # perf/configs/phi-4-mini-flash-reasoning.json's ``rehearse`` sizes
+    ("the rehearsal model's top bucket",
+     dict(pages_per_seq=16, max_len=64, num_pages=160, mamba_expand=2),
+     [50, 61, 64]),
+    ("a top bucket max_len cut inside a page",
+     dict(max_len=50), [45, 48, 49, 50]),
+]
+
+
+@pytest.mark.parametrize(
+    "sizes,n", [pytest.param(sizes, n, id=f"{name}-{n}")
+                for name, sizes, ns in _STORES for n in ns])
+def test_the_page_wise_store_leaves_what_the_row_wise_store_left(sizes, n):
+    """On every page the sequence holds (its run's and its rings') and
+    on its state entry the pools after ``store_prompts`` are the pools
+    after the (row, head) scatter of every bucket row; every other
+    sequence's pages and entries are as they were.  The null page is
+    anybody's."""
+    m = make(**sizes)
+    rng = np.random.RandomState(n)
+    bucket = m.prefill_bucket(n)
+    other = m.allocator.alloc(m.context_pages(prompt(9), 8))
+    pages = m.allocator.alloc(m.context_pages(prompt(n), 0))
+    where = m._prompt_rows(pages, bucket, n)
+    assert where[0].shape == (m.rings + 1, bucket)
+    cache = tuple(jnp.asarray(rng.standard_normal(p.shape), p.dtype)
+                  for p in m._cache())
+    H, D = cache[0].shape[2], cache[0].shape[4]
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    rows = [(normal(bucket, H, D), normal(bucket, H, D))
+            for _ in range(m.rings + 1)]
+    states = [(normal(*cache[2].shape[2:]), normal(*cache[3].shape[2:]))
+              for _ in range(m.linear_layers)]
+    owned, lin, kept = iter(rows), iter(states), []
+    for li, kind in enumerate(m.block.layer_types):
+        if kind == WINDOW:
+            kept.append(m.block.layer(li)._ring_of(*next(owned),
+                                                   jnp.int32(n)))
+            assert kept[-1][0].shape[0] == min(
+                m.ring_pages, -(-bucket // m.page_size)) * m.page_size
+        else:
+            kept.append(next(owned) if kind == FULL else
+                        next(lin) if kind == MAMBA else None)
+    got = m.block.store_prompts(cache, kept, where)
+    want = _rowwise_store(m, cache, rows, states, pages, bucket, n)
+    run, rings = m._split(pages)
+    mine = sorted(set(run) | set(rings))
+    for new, old, was in zip(got[:2], want[:2], cache[:2]):
+        np.testing.assert_array_equal(new[0, mine], old[0, mine])
+        others = np.setdiff1d(np.arange(1, new.shape[1]), mine)
+        np.testing.assert_array_equal(new[0, others], was[0, others])
+    entry = m.allocator.entry_of(pages)
+    for new, old, was in zip(got[2:], want[2:], cache[2:]):
+        np.testing.assert_array_equal(new, old)
+        rest = np.setdiff1d(np.arange(new.shape[1]), [entry])
+        np.testing.assert_array_equal(new[:, rest], was[:, rest])
+    # the rows before the ring go nowhere: of a window layer the new
+    # store names no more pages than the ring has, where the old one
+    # named a row a bucket row
+    assert np.count_nonzero(where[0][:m.rings]) <= m.rings * m.ring_pages
+
+
+@pytest.mark.parametrize("n,new,want", [
+    # 64-row bucket = 16 pages of 4; two rings of 3 pages
+    (3, 0, {"run": 1, "ring": 2, "null": 15 + 4}),
+    (21, 3, {"run": 6, "ring": 6, "null": 10}),
+    (64, 8, {"run": 16, "ring": 6, "null": 0}),
+    (65, 0, {"run": 17, "ring": 6, "null": 15}),
+])
+def test_the_store_counts_its_pages_by_where_they_went(model, n, new, want):
+    """``decode_prefill_stored_pages_total``: a prefill's pages (a K
+    page and its V page one) by ``kind``: the sequence's run, its rings,
+    the null page (a bucket's pages past the sequence's, a ring's past
+    a short prompt's)."""
+    pages_total = metrics.REGISTRY.get("decode_prefill_stored_pages_total")
+
+    def counted():
+        return {k: pages_total.value(kind=k) for k in want}
+
+    ids = prompt(n, 2)
+    pages = model.allocator.alloc(model.context_pages(ids, new))
+    before = counted()
+    try:
+        model.prefill(ids, pages)
+    finally:
+        model.allocator.free(pages)
+    after = counted()
+    assert {k: after[k] - before[k] for k in want} == want
+
+
+def _updates_under(jaxpr, scope, out=None):
+    """[(primitive, the operand's shape, numbers a single update
+    writes)] of every scatter and dynamic_update_slice whose name stack
+    holds ``scope``, sub-jaxprs and all."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if scope in str(eqn.source_info.name_stack):
+            if name.startswith("scatter"):
+                upd = eqn.invars[2].aval.shape
+                dims = eqn.params["dimension_numbers"].update_window_dims
+                out.append((name, eqn.invars[0].aval.shape,
+                            int(np.prod([upd[d] for d in dims]))))
+            elif name == "dynamic_update_slice":
+                out.append((name, eqn.invars[0].aval.shape,
+                            int(np.prod(eqn.invars[1].aval.shape))))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _updates_under(sub, scope, out)
+    return out
+
+
+@pytest.mark.parametrize("bucket", [64, 128])
+def test_no_update_of_the_bucket_programs_store_is_less_than_a_page(
+        model, bucket):
+    """Under ``blk_store`` of a bucket's program every update into a
+    pool writes a whole page (heads x rows x lanes numbers) or more (a
+    state entry's layers): TWO scatters into pools of pages, one a
+    pool, each over the page axis with a page as its window."""
+    m, cache = model, model._cache()
+    jaxpr = jax.make_jaxpr(
+        lambda *a: dm._prefill_bucket(
+            *a, heads=m.heads, block=m.block, extra=cache[2:]))(
+        m.params, *cache[:2], np.zeros((bucket,), np.int32),
+        (np.zeros((m.rings + 1, bucket), np.int32), np.int32(0)),
+        np.int32(9))
+    updates = _updates_under(jaxpr.jaxpr, "blk_store")
+    page = int(np.prod(cache[0].shape[2:]))
+    assert len(updates) == 4 and all(size >= page for *_, size in updates)
+    pools = [u for u in updates if u[1] == cache[0].shape[1:]]
+    assert [size for *_, size in pools] == [page, page]
+    assert {name for name, *_ in pools} == {"scatter"}
+
+
 def test_the_bucket_program_keeps_one_row_from_the_full_layer_on(model):
     """In the jaxpr of the 64-row bucket the cross layer's and the
     GMU's projections have ONE row; the self-decoder's have 64."""
