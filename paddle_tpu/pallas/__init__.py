@@ -262,3 +262,36 @@ from paddle_tpu.pallas.softmax import softmax as pallas_softmax  # noqa: E402
 from paddle_tpu.pallas.lstm import lstm_seq as pallas_lstm_seq  # noqa: E402
 from paddle_tpu.pallas.flash_attention import (  # noqa: E402
     flash_attention as pallas_flash_attention)
+
+
+def use_paged_index_scores(pool_dtype, page_size: int, heads: int,
+                           dim: int) -> bool:
+    """A sparse latent layer's decode step scores the slots' cached
+    index rows by the kernel wherever ``paged_fits()`` holds, by the
+    decode kernels' rule: no threshold; else gathered in XLA
+    (``paged_index_scores_reference``)."""
+    from paddle_tpu.pallas import sparse_latent as _s
+
+    return dispatch("paged_index_scores", policy(
+        _s.paged_fits(pool_dtype, page_size, heads, dim), True))
+
+
+def use_index_scores(rows: int, keys: int, heads: int, dim: int) -> bool:
+    """A prefill's index scores of ``rows`` query rows on ``keys`` key
+    rows by the kernel wherever ``dense_fits()`` holds (whole blocks of
+    128 rows and up); else ``index_scores_reference``."""
+    from paddle_tpu.pallas import sparse_latent as _s
+
+    return dispatch("index_scores", policy(
+        _s.dense_fits(rows, keys, heads, dim), True))
+
+
+def use_selected_flash_attention(heads: int, rows: int, keys: int,
+                                 dim: int) -> bool:
+    """A prefill's attention under the selection's mask by the kernel
+    wherever ``flash_fits()`` holds; else
+    ``selected_attention_reference``."""
+    from paddle_tpu.pallas import sparse_latent as _s
+
+    return dispatch("selected_flash_attention", policy(
+        _s.flash_fits(heads, rows, keys, dim), True))

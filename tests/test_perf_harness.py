@@ -8,7 +8,8 @@ imported and not copied), the expert layer's prefill readers (PR 38's
 cases, imported too), the state-space layer's readers and the new
 cell's entries (PR 41's), the latent layer's readers and its cell's
 entries (PR 45's), the decoder-hybrid-decoder's cell, its readers and
-its rehearsal (PR 48's), and the readers of the decode tick's own
+its rehearsal (PR 48's), the sparse latent cell's entries and its seven
+readers (PR 53's), and the readers of the decode tick's own
 account (PR 37) against a registry pair recorded from a session and
 against spans laid over the device plane of the trace recorded on the
 chip (``perf/tests/data``), and PR 51's readers of the skeleton's
@@ -27,7 +28,7 @@ pytest.register_assert_rewrite(
     "perf.tests.test_moe_prefill_readers", "perf.tests.test_ssm_readers",
     "perf.tests.test_granite_cell", "perf.tests.test_kanana_cell",
     "perf.tests.test_latent_readers", "perf.tests.test_phi4_flash_cell",
-    "perf.tests.test_skeleton_readers")
+    "perf.tests.test_skeleton_readers", "perf.tests.test_glm_cell")
 
 from perf.harness import program_spans as ps  # noqa: E402
 from perf.harness import tick_account as ta  # noqa: E402
@@ -42,6 +43,7 @@ from perf.tests.test_moe_prefill_readers import (  # noqa: E402,F401
 from perf.tests import test_granite_cell as _granite_cell  # noqa: E402
 from perf.tests import test_kanana_cell as _kanana_cell  # noqa: E402
 from perf.tests import test_latent_readers as _latent_readers  # noqa: E402
+from perf.tests import test_glm_cell as _glm_cell  # noqa: E402
 from perf.tests import test_phi4_flash_cell as _phi4_cell  # noqa: E402
 from perf.tests.test_granite_cell import (  # noqa: E402,F401
     test_correct_holds_the_attention_layers_and_the_state,
@@ -152,6 +154,24 @@ def test_the_decoder_hybrid_decoder_cell_and_its_readers(case, monkeypatch):
     if case is _phi4_cell.test_the_cell_is_appended_where_it_reports:
         monkeypatch.setattr(_phi4_cell, "BENCH",
                             _as_left_with(_phi4_cell.BENCH, 10, 78))
+    case()
+
+
+# PR 53's: the sparse latent cell (the last of 11 today), its entries
+# and its seven readers; its rehearsal runs from ``tests/test_glm_cell.py``,
+# a file of its own, so that another worker takes it
+@pytest.mark.parametrize("case", [
+    _glm_cell.test_the_traffic_is_the_issues_letter_for_letter,
+    _glm_cell.test_every_context_selects_and_the_longest_sequence_fits,
+    _glm_cell.test_every_catalog_key_is_uncut_but_the_three_in_reduced,
+    _glm_cell.test_the_cell_is_appended_where_it_reports,
+    _glm_cell.test_every_listed_reader_loads,
+    _glm_cell.test_correct_holds_every_ablation_and_the_precisions,
+    _glm_cell.test_sizes_and_the_algorithms_counts,
+    _glm_cell.test_the_seven_readers_arithmetic,
+    _glm_cell.test_a_program_without_the_scopes_or_the_counters_reads_nothing],  # noqa: E501
+    ids=_CASE_ID)
+def test_the_sparse_latent_cell_and_its_readers(case):
     case()
 
 
