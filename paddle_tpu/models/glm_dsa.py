@@ -55,11 +55,17 @@ Computed three ways, the same numbers:
   all of them: the dense numbers.
 - **a prefill bucket** (``prompt_mixer``): up to ``index_topk`` rows
   plain causal through the flash kernel, expanded, as Kanana's; above,
-  ``I`` for every (query row, key row) pair (``index_scores``), each
-  row's ``index_topk``-th largest score found by bisection over the
-  scores' bits, ties cut by row, and expanded attention under the mask
-  ``s in S_t`` (``selected_flash_attention``: a second entry point, the
-  causal flash kernel is not touched).
+  ``I`` for every (query row, key row) pair (``index_scores``), then
+  ``select`` hands back ``S_t`` as the BIAS the attention runs under (0
+  on a member, a large negative number elsewhere): each row's
+  ``index_topk``-th largest score found by bisection over the scores'
+  bits and its ties cut by row inside ``selection_bias``, a block of
+  query rows over the whole key width in VMEM, the score matrix read
+  once (``selection_mask`` below, the same set by 34 passes over the
+  matrix in XLA, is its oracle and the path off a TPU and for shapes
+  that do not tile); and expanded attention under that bias
+  (``selected_flash_attention``: a second entry point, the causal flash
+  kernel is not touched).
 - **a chunk over cached rows** (``mixer(lone=True)``; a prompt longer
   than the top bucket is its first bucket and then consecutive chunks,
   inside one admission; a prefix hit's suffix is chunks too): the
@@ -170,6 +176,11 @@ def selection_mask(scores, seen, k: int):
     return (above | (tied & first)) & seen
 
 
+def _bias(sel, dtype):
+    """A selected set as the bias attention runs under: 0 on a member."""
+    return jnp.where(sel, 0.0, _NEG_INF).astype(dtype)
+
+
 def select_rows(scores, k: int):
     """(S, k) int32: each slot's ``k`` rows of largest score, a tie to
     the lower row (``jax.lax.top_k``'s order); a slot with fewer scored
@@ -246,34 +257,41 @@ class GlmDsaBlock(KananaMlaBlock):
                 ..., 0, :].astype(lp["wi_k"].dtype)
 
     def select(self, q_i, w_i, keys, first):
-        """(T, n) bool, ``S_t`` of every query row: the rows at
-        positions ``first + 0..T-1`` over the key rows ``keys`` (n,
-        index_dim) at positions ``0..n-1``."""
+        """(T, n) in the keys' dtype, ``S_t`` of every query row as the
+        bias its attention runs under (0 where key ``s`` is in ``S_t``,
+        a large negative number elsewhere): the rows at positions
+        ``first + 0..T-1`` over the key rows ``keys`` (n, index_dim) at
+        positions ``0..n-1``."""
         from paddle_tpu import pallas as pk
 
         T, n = q_i.shape[0], keys.shape[0]
         seen = (jnp.arange(n, dtype=jnp.int32)[None, :]
                 <= first + jnp.arange(T, dtype=jnp.int32)[:, None])
         if n <= self.index_topk:
-            return seen
+            return _bias(seen, keys.dtype)
+        limit = jnp.reshape(first, (1,))
         with jax.named_scope("attn_index"):
             q = jnp.moveaxis(q_i, 1, 0)                     # (J, T, D)
             if pk.use_index_scores(T, n, self.index_heads, self.index_dim):
-                scores = sl.index_scores(
-                    q, w_i, keys, jnp.reshape(first, (1,)),
-                    interpret=pk.interpret_mode())
+                scores = sl.index_scores(q, w_i, keys, limit,
+                                         interpret=pk.interpret_mode())
             else:
                 scores = sl.index_scores_reference(q, w_i, keys)
         with jax.named_scope("attn_index_select"):
-            return selection_mask(scores, seen, self.index_topk)
+            if pk.use_selection_bias(T, n, keys.dtype):
+                return sl.selection_bias(
+                    scores, limit, k=self.index_topk, dtype=keys.dtype,
+                    interpret=pk.interpret_mode())
+            return _bias(selection_mask(scores, seen, self.index_topk),
+                         keys.dtype)
 
-    def attend(self, lp, qn, qr, rows, sel, first, heads):
+    def attend(self, lp, qn, qr, rows, bias, first, heads):
         """Expanded attention of the query rows (T, heads, .) on the
-        latent rows ``rows`` (n, width) under ``sel`` (T, n) -> (T,
-        heads, v)."""
+        latent rows ``rows`` (n, width) under ``select``'s ``bias`` (T,
+        n) -> (T, heads, v)."""
         from paddle_tpu import pallas as pk
 
-        T, n = sel.shape
+        T, n = bias.shape
         dtype, qk = rows.dtype, self.nope + self.rope_dim
         with jax.named_scope("attn_sparse"):
             with jax.named_scope("attn_latent_expand"):
@@ -288,7 +306,6 @@ class GlmDsaBlock(KananaMlaBlock):
                 k = jnp.concatenate([kn.astype(dtype), kr], axis=-1)
             q = jnp.moveaxis(jnp.concatenate([qn, qr], axis=-1), 1,
                              0).astype(dtype)               # (H, T, qk)
-            bias = jnp.where(sel, 0.0, _NEG_INF).astype(dtype)
             if pk.use_selected_flash_attention(heads, T, n, qk):
                 # the kernel has one head size: the values ride in the
                 # keys' with zero lanes behind them
@@ -327,9 +344,9 @@ class GlmDsaBlock(KananaMlaBlock):
                 a = self._causal(lp, qn, qr, rows, heads)
             else:
                 first = jnp.int32(0)
-                sel = self.select(*self.index_query(lp, cq, n, pos), keys,
-                                  first)
-                a = self.attend(lp, qn, qr, rows, sel, first, heads)
+                bias = self.select(*self.index_query(lp, cq, n, pos), keys,
+                                   first)
+                a = self.attend(lp, qn, qr, rows, bias, first, heads)
             return self.attn_out(lp, x, a.reshape(T, -1)), (rows, keys)
 
     def store_prompts(self, cache, kept, where):
@@ -373,8 +390,8 @@ class GlmDsaBlock(KananaMlaBlock):
         rows = pages[moved].reshape(-1, pool.shape[-1])
         pages, moved = _pages(index_pool, li, addr.tables)
         keys = pages[moved].reshape(-1, index_pool.shape[-1])
-        sel = self.select(q_i, w_i, keys, first)
-        return self.attend(lp, qn, qr, rows, sel, first, heads)
+        bias = self.select(q_i, w_i, keys, first)
+        return self.attend(lp, qn, qr, rows, bias, first, heads)
 
     def _step(self, lp, qn, qr, q_i, w_i, pool, index_pool, li, addr,
               heads):
@@ -749,7 +766,7 @@ def chosen_sets(model, tokens):
             _, _, cq = block.queries(lp, n, pos, model.heads)
             sets.append(block.select(
                 *block.index_query(lp, cq, n, pos),
-                block.index_key(lp, n, pos), jnp.int32(0)))
+                block.index_key(lp, n, pos), jnp.int32(0)) == 0)
             x, _ = block.prompt_mixer(lp, x, pos, model.heads, None)
             if "wr" in lp:
                 m = rms_norm(x, lp["w_post"], block.eps).astype(

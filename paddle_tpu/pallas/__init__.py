@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Eleven kernel families, seventeen ``pl.pallas_call``s: the fused
+Twelve kernel families, twenty-one ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -40,6 +40,13 @@ matrix copied by hand through a double buffer one expert ahead, so read
 once; its row tile is ``grouped_gemm.ROW_TILE`` (the MXU's 128 rows,
 near the rows a group holds), its column block the widest that stays
 resident, and a call of fewer rows than a tile is ``ragged_dot``'s.
+Sparse latent attention's four are in ``sparse_latent.py`` (its head
+has their contracts): the indexer's scores over a slot's live index
+pages at a decode step, over dense rows at a prefill, that prefill's
+selection (each query row's ``index_topk``-th score, its ties and the
+bias of the chosen set from one read of the score matrix, a block of
+query rows over the whole key width in VMEM) and flash attention under
+that bias.
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -50,7 +57,7 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   decode kernels (ragged paged attention, prefill flash attention,
   the gated delta, SSD, S6 and conv steps, the chunked gated delta rule of
   a hybrid's prefill, latent paged attention, the experts' grouped
-  GEMM) have no threshold.  All three
+  GEMM, sparse latent attention's four) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
   LSTM's and the softmax's thresholds are not re-measured on this chip
@@ -284,6 +291,17 @@ def use_index_scores(rows: int, keys: int, heads: int, dim: int) -> bool:
 
     return dispatch("index_scores", policy(
         _s.dense_fits(rows, keys, heads, dim), True))
+
+
+def use_selection_bias(rows: int, keys: int, dtype) -> bool:
+    """A prefill's selected sets, from its index scores to the bias its
+    attention runs under, by the kernel wherever ``selection_fits()``
+    holds (a row block resident over the whole key width); else
+    ``glm_dsa.selection_mask``, 34 passes over the scores in XLA."""
+    from paddle_tpu.pallas import sparse_latent as _s
+
+    return dispatch("selection_bias", policy(
+        _s.selection_fits(rows, keys, dtype), True))
 
 
 def use_selected_flash_attention(heads: int, rows: int, keys: int,

@@ -1,4 +1,4 @@
-"""The three kernels of sparse latent attention (``models/glm_dsa.py``:
+"""The four kernels of sparse latent attention (``models/glm_dsa.py``:
 a learned indexer chooses, for every query row, the cached rows its
 latent attention reads).
 
@@ -33,6 +33,36 @@ A block past ``limit[0] + `` the query block's last row (keys no query
 row of the block may see) is skipped and its output left unwritten: the
 caller masks by position.
 
+**``selection_bias``** (a prefill's selection).  From those scores the
+mask the attention below runs under, ``S_t`` of every query row as an
+additive bias:
+
+    scores (T, n) float32   ``index_scores``' output; the blocks it left
+                            unwritten hold anything (NaN included)
+    limit  (1,) int32       the position of the first query row
+    k      static           rows a query row keeps (``index_topk``)
+    ->     (T, n) ``dtype``: 0 where key ``s`` is in ``S_t``, a large
+           negative number elsewhere
+
+``S_t`` is, of the keys row ``t`` sees (``s <= limit + t``), the
+``min(k, seen)`` of largest score in the floats' total order (-0.0 under
+0.0, the infinities at the ends), a tie at the edge to the lower ``s``:
+member for member ``models/glm_dsa.py:selection_mask``.  One grid step
+holds ``selection_rows()`` query rows over the WHOLE key width in VMEM
+(64 rows x 25,600 keys x 4 B = 6.5 MB): it masks by position, turns the
+scores into int32 keys of the same order in a scratch, finds each row's
+``k``-th largest there by bisection over the 32 bits (a pass is a
+compare and a lane-wise count over the scratch, the lanes summed once a
+pass; the counts at both ends of the interval ride along, so the rows
+above the edge and the rows tied on it are known when it closes), cuts
+the tied, where a row has more of them than room, at a column found by
+a second bisection over the column index (a block none of whose rows
+needs it skips that), and writes the bias.  The scores are read from
+HBM once and the bias written once: 4 + 2 B a pair where the XLA form
+passed 34 times over the matrix.  Key columns past the row block's last
+seen position (a bucket's causal half) are neither compared nor
+counted; they are written as not selected.
+
 **``selected_flash_attention``** (a prefill over selected rows).  Flash
 attention forward whose mask is an additive bias a (query row, key row)
 pair, shared by all heads (0 where the pair is selected, a large
@@ -57,10 +87,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 _F32 = jnp.float32
 _NEG_INF = -1e30            # finite, inside the attention kernel
+_INT_MIN = -2 ** 31
 LANES = 128
 MAX_FETCH = 32              # index pages a turn of the paged walk reads
 SCORE_BLOCK_Q = 256
 SCORE_BLOCK_K = 512
+SELECT_ROWS = 64            # query rows a step of the selection holds
+SELECT_CHUNK = 1024         # key columns a turn of one of its passes takes
 FLASH_BLOCK = 512
 HEADS_A_STEP = 4
 VMEM_LIMIT = 64 * 1024 * 1024
@@ -255,6 +288,145 @@ def index_scores(q, w, k, limit, *, interpret: bool = False):
         interpret=interpret,
     )(limit.astype(jnp.int32).reshape(1), q.astype(k.dtype), w.astype(_F32),
       k)
+
+
+# -- each row's selected set as a bias (a prefill) ----------------------------
+
+
+def selection_rows(T: int, n: int, itemsize: int) -> int:
+    """Query rows a grid step holds: the most up to ``SELECT_ROWS``, in
+    halvings down to a bfloat16 tile's 16, that divide ``T`` and whose
+    scores (two buffers), keys and bias (two buffers) over ``n`` keys
+    take half of ``VMEM_LIMIT``; or 0."""
+    r = SELECT_ROWS
+    while r >= 16 and (T % r
+                       or r * n * (12 + 2 * itemsize) > VMEM_LIMIT // 2):
+        r //= 2
+    return r if r >= 16 else 0
+
+
+def selection_fits(T: int, n: int, dtype) -> bool:
+    """Key rows in whole chunks of 128 and up, query rows in whole row
+    blocks that stay resident over the key width."""
+    return (_block(n, SELECT_CHUNK) > 0
+            and selection_rows(T, n, jnp.dtype(dtype).itemsize) > 0)
+
+
+def _select_kernel(limit_ref, s_ref, o_ref, keys, *, k, rows, chunk, n):
+    first = limit_ref[0] + pl.program_id(0) * rows
+    # a row's position is the last key column it sees
+    pos = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    turns = jnp.minimum((first + rows - 1) // chunk + 1, n // chunk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+
+    def tiles(c):
+        """The column offsets of chunk ``c``'s 128-lane tiles."""
+        return [pl.multiple_of(c * chunk + j * LANES, LANES)
+                for j in range(chunk // LANES)]
+
+    def make(c, carry):
+        for off in tiles(c):
+            b = jax.lax.bitcast_convert_type(s_ref[:, pl.ds(off, LANES)],
+                                             jnp.int32)
+            key = jnp.where(b < 0, b ^ jnp.int32(0x7fffffff), b)
+            keys[:, pl.ds(off, LANES)] = jnp.where(lane <= pos - off, key,
+                                                   _INT_MIN)
+        return carry
+
+    jax.lax.fori_loop(0, turns, make, 0)
+
+    def count(hit):
+        """(rows, 1): the seen chunks' keys ``x`` at columns ``off +
+        lane`` with ``hit(x, off)``, counted lane-wise, the lanes summed
+        once."""
+        def turn(c, acc):
+            for off in tiles(c):
+                acc = acc + hit(keys[:, pl.ds(off, LANES)],
+                                off).astype(jnp.int32)
+            return acc
+
+        acc = jax.lax.fori_loop(0, turns, turn,
+                                jnp.zeros((rows, LANES), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    # the k-th largest key: the largest v with count(keys >= v) >= k
+    # (``glm_dsa.kth_largest``), count(>= lo) and count(> hi) carried
+    def halve(_, bounds):
+        lo, hi, at_lo, over_hi = bounds
+        mid = (lo >> 1) + (hi >> 1) + ((lo | hi) & 1)        # the ceiling
+        wide = jnp.broadcast_to(mid, (rows, LANES))
+        cnt = count(lambda x, off: x >= wide)
+        ok = cnt >= k
+        return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1),
+                jnp.where(ok, cnt, at_lo), jnp.where(ok, over_hi, cnt))
+
+    col = jnp.zeros((rows, 1), jnp.int32)
+    edge, _, at_edge, above = jax.lax.fori_loop(0, 32, halve, (
+        col + _INT_MIN, col + (2 ** 31 - 1), col + turns * chunk, col))
+    edge_wide = jnp.broadcast_to(edge, (rows, LANES))
+    # a row that sees k keys or fewer keeps them all; another keeps the
+    # keys above its edge and the first ``room`` of those tied on it
+    room = k - above
+    crowded = (jnp.minimum(pos + 1, n) > k) & (at_edge - above > room)
+
+    def cut(_):
+        """The column of each crowded row's ``room``-th tied key."""
+        def narrow(_, bounds):
+            lo, hi = bounds
+            mid = (lo + hi) >> 1
+            ok = count(lambda x, off: (x == edge_wide)
+                       & (lane <= mid - off)) >= room
+            return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
+
+        return jax.lax.fori_loop(0, (n - 1).bit_length(), narrow,
+                                 (col, col + (n - 1)))[0]
+
+    last = jax.lax.cond(jnp.max(crowded.astype(jnp.int32)) > 0, cut,
+                        lambda _: col, None)
+    last = jnp.minimum(jnp.where(crowded, last, n), pos)
+
+    def write(c, carry):
+        for off in tiles(c):
+            x = keys[:, pl.ds(off, LANES)]
+            sel = (x > edge_wide) | ((x == edge_wide) & (lane <= last - off))
+            o_ref[:, pl.ds(off, LANES)] = jnp.where(
+                sel, 0.0, _NEG_INF).astype(o_ref.dtype)
+        return carry
+
+    def blank(c, carry):
+        for off in tiles(c):
+            o_ref[:, pl.ds(off, LANES)] = jnp.full(
+                (rows, LANES), _NEG_INF, _F32).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, turns, write, 0)
+    jax.lax.fori_loop(turns, n // chunk, blank, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "dtype", "interpret"))
+def selection_bias(scores, limit, *, k: int, dtype,
+                   interpret: bool = False):
+    """The Pallas call (the contract at the top of the file)."""
+    T, n = scores.shape
+    rows = selection_rows(T, n, jnp.dtype(dtype).itemsize)
+    chunk = _block(n, SELECT_CHUNK)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(T // rows,),
+        in_specs=[pl.BlockSpec((rows, n), lambda i, lim: (i, 0))],
+        out_specs=pl.BlockSpec((rows, n), lambda i, lim: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, n), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_select_kernel, k=k, rows=rows, chunk=chunk, n=n),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, n), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="selection_bias",
+        interpret=interpret,
+    )(limit.astype(jnp.int32).reshape(1), scores.astype(_F32))
 
 
 # -- flash attention under a bias a (query row, key row) pair -----------------

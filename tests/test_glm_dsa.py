@@ -8,8 +8,9 @@ every case selects, and once above it (dense); the selected set itself,
 row by row, a constructed tie included; the four ranks' shares add up
 to the uncut layer; ``copy_page``, a prefix hit and free / realloc keep
 latent and index rows together; every ablation of the reference moves
-the logits; the three kernels interpreted against their jnp references;
-refusals by name; the counters."""
+the logits; the four kernels interpreted against their jnp references
+(the prefill's selection as a bias member for member against
+``selection_mask``, case by case); refusals by name; the counters."""
 
 import os
 import re
@@ -394,6 +395,122 @@ def test_the_decode_step_selects_through_the_kernels(model, kernels):
     assert count(kernel="paged_index_scores",
                  path="interpret") - before == model.layers
     assert ref.rel_rms(got, _want(model, prompt + tokens, 40)) <= TOL
+
+
+# -- the prefill's selection as a kernel --------------------------------------
+
+
+def _selection_case(case):
+    """(scores (T, n) float32, first, k, the bias' dtype) of one case of
+    ``selection_bias`` against ``selection_mask``."""
+    rng = np.random.RandomState(len(case))
+    T, n, first, k, dtype = 64, 1024, 0, 100, jnp.float32
+    if case == "short_rows":
+        # a bucket from its first row: rows 0..99 see k keys or fewer
+        # and keep them all, the rows after them select; 128 rows are
+        # two row blocks, the first of which never fills a chunk
+        T, k = 128, 100
+    elif case == "tied_run":
+        first = 896                     # a chunk over 896 cached rows
+    elif case == "chunk_over_nan":
+        # a chunk over 200 cached rows: its rows see 201..264 keys, the
+        # kernel compares the first chunk of 1,024 and skips the second
+        n, first = 2048, 200
+    elif case == "narrow_row_block":
+        # a key width at which 64 rows do not stay resident: two grid
+        # steps of 32 rows, in the pools' bfloat16
+        n, first, k, dtype = 33792, 30000, 2048, jnp.bfloat16
+        assert sl.selection_rows(T, n, 2) == 32
+        assert sl.selection_rows(T, 25600, 2) == 64
+    scores = rng.randn(T, n).astype(np.float32)
+    if case == "tied_run":
+        # a run of equal scores across the k-th place of every row, in
+        # columns that straddle two chunks of the count: 60 above it, 40
+        # places for 300 tied
+        scores = np.minimum(scores, 0.0)
+        scores[:, 5:905:15] = 3.0
+        scores[:, 400:1000:2] = 1.5
+    elif case == "signs_and_infinities":
+        scores = rng.choice(
+            np.asarray([0.0, -0.0, np.inf, -np.inf, 1e-30, -1e-30, 2.5,
+                        -2.5], np.float32), size=(T, n))
+        first, k = 500, 200            # the edge falls among the zeros
+    elif case == "chunk_over_nan":
+        # what ``index_scores`` leaves unwritten: the blocks of 512 keys
+        # past the sight of a block of 256 query rows (keys 1,024 on: the
+        # half of the compared chunk no row sees, and all of the other)
+        unseen = (np.arange(n)[None, :] // 512 * 512
+                  > first + np.arange(T)[:, None] // 256 * 256 + 255)
+        assert unseen[:, 512:].all() and not unseen[:, :512].any()
+        scores[unseen] = np.nan
+    return scores, first, k, dtype
+
+
+@pytest.mark.parametrize("case", [
+    "random", "tied_run", "short_rows", "chunk_over_nan",
+    "signs_and_infinities", "narrow_row_block"])
+def test_selection_bias_is_selection_mask_member_for_member(case):
+    scores, first, k, dtype = _selection_case(case)
+    T, n = scores.shape
+    seen = np.arange(n)[None, :] <= first + np.arange(T)[:, None]
+    want = np.asarray(gd.selection_mask(jnp.asarray(scores),
+                                        jnp.asarray(seen), k))
+    bias = sl.selection_bias(jnp.asarray(scores),
+                             jnp.asarray([first], jnp.int32), k=k,
+                             dtype=dtype, interpret=True)
+    assert bias.dtype == dtype and bias.shape == (T, n)
+    got = np.asarray(bias.astype(jnp.float32))
+    np.testing.assert_array_equal(got == 0, want)
+    assert (got[~want] <= -1e29).all()
+    np.testing.assert_array_equal(
+        want.sum(-1), np.minimum(first + np.arange(T) + 1, k))
+
+
+def test_which_shapes_the_selection_kernel_takes():
+    assert sl.selection_fits(8192, 8192, jnp.bfloat16)
+    assert sl.selection_fits(4096, 25600, jnp.bfloat16)
+    assert sl.selection_fits(32, 128, jnp.float32)
+    assert not sl.selection_fits(16, 64, jnp.float32)    # the toy chunk
+    assert not sl.selection_fits(8, 128, jnp.float32)
+    assert sl.selection_rows(4096, 25600, 2) == 64
+    assert sl.selection_rows(4096, 28672, 4) == 32
+    assert sl.selection_rows(48, 1024, 2) == 16
+    # past half of VMEM_LIMIT at 16 rows no block is resident
+    assert not sl.selection_fits(4096, 1 << 18, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("rows, first", [(128, 0), (32, 96)],
+                         ids=["prompt", "chunk"])
+def test_select_through_the_kernel_is_select_through_the_reference(
+        model, rows, first):
+    """``GlmDsaBlock.select`` of a toy prompt (both prefill kernels) and
+    of a toy chunk over cached rows (32 rows: the scores by the jnp
+    reference, the selection by the kernel), against the path with the
+    kernels off.  Whole-number queries and keys and head weights in
+    quarters: the scores are exact in any order of the sum, so the two
+    paths score alike to the bit, and tie often."""
+    block, n = model.block, 128
+    rng = np.random.RandomState(rows)
+    q = jnp.asarray(rng.randint(-2, 3, (rows, block.index_heads,
+                                        block.index_dim)), jnp.float32)
+    w = jnp.asarray(rng.randint(-4, 5, (rows, block.index_heads)) / 4.0,
+                    jnp.float32)
+    keys = jnp.asarray(rng.randint(-2, 3, (n, block.index_dim)), jnp.float32)
+    count = metrics.REGISTRY.get("pallas_dispatch_total").value
+    state = dict(pk._STATE)
+    try:
+        pk.enable(False)
+        want = np.asarray(block.select(q, w, keys, jnp.int32(first)))
+        pk.enable(True, interpret=True)
+        before = count(kernel="selection_bias", path="interpret")
+        got = np.asarray(block.select(q, w, keys, jnp.int32(first)))
+        assert count(kernel="selection_bias",
+                     path="interpret") - before == 1
+    finally:
+        pk._STATE.update(state)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        (got == 0).sum(-1), np.minimum(first + np.arange(rows) + 1, TOPK))
 
 
 # -- behind the session -------------------------------------------------------
