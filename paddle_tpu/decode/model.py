@@ -18,8 +18,9 @@ heads, the first pool alone, and defines both mixers itself:
 ``models/kanana_mla.py``;
 a hybrid's recurrent layers keep a state a sequence in buffers of
 their own, ``extra``, beside the pages of its attention layers:
-``decode/state_entry.py``, under ``models/olmo_hybrid.py`` and
-``models/granite_hybrid.py``; a decoder-hybrid-decoder has layers that
+``decode/state_entry.py``, under ``models/olmo_hybrid.py``,
+``models/granite_hybrid.py`` and, the pages holding latent rows,
+``models/ling_hybrid.py``; a decoder-hybrid-decoder has layers that
 keep NOTHING: they read another layer's page run, or an activation an
 earlier layer hands on inside the program, and its prefill goes on with
 the prompt's last row alone from the layer after which no other row

@@ -1,8 +1,11 @@
 """What a hybrid needs of the paged skeleton (``decode/model.py``): a
 model whose *recurrent* layers keep one state a sequence, whatever its
-length, beside the K/V pages of its attention layers.  Two models stand
+length, beside the pages of its attention layers.  Four models stand
 on it: ``models/olmo_hybrid.py`` (the gated delta rule three layers of
-four) and ``models/granite_hybrid.py`` (Mamba-2 nine layers of ten).
+four), ``models/granite_hybrid.py`` (Mamba-2 nine layers of ten),
+``models/phi4_flash.py`` (Mamba-1 beside rings and one shared run) and
+``models/ling_hybrid.py`` (the delta rule under a per-channel decay five
+layers of six, beside ONE latent row a token in the sixth).
 
 Two resources a sequence, from the one cache manager
 (``decode/paged_kv.py:CacheManager``): its page run, which the
@@ -11,7 +14,11 @@ and ONE state entry: every recurrent layer's state and the last rows
 that layer's conv saw (pools ``(recurrent layers, entries, ...)``, entry
 0 the null entry that inactive slots address).  Its table row is the
 page run's columns, then the entry.  The cache is ``(k_pool, v_pool,
-state_pool, conv_pool)``, all four donated to every program.
+state_pool, conv_pool)``, all four donated to every program.  What a
+page holds is the block's to say (``StateEntryCache.page_mixer`` and
+``store_pages``; ``StateEntryLM._make_pools``' ``page_heads`` and
+``value_pool``): K and V heads in two pools by default, ONE latent row
+in the first pool alone (the second a placeholder) for a latent layer.
 
 What needs a state as it stood at an earlier row is refused by name
 (``UnsupportedOverState``): a prefill over cached pages, a fork, the
@@ -169,7 +176,7 @@ class StateEntryCache(PageRunCache):
         rec = [t == self.recurrent_kind for t in self.layer_types]
         full = [kv for kv, r in zip(kept, rec) if not r]
         lin = [sc for sc, r in zip(kept, rec) if r]
-        k_pool, v_pool = super().store_prompts((k_pool, v_pool), full, flat)
+        k_pool, v_pool = self.store_pages((k_pool, v_pool), full, flat)
         states = _pad_last(jnp.stack([s for s, _ in lin]),
                            state_pool.shape[-1]).astype(state_pool.dtype)
         tails = jnp.stack([c for _, c in lin]).astype(
@@ -183,9 +190,21 @@ class StateEntryCache(PageRunCache):
                 "a chunk of rows a sequence (a suffix prefill, the "
                 "speculative verify) would need the state between them")
         if not self.recurrent:
-            x, kv = super().mixer(lp, x, pos, cache[:2], li, addr, heads)
+            x, kv = self.page_mixer(lp, x, pos, cache[:2], li, addr, heads)
             return x, kv + tuple(cache[2:])
         return self.recurrent_step(lp, x, cache, addr)
+
+    # -- the page side: K and V heads in two pools, unless the block says --
+
+    def store_pages(self, pages, kept, flat):
+        """``pages`` (the two pools) with what the attention layers
+        ``kept`` of one prompt written at the page run's rows ``flat``."""
+        return PageRunCache.store_prompts(self, pages, kept, flat)
+
+    def page_mixer(self, lp, x, pos, pages, li, addr, heads):
+        """An attention layer over a decode step's rows and the page
+        run -> (the rows after the mixer's residual, the two pools)."""
+        return PageRunCache.mixer(self, lp, x, pos, pages, li, addr, heads)
 
     def recurrent_step(self, lp, x, cache, addr):
         """A recurrent layer over a decode step's rows ``x`` (S, d), one
@@ -217,15 +236,24 @@ class StateEntryLM(PagedDecoderLM):
         self.linear_layers = recurrent
         self.full_layers = self.layers - recurrent
 
+    # the ``decode_cache_rows`` / ``decode_cache_bytes`` kind of the page
+    # run's rows ("latent" where a page holds latent rows)
+    page_kind = "full"
+
     def _make_pools(self, num_pages, dtype, state_entries, page_heads,
-                    state_shape, tail_shape):
-        """``page_heads``: (the heads a page's row is stored as, their
-        width); ``state_shape``: a layer's state as stored, float32;
-        ``tail_shape``: what a layer's conv keeps, in ``dtype``."""
+                    state_shape, tail_shape, value_pool=True):
+        """``page_heads``: what a page's row is stored as ((heads, their
+        width), or (lanes,) of a latent row); ``value_pool``: whether
+        the rows have values in a second pool of the same shape, else it
+        is a placeholder of one element a layer that every program
+        threads through and none reads; ``state_shape``: a layer's state
+        as stored, float32; ``tail_shape``: what a layer's conv keeps,
+        in ``dtype``."""
         self.allocator = CacheManager(num_pages, state_entries)
         shape = (self.full_layers, num_pages, self.page_size, *page_heads)
         self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
+        self.v_pool = jnp.zeros(shape if value_pool
+                                else (self.full_layers, 1), dtype)
         self.extra_pools = (
             jnp.zeros((self.linear_layers, state_entries, *state_shape),
                       _F32),
@@ -280,12 +308,19 @@ class StateEntryLM(PagedDecoderLM):
         cache, summed over the layers of the kind: an attention layer
         holds every row; a recurrent layer one state a sequence,
         whatever its length."""
-        return {"full": int(np.sum(lens)) * self.full_layers,
+        return {self.page_kind: int(np.sum(lens)) * self.full_layers,
                 "state": len(lens) * self.linear_layers}
 
+    @property
+    def page_row_bytes(self) -> int:
+        """Bytes one token's row takes in one attention layer, as
+        stored: both pools' (a placeholder pool holds no row)."""
+        return sum(int(np.prod(p.shape[3:])) * p.dtype.itemsize
+                   for p in (self.k_pool, self.v_pool) if p.ndim > 3)
+
     def cache_bytes(self, lens) -> dict:
-        row = 2 * self.stored_heads * self.dh * self.k_pool.dtype.itemsize
-        return {"full": int(np.sum(lens)) * self.full_layers * row,
+        return {self.page_kind: (int(np.sum(lens)) * self.full_layers
+                                 * self.page_row_bytes),
                 "state": len(lens) * self.entry_bytes()}
 
     # -- refused by name -----------------------------------------------------

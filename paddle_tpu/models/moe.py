@@ -53,7 +53,9 @@ computed and nothing stands in for them.
 The four ``jax.named_scope``s (``moe_router``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine``) put every instruction of the layer
 under a name in the compiled program's ``op_name`` (inside a share's
-loop over blocks as ``.../while/body/moe_experts/...``).
+loop over blocks as ``.../while/body/moe_experts/...``); a router with
+a group step (``kept_groups``: DeepSeek-V3's ``n_group`` /
+``topk_group``) runs it under ``moe_group`` inside ``moe_dispatch``.
 """
 
 from __future__ import annotations
@@ -109,6 +111,20 @@ _M_GROUPED_BLOCKS = _metrics.counter(
     "it says how often one block was not enough")
 
 
+_M_GROUPS = _metrics.counter(
+    "moe_groups_chosen_total",
+    "live rows that kept an expert group at the router's group step "
+    "(n_group / topk_group), by group, summed over the routed layers; "
+    "its largest group over the mean is how uneven the group step was")
+
+
+def count_groups(phase: str, group_load: np.ndarray) -> None:
+    """Feed ``moe_groups_chosen_total`` from one call's (layers,
+    n_group) count of the live rows that kept each group."""
+    for group, rows in enumerate(group_load.sum(axis=0)):
+        _M_GROUPS.inc(int(rows), group=str(group), phase=phase)
+
+
 def count_load(phase: str, load: np.ndarray, rows: int, top_k: int,
                experts: int, elsewhere: int = 0) -> None:
     """Feed the registry from one call's (layers, held experts) load,
@@ -157,20 +173,49 @@ def sigmoid_scores(bias, scale: float):
     return rule
 
 
-def route(m, wr, top_k: int, scores=softmax_scores):
-    """Router of rows ``m`` (R, d) over ``wr`` (d, E): the float32
-    scores of all experts by the rule ``scores``, the ``top_k`` largest
-    per row of what it ranks by (a tie goes to the lower expert index),
-    and the weights it makes of what it weighs by -> (weights (R, k)
-    f32, experts (R, k) int32)."""
+def kept_groups(rank_by, n_group: int, topk_group: int):
+    """The group step of DeepSeek-V3's router (``n_group`` /
+    ``topk_group``).  The experts lie in ``n_group`` groups of equal
+    size, side by side; a group's score is the sum of its two largest
+    entries of ``rank_by`` (R, E), and each row keeps its ``topk_group``
+    best groups (a tie goes to the lower group) -> (R, n_group) bool."""
+    R, E = rank_by.shape
+    best_two, _ = jax.lax.top_k(rank_by.reshape(R, n_group, E // n_group), 2)
+    _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    return jnp.any(groups[..., None] == jnp.arange(n_group), axis=1)
+
+
+def _route(m, wr, top_k, scores, groups):
+    """``route``, and the (R, n_group) bool of the groups each row kept
+    (None without a group step)."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(m, wr, preferred_element_type=_F32)
         rank_by, weigh_by, weights_of = scores(logits)
     with jax.named_scope("moe_dispatch"):
-        top, idx = jax.lax.top_k(rank_by, top_k)
-        if weigh_by is not rank_by:
+        kept, ranked = None, rank_by
+        if groups is not None and groups[0] > 1:
+            n_group, topk_group = groups
+            with jax.named_scope("moe_group"):
+                kept = kept_groups(rank_by, n_group, topk_group)
+                ranked = jnp.where(
+                    jnp.repeat(kept, rank_by.shape[1] // n_group, axis=1),
+                    rank_by, -jnp.inf)
+        top, idx = jax.lax.top_k(ranked, top_k)
+        if weigh_by is not ranked:
             top = jnp.take_along_axis(weigh_by, idx, axis=-1)
-        return weights_of(top), idx
+        return weights_of(top), idx, kept
+
+
+def route(m, wr, top_k: int, scores=softmax_scores, groups=None):
+    """Router of rows ``m`` (R, d) over ``wr`` (d, E): the float32
+    scores of all experts by the rule ``scores``, the ``top_k`` largest
+    per row of what it ranks by (a tie goes to the lower expert index),
+    and the weights it makes of what it weighs by -> (weights (R, k)
+    f32, experts (R, k) int32).  ``groups``: ``(n_group, topk_group)``,
+    the ``top_k`` taken among the experts of the groups ``kept_groups``
+    keeps (under ``moe_group``); None or one group: every expert
+    stands, and the program is what it was."""
+    return _route(m, wr, top_k, scores, groups)[:2]
 
 
 # The dense pass is taken by a call of at most DENSE_MAX_ROWS rows that
@@ -342,7 +387,7 @@ def _dense_experts(m, w, expert_of, w_gate, w_up, w_down, top_k):
 
 
 def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
-                   scores=softmax_scores, held=None):
+                   scores=softmax_scores, held=None, groups=None):
     """``sum_e w_e * W_down,e( silu(W_gate,e m) * W_up,e m )`` over
     those of each row's ``top_k`` experts that are held here.
 
@@ -351,7 +396,8 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
     of them, C == E); ``live`` (R,) bool or None (all rows) -> (y
     (R, d) float32, load (C,) int32: assignments per held expert over
     the live rows, elsewhere () int32: the live rows' assignments to
-    experts not held).
+    experts not held).  With ``groups`` (``route``'s) a fourth: (n_group,)
+    int32, the live rows that kept each group.
 
     ``expert_path`` of the call's shape says which of the two ways
     computes the sum, and ``grouped_block_rows`` over how many sorted
@@ -365,7 +411,7 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
     # bucket's padding right of the prompt; no live row reads it) is no
     # group's, as if it had chosen experts held elsewhere
     live_groups = partial and path == "grouped" and live is not None
-    w, idx = route(m, wr, top_k, scores)
+    w, idx, kept = _route(m, wr, top_k, scores, groups)
     with jax.named_scope("moe_dispatch"):
         expert_of = idx.reshape(-1)                          # (R*k,)
         if partial:
@@ -391,4 +437,9 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
     else:
         y = _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
                              top_k)
-    return y, load, elsewhere
+    if kept is None:
+        return y, load, elsewhere
+    with jax.named_scope("moe_dispatch"):
+        if live is not None:
+            kept &= live[:, None]
+        return y, load, elsewhere, jnp.sum(kept.astype(jnp.int32), axis=0)

@@ -4,7 +4,7 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Twelve kernel families, twenty-one ``pl.pallas_call``s: the fused
+Thirteen kernel families, twenty-three ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
@@ -23,7 +23,12 @@ and is made in VMEM (``s6_step.py``, 1), the depthwise conv before
 any of them, over the
 same entries' kept rows (``conv_step.py``, 1), the gated delta rule
 chunked over a prefill bucket's rows, the state in VMEM from chunk to
-chunk (``gated_delta_chunked.py``, 1), and absorbed latent attention
+chunk (``gated_delta_chunked.py``, 1), both again under a decay that
+is a vector over a head's key channels (Kimi Delta Attention,
+``kda.py``, 2: the step with a row of decays where a scalar stood, the
+chunked rule built a block of 16 query rows at a time against one
+reference row, so that no factor of a per-channel ratio leaves
+float32), and absorbed latent attention
 over paged latent rows, every head on the one stored row, which is key
 and value both (``latent_attention.py``, 1: a grid step a slot, the
 slot's live pages walked by a dynamic loop and copied by hand through a
@@ -55,8 +60,8 @@ Mode (``enable()``; a process starts in ``auto``, not interpreted):
   ``H <= LSTM_MAX_HIDDEN``, the softmax at ``cols <=
   SOFTMAX_MAX_COLS``, flash attention at ``S >= FLASH_MIN_SEQ``; the
   decode kernels (ragged paged attention, prefill flash attention,
-  the gated delta, SSD, S6 and conv steps, the chunked gated delta rule of
-  a hybrid's prefill, latent paged attention, the experts' grouped
+  the gated delta, KDA, SSD, S6 and conv steps, the chunked gated delta
+  and KDA rules of a hybrid's prefill, latent paged attention, the experts' grouped
   GEMM, sparse latent attention's four) have no threshold.  All three
   thresholds come from an earlier setup.  Flash attention at S=2048
   and the decode kernels are what the LM and generate cells run; the
@@ -205,6 +210,31 @@ def use_gated_delta_chunked(state_dtype, rows: int, heads: int, d_v: int,
 
     return dispatch("gated_delta_chunked", policy(
         _g.fits(state_dtype, rows, heads, d_v, d_k), True))
+
+
+def use_kda_step(state_dtype, heads: int, d_v: int, wide: int) -> bool:
+    """The per-channel-decay delta rule's decode step (``kda.py``), by
+    ``use_gated_delta_step``'s rule: the kernel wherever ``step_fits()``
+    holds, else the slots' entries gathered, advanced and scattered in
+    XLA (``models/ling_hybrid.py:step_kda``)."""
+    from paddle_tpu.pallas import kda as _k
+
+    return dispatch("kda_step", policy(
+        _k.step_fits(state_dtype, heads, d_v, wide), True))
+
+
+def use_kda_chunked(state_dtype, rows: int, heads: int, d_v: int, d_k: int,
+                    lower_bound: float) -> bool:
+    """That rule over a prefill bucket's ``rows`` by the kernel wherever
+    ``chunked_fits()`` holds (a float32 state, a bucket of whole chunks,
+    keys of whole lanes, a log-decay bounded below by ``lower_bound``),
+    else chunked in XLA (``models/ling_hybrid.py:chunked_kda``, its
+    reference)."""
+    from paddle_tpu.pallas import kda as _k
+
+    return dispatch("kda_chunked", policy(
+        _k.chunked_fits(state_dtype, rows, heads, d_v, d_k, lower_bound),
+        True))
 
 
 def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:

@@ -42,22 +42,24 @@ LANES, SUBLANES = 128, 8
 BLOCK_BYTES = 3 << 19
 
 
-def head_block(heads: int, d_v: int, wide: int):
+def head_block(heads: int, d_v: int, wide: int,
+               block_bytes: int = BLOCK_BYTES):
     """Heads a grid step takes: the most that divide ``heads`` and keep
-    the block within ``BLOCK_BYTES`` (15 of 30 at (192, 128): 1.47 MB);
+    the block within ``block_bytes`` (15 of 30 at (192, 128): 1.47 MB);
     None where one head is already over it."""
     fit = [hb for hb in range(1, heads + 1)
-           if heads % hb == 0 and hb * d_v * wide * 4 <= BLOCK_BYTES]
+           if heads % hb == 0 and hb * d_v * wide * 4 <= block_bytes]
     return max(fit, default=None)
 
 
-def fits(state_dtype, heads: int, d_v: int, wide: int) -> bool:
+def fits(state_dtype, heads: int, d_v: int, wide: int,
+         block_bytes: int = BLOCK_BYTES) -> bool:
     """Float32 entries whose rows are whole lanes (``wide``, the key
     width as stored, a multiple of 128) and whose ``d_v`` rows are whole
     tiles of 8, in blocks of ``head_block`` heads."""
     return (jnp.dtype(state_dtype) == _F32 and wide % LANES == 0
             and d_v % SUBLANES == 0
-            and head_block(heads, d_v, wide) is not None)
+            and head_block(heads, d_v, wide, block_bytes) is not None)
 
 
 def _kernel(at_ref, alpha_ref, beta_ref, q_ref, k_ref, v_ref, pool_ref,

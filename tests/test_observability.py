@@ -871,7 +871,7 @@ def test_every_pallas_call_has_a_name():
                     else:
                         unnamed.append(f"{path}:{node.lineno}")
     assert not unnamed
-    assert len(names) == 21 and len(set(names)) == len(names)
+    assert len(names) == 23 and len(set(names)) == len(names)
     # the ring's name does not hold the grouped one's, which
     # perf/layer_metrics/attn_full_roofline.py counts kernels by
     assert [n for n in names if "ragged_paged_attention_gqa" in n] == [
@@ -884,7 +884,8 @@ def test_every_pallas_call_has_a_name():
             "gated_delta_step", "ssd_step", "s6_step", "conv_step",
             "gated_delta_chunked", "latent_paged_attention",
             "paged_index_scores", "index_scores", "selection_bias",
-            "selected_flash_attention"} <= set(names)
+            "selected_flash_attention", "kda_step",
+            "kda_chunked"} <= set(names)
 
 
 def test_the_latent_layers_names():
