@@ -44,15 +44,18 @@ for two.)
 
 Computed three ways, the same numbers:
 
-- **a decode step** (``mixer``): the step's rows written, then, while
-  no slot holds more than ``index_topk`` rows, ``latent_paged_attention``
-  as Kanana runs it; else, for every slot, ``paged_index_scores`` over
-  its live index pages (``attn_index``), ``jax.lax.top_k`` of the scores
-  (``attn_index_select``: the set is exact, a tie to the lower row), the
-  chosen latent rows fetched by row through the page table and absorbed
-  attention on them alone by the same kernel over the fetched rows
-  (``attn_sparse``).  A slot with fewer rows than ``index_topk`` selects
-  all of them: the dense numbers.
+- **a decode step** (``mixer``): the step's rows written, then ONE
+  walk of every slot's live latent pages, absorbed
+  (``latent_paged_attention``): plain, as Kanana runs it, while no slot
+  holds more than ``index_topk`` rows; else under the selected sets.
+  For those, ``paged_index_scores`` over each slot's live index pages
+  (``attn_index``), then ``selection_bias`` over the slots' scores, a
+  row of the kernel a slot (``attn_index_select``: the set is exact, a
+  tie to the lower row; ``selection_mask`` where the slots are no row
+  block), and the walk with that bias added to each page's scores
+  (``attn_sparse``): nothing is sorted and no row is fetched by id.  A
+  slot with fewer rows than ``index_topk`` selects all of them: the
+  dense numbers.
 - **a prefill bucket** (``prompt_mixer``): up to ``index_topk`` rows
   plain causal through the flash kernel, expanded, as Kanana's; above,
   ``I`` for every (query row, key row) pair (``index_scores``), then
@@ -179,13 +182,6 @@ def selection_mask(scores, seen, k: int):
 def _bias(sel, dtype):
     """A selected set as the bias attention runs under: 0 on a member."""
     return jnp.where(sel, 0.0, _NEG_INF).astype(dtype)
-
-
-def select_rows(scores, k: int):
-    """(S, k) int32: each slot's ``k`` rows of largest score, a tie to
-    the lower row (``jax.lax.top_k``'s order); a slot with fewer scored
-    rows has them first, then rows that scored -inf."""
-    return jax.lax.top_k(scores, k)[1]
 
 
 def _store(pool, rows, where):
@@ -395,17 +391,26 @@ class GlmDsaBlock(KananaMlaBlock):
 
     def _step(self, lp, qn, qr, q_i, w_i, pool, index_pool, li, addr,
               heads):
-        """(S, heads, v): every slot's one row over its cached rows."""
+        """(S, heads, v): every slot's one row over its cached rows, ONE
+        walk of the slot's live latent pages either way: plain while no
+        slot holds ``index_topk`` rows, else under the selected sets as
+        a bias a (slot, cached row) pair.  The walk reads every live row
+        to attend on ``index_topk`` of them: at the pages' stream rate
+        that beats fetching the chosen rows one by one (16 ns a row)
+        while a slot holds under ~35,000 rows; past that a selection by
+        blocks of rows, whose pages the walk could skip, takes over
+        (ROADMAP R12 b)."""
         S, k = qn.shape[0], self.index_topk
         pg = pool.shape[2]
+        n = addr.tables.shape[1] * pg
         qn, qr = qn[:, None], qr[:, None]                   # (S, 1, ..)
 
-        def dense(_):
+        def walk(bias):
             return self._absorbed(lp, qn, qr, pool, li, addr.tables,
-                                  addr.lens, heads)
+                                  addr.lens, heads, bias=bias)
 
-        if addr.tables.shape[1] * pg <= k:     # no sequence can hold more
-            return dense(None)
+        if n <= k:                             # no sequence can hold more
+            return walk(None)
 
         def sparse(_):
             from paddle_tpu import pallas as pk
@@ -421,20 +426,24 @@ class GlmDsaBlock(KananaMlaBlock):
                 else:
                     scores = sl.paged_index_scores_reference(*args)
             with jax.named_scope("attn_index_select"):
-                chosen = select_rows(scores, k)             # (S, k)
+                if pk.use_selection_bias(S, n, _F32):
+                    # a row of the kernel is a slot, and each sees the
+                    # columns up to the longest slot's last: the scores
+                    # past a slot's own rows are -inf, under every real
+                    # one, and what of them a slot short of k rows keeps
+                    # the walk masks by ``lens``
+                    bias = sl.selection_bias(
+                        scores, jnp.max(addr.lens).reshape(1), k=k,
+                        dtype=_F32, interpret=pk.interpret_mode())
+                else:
+                    seen = (jnp.arange(n, dtype=jnp.int32)[None, :]
+                            <= addr.lens[:, None])
+                    bias = _bias(selection_mask(scores, seen, k), _F32)
             with jax.named_scope("attn_sparse"):
-                L, N, _, W = pool.shape
-                page = jnp.take_along_axis(addr.tables, chosen // pg,
-                                           axis=1) + li * N
-                fetched = pool.reshape(L * N * pg, W)[
-                    page * pg + chosen % pg]                # (S, k, W)
-                table = jnp.arange(S * (k // pg),
-                                   dtype=jnp.int32).reshape(S, k // pg)
-                return self._absorbed(
-                    lp, qn, qr, fetched.reshape(1, S * (k // pg), pg, W),
-                    0, table, jnp.minimum(addr.lens + 1, k) - 1, heads)
+                return walk(bias)
 
-        return jax.lax.cond(jnp.max(addr.lens) < k, dense, sparse, None)
+        return jax.lax.cond(jnp.max(addr.lens) < k, lambda _: walk(None),
+                            sparse, None)
 
 
 # -- parameters --------------------------------------------------------------
@@ -573,12 +582,10 @@ class GlmDsaLM(KananaMlaLM):
         PagedDecoderLM.__init__(self, vocab, d_model, num_heads, num_layers,
                                 max_len, page_size, pages_per_seq, bos_id,
                                 eos_id)
-        if index_topk % page_size or prefill_rows % page_size \
-                or chunk_rows % page_size:
+        if prefill_rows % page_size or chunk_rows % page_size:
             raise ValueError(
-                "index_topk, prefill_rows and chunk_rows are whole pages "
-                f"of {page_size} rows: the selected rows are read as "
-                "pages of fetched rows, a chunk starts on a page")
+                "prefill_rows and chunk_rows are whole pages of "
+                f"{page_size} rows: a chunk starts on a page")
         self.dh = int(qk_nope_head_dim) + int(qk_rope_head_dim)
         self.prefill_rows, self.chunk_rows = int(prefill_rows), int(chunk_rows)
         self.block = GlmDsaBlock(

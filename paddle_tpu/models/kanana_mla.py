@@ -303,8 +303,11 @@ class KananaMlaBlock(PageRunCache):
             out = self.attn_out(lp, xs, a.reshape(S, T, -1))
         return out.reshape(x.shape), (pool, placeholder)
 
-    def _absorbed(self, lp, qn, qr, pool, li, tables, lens, heads):
-        """q (S, T, heads, .) over the slots' pages -> (S, T, heads, v)."""
+    def _absorbed(self, lp, qn, qr, pool, li, tables, lens, heads,
+                  bias=None):
+        """q (S, T, heads, .) over the slots' pages -> (S, T, heads, v);
+        ``bias`` (S, table rows), where a block has one (a sparse
+        layer's selected set), is added to a slot's scores."""
         from paddle_tpu import pallas as pk
 
         S, T = qn.shape[:2]
@@ -318,12 +321,12 @@ class KananaMlaBlock(PageRunCache):
         kw = dict(heads=heads, v_width=self.rank, scale=self.softmax_scale)
         if pk.use_latent_paged_attention(dtype, pages.shape[1], T * heads,
                                          W, self.rank):
-            o = la.latent_paged_attention(q, pages, moved, lens,
+            o = la.latent_paged_attention(q, pages, moved, lens, bias,
                                           interpret=pk.interpret_mode(),
                                           **kw)
         else:
             o = la.latent_paged_attention_reference(q, pages, moved, lens,
-                                                    **kw)
+                                                    bias=bias, **kw)
         with jax.named_scope("attn_latent_absorb"):
             o = o.reshape(S, T, heads, self.rank).astype(lp["w_uv"].dtype)
             return jnp.einsum("sthc,hcv->sthv", o, lp["w_uv"],

@@ -47,11 +47,12 @@ near the rows a group holds), its column block the widest that stays
 resident, and a call of fewer rows than a tile is ``ragged_dot``'s.
 Sparse latent attention's four are in ``sparse_latent.py`` (its head
 has their contracts): the indexer's scores over a slot's live index
-pages at a decode step, over dense rows at a prefill, that prefill's
-selection (each query row's ``index_topk``-th score, its ties and the
-bias of the chosen set from one read of the score matrix, a block of
-query rows over the whole key width in VMEM) and flash attention under
-that bias.
+pages at a decode step, over dense rows at a prefill, the selection
+(each row's ``index_topk``-th score, its ties and the bias of the
+chosen set from one read of the score matrix, a block of rows over the
+whole key width in VMEM: a prefill's query rows, a decode step's slots)
+and a prefill's flash attention under that bias; a decode step's bias
+is an operand of ``latent_attention.py``'s walk.
 
 Mode (``enable()``; a process starts in ``auto``, not interpreted):
 
@@ -270,7 +271,8 @@ def use_conv_step(pool_dtype, entry_shape, row_dtype, taps: int,
 
 def use_latent_paged_attention(pool_dtype, page_size: int, rows: int,
                                width: int, v_width: int) -> bool:
-    """A latent layer's decode step (and a verify chunk of a few rows)
+    """A latent layer's decode step (and a verify chunk of a few rows;
+    a sparse layer's step under its selected sets as a bias)
     attends over the slots' pages of latent rows by the kernel wherever
     ``fits()`` holds (rows of whole 128-lane tiles, a q block of ``rows``
     = chunk rows x heads that stays resident), by the decode kernels'
@@ -324,10 +326,11 @@ def use_index_scores(rows: int, keys: int, heads: int, dim: int) -> bool:
 
 
 def use_selection_bias(rows: int, keys: int, dtype) -> bool:
-    """A prefill's selected sets, from its index scores to the bias its
-    attention runs under, by the kernel wherever ``selection_fits()``
-    holds (a row block resident over the whole key width); else
-    ``glm_dsa.selection_mask``, 34 passes over the scores in XLA."""
+    """The selected sets of a prefill's query rows or a decode step's
+    slots, from the index scores to the bias the attention runs under,
+    by the kernel wherever ``selection_fits()`` holds (a row block
+    resident over the whole key width); else ``glm_dsa.selection_mask``,
+    34 passes over the scores in XLA."""
     from paddle_tpu.pallas import sparse_latent as _s
 
     return dispatch("selection_bias", policy(

@@ -15,7 +15,12 @@ the page is read ONCE for both and for all heads.
     tables (S, P) int32    a slot's pages (already moved to the layer)
     lens   (S,)            rows cached BEFORE the chunk; the chunk's own
                            T rows are written before the call
+    bias   (S, P * page) float32, or None: added to a slot's scores
+                           column by column, the same for all its query
+                           rows (a sparse layer's selected set: 0 on a
+                           member, a large negative number elsewhere)
     ->     (S, R, v_width) row r: softmax over t < lens + r // heads + 1
+                           of q . row * scale (+ bias)
 
 One grid step a SLOT.  The pool stays in HBM; the step walks the slot's
 live pages alone (``ceil((lens + T) / page)`` of them, a dynamic trip
@@ -28,6 +33,14 @@ in SMEM from grid step to grid step, which is why the grid is
 ``arbitrary``).  Both products run on the MXU in the pool's dtype with
 float32 accumulation: ``q k^T`` over ``width`` lanes, ``p`` (rounded to
 the pool's dtype) on the first ``v_width`` lanes of the same buffer.
+
+The bias is an operand that is there or not, a static of the trace: a
+slot's row of it resident beside its q block, a turn a sublane row (one
+row of ``fetch * page`` columns, broadcast over the query rows).  The
+walk under a bias is still the walk of ALL the slot's live pages: a turn
+none of whose columns is a member leaves the running max where it
+started, and the first member's turn rescales what such turns summed to
+nothing.  Without one the traced body holds no load and no buffer more.
 
 At 32 heads a row of 576 stored at 640 does 32 x (640 + 512) x 2 / 1,280
 = 58 FLOP a byte: beside a v5e's ridge at 32 rows a tile (~60), neither
@@ -68,12 +81,14 @@ def fits(dtype, page_size: int, rows: int, width: int, v_width: int) -> bool:
 
 
 def latent_paged_attention_reference(q, pages, tables, lens, *, heads,
-                                     v_width, scale):
+                                     v_width, scale, bias=None):
     """The contract above in jnp: the oracle, and the path off a TPU."""
     S, R, W = q.shape
     page, P = pages.shape[1], tables.shape[1]
     rows = pages[tables].reshape(S, P * page, W).astype(_F32)
     s = jnp.einsum("srw,stw->srt", q.astype(_F32), rows) * scale
+    if bias is not None:
+        s = s + bias.astype(_F32)[:, None, :]
     limit = lens.reshape(-1, 1) + jnp.arange(R)[None, :] // heads + 1
     seen = jnp.arange(P * page)[None, None, :] < limit[:, :, None]
     p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
@@ -81,12 +96,15 @@ def latent_paged_attention_reference(q, pages, tables, lens, *, heads,
                       rows[..., :v_width]).astype(q.dtype)
 
 
-def _kernel(tab_ref, lens_ref, q_ref, pool_ref, o_ref, buf, sems, start,
-            m_scr, l_scr, acc_scr, *, heads, chunk, page, fetch, v_width,
-            scale, slots):
+def _kernel(tab_ref, lens_ref, q_ref, pool_ref, *rest, heads, chunk, page,
+            fetch, v_width, scale, slots, biased):
     """One slot.  ``buf`` (2, fetch * page, width): the double buffer;
     ``sems`` (2, fetch): one DMA semaphore a page in flight; ``start``
-    (1,) in SMEM: the half this slot's first turn was copied into."""
+    (1,) in SMEM: the half this slot's first turn was copied into;
+    ``bias_ref`` (1, turns, fetch * page), where ``biased``: the slot's
+    bias, a turn a row."""
+    bias_ref = rest[0] if biased else None
+    o_ref, buf, sems, start, m_scr, l_scr, acc_scr = rest[biased:]
     s = pl.program_id(0)
     turn_rows = fetch * page
 
@@ -130,6 +148,8 @@ def _kernel(tab_ref, lens_ref, q_ref, pool_ref, o_ref, buf, sems, start,
         rows = buf[half]                                    # (rows, width)
         sc = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                                  preferred_element_type=_F32) * scale
+        if biased:
+            sc = sc + bias_ref[0, pl.ds(t, 1), :]
         sc = jnp.where(t * turn_rows + col < limit, sc, _NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
@@ -149,17 +169,24 @@ def _kernel(tab_ref, lens_ref, q_ref, pool_ref, o_ref, buf, sems, start,
 
 @functools.partial(jax.jit, static_argnames=("heads", "v_width", "scale",
                                              "interpret"))
-def latent_paged_attention(q, pages, tables, lens, *, heads, v_width, scale,
-                           interpret: bool = False):
+def latent_paged_attention(q, pages, tables, lens, bias=None, *, heads,
+                           v_width, scale, interpret: bool = False):
     """The Pallas call (the contract at the top of the file)."""
     S, R, W = q.shape
     page, P = pages.shape[1], tables.shape[1]
     fetch = fetch_pages(P)
+    in_specs = [pl.BlockSpec((1, R, W), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)]          # pool: in HBM
+    operands = [q.astype(pages.dtype), pages]
+    if bias is not None:                  # a slot's row, a turn a sublane
+        by_turn = (P // fetch, fetch * page)
+        in_specs.append(pl.BlockSpec((1,) + by_turn,
+                                     lambda s, *_: (s, 0, 0)))
+        operands.append(bias.astype(_F32).reshape((S,) + by_turn))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # tables and lens land in SMEM
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, R, W), lambda s, *_: (s, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],       # pool: in HBM
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, R, v_width), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, fetch * page, W), pages.dtype),
@@ -172,12 +199,12 @@ def latent_paged_attention(q, pages, tables, lens, *, heads, v_width, scale,
     )
     return pl.pallas_call(
         functools.partial(_kernel, heads=heads, chunk=R // heads, page=page,
-                          fetch=fetch, v_width=v_width, scale=scale, slots=S),
+                          fetch=fetch, v_width=v_width, scale=scale, slots=S,
+                          biased=bias is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, R, v_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="latent_paged_attention",
         interpret=interpret,
-    )(tables.astype(jnp.int32), lens.astype(jnp.int32),
-      q.astype(pages.dtype), pages)
+    )(tables.astype(jnp.int32), lens.astype(jnp.int32), *operands)

@@ -1,6 +1,8 @@
 """The four kernels of sparse latent attention (``models/glm_dsa.py``:
 a learned indexer chooses, for every query row, the cached rows its
-latent attention reads).
+latent attention reads).  The fifth the layer runs, a decode step's
+read, is ``latent_attention.py``'s walk of a slot's live pages, under
+the bias ``selection_bias`` makes of ``paged_index_scores``' scores.
 
 **``paged_index_scores``** (a decode step).  The indexer keeps ONE key
 row a token a layer, ``D`` lanes, on the page run beside the latent row.
@@ -33,9 +35,9 @@ A block past ``limit[0] + `` the query block's last row (keys no query
 row of the block may see) is skipped and its output left unwritten: the
 caller masks by position.
 
-**``selection_bias``** (a prefill's selection).  From those scores the
-mask the attention below runs under, ``S_t`` of every query row as an
-additive bias:
+**``selection_bias``** (the selection: a prefill's, and a decode
+step's).  From those scores the mask the attention below runs under,
+``S_t`` of every query row as an additive bias:
 
     scores (T, n) float32   ``index_scores``' output; the blocks it left
                             unwritten hold anything (NaN included)
@@ -62,6 +64,15 @@ HBM once and the bias written once: 4 + 2 B a pair where the XLA form
 passed 34 times over the matrix.  Key columns past the row block's last
 seen position (a bucket's causal half) are neither compared nor
 counted; they are written as not selected.
+
+A decode step hands it ``paged_index_scores``' block as it is, a row a
+SLOT (32 slots x 25,600 scores are one grid step), ``limit`` the longest
+slot's last row and ``dtype`` float32: every row then sees every column
+any slot has, and a slot's own length is in its scores, -inf past it and
+so under every real score.  A slot with ``k`` rows or more gets its
+``k`` best, ties as above; one with fewer gets them all and, as
+"members", the first of its -inf columns up to ``k``: the walk that
+adds the bias (``latent_attention.py``) masks by the slot's length.
 
 **``selected_flash_attention``** (a prefill over selected rows).  Flash
 attention forward whose mask is an additive bias a (query row, key row)

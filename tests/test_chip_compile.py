@@ -1689,8 +1689,9 @@ def test_softmax_compiles(one_chip):
 # memory_analysis() of the programs at the configuration's 4,757 pages.
 # perf/configs/glm-5.json records PR 53's (the bucket 14,486,642,176, the
 # chunk 14,999,422,464: what set ``num_pages``); since PR 54 the
-# selection's int32 keys and masks live in VMEM and both plan less
-GLM_PLANS = {"decode": 12_539_195_392, 8192: 14_350_835_712,
+# selection's int32 keys and masks live in VMEM and both plan less; since
+# PR 56 the step fetches no rows (12,539,195,392 with the fetch)
+GLM_PLANS = {"decode": 12_521_984_512, 8192: 14_350_835_712,
              "chunk over 25600": 14_998_438_912}
 GLM_PARAMS = 3_909_632_768
 
@@ -1808,18 +1809,22 @@ def test_sparse_latent_kernels_compile_at_the_cells_shapes(one_chip):
         assert len(ops) == 1 and "selection_bias" in ops[0]
 
 
-def test_glm_decode_step_selects_and_reads_the_chosen_rows_alone(
+def test_glm_decode_step_walks_the_live_pages_under_the_selected_sets(
         one_chip, monkeypatch):
     """The decode step of the ``glm-5`` configuration at its real sizes
     (1 dense + 4 routed layers, 64 heads, 4,757 pages of 128 rows x (640
-    + 128) lanes, 32 slots of 200 table columns): a layer either walks
-    the slots' latent pages (``latent_paged_attention``, no slot over
-    2,048 rows) or scores the slots' index rows (ONE ``paged_index_scores``
-    call), keeps the 2,048 best and reads those by the SAME kernel over
-    the fetched rows, under ``attn_sparse``; both pools aliased input to
-    output, nothing of a pool's or a slab's size copied; 3,909,632,768
-    parameters; a plan of the arguments + 41 MB."""
+    + 128) lanes, 32 slots of 200 table columns): a layer walks the
+    slots' live latent pages (``latent_paged_attention``) either way;
+    where a slot is over 2,048 rows it first scores the slots' index
+    rows (ONE ``paged_index_scores`` call), makes the 2,048 best a slot
+    a bias from one read of the 32 x 25,600 scores (``selection_bias``,
+    one grid step) and walks under it (``attn_sparse``): no ``top_k``,
+    no sort, no gather under the mixer, nothing of the 32 x 2,048
+    fetched rows' size; both pools aliased input to output, nothing of a
+    pool's or a slab's size copied; 3,909,632,768 parameters; a plan of
+    the arguments + 24 MB."""
     from paddle_tpu.decode import model as dm
+    from paddle_tpu.observability import metrics
 
     cfg, params, pool, index_pool, block, sds = _glm_cell(
         one_chip, monkeypatch)
@@ -1829,11 +1834,17 @@ def test_glm_decode_step_selects_and_reads_the_chosen_rows_alone(
     per_layer = [sum(math.prod(a.shape) for a in jax.tree.leaves(lp))
                  for lp in params["layers"]]
     assert per_layer[:2] == [400_898_816, 817_708_032]
+    count = metrics.REGISTRY.get("pallas_dispatch_total").value
+    kernels = ("paged_index_scores", "selection_bias",
+               "latent_paged_attention")
+    before = [count(kernel=k, path="compiled") for k in kernels]
     compiled = dm._decode_step.lower(
         params, pool, index_pool, sds((S, g["pages_per_seq"]), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block).compile()
+    assert [count(kernel=k, path="compiled") - b
+            for k, b in zip(kernels, before)] == [L, L, 2 * L]
     _assert_step_outputs(compiled, S, cfg["vocab_size"])
     m = compiled.memory_analysis()
     pools = (math.prod(pool.shape) + math.prod(index_pool.shape)) * 2
@@ -1841,23 +1852,31 @@ def test_glm_decode_step_selects_and_reads_the_chosen_rows_alone(
     assert m.alias_size_in_bytes >= pools
     assert _planned_bytes(compiled) == GLM_PLANS["decode"]
     text = compiled.as_text()
-    assert not _pool_sized_strays(text, _glm_pool_sizes(pool, index_pool))
+    fetched = {S * cfg["index_topk"] * block.width: "fetched rows"}
+    assert not _pool_sized_strays(
+        text, {**_glm_pool_sizes(pool, index_pool), **fetched})
+    mixer = [line for line in text.splitlines() if "/attn_latent/" in line]
+    assert mixer and not [
+        line for line in mixer
+        if re.search(r"\b(sort|gather|topk)\(|top_k|TopK", line)]
     ops = [op for op in _kernel_op_names(text) if "grouped_gemm" not in op]
     under = "_decode_step)/blk_mixer/attn_latent/cond/"
-    assert sum(under in op and "/attn_index/" in op
-               and "paged_index_scores" in op for op in ops) == L
-    assert sum(under in op and "/attn_sparse/" in op
-               and "latent_paged_attention" in op for op in ops) == L
+    for scope, kernel in (("attn_index", "paged_index_scores"),
+                          ("attn_index_select", "selection_bias"),
+                          ("attn_sparse", "latent_paged_attention")):
+        assert sum(under in op and f"/{scope}/" in op and kernel in op
+                   for op in ops) == L, scope
     assert sum(under in op and "attn_sparse" not in op
                and "latent_paged_attention" in op for op in ops) == L
-    assert len(ops) == 3 * L
+    assert len(ops) == 4 * L
     for scope in ("attn_latent/attn_latent_down", "attn_latent/attn_index",
                   "moe_shared", "moe_router"):
         assert f"jit(_decode_step)/{_under(scope)}/" in text, scope
     # 32 rows send a held expert one row in the mean: the grouped path
     assert "jit(_decode_step)/blk_mlp/while/body/moe_experts/" in text
-    for scope in ("attn_index_select", "attn_sparse"):
-        assert re.search(rf"attn_latent/cond/\w+/{scope}/", text), scope
+    # the absorbed products stay where the read's seconds leave them out
+    assert re.search(
+        r"attn_latent/cond/\w+/attn_sparse/attn_latent_absorb/", text)
 
 
 @pytest.mark.parametrize("program", [8192, "chunk over 25600"])

@@ -92,10 +92,10 @@ def _prompt(n, seed=0):
     return np.random.RandomState(seed).randint(2, SIZES["vocab"], n).tolist()
 
 
-def _through_the_pages(model, prompt, tokens, slot=1, cached_len=0):
+def _through_the_pages(model, prompt, tokens, slot=1, cached_len=0, S=S):
     """Prefill (the suffix over cached rows when ``cached_len``), then
-    ``tokens`` teacher-forced one decode step each: the len(tokens) + 1
-    logits rows."""
+    ``tokens`` teacher-forced one decode step each, one slot of ``S``:
+    the len(tokens) + 1 logits rows."""
     pages = model.allocator.alloc(model.context_pages(prompt, len(tokens)))
     try:
         if cached_len:
@@ -196,8 +196,8 @@ def test_the_selected_set_is_the_references_row_by_row(model):
 
 def test_a_tie_at_the_edge_goes_to_the_lower_row():
     """Five rows share the 3rd largest score of a query that keeps 4:
-    the two lowest of them are kept, by both forms of the selection and
-    by the reference's sort."""
+    the two lowest of them are kept, by the selection and by the
+    reference's sort."""
     scores = np.asarray([[0.5, 2.0, 0.5, 9.0, 0.5, -1.0, 0.5, 0.5, 7.0, 0.1],
                          [1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0]],
                         np.float32)
@@ -208,9 +208,6 @@ def test_a_tie_at_the_edge_goes_to_the_lower_row():
     assert np.nonzero(mask[0])[0].tolist() == [0, 1, 3, 8]
     assert np.nonzero(mask[1])[0].tolist() == [0, 1, 2, 3]
     masked = np.where(seen, scores, -np.inf)
-    rows = np.asarray(gd.select_rows(jnp.asarray(masked), 4))
-    assert sorted(rows[0].tolist()) == [0, 1, 3, 8]
-    assert sorted(rows[1].tolist()) == [0, 1, 2, 3]
     order = np.argsort(-masked, axis=-1, kind="stable")[:, :4]
     assert sorted(order[0].tolist()) == [0, 1, 3, 8]
     # fewer seen rows than are kept: all of them, and no other
@@ -384,17 +381,54 @@ def test_index_scores_and_selected_flash_match_their_references():
     assert not sl.paged_fits(jnp.bfloat16, 8, 32, 128)
 
 
-def test_the_decode_step_selects_through_the_kernels(model, kernels):
-    """The toy sizes fit both decode kernels: the steps' index scores
-    by ``paged_index_scores`` and the read of the 16 fetched rows by
-    ``latent_paged_attention``, interpreted, counted, the same rows."""
+@pytest.mark.parametrize("slots", [S, 16], ids=["mask", "selection_bias"])
+def test_the_decode_step_selects_through_the_kernels(model, kernels, slots):
+    """The toy sizes fit the decode kernels: the steps' index scores by
+    ``paged_index_scores``, the selected sets as a bias (of 16 slots by
+    ``selection_bias``, a row of the kernel a slot; four are no row
+    block and keep ``selection_mask``) and the read by
+    ``latent_paged_attention``'s walk of the slot's own pages under it,
+    interpreted, counted, the reference model's rows.  Every other slot
+    is empty: fewer rows than are kept."""
     prompt, tokens = _prompt(40, seed=40), _prompt(3, seed=1)
     count = metrics.REGISTRY.get("pallas_dispatch_total").value
-    before = count(kernel="paged_index_scores", path="interpret")
-    got = _through_the_pages(model, prompt, tokens)
-    assert count(kernel="paged_index_scores",
-                 path="interpret") - before == model.layers
+    names = ("paged_index_scores", "selection_bias",
+             "latent_paged_attention")
+    before = [count(kernel=k, path="interpret") for k in names]
+    got = _through_the_pages(model, prompt, tokens, S=slots)
+    # one trace of the step: a layer scores once, selects once and
+    # walks in both branches of the ``cond``
+    assert [count(kernel=k, path="interpret") - b
+            for k, b in zip(names, before)] == [
+        model.layers, model.layers * (slots == 16), 2 * model.layers]
     assert ref.rel_rms(got, _want(model, prompt + tokens, 40)) <= TOL
+
+
+def test_the_steps_selection_is_selection_mask_over_ragged_slots():
+    """``selection_bias`` as the step calls it (a row a slot, every
+    column up to the longest slot's last seen, -inf past a slot's own
+    rows) against ``selection_mask`` over each slot's rows: the same
+    members among a slot's rows; what a slot short of ``k`` rows keeps
+    past them is the walk's to mask.  A run of equal scores lies across
+    the k-th place of the long slots."""
+    rng = np.random.RandomState(7)
+    n, k = 2048, 100
+    lens = np.asarray([0, 1, 57, 99, 100, 101, 640, 1023, 1024, 1500,
+                       2047, 300, 5, 900, 1999, 77], np.int32)   # rows - 1
+    scores = rng.randn(len(lens), n).astype(np.float32)
+    scores[6:, 3:600:4] = 0.75
+    seen = np.arange(n)[None, :] <= lens[:, None]
+    masked = jnp.asarray(np.where(seen, scores, -np.inf))
+    want = np.asarray(gd.selection_mask(masked, jnp.asarray(seen), k))
+    bias = np.asarray(sl.selection_bias(
+        masked, jnp.asarray([lens.max()], jnp.int32), k=k,
+        dtype=jnp.float32, interpret=True))
+    np.testing.assert_array_equal((bias == 0) & seen, want)
+    np.testing.assert_array_equal(want.sum(-1), np.minimum(lens + 1, k))
+    order = np.argsort(-np.asarray(masked), axis=-1, kind="stable")[:, :k]
+    top = np.zeros_like(want)
+    np.put_along_axis(top, order, True, axis=-1)    # ``top_k``'s members
+    np.testing.assert_array_equal(top & seen, want)
 
 
 # -- the prefill's selection as a kernel --------------------------------------
@@ -564,7 +598,7 @@ def test_what_the_sparse_model_cannot_do_is_refused_by_name(model):
     with pytest.raises(ValueError, match="outside 1..128"):
         model.prefill_bucket(129)
     with pytest.raises(ValueError, match="whole pages"):
-        gd.GlmDsaLM(**{**SIZES, "index_topk": 12})
+        gd.GlmDsaLM(**{**SIZES, "chunk_rows": 12})
 
 
 def test_the_prefill_counts_the_pairs_it_scored(model):

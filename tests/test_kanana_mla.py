@@ -284,6 +284,110 @@ def test_kernel_matches_its_reference_over_ragged_lengths(T, pages_per_seq):
     assert la.fetch_pages(P) == (4 if P == 8 else 2)
 
 
+def _biased_case(case, P):
+    """(lens, scores (S, P * pg), k) of one case of the walk under a
+    bias: each slot attends on its ``min(k, lens + 1)`` cached rows of
+    largest score (a sparse latent layer's selected set, made here by
+    the sort the kernel knows nothing of)."""
+    rng = np.random.RandomState(len(case) + P)
+    pg, k = 8, 12
+    n = P * pg
+    # an inactive slot, a slot with fewer rows than k (all kept), on a
+    # page's edge, across the fetch's edge, the table's whole width
+    lens = np.asarray([0, 6, 2 * pg - 1, 4 * pg + 1, n - 1], np.int32)
+    scores = rng.randn(len(lens), n).astype(np.float32)
+    if case == "late_members":
+        # every member of the long slots past their first 32 rows (a
+        # turn of four pages, two turns of two): the running max is
+        # still where it started when the first member comes
+        scores[3:, :4 * pg] -= 100.0
+        lens[3] = n - 2
+    elif case == "tie_at_the_edge":
+        # four rows above a run of equal scores across the k-th place:
+        # the eight lowest rows of the run
+        scores[2:] = np.minimum(scores[2:], 0.0)
+        scores[2:, 1:31:2] = 1.5
+        scores[2:, 2:12:3] = 3.0
+    return lens, scores, k
+
+
+@pytest.mark.parametrize("pages_per_seq", [8, 6], ids=["fetch4", "fetch2"])
+@pytest.mark.parametrize("case", ["ragged", "late_members",
+                                  "tie_at_the_edge"])
+def test_kernel_under_a_bias_attends_on_the_members_alone(case,
+                                                          pages_per_seq):
+    """The walk of a slot's live pages with a bias a (slot, cached row)
+    pair: the kernel against its reference under the same bias, and
+    both against a softmax over the member rows alone, in numpy."""
+    H, W, V, pg, N, P = 4, 256, 128, 8, 64, pages_per_seq
+    lens, scores, k = _biased_case(case, P)
+    rng = np.random.RandomState(P)
+    Sx, n = len(lens), P * pg
+    seen = np.arange(n)[None, :] <= lens[:, None]
+    order = np.argsort(-np.where(seen, scores, -np.inf), axis=-1,
+                       kind="stable")[:, :k]        # a tie: the lower row
+    members = np.zeros((Sx, n), bool)
+    np.put_along_axis(members, order, True, axis=-1)
+    members &= seen
+    assert members.sum(-1).tolist() == np.minimum(lens + 1, k).tolist()
+    if case == "late_members":
+        assert not members[3:, :4 * pg].any()
+    if case == "tie_at_the_edge":
+        assert np.nonzero(members[4])[0].tolist() == [
+            1, 2, 3, 5, 7, 8, 9, 11, 13, 15, 17, 19]
+    bias = jnp.asarray(np.where(members, 0.0, -1e30), jnp.float32)
+    q = jnp.asarray(rng.randn(Sx, H, W), jnp.float32)
+    pages = jnp.asarray(rng.randn(N, pg, W), jnp.float32)
+    tables = rng.randint(1, N, (Sx, P)).astype(np.int32)
+    tables[0] = 0
+    kw = dict(heads=H, v_width=V, scale=0.11)
+    args = (q, pages, jnp.asarray(tables), jnp.asarray(lens))
+    got = np.asarray(la.latent_paged_attention(*args, bias, interpret=True,
+                                               **kw))
+    want = np.asarray(la.latent_paged_attention_reference(*args, bias=bias,
+                                                          **kw))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    rows = np.asarray(pages)[tables].reshape(Sx, n, W)
+    for s in range(Sx):
+        kept = rows[s][members[s]]
+        sc = np.asarray(q[s]) @ kept.T * 0.11
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        alone = (p / p.sum(-1, keepdims=True)) @ kept[:, :V]
+        np.testing.assert_allclose(got[s], alone, rtol=2e-5, atol=2e-5)
+
+
+def test_without_a_bias_the_kernel_is_the_one_it_was():
+    """``bias=None`` is a static of the trace: the call has the four
+    operands it had and its kernel body no load more; its output is, to
+    the bit, the output under a bias of zeros (x + 0.0 is x)."""
+    rng = np.random.RandomState(0)
+    H, W, V, pg, N, P = 4, 256, 128, 8, 64, 8
+    lens = jnp.asarray([0, 5, 8, 4 * pg + 1, P * pg - 1], jnp.int32)
+    q = jnp.asarray(rng.randn(5, H, W), jnp.float32)
+    pages = jnp.asarray(rng.randn(N, pg, W), jnp.float32)
+    tables = jnp.asarray(rng.randint(1, N, (5, P)), jnp.int32)
+    kw = dict(heads=H, v_width=V, scale=0.11, interpret=True)
+    plain = la.latent_paged_attention(q, pages, tables, lens, **kw)
+    zeros = la.latent_paged_attention(
+        q, pages, tables, lens, jnp.zeros((5, P * pg), jnp.float32), **kw)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(zeros))
+
+    def call(*bias):
+        jaxpr = jax.make_jaxpr(lambda *a: la.latent_paged_attention(
+            *a, **kw))(q, pages, tables, lens, *bias)
+        eqn, = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                if e.primitive.name == "pallas_call"]
+        return eqn
+
+    bare, biased = call(), call(jnp.zeros((5, P * pg), jnp.float32))
+    assert len(bare.invars) == 4 and len(biased.invars) == 5
+    assert len(bare.params["jaxpr"].invars) \
+        == len(biased.params["jaxpr"].invars) - 1
+    # the one add of the bias' row is the whole difference
+    assert str(biased.params["jaxpr"]).count(" add ") \
+        == str(bare.params["jaxpr"]).count(" add ") + 1
+
+
 def test_kernel_fits_whole_tiles_and_a_resident_chunk():
     bf16, f32 = jnp.bfloat16, jnp.float32
     assert la.fits(bf16, 128, 32, 640, 512)
