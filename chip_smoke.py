@@ -52,12 +52,14 @@ EXPECT = {"platform": "tpu", "kernel_path": "compiled",
           "marker": "tpu_custom_call", "memory_stats": True}
 
 SIZES = {
-    # bench.py's headline configuration
+    # ResNet-50 at BS=256 on 3x224x224 over 1,000 classes: the
+    # resnet50-train-bs256 cell's program (perf/configs/resnet50.json)
     "train": dict(batch=256, image=(3, 224, 224), classes=1000, steps=6,
                   small_batch=8),
     # a 4-layer d=2048 LM block at S=1024, where flash attention starts
     "transformer": dict(B=8, S=1024, D=2048, L=4, V=32768, steps=3),
-    # benchmark/run.py's "lstm" row (h=256)
+    # an embedding of 512 into one LSTM of 256 hidden units over 100
+    # steps: inside the LSTM kernel's hidden-size threshold
     "lstm": dict(B=64, T=100, emb=512, hidden=256, steps=3),
     "softmax": dict(rows=4096, cols=256, steps=3),
     # one period of Olmo-Hybrid at the published head shapes (30 linear
@@ -170,22 +172,30 @@ def _rel(a, b):
 # -- phase: train --------------------------------------------------------
 
 
+def _resnet50(batch, image, classes):
+    """The resnet50 cells' training program (ResNet-50, mean
+    cross-entropy, Momentum 0.9) at the smoke's sizes, at the cells'
+    learning rate: on one repeated random batch 0.1 without warm-up
+    overshoots for the first dozen steps, and phase_train asserts the
+    loss falls."""
+    from perf.programs import resnet_imagenet
+
+    cfg = {"image": image, "class_dim": classes, "depth": 50,
+           "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
+    return resnet_imagenet.build(cfg, {"batch": batch})
+
+
 def phase_train(size, seed):
     import jax
     import jax.numpy as jnp
 
-    import bench
+    import paddle_tpu as fluid
     from paddle_tpu import amp, executor as em
 
-    amp.enable()  # bf16 matmul/conv, fp32 master weights (bench.py's default)
+    amp.enable()  # bf16 matmul/conv, fp32 master weights
     batch, image, classes = size["batch"], size["image"], size["classes"]
-    # bench.py's program; only the learning-rate VALUE differs (0.01 for
-    # 0.1 — a scalar the startup program fills, the step program is the
-    # same): on one repeated random batch 0.1 without warm-up overshoots
-    # for the first dozen steps, and this phase asserts the loss falls.
-    fluid, loss = bench.build(batch, image, classes, learning_rate=0.01)
-    main, startup = (fluid.default_main_program(),
-                     fluid.default_startup_program())
+    built = _resnet50(batch, image, classes)
+    loss, main, startup = built["loss"], built["main"], built["startup"]
     rng = np.random.RandomState(seed)
     xs = rng.randn(batch, *image).astype("float32")
     ys = rng.randint(0, classes, (batch, 1)).astype("int64")
@@ -786,7 +796,6 @@ def phase_multichip(sizes, seed, devices=None):
     from jax.sharding import NamedSharding
 
     import __graft_entry__ as graft
-    import bench
     import paddle_tpu as fluid
     from paddle_tpu import amp, executor as em, models
     from paddle_tpu.parallel import (DataParallelStrategy,
@@ -855,13 +864,12 @@ def phase_multichip(sizes, seed, devices=None):
 
     # (a) ResNet-50 data-parallel, global batch 256
     r = sizes["resnet"]
-    fl, loss = bench.build(r["batch"], r["image"], r["classes"],
-                           learning_rate=0.01)  # as in phase_train
+    built = _resnet50(r["batch"], r["image"], r["classes"])
     feed = {"img": rng.randn(r["batch"], *r["image"]).astype("float32"),
             "label": rng.randint(0, r["classes"],
                                  (r["batch"], 1)).astype("int64")}
-    compare(f"resnet50 dp={n}", fl.default_main_program(),
-            fl.default_startup_program(), loss, feed,
+    compare(f"resnet50 dp={n}", built["main"], built["startup"],
+            built["loss"], feed,
             [("one device", DataParallelStrategy(
                 make_mesh({"dp": 1}, devices=devices[:1]), axis="dp")),
              (f"dp={n}", DataParallelStrategy(

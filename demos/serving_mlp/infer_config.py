@@ -1,10 +1,10 @@
 """Serving MLP inference config (fluid script form).
 
-The model the serving stack benchmarks (benchmark/serving_bench.py
-build_model): a relu fc stack ending in a softmax head.  Shipped as a
-lint/optimize target so `paddle lint --optimize` exercises the rewrite
-pipeline + donation-safety analyzer over the exact program shape the
-replica pool serves — see scripts/lint_self.sh.
+A relu fc stack ending in a softmax head.  Shipped as a lint/optimize
+target so `paddle lint --optimize` exercises the rewrite pipeline +
+donation-safety analyzer over the program shape the replica pool
+serves — see scripts/lint_self.sh and
+tests/test_optimizer.py::test_serving_mlp_demo_config_optimizes_with_bit_parity.
 
 Feed: x (batch, 32).  Fetch: prediction (batch, 10).
 """

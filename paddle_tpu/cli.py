@@ -52,7 +52,7 @@ Subcommands:
       (snapshot the telemetry registry — paddle_tpu/observability — as
        a human table or JSON; --run execs a fluid script first so its
        Executor.run counters show, --url scrapes a live `paddle serve`
-       /stats endpoint, --file renders a bench telemetry artifact,
+       /stats endpoint, --file renders a saved snapshot or artifact,
        --trace also exports the host event ring as Chrome-trace JSON)
   paddle pserver [--port=P] [--checkpoint=PATH] [--checkpoint_sec=S]
   paddle master [--port=P] [--lease_sec=S] [--failure_max=N]
@@ -484,8 +484,9 @@ def cmd_stats(argv):
     Dump the observability registry (paddle_tpu/observability): every
     counter/gauge/histogram the executor, serving, and trainer paths
     recorded, as a human table or JSON.  Sources, in precedence order:
-    a live server's /stats endpoint (--url), a bench telemetry artifact
-    (--file), or this process's registry (optionally after exec'ing a
+    a live server's /stats endpoint (--url), a saved snapshot or a
+    telemetry artifact (--file: a document with the registry under
+    "metrics"), or this process's registry (optionally after exec'ing a
     fluid script via --run so its Executor.run calls are measured).
 
     --trace writes the program's spans as Chrome-trace JSON: those of
@@ -515,7 +516,7 @@ def cmd_stats(argv):
     elif args.get("file"):
         with open(args["file"]) as f:
             data = json_mod.load(f)
-        # a bench telemetry artifact nests the registry under "metrics";
+        # a telemetry artifact nests the registry under "metrics";
         # a raw snapshot dump IS the registry
         snap = data.get("metrics", data) or {}
     else:
@@ -539,8 +540,9 @@ def cmd_stats(argv):
               "telemetry registry is empty (no metrics recorded)")
     if args.get("trace"):
         if args.get("file") and not args.get("url"):
-            # a bench artifact embeds its run's Chrome trace — export
-            # that, not this CLI process's (empty) event ring
+            # an artifact may embed its run's Chrome trace ("events"):
+            # export that, not this CLI process's (empty) event ring.
+            # Nothing in the tree writes one since PR 57 (ROADMAP D5)
             trace = data.get("events")
             if not trace:
                 print(f"--trace: {args['file']} carries no embedded "

@@ -4,7 +4,7 @@ The cache directory is part of every entry's key, so it must not move
 between runs: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
 it (jax reads the variable itself — nothing is set in code), else one
 fixed git-ignored directory at the root of the checkout.  Entry points
-(``paddle`` CLI, bench.py, chip_smoke.py, the tests' conftest) call
+(``paddle`` CLI, perf/run.py, chip_smoke.py, the tests' conftest) call
 ``configure()`` once, before first backend use.
 """
 

@@ -59,7 +59,7 @@ class TPUPlace(Place):
     """The default-backend place: work runs on jax's default device —
     the TPU on a TPU host, the CPU under ``JAX_PLATFORMS=cpu`` (the
     tests).  It does NOT assert a TPU; measurement paths (chip_smoke.py,
-    bench.py) check ``jax.devices()[0].platform`` themselves."""
+    perf/run.py) check ``jax.devices()[0].platform`` themselves."""
 
     _backend = None  # None = jax default backend
 

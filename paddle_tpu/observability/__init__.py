@@ -5,8 +5,7 @@ The instrument panel for everything the ROADMAP wants measured:
 - ``metrics``   — typed counters/gauges/histograms with labels; the
   Executor and InferenceServer update the process-global ``REGISTRY``
   on every compile/step/request.  Exposed as Prometheus text on the
-  server's ``GET /metrics``, as JSON/tables via ``paddle stats``, and
-  as the bench telemetry artifact.
+  server's ``GET /metrics`` and as JSON/tables via ``paddle stats``.
 - ``events``    — ``span(name, **args)``, the one way the program
   writes a span: a ``jax.profiler.TraceAnnotation`` (in a jax profile,
   on the device trace's clock) and, while ``recording()`` is on, a
@@ -76,9 +75,9 @@ def measure_step_overhead(iters: int = 2000) -> float:
     and the ``executor.run`` span with its six children.
 
     The counters go to a private registry so measuring does not pollute
-    live metrics.  Recorded into the bench telemetry artifact
-    (``telemetry_overhead`` fields) and asserted ≤ budget in tests —
-    the hot-path ≤2% guarantee, measured instead of promised.
+    live metrics.  Asserted ≤ budget in tests
+    (``test_step_overhead_within_budget``) — the hot-path ≤2% guarantee,
+    measured instead of promised.
     """
     reg = MetricsRegistry()
     hits = reg.counter("overhead_probe_hits_total")

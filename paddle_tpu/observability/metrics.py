@@ -12,8 +12,8 @@ updates.  Design constraints:
   Executor can update per-step metrics unconditionally;
 - exposition is pull-based and allocation-free until asked:
   ``render_prometheus()`` for a /metrics scrape,
-  ``snapshot()`` (plain JSON-able dicts) for ``paddle stats`` and the
-  bench telemetry artifact, ``format_snapshot()`` for humans.
+  ``snapshot()`` (plain JSON-able dicts) for ``paddle stats``,
+  ``format_snapshot()`` for humans.
 
 The Prometheus text format follows the 0.0.4 exposition spec
 (cumulative ``_bucket{le=...}`` counts, ``_sum``/``_count`` rows).

@@ -40,7 +40,7 @@ first-wins and the queue sweep skips ``done`` requests.
 
 ``FaultInjector`` is the test/chaos hook: arm it to make dispatch N
 raise, hang, or hard-die, from ``tests/test_serving_selfheal.py`` and
-``benchmark/serving_chaos_bench.py``.
+``paddle serve --chaos=KIND@N``.
 """
 
 from __future__ import annotations

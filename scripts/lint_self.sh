@@ -52,9 +52,7 @@ echo "== ruff: analysis + observability + distributed fault-tolerance + serving 
 if command -v ruff >/dev/null 2>&1; then
     ruff check paddle_tpu/analysis/ paddle_tpu/observability/ \
         paddle_tpu/distributed/elastic.py paddle_tpu/distributed/retry.py \
-        paddle_tpu/serving/ paddle_tpu/decode/ paddle_tpu/aot/ \
-        benchmark/serving_bench.py benchmark/decode_bench.py \
-        benchmark/serving_chaos_bench.py benchmark/coldstart_bench.py
+        paddle_tpu/serving/ paddle_tpu/decode/ paddle_tpu/aot/
 else
     echo "ruff not installed; skipping style pass"
 fi
@@ -63,64 +61,5 @@ echo "== paddle compile: AOT artifact round trip (export -> boot -> parity)"
 # exports a throwaway MLP, boots one server cold-JIT and one from the
 # artifacts, and asserts a pure aot boot with byte-identical /predict
 $PADDLE compile --smoke
-
-echo "== serving_bench: smoke (batching engine + artifact writer)"
-python benchmark/serving_bench.py --smoke --out /tmp/serving_bench_smoke.json \
-    > /dev/null
-python - <<'EOF'
-import json
-doc = json.load(open("/tmp/serving_bench_smoke.json"))
-assert doc["schema"] == "paddle_tpu.serving_bench.v1", doc["schema"]
-assert doc["configs"], "no bench configs recorded"
-EOF
-
-echo "== serving_chaos_bench: smoke (kill a replica mid-burst, zero lost)"
-python benchmark/serving_chaos_bench.py --smoke \
-    --out /tmp/serving_chaos_smoke.json > /dev/null
-python - <<'EOF'
-import json
-doc = json.load(open("/tmp/serving_chaos_smoke.json"))
-assert doc["schema"] == "paddle_tpu.serving_chaos.v1", doc["schema"]
-assert doc["smoke"]["lost"] == 0, doc["smoke"]
-assert doc["smoke"]["replica_killed"], "fault injector never fired"
-assert doc["smoke"]["restarts"] >= 1, doc["smoke"]
-EOF
-
-echo "== decode_bench: smoke (paged decode engine + artifact writer)"
-python benchmark/decode_bench.py --smoke --out /tmp/decode_bench_smoke.json \
-    > /dev/null
-python - <<'EOF'
-import json
-doc = json.load(open("/tmp/decode_bench_smoke.json"))
-assert doc["schema"] == "paddle_tpu.decode_bench.v1", doc["schema"]
-assert doc["tokens_identical"], "paged decode diverged from the solo oracle"
-assert doc["paged"]["cache"]["miss"] == 0, doc["paged"]["cache"]
-EOF
-
-echo "== decode_bench: smoke (prefix cache: shared-KV pages + skipped prefill)"
-python benchmark/decode_bench.py --mode=prefix --smoke \
-    --out /tmp/decode_bench_prefix_smoke.json > /dev/null
-python - <<'EOF'
-import json
-doc = json.load(open("/tmp/decode_bench_prefix_smoke.json"))
-assert doc["schema"] == "paddle_tpu.decode_bench.v2", doc["schema"]
-assert doc["prefix"]["tokens_identical"], \
-    "prefix-cached decode diverged from the uncached run"
-assert doc["prefix"]["cache_on"]["cache_stats"]["hits"] > 0, \
-    "prefix cache recorded no hits on a prefix-heavy load"
-EOF
-
-echo "== decode_bench: smoke (speculative decoding: greedy token identity)"
-python benchmark/decode_bench.py --mode=spec --smoke \
-    --out /tmp/decode_bench_spec_smoke.json > /dev/null
-python - <<'EOF'
-import json
-doc = json.load(open("/tmp/decode_bench_spec_smoke.json"))
-assert doc["schema"] == "paddle_tpu.decode_bench.v2", doc["schema"]
-assert doc["spec"]["tokens_identical"], \
-    "speculative decode is not token-identical to greedy"
-assert doc["spec"]["speculative"]["proposed"] > 0, \
-    "spec smoke proposed no draft tokens"
-EOF
 
 echo "lint_self OK"

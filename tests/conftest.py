@@ -27,39 +27,6 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
-def pytest_addoption(parser):
-    parser.addoption(
-        "--shard", action="store",
-        default=os.environ.get("PYTEST_SHARD"),
-        help="'i/n' (1-based): run only the i-th of n deterministic "
-             "slices of the suite.  Slicing is per test FILE (stable "
-             "crc32 of the filename), so module-scoped fixtures stay "
-             "together and every test runs in exactly one shard.  Lets "
-             "the tier-1 suite split across driver windows instead of "
-             "squeezing into one 600 s timeout (scripts/run_tier1.sh).")
-
-
-def pytest_collection_modifyitems(config, items):
-    spec = config.getoption("--shard")
-    if not spec:
-        return
-    try:
-        idx, total = (int(p) for p in spec.split("/", 1))
-    except ValueError:
-        raise pytest.UsageError(f"--shard must look like '2/3', got {spec!r}")
-    if not (total >= 1 and 1 <= idx <= total):
-        raise pytest.UsageError(f"--shard {spec!r}: need 1 <= i <= n")
-    import zlib
-
-    keep, drop = [], []
-    for item in items:
-        h = zlib.crc32(os.path.basename(str(item.fspath)).encode())
-        (keep if h % total == idx - 1 else drop).append(item)
-    items[:] = keep
-    if drop:
-        config.hook.pytest_deselected(items=drop)
-
-
 @pytest.fixture(autouse=True)
 def fresh_programs():
     """Each test gets fresh default programs, a fresh global scope, and

@@ -202,24 +202,6 @@ print("TRAINER_OK", costs[0], costs[-1])
     assert "launched 2 pservers" in out.stdout
 
 
-def test_benchmark_runner_smoke():
-    """benchmark/run.py (reference: benchmark/paddle/image configs +
-    run.sh timing loop) produces a JSON line per model."""
-    import json
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_STEPS="1",
-               BENCH_BATCH="2", BENCH_SMOKE="1")
-    out = subprocess.run([sys.executable,
-                          os.path.join(REPO, "benchmark", "run.py"),
-                          "smallnet"],
-                         capture_output=True, text=True, env=env,
-                         timeout=400, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    rec = json.loads(line)
-    assert rec["model"] == "smallnet" and rec["img_per_sec"] > 0
-
-
 def test_inference_server_serves_model(tmp_path):
     """paddle serve: HTTP inference over a save_inference_model export
     (serving.py) — health, predict parity with in-process run, and

@@ -269,6 +269,7 @@ def test_prefix_cache_evict_for_pages_only_drops_sole_refs():
 
 
 def test_spec_decode_token_identity_standalone():
+    from paddle_tpu.decode import spec as spec_mod
     from paddle_tpu.decode.spec import (ModelDraft, NgramDraft,
                                         SpeculativeDecoder)
 
@@ -278,13 +279,20 @@ def test_spec_decode_token_identity_standalone():
     got = SpeculativeDecoder(m, NgramDraft(), k=4).generate(PROMPT, 12)
     assert got == oracle
     assert m.allocator.pages_in_use == 0
+    # identity through chunks that really carried drafts, not through
+    # a decoder that never proposed
+    proposed = spec_mod._M_PROPOSED.value()
+    assert proposed > 0
     # perfect draft (same weights): high acceptance, same tokens
     got = SpeculativeDecoder(m, ModelDraft(_mk()), k=4).generate(PROMPT, 12)
     assert got == oracle
     assert m.allocator.pages_in_use == 0
+    assert spec_mod._M_PROPOSED.value() > proposed
+    assert spec_mod._M_ACCEPTED.value() > 0
 
 
 def test_spec_decode_token_identity_in_session():
+    from paddle_tpu.decode import spec as spec_mod
     from paddle_tpu.decode.session import DecodeRequest, DecodeSession
     from paddle_tpu.decode.spec import NgramDraft
 
@@ -299,6 +307,7 @@ def test_spec_decode_token_identity_in_session():
     for r, want in zip(reqs, oracles):
         assert r.result(5) == want
     assert m.allocator.pages_in_use == 0
+    assert spec_mod._M_PROPOSED.value() > 0
 
 
 def test_spec_session_refuses_sampling_and_beam():

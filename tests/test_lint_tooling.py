@@ -92,3 +92,34 @@ def test_lint_self_script_green():
         capture_output=True, text=True, env=ENV, timeout=560, cwd=REPO)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-2000:])
     assert "lint_self OK" in out.stdout
+
+
+def test_own_environment_variables_ratchet():
+    """ROADMAP D5's count: the environment variables of the repo's own
+    that the program, the chip smoke and the scripts read (Python's
+    ``environ`` / ``getenv`` forms, a shell script's ``${NAME:-default}``),
+    less jax's and the system's.  A read may break after its bracket
+    (``environ.get(\n"NAME")``): the pattern runs over the file, not a
+    line, which is how ``PADDLE_TPU_COORD`` escaped the roadmap's grep."""
+    import re
+
+    python = re.compile(r"(?:environ(?:\.get|\.setdefault|\.pop)?|getenv)"
+                        r"[\[(]\s*['\"]([A-Z_0-9]+)['\"]")
+    shell = re.compile(r"\$\{([A-Z_][A-Z_0-9]*):?[-=]")
+    files = [os.path.join(REPO, "chip_smoke.py")]
+    for top in ("paddle_tpu", "scripts"):
+        for root, _, names in os.walk(os.path.join(REPO, top)):
+            files += [os.path.join(root, n) for n in names
+                      if top == "scripts" or n.endswith(".py")]
+    own = set()
+    for path in files:
+        with open(path, errors="replace") as f:
+            text = f.read()
+        own.update(python.findall(text), shell.findall(text))
+    own = {v for v in own if not v.startswith("JAX_")
+           and v not in ("XLA_FLAGS", "PYTHONPATH", "XDG_CACHE_HOME")}
+    LIMIT = 11  # ratchet: only lower this, never raise it
+    assert len(own) <= LIMIT, (
+        f"{len(own)} environment variables of the repo's own > {LIMIT}: "
+        f"{sorted(own)}; a choice is made from what the code can observe, "
+        "a value with one caller is a constant (ROADMAP D5)")

@@ -556,15 +556,75 @@ def test_inc_many_is_inc_under_one_lock():
         c.inc_many([(label_key(), -1)])
 
 
-def test_tick_account_overhead_is_within_budget():
-    """The account is on in every run: what it adds to a tick (a
-    ``perf_counter`` pair a phase, the flush) has a budget of 25 us,
-    0.17% of the Cerebras cell's 15 ms tick.  Measured 9-12 us alone on
-    this sandbox's CPU; the assertion leaves a loaded CI machine room,
-    and the best of five is what the code costs."""
+def test_tick_account_overhead_is_within_budget(monkeypatch):
+    """The account is on in every run, so what it adds to a tick has a
+    budget, held by what it DOES: one ``perf_counter`` pair a phase
+    statement and, at the flush, one write (one acquire of the lock) a
+    family.  The counts are those of ONE tick of
+    ``measure_tick_account_overhead``'s synthetic tick (every phase, one
+    admission in a bucket, so every family is written) with the account
+    open, beside the same tick as plain spans with nobody recording.
+    The host's clock is printed and held only to an order of magnitude
+    (9-12 us alone on this sandbox's CPU, 25.3 beside five other
+    workers; 0.17% of the Cerebras cell's 15 ms tick): a reading that
+    moves with the machine's load says nothing about the code."""
+    from paddle_tpu.observability import events, metrics
+
+    log = []
+    clock = time.perf_counter
+
+    def counted_clock():
+        log.append("clock")
+        return clock()
+
+    with monkeypatch.context() as patch:
+        def logged(cls, method, say):
+            plain = getattr(cls, method)
+
+            def wrapper(self, *a, **kw):
+                log.append(say(self))
+                return plain(self, *a, **kw)
+            patch.setattr(cls, method, wrapper)
+
+        logged(metrics.Counter, "inc", lambda c: "write " + c.name)
+        logged(metrics.Counter, "inc_many", lambda c: "write " + c.name)
+        logged(events.PhaseAccount, "open", lambda a: "open")
+        patch.setattr(events.PhaseAccount, "close",
+                      staticmethod(lambda: log.append("close")))
+        patch.setattr(time, "perf_counter", counted_clock)
+        gc.disable()        # a collection's callback reads the clock too
+        try:
+            # an accounted tick (the warm-up), the plain one, an accounted one
+            obs.measure_tick_account_overhead(iters=1)
+        finally:
+            gc.enable()
+    assert log.count("open") == log.count("close") == 2
+    opened, closed = log.index("open"), log.index("close")
+    accounted = log[opened + 1:closed]
+    # between the ticks: the loops' own four readings of the clock
+    plain = log[closed + 1:log.index("open", closed)]
+    assert plain.count("clock") == 4, plain
+    plain = [line for line in plain if line != "clock"]
+    assert plain == ["write overhead_probe_step_inputs_total",
+                     "write overhead_probe_deliveries_total"]
+    statements = len(PHASE_SPANS) + 1           # every phase, and the tick
+    assert accounted.count("clock") == 2 * statements
+    writes = [line for line in accounted if line != "clock"]
+    # a family, a write: every family of the account once, none twice
+    assert sorted(writes) == sorted("write " + name for name in (
+        "decode_tick_seconds_total", "decode_ticks_total",
+        "decode_tick_admissions_total", "decode_admissions_total",
+        "decode_admit_tick_rows_total",
+        "decode_admit_stalled_slot_seconds_total",
+        "decode_slot_seconds_total", "decode_step_inputs_total",
+        "decode_deliveries_total"))
+
     got = min(obs.measure_tick_account_overhead(iters=500)
-              for _ in range(5))
-    assert got < 25e-6, f"tick account overhead {got * 1e6:.1f} us a tick"
+              for _ in range(3))
+    print(f"tick account overhead {got * 1e6:.1f} us a tick "
+          f"({2 * statements} clock readings, {len(writes) - len(plain)} "
+          "writes more than the plain tick)")
+    assert got < 250e-6, f"tick account overhead {got * 1e6:.1f} us a tick"
 
 
 def test_submit_lag_is_observed_once_a_generate_request():

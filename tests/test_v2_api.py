@@ -161,9 +161,9 @@ def test_new_datasets_schemas():
 def test_resnet_block_v2_trainer():
     """The BASELINE.json north-star API path: a residual conv network
     training end-to-end from ``paddle.v2.trainer.SGD`` (tiny shapes;
-    the full-size throughput row is bench.py).  Covers
-    img_conv/batch_norm/img_pool + the residual add through the v2
-    facade with a synthetic separable image task."""
+    the full-size throughput row is ``perf/``'s ``resnet50-train-bs256``
+    cell).  Covers img_conv/batch_norm/img_pool + the residual add
+    through the v2 facade with a synthetic separable image task."""
     import paddle_tpu.v2 as paddle
 
     paddle.init(use_gpu=False, trainer_count=1)
