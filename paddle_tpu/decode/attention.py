@@ -12,6 +12,16 @@ as the causal-block skip in ``pallas/flash_attention.py``).  Softmax is
 the same online (running max / normalizer) accumulation as the flash
 forward, in f32 VMEM scratch.
 
+The chunk kernel (a verify chunk's or a prefix suffix's T rows a slot,
+and every call on grouped heads, a decode step's among them) takes ONE
+grid step a slot instead and walks the slot's live pages alone, copying
+them itself out of the pools left in HBM (``_rpa_walk_kernel``, PR 58):
+a table column past a slot's length costs a grid step of the
+``(slots, pages_per_seq)`` grid even where it costs no read, and at 26
+live columns of 96 those steps were a third of the call.  Only a window
+layer's ring, five columns nearly always all seen, keeps a grid step a
+column (``_rpa_chunk_kernel``).
+
 Prefill stays dense: a prompt is contiguous, so the existing flash
 attention forward (``pallas/flash_attention.py``) — or its jnp fallback
 at small shapes — handles it, and the resulting K/V rows are written
@@ -80,7 +90,19 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     ``pallas/latent_attention.py`` (PR 45), and its layout probe found
     the same as the two above: a pool of 576 lanes is laid out at 640
     and the kernel's page copy refused, so the rows are stored at 640
-    (``models/kanana_mla.py:row_width``)."""
+    (``models/kanana_mla.py:row_width``).
+
+    The walk over a page run (PR 58: the chunk and grouped calls) keeps
+    in VMEM, beside the chunk's q/o blocks and float32 accumulator, two
+    double buffers of ``WALK_PAGES`` pages, K's and V's: 16 pages at 4 a
+    turn.  Ten stored heads of 128 over 128-row bfloat16 pages
+    (Phi-4-mini-flash: 327,680 B a page) are 5.24 MB; four stored heads
+    (Granite: 131,072 B) 2.10 MB; eight (K-EXAONE: 262,144 B) 4.19 MB,
+    of the 16 MiB a kernel may hold; the float32 copies of the one page
+    being computed on come to 1.3 MB more at the Phi shape.  Compiled,
+    it also asks pages that are whole tiles where they lie in HBM, which
+    the three layouts above are and the ones they replaced are not:
+    ``walk_fits``."""
     ok = (page_size % 8 == 0 and head_dim % 8 == 0
           and head_dim <= 256 and num_heads >= 1)
     if kv_heads in (None, num_heads):
@@ -95,6 +117,32 @@ def storage_heads(num_heads: int, dtype) -> int:
     padding and the compiler keeps it (``fits``)."""
     rows = 32 // jnp.dtype(dtype).itemsize
     return -(-int(num_heads) // rows) * rows
+
+
+WALK_PAGES = 4              # pages a turn of the walk copies and computes
+WALK_BUFFER_BYTES = 10 << 20    # of the 16 MiB a kernel may hold in VMEM
+
+
+def walk_fits(dtype, page_size: int, kv_heads: int, head_dim: int,
+              heads_major: bool = False) -> bool:
+    """What the COMPILED walk over a page run (``_rpa_walk_kernel``)
+    asks beyond ``fits``: it copies a page out of a pool left in HBM,
+    and Mosaic takes that slice only of pages that are whole tiles where
+    they lie (PR 58's probe on a described v5e; the ``(S, P)`` grid took
+    every shape below through a BlockSpec, with the compiler's copies of
+    the pool round it that ``fits`` tells of).  Rows of whole 128-lane
+    tiles (64, 192: refused); a page's second-minor extent (its heads,
+    or its rows ``heads_major``) a multiple of 8 or a power of two that
+    fills a 4-byte sublane (10 or 30 bfloat16 heads inside a page's
+    rows: refused; heads-major: taken); and the two double buffers,
+    ``4 * WALK_PAGES`` pages, within ``WALK_BUFFER_BYTES``.  Interpreted,
+    every shape ``fits`` takes runs."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = page_size if heads_major else kv_heads
+    whole = rows % 8 == 0 or (rows & (rows - 1) == 0 and rows * itemsize >= 4)
+    page_bytes = page_size * kv_heads * head_dim * itemsize
+    return (head_dim % 128 == 0 and whole
+            and 4 * WALK_PAGES * page_bytes <= WALK_BUFFER_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -318,97 +366,212 @@ def _ring_seen(shape, lens, r, page: int, R: int, T: int, G: int,
     return (base >= 0) & (back >= 0) & (back < window)
 
 
-def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, scale, page, npp, T, G=1,
-                      heads_major=False, window=None):
-    """Chunked variant of ``_rpa_kernel``: the q block holds the slot's
-    whole T-token chunk; masking offsets the length limit per row.
-    With ``G`` > 1 (grouped heads) the q block's ``T * G`` rows are the
-    chunk's rows times the G query heads that read each of the block's
-    K/V heads, row ``t * G + g`` at the chunk's row ``t``: the group
-    rides the one K/V page, read once.  ``heads_major``: a page is
-    stored (H, page, D), as the two batched dots take it.
+def _softmax_page(q, k, v, seen, m_scr, l_scr, acc_scr, scale, heads_major):
+    """One page into a slot's online softmax, the arithmetic both chunk
+    bodies share, all float32: q (H, T * G, D), the page's k and v
+    widened as they are stored ((page, H, D), or (H, page, D)
+    ``heads_major``), ``seen(shape)`` the mask over the (H, T * G, page)
+    scores; the running max, normaliser and accumulator in VMEM
+    scratch."""
+    if not heads_major:
+        k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
+    # scores (H, T, page): batch over H, contract D
+    sc = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=_F32) * scale
+    sc = jnp.where(seen(sc.shape), sc, _NEG_INF)
+    m_prev = m_scr[...]                             # (H, T, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+    pr = jnp.exp(sc - m_new)                        # (H, T, page)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(pr, axis=2, keepdims=True)
+    m_scr[...] = m_new
+    # (H, T, page) x (H, page, D) batched over H -> (H, T, D)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        pr, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=_F32)
 
-    ``window``: the table's ``npp`` columns are a window layer's ring
-    (``ring_window_attention``'s contract and its arithmetic: which
-    page a column holds and where its keys stand are reckoned from the
-    rows' positions, on scalars and one iota a page) and a row sees its
-    own key and the ``window - 1`` before it.  The columns are walked
-    as they lie: a softmax does not mind the order."""
+
+def _softmax_start(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _softmax_finish(o_ref, l_scr, acc_scr):
+    """A slot no page of which was live (an empty seat) writes zeros."""
+    l = l_scr[...]
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = jnp.swapaxes(acc_scr[...] / l, 0, 1).astype(o_ref.dtype)
+
+
+def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                     kbuf, vbuf, sems, start, q_scr, m_scr, l_scr, acc_scr,
+                     *, scale, page, npp, T, G, heads_major, fetch, slots):
+    """The chunk kernel over a page run: ONE grid step a slot, which
+    walks the slot's live pages alone (``pallas/latent_attention.py``'s
+    pattern).  The q block holds the slot's whole chunk, ``T * G`` rows:
+    the chunk's T rows times the G query heads that read each of the
+    page's K/V heads (grouped heads), row ``t * G + g`` at the chunk's
+    row ``t``, so the group rides the one K/V page, read once.  The
+    chunk's last row stands at ``lens + T - 1``: the first
+    ``ceil((lens + T) / page)`` table columns are live, a dynamic trip
+    count, and a column past them costs nothing, no grid step and no
+    copy (a 96-column table of which 26 are live costs 26 page reads).
+
+    The pools stay in HBM.  ``kbuf`` / ``vbuf`` (2, fetch, a page): a
+    turn's ``fetch`` pages are copied a page a DMA into one half while
+    the other half is computed on (``sems`` (K | V, half, page): one
+    semaphore a copy in flight; only a live page is copied, waited for
+    and computed); a slot's last turn starts the NEXT slot's first
+    copies, so only slot 0's are waited for with nothing to do; ``start``
+    (1,) in SMEM keeps the half a slot's first turn lies in from grid
+    step to grid step, which is why the grid is ``arbitrary``.
+    ``q_scr`` (H, T * G, D): the chunk in float32 with its heads
+    outermost, as the two batched dots take it, turned once a slot."""
+    s = pl.program_id(0)
+
+    def live_pages(slot):
+        return jnp.clip(pl.cdiv(lens_ref[slot] + T, page), 0, npp)
+
+    def page_copies(slot, col, half, j):
+        pid = ptab_ref[slot, col]
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[half, j],
+                                      sems.at[0, half, j]),
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[half, j],
+                                      sems.at[1, half, j]))
+
+    def for_live(live, turn, do):
+        """``do(j, col)`` for each of a turn's table columns under
+        ``live``."""
+        for j in range(fetch):
+            col = turn * fetch + j
+            pl.when(col < live)(functools.partial(do, j, col))
+
+    def start_copies(slot, live, turn, half):
+        def begin(j, col):
+            for c in page_copies(slot, col, half, j):
+                c.start()
+        for_live(live, turn, begin)
+
+    @pl.when(s == 0)
+    def _first():
+        start[0] = 0
+        start_copies(0, live_pages(0), 0, 0)
+
+    seq_len, first, live = lens_ref[s], start[0], live_pages(s)
+    turns = pl.cdiv(live, fetch)
+    _softmax_start(m_scr, l_scr, acc_scr)
+    q_scr[...] = jnp.swapaxes(q_ref[0].astype(_F32), 0, 1)
+
+    @pl.when((turns == 0) & (s + 1 < slots))
+    def _empty_seat():
+        start_copies(s + 1, live_pages(s + 1), 0, first)
+
+    def turn(t, carry):
+        half = (first + t) % 2
+
+        @pl.when(t + 1 < turns)
+        def _next_turn():
+            start_copies(s, live, t + 1, 1 - half)
+
+        @pl.when((t + 1 == turns) & (s + 1 < slots))
+        def _next_slot():
+            start_copies(s + 1, live_pages(s + 1), 0, 1 - half)
+
+        def compute(j, col):
+            for c in page_copies(s, col, half, j):
+                c.wait()
+
+            def seen(shape):
+                t_pos = col * page + jax.lax.broadcasted_iota(
+                    jnp.int32, shape, 2)
+                row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                if G > 1:
+                    row = row // G
+                return t_pos < seq_len + row + 1
+
+            _softmax_page(q_scr[...], kbuf[half, j].astype(_F32),
+                          vbuf[half, j].astype(_F32), seen,
+                          m_scr, l_scr, acc_scr, scale, heads_major)
+
+        for_live(live, t, compute)
+        return carry
+
+    jax.lax.fori_loop(0, turns, turn, 0)
+    # the half the next slot's first turn was copied into
+    start[0] = (first + turns) % 2
+    _softmax_finish(o_ref, l_scr, acc_scr)
+
+
+def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
+                      m_scr, l_scr, acc_scr, *, scale, page, npp, T, G,
+                      heads_major, window):
+    """The chunk kernel over a window layer's RING (the page run's is
+    ``_rpa_walk_kernel``): one (slot, ring column) grid step, the column's
+    page the K/V block, the q block and its ``T * G`` rows as the walk's.
+    A ring is five columns or two, nearly always all seen: a grid step a
+    column wastes none.
+
+    The table's ``npp`` columns are the ring (``ring_window_attention``'s
+    contract and its arithmetic: which page a column holds and where its
+    keys stand are reckoned from the rows' positions, on scalars and one
+    iota a page) and a row sees its own key and the ``window - 1``
+    before it.  The columns are walked as they lie: a softmax does not
+    mind the order."""
     s = pl.program_id(0)
     p = pl.program_id(1)
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    pl.when(p == 0)(functools.partial(_softmax_start, m_scr, l_scr, acc_scr))
     seq_len = lens_ref[s]
 
-    if window is None:
-        # last chunk row reaches position seq_len + T - 1: pages wholly
-        # past that contribute to no query row and skip their math + DMA
-        live = p * page < seq_len + T
-    else:
-        # so does a ring column no row of the chunk sees a key of
-        live = ring_column_seen(seq_len, T, p, page, npp, window)
-
-    @pl.when(live)
+    # a ring column no row of the chunk sees a key of skips its math
+    @pl.when(ring_column_seen(seq_len, T, p, page, npp, window))
     def _page():
         q = q_ref[0].astype(_F32)                       # (T * G, H, D)
-        k = k_ref[0].astype(_F32)                       # (page, H, D)
+        k = k_ref[0].astype(_F32)
         v = v_ref[0].astype(_F32)
-        if not heads_major:
-            k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
-        # scores (H, T, page): batch over H, contract D
-        sc = jax.lax.dot_general(
-            jnp.swapaxes(q, 0, 1), k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=_F32) * scale
-        if window is None:
-            t_pos = p * page + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 2)
-            row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            if G > 1:
-                row = row // G
-            sc = jnp.where(t_pos < seq_len + row + 1, sc, _NEG_INF)
-        else:
-            sc = jnp.where(_ring_seen(sc.shape, seq_len, p, page, npp, T,
-                                      G, window), sc, _NEG_INF)
-        m_prev = m_scr[...]                             # (H, T, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
-        pr = jnp.exp(sc - m_new)                        # (H, T, page)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(pr, axis=2, keepdims=True)
-        m_scr[...] = m_new
-        # (H, T, page) x (H, page, D) batched over H -> (H, T, D)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            pr, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=_F32)
+        _softmax_page(
+            jnp.swapaxes(q, 0, 1), k, v,
+            lambda shape: _ring_seen(shape, seq_len, p, page, npp, T, G,
+                                     window),
+            m_scr, l_scr, acc_scr, scale, heads_major)
 
-    @pl.when(p == npp - 1)
-    def _finish():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.swapaxes(acc_scr[...] / l, 0, 1).astype(o_ref.dtype)
+    pl.when(p == npp - 1)(
+        functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
 
 
 def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
                 G, heads_major=False, window=None):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
-    one grid step per (slot, page), the whole chunk resident in the q/o
-    blocks.  A page is (page, H, D), or (H, page, D) ``heads_major``.
-    ``window``: the table is a ring's columns, ``lens`` the position of
-    the chunk's first row."""
+    the whole chunk resident in the q/o blocks.  A page is (page, H, D),
+    or (H, page, D) ``heads_major``.
+
+    Over a page run (``window`` None: the decode step's row through
+    ``paged_attention``, the verify chunk and the prefix suffix through
+    ``paged_chunk_attention``, grouped heads or not): one grid step a
+    slot, the pools left in HBM and the slot's live pages copied by the
+    kernel, ``WALK_PAGES`` a turn (``_rpa_walk_kernel``).  ``window``:
+    the table is a ring's columns, ``lens`` the position of the chunk's
+    first row, one grid step a (slot, column) with the column's page the
+    K/V block (``_rpa_chunk_kernel``).  Which of the two is the static
+    ``window`` alone."""
     S, TG, H, D = q.shape
     page = k_pages.shape[2 if heads_major else 1]
     P = page_tables.shape[1]
     if scale is None:
         scale = D ** -0.5
-    if window is None:
-        def page_of(s, p, pt, ln):
-            return (pt[s, p], 0, 0, 0)
-    else:
+    statics = dict(scale=scale, page=page, npp=P, T=TG // G, G=G,
+                   heads_major=heads_major)
+    softmax = [
+        pltpu.VMEM((H, TG, 1), _F32),     # running max
+        pltpu.VMEM((H, TG, 1), _F32),     # running normalizer
+        pltpu.VMEM((H, TG, D), _F32),     # output accumulator
+    ]
+    out_shape = jax.ShapeDtypeStruct((S, TG, H, D), q.dtype)
+    args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
+            q, k_pages, v_pages)
+    if window is not None:
         def page_of(s, p, pt, ln):
             # a column no row sees names the slot's newest page, which
             # one does: no id of a column the ring has not reached is
@@ -417,38 +580,51 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
             seen = ring_column_seen(ln[s], TG // G, p, page, P, window)
             return (pt[s, jnp.where(seen, p, (ln[s] // page) % P)],
                     0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, P),
-        in_specs=[
-            pl.BlockSpec((1, TG, H, D), lambda s, p, pt, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1,) + k_pages.shape[1:], page_of),
-            pl.BlockSpec((1,) + v_pages.shape[1:], page_of),
-        ],
-        out_specs=pl.BlockSpec((1, TG, H, D),
-                               lambda s, p, pt, ln: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, TG, 1), _F32),     # running max
-            pltpu.VMEM((H, TG, 1), _F32),     # running normalizer
-            pltpu.VMEM((H, TG, D), _F32),     # output accumulator
-        ],
-    )
-    kernel = functools.partial(_rpa_chunk_kernel, scale=scale, page=page,
-                               npp=P, T=TG // G, G=G,
-                               heads_major=heads_major, window=window)
-    call = dict(
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, TG, H, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret)
-    args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
-            q, k_pages, v_pages)
-    # one kernel under three names: a trace tells the grouped call and
-    # the ring's apart
-    if window is not None:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S, P),
+            in_specs=[
+                pl.BlockSpec((1, TG, H, D),
+                             lambda s, p, pt, ln: (s, 0, 0, 0)),
+                pl.BlockSpec((1,) + k_pages.shape[1:], page_of),
+                pl.BlockSpec((1,) + v_pages.shape[1:], page_of),
+            ],
+            out_specs=pl.BlockSpec((1, TG, H, D),
+                                   lambda s, p, pt, ln: (s, 0, 0, 0)),
+            scratch_shapes=softmax,
+        )
         return pl.pallas_call(
-            kernel, name="ring_paged_attention", **call)(*args)
+            functools.partial(_rpa_chunk_kernel, window=window, **statics),
+            grid_spec=grid_spec, out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            name="ring_paged_attention", interpret=interpret)(*args)
+    fetch = WALK_PAGES
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,            # page table + lens land in SMEM
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, TG, H, D), lambda s, pt, ln: (s, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),          # the pools: in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, TG, H, D), lambda s, pt, ln: (s, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, fetch) + k_pages.shape[1:], k_pages.dtype),
+            pltpu.VMEM((2, fetch) + v_pages.shape[1:], v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, fetch)),
+            pltpu.SMEM((1,), jnp.int32),      # the half a slot starts in
+            pltpu.VMEM((H, TG, D), _F32),     # the chunk, heads outermost
+        ] + softmax,
+    )
+    kernel = functools.partial(_rpa_walk_kernel, fetch=fetch, slots=S,
+                               **statics)
+    call = dict(
+        grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret)
+    # one body under two names: a trace tells the grouped call apart
     if G > 1:
         return pl.pallas_call(
             kernel, name="ragged_paged_attention_gqa", **call)(*args)
@@ -562,8 +738,8 @@ def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale,
     page, Hkv = k_pages.shape[1:3]
     if heads_major:
         page, Hkv = Hkv, page
-    if pk.dispatch("ragged_paged_attention_gqa",
-                   pk.policy(fits(page, Hq, D, Hkv), True)):
+    if _use_walk("ragged_paged_attention_gqa", k_pages.dtype, page, Hq, D,
+                 Hkv, heads_major):
         return ragged_paged_attention_gqa(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
             interpret=pk.interpret_mode(), heads_major=heads_major)
@@ -693,6 +869,18 @@ def _use_kernel(kernel: str, page_size: int, H: int, D: int) -> bool:
     return pk.dispatch(kernel, pk.policy(fits(page_size, H, D), True))
 
 
+def _use_walk(kernel: str, dtype, page_size: int, Hq: int, D: int, Hkv: int,
+              heads_major: bool = False) -> bool:
+    """``_use_kernel`` for the calls over a page run, which walk: where
+    the kernel would be compiled the pages also have to be whole tiles
+    where they lie (``walk_fits``)."""
+    from paddle_tpu import pallas as pk
+
+    return pk.dispatch(kernel, pk.policy(
+        fits(page_size, Hq, D, Hkv) and (pk.interpret_mode() or walk_fits(
+            dtype, page_size, Hkv, D, heads_major)), True))
+
+
 def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
                           scale=None):
     """Dispatcher for the chunked step (mirrors ``paged_attention``)."""
@@ -701,7 +889,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
     if _grouped(q, k_pages):
         return _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale)
     S, T, H, D = q.shape
-    if _use_kernel("ragged_paged_attention_chunk", k_pages.shape[1], H, D):
+    if _use_walk("ragged_paged_attention_chunk", k_pages.dtype,
+                 k_pages.shape[1], H, D, H):
         return ragged_paged_attention_chunk(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
             interpret=pk.interpret_mode())

@@ -8,11 +8,13 @@ Thirteen kernel families, twenty-three ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
-ragged paged attention (``decode/attention.py``, 2 kernels under 4
-names: the chunk kernel is also called on grouped heads, Hq query heads
-on Hkv K/V heads, as ``ragged_paged_attention_gqa``, and, under a
-window mask reckoned from positions, over a window layer's ring of
-pages where they lie in the pool, as ``ring_paged_attention``: taken
+ragged paged attention (``decode/attention.py``, 3 kernel bodies under
+4 names: the chunk kernel, a walk of a slot's live pages since PR 58,
+is also called on grouped heads, Hq query heads on Hkv K/V heads, as
+``ragged_paged_attention_gqa``; its page's arithmetic under a window
+mask reckoned from positions, a grid step a (slot, ring column), reads
+a window layer's ring of pages where they lie in the pool, as
+``ring_paged_attention``: taken
 for pages stored heads-major, ``decode/attention.py:
 paged_ring_attention``; of row-major pages XLA fuses the gather of a
 ring into the scores' product and the kernel read slower), the gated
@@ -32,8 +34,8 @@ float32), and absorbed latent attention
 over paged latent rows, every head on the one stored row, which is key
 and value both (``latent_attention.py``, 1: a grid step a slot, the
 slot's live pages walked by a dynamic loop and copied by hand through a
-double buffer, where the ragged kernels take a grid step a table
-column).  The latent pool's rows are stored at 640 lanes for the 576
+double buffer, where ``ragged_paged_attention`` and the ring's take a
+grid step a table column).  The latent pool's rows are stored at 640 lanes for the 576
 the algorithm needs: at 576 the chip's compiler lays the pool out at
 640 anyway and refuses the kernel's page copy ("slice shape must be
 aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).  The last is the grouped GEMM

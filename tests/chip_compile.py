@@ -80,6 +80,34 @@ def _kernel_op_names(text):
             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
 
 
+def _kernel_grids(text):
+    """[(op_name, grid)] of every Pallas custom call of a compiled
+    program: the ``iteration_bounds`` of the Mosaic module each call
+    carries (its serialized body, parsed as generic MLIR)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    grids = []
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        for line in text.splitlines():
+            body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line)
+            if MARKER not in line or not body:
+                continue
+            asm = ir.Module.parse(base64.b64decode(
+                body.group(1))).operation.get_asm(enable_debug_info=False)
+            bounds = re.search(r"iteration_bounds = array<i64(?:: ([\d, ]+))?>",
+                               asm)
+            grids.append((
+                re.search(r'op_name="([^"]*)"', line).group(1),
+                tuple(int(n) for n in (bounds.group(1) or "").split(",")
+                      if n.strip())))
+    return grids
+
+
 def _assert_grouped_gemm_kernel(text, layers, looped):
     """A grouped prefill bucket: two grouped-GEMM custom calls a routed
     layer (gate and up in one, then down), each under ``moe_experts``

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _hybrid_sizes, _kernel_op_names, one_chip, _planned_bytes,
+    _hybrid_sizes, _kernel_grids, _kernel_op_names, one_chip, _planned_bytes,
     _pool_sized_strays, _under)
 
 
@@ -132,6 +132,9 @@ def test_granite_decode_step_moves_states_tails_and_pages_in_place(
     gqa = [op for op in kernels if "ragged_paged_attention_gqa/" in op]
     assert len(gqa) == 4 and all("_decode_step)/blk_mixer/attn_full/" in op
                                  for op in gqa)
+    # a slot a grid step: the walk of its live pages, not 15 columns
+    grids = dict(_kernel_grids(text))
+    assert {grids[op] for op in gqa} == {(S,)}
     step = [op for op in kernels if "ssd_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
     assert len(step) == len(conv) == 36 and len(kernels) == 76
@@ -152,28 +155,48 @@ def test_granite_decode_step_moves_states_tails_and_pages_in_place(
                 if s[1] in ("copy", "transpose")]
 
 
-def test_granite_pages_of_unpacked_heads_are_padded_and_copied(
+def test_granite_pages_of_unpacked_heads_are_refused_by_the_walk(
         one_chip, monkeypatch):
     """The layout that was NOT kept: pages of 8 K/V heads of 64, one
-    head a stored row.  At the configuration's 961 pages (1.0 GB of
-    K/V) the step plans gigabytes of temporaries:
-    the compiler pads a row's 64 lanes to 128 and copies the pools to
-    that layout around the kernel's calls, where two heads a 128-lane
-    row plan the arguments + 88 MB (the case above).  When this fails the compiler's choice has changed
-    and ``heads_a_row`` can be looked at again."""
+    head a stored row.  Under the ``(S, P)`` grid (until PR 58) the step
+    planned gigabytes of temporaries at the configuration's 961 pages
+    (1.0 GB of K/V): the compiler padded a row's 64 lanes to 128 and
+    copied the pools to that layout around the kernel's calls, where two
+    heads a 128-lane row plan the arguments + 88 MB (the case above).
+    The walk copies a page out of the pool where it lies, and Mosaic
+    refuses the slice of a 64-lane row outright: ``walk_fits`` says so,
+    and the step of such a pool takes the gathered reference (no grouped
+    kernel call in it).  When the bare compile below passes the
+    compiler's rule has changed and ``heads_a_row`` can be looked at
+    again."""
+    import pytest
+
+    from paddle_tpu.decode import attention as A
     from paddle_tpu.decode import model as dm
+    from tests.chip_compile import _compiled_text
 
     cfg, params, pool, extra, block, width, sds = _granite_cell(
         one_chip, monkeypatch, pack=1)
-    assert pool.shape[3:] == (8, 64)
-    g, S = cfg["generate"], cfg["generate"]["slots"]
-    compiled = dm._decode_step.lower(
+    page, (KV, dh) = cfg["generate"]["page_size"], pool.shape[3:]
+    assert (KV, dh) == (8, 64)
+    g, S, Hq = cfg["generate"], cfg["generate"]["slots"], \
+        cfg["num_attention_heads"]
+    assert A.fits(page, Hq, dh, KV)
+    assert not A.walk_fits(pool.dtype, page, KV, dh)
+    assert A.walk_fits(pool.dtype, page, KV // 2, 2 * dh)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compiled_text(
+            A.ragged_paged_attention_gqa, one_chip,
+            ((S, 1, Hq, dh), pool.dtype), (pool.shape[1:], pool.dtype),
+            (pool.shape[1:], pool.dtype), ((S, width - 1), jnp.int32),
+            ((S,), jnp.int32))
+    text = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
-        heads=cfg["num_attention_heads"], page_size=g["page_size"],
-        block=block, extra=extra).compile()
-    kv = 2 * math.prod(pool.shape) * pool.dtype.itemsize
-    assert compiled.memory_analysis().temp_size_in_bytes > 2 * kv
+        heads=Hq, page_size=g["page_size"], block=block,
+        extra=extra).as_text()
+    assert "ragged_paged_attention_gqa" not in text
+    assert "ssd_step" in text
 
 
 def test_granite_top_prefill_fits_beside_weights_states_and_pages(

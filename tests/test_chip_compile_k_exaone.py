@@ -14,8 +14,8 @@ import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
     _assert_experts_read_where_they_lie, _assert_grouped_gemm_kernel,
-    _assert_pools_in_place, _assert_step_outputs, _kernel_op_names,
-    one_chip, _planned_bytes, _ring_dispatches, _under)
+    _assert_pools_in_place, _assert_step_outputs, _kernel_grids,
+    _kernel_op_names, one_chip, _planned_bytes, _ring_dispatches, _under)
 
 
 def _exaone_cell(one_chip, monkeypatch):
@@ -105,6 +105,8 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
     gqa = _kernel_op_names(text)
     assert len(gqa) == 1 and "_decode_step)/blk_mixer/attn_full/" in gqa[0]
     assert "ragged_paged_attention_gqa" in gqa[0]
+    # a slot a grid step: the walk of its live pages, not 36 columns
+    assert _kernel_grids(text) == [(gqa[0], (S,))]
     _assert_experts_read_where_they_lie(
         text, cfg["num_experts"], cfg["hidden_size"],
         cfg["moe_intermediate_size"])

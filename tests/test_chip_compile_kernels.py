@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _compiled_text, _kernel_op_names, MARKER, one_chip)
+    _compiled_text, _kernel_grids, _kernel_op_names, MARKER, one_chip)
 
 
 # (slots, heads, head_dim, page, pool pages, pages/seq, dtype): a real
@@ -48,6 +48,8 @@ def test_ragged_paged_attention_chunk_compiles(one_chip):
     assert MARKER in text
     assert all("ragged_paged_attention_chunk/" in op
                for op in _kernel_op_names(text))
+    # the walk: a slot a grid step, not a (slot, table column)
+    assert [grid for _, grid in _kernel_grids(text)] == [(S,)]
 
 
 @pytest.mark.parametrize("T", [1, 4], ids=["step", "chunk"])
@@ -67,6 +69,7 @@ def test_ragged_paged_attention_gqa_compiles(one_chip, T):
     assert MARKER in text
     assert all("ragged_paged_attention_gqa/" in op
                for op in _kernel_op_names(text))
+    assert [grid for _, grid in _kernel_grids(text)] == [(S,)]
 
 
 @pytest.mark.parametrize("T", [1, 4], ids=["step", "chunk"])
@@ -93,6 +96,8 @@ def test_ring_paged_attention_compiles(one_chip, T):
     ops = _kernel_op_names(text)
     assert len(ops) == 1 and "ring_paged_attention/" in ops[0]
     assert "ragged_paged_attention_gqa" not in ops[0]
+    # the ring keeps a grid step a (slot, ring column)
+    assert [grid for _, grid in _kernel_grids(text)] == [(S, R)]
 
 
 def test_gated_delta_chunked_compiles(one_chip):

@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _hybrid_sizes, _kernel_op_names, one_chip, _planned_bytes,
-    _pool_sized_strays, _ring_dispatches, _under)
+    _hybrid_sizes, _kernel_grids, _kernel_op_names, one_chip,
+    _planned_bytes, _pool_sized_strays, _ring_dispatches, _under)
 
 
 # -- a decoder-hybrid-decoder (PR 48) -----------------------------------------
@@ -159,6 +159,12 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
     ring = [op for op in kernels if "ring_paged_attention/" in op]
     assert len(ring) == 8 and all("_decode_step)/blk_mixer/attn_window/" in op
                                   for op in ring)
+    # the run's eight reads walk a slot's live pages, a slot a grid
+    # step (96 table columns a slot are no part of the grid); a ring's
+    # keep a step a (slot, ring column)
+    grids = dict(_kernel_grids(text))
+    assert {grids[op] for op in gqa} == {(S,)}
+    assert {grids[op] for op in ring} == {(S, g["ring_pages"])}
     assert sum("/attn_window/" in ln for ln in scatters) == 16
     # a slot's ring is 5 pages of 10 heads x 128 rows: no gathered copy
     assert not re.search(r"\[64,5,10,128,128\]|\[64,5,128,10,128\]"
