@@ -956,3 +956,54 @@ def dense_prefill_attention(q, k, v, causal: bool = True):
         out = jnp.einsum("hts,hsd->htd", jax.nn.softmax(s, axis=-1),
                          vb.astype(_F32)).astype(q.dtype)
     return jnp.moveaxis(out, 0, 1)
+
+
+def _attend_with_lse(q, k, v, causal: bool):
+    """(BH, S, D) over (BH, Sk, D) -> (out (BH, S, D) float32, the rows'
+    log-sum-exp (BH, S)): the flash forward where its blocks fit the
+    shape, else the plain softmax."""
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.pallas import flash_attention as fa
+
+    (_, S, D), Sk = q.shape, k.shape[1]
+    if pk.dispatch("prefill_flash_attention", pk.policy(
+            fa.fits_forward(S, Sk, D, q.dtype.itemsize), True)):
+        out, lse = fa.flash_attention_with_lse(
+            q, k, v, causal, None, pk.interpret_mode())
+        return out.astype(_F32), lse
+    s = jnp.einsum("htd,hsd->hts", q.astype(_F32),
+                   k.astype(_F32)) * (D ** -0.5)
+    if causal:
+        s = jnp.where(jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :], s,
+                      _NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("hts,hsd->htd", jnp.exp(s - lse[..., None]),
+                      v.astype(_F32)), lse
+
+
+def prompt_chunk_attention(q, k, v, k_run, v_run):
+    """Attention of a CHUNK of one prompt that continues its own
+    prefill: ``q`` (C, H, D) and the chunk's own ``k``, ``v`` (C, Hkv,
+    D), after the sequence's cached rows ``k_run``, ``v_run`` (Hkv,
+    done, D: heads first, as the kernel takes them), every one real and
+    before every chunk row -> (C, H, D) in q's dtype.  Two reads merged
+    by their log-sum-exp: the cached rows
+    without a mask, the group's query heads folded into the rows of
+    their K/V head (no row order matters there, so K/V are not
+    repeated), and the chunk itself causal (``dense_prefill_attention``'s
+    repeat of a few MB).  Padding rows at the chunk's end are hidden
+    from the real rows by causality."""
+    (C, H, D), Hkv = q.shape, k.shape[1]
+    G = H // Hkv
+    qh = jnp.moveaxis(q, 1, 0)                               # (H, C, D)
+    own, own_lse = _attend_with_lse(
+        qh, jnp.repeat(jnp.moveaxis(k, 1, 0), G, axis=0),
+        jnp.repeat(jnp.moveaxis(v, 1, 0), G, axis=0), True)
+    run, run_lse = _attend_with_lse(
+        qh.reshape(Hkv, G * C, D), k_run, v_run, False)
+    run, run_lse = run.reshape(H, C, D), run_lse.reshape(H, C)
+    top = jnp.maximum(own_lse, run_lse)
+    w_own, w_run = jnp.exp(own_lse - top), jnp.exp(run_lse - top)
+    out = ((own * w_own[..., None] + run * w_run[..., None])
+           / (w_own + w_run)[..., None])
+    return jnp.moveaxis(out, 0, 1).astype(q.dtype)

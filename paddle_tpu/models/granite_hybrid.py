@@ -189,8 +189,53 @@ def step_ssd(x, dt, g, B, C, state):
     return jnp.sum(new * C[..., None, None, :], axis=-1), new
 
 
+class PackedHeadPages:
+    """The page side of an attention layer whose pages hold ``pack`` K/V
+    heads side by side a stored row of whole lanes (two 64-wide heads a
+    128-lane row), over ``StateEntryCache``'s pools of the attention
+    layers alone.  The block's fields ``pack``, ``kv_heads`` and
+    ``full_pages`` say the layout; ``models/lfm2_moe.py`` stands on it
+    too."""
+
+    def _packed(self, rows, pool):
+        """K or V rows (..., kv_heads, dh) as a page stores them:
+        ``pack`` heads side by side a row of whole lanes (a reshape)."""
+        return rows.reshape(rows.shape[:-2] + pool.shape[3:])
+
+    def store_prompt(self, pool, rows, flat):
+        return super().store_prompt(pool, self._packed(rows, pool), flat)
+
+    def cached_attention(self, k_pool, v_pool, li, q, k, v, flat, tables,
+                         lens):
+        """A decode step's: the pools hold the attention layers alone,
+        ``pack`` K/V heads a stored row, and the table's first columns
+        are the page run.  The grouped kernel reads a stored row as one
+        head of ``pack * dh`` lanes; each query head carries its
+        numbers in the lanes of the K/V head it reads and zeros in the
+        others', and takes that part of the output lanes."""
+        S, Hq, dh = q.shape
+        Hs, pack = k_pool.shape[3], self.pack
+        G = Hq // self.kv_heads
+        slab = self.index_in_kind       # of the attention layers' pools
+        with jax.named_scope("attn_full"):
+            k_pool = _write_rows(k_pool, slab, flat, self._packed(k, k_pool))
+            v_pool = _write_rows(v_pool, slab, flat, self._packed(v, v_pool))
+            pages = _layer_pages(k_pool, v_pool, slab,
+                                 tables[:, :self.full_pages])
+            # (S, stored heads, which of the row's heads, G, dh): in the
+            # lanes of its own K/V head, zeros in the others'
+            mine = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+            wide = (q.reshape(S, Hs, pack, G, 1, dh) * mine).reshape(
+                S, Hq, pack * dh)
+            a = paged_attention(wide, *pages, lens + 1,
+                                scale=dh ** -0.5)
+            a = a.reshape(S, Hs, pack, G, pack, dh)
+            a = jnp.stack([a[:, :, p, :, p] for p in range(pack)], axis=2)
+        return a.reshape(S, Hq, dh), k_pool, v_pool
+
+
 @dataclasses.dataclass(frozen=True)
-class GraniteHybridBlock(StateEntryCache):
+class GraniteHybridBlock(PackedHeadPages, StateEntryCache):
     """See ``decode/model.py:Gpt2Block`` for the block's contract and
     ``decode/state_entry.py:StateEntryCache`` for the cache side.  The
     state-space layers' sizes go by the published names:
@@ -258,43 +303,7 @@ class GraniteHybridBlock(StateEntryCache):
             n, emb, (((n.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=_F32) / self.logits_scaling
 
-    # -- the cache side of an attention layer -------------------------------
-
-    def _packed(self, rows, pool):
-        """K or V rows (..., kv_heads, dh) as a page stores them:
-        ``pack`` heads side by side a row of whole lanes (a reshape)."""
-        return rows.reshape(rows.shape[:-2] + pool.shape[3:])
-
-    def store_prompt(self, pool, rows, flat):
-        return super().store_prompt(pool, self._packed(rows, pool), flat)
-
-    def cached_attention(self, k_pool, v_pool, li, q, k, v, flat, tables,
-                         lens):
-        """A decode step's: the pools hold the attention layers alone,
-        ``pack`` K/V heads a stored row, and the table's first columns
-        are the page run.  The grouped kernel reads a stored row as one
-        head of ``pack * dh`` lanes; each query head carries its
-        numbers in the lanes of the K/V head it reads and zeros in the
-        others', and takes that part of the output lanes."""
-        S, Hq, dh = q.shape
-        Hs, pack = k_pool.shape[3], self.pack
-        G = Hq // self.kv_heads
-        slab = self.index_in_kind       # of the attention layers' pools
-        with jax.named_scope("attn_full"):
-            k_pool = _write_rows(k_pool, slab, flat, self._packed(k, k_pool))
-            v_pool = _write_rows(v_pool, slab, flat, self._packed(v, v_pool))
-            pages = _layer_pages(k_pool, v_pool, slab,
-                                 tables[:, :self.full_pages])
-            # (S, stored heads, which of the row's heads, G, dh): in the
-            # lanes of its own K/V head, zeros in the others'
-            mine = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
-            wide = (q.reshape(S, Hs, pack, G, 1, dh) * mine).reshape(
-                S, Hq, pack * dh)
-            a = paged_attention(wide, *pages, lens + 1,
-                                scale=dh ** -0.5)
-            a = a.reshape(S, Hs, pack, G, pack, dh)
-            a = jnp.stack([a[:, :, p, :, p] for p in range(pack)], axis=2)
-        return a.reshape(S, Hq, dh), k_pool, v_pool
+    # -- the cache side of an attention layer: ``PackedHeadPages`` ----------
 
     # -- a mamba layer's pieces ---------------------------------------------
 

@@ -160,16 +160,21 @@ def softmax_scores(logits):
     return p, p, lambda chosen: chosen
 
 
-def sigmoid_scores(bias, scale: float):
+def sigmoid_scores(bias, scale: float, eps: float = 0.0):
     """The DeepSeek-V3 rule, as a function of the layer's selection
     ``bias`` (E,): ``s = sigmoid(logits)``; experts are ranked by ``s +
     bias`` and weighed by the unbiased ``s``, a row's weights being
-    ``scale * s / sum over its k chosen`` (all of them, wherever they
-    are held)."""
+    ``scale * s / (sum over its k chosen + eps)`` (all of them, wherever
+    they are held; ``eps`` 0 unless the model's rule has one: LFM2's
+    1e-6)."""
     def rule(logits):
         s = jax.nn.sigmoid(logits)
-        return s + bias.astype(_F32), s, lambda chosen: (
-            scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True))
+
+        def weights_of(chosen):
+            total = jnp.sum(chosen, axis=-1, keepdims=True)
+            return scale * chosen / (total + eps if eps else total)
+
+        return s + bias.astype(_F32), s, weights_of
     return rule
 
 

@@ -24,8 +24,10 @@ rows ``j C / 128 ..`` of the block, at no tile's edge for either model
 (34 and 90 rows a tap): Mosaic shifts the sublanes, a few vregs a slot.
 
 Per slot, float32 multiply-adds on rows kept in the weights' dtype, tap
-0 first as ``step_conv`` sums them: ``out = silu(sum_j w[j] rows[j] (+
-b))`` over ``rows = [the entry's taps - 1 rows; the step's row]``, and
+0 first as ``step_conv`` sums them: ``out = act(sum_j w[j] rows[j] (+
+b))`` (``act`` SiLU in front of a recurrence, the caller's default;
+none for a gated short conv, ``models/lfm2_moe.py``, whose entries are
+its whole state: 32 rows of lanes at 2,048 channels) over ``rows = [the entry's taps - 1 rows; the step's row]``, and
 the entry written back as ``rows[1:]``.
 
 Slots seated nowhere all address the null entry 0 (``gated_delta.py``
@@ -44,6 +46,17 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.pallas.gated_delta import LANES
 
 _F32 = jnp.float32
+SILU = "silu"
+
+
+def activate(acc, activation):
+    """What a conv does to its sum: ``"silu"``, or nothing (None)."""
+    if activation is None:
+        return acc
+    if activation != SILU:
+        raise ValueError(f"a conv's activation is {SILU!r} or None, not "
+                         f"{activation!r}")
+    return jax.nn.silu(acc)
 
 
 def fits(pool_dtype, entry_shape, row_dtype, taps: int,
@@ -56,7 +69,7 @@ def fits(pool_dtype, entry_shape, row_dtype, taps: int,
                                        LANES))
 
 
-def _kernel(at_ref, row_ref, w_ref, *refs, taps, R):
+def _kernel(at_ref, row_ref, w_ref, *refs, taps, R, activation):
     """One slot.  ``row_ref``, ``out_ref`` (1, R, 128), R rows of lanes
     a tap; ``w_ref`` (taps, R, 128); then ``b_ref`` (R, 128) where the
     conv has a bias; ``pool_ref``, ``new_ref`` (1, (taps - 1) * R,
@@ -71,19 +84,20 @@ def _kernel(at_ref, row_ref, w_ref, *refs, taps, R):
     acc = acc + row.astype(_F32) * w_ref[taps - 1].astype(_F32)
     for b_ref in bias:
         acc = acc + b_ref[...].astype(_F32)
-    out_ref[0] = jax.nn.silu(acc)
+    out_ref[0] = activate(acc, activation)
     if taps > 2:
         new_ref[0, :(taps - 2) * R, :] = pool_ref[0, R:, :]
     new_ref[0, (taps - 2) * R:, :] = row
 
 
-def conv_step(pool, at, row, w, b=None, interpret: bool = False):
+def conv_step(pool, at, row, w, b=None, activation=SILU,
+              interpret: bool = False):
     """``pool`` (entries, (taps - 1) * C / 128, 128); ``at`` (S,) the
     entry of each slot; ``row`` (S, C) the step's rows, in the pool's
-    dtype; ``w`` (taps, C); ``b`` (C,) or None -> (out (S, C) float32 =
-    ``silu(sum_j w[j] rows[j] (+ b))``, the pool with the S entries
-    moved on one row).  The pool is aliased input to output: donate
-    it."""
+    dtype; ``w`` (taps, C); ``b`` (C,) or None; ``activation``
+    ``"silu"`` or None (static) -> (out (S, C) float32 = ``act(sum_j
+    w[j] rows[j] (+ b))``, the pool with the S entries moved on one
+    row).  The pool is aliased input to output: donate it."""
     taps, C = w.shape
     S, R = at.shape[0], C // LANES
 
@@ -105,7 +119,7 @@ def conv_step(pool, at, row, w, b=None, interpret: bool = False):
         out_specs=[slot, entry],
     )
     out, pool = pl.pallas_call(
-        functools.partial(_kernel, taps=taps, R=R),
+        functools.partial(_kernel, taps=taps, R=R, activation=activation),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((S, R, LANES), _F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],

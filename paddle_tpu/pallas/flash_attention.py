@@ -133,6 +133,14 @@ def fits(B: int, H: int, S: int, D: int) -> bool:
     return all(_resolve_blocks(S, S, D, kernel=k)[0] for k in KERNELS)
 
 
+def fits_forward(S: int, Sk: int, D: int, itemsize: int = 4) -> bool:
+    """Whether the forward kernel alone has a block pair for ``S`` query
+    rows over ``Sk`` key rows (a prompt chunk over its cached rows:
+    ``decode/attention.py:prompt_chunk_attention``)."""
+    return (D <= 256 and D % 8 == 0
+            and bool(_resolve_blocks(S, Sk, D, itemsize)[0]))
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------

@@ -885,6 +885,44 @@ def test_the_latent_layers_names():
     assert latent.DECODE_KERNEL == "latent_paged_attention"
 
 
+def test_the_short_convs_and_the_chunks_names():
+    """What the gated short conv and the chunk over a state entry add to
+    the tracing (PR 59): the host span a chunk program, the two counters
+    the chunk readers take rows and pairs by, and the device-side scopes,
+    spelled as the readers of ``perf/harness/short_conv.py`` spell
+    them; the conv's kernel keeps its one name whatever its
+    activation."""
+    import inspect
+
+    from paddle_tpu.decode import state_entry as se
+    from paddle_tpu.models import lfm2_moe as lm
+    from perf.harness import short_conv as sc
+
+    for name in (sc.CHUNK_ROWS, sc.CHUNK_PAIRS):
+        assert obs.metrics.REGISTRY.get(name) is not None, name
+    assert (sc.CHUNK_ROWS, sc.CHUNK_PAIRS) == (
+        "decode_prefill_chunk_rows_total", "decode_prefill_chunk_pairs_total")
+    src = inspect.getsource(se)
+    assert 'phase("decode.prefill_chunk", done=done, rows=real,' in src
+    assert 'over="state"' in src
+    assert se._prefill_state_chunk.__name__ == "_prefill_state_chunk"
+    assert sc.CHUNK_MODULE == "_prefill_state_chunk"
+    src = inspect.getsource(lm)
+    for scope in ("short_conv", "short_conv_step", "short_conv_scan",
+                  "attn_full", "attn_chunk"):
+        assert f'jax.named_scope("{scope}")' in src, scope
+    assert (sc.ANY_SCOPE, sc.CHUNK_ATTENTION_SCOPE) == (
+        "/short_conv/", "/attn_chunk/")
+    model = lm.Lfm2MoeLM(
+        vocab=32, d_model=128, num_heads=2, num_kv_heads=2, head_dim=64,
+        layer_types=lm.PERIOD, num_dense_layers=1, intermediate_size=16,
+        moe_intermediate_size=128, num_experts=4, experts_per_tok=2,
+        max_len=16, num_pages=6, page_size=4, pages_per_seq=4,
+        state_entries=3, prefill_rows=8, chunk_rows=4, dtype="float32")
+    assert list(model.cache_rows([3])) == list(model.cache_bytes([3])) \
+        == ["full", "state"]
+
+
 def test_the_program_writes_spans_through_span_only():
     """No TraceAnnotation and no direct ring write outside
     paddle_tpu/observability, except flags trace_ops's per-op wrap."""
