@@ -1,26 +1,28 @@
-"""Ragged paged-attention decode kernel + dense prefill path.
+"""Ragged paged-attention decode kernels + dense prefill path.
 
 Decode shape (the "Ragged Paged Attention" design, PAPERS.md): each
 active sequence contributes ONE query token per step, but its context
 lives scattered across fixed-size KV pages named by a per-sequence page
-table.  The kernel runs a ``(slots, pages_per_seq)`` grid with the page
-table and lengths *scalar-prefetched* into SMEM, so each K/V BlockSpec
-picks its page straight from the table — the gather never materializes
-a per-sequence contiguous copy — and pages wholly past the sequence
-length are skipped (their FLOPs AND their DMA do not happen, same trick
-as the causal-block skip in ``pallas/flash_attention.py``).  Softmax is
-the same online (running max / normalizer) accumulation as the flash
-forward, in f32 VMEM scratch.
+table.  The table and the lengths are *scalar-prefetched* into SMEM and
+the kernel picks each page straight from the table — the gather never
+materializes a per-sequence contiguous copy — and pages wholly past the
+sequence length are skipped (their FLOPs AND their DMA do not happen,
+same trick as the causal-block skip in ``pallas/flash_attention.py``).
+Softmax is the same online (running max / normalizer) accumulation as
+the flash forward, in f32 VMEM scratch.
 
-The chunk kernel (a verify chunk's or a prefix suffix's T rows a slot,
-and every call on grouped heads, a decode step's among them) takes ONE
-grid step a slot instead and walks the slot's live pages alone, copying
-them itself out of the pools left in HBM (``_rpa_walk_kernel``, PR 58):
-a table column past a slot's length costs a grid step of the
-``(slots, pages_per_seq)`` grid even where it costs no read, and at 26
-live columns of 96 those steps were a third of the call.  Only a window
-layer's ring, five columns nearly always all seen, keeps a grid step a
-column (``_rpa_chunk_kernel``).
+Every call over a page run (a decode step's row a slot, on grouped
+heads or not; a verify chunk's or a prefix suffix's T rows) takes ONE
+grid step a slot and walks the slot's live pages alone, copying them
+itself out of the pools left in HBM (``_rpa_walk_kernel``: PR 58 the
+chunk and grouped calls, PR 60 the step's row on ungrouped heads): a
+table column past a slot's length costs a grid step of a ``(slots,
+pages_per_seq)`` grid even where it costs no read, 0.2-0.3 us, and at
+17 live columns of 64 those steps were a third of the call.  Two calls
+keep a grid step a column: the step's row on pages the compiled walk
+does not take (``walk_fits``: 1 MB pages, heads under 128 lanes;
+``_rpa_kernel``, the first kernel of this file), and a window layer's
+ring, five columns nearly always all seen (``_rpa_chunk_kernel``).
 
 Prefill stays dense: a prompt is contiguous, so the existing flash
 attention forward (``pallas/flash_attention.py``) — or its jnp fallback
@@ -92,17 +94,22 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     and the kernel's page copy refused, so the rows are stored at 640
     (``models/kanana_mla.py:row_width``).
 
-    The walk over a page run (PR 58: the chunk and grouped calls) keeps
-    in VMEM, beside the chunk's q/o blocks and float32 accumulator, two
-    double buffers of ``WALK_PAGES`` pages, K's and V's: 16 pages at 4 a
-    turn.  Ten stored heads of 128 over 128-row bfloat16 pages
-    (Phi-4-mini-flash: 327,680 B a page) are 5.24 MB; four stored heads
-    (Granite: 131,072 B) 2.10 MB; eight (K-EXAONE: 262,144 B) 4.19 MB,
-    of the 16 MiB a kernel may hold; the float32 copies of the one page
-    being computed on come to 1.3 MB more at the Phi shape.  Compiled,
-    it also asks pages that are whole tiles where they lie in HBM, which
-    the three layouts above are and the ones they replaced are not:
-    ``walk_fits``."""
+    The walk over a page run (PR 58: the chunk and grouped calls; PR 60:
+    the decode step's row on ungrouped heads) keeps in VMEM, beside the
+    chunk's q/o blocks and float32 accumulator, two double buffers of
+    ``WALK_PAGES`` pages, K's and V's: 16 pages at 4 a turn.  Ten stored
+    heads of 128 over 128-row bfloat16 pages (Phi-4-mini-flash: 327,680
+    B a page) are 5.24 MB; four stored heads (Granite: 131,072 B) 2.10
+    MB; eight (K-EXAONE: 262,144 B) 4.19 MB; sixteen heads over 32-row
+    pages bfloat16 (OLMoE: 131,072 B) 2.10 MB and float32 (Cerebras:
+    262,144 B) 4.19 MB, of the 16 MiB a kernel may hold; the float32
+    copies of the one page being computed on come to 1.3 MB more at the
+    Phi shape.  Compiled, it also asks pages that are whole tiles where
+    they lie in HBM, which the layouts above are and the ones they
+    replaced are not: ``walk_fits``.  The Olmo-Hybrid's 32 stored heads
+    over 128-row pages (1 MB a page, 16.8 MB of buffers) it refuses:
+    that step's row is the one compiled caller the ``(S, P)`` grid of
+    ``ragged_paged_attention`` has left."""
     ok = (page_size % 8 == 0 and head_dim % 8 == 0
           and head_dim <= 256 and num_heads >= 1)
     if kv_heads in (None, num_heads):
@@ -188,8 +195,12 @@ def _page_update(q, k, v, m_prev, l_prev, acc_prev, t0, seq_len, scale):
     ``dot_general`` whose left operand has only a batch and a
     contracting dim — so the scores and the ``pr . v`` product are VPU
     multiply-reduces: over the lane dim D for the scores, over the
-    leading page dim for the accumulator.  Decode attention is bound by
-    the page DMA, not by these FLOPs.
+    leading page dim for the accumulator.  The padded-row MXU form
+    (``_softmax_page`` on 8 sublanes, both pages turned in VMEM) read
+    slower under the walk at 32-row pages of 16 heads and rounds its
+    operands to bfloat16 (PERF.md §6, PR 60).  On float32 pages this
+    arithmetic hides under the page's copy but for a fifth; on bfloat16
+    pages it is the bound: 0.57 us a page whose copy is 0.32.
     """
     q = q.astype(_F32)
     k = k.astype(_F32)
@@ -203,6 +214,17 @@ def _page_update(q, k, v, m_prev, l_prev, acc_prev, t0, seq_len, scale):
     l_new = l_prev * corr + jnp.sum(pr, axis=0)
     acc_new = acc_prev * corr + jnp.sum(pr * v, axis=0)         # (H, D)
     return m_new, l_new, acc_new
+
+
+def _row_page(q, k, v, m_scr, l_scr, acc_scr, col, page, seq_len, scale):
+    """``_page_update`` of table column ``col``'s page on the running
+    max, normaliser and accumulator in VMEM scratch."""
+    m_new, l_new, acc_new = _page_update(
+        q, k, v, m_scr[...], l_scr[...], acc_scr[...], col * page, seq_len,
+        scale)
+    m_scr[...] = m_new
+    l_scr[...] = l_new
+    acc_scr[...] = acc_new
 
 
 def _rpa_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -225,30 +247,30 @@ def _rpa_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page < seq_len)
     def _page():
-        m_new, l_new, acc_new = _page_update(
-            q_ref[0], k_ref[0], v_ref[0], m_scr[...], l_scr[...],
-            acc_scr[...], p * page, seq_len, scale)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
-        acc_scr[...] = acc_new
+        _row_page(q_ref[0], k_ref[0], v_ref[0], m_scr, l_scr, acc_scr,
+                  p, page, seq_len, scale)
 
     @pl.when(p == npp - 1)
     def _finish():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, lens,
                            scale=None, interpret: bool = False):
-    """Pallas ragged paged-attention decode step.
+    """Pallas ragged paged-attention decode step on a ``(slots,
+    pages_per_seq)`` grid.
 
     Same contract as the reference: q (S, H, D), pools (N, page, H, D),
     page_tables (S, P), lens (S,) -> (S, H, D).  One slot a grid step,
     the slot dimension ``parallel``: sweeping 2, 4 or 8 slots' pages
     under one resident q/o block, and ``arbitrary`` for it, read the
-    same or slower at the cells' shapes (PERF.md §6, PR 44).
+    same or slower at the cells' shapes (PERF.md §6, PR 44).  A column
+    past a slot's length is a grid step all the same:
+    ``ragged_paged_attention_walk`` is the same contract without them,
+    and ``paged_attention`` sends it every pool ``walk_fits`` takes
+    (PR 60); compiled, this grid is left the pools it refuses (the
+    Olmo-Hybrid's 1 MB pages, heads under 128 lanes).
     """
     S, H, D = q.shape
     page = k_pages.shape[1]
@@ -398,16 +420,23 @@ def _softmax_start(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _softmax_finish(o_ref, l_scr, acc_scr):
-    """A slot no page of which was live (an empty seat) writes zeros."""
+def _softmax_out(l_scr, acc_scr):
+    """The accumulator over the normaliser, float32.  A slot no page of
+    which was live (an empty seat) gives zeros."""
     l = l_scr[...]
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = jnp.swapaxes(acc_scr[...] / l, 0, 1).astype(o_ref.dtype)
+    return acc_scr[...] / l
+
+
+def _softmax_finish(o_ref, l_scr, acc_scr):
+    o_ref[0] = jnp.swapaxes(_softmax_out(l_scr, acc_scr), 0, 1).astype(
+        o_ref.dtype)
 
 
 def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                      kbuf, vbuf, sems, start, q_scr, m_scr, l_scr, acc_scr,
-                     *, scale, page, npp, T, G, heads_major, fetch, slots):
+                     *, scale, page, npp, T, G, heads_major, fetch, slots,
+                     row):
     """The chunk kernel over a page run: ONE grid step a slot, which
     walks the slot's live pages alone (``pallas/latent_attention.py``'s
     pattern).  The q block holds the slot's whole chunk, ``T * G`` rows:
@@ -428,7 +457,14 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     (1,) in SMEM keeps the half a slot's first turn lies in from grid
     step to grid step, which is why the grid is ``arbitrary``.
     ``q_scr`` (H, T * G, D): the chunk in float32 with its heads
-    outermost, as the two batched dots take it, turned once a slot."""
+    outermost, as the two batched dots take it, turned once a slot.
+
+    ``row`` (static: a chunk of ONE row on row-major pages, the decode
+    step's on ungrouped heads): one row is no free dimension for the two
+    batched dots, and a page's arithmetic is ``_page_update``'s
+    multiply-reduces on the page as it lies, nothing turned; ``q_scr``
+    and the accumulator are (H, D), the running max and normaliser
+    (H, 1).  Every other call's body is what it was."""
     s = pl.program_id(0)
 
     def live_pages(slot):
@@ -462,7 +498,10 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     seq_len, first, live = lens_ref[s], start[0], live_pages(s)
     turns = pl.cdiv(live, fetch)
     _softmax_start(m_scr, l_scr, acc_scr)
-    q_scr[...] = jnp.swapaxes(q_ref[0].astype(_F32), 0, 1)
+    if row:
+        q_scr[...] = q_ref[0, 0].astype(_F32)
+    else:
+        q_scr[...] = jnp.swapaxes(q_ref[0].astype(_F32), 0, 1)
 
     @pl.when((turns == 0) & (s + 1 < slots))
     def _empty_seat():
@@ -482,6 +521,11 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         def compute(j, col):
             for c in page_copies(s, col, half, j):
                 c.wait()
+            if row:
+                # the row sees its own key: ``seq_len + 1`` rows
+                _row_page(q_scr[...], kbuf[half, j], vbuf[half, j], m_scr,
+                          l_scr, acc_scr, col, page, seq_len + 1, scale)
+                return
 
             def seen(shape):
                 t_pos = col * page + jax.lax.broadcasted_iota(
@@ -501,7 +545,10 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     jax.lax.fori_loop(0, turns, turn, 0)
     # the half the next slot's first turn was copied into
     start[0] = (first + turns) % 2
-    _softmax_finish(o_ref, l_scr, acc_scr)
+    if row:
+        o_ref[0, 0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
+    else:
+        _softmax_finish(o_ref, l_scr, acc_scr)
 
 
 def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -542,7 +589,7 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
-                G, heads_major=False, window=None):
+                G, heads_major=False, window=None, step=False):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
     the whole chunk resident in the q/o blocks.  A page is (page, H, D),
     or (H, page, D) ``heads_major``.
@@ -551,7 +598,9 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
     ``paged_attention``, the verify chunk and the prefix suffix through
     ``paged_chunk_attention``, grouped heads or not): one grid step a
     slot, the pools left in HBM and the slot's live pages copied by the
-    kernel, ``WALK_PAGES`` a turn (``_rpa_walk_kernel``).  ``window``:
+    kernel, ``WALK_PAGES`` a turn (``_rpa_walk_kernel``).  ``step``: the
+    call is the decode step's on ungrouped heads and carries that
+    kernel's name.  ``window``:
     the table is a ring's columns, ``lens`` the position of the chunk's
     first row, one grid step a (slot, column) with the column's page the
     K/V block (``_rpa_chunk_kernel``).  Which of the two is the static
@@ -563,10 +612,15 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
         scale = D ** -0.5
     statics = dict(scale=scale, page=page, npp=P, T=TG // G, G=G,
                    heads_major=heads_major)
+    # a (head, chunk row) a row of the softmax's state; the walk's one
+    # row on a page as it lies (``_rpa_walk_kernel``) keeps its heads in
+    # the sublanes
+    row = TG == 1 and window is None and not heads_major
+    rows = (H,) if row else (H, TG)
     softmax = [
-        pltpu.VMEM((H, TG, 1), _F32),     # running max
-        pltpu.VMEM((H, TG, 1), _F32),     # running normalizer
-        pltpu.VMEM((H, TG, D), _F32),     # output accumulator
+        pltpu.VMEM(rows + (1,), _F32),    # running max
+        pltpu.VMEM(rows + (1,), _F32),    # running normalizer
+        pltpu.VMEM(rows + (D,), _F32),    # output accumulator
     ]
     out_shape = jax.ShapeDtypeStruct((S, TG, H, D), q.dtype)
     args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
@@ -614,17 +668,20 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
             pltpu.VMEM((2, fetch) + v_pages.shape[1:], v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2, fetch)),
             pltpu.SMEM((1,), jnp.int32),      # the half a slot starts in
-            pltpu.VMEM((H, TG, D), _F32),     # the chunk, heads outermost
+            pltpu.VMEM(rows + (D,), _F32),    # the chunk, heads outermost
         ] + softmax,
     )
     kernel = functools.partial(_rpa_walk_kernel, fetch=fetch, slots=S,
-                               **statics)
+                               row=row, **statics)
     call = dict(
         grid_spec=grid_spec, out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret)
-    # one body under two names: a trace tells the grouped call apart
+    # one body under three names: a trace tells the calls apart
+    if step:
+        return pl.pallas_call(
+            kernel, name="ragged_paged_attention", **call)(*args)
     if G > 1:
         return pl.pallas_call(
             kernel, name="ragged_paged_attention_gqa", **call)(*args)
@@ -639,6 +696,16 @@ def ragged_paged_attention_chunk(q, k_pages, v_pages, page_tables, lens,
     ``ragged_paged_attention_chunk_reference``)."""
     return _chunk_call(q, k_pages, v_pages, page_tables, lens, scale,
                        interpret, 1)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def ragged_paged_attention_walk(q, k_pages, v_pages, page_tables, lens,
+                                scale=None, interpret: bool = False):
+    """``ragged_paged_attention``'s contract by the walk: the decode
+    step's row a slot is the chunk of one row after ``lens - 1`` cached
+    rows (an empty seat, ``lens`` 0, walks no page and writes zeros)."""
+    return _chunk_call(q[:, None], k_pages, v_pages, page_tables, lens - 1,
+                       scale, interpret, 1, step=True)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -869,16 +936,26 @@ def _use_kernel(kernel: str, page_size: int, H: int, D: int) -> bool:
     return pk.dispatch(kernel, pk.policy(fits(page_size, H, D), True))
 
 
+def _walks(dtype, page_size: int, Hkv: int, D: int,
+           heads_major: bool = False) -> bool:
+    """Whether the walk takes the pool's pages: interpreted, every
+    shape; compiled, what ``walk_fits`` says."""
+    from paddle_tpu import pallas as pk
+
+    return pk.interpret_mode() or walk_fits(dtype, page_size, Hkv, D,
+                                            heads_major)
+
+
 def _use_walk(kernel: str, dtype, page_size: int, Hq: int, D: int, Hkv: int,
               heads_major: bool = False) -> bool:
-    """``_use_kernel`` for the calls over a page run, which walk: where
-    the kernel would be compiled the pages also have to be whole tiles
-    where they lie (``walk_fits``)."""
+    """``_use_kernel`` for the calls over a page run that have no other
+    kernel than the walk: where it would be compiled the pages also
+    have to be whole tiles where they lie (``walk_fits``)."""
     from paddle_tpu import pallas as pk
 
     return pk.dispatch(kernel, pk.policy(
-        fits(page_size, Hq, D, Hkv) and (pk.interpret_mode() or walk_fits(
-            dtype, page_size, Hkv, D, heads_major)), True))
+        fits(page_size, Hq, D, Hkv)
+        and _walks(dtype, page_size, Hkv, D, heads_major), True))
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
@@ -912,8 +989,13 @@ def paged_attention(q, k_pages, v_pages, page_tables, lens, scale=None,
         return _paged_gqa(q[:, None], k_pages, v_pages, page_tables,
                           lens - 1, scale, heads_major)[:, 0]
     S, H, D = q.shape
-    if _use_kernel("ragged_paged_attention", k_pages.shape[1], H, D):
-        return ragged_paged_attention(
+    page = k_pages.shape[1]
+    if _use_kernel("ragged_paged_attention", page, H, D):
+        # one online softmax over a slot's live pages, two grids: which
+        # one follows from the pool's shape and dtype alone
+        return (ragged_paged_attention_walk
+                if _walks(k_pages.dtype, page, H, D)
+                else ragged_paged_attention)(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
             interpret=pk.interpret_mode())
     return ragged_paged_attention_reference(

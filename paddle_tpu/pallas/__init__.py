@@ -4,14 +4,18 @@ paddle/cuda/src/hl_cuda_lstm.cu etc. — reimplemented for the MXU/VPU),
 and the one place where the choice between a kernel and its jnp/XLA
 lowering is made.
 
-Thirteen kernel families, twenty-three ``pl.pallas_call``s: the fused
+Thirteen kernel families, twenty-four ``pl.pallas_call``s: the fused
 whole-sequence LSTM (``lstm.py``, 1), the row softmax (``softmax.py``,
 1), flash attention forward and backward (``flash_attention.py``, 3;
 also run by ring attention's chunks and by the decoder's prefill),
-ragged paged attention (``decode/attention.py``, 3 kernel bodies under
-4 names: the chunk kernel, a walk of a slot's live pages since PR 58,
-is also called on grouped heads, Hq query heads on Hkv K/V heads, as
-``ragged_paged_attention_gqa``; its page's arithmetic under a window
+ragged paged attention (``decode/attention.py``, 3 kernel bodies, 5
+calls, 4 names: the chunk kernel, a walk of a slot's live pages since
+PR 58, is also called on grouped heads, Hq query heads on Hkv K/V
+heads, as ``ragged_paged_attention_gqa``, and since PR 60 on the decode
+step's one row on ungrouped heads, as ``ragged_paged_attention``, the
+name of the first kernel, whose grid step a (slot, table column) is
+left the pools whose pages the compiled walk does not take,
+``walk_fits``; the chunk's page arithmetic under a window
 mask reckoned from positions, a grid step a (slot, ring column), reads
 a window layer's ring of pages where they lie in the pool, as
 ``ring_paged_attention``: taken
@@ -34,8 +38,9 @@ float32), and absorbed latent attention
 over paged latent rows, every head on the one stored row, which is key
 and value both (``latent_attention.py``, 1: a grid step a slot, the
 slot's live pages walked by a dynamic loop and copied by hand through a
-double buffer, where ``ragged_paged_attention`` and the ring's take a
-grid step a table column).  The latent pool's rows are stored at 640 lanes for the 576
+double buffer, the pattern of ``decode/attention.py``'s walk; only
+the ring's kernel and ``ragged_paged_attention`` on the pages that walk
+refuses take a grid step a table column).  The latent pool's rows are stored at 640 lanes for the 576
 the algorithm needs: at 576 the chip's compiler lays the pool out at
 640 anyway and refuses the kernel's page copy ("slice shape must be
 aligned to tiling (128)"; ``tests/test_chip_compile.py``, PR 45).  The last is the grouped GEMM

@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _assert_pools_in_place, _assert_step_outputs, _kernel_op_names,
-    one_chip, _planned_bytes)
+    _assert_pools_in_place, _assert_step_outputs, _kernel_grids,
+    _kernel_op_names, one_chip, _planned_bytes)
 
 
 def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
@@ -41,8 +41,11 @@ def test_decode_step_names_its_kernel_and_its_wrapper(one_chip, monkeypatch):
         heads=lm_kw["heads"], page_size=pg).compile().as_text()
     ops = _kernel_op_names(text)
     assert len(ops) == lm_kw["layers"]
-    assert all("_decode_step" in op and "ragged_paged_attention" in op
+    assert all("_decode_step" in op and "ragged_paged_attention/" in op
                for op in ops)
+    # heads of 8 lanes: ``walk_fits`` refuses, the (S, P) grid compiles
+    assert [grid for _, grid in _kernel_grids(text)] == [
+        (S, P)] * lm_kw["layers"]
 
 
 # the decode steps' plans at the parent of PR 27, whose steps were not
@@ -58,7 +61,9 @@ def test_cerebras_decode_step_writes_and_reads_its_pools_in_place(
     rows, 16 slots): the donated pools are aliased, every K/V row is
     scattered into the pool's own buffer and the rpa kernel reads the
     whole pool through moved page tables, so the plan is at least two
-    pools under the undonated step's."""
+    pools under the undonated step's.  The kernel walks a slot's live
+    pages, one grid step a slot (PR 60: ``walk_fits`` takes the f32
+    pages; the plan was 9,314,695,680 under the ``(S, P)`` grid)."""
     from paddle_tpu import pallas as pk
     from paddle_tpu.decode import model as dm
 
@@ -80,14 +85,15 @@ def test_cerebras_decode_step_writes_and_reads_its_pools_in_place(
         sds((S,), jnp.int32), heads=H, page_size=pg).compile()
     _assert_step_outputs(compiled, S, 50257)
     planned = _planned_bytes(compiled)
-    assert planned == 9_314_695_680, planned
+    assert planned == 9_314_921_472, planned
     text = _assert_pools_in_place(
         compiled, len(jax.tree.leaves(params)), shape, 4,
         CEREBRAS_STEP_PLAN_UNDONATED)
     ops = _kernel_op_names(text)
     assert len(ops) == L
-    assert all("_decode_step" in op and "ragged_paged_attention" in op
+    assert all("_decode_step" in op and "ragged_paged_attention/" in op
                for op in ops)
+    assert [grid for _, grid in _kernel_grids(text)] == [(S,)] * L
 
 
 def test_prefill_top_bucket_fits_and_aliases_its_pools(one_chip, monkeypatch):

@@ -37,6 +37,39 @@ def test_ragged_paged_attention_compiles(one_chip, shape):
                for op in _kernel_op_names(text))
 
 
+# the Olmo-Hybrid's full layers: 48 slots, 32 stored heads of 128 on
+# 128-row bf16 pages, 1 MB a page
+RPA_HYBRID = (48, 32, 128, 128, 447, 36, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("shape, walks", [
+    (RPA_CEREBRAS, True), (RPA_OLMOE, True), (RPA_REAL, True),
+    (RPA_HYBRID, False)],
+    ids=["cerebras-step", "olmoe-step", "real", "olmo-hybrid-step"])
+def test_the_steps_row_through_paged_attention_compiles(
+        one_chip, monkeypatch, shape, walks):
+    """The decode step's call on ungrouped heads, through the
+    dispatcher: where ``walk_fits`` takes the pool's pages (the two
+    cells', the /generate model's) the walk, a slot a grid step; where
+    it refuses them (the hybrid's 1 MB pages) the ``(S, P)`` grid; both
+    under the name the step's kernel has always had."""
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import attention as A
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", False)
+    S, H, D, page, N, P, dt = shape
+    assert A.walk_fits(dt, page, H, D) == walks
+    text = _compiled_text(
+        lambda *a: A.paged_attention(*a),
+        one_chip, ((S, H, D), dt), ((N, page, H, D), dt),
+        ((N, page, H, D), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
+    ops = _kernel_op_names(text)
+    assert len(ops) == 1 and "ragged_paged_attention/" in ops[0]
+    assert [grid for _, grid in _kernel_grids(text)] == [
+        (S,) if walks else (S, P)]
+
+
 def test_ragged_paged_attention_chunk_compiles(one_chip):
     from paddle_tpu.decode import attention as A
 

@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _hybrid_sizes, _kernel_op_names, one_chip, _planned_bytes,
-    _pool_sized_strays)
+    _hybrid_sizes, _kernel_grids, _kernel_op_names, one_chip,
+    _planned_bytes, _pool_sized_strays)
 
 
 def _hybrid_cell(one_chip, monkeypatch):
@@ -113,6 +113,10 @@ def test_hybrid_decode_step_moves_states_and_pages_in_place(one_chip,
     rpa = [op for op in kernels if "ragged_paged_attention/" in op]
     assert len(rpa) == 4 and all("_decode_step)/blk_mixer/attn_full/" in op
                                  for op in rpa)
+    # 1 MB a page: ``walk_fits`` refuses the double buffers, and the
+    # step keeps a grid step a (slot, table column) (PR 60)
+    assert [grid for op, grid in _kernel_grids(text)
+            if "ragged_paged_attention/" in op] == [(S, width - 1)] * 4
     step = [op for op in kernels if "gated_delta_step/" in op]
     conv = [op for op in kernels if "conv_step/" in op]
     assert len(step) == len(conv) == 12 and len(kernels) == 28

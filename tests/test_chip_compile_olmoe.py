@@ -12,8 +12,8 @@ import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
     _assert_experts_read_where_they_lie, _assert_grouped_gemm_kernel,
-    _assert_pools_in_place, _assert_step_outputs, _kernel_op_names,
-    one_chip, _planned_bytes, _under)
+    _assert_pools_in_place, _assert_step_outputs, _kernel_grids,
+    _kernel_op_names, one_chip, _planned_bytes, _under)
 
 
 # the decode steps' plans at the parent of PR 27, whose steps were not
@@ -61,7 +61,10 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
     """The decode step of the ``olmoe-1b-7b`` generate configuration at
     its real sizes (8 layers, 64 experts of 1,024, bf16 weights and
     1,537 pages of 32 bf16 rows, 32 slots): the rpa kernel takes bf16
-    pages at (32, 16, 128); the step's 32 rows take the dense pass, so
+    pages at (32, 16, 128) and walks a slot's live pages, one grid step
+    a slot (PR 60: ``walk_fits`` takes the pages; the plan is the
+    ``(S, P)`` grid's to the byte, the double buffers live in VMEM);
+    the step's 32 rows take the dense pass, so
     the experts ARE 64 masked dense matmuls a projection, batched into
     one: at four rows an expert that reads the same bytes faster than
     the chip's grouped-matmul kernel (``models/moe.py:expert_path``; a
@@ -88,8 +91,9 @@ def test_olmoe_decode_step_compiles_with_bf16_pages(one_chip, monkeypatch):
         OLMOE_STEP_PLAN_UNDONATED)
     ops = _kernel_op_names(text)
     assert len(ops) == L and all(
-        "_decode_step" in op and "ragged_paged_attention" in op
+        "_decode_step" in op and "ragged_paged_attention/" in op
         for op in ops)
+    assert [grid for _, grid in _kernel_grids(text)] == [(S,)] * L
     _assert_experts_read_where_they_lie(
         text, cfg["num_experts"], cfg["hidden_size"],
         cfg["intermediate_size"])

@@ -114,6 +114,100 @@ def test_ragged_paged_attention_kernel_matches_reference():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lens", [
+    [0, 1, 3, 9], [8, 16, 24, 17], [24, 24, 24, 24]],
+    ids=["empty-seat-and-partial", "last-page-full", "all-columns-live"])
+def test_the_steps_row_by_the_walk_matches_reference(dtype, lens):
+    """The decode step's row on ungrouped heads as the chunk of one row
+    after ``lens - 1`` cached rows (``ragged_paged_attention_walk``,
+    ``T * G == 1``), interpreted: the reference's numbers and the
+    ``(S, P)`` grid's on ragged lengths; an empty seat (``lens`` 0: no
+    row cached, none its own) walks no page and writes zeros, as the
+    grid does; a slot of one row, of exactly one page (8), of whole
+    pages (16, 24: the last page full) and of every column."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.decode import attention as A
+
+    S, H, D, page, N, P = 4, 2, 16, 8, 16, 3
+    dt = jnp.dtype(dtype)
+    q = jax.random.normal(jax.random.key(0), (S, H, D), dt)
+    kp = jax.random.normal(jax.random.key(1), (N, page, H, D), dt)
+    vp = jax.random.normal(jax.random.key(2), (N, page, H, D), dt)
+    pt = jnp.asarray(np.random.RandomState(0).permutation(N - 1)[:S * P]
+                     .reshape(S, P) + 1, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    ref = np.asarray(A.ragged_paged_attention_reference(
+        q, kp, vp, pt, lens).astype(jnp.float32))
+    grid = np.asarray(A.ragged_paged_attention(
+        q, kp, vp, pt, lens, interpret=True).astype(jnp.float32))
+    walk = np.asarray(A.ragged_paged_attention_walk(
+        q, kp, vp, pt, lens, interpret=True).astype(jnp.float32))
+    live = np.asarray(lens) > 0
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(walk[live], ref[live], atol=tol, rtol=tol)
+    assert not walk[~live].any()
+    # the same sums in the same order as the grid's: page by page
+    np.testing.assert_allclose(walk, grid, atol=1e-6, rtol=1e-6)
+
+
+def _pallas_calls(jaxpr):
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("H, D, page, dtype, interpret, fits, grid", [
+    (16, 128, 32, "bfloat16", False, True, (4,)),     # OLMoE's pages
+    (16, 128, 32, "float32", False, True, (4,)),      # Cerebras'
+    (16, 128, 16, "bfloat16", False, True, (4,)),     # chip_smoke's model
+    (32, 128, 128, "bfloat16", False, False, (4, 3)),  # the hybrid's: 1 MB
+    (16, 64, 32, "bfloat16", False, False, (4, 3)),   # heads of 64 lanes
+    (4, 8, 8, "float32", False, False, (4, 3)),       # the toy step, compiled
+    (4, 8, 8, "float32", True, False, (4,)),          # interpreted: every shape
+    (32, 128, 128, "bfloat16", True, False, (4,)),
+], ids=["olmoe", "cerebras", "smoke", "hybrid-1MB-pages", "heads-of-64",
+        "toy-compiled", "toy-interpreted", "hybrid-interpreted"])
+def test_paged_attention_walks_where_walk_fits_holds(
+        monkeypatch, H, D, page, dtype, interpret, fits, grid):
+    """Which kernel the step's ungrouped row runs follows from the
+    pool's shape and dtype alone (``walk_fits``): one grid step a slot
+    where the compiled walk takes the pages, a grid step a (slot, table
+    column) where it refuses them; interpreted, every shape walks.  Both
+    under the one name ``ragged_paged_attention`` and one dispatch
+    decision."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import attention as A
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", interpret)
+    S, N, P = 4, 9, 3
+    dt = jnp.dtype(dtype)
+    assert A.walk_fits(dt, page, H, D) == fits
+    sds = jax.ShapeDtypeStruct
+    counter = pk._M_DISPATCH
+    path = "interpret" if interpret else "compiled"
+    before = counter.value(kernel="ragged_paged_attention", path=path)
+    jaxpr = jax.make_jaxpr(lambda *a: A.paged_attention(*a))(
+        sds((S, H, D), dt), sds((N, page, H, D), dt),
+        sds((N, page, H, D), dt), sds((S, P), jnp.int32),
+        sds((S,), jnp.int32))
+    assert counter.value(kernel="ragged_paged_attention",
+                         path=path) == before + 1
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert [(c.params["name"], c.params["grid_mapping"].grid)
+            for c in calls] == [("ragged_paged_attention", grid)]
+
+
 def test_dense_prefill_attention_causal_reference():
     import jax
     import jax.numpy as jnp
