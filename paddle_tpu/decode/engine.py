@@ -182,8 +182,10 @@ class GenerationEngine:
         delivers step k's tokens, so the handler threads those tokens
         wake take the interpreter lock while the device computes, and
         this thread next lets go of it in the following tick's collect,
-        waiting for that step.  Between ticks a step may be in flight:
-        the session is not idle then, and ``fail_all`` drops it."""
+        waiting for that step.  Between ticks a step may be in flight,
+        or two (a full batch in which nothing can change: the session's
+        docstring): the session is not idle then, and ``fail_all``
+        drops them."""
         while not self._stop.is_set():
             if self.session.idle():
                 # the device is idle because no request is there

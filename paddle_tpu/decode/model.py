@@ -350,8 +350,9 @@ class StepInFlight:
     what the device holds of them for the step after this one (a decode
     step's ids, the tables it was given, ``lens + live``); any of them
     can be handed to the next ``step_dispatch`` in place of the host's
-    array, which then uploads nothing for it.  None after a verify
-    step, whose accepted counts only the host knows."""
+    array, which then uploads nothing for it, before this step has been
+    collected too.  None after a verify step, whose accepted counts
+    only the host knows."""
 
     __slots__ = ("next", "_pools_in", "_logits", "_ids", "_report")
 
@@ -416,7 +417,9 @@ class PagedDecoderLM:
         the session.  A step's wait comes in a later call than its
         dispatch (``step_collect``) and is rounded again, with the
         buffers the dispatch was handed (``pools_in``); no program runs
-        between the two."""
+        between the two but a step dispatched behind it, which that
+        failure takes with it (the session drops it: it ran on the
+        failed step's pools)."""
         pools_in = pools_in or self._cache()
         try:
             yield
@@ -599,8 +602,12 @@ class PagedDecoderLM:
         of ``tokens`` (S, 1), ``tables`` and ``lens`` is the host's
         numpy array, or what the previous step's ``next`` holds of it
         on the device: a steady tick hands in all three of those and
-        uploads nothing.  No other program of this model may be called
-        until ``step_collect`` has been."""
+        uploads nothing.  Until ``step_collect`` has been called on the
+        step, the one program of this model that may be is another
+        ``step_dispatch`` fed by this step's ``next`` alone (the device
+        runs the two back to back; nothing of ``next`` is donated, so
+        this step's ids stay readable); the steps are collected in the
+        order of their dispatch."""
         if isinstance(tokens, np.ndarray):
             tokens = tokens[:, 0]
         return self._dispatch(_decode_step, tokens, tables, lens)

@@ -11,11 +11,12 @@ device's step:
 
 1. **collect**: wait for the ids of the step that is in flight, if one
    is.
-2. **decide**: per live slot, from the ids: deadline, the next token,
-   whether the sequence ends (EOS or token budget).  A slot that ends
-   is cleared here — its lane's table row nulled, its pages back on the
-   allocator's free list — so a sequence that ended at step *k* is
-   never live in step *k+1*.  Nothing is emitted yet.
+2. **decide**: per live slot, from the ids: deadline, cancel, the next
+   token, whether the sequence ends (EOS or token budget).  A slot that
+   ends is cleared here — its lane's table row nulled, its pages back
+   on the allocator's free list — so a sequence that ended at step *k*
+   is never live in a step dispatched after that.  Nothing is emitted
+   yet.
 3. **sweep, admit, copy-on-write**: pending requests claim open slots
    while the page pool can hold their whole context (prompt + every
    token they may generate — reserved up front, so a running sequence
@@ -31,6 +32,30 @@ device's step:
 A model without the two halves (``PagedSeq2SeqModel``, a wrapper that
 overrides ``decode``) runs ``decode`` whole where 4 is, followed by 1,
 2 and 5 at once; so does a speculative tick with ``verify_chunk``.
+
+**Up to two steps are in flight.**  Between the device's last op of
+step *k+1* and the first of step *k+2* lie the ids' way to the host and
+the host's decide and dispatch: a gap of 2-3 ms a tick in which the
+chip does nothing.  So after 4 the tick dispatches step *k+2* behind
+step *k+1*, fed by nothing but what that step hands on
+(``StepInFlight.next``), whenever no row can change in between
+(``_ahead_held``): every slot holds a live sequence that takes the
+device's own token, none reaches its budget in the step in flight,
+nothing the host wrote is missing on the device and no page the next
+row lands in is shared.  No admission is possible before step *k+1*
+lands then, queued step or not, so the step behind delays nobody.  The
+next tick collects the OLDEST flight (1, 2), finds one still in flight,
+skips 3 but for the sweep, which is the queue's (no prefill or page
+copy runs under a step: the host's mirror of the lanes is exact only
+with nothing in flight), dispatches the next one behind it if the
+condition still holds, and delivers.
+When the condition fails nothing is queued, the flights drain, and the
+tick in which none is left is the tick above.  What the condition
+cannot see is absorbed: a slot that ends by EOS, deadline or cancel
+while a step is queued behind has one junk row computed into pages that
+still held the room, its lane is skipped when that step is decided, the
+cleared lane marks the arrays stale so nothing more is queued, and its
+slot is seated again in the tick in which nothing is in flight.
 
 **The tick keeps its own account, in every run** (``TickAccount``).
 The statement that opens a phase's span (``observability.phase``, here
@@ -107,7 +132,9 @@ _M_STEP_SEC = _metrics.histogram(
     "decode_step_seconds",
     "one batched decode step from its dispatch to its ids on the host "
     "(over a model that steps in two halves that is two ticks' time: "
-    "the delivery of the step before runs inside it)")
+    "the delivery of the step before runs inside it); a step dispatched "
+    "behind another from the landing of the one in front, so one step "
+    "still")
 _M_STEP_INPUTS = _metrics.counter(
     "decode_step_inputs_total",
     "decode and verify steps dispatched, by where their tables, lengths "
@@ -118,6 +145,22 @@ _M_DELIVERIES = _metrics.counter(
     "deliveries of one step's tokens to their requests, by what the "
     "device was doing meanwhile: `step` = the next step was in flight, "
     "`nothing` = no step was")
+_M_DISPATCHES = _metrics.counter(
+    "decode_step_dispatch_total",
+    "decode steps dispatched over a model that steps in two halves, by "
+    "what was in flight at the dispatch: `step` = the step before, not "
+    "yet collected (the device runs the two back to back), `nothing` = "
+    "no step was (the device waits for this dispatch)")
+# `why` of decode_ahead_held_total, in the order the tick asks
+AHEAD_HELD = ("draft", "free_slot", "budget", "stale", "host_choice", "cow")
+_M_AHEAD_HELD = _metrics.counter(
+    "decode_ahead_held_total",
+    "ticks that had a step in flight and dispatched none behind it, by "
+    "the first condition that failed: `draft` = a speculative session, "
+    "`free_slot` = a slot holds no live sequence, `budget` = a slot's "
+    "last token is the flight's, `stale` = the host changed a row the "
+    "device lacks, `host_choice` = a slot samples or is a beam's, `cow` "
+    "= the row after the flight's lands in a shared page")
 _M_PREFILL_SEC = _metrics.histogram(
     "decode_prefill_seconds", "wall time per sequence prefill (admission)")
 _M_TTFT = _metrics.histogram(
@@ -204,7 +247,8 @@ class TickAccount:
     the ``phase`` statements that run while it is open on the thread),
     the admissions it seated, by prefill bucket (how many, the
     slot-seconds they held the seated slots still, on real rows and on
-    padding), where its step's inputs came from and under what
+    padding), where its steps' inputs came from, behind what they were
+    dispatched (or why none was, behind a flight) and under what
     its deliveries ran.  Also the judge of a slow tick.  The families
     live in ``registry`` (the process's unless given: the overhead probe
     gives its own).  ``packed_rows``: the model's
@@ -264,6 +308,8 @@ class TickAccount:
         self._m_inputs = reg.counter(_M_STEP_INPUTS.name, _M_STEP_INPUTS.help)
         self._m_deliveries = reg.counter(_M_DELIVERIES.name,
                                          _M_DELIVERIES.help)
+        self._m_behind = reg.counter(_M_DISPATCHES.name, _M_DISPATCHES.help)
+        self._m_held = reg.counter(_M_AHEAD_HELD.name, _M_AHEAD_HELD.help)
         self._m_slow = reg.counter(
             "decode_slow_ticks_total",
             "ticks that lasted over SLOW_TICK_FACTOR times the running "
@@ -277,6 +323,8 @@ class TickAccount:
         self._k_admissions = [key(n=n) for n in ("0", "1", "2", "3", "4+")]
         self._k_inputs = [key(source="resident"), key(source="uploaded")]
         self._k_under = [key(under="step"), key(under="nothing")]
+        self._k_behind = [key(behind="nothing"), key(behind="step")]
+        self._k_held = {why: key(why=why) for why in AHEAD_HELD}
         # a bucket -> its keys: admissions, stalled on real rows, on pad
         self._k_bucket: dict = {}
         self._k_tick_rows = [key(kind="run"), key(kind="packed")]
@@ -295,6 +343,8 @@ class TickAccount:
         self.run = self.rows = 0    # the seated ones' buckets, prompts
         self.inputs = [0, 0]                # steps: resident, uploaded
         self.under = [0, 0]                 # deliveries: step, nothing
+        self.behind = [0, 0]                # dispatches behind: nothing, step
+        self.held: Optional[str] = None     # why none went behind a flight
 
     def admitted(self, admit: phase, live_before: int, seated: bool) -> None:
         """One ``decode.admit`` ended: whatever came of it, the slots
@@ -346,9 +396,12 @@ class TickAccount:
             self._m_slot_seconds.inc(total * live)
         for family, keys, counts in (
                 (self._m_inputs, self._k_inputs, self.inputs),
-                (self._m_deliveries, self._k_under, self.under)):
+                (self._m_deliveries, self._k_under, self.under),
+                (self._m_behind, self._k_behind, self.behind)):
             if counts[0] or counts[1]:
                 family.inc_many([kv for kv in zip(keys, counts) if kv[1]])
+        if self.held is not None:
+            self._m_held.inc_many(((self._k_held[self.held], 1),))
         mean = self._mean[admitting]
         if total > SLOW_TICK_FLOOR_S and (
                 mean is None or total > SLOW_TICK_FACTOR * mean):
@@ -510,7 +563,8 @@ class DecodeRequest:
 
     def cancel(self) -> None:
         """Consumer-side abandon (disconnected stream): flag the
-        request; the stepper evicts the slot and frees its pages at the
+        request; the stepper evicts the slot and frees its pages where
+        its next step is decided, or drops it from the queue at the
         next tick (never cross-thread surgery on live slot state)."""
         self.cancelled = True
 
@@ -572,14 +626,19 @@ class _BeamGroup:
 class _StepInputs:
     """What a decode step reads of every lane — page tables, lengths,
     the token to feed — twice: the host's mirror (numpy, exact whenever
-    no step is in flight) and, over a model that steps in two halves,
-    what the device holds since the last dispatch (``resident``:
-    ``StepInFlight.next``, opaque here).  ``stale`` names the arrays in
-    which the host has changed a row that the device's copy lacks:
-    every write goes through a method that says so, except what a step
-    itself produces (its ids as the next tokens, the live lanes'
-    lengths plus one: ``from_step``).  The device-resident slot state of the session is this
-    object and nothing else."""
+    no step is in flight: under one it lacks what that step will
+    produce, so nothing reads it then) and, over a model that steps in
+    two halves, what the device holds since the last dispatch
+    (``resident``: the newest ``StepInFlight.next``, opaque here).
+    ``stale`` names the arrays in which the host has changed a row that
+    the device's copy lacks: every write goes through a method that
+    says so, except what a step itself produces (its ids as the next
+    tokens, the live lanes' lengths plus one: ``from_step``).  With
+    nothing stale the next step's three inputs are ``resident`` as it
+    stands, whether the step that hands them on has landed or not: that
+    is what a step dispatched behind another is fed.  The
+    device-resident slot state of the session is this object and
+    nothing else."""
 
     NAMES = ("tokens", "tables", "lens")
     __slots__ = NAMES + ("stale", "resident", "_pad")
@@ -664,9 +723,15 @@ class _Outbox:
 
 
 class _Flight:
-    """The step in flight: the model's handle, the lanes that were live
-    when it was dispatched (fixed until it lands: no row changes under
-    a step in flight) and when that was."""
+    """A step in flight: the model's handle, the lanes that were live
+    when it was dispatched and when that was (``t0``; for a step
+    dispatched behind another, when the one in front landed: what
+    ``decode_step_seconds`` measures from).  The session holds at most
+    two, oldest first, and no prefill or page copy runs while it holds
+    any: the host changes a row under a step in
+    flight only by ending a sequence at the decide of the step in front
+    (EOS, a deadline, a cancel), which the step behind absorbs (the
+    module's docstring)."""
 
     __slots__ = ("step", "lanes", "t0")
 
@@ -773,7 +838,7 @@ class DecodeSession:
         self._two_halves = all(
             callable(getattr(type(model), half, None))
             for half in ("step_dispatch", "step_collect"))
-        self._flight: Optional[_Flight] = None
+        self._flights: List[_Flight] = []       # at most two, oldest first
         self._outbox = _Outbox()
         self._account = TickAccount(
             packed_rows=getattr(model, "packed_prefill_rows", None))
@@ -852,7 +917,7 @@ class DecodeSession:
 
     def idle(self) -> bool:
         with self._lock:
-            return (not self._pending and self._flight is None
+            return (not self._pending and not self._flights
                     and all(s is None for s in self._slots))
 
     @property
@@ -868,11 +933,13 @@ class DecodeSession:
         """One tick: collect -> decide -> sweep, admit, copy-on-write ->
         dispatch -> deliver (the module's docstring; a model without
         the two halves decodes whole where the dispatch is and is
-        collected, decided and delivered at once).  Returns the number
-        of slots that were live in the step this tick dispatched (0 =
-        nothing dispatched).  A step that *raises*, at its dispatch or
-        at its collect, is contained (``_contain_step_failure``): the
-        slots that were in the batch are evicted — first offense
+        collected, decided and delivered at once; with a second step
+        still in flight after the collect only the queue is swept,
+        nothing is seated or split).  Returns the number of slots that
+        were live in the step this tick dispatched last (0 = nothing
+        dispatched).  A step that *raises*, at its dispatch or at its
+        collect, is contained (``_contain_step_failure``): the slots
+        that were in the batch are evicted — first offense
         requeued to retry from scratch, second offense quarantined with
         503 ``step_failed`` — and the stepper thread lives on.  So is a
         copy-on-write split that lost the model's pools (``PoolsLost``;
@@ -883,7 +950,7 @@ class DecodeSession:
             self._gc_mark = _GC[0]
         self._close_between()
         live = [s.req.rid for s in self._slots if s is not None]
-        waiting, in_flight = len(self._pending), self._flight is not None
+        waiting, in_flight = len(self._pending), bool(self._flights)
         tick = phase("decode.tick", active=len(live), waiting=waiting,
                      rids=",".join(map(str, live)))
         try:
@@ -934,11 +1001,31 @@ class DecodeSession:
                 gauge()
 
     def _tick(self) -> int:
-        if self._flight is not None:
+        flights = self._flights
+        if flights:
             self._collect()
-        with phase("decode.sweep"):
+        with phase("decode.sweep"):     # the queue's: it touches no row
             self._sweep_cancelled()
             self._sweep_expired()
+        n = 0
+        if not flights:
+            n = self._step_on_settled_rows()
+        if flights:
+            # one step is in flight, left by the collect or dispatched
+            # just now: the next goes behind it if no row can change
+            # before it lands
+            lanes = flights[-1].lanes
+            why = self._ahead_held()
+            if why is not None:
+                self._account.held = why
+            elif self._dispatch_step(lanes):
+                n = len(lanes)
+        return n
+
+    def _step_on_settled_rows(self) -> int:
+        """With no step in flight: admit, copy-on-write, and the step
+        over what is seated then (dispatched; whole for a model without
+        the halves and for a verify chunk) -> its live slots."""
         self._admit()
         active_idx = [i for i, s in enumerate(self._slots) if s is not None]
         if not active_idx:
@@ -958,41 +1045,102 @@ class DecodeSession:
                           if self._slots[i] is not None]
             if not active_idx:
                 return 0
+        if self._two_halves:
+            self._dispatch_step(active_idx)
+            return len(active_idx)
         inputs = self._inputs
         t0 = time.perf_counter()
         try:
             with span("decode.step"):
-                if self._two_halves:
-                    tokens, tables, lens, uploaded = inputs.for_dispatch()
-                    step = self.model.step_dispatch(
-                        tokens, self._states, tables, lens)
-                else:
-                    uploaded = True
-                    landed = self.model.decode(
-                        inputs.tokens, self._states, inputs.tables,
-                        inputs.lens)
+                landed = self.model.decode(
+                    inputs.tokens, self._states, inputs.tables, inputs.lens)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(active_idx, exc)
             return len(active_idx)
-        self._account.inputs[bool(uploaded)] += 1
-        if self._two_halves:
-            inputs.dispatched(step.next)
-            self._flight = _Flight(step, active_idx, t0)
-        else:
-            self._landed(active_idx, *landed, t0=t0)
+        self._account.inputs[1] += 1
+        self._landed(active_idx, *landed, t0=t0)
         return len(active_idx)
 
+    def _dispatch_step(self, lanes: List[int]) -> bool:
+        """``step_dispatch`` over what the device holds, with what is
+        stale uploaded: the flight joins the others.  False when the
+        dispatch raised (contained: every flight is dropped)."""
+        inputs = self._inputs
+        behind = bool(self._flights)
+        t0 = time.perf_counter()
+        try:
+            with span("decode.step"):
+                tokens, tables, lens, uploaded = inputs.for_dispatch()
+                step = self.model.step_dispatch(
+                    tokens, self._states, tables, lens)
+        except BaseException as exc:  # noqa: BLE001 - contained per slot
+            self._contain_step_failure(lanes, exc)
+            return False
+        self._account.inputs[bool(uploaded)] += 1
+        self._account.behind[behind] += 1
+        inputs.dispatched(step.next)
+        self._flights.append(_Flight(step, lanes, t0))
+        return True
+
+    def _ahead_held(self) -> Optional[str]:
+        """Why no step may be dispatched behind the one in flight, the
+        first reason of ``AHEAD_HELD`` that holds, or None: then no row
+        can change before the flight lands, and the step after it reads
+        exactly what the flight hands on.  All of it is the session's
+        own state at this moment.
+
+        - A slot is seated only when one is free, and a slot comes free
+          by its budget, which the host counts ahead (``new_tokens``
+          lacks the flight's token): with every slot live and none at
+          its budget no admission can fall before the flight lands.
+        - Nothing stale and ``resident`` the flight's ``next``: the
+          tables are the device's, the lengths ``lens + live`` and the
+          tokens the flight's ids, none of which the host has to see.
+        - No slot's token is chosen on the host from the logits.
+        - The row after the flight's (``ctx_len`` lacks the flight's)
+          lands in a page that is the sequence's alone; its pages hold
+          the room, the budget not being reached.
+
+        EOS, a deadline and a cancel show only when the flight is
+        decided: the step behind absorbs them (the module's
+        docstring)."""
+        if self._spec_draft is not None:
+            return "draft"
+        slots = self._slots
+        if any(s is None or s.dead for s in slots):
+            return "free_slot"
+        if any(s.new_tokens + 1 >= s.req.max_new_tokens for s in slots):
+            return "budget"
+        inputs = self._inputs
+        handed_on = self._flights[-1].step.next
+        if (inputs.stale or handed_on is None
+                or inputs.resident is not handed_on):
+            return "stale"
+        if any(s.group is not None or s.req.temperature for s in slots):
+            return "host_choice"
+        if self.model.grows_kv:
+            ps, shared = self.model.page_size, self.model.allocator.is_shared
+            for s in slots:
+                pi = (s.ctx_len + 1) // ps
+                if pi < len(s.pages) and shared(s.pages[pi]):
+                    return "cow"
+        return None
+
     def _collect(self) -> None:
-        """The step in flight lands: its ids reach the host and its
-        token choices go into the outbox.  A failure on the device shows
-        here, and is contained over the lanes the step was dispatched
-        with."""
-        flight, self._flight = self._flight, None
+        """The oldest step in flight lands: its ids reach the host and
+        its token choices go into the outbox.  A failure on the device
+        shows here, and is contained over the lanes the step was
+        dispatched with; a step queued behind it, which ran on the
+        failed one's pools and over the same lanes, is dropped with
+        it."""
+        flight = self._flights.pop(0)
         try:
             landed = self.model.step_collect(flight.step)
         except BaseException as exc:  # noqa: BLE001 - contained per slot
             self._contain_step_failure(flight.lanes, exc)
             return
+        if self._flights:       # the step behind is the device's from here
+            self._flights[0].t0 = time.perf_counter()
         self._landed(flight.lanes, *landed, t0=flight.t0)
 
     def _landed(self, lanes: List[int], logits, new_states, *, t0: float,
@@ -1007,7 +1155,9 @@ class DecodeSession:
         if self.model.grows_kv and drafts is None:
             for i in lanes:
                 slot = self._slots[i]
-                if not slot.dead:
+                # None: ended at the decide of the step in front, while
+                # this one was queued behind it
+                if slot is not None and not slot.dead:
                     slot.ctx_len += 1
                     self._inputs.length(i, slot.ctx_len, from_step=True)
         self._outbox.decided = True
@@ -1016,9 +1166,9 @@ class DecodeSession:
 
     def _decide(self, lanes: List[int], logits,
                 drafts: Optional[dict] = None) -> None:
-        """The token choice of one landed step, lane by lane: expiry,
-        the next token(s), and whether the sequence ends; a slot that
-        ends is cleared here, before the next dispatch.  Emission and
+        """The token choice of one landed step, lane by lane: expiry
+        and cancel, the next token(s), and whether the sequence ends; a
+        slot that ends is cleared here, before the next dispatch.  Emission and
         the requests' finish wait in the outbox for ``_deliver``.  A
         greedy slot takes the token the step chose on the device where
         the logits carry ``ids``; a slot that samples and a beam group
@@ -1049,6 +1199,10 @@ class DecodeSession:
                     self._finish_group(g, "deadline", TimeoutError(
                         "generation deadline expired"), out)
                     continue
+                if g.req.cancelled:
+                    _M_CANCELLED.inc()
+                    self._finish_group(g, "cancelled", out=out)
+                    continue
                 self._group_select(
                     g, logits[np.asarray(g.slot_idx, np.intp)], out)
                 out.on_host += 1
@@ -1056,6 +1210,10 @@ class DecodeSession:
             if slot.req.expired(now):
                 self._evict(i, "deadline",
                             TimeoutError("generation deadline expired"), out)
+                continue
+            if slot.req.cancelled:     # the consumer is gone
+                _M_CANCELLED.inc()
+                self._evict(i, "cancelled", out=out)
                 continue
             on_device = ids is not None and not slot.req.temperature
             if drafts is not None:
@@ -1100,7 +1258,7 @@ class DecodeSession:
                 _M_CHOICE.inc(out.on_device, where="device")
             if out.on_host:
                 _M_CHOICE.inc(out.on_host, where="host")
-            self._account.under[self._flight is None] += 1
+            self._account.under[not self._flights] += 1
         out.reset()
 
     def run(self, max_steps: Optional[int] = None) -> None:
@@ -1353,6 +1511,9 @@ class DecodeSession:
         sequence, whichever program failed, and drops the prefix index:
         no page holds the rows it was filled with."""
         _M_STEP_FAIL.inc()
+        # a step still in flight ran on the failed one's pools, or was
+        # fed by it: dropped, its results never read
+        self._flights.clear()
         # what a landed step chose goes out before its requests are
         # sent back to start again
         self._deliver()
@@ -1401,14 +1562,11 @@ class DecodeSession:
         _M_ACTIVE.set(self.active)
 
     def _sweep_cancelled(self) -> None:
-        """Evict slots whose consumer abandoned them (dead streaming
-        socket) and drop cancelled waiters — pages and queue capacity
-        come back immediately instead of after max_new_tokens."""
-        for i, slot in enumerate(self._slots):
-            if (slot is not None and slot.req.cancelled
-                    and not slot.req.done):
-                _M_CANCELLED.inc()
-                self._evict(i, "cancelled")
+        """Drop the waiters whose consumer abandoned them (dead
+        streaming socket): queue capacity comes back at once.  A seated
+        one leaves where its step is decided, as one past its deadline
+        does (``_decide``): pages come back after that step instead of
+        after max_new_tokens."""
         with self._lock:
             live, dead = [], []
             for req in self._pending:
@@ -1600,9 +1758,9 @@ class DecodeSession:
         self._finish(slot.req, reason, error, out)
 
     def fail_all(self, exc: BaseException) -> None:
-        """Shutdown: fail every live and queued request; a step in
-        flight is dropped, its results never read."""
-        self._flight = None
+        """Shutdown: fail every live and queued request; the steps in
+        flight are dropped, their results never read."""
+        self._flights.clear()
         self._close_between()
         self._inputs.forget()
         with self._lock:

@@ -741,6 +741,20 @@ PARENT_BEAMS = [(-25.036277770996094, [53] * 7),
                 (-25.228370666503906, [6] + [53] * 6),
                 (-25.4178466796875, [53] * 6 + [42])]
 PARENT_BESIDE_THE_BEAM = [3, 3, 3, 26, 26, 26, 26, 26, 26]
+# what the parent commit of ISSUE 61 (9cc8220) streamed: five greedy
+# requests with co-prime budgets over three lanes, and three over two
+# lanes, the first ending on the EOS at its fourth token
+PARENT_FULL_BATCH = [[8] * 7, [6] * 11, [53, 53] + [7] * 11, [58] * 5,
+                     [9, 9, 9] + [22] * 6]
+PARENT_EOS_QUEUED_BEHIND = [[6, 6, 6, 0], [3] * 7 + [42] * 3, [4] * 6]
+
+
+def _ahead_counts():
+    from paddle_tpu.decode.session import _M_AHEAD_HELD, _M_DISPATCHES
+
+    return ({b: _M_DISPATCHES.value(behind=b) for b in ("nothing", "step")},
+            {w: _M_AHEAD_HELD.value(why=w)
+             for w in ("free_slot", "budget", "stale")})
 
 
 def _in_flight_case(what):
@@ -757,7 +771,8 @@ def _in_flight_case(what):
     a = sess.submit(DecodeRequest(list(PROMPT), max_new_tokens=9))
     b = sess.submit(DecodeRequest([2, 3, 4, 5, 6], max_new_tokens=9))
     sess.step(), sess.step()
-    assert sess._flight is not None and len(a.tokens) == 2
+    # the batch is full: steps 2 and 3 are in flight, 3 behind 2
+    assert len(sess._flights) == 2 and len(a.tokens) == 2
     if what == "cancel":
         a.cancel()
     else:
@@ -775,7 +790,8 @@ def _in_flight_case(what):
 
 @pytest.mark.parametrize("case", [
     "greedy", "sampling_and_eos_mid_stream", "beam", "prefix_cache_hit",
-    "speculative", "seq2seq", "cancel_in_flight", "deadline_in_flight"])
+    "speculative", "seq2seq", "cancel_in_flight", "deadline_in_flight",
+    "greedy_full_batch_a_step_ahead", "eos_with_a_step_queued_behind"])
 def test_the_new_tick_order_gives_the_parents_tokens(case):
     from paddle_tpu.decode.prefix import PrefixCache
     from paddle_tpu.decode.session import (BeamRequest, DecodeRequest,
@@ -788,7 +804,7 @@ def test_the_new_tick_order_gives_the_parents_tokens(case):
         sess.run(500)
         for r in reqs:
             assert r.wait(5) and r.error is None, r.error
-        assert sess._flight is None and sess.idle()
+        assert not sess._flights and sess.idle()
         return [list(r.tokens) for r in reqs]
 
     if case in ("cancel_in_flight", "deadline_in_flight"):
@@ -818,7 +834,39 @@ def test_the_new_tick_order_gives_the_parents_tokens(case):
         finally:
             engine.stop()
         return
-    if case == "greedy":
+    if case == "greedy_full_batch_a_step_ahead":
+        lm = _mk(seed=5)
+        prompts = [PROMPT, [2, 3, 4, 5, 6], [9, 8, 7, 1, 2, 3, 4],
+                   PROMPT[3:], [4, 4, 2, 9]]
+        budgets = [7, 11, 13, 5, 9]
+        before = _ahead_counts()
+        got = run(DecodeSession(lm, max_slots=3),
+                  [DecodeRequest(list(p), max_new_tokens=b)
+                   for p, b in zip(prompts, budgets)])
+        assert got == PARENT_FULL_BATCH
+        assert got == [lm.dense_greedy(p, b)
+                       for p, b in zip(prompts, budgets)]
+        behind, held = _ahead_counts()
+        # the path was engaged, and let go at every budget's end
+        assert behind["step"] - before[0]["step"] >= 8
+        assert held["budget"] - before[1]["budget"] >= 2
+    elif case == "eos_with_a_step_queued_behind":
+        lm = _mk(seed=3)
+        prompts = [[2, 3, 4, 5, 6], PROMPT, [9, 8, 7, 1, 2, 3, 4]]
+        sess = DecodeSession(lm, max_slots=2)
+        reqs = [DecodeRequest(list(p), max_new_tokens=b)
+                for p, b in zip(prompts, (12, 10, 6))]
+        for r in reqs:
+            sess.submit(r)
+        for _ in range(3):
+            sess.step()
+        # step 3, which chooses the first one's EOS, is in flight with
+        # step 4 queued behind it
+        assert len(sess._flights) == 2 and reqs[0].tokens == [6, 6, 6]
+        got = run(sess, [])
+        assert [list(r.tokens) for r in reqs] == PARENT_EOS_QUEUED_BEHIND
+        assert reqs[0].finish_reason == "eos" and got == []
+    elif case == "greedy":
         lm = _mk(seed=5)
         prompts = [PROMPT, [2, 3, 4, 5, 6], [9, 8, 7, 1, 2, 3, 4]]
         got = run(DecodeSession(lm, max_slots=2),     # 3 requests, 2 lanes

@@ -422,7 +422,11 @@ def test_decode_request_failing_twice_is_quarantined_503():
 
 class _LostAtTheWait:
     """In place of a step's ids: the dispatch went through, the wait
-    for the result is where the device's failure shows."""
+    for the result is where the device's failure shows.  ``ids``: what
+    a step dispatched behind this one is fed, as the device would."""
+
+    def __init__(self, ids):
+        self.ids = ids
 
     def copy_to_host_async(self):
         pass
@@ -442,13 +446,14 @@ def _lose_pools_on_next_call(monkeypatch, name, at="call"):
     real, failed = getattr(dm, name), []
 
     def program(*args, **kw):
+        args = [a.ids if isinstance(a, _LostAtTheWait) else a for a in args]
         if not failed:
             failed.append(True)
             first = 0 if name == "_copy_pools_page" else 1
             if at == "wait":
-                logits, k, v, report, _, *more = real(*args, **kw)
+                logits, k, v, report, ids, *more = real(*args, **kw)
                 assert all(p.is_deleted() for p in args[first:first + 2])
-                return (logits, k, v, report, _LostAtTheWait(), *more)
+                return (logits, k, v, report, _LostAtTheWait(ids), *more)
             for pool in args[first:first + 2]:
                 pool.delete()
             raise RuntimeError("injected: the device halted")
@@ -467,7 +472,8 @@ def _paged_lm(seed):
 
 @pytest.mark.parametrize("program", [
     "_decode_step", "_decode_step:wait", "_decode_step:wait+admission",
-    "_prefill_bucket", "_prefill_chunk", "_copy_pools_page"])
+    "_decode_step:wait+queued", "_prefill_bucket", "_prefill_chunk",
+    "_copy_pools_page"])
 def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     """The pools are made anew and counted once; every seated sequence
     goes back by the strike rule and completes from a fresh prefill with
@@ -475,7 +481,10 @@ def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     served from a page whose rows are gone; every page comes back.  A
     decode step fails at its dispatch or, ``:wait``, at the collect a
     tick later — there also with a request that arrived while the step
-    was in flight and is admitted in the tick that contains it."""
+    was in flight and is admitted in the tick that contains it, and
+    (``+queued``: two lanes, both seated) with the next step queued
+    behind it on the pools it handed on: that one is dropped, never
+    collected, and the pools are still made anew once."""
     from paddle_tpu.decode import model as dm
     from paddle_tpu.decode.paged_kv import PoolsLost
     from paddle_tpu.decode.prefix import PrefixCache
@@ -485,7 +494,8 @@ def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     program, _, at = program.partition(":")
     lm = _paged_lm(11)
     cache = PrefixCache(lm.allocator, lm.page_size)
-    sess = DecodeSession(lm, max_slots=4, prefix_cache=cache)
+    sess = DecodeSession(lm, max_slots=2 if at.endswith("queued") else 4,
+                         prefix_cache=cache)
     shared = [1, 5, 9, 3, 7, 2, 8, 4]              # two full pages
     p_a, p_b, p_c = shared + [6], shared + [11, 12], [1, 13, 14]
     want = {tuple(p): lm.dense_greedy(p, 5) for p in (p_a, p_b, p_c)}
@@ -516,9 +526,13 @@ def test_program_that_consumed_its_pools_and_failed(monkeypatch, program):
     late = None
     if at.endswith("admission"):
         sess.step()                     # the doomed step is in flight
-        assert old[0].is_deleted() and sess._flight is not None
+        assert old[0].is_deleted() and len(sess._flights) == 1
         late = sess.submit(DecodeRequest(p_c + [2], max_new_tokens=4))
         want_late = lm.dense_greedy(p_c + [2], 4)
+    if at.endswith("queued"):
+        sess.step()         # the doomed step, and the next behind it
+        assert old[0].is_deleted() and len(sess._flights) == 2
+        assert sess._flights[1].step._pools_in[0].is_deleted()
     sess.run(max_steps=100)
     if late is not None:
         assert late.result(0) == want_late and late.step_failures == 0

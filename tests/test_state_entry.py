@@ -213,14 +213,17 @@ def test_pools_lost_rebuilds_pages_and_states_together(hybrid, monkeypatch):
     """A decode step that fails after consuming its donated buffers:
     all four are made anew, counted once, the seated sequences go back
     and complete with the oracle's tokens, and every page and entry
-    comes back."""
+    comes back.  Both lanes are seated, so steps 1 and 2 are in flight
+    after the first tick: the step that fails is the third, at its
+    dispatch behind the second, which is dropped with it."""
     m = hybrid.make()
     session = DecodeSession(m, max_slots=2)
     prompts = [prompt(9, 70), prompt(14, 71)]
-    want = [greedy_by_reference(m, p, 3) for p in prompts]
-    reqs = [session.submit(DecodeRequest(p, max_new_tokens=3))
+    want = [greedy_by_reference(m, p, 4) for p in prompts]
+    reqs = [session.submit(DecodeRequest(p, max_new_tokens=4))
             for p in prompts]
     session.step()
+    assert len(session._flights) == 2
     real, failed = dm._decode_step, []
 
     def program(params, k_pool, v_pool, *args, extra, **kw):
