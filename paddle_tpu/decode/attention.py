@@ -86,6 +86,18 @@ def fits(page_size: int, num_heads: int, head_dim: int,
     two batched dots take as it lies: ``heads_major``
     (``models/phi4_flash.py``).
 
+    K and V need not be one width.  A model whose keys are wider than
+    its values (``models/mimo_v2.py``: keys of 192, values of 128) hands
+    the chunk kernels K pages and V pages of their own last dimensions:
+    each pool's buffers are its own pages', and the accumulator and the
+    output are the values' wide (PR 62).  A key of 192 lanes is no whole
+    tile, though: stored as published the compiled walk cannot copy its
+    page (``walk_fits``), the step falls to the gathered reference and
+    the compiler re-lays the whole K pool out for the gather (19.9 GB
+    asked of the chip's 15.75: ``tests/test_chip_compile_mimo.py``); so
+    that model stores a key at 256 lanes, zeros behind the 192, pads q
+    to match and passes the scores' scale itself.
+
     Nor do these kernels take a latent layer's pages: one row a token
     whose 576 numbers are the key and whose first 512 are the value, K
     and V from one buffer at ``D > 256``.  That is
@@ -137,8 +149,11 @@ def walk_fits(dtype, page_size: int, kv_heads: int, head_dim: int,
     and Mosaic takes that slice only of pages that are whole tiles where
     they lie (PR 58's probe on a described v5e; the ``(S, P)`` grid took
     every shape below through a BlockSpec, with the compiler's copies of
-    the pool round it that ``fits`` tells of).  Rows of whole 128-lane
-    tiles (64, 192: refused); a page's second-minor extent (its heads,
+    the pool round it that ``fits`` tells of).  ``head_dim``: the lanes
+    a K page's row is STORED at (the wider pool's, where V is narrower:
+    its pages are then the smaller and fit whenever K's do).  Rows of
+    whole 128-lane tiles (64, 192: refused; a model with keys of 192
+    stores them at 256); a page's second-minor extent (its heads,
     or its rows ``heads_major``) a multiple of 8 or a power of two that
     fills a 4-byte sublane (10 or 30 bfloat16 heads inside a page's
     rows: refused; heads-major: taken); and the two double buffers,
@@ -592,7 +607,9 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
                 G, heads_major=False, window=None, step=False):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
     the whole chunk resident in the q/o blocks.  A page is (page, H, D),
-    or (H, page, D) ``heads_major``.
+    or (H, page, D) ``heads_major``; a V page may be narrower than a K
+    page, ``(.., Dv)``: the accumulator and the output are the values'
+    wide, and each pool's buffers its own pages'.
 
     Over a page run (``window`` None: the decode step's row through
     ``paged_attention``, the verify chunk and the prefix suffix through
@@ -606,6 +623,7 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
     K/V block (``_rpa_chunk_kernel``).  Which of the two is the static
     ``window`` alone."""
     S, TG, H, D = q.shape
+    Dv = v_pages.shape[-1]          # the values' own width: the output's
     page = k_pages.shape[2 if heads_major else 1]
     P = page_tables.shape[1]
     if scale is None:
@@ -620,9 +638,9 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
     softmax = [
         pltpu.VMEM(rows + (1,), _F32),    # running max
         pltpu.VMEM(rows + (1,), _F32),    # running normalizer
-        pltpu.VMEM(rows + (D,), _F32),    # output accumulator
+        pltpu.VMEM(rows + (Dv,), _F32),   # output accumulator
     ]
-    out_shape = jax.ShapeDtypeStruct((S, TG, H, D), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((S, TG, H, Dv), q.dtype)
     args = (page_tables.astype(jnp.int32), lens.astype(jnp.int32),
             q, k_pages, v_pages)
     if window is not None:
@@ -643,7 +661,7 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
                 pl.BlockSpec((1,) + k_pages.shape[1:], page_of),
                 pl.BlockSpec((1,) + v_pages.shape[1:], page_of),
             ],
-            out_specs=pl.BlockSpec((1, TG, H, D),
+            out_specs=pl.BlockSpec((1, TG, H, Dv),
                                    lambda s, p, pt, ln: (s, 0, 0, 0)),
             scratch_shapes=softmax,
         )
@@ -662,7 +680,8 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
             pl.BlockSpec(memory_space=pl.ANY),          # the pools: in HBM
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, TG, H, D), lambda s, pt, ln: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, TG, H, Dv),
+                               lambda s, pt, ln: (s, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, fetch) + k_pages.shape[1:], k_pages.dtype),
             pltpu.VMEM((2, fetch) + v_pages.shape[1:], v_pages.dtype),
@@ -719,7 +738,10 @@ def ragged_paged_attention_gqa_reference(q, k_pages, v_pages, page_tables,
     """The chunk reference on grouped heads: q (S, T, Hq, D); k/v_pages
     (N, page, Hkv, D), or (N, Hkv, page, D) ``heads_major``; query head
     ``i`` reading K/V head ``i // (Hq // Hkv)``; ``lens`` the rows
-    before the chunk -> (S, T, Hq, D)."""
+    before the chunk -> (S, T, Hq, D).  The V pages may be narrower
+    than the K pages, ``(.., Dv)``: the output is then (S, T, Hq, Dv)
+    (``models/mimo_v2.py``: keys of 192 stored at 256 lanes on values
+    of 128)."""
     if heads_major:
         k_pages, v_pages = (jnp.swapaxes(k_pages, 1, 2),
                             jnp.swapaxes(v_pages, 1, 2))
@@ -729,15 +751,16 @@ def ragged_paged_attention_gqa_reference(q, k_pages, v_pages, page_tables,
     P = page_tables.shape[1]
     if scale is None:
         scale = D ** -0.5
+    Dv = v_pages.shape[-1]
     k = k_pages[page_tables].reshape(S, P * page, Hkv, D).astype(_F32)
-    v = v_pages[page_tables].reshape(S, P * page, Hkv, D).astype(_F32)
+    v = v_pages[page_tables].reshape(S, P * page, Hkv, Dv).astype(_F32)
     qg = q.astype(_F32).reshape(S, T, Hkv, G, D)
     s = jnp.einsum("sjhgd,sthd->sjhgt", qg, k) * scale
     limit = lens.reshape(-1, 1) + jnp.arange(T)[None, :] + 1     # (S, T)
     mask = jnp.arange(P * page)[None, None, :] < limit[:, :, None]
     s = jnp.where(mask[:, :, None, None, :], s, _NEG_INF)
     out = jnp.einsum("sjhgt,sthd->sjhgd", jax.nn.softmax(s, axis=-1), v)
-    return out.reshape(S, T, Hq, D).astype(q.dtype)
+    return out.reshape(S, T, Hq, Dv).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
@@ -768,8 +791,9 @@ def _grouped_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
         S, T * G, Hkv, D)
     out = _chunk_call(rows, k_pages, v_pages, page_tables, lens, scale,
                       interpret, G, heads_major, window)
-    return jnp.moveaxis(out.reshape(S, T, G, Hkv, D), 2, 3).reshape(
-        S, T, Hq, D)
+    Dv = out.shape[-1]
+    return jnp.moveaxis(out.reshape(S, T, G, Hkv, Dv), 2, 3).reshape(
+        S, T, Hq, Dv)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret",
@@ -848,7 +872,23 @@ def paged_ring_attention(q, k_pages, v_pages, ring_tables, pos, window: int,
     return ring_window_attention(q, k_ring, v_ring, pos, window, page)
 
 
-def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
+def _softmax(s, sink=None):
+    """Softmax over the last axis.  ``sink`` (broadcast against ``s``
+    less its last axis): a learned scalar a query head that joins the
+    denominator and carries no value, ``p_j = exp(s_j - m) / (exp(b -
+    m) + sum_k exp(s_k - m))``: a softmax over one more key whose value
+    row is zero (the gpt-oss form; ``models/mimo_v2.py``'s window
+    layers).  A row that sees no key gives zeros under a sink."""
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    sink = sink.astype(_F32)[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+    e = jnp.exp(s - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int,
+                          sink=None, scale=None):
     """Attention of a window layer over its per-sequence ring, plain
     XLA, on a gathered copy of the ring (a few pages a slot, 256 rows
     at a window of 128 and 640 at 512).  Of row-major pages the gather
@@ -864,14 +904,20 @@ def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
     per query row from its own position, so within a chunk of up to
     ``page`` rows a slot that a later row has begun to overwrite still
     reads as the old page for an earlier row: the overwritten rows are
-    older than that row's window."""
+    older than that row's window.
+
+    ``v_ring`` may be narrower than ``k_ring``, (.., Dv): the output is
+    (S, T, Hq, Dv).  ``scale``: the scores' (None: ``D ** -0.5``).
+    ``sink`` (Hq,): a scalar a query head in the softmax's denominator
+    (``_softmax``)."""
     S, T, Hq, D = q.shape
-    R, Hkv = k_ring.shape[1], k_ring.shape[3]
+    R, Hkv, Dv = k_ring.shape[1], k_ring.shape[3], v_ring.shape[-1]
     G = Hq // Hkv
     k = k_ring.reshape(S, R * page, Hkv, D).astype(_F32)
-    v = v_ring.reshape(S, R * page, Hkv, D).astype(_F32)
+    v = v_ring.reshape(S, R * page, Hkv, Dv).astype(_F32)
     qg = q.astype(_F32).reshape(S, T, Hkv, G, D)
-    s = jnp.einsum("sjhgd,sthd->sjhgt", qg, k) * (D ** -0.5)
+    s = jnp.einsum("sjhgd,sthd->sjhgt", qg, k) * (
+        D ** -0.5 if scale is None else scale)
     slot = jnp.arange(R * page, dtype=jnp.int32) // page
     off = jnp.arange(R * page, dtype=jnp.int32) % page
     newest = (pos // page)[:, :, None]                         # (S, T, 1)
@@ -880,50 +926,71 @@ def ring_window_attention(q, k_ring, v_ring, pos, window: int, page: int):
     back = pos[:, :, None] - k_pos
     seen = (k_pos >= 0) & (back >= 0) & (back < window)
     s = jnp.where(seen[:, :, None, None, :], s, _NEG_INF)
-    out = jnp.einsum("sjhgt,sthd->sjhgd", jax.nn.softmax(s, axis=-1), v)
-    return out.reshape(S, T, Hq, D).astype(q.dtype)
+    pr = _softmax(s, None if sink is None else sink.reshape(Hkv, G))
+    out = jnp.einsum("sjhgt,sthd->sjhgd", pr, v)
+    return out.reshape(S, T, Hq, Dv).astype(q.dtype)
 
 
-def banded_prefill_attention(q, k, v, window: int):
+def banded_prefill_attention(q, k, v, window: int, sink=None, scale=None,
+                             before=None):
     """Causal attention of one contiguous prompt in which a row sees
     itself and the ``window - 1`` rows before it: q (T, Hq, D), k/v
     (T, Hkv, D) -> (T, Hq, D).  Blocks of ``window`` queries against
     their own and the previous block of keys: the scores are (T, Hq, 2
     * window), never T x T.  A prompt that is not whole blocks (only
-    the eager oracle's) takes the masked dense form."""
+    the eager oracle's) takes the masked dense form.
+
+    ``v`` may be narrower than ``k``, (T, Hkv, Dv): the output is (T,
+    Hq, Dv).  ``scale``: the scores' (None: ``D ** -0.5``).  ``sink``
+    (Hq,): a scalar a query head in the softmax's denominator
+    (``_softmax``).  ``before``: (k, v) of the ``window`` rows that
+    stand before row 0, for a CHUNK of a prompt that continues over what
+    a ring keeps (whole blocks only): the first block sees them where a
+    prompt's sees nothing."""
     T, Hq, D = q.shape
     Hkv = k.shape[1]
     G, W = Hq // Hkv, window
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
+    if sink is not None:
+        sink = sink.reshape(Hkv, G, 1)
     if T % W:
+        if before is not None:
+            raise ValueError("rows before a chunk: whole blocks only")
         t = jnp.arange(T)
         back = t[:, None] - t[None, :]
         s = jnp.einsum("qhgd,khd->hgqk",
                        q.astype(_F32).reshape(T, Hkv, G, D),
                        k.astype(_F32)) * scale
         s = jnp.where((back >= 0) & (back < W), s, _NEG_INF)
-        out = jnp.einsum("hgqk,khd->qhgd", jax.nn.softmax(s, axis=-1),
+        out = jnp.einsum("hgqk,khd->qhgd", _softmax(s, sink),
                          v.astype(_F32))
-        return out.reshape(T, Hq, D).astype(q.dtype)
+        return out.reshape(T, Hq, v.shape[-1]).astype(q.dtype)
     nb = T // W
     qb = q.reshape(nb, W, Hkv, G, D)
 
-    def with_previous(x):
-        xb = x.reshape(nb, W, Hkv, D)
-        prev = jnp.concatenate([jnp.zeros_like(xb[:1]), xb[:-1]], axis=0)
+    def with_previous(x, x_before):
+        xb = x.reshape(nb, W, Hkv, x.shape[-1])
+        lead = (jnp.zeros_like(xb[:1]) if x_before is None
+                else x_before.astype(xb.dtype)[None])
+        prev = jnp.concatenate([lead, xb[:-1]], axis=0)
         return jnp.concatenate([prev, xb], axis=1)          # (nb, 2W, ..)
 
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, with_previous(k),
+    k_before, v_before = (None, None) if before is None else before
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, with_previous(k, k_before),
                    preferred_element_type=_F32) * scale
     i = jnp.arange(W)[:, None]
     j = jnp.arange(2 * W)[None, :]
-    band = (j > i) & (j <= i + W)                            # (W, 2W)
-    first = (jnp.arange(nb) > 0)[:, None, None] | (j >= W)   # (nb, W, 2W)
-    s = jnp.where((band & first)[:, None, None], s, _NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", pr, with_previous(v),
+    seen = (j > i) & (j <= i + W)                            # (W, 2W)
+    if before is None:      # a prompt's first block: nothing before it
+        seen = seen & ((jnp.arange(nb) > 0)[:, None, None] | (j >= W))
+    else:
+        seen = seen[None]
+    s = jnp.where(seen[:, None, None], s, _NEG_INF)
+    pr = _softmax(s, None if sink is None else sink[None]).astype(v.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", pr, with_previous(v, v_before),
                      preferred_element_type=_F32)
-    return out.reshape(T, Hq, D).astype(q.dtype)
+    return out.reshape(T, Hq, v.shape[-1]).astype(q.dtype)
 
 
 def _use_kernel(kernel: str, page_size: int, H: int, D: int) -> bool:

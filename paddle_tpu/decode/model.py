@@ -13,6 +13,9 @@ block as a static argument.  A block also says how a layer mixes
 tokens and what it keeps of them (``PageRunCache``: attention, every
 layer every row, in one page run a sequence; K-EXAONE's window layers
 keep a bounded ring each, beside a full layer, in the same pool;
+MiMo-V2.5's keep their rings in an ENTRY a sequence beside SEVERAL full
+layers' page run, K and V pools of different widths:
+``models/mimo_v2.py``, over ``decode/state_entry.py``;
 a latent layer keeps ONE compressed row a token in the place of K and V
 heads, the first pool alone, and defines both mixers itself:
 ``models/kanana_mla.py``;

@@ -162,6 +162,9 @@ class CacheManager(PageAllocator):
     """Pages and, beside them, state entries: the second resource of a
     model whose recurrent layers keep a state of fixed size a sequence
     (``decode/state_entry.py``), from the one object the session asks.
+    What an entry holds is the model's: a recurrent state and a conv
+    tail, or a window layer's rings (``models/mimo_v2.py``, whose
+    ``RingRunManager`` adds a gauge that names both resources).
 
     A sequence's reservation of ``n`` units is ``n - 1`` pages and ONE
     state entry (the model's ``context_pages`` counts the entry in, as

@@ -1,11 +1,16 @@
 """What a hybrid needs of the paged skeleton (``decode/model.py``): a
 model whose *recurrent* layers keep one state a sequence, whatever its
-length, beside the pages of its attention layers.  Four models stand
+length, beside the pages of its attention layers.  Six models stand
 on it: ``models/olmo_hybrid.py`` (the gated delta rule three layers of
 four), ``models/granite_hybrid.py`` (Mamba-2 nine layers of ten),
-``models/phi4_flash.py`` (Mamba-1 beside rings and one shared run) and
+``models/phi4_flash.py`` (Mamba-1 beside rings and one shared run),
 ``models/ling_hybrid.py`` (the delta rule under a per-channel decay five
-layers of six, beside ONE latent row a token in the sixth).
+layers of six, beside ONE latent row a token in the sixth),
+``models/lfm2_moe.py`` (gated short convs, whose state is a conv tail)
+and ``models/mimo_v2.py``, whose "recurrent" layers are WINDOW attention
+layers: what they keep of a sequence is of fixed size too, a ring of
+K/V rows, so its entry holds the rings (in the two ``extra`` pools'
+places) and its chunks continue over them.
 
 Two resources a sequence, from the one cache manager
 (``decode/paged_kv.py:CacheManager``): its page run, which the
@@ -409,6 +414,11 @@ class StateEntryLM(PagedDecoderLM):
 
     # -- a long prompt: the top bucket, then chunks over the entry ------------
 
+    # what a chunk continues over, as ``decode_prefill_chunk_rows_total``
+    # and ``.._pairs_total`` label it ("ring": ``models/mimo_v2.py``,
+    # whose entry holds its window layers' rings)
+    chunk_over = "state"
+
     # Rows of the top bucket and of a chunk after it (whole pages), on a
     # model whose block fills ``StateEntryCache.recurrent_chunk`` and
     # ``page_chunk``; None: one program holds a prompt or it is refused.
@@ -486,9 +496,9 @@ class StateEntryLM(PagedDecoderLM):
                 self._observe("prefill", report, C)
             _M_PREFILL_TOKENS.inc(real)
             _M_PREFILL_PADDED.inc(C)
-            _M_CHUNK_ROWS.inc(real, over="state")
+            _M_CHUNK_ROWS.inc(real, over=self.chunk_over)
             _M_CHUNK_PAIRS.inc(real * done + real * (real + 1) // 2,
-                               over="state")
+                               over=self.chunk_over)
         return T, [], logits
 
     # -- refused by name -----------------------------------------------------

@@ -27,8 +27,11 @@ layer: a ring that holds the layer's newest ``ring_pages * pg`` rows
 (page ``pi`` of the sequence in ring slot ``pi % ring_pages``), never
 more, however long the sequence grows.  Its table row is ``full_pages``
 columns of the full run, then each sliding layer's ring.  One full
-layer a model: a second would need a page run of its own, which nothing
-lays out yet.
+layer a model HERE: a second would need a slab of its own in a
+layer-axis pool, which this model's one pool ``(1, N, ..)`` is not;
+``models/mimo_v2.py`` lays that out (its full layers' run over
+``(full layers, N, ..)`` pools, its rings an entry a sequence beside
+it).
 
 Matmul operands in the weights' dtype (bfloat16 as served), float32
 accumulation, residual stream, norms, scores and rotation; K (rotated

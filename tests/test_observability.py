@@ -908,7 +908,9 @@ def test_the_short_convs_and_the_chunks_names():
         "decode_prefill_chunk_rows_total", "decode_prefill_chunk_pairs_total")
     src = inspect.getsource(se)
     assert 'phase("decode.prefill_chunk", done=done, rows=real,' in src
-    assert 'over="state"' in src
+    # the label is the model's (``chunk_over``: "ring" where the entry
+    # holds window layers' rings, PR 62), "state" unless it says so
+    assert 'chunk_over = "state"' in src and "over=self.chunk_over" in src
     assert se._prefill_state_chunk.__name__ == "_prefill_state_chunk"
     assert sc.CHUNK_MODULE == "_prefill_state_chunk"
     src = inspect.getsource(lm)
