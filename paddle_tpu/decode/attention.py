@@ -9,7 +9,11 @@ materializes a per-sequence contiguous copy — and pages wholly past the
 sequence length are skipped (their FLOPs AND their DMA do not happen,
 same trick as the causal-block skip in ``pallas/flash_attention.py``).
 Softmax is the same online (running max / normalizer) accumulation as
-the flash forward, in f32 VMEM scratch.
+the flash forward, in f32 VMEM scratch.  What a page's arithmetic is
+follows from the pool's dtype and layout and the call's rows alone
+(``page_form``): one row multiply-reduces on the VPU, several rows on
+row-major bfloat16 pages feed the MXU the page as it is stored, the rest
+widen it to float32 first.
 
 Every call over a page run (a decode step's row a slot, on grouped
 heads or not; a verify chunk's or a prefix suffix's T rows) takes ONE
@@ -216,6 +220,13 @@ def _page_update(q, k, v, m_prev, l_prev, acc_prev, t0, seq_len, scale):
     operands to bfloat16 (PERF.md §6, PR 60).  On float32 pages this
     arithmetic hides under the page's copy but for a fifth; on bfloat16
     pages it is the bound: 0.57 us a page whose copy is 0.32.
+
+    Which pool gets this body: every pool whose call is ONE row a K/V
+    head on row-major pages (``page_form``'s ``"row"``: the decode step
+    on ungrouped heads, float32 or bfloat16).  Several rows a K/V head
+    (grouped heads, a chunk) are a free dimension, and take the MXU
+    (``_softmax_page``, on the pages as they are stored where they are
+    row-major bfloat16, widened to float32 elsewhere).
     """
     q = q.astype(_F32)
     k = k.astype(_F32)
@@ -403,30 +414,86 @@ def _ring_seen(shape, lens, r, page: int, R: int, T: int, G: int,
     return (base >= 0) & (back >= 0) & (back < window)
 
 
+PARTS = 3   # bfloat16 parts of a float32 query or probability: all 24 bits
+
+
+def _operand(x, dtype, parts: int):
+    """float32 ``x`` (H, rows, W) as the LEFT operand of a batched dot
+    whose right operand is of ``dtype``: itself against float32; against
+    bfloat16 its ``parts`` bfloat16 parts, whose sum is ``x`` to ``8 *
+    parts`` bits (three: every bit a float32 holds), stacked as further
+    ROWS (H, parts * rows, W).  The right operand IS bfloat16, so each
+    product is exact in float32 and ``_sum_parts`` of the dot is the
+    float32 product's, by one MXU pass over the right operand where a
+    float32 dot of widened operands makes one too and rounds ``x`` to
+    bfloat16 on the way in (PERF.md section 6, PR 63: the widened body's
+    outputs stood 1e-3 off a float32 oracle on the chip, this one's
+    1e-6).  Nothing is rounded to bfloat16."""
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return x
+    out = []
+    for i in range(parts):
+        out.append(x.astype(jnp.bfloat16))
+        if i + 1 < parts:
+            x = x - out[-1].astype(_F32)
+    return jnp.concatenate(out, axis=1)
+
+
+def _sum_parts(x, rows: int):
+    """(H, parts * rows, W) -> (H, rows, W): the row blocks of an
+    ``_operand``'s products added up, smallest first."""
+    if x.shape[1] == rows:
+        return x
+    total = x[:, x.shape[1] - rows:]
+    for at in range(x.shape[1] - 2 * rows, -1, -rows):
+        total = total + x[:, at:at + rows]
+    return total
+
+
+def _scores(q, k, rows: int):
+    """q (H, parts * rows, D) against k (H, keys, D), both float32 or
+    both bfloat16 -> (H, rows, keys) float32, unscaled: batch over H,
+    contract D.  bfloat16 products are exact in float32 and are summed in
+    float32: the float32 dot of the widened operands."""
+    return _sum_parts(jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=_F32),
+        rows)
+
+
 def _softmax_page(q, k, v, seen, m_scr, l_scr, acc_scr, scale, heads_major):
-    """One page into a slot's online softmax, the arithmetic both chunk
-    bodies share, all float32: q (H, T * G, D), the page's k and v
-    widened as they are stored ((page, H, D), or (H, page, D)
-    ``heads_major``), ``seen(shape)`` the mask over the (H, T * G, page)
-    scores; the running max, normaliser and accumulator in VMEM
-    scratch."""
+    """Keys and values into a slot's online softmax, the arithmetic every
+    chunk body shares: q (H, parts * T * G, D) (``_operand``'s rows); k
+    and v as they are stored ((keys, H, D), or (H, keys, D)
+    ``heads_major``), float32 or bfloat16 as q is; ``seen(shape)`` the
+    mask over the (H, T * G, keys) scores; the running max, normaliser
+    and accumulator float32 in VMEM scratch.  Row-major keys are turned
+    in VMEM so that a head is a batch of the two dots.
+
+    Who hands it what (``page_form``).  WIDENED, a page a call: a ring's
+    columns (``_rpa_chunk_kernel``), a float32 pool over a page run
+    (nothing is widened there: the ``astype`` is the identity) and
+    heads-major pages (PERF.md section 6, PR 63, has the reading that
+    kept Phi-4-mini-flash's here: at 88% of its stream neither the
+    bfloat16 operands nor a turn at a time read faster).  STORED, a turn's
+    pages a call: a row-major bfloat16 pool over a page run, the pages in
+    the dtype they are stored in, turned as bfloat16 (half the vregs of
+    the float32 turn) and never widened."""
+    rows = m_scr.shape[1]
     if not heads_major:
         k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
-    # scores (H, T, page): batch over H, contract D
-    sc = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=_F32) * scale
+    # scores (H, T * G, keys): batch over H, contract D
+    sc = _scores(q, k, rows) * scale
     sc = jnp.where(seen(sc.shape), sc, _NEG_INF)
-    m_prev = m_scr[...]                             # (H, T, 1)
+    m_prev = m_scr[...]                             # (H, T * G, 1)
     m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
-    pr = jnp.exp(sc - m_new)                        # (H, T, page)
+    pr = jnp.exp(sc - m_new)                        # (H, T * G, keys)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + jnp.sum(pr, axis=2, keepdims=True)
     m_scr[...] = m_new
-    # (H, T, page) x (H, page, D) batched over H -> (H, T, D)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        pr, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=_F32)
+    # (H, T * G, keys) x (H, keys, Dv) batched over H -> (H, T * G, Dv)
+    acc_scr[...] = acc_scr[...] * corr + _sum_parts(jax.lax.dot_general(
+        _operand(pr, v.dtype, PARTS), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=_F32), rows)
 
 
 def _softmax_start(m_scr, l_scr, acc_scr):
@@ -451,7 +518,7 @@ def _softmax_finish(o_ref, l_scr, acc_scr):
 def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                      kbuf, vbuf, sems, start, q_scr, m_scr, l_scr, acc_scr,
                      *, scale, page, npp, T, G, heads_major, fetch, slots,
-                     row):
+                     form):
     """The chunk kernel over a page run: ONE grid step a slot, which
     walks the slot's live pages alone (``pallas/latent_attention.py``'s
     pattern).  The q block holds the slot's whole chunk, ``T * G`` rows:
@@ -466,21 +533,43 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     The pools stay in HBM.  ``kbuf`` / ``vbuf`` (2, fetch, a page): a
     turn's ``fetch`` pages are copied a page a DMA into one half while
     the other half is computed on (``sems`` (K | V, half, page): one
-    semaphore a copy in flight; only a live page is copied, waited for
-    and computed); a slot's last turn starts the NEXT slot's first
-    copies, so only slot 0's are waited for with nothing to do; ``start``
-    (1,) in SMEM keeps the half a slot's first turn lies in from grid
-    step to grid step, which is why the grid is ``arbitrary``.
-    ``q_scr`` (H, T * G, D): the chunk in float32 with its heads
-    outermost, as the two batched dots take it, turned once a slot.
+    semaphore a copy in flight; only a live page is copied and waited
+    for); a slot's last turn starts the NEXT slot's first copies, so only
+    slot 0's are waited for with nothing to do; ``start`` (1,) in SMEM
+    keeps the half a slot's first turn lies in from grid step to grid
+    step, which is why the grid is ``arbitrary``.
 
-    ``row`` (static: a chunk of ONE row on row-major pages, the decode
-    step's on ungrouped heads): one row is no free dimension for the two
+    ``form`` (static, ``page_form``'s) says what the arithmetic is:
+
+    ``"widened"`` (heads-major pages, float32 pools): a live page a
+    ``_softmax_page`` on the page widened to float32.  ``q_scr`` (H, T *
+    G, D): the chunk in float32 with its heads outermost, as the two
+    batched dots take it, turned once a slot.
+
+    ``"stored"`` (row-major bfloat16 pages): a TURN a ``_softmax_page``
+    on the turn's ``fetch`` pages as ONE run of keys in the dtype they
+    are stored in; ``q_scr`` (H, parts * T * G, D) bfloat16, a float32
+    query split in exact parts.  A page's update is a chain (the scores'
+    dot, a row maximum, the exponentials, the second dot) that starts
+    from the last page's maximum and ends in the accumulator; a page a
+    chain, each under its own ``pl.when``, nothing of one overlapped the
+    next and 0.75-0.95 us a page went by whatever the page's bytes or
+    arithmetic were (PERF.md section 6, PR 63).  A turn a chain is a
+    quarter of them on operands four times as long.  A page of the last
+    turn that is not live is computed too, under the mask: it holds an
+    earlier page or the zeros both buffers are filled with before the
+    first copy (a masked key's probability is an exact zero, and a zero
+    times a finite number is zero; what a buffer was before anything was
+    written to it need not be finite).
+
+    ``"row"`` (a chunk of ONE row on row-major pages, the decode step's
+    on ungrouped heads): one row is no free dimension for the two
     batched dots, and a page's arithmetic is ``_page_update``'s
     multiply-reduces on the page as it lies, nothing turned; ``q_scr``
     and the accumulator are (H, D), the running max and normaliser
-    (H, 1).  Every other call's body is what it was."""
+    (H, 1)."""
     s = pl.program_id(0)
+    row, stored = form == "row", form == "stored"
 
     def live_pages(slot):
         return jnp.clip(pl.cdiv(lens_ref[slot] + T, page), 0, npp)
@@ -508,6 +597,9 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     @pl.when(s == 0)
     def _first():
         start[0] = 0
+        if stored:
+            kbuf[...] = jnp.zeros_like(kbuf)
+            vbuf[...] = jnp.zeros_like(vbuf)
         start_copies(0, live_pages(0), 0, 0)
 
     seq_len, first, live = lens_ref[s], start[0], live_pages(s)
@@ -516,7 +608,8 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     if row:
         q_scr[...] = q_ref[0, 0].astype(_F32)
     else:
-        q_scr[...] = jnp.swapaxes(q_ref[0].astype(_F32), 0, 1)
+        q_scr[...] = _operand(jnp.swapaxes(q_ref[0].astype(_F32), 0, 1),
+                              q_scr.dtype, q_scr.shape[1] // (T * G))
 
     @pl.when((turns == 0) & (s + 1 < slots))
     def _empty_seat():
@@ -533,15 +626,9 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _next_slot():
             start_copies(s + 1, live_pages(s + 1), 0, 1 - half)
 
-        def compute(j, col):
-            for c in page_copies(s, col, half, j):
-                c.wait()
-            if row:
-                # the row sees its own key: ``seq_len + 1`` rows
-                _row_page(q_scr[...], kbuf[half, j], vbuf[half, j], m_scr,
-                          l_scr, acc_scr, col, page, seq_len + 1, scale)
-                return
-
+        def seen_from(col):
+            """The mask of the keys from table column ``col`` on: chunk
+            row ``r // G`` sees a key before ``seq_len + r // G + 1``."""
             def seen(shape):
                 t_pos = col * page + jax.lax.broadcasted_iota(
                     jnp.int32, shape, 2)
@@ -549,12 +636,34 @@ def _rpa_walk_kernel(ptab_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                 if G > 1:
                     row = row // G
                 return t_pos < seq_len + row + 1
+            return seen
 
+        def arrived(j, col):
+            for c in page_copies(s, col, half, j):
+                c.wait()
+
+        def compute(j, col):
+            arrived(j, col)
+            if row:
+                # the row sees its own key: ``seq_len + 1`` rows
+                _row_page(q_scr[...], kbuf[half, j], vbuf[half, j], m_scr,
+                          l_scr, acc_scr, col, page, seq_len + 1, scale)
+                return
             _softmax_page(q_scr[...], kbuf[half, j].astype(_F32),
-                          vbuf[half, j].astype(_F32), seen,
+                          vbuf[half, j].astype(_F32), seen_from(col),
                           m_scr, l_scr, acc_scr, scale, heads_major)
 
-        for_live(live, t, compute)
+        if stored:
+            for_live(live, t, arrived)
+
+            def keys(buf):      # the turn's pages, one run of rows
+                return buf[half].reshape((fetch * page,) + buf.shape[3:])
+
+            _softmax_page(q_scr[...], keys(kbuf), keys(vbuf),
+                          seen_from(t * fetch), m_scr, l_scr, acc_scr,
+                          scale, heads_major)
+        else:
+            for_live(live, t, compute)
         return carry
 
     jax.lax.fori_loop(0, turns, turn, 0)
@@ -603,6 +712,26 @@ def _rpa_chunk_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
 
 
+def page_form(k_dtype, v_dtype, heads_major: bool, rows: int) -> str:
+    """What the arithmetic of the walk over a page run is
+    (``_rpa_walk_kernel``), from what the call sees at trace time: the
+    pools' dtype, their layout and the chunk's rows a K/V head.
+
+    ``"row"``: one row on row-major pages (PR 60's multiply-reduces).
+    ``"stored"``: row-major bfloat16 pages under several rows: the MXU is
+    fed the pages in the dtype they are stored in, a turn of them a
+    softmax update (PR 63).  ``"widened"``: the rest, a page an update on
+    float32 operands -- a float32 pool, where no widening exists, and
+    heads-major pages.  ``pallas_dispatch_total``'s ``path`` carries the
+    form where it is the stored one (``_use_walk``)."""
+    if rows == 1 and not heads_major:
+        return "row"
+    if not heads_major and all(jnp.dtype(d) == jnp.bfloat16
+                               for d in (k_dtype, v_dtype)):
+        return "stored"
+    return "widened"
+
+
 def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
                 G, heads_major=False, window=None, step=False):
     """The chunk kernel's call: q (S, T * G, H, D) on pages of H heads,
@@ -633,8 +762,9 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
     # a (head, chunk row) a row of the softmax's state; the walk's one
     # row on a page as it lies (``_rpa_walk_kernel``) keeps its heads in
     # the sublanes
-    row = TG == 1 and window is None and not heads_major
-    rows = (H,) if row else (H, TG)
+    form = None if window is not None else page_form(
+        k_pages.dtype, v_pages.dtype, heads_major, TG)
+    rows = (H,) if form == "row" else (H, TG)
     softmax = [
         pltpu.VMEM(rows + (1,), _F32),    # running max
         pltpu.VMEM(rows + (1,), _F32),    # running normalizer
@@ -672,6 +802,12 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
                 dimension_semantics=("parallel", "arbitrary")),
             name="ring_paged_attention", interpret=interpret)(*args)
     fetch = WALK_PAGES
+    # the chunk, heads outermost: float32, or the stored pages' bfloat16
+    # (a float32 query's exact parts one under another)
+    q_scr = pltpu.VMEM(rows + (D,), _F32)
+    if form == "stored":
+        q_parts = 1 if q.dtype == jnp.bfloat16 else PARTS
+        q_scr = pltpu.VMEM((H, q_parts * TG, D), jnp.bfloat16)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # page table + lens land in SMEM
         grid=(S,),
@@ -687,11 +823,11 @@ def _chunk_call(q, k_pages, v_pages, page_tables, lens, scale, interpret,
             pltpu.VMEM((2, fetch) + v_pages.shape[1:], v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2, fetch)),
             pltpu.SMEM((1,), jnp.int32),      # the half a slot starts in
-            pltpu.VMEM(rows + (D,), _F32),    # the chunk, heads outermost
+            q_scr,
         ] + softmax,
     )
     kernel = functools.partial(_rpa_walk_kernel, fetch=fetch, slots=S,
-                               row=row, **statics)
+                               form=form, **statics)
     call = dict(
         grid_spec=grid_spec, out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -825,12 +961,8 @@ def _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale,
     ``lens`` cached rows."""
     from paddle_tpu import pallas as pk
 
-    Hq, D = q.shape[-2:]
-    page, Hkv = k_pages.shape[1:3]
-    if heads_major:
-        page, Hkv = Hkv, page
-    if _use_walk("ragged_paged_attention_gqa", k_pages.dtype, page, Hq, D,
-                 Hkv, heads_major):
+    if _use_walk("ragged_paged_attention_gqa", q, k_pages, v_pages,
+                 heads_major):
         return ragged_paged_attention_gqa(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
             interpret=pk.interpret_mode(), heads_major=heads_major)
@@ -1013,16 +1145,27 @@ def _walks(dtype, page_size: int, Hkv: int, D: int,
                                             heads_major)
 
 
-def _use_walk(kernel: str, dtype, page_size: int, Hq: int, D: int, Hkv: int,
+def _use_walk(kernel: str, q, k_pages, v_pages,
               heads_major: bool = False) -> bool:
     """``_use_kernel`` for the calls over a page run that have no other
-    kernel than the walk: where it would be compiled the pages also
-    have to be whole tiles where they lie (``walk_fits``)."""
+    kernel than the walk, q (S, T, Hq, D): where it would be compiled
+    the pages also have to be whole tiles where they lie
+    (``walk_fits``).  A call whose pages the walk consumes as they are
+    stored is counted under ``path="compiled_stored"`` (or
+    ``interpret_stored``), one whose pages it widens under the plain
+    path (``page_form``)."""
     from paddle_tpu import pallas as pk
 
+    T, Hq, D = q.shape[1:]
+    page, Hkv = k_pages.shape[1:3]
+    if heads_major:
+        page, Hkv = Hkv, page
+    form = page_form(k_pages.dtype, v_pages.dtype, heads_major,
+                     T * (Hq // Hkv))
     return pk.dispatch(kernel, pk.policy(
-        fits(page_size, Hq, D, Hkv)
-        and _walks(dtype, page_size, Hkv, D, heads_major), True))
+        fits(page, Hq, D, Hkv)
+        and _walks(k_pages.dtype, page, Hkv, D, heads_major), True),
+        form if form == "stored" else "")
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
@@ -1032,9 +1175,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_tables, lens,
 
     if _grouped(q, k_pages):
         return _paged_gqa(q, k_pages, v_pages, page_tables, lens, scale)
-    S, T, H, D = q.shape
-    if _use_walk("ragged_paged_attention_chunk", k_pages.dtype,
-                 k_pages.shape[1], H, D, H):
+    if _use_walk("ragged_paged_attention_chunk", q, k_pages, v_pages):
         return ragged_paged_attention_chunk(
             q, k_pages, v_pages, page_tables, lens, scale=scale,
             interpret=pk.interpret_mode())

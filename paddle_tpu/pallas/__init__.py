@@ -93,7 +93,9 @@ On a TPU backend a kernel that dispatches runs compiled or raises —
 interpret mode is ignored there, and the jnp/XLA reference is chosen
 only by ``fits()`` and the mode.  Every dispatch decision is counted at
 trace time in ``pallas_dispatch_total{kernel, path}`` (path =
-compiled | interpret | reference).
+compiled | interpret | reference; a kernel with more than one body
+names the body it took behind an underscore, ``compiled_stored``:
+``decode/attention.py:page_form``).
 """
 
 from __future__ import annotations
@@ -103,7 +105,8 @@ from paddle_tpu.observability import metrics as _metrics
 _M_DISPATCH = _metrics.counter(
     "pallas_dispatch_total",
     "Pallas kernel dispatch decisions, counted at trace time, by kernel "
-    "and path (compiled | interpret | reference = the jnp/XLA lowering)")
+    "and path (compiled | interpret | reference = the jnp/XLA lowering; "
+    "_<form> behind it where a kernel has several bodies)")
 
 # The auto-mode thresholds, each written here and nowhere else.
 LSTM_MAX_HIDDEN = 384    # earlier setup: XLA won at H>=512
@@ -157,10 +160,15 @@ def auto_ok() -> bool:
     return _STATE["interpret"] or tpu_backend()
 
 
-def dispatch(kernel: str, use: bool) -> bool:
-    """Count one dispatch decision (trace time) and return it."""
+def dispatch(kernel: str, use: bool, form: str = "") -> bool:
+    """Count one dispatch decision (trace time) and return it.  ``form``:
+    which of a kernel's bodies the call takes, where it has several and
+    this is not the plain one; it rides the ``path`` label of a call that
+    runs the kernel."""
     path = ("reference" if not use
             else "interpret" if interpret_mode() else "compiled")
+    if use and form:
+        path += "_" + form
     _M_DISPATCH.inc(kernel=kernel, path=path)
     return use
 

@@ -66,6 +66,28 @@ def _ring_dispatches():
             for p in ("compiled", "interpret", "reference")}
 
 
+def _walk_dispatches():
+    """``pallas_dispatch_total{kernel="ragged_paged_attention_gqa"}`` by
+    path: one decision a layer on a page run a traced program, and which
+    body the walk took there (PR 63: ``compiled_stored`` a row-major
+    bfloat16 page consumed as it is stored, ``compiled`` the widened
+    body)."""
+    from paddle_tpu import pallas as pk
+
+    return {p: pk._M_DISPATCH.value(kernel="ragged_paged_attention_gqa",
+                                    path=p)
+            for p in ("compiled", "compiled_stored", "interpret",
+                      "interpret_stored", "reference")}
+
+
+def _walks_took(before, **paths):
+    """What ``_walk_dispatches`` counted since ``before``: ``paths`` and
+    nothing else."""
+    after = _walk_dispatches()
+    assert {p: after[p] - before[p] for p in after} == {
+        p: paths.get(p, 0) for p in after}
+
+
 def _under(scope):
     """``scope`` behind the skeleton's own (PR 51: ``decode/model.py``
     names its call sites, outermost): a feed-forward's mechanism lies

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
     _assert_experts_read_where_they_lie, _assert_grouped_gemm_kernel,
     _assert_pools_in_place, _assert_step_outputs, _kernel_grids,
-    _kernel_op_names, one_chip, _planned_bytes, _ring_dispatches, _under)
+    _kernel_op_names, one_chip, _planned_bytes, _ring_dispatches, _under,
+    _walk_dispatches, _walks_took)
 
 
 def _exaone_cell(one_chip, monkeypatch):
@@ -86,12 +87,14 @@ def test_exaone_decode_step_reads_both_caches_in_place(one_chip,
     cfg, params, pool, shape, block, width, sds = _exaone_cell(
         one_chip, monkeypatch)
     g, L, S = cfg["generate"], cfg["num_hidden_layers"], 64
+    walks = _walk_dispatches()
     before = _ring_dispatches()
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block).compile()
+    _walks_took(walks, compiled_stored=1)
     # the six sliding layers' row-major rings stay on the gathered form
     after = _ring_dispatches()
     assert {p: after[p] - before[p] for p in after} == {

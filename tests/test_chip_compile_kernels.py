@@ -85,20 +85,38 @@ def test_ragged_paged_attention_chunk_compiles(one_chip):
     assert [grid for _, grid in _kernel_grids(text)] == [(S,)]
 
 
-@pytest.mark.parametrize("T", [1, 4], ids=["step", "chunk"])
-def test_ragged_paged_attention_gqa_compiles(one_chip, T):
-    """64 query heads on 8 K/V heads of 128 over 128-row bf16 pages:
-    K-EXAONE's full layer at the serving shape (64 slots, 36 pages a
-    sequence)."""
+# (slots, query heads, stored K/V heads, key lanes, value lanes, pages a
+# sequence, pages): the cells' full layers on row-major bf16 pages of 128
+GQA_SHAPES = {
+    "k_exaone": (64, 64, 8, 128, 128, 36, 3073),
+    "mimo": (48, 64, 4, 256, 128, 256, 7656),       # keys at 256 lanes
+    "packed": (64, 32, 4, 128, 128, 192, 6600),     # LFM2, Granite
+}
+
+
+@pytest.mark.parametrize("cell, T, q", [
+    ("k_exaone", 1, jnp.bfloat16), ("k_exaone", 4, jnp.bfloat16),
+    ("mimo", 1, jnp.bfloat16), ("packed", 1, jnp.bfloat16),
+    ("mimo", 4, jnp.float32)],
+    ids=["step", "chunk", "mimo_step", "packed_step", "mimo_chunk_f32_q"])
+def test_ragged_paged_attention_gqa_compiles(one_chip, cell, T, q):
+    """The grouped walk on the pages as they are stored (PR 63: a turn's
+    pages a softmax update on bfloat16 operands) at the serving shapes:
+    64 query heads on 8 K/V heads of 128 (K-EXAONE's full layer), 64 on 4
+    with keys of 256 lanes on values of 128 (MiMo-V2.5), 32 widened heads
+    on 4 stored rows of two (LFM2, Granite); the decode step's row, a
+    chunk of four, and a float32 query, whose three exact bfloat16 parts
+    ride as further rows."""
     from paddle_tpu.decode import attention as A
 
-    S, Hq, Hkv, D, page, N, P, dt = 64, 64, 8, 128, 128, 3073, 36, \
-        jnp.bfloat16
-    assert A.fits(page, Hq, D, Hkv)
+    S, Hq, Hkv, D, Dv, P, N = GQA_SHAPES[cell]
+    page, dt = 128, jnp.bfloat16
+    assert A.fits(page, Hq, D, Hkv) and A.walk_fits(dt, page, Hkv, D)
+    assert A.page_form(dt, dt, False, T * Hq // Hkv) == "stored"
     text = _compiled_text(
         A.ragged_paged_attention_gqa, one_chip,
-        ((S, T, Hq, D), dt), ((N, page, Hkv, D), dt),
-        ((N, page, Hkv, D), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
+        ((S, T, Hq, D), q), ((N, page, Hkv, D), dt),
+        ((N, page, Hkv, Dv), dt), ((S, P), jnp.int32), ((S,), jnp.int32))
     assert MARKER in text
     assert all("ragged_paged_attention_gqa/" in op
                for op in _kernel_op_names(text))

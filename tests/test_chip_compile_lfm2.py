@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
     _kernel_grids, _kernel_op_names, one_chip, _planned_bytes,
-    _pool_sized_strays, _under)
+    _pool_sized_strays, _under, _walk_dispatches, _walks_took)
 
 PARAMETERS = 3_928_728_256
 
@@ -106,11 +106,13 @@ def test_lfm2_decode_step_moves_tails_and_pages_in_place(one_chip,
     cfg, params, pool, extra, block, width, sds = _lfm2_cell(
         one_chip, monkeypatch)
     g, S = cfg["generate"], cfg["generate"]["slots"]
+    walks = _walk_dispatches()
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block, extra=extra).compile()
+    _walks_took(walks, compiled_stored=3)
     out = jax.tree.leaves(compiled.out_info)
     assert (out[0].shape, out[0].dtype) == ((S, cfg["vocab_size"]),
                                             jnp.float32)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
     _kernel_grids, _kernel_op_names, one_chip, _planned_bytes,
-    _pool_sized_strays, _under)
+    _pool_sized_strays, _under, _walk_dispatches, _walks_took)
 
 PARAMETERS = 3_429_955_392
 
@@ -130,7 +130,9 @@ def test_mimo_decode_step_walks_the_run_and_gathers_the_rings(one_chip,
     cell = _mimo_cell(one_chip, monkeypatch)
     cfg, params, pools, extra, block, width, sds = cell
     S = cfg["generate"]["slots"]
+    walks = _walk_dispatches()
     compiled = _lower_step(*cell).compile()
+    _walks_took(walks, compiled_stored=2)
     out = jax.tree.leaves(compiled.out_info)
     assert (out[0].shape, out[0].dtype) == ((S, cfg["vocab_size"]),
                                             jnp.float32)

@@ -11,8 +11,9 @@ import jax
 import jax.numpy as jnp
 
 from tests.chip_compile import (  # noqa: F401 (one_chip: a fixture)
-    _hybrid_sizes, _kernel_grids, _kernel_op_names, one_chip,
-    _planned_bytes, _pool_sized_strays, _ring_dispatches, _under)
+    _hybrid_sizes, _kernel_grids, _kernel_op_names, one_chip, _planned_bytes,
+    _pool_sized_strays, _ring_dispatches, _under, _walk_dispatches,
+    _walks_took)
 
 
 # -- a decoder-hybrid-decoder (PR 48) -----------------------------------------
@@ -119,12 +120,14 @@ def test_phi4_decode_step_moves_states_rings_and_the_one_run_in_place(
         one_chip, monkeypatch)
     g, S = cfg["generate"], cfg["generate"]["slots"]
     assert width == 137
+    walks = _walk_dispatches()
     before = _ring_dispatches()
     compiled = dm._decode_step.lower(
         params, pool, pool, sds((S, width), jnp.int32),
         sds((S,), jnp.int32), sds((S,), jnp.int32),
         heads=cfg["num_attention_heads"], page_size=g["page_size"],
         block=block, extra=extra).compile()
+    _walks_took(walks, compiled=8)
     after = _ring_dispatches()
     assert {p: after[p] - before[p] for p in after} == {
         "compiled": 8, "interpret": 0, "reference": 0}

@@ -855,6 +855,38 @@ def test_every_pallas_call_has_a_name():
             "kda_chunked"} <= set(names)
 
 
+@pytest.mark.parametrize("pool, heads_major, path", [
+    ("bfloat16", False, "interpret_stored"),
+    ("float32", False, "interpret"),
+    ("bfloat16", True, "interpret"),
+])
+def test_the_grouped_walks_dispatch_names_the_body_it_took(
+        monkeypatch, pool, heads_major, path):
+    """``pallas_dispatch_total{kernel="ragged_paged_attention_gqa"}``
+    tells the walk that consumes a page as it is stored from the one
+    that widens it (PR 63): a row-major bfloat16 pool counts under
+    ``<path>_stored``, a float32 pool and heads-major pages under the
+    plain path, so a program's registry says which body it compiled."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import pallas as pk
+    from paddle_tpu.decode import attention as A
+    from tests.chip_compile import _walk_dispatches, _walks_took
+
+    monkeypatch.setitem(pk._STATE, "mode", "on")
+    monkeypatch.setitem(pk._STATE, "interpret", True)
+    S, Hq, Hkv, D, page, N, P = 2, 8, 2, 128, 8, 5, 2
+    sds = jax.ShapeDtypeStruct
+    pages = sds((N, Hkv, page, D) if heads_major else (N, page, Hkv, D),
+                jnp.dtype(pool))
+    before = _walk_dispatches()
+    jax.make_jaxpr(lambda *a: A.paged_attention(*a, heads_major=heads_major))(
+        sds((S, Hq, D), jnp.bfloat16), pages, pages, sds((S, P), jnp.int32),
+        sds((S,), jnp.int32))
+    _walks_took(before, **{path: 1})
+
+
 def test_the_latent_layers_names():
     """What the latent layer adds to the tracing (PR 45): the kernel's
     dispatch under its own name, the causal-pairs counter the prefill
