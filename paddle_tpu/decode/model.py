@@ -29,6 +29,16 @@ earlier layer hands on inside the program, and its prefill goes on with
 the prompt's last row alone from the layer after which no other row
 reaches a cache: ``models/phi4_flash.py``).
 
+A LAYER is what the block says it is.  The loops below ask every layer
+for its mixer and then for its feed-forward, each under its scope
+(``blk_mixer``, ``blk_mlp``) with its own pre-norm and residual inside;
+a block whose layers are ONE part alone (Nemotron-H: a Mamba-2 layer, an
+attention layer or an expert layer, ``x <- x + part(norm(x))``) answers
+for the part a layer does not have with the rows as they came, keeps
+None of the prompt there and reports None: no instruction is emitted
+under that scope, so ``blk_mixer`` still times the mixers and
+``blk_mlp`` the feed-forwards (``models/nemotron_h.py``).
+
 Prefill is ONE jitted program per length *bucket* (the shared pow2
 ladder of ``paddle_tpu/bucket.py``, from 64 up to the sequence
 capacity).  The prompt is padded on the right to its bucket, the
@@ -287,9 +297,12 @@ GPT2 = Gpt2Block()
 
 
 def _stack_reports(reports):
-    """Per-layer reports of ``block.mlp`` as one array (L, ...), or
-    None for a block that reports nothing."""
-    return None if reports[0] is None else jnp.stack(reports)
+    """Per-layer reports of ``block.mlp`` as one array (L, ...), over
+    the layers that report alone (a layer that is a mixer and nothing
+    else reports None: ``models/nemotron_h.py``), or None for a block
+    that reports nothing."""
+    reports = [r for r in reports if r is not None]
+    return jnp.stack(reports) if reports else None
 
 
 def _dense_blocks(block, params, tokens, heads, live, last=None):

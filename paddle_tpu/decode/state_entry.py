@@ -1,8 +1,11 @@
 """What a hybrid needs of the paged skeleton (``decode/model.py``): a
 model whose *recurrent* layers keep one state a sequence, whatever its
-length, beside the pages of its attention layers.  Six models stand
+length, beside the pages of its attention layers.  Seven models stand
 on it: ``models/olmo_hybrid.py`` (the gated delta rule three layers of
 four), ``models/granite_hybrid.py`` (Mamba-2 nine layers of ten),
+``models/nemotron_h.py`` (Mamba-2 in eight groups 23 layers of 52, six
+attention layers, and 23 layers that are experts ALONE: they keep
+nothing, neither of a prompt nor of a step),
 ``models/phi4_flash.py`` (Mamba-1 beside rings and one shared run),
 ``models/ling_hybrid.py`` (the delta rule under a per-channel decay five
 layers of six, beside ONE latent row a token in the sixth),
@@ -226,7 +229,8 @@ class StateEntryCache(PageRunCache):
         flat, entry = where
         k_pool, v_pool, state_pool, conv_pool = cache
         rec = [t == self.recurrent_kind for t in self.layer_types]
-        full = [kv for kv, r in zip(kept, rec) if not r]
+        # a layer that mixes no tokens (experts alone) kept None
+        full = [kv for kv, r in zip(kept, rec) if not r and kv is not None]
         lin = [sc for sc, r in zip(kept, rec) if r]
         k_pool, v_pool = self.store_pages((k_pool, v_pool), full, flat)
         if state_pool.ndim > 2:         # else a placeholder: no state kept
@@ -315,13 +319,16 @@ class StateEntryLM(PagedDecoderLM):
     supports_verify = False           # nor rolled back past rejected rows
 
     def _count_layers(self, layer_types, recurrent_kind):
+        """``layer_types``: the kind of every layer that mixes tokens
+        (all of them, but for a model with layers that are a
+        feed-forward alone)."""
         recurrent = sum(t == recurrent_kind for t in layer_types)
         if not 0 < recurrent < len(layer_types):
             raise ValueError("a hybrid holds layers of both kinds")
         self.full_pages = self.pages_per_seq
         self.pages_per_seq = self.full_pages + 1
         self.linear_layers = recurrent
-        self.full_layers = self.layers - recurrent
+        self.full_layers = len(layer_types) - recurrent
 
     # the ``decode_cache_rows`` / ``decode_cache_bytes`` kind of the page
     # run's rows ("latent" where a page holds latent rows)

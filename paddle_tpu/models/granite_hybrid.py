@@ -12,7 +12,11 @@ The block (pre-norm, Granite's four scalar multipliers):
     logits = E^T RMSNorm(x_L) / logits_scaling          (the head is tied)
 
 A **mamba** layer, ``H`` heads of ``P`` channels, state size ``N``, one
-group (``B`` and ``C`` shared by all heads), ``u`` the normed input:
+group (``B`` and ``C`` shared by all heads: Granite's ``mamba_n_groups``
+1; with ``G`` groups, Nemotron-H's 8, heads ``g H/G .. (g + 1) H/G - 1``
+read ``B_g`` and ``C_g``, ``xBC`` holds ``G`` of each and the gated norm
+runs over each group's channels on its own: ``models/nemotron_h.py``
+stands on this block), ``u`` the normed input:
 
     [z; xBC; dt] = W_in u;  xBC_t <- silu(conv4(xBC)_t + b)  (depthwise,
         causal, zeros before row 0);  x_t (H, P), B_t, C_t (N) = xBC_t
@@ -137,7 +141,8 @@ def chunked_ssd(x, dt, g, B, C, state, chunk=CHUNK):
     and ``g`` (the log of the decay) (T, H), ``B``, ``C`` (T, N),
     ``state`` (H, P, N) as it stood before row 0 -> (y (T, H, P) =
     ``S_t C_t`` without the skip, the state after row T - 1).  All
-    float32.
+    float32.  ``B``, ``C`` (T, G, N): ``G`` one-group recurrences side
+    by side, each over the ``H / G`` heads of its group.
 
     Inside a chunk, with ``G_t`` the running sum of ``g``: ``Y = e^G (C
     S_0^T) + M (dt x)`` with ``M[t, s] = e^(G_t - G_s) C_t.B_s`` on and
@@ -147,6 +152,18 @@ def chunked_ssd(x, dt, g, B, C, state, chunk=CHUNK):
     with ``dt = 0`` (a bucket's padding; then ``g = 0`` too) leaves the
     state as it was."""
     T, H, P = x.shape
+    if B.ndim == 3:
+        G = B.shape[1]
+
+        def by_group(a):               # the heads axis as (G, H / G)
+            return a.reshape(a.shape[:1] + (G, H // G) + a.shape[2:])
+
+        y, state = jax.vmap(
+            functools.partial(chunked_ssd, chunk=chunk),
+            in_axes=(1, 1, 1, 1, 1, 0), out_axes=(1, 0))(
+                by_group(x), by_group(dt), by_group(g), B, C,
+                state.reshape((G, H // G) + state.shape[1:]))
+        return y.reshape(x.shape), state.reshape((H,) + state.shape[2:])
     c = min(chunk, T)
     pad = -T % c
     if pad:
@@ -181,12 +198,19 @@ def chunked_ssd(x, dt, g, B, C, state, chunk=CHUNK):
 def step_ssd(x, dt, g, B, C, state):
     """One row on a state (any leading shape: a slot's, or the slots'):
     ``x`` (..., H, P), ``dt``, ``g`` (..., H), ``B``, ``C`` (..., N),
+    or (..., G, N) a group of ``H / G`` heads,
     ``state`` (..., H, P, N) -> (y (..., H, P) = ``S_t C_t``, the new
     state).  Multiply-reduces, float32.  ``pallas/ssd_step.py`` is
     this, in this order, on blocks of the pool in VMEM."""
+    if B.ndim == x.ndim:                # a group: each head its group's
+        per_group = x.shape[-2] // B.shape[-2]
+        B, C = (jnp.repeat(v, per_group, axis=-2)[..., None, :]
+                for v in (B, C))
+    else:
+        B, C = B[..., None, None, :], C[..., None, None, :]
     new = (jnp.exp(g)[..., None, None] * state
-           + (x * dt[..., None])[..., None] * B[..., None, None, :])
-    return jnp.sum(new * C[..., None, None, :], axis=-1), new
+           + (x * dt[..., None])[..., None] * B)
+    return jnp.sum(new * C, axis=-1), new
 
 
 class PackedHeadPages:
@@ -243,7 +267,9 @@ class GraniteHybridBlock(PackedHeadPages, StateEntryCache):
     ``mamba_d_state``; a layer's state is (mamba_n_heads, mamba_d_head,
     mamba_d_state), stored as ``pack_state`` lays it out.  ``pack``: the
     K/V heads a page's row holds side by side; ``state_pack``: the mamba
-    heads a row of an entry does."""
+    heads a row of an entry does; ``mamba_n_groups``: the groups of
+    heads that each read a ``B`` and a ``C`` of their own and are normed
+    on their own (1: Granite's; 8: Nemotron-H's)."""
 
     recurrent_kind = MAMBA
     layer_types: tuple = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
@@ -254,6 +280,7 @@ class GraniteHybridBlock(PackedHeadPages, StateEntryCache):
     mamba_n_heads: int = 64
     mamba_d_head: int = 64
     mamba_d_state: int = 128
+    mamba_n_groups: int = 1      # groups of heads, a B and a C each
     eps: float = 1e-5
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
@@ -311,30 +338,42 @@ class GraniteHybridBlock(PackedHeadPages, StateEntryCache):
         """-> (the gate's rows z; the rows the conv sees, in the
         weights' dtype; dt and the log decay ``g``, (..., H))."""
         H, P, N = self._sizes
+        BC = 2 * self.mamba_n_groups * N
         u = rms_norm(x, lp["w_in"], self.eps)
-        zxbcdt = _mm(u, lp["w_zxbcdt"])
+        with jax.named_scope("ssm_proj"):
+            zxbcdt = _mm(u, lp["w_zxbcdt"])
         z = zxbcdt[..., :H * P]
-        xBC = zxbcdt[..., H * P:2 * H * P + 2 * N].astype(
+        xBC = zxbcdt[..., H * P:2 * H * P + BC].astype(
             lp["w_zxbcdt"].dtype)
-        dt = jax.nn.softplus(zxbcdt[..., 2 * H * P + 2 * N:]
+        dt = jax.nn.softplus(zxbcdt[..., 2 * H * P + BC:]
                              + lp["dt_bias"])
         return z, xBC, dt, -jnp.exp(lp["A_log"]) * dt
 
     def _split(self, xc):
-        """The conv's output rows -> x (..., H, P), B, C (..., N)."""
-        H, P, N = self._sizes
+        """The conv's output rows -> x (..., H, P), B, C (..., N), or
+        (..., G, N) where the heads read them in G groups."""
+        (H, P, N), G = self._sizes, self.mamba_n_groups
         x = xc[..., :H * P].reshape(xc.shape[:-1] + (H, P))
-        return x, xc[..., H * P:H * P + N], xc[..., H * P + N:]
+        B, C = xc[..., H * P:H * P + G * N], xc[..., H * P + G * N:]
+        if G > 1:
+            B, C = (v.reshape(v.shape[:-1] + (G, N)) for v in (B, C))
+        return x, B, C
 
     def _gated_norm(self, lp, y, xs, z):
         """The skip, the gate, then the norm over all the layer's
-        channels."""
+        channels, or over each group's on their own."""
         y = (y + lp["D"][:, None] * xs).reshape(z.shape)
-        return rms_norm(y * jax.nn.silu(z), lp["w_norm"], self.eps)
+        y, w, G = y * jax.nn.silu(z), lp["w_norm"], self.mamba_n_groups
+        if G > 1:
+            by_group = y.shape[:-1] + (G, y.shape[-1] // G)
+            return rms_norm(y.reshape(by_group), w.reshape(by_group[-2:]),
+                            self.eps).reshape(y.shape)
+        return rms_norm(y, w, self.eps)
 
     def _out(self, lp, x, y):
         """The output projection and the block's residual."""
-        return x + self.residual_multiplier * _mm(y, lp["w_out"])
+        with jax.named_scope("ssm_proj"):
+            return x + self.residual_multiplier * _mm(y, lp["w_out"])
 
     # -- the mixers ---------------------------------------------------------
 
@@ -375,7 +414,8 @@ class GraniteHybridBlock(PackedHeadPages, StateEntryCache):
             xs, B, C = self._split(xc)
             with jax.named_scope("ssm_state"):
                 states = state_pool.reshape((-1,) + state_pool.shape[2:])
-                if pk.use_ssd_step(state_pool.dtype, *state_pool.shape[2:]):
+                if pk.use_ssd_step(state_pool.dtype, *state_pool.shape[2:],
+                                   self.mamba_n_groups):
                     # along the lanes as an entry's rows of heads lie:
                     # each head's decay over its own channels
                     lanes = (S,) + state_pool.shape[2:3] + (-1,)
@@ -415,11 +455,50 @@ def _normal(key, *, shape, std, dtype):
     return (jax.random.normal(key, shape, _F32) * std).astype(dtype)
 
 
+def attention_params(keys, *, d, heads, kv_heads, head_dim, qk_row_std,
+                     dtype):
+    """An attention layer's four projections from four keys: q and k
+    at ``qk_row_std * d^-1/2``, v and o at 0.02."""
+    qk = qk_row_std * d ** -0.5
+    return dict(
+        wq=_normal(keys[0], shape=(d, heads * head_dim), std=qk, dtype=dtype),
+        wk=_normal(keys[1], shape=(d, kv_heads * head_dim), std=qk,
+                   dtype=dtype),
+        wv=_normal(keys[2], shape=(d, kv_heads * head_dim), std=0.02,
+                   dtype=dtype),
+        wo=_normal(keys[3], shape=(heads * head_dim, d), std=0.02,
+                   dtype=dtype))
+
+
+def mamba_params(keys, *, d, heads, head_dim, d_state, groups, conv, dtype):
+    """A mamba layer's parameters from five keys (the in-projection, the
+    conv's taps and bias, the out-projection, the step, the rate), as
+    ``init_params`` says they are drawn."""
+    inner = heads * head_dim
+    channels = inner + 2 * groups * d_state
+    bound = conv ** -0.5
+    step = jnp.exp(jax.random.uniform(
+        keys[3], (heads,), _F32, np.log(0.001), np.log(0.1)))
+    taps = jax.random.uniform(keys[1], (conv + 1, channels), _F32,
+                              -bound, bound).astype(dtype)
+    return dict(
+        w_zxbcdt=_normal(keys[0], shape=(d, inner + channels + heads),
+                         std=0.02, dtype=dtype),
+        w_conv=taps[:conv], b_conv=taps[conv],
+        w_out=_normal(keys[2], shape=(inner, d), std=0.02, dtype=dtype),
+        w_norm=jnp.ones((inner,), dtype),
+        dt_bias=jnp.log(jnp.expm1(step)),
+        A_log=jnp.log(jax.random.uniform(keys[4], (heads,), _F32, 1.0,
+                                         16.0)),
+        D=jnp.ones((heads,), _F32))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "kind", "d", "heads", "kv_heads", "head_dim", "width", "mamba_n_heads",
-    "mamba_d_head", "mamba_d_state", "conv", "dtype"))
+    "mamba_d_head", "mamba_d_state", "mamba_n_groups", "conv", "dtype"))
 def _init_layer(key, *, kind, d, heads, kv_heads, head_dim, width,
-                mamba_n_heads, mamba_d_head, mamba_d_state, conv, dtype):
+                mamba_n_heads, mamba_d_head, mamba_d_state, conv, dtype,
+                mamba_n_groups=1):
     """One layer's parameters: one program a kind of layer."""
     def normal(k, *shape, std=0.02):
         return _normal(k, shape=shape, std=std, dtype=dtype)
@@ -431,33 +510,20 @@ def _init_layer(key, *, kind, d, heads, kv_heads, head_dim, width,
           "w_up": normal(lk[1], d, width),
           "w_down": normal(lk[2], width, d)}
     if kind == ATTENTION:
-        qk = QK_ROW_STD * d ** -0.5
-        lp.update(wq=normal(lk[3], d, heads * head_dim, std=qk),
-                  wk=normal(lk[4], d, kv_heads * head_dim, std=qk),
-                  wv=normal(lk[5], d, kv_heads * head_dim),
-                  wo=normal(lk[6], heads * head_dim, d))
-        return lp
-    H, inner = mamba_n_heads, mamba_n_heads * mamba_d_head
-    channels = inner + 2 * mamba_d_state
-    bound = conv ** -0.5
-    step = jnp.exp(jax.random.uniform(
-        lk[7], (H,), _F32, np.log(0.001), np.log(0.1)))
-    taps = jax.random.uniform(lk[4], (conv + 1, channels), _F32,
-                              -bound, bound).astype(dtype)
-    lp.update(
-        w_zxbcdt=normal(lk[3], d, inner + channels + H),
-        w_conv=taps[:conv], b_conv=taps[conv],
-        w_out=normal(lk[5], inner, d),
-        w_norm=jnp.ones((inner,), dtype),
-        dt_bias=jnp.log(jnp.expm1(step)),
-        A_log=jnp.log(jax.random.uniform(lk[8], (H,), _F32, 1.0, 16.0)),
-        D=jnp.ones((H,), _F32))
+        lp.update(attention_params(
+            lk[3:7], d=d, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            qk_row_std=QK_ROW_STD, dtype=dtype))
+    else:
+        lp.update(mamba_params(
+            (lk[3], lk[4], lk[5], lk[7], lk[8]), d=d, heads=mamba_n_heads,
+            head_dim=mamba_d_head, d_state=mamba_d_state,
+            groups=mamba_n_groups, conv=conv, dtype=dtype))
     return lp
 
 
 def init_params(key, *, vocab, d, heads, kv_heads, head_dim, layer_types,
                 width, mamba_n_heads, mamba_d_head, mamba_d_state, conv,
-                dtype):
+                dtype, mamba_n_groups=1):
     """Every matrix N(0, 0.02) in ``dtype`` but an attention layer's q
     and k projections (``QK_ROW_STD``), every norm scale 1.  The
     recurrence's parameters as Mamba-2 initialises them, float32:
@@ -473,7 +539,7 @@ def init_params(key, *, vocab, d, heads, kv_heads, head_dim, layer_types,
     sizes = dict(d=d, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
                  width=width, mamba_n_heads=mamba_n_heads,
                  mamba_d_head=mamba_d_head, mamba_d_state=mamba_d_state,
-                 conv=conv, dtype=dtype)
+                 mamba_n_groups=mamba_n_groups, conv=conv, dtype=dtype)
     return {"emb": _normal(ks[0], shape=(vocab, d), std=0.02, dtype=dtype),
             "w_f": jnp.ones((d,), dtype),
             "layers": [_init_layer(k, kind=kind, **sizes)
@@ -506,9 +572,8 @@ class GraniteHybridLM(StateEntryLM):
         layer_types = tuple(layer_types)
         super().__init__(vocab, d_model, num_heads, len(layer_types),
                          max_len, page_size, pages_per_seq, bos_id, eos_id)
-        if mamba_n_groups != 1:
-            raise ValueError("one group: B and C shared by all heads is "
-                             "what the step and the scan lay out")
+        if int(mamba_n_heads) % int(mamba_n_groups):
+            raise ValueError("the groups have to divide the mamba heads")
         if num_heads % num_kv_heads:
             raise ValueError("the K/V heads have to divide the query heads")
         self.dh, self.kv_heads = int(head_dim), int(num_kv_heads)
@@ -521,6 +586,7 @@ class GraniteHybridLM(StateEntryLM):
             mamba_n_heads=int(mamba_n_heads),
             mamba_d_head=int(mamba_d_head),
             mamba_d_state=int(mamba_d_state),
+            mamba_n_groups=int(mamba_n_groups),
             eps=float(rms_norm_eps),
             embedding_multiplier=float(embedding_multiplier),
             residual_multiplier=float(residual_multiplier),
@@ -534,7 +600,8 @@ class GraniteHybridLM(StateEntryLM):
             heads=self.heads, kv_heads=self.kv_heads, head_dim=self.dh,
             layer_types=layer_types, width=int(intermediate_size),
             mamba_n_heads=H, mamba_d_head=P, mamba_d_state=N,
-            conv=self.conv_taps, dtype=dtype)
+            mamba_n_groups=self.block.mamba_n_groups, conv=self.conv_taps,
+            dtype=dtype)
         # a page's row as the gauges count it: the published K/V heads
         # (stored ``pack`` a row of whole lanes, nothing padded)
         self.stored_heads = self.kv_heads
@@ -542,4 +609,5 @@ class GraniteHybridLM(StateEntryLM):
             num_pages, dtype, int(state_entries),
             (self.kv_heads // pack, pack * self.dh),
             (H // state_pack, N, state_pack * P),
-            tail_shape(self.conv_taps, H * P + 2 * N))
+            tail_shape(self.conv_taps,
+                       H * P + 2 * self.block.mamba_n_groups * N))

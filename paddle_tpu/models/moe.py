@@ -4,14 +4,19 @@
 and the stacked expert weights: float32 router scores by the block's
 scoring rule (``softmax_scores``: OLMoE's; ``sigmoid_scores``: the
 DeepSeek-V3 router K-EXAONE uses), top-k, then the sum over each row's
-chosen experts, computed in one of two ways that ``expert_path`` picks
-from the call's static shape at trace time (no option selects one):
+chosen experts.  What ONE expert computes is the block's to say too
+(``form``): ``SWIGLU``, three matrices, ``W_down(silu(W_gate m) * W_up
+m)``, every model's but one; ``RELU2``, two, ``W_down relu(W_up m)^2``
+(Nemotron-H's).  The sum is computed in one of two ways that
+``expert_path`` picks from the call's static shape at trace time (no
+option selects one):
 
 - ``grouped``, a call of many rows (a prefill bucket over
   ``DENSE_MAX_ROWS``) or of so few that most experts get none (a step
   of a few slots): a stable sort of the ``rows x k`` assignments by
   expert, the grouped GEMMs over sorted rows (``pallas/grouped_gemm.py``
-  on a TPU: gate and up in one call, then down, each streaming the
+  on a TPU: the matrices in front of the activation in one call whose
+  epilogue applies it, then down, each streaming the
   matrices of the experts that were hit once; ``jax.lax.ragged_dot``,
   its reference, a projection elsewhere and under ``pallas.enable(False)``),
   and the weighted sum of each row's results.  How many sorted rows a
@@ -60,6 +65,8 @@ a group step (``kept_groups``: DeepSeek-V3's ``n_group`` /
 
 from __future__ import annotations
 
+from typing import Callable, NamedTuple
+
 import numpy as np
 
 import jax
@@ -67,6 +74,7 @@ import jax.numpy as jnp
 
 from paddle_tpu import pallas as _pallas
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.pallas import expert_acts
 from paddle_tpu.pallas import grouped_gemm as _gg
 
 _F32 = jnp.float32
@@ -149,6 +157,21 @@ def count_load(phase: str, load: np.ndarray, rows: int, top_k: int,
         _M_GROUPED_ROWS.inc(assigned, rows="assigned", phase=phase)
         _M_GROUPED_ROWS.inc(blocks * block_rows, rows="computed",
                             phase=phase)
+
+
+class ExpertForm(NamedTuple):
+    """What one expert computes in front of its down projection, the
+    block's to say as the scoring rule is: ``h = act(m W_1, ..)`` of
+    the float32 products with the matrices the caller hands in, and
+    ``fused``, the grouped GEMM call that computes ``h`` over sorted
+    rows with ``act`` as its epilogue."""
+
+    act: Callable
+    fused: Callable
+
+
+SWIGLU = ExpertForm(expert_acts.swiglu, _gg.gate_up)
+RELU2 = ExpertForm(expert_acts.relu2, _gg.up)
 
 
 def softmax_scores(logits):
@@ -273,10 +296,18 @@ def grouped_block_rows(rows: int, top_k: int, held: int, experts: int) -> int:
     on a chip that holds ``held`` of the router's ``experts``: about
     twice what even routing sends here, ``2 rows top_k held / experts``,
     rounded up to the kernel's row tile and never over ``rows x
-    top_k``, which is what it is when every expert is held.  A function
+    top_k``, which is what it is when every expert is held.  Where a
+    chip holds part of the experts and ``rows x top_k`` is a row tile or
+    more but no whole number of them (32 slots x 6: 192), "never over"
+    is that number rounded UP to whole row tiles, so that the block is
+    one the kernel takes (the rows behind the assignments are no
+    group's): at 192 rows ``jax.lax.ragged_dot`` read 5.8 times its
+    bytes' time (PERF.md section 6, PR 64).  A function
     of the call's static shape alone, like ``expert_path``."""
     full = rows * top_k
     want = -(-2 * full * held // experts)
+    if held != experts and full >= _gg.ROW_TILE:
+        full = -(-full // _gg.ROW_TILE) * _gg.ROW_TILE
     return min(full, -(-want // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
 
 
@@ -287,28 +318,28 @@ def grouped_blocks(held_assignments, block_rows: int):
     return -(-held_assignments // block_rows)
 
 
-def _grouped_gemms(xs, sizes, w_gate, w_up, w_down):
-    """Each expert's SwiGLU over its group of the sorted rows ``xs``
+def _grouped_gemms(xs, sizes, w_in, w_down, form):
+    """Each expert (``form`` of the matrices ``w_in``, then ``w_down``)
+    over its group of the sorted rows ``xs``
     (``sizes`` rows a group, in order; rows behind the last group are
     no group's and what comes out for them means nothing) -> float32.
     Float32 products of the operands as stored, ``h`` rounded to the
     rows' dtype in between, by the kernel or by its reference."""
-    _, d, f = w_gate.shape
+    _, d, f = w_in[0].shape
     with jax.named_scope("moe_experts"):
-        if _pallas.use_grouped_gemm(xs.dtype, w_gate.dtype, xs.shape[0],
+        if _pallas.use_grouped_gemm(xs.dtype, w_in[0].dtype, xs.shape[0],
                                     d, f):
             # one walk over the sorted rows for both calls
             kw = dict(walk=_gg.visits(sizes, xs.shape[0], _gg.ROW_TILE),
                       interpret=_pallas.interpret_mode())
-            h = _gg.gate_up(xs, w_gate, w_up, sizes, **kw)
+            h = form.fused(xs, *w_in, sizes, **kw)
             return _gg.grouped_gemm(h, w_down, sizes, **kw)
-        g = _gg.grouped_gemm_reference(xs, w_gate, sizes)
-        u = _gg.grouped_gemm_reference(xs, w_up, sizes)
-        h = (jax.nn.silu(g) * u).astype(xs.dtype)
+        h = form.act(*(_gg.grouped_gemm_reference(xs, w, sizes)
+                       for w in w_in)).astype(xs.dtype)
         return _gg.grouped_gemm_reference(h, w_down, sizes)
 
 
-def _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down, top_k):
+def _grouped_experts(m, w, expert_of, sizes, w_in, w_down, top_k, form):
     """Every expert held: a stable sort of the ``R x k`` assignments by
     expert, one grouped GEMM a projection over all of the sorted rows
     (each is some group's), un-sort, weighted sum."""
@@ -316,15 +347,15 @@ def _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down, top_k):
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(expert_of, stable=True)
         xs = m[order // top_k]                               # sorted rows
-    ys = _grouped_gemms(xs, sizes, w_gate, w_up, w_down)
+    ys = _grouped_gemms(xs, sizes, w_in, w_down, form)
     with jax.named_scope("moe_combine"):
         back = jnp.zeros((R * top_k,), jnp.int32).at[order].set(
             jnp.arange(R * top_k, dtype=jnp.int32))
         return jnp.einsum("rk,rkd->rd", w, ys[back].reshape(R, top_k, d))
 
 
-def _grouped_held_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
-                          top_k, block_rows):
+def _grouped_held_experts(m, w, expert_of, sizes, w_in, w_down, top_k,
+                          block_rows, form):
     """Part of the experts held: the same sum over the assignments that
     are some held expert's group, and over nothing else.  Those sort
     first (``expert_of`` is C for the others); they are taken in blocks
@@ -332,7 +363,7 @@ def _grouped_held_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
     (``grouped_blocks``: one, under routing anywhere near even), so the
     result is exact for any routing, a call whose rows all choose held
     experts included, at a cost that follows what is held.  A block
-    gathers its ``(B, d)`` rows, runs the three grouped GEMMs with the
+    gathers its ``(B, d)`` rows, runs the grouped GEMMs with the
     group sizes clipped to the block, and adds its weighted ``(B, d)``
     result into the ``(R, d)`` output by row: nothing of ``R x k`` rows
     by ``d`` columns exists."""
@@ -355,7 +386,7 @@ def _grouped_held_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
             of_block = (jnp.clip(ends, lo, lo + B)
                         - jnp.clip(starts, lo, lo + B))
             xs = m[row]
-        ys = _grouped_gemms(xs, of_block, w_gate, w_up, w_down)
+        ys = _grouped_gemms(xs, of_block, w_in, w_down, form)
         with jax.named_scope("moe_combine"):
             # the rows behind the last group are no group's: whatever
             # the kernel left there is not read
@@ -367,36 +398,39 @@ def _grouped_held_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
                              jnp.zeros((R, d), _F32))
 
 
-def _dense_experts(m, w, expert_of, w_gate, w_up, w_down, top_k):
+def _dense_experts(m, w, expert_of, w_in, w_down, top_k, form):
     """The same sum with every row through every held expert, as
     matmuls batched over the experts: the weights are read once where
     they lie, and the routing weight, 0 for an expert a row did not
     choose (or that is held elsewhere), does the selecting."""
     R, d = m.shape
-    C = w_gate.shape[0]
+    C = w_down.shape[0]
     with jax.named_scope("moe_dispatch"):
         # a row chooses an expert at most once: each sum has one term
         chose = expert_of.reshape(R, top_k, 1) == jnp.arange(C)
         wc = jnp.sum(jnp.where(chose, w[:, :, None], 0.0), axis=1)  # (R, C)
     with jax.named_scope("moe_experts"):
         ms = jnp.broadcast_to(m, (C, R, d))
-        g = jnp.einsum("crd,cdf->crf", ms, w_gate,
-                       preferred_element_type=_F32)
-        u = jnp.einsum("crd,cdf->crf", ms, w_up,
-                       preferred_element_type=_F32)
-        h = (jax.nn.silu(g) * u).astype(m.dtype)
+        h = form.act(*(jnp.einsum("crd,cdf->crf", ms, w_i,
+                                  preferred_element_type=_F32)
+                       for w_i in w_in)).astype(m.dtype)
         ys = jnp.einsum("crf,cfd->crd", h, w_down,
                         preferred_element_type=_F32)
     with jax.named_scope("moe_combine"):
         return jnp.einsum("rc,crd->rd", wc, ys)
 
 
-def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
-                   scores=softmax_scores, held=None, groups=None):
-    """``sum_e w_e * W_down,e( silu(W_gate,e m) * W_up,e m )`` over
-    those of each row's ``top_k`` experts that are held here.
+def routed_experts(m, wr, *weights, top_k: int, live=None,
+                   scores=softmax_scores, held=None, groups=None,
+                   form=SWIGLU):
+    """``sum_e w_e * expert_e(m)`` over those of each row's ``top_k``
+    experts that are held here; an expert by ``form``: ``SWIGLU``,
+    ``W_down,e( silu(W_gate,e m) * W_up,e m )`` of ``weights = (w_gate,
+    w_up, w_down)``, or ``RELU2``, ``W_down,e relu(W_up,e m)^2`` of
+    ``weights = (w_up, w_down)``.
 
-    m (R, d); wr (d, E); w_gate, w_up (C, d, f); w_down (C, f, d), the
+    m (R, d); wr (d, E); the matrices in front of the activation (C, d,
+    f); w_down (C, f, d), the
     C experts ``held = (first, C)`` names of the router's E (None: all
     of them, C == E); ``live`` (R,) bool or None (all rows) -> (y
     (R, d) float32, load (C,) int32: assignments per held expert over
@@ -409,6 +443,7 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
     assignments at a time the grouped one runs."""
     R, d = m.shape
     E = wr.shape[1]
+    *w_in, w_down = weights
     first, C = held or (0, E)
     partial = (first, C) != (0, E)
     path = expert_path(R, top_k, E)
@@ -434,14 +469,14 @@ def routed_experts(m, wr, w_gate, w_up, w_down, *, top_k: int, live=None,
         elsewhere = (R if live is None else jnp.sum(live)) * top_k \
             - jnp.sum(load)
     if path == "dense":
-        y = _dense_experts(m, w, expert_of, w_gate, w_up, w_down, top_k)
+        y = _dense_experts(m, w, expert_of, w_in, w_down, top_k, form)
     elif partial:
         y = _grouped_held_experts(
-            m, w, expert_of, sizes, w_gate, w_up, w_down, top_k,
-            grouped_block_rows(R, top_k, C, E))
+            m, w, expert_of, sizes, w_in, w_down, top_k,
+            grouped_block_rows(R, top_k, C, E), form)
     else:
-        y = _grouped_experts(m, w, expert_of, sizes, w_gate, w_up, w_down,
-                             top_k)
+        y = _grouped_experts(m, w, expert_of, sizes, w_in, w_down, top_k,
+                             form)
     if kept is None:
         return y, load, elsewhere
     with jax.named_scope("moe_dispatch"):

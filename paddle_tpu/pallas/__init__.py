@@ -253,14 +253,16 @@ def use_kda_chunked(state_dtype, rows: int, heads: int, d_v: int, d_k: int,
         True))
 
 
-def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int) -> bool:
+def use_ssd_step(state_dtype, rows: int, d_state: int, lanes: int,
+                 groups: int = 1) -> bool:
     """A state-space layer's decode step over entries ``(rows, d_state,
-    lanes)``, by the same rule as ``use_gated_delta_step``: the kernel
-    wherever ``fits()`` holds, else gathered and scattered in XLA."""
+    lanes)`` whose heads read B and C in ``groups`` groups, by the same
+    rule as ``use_gated_delta_step``: the kernel wherever ``fits()``
+    holds, else gathered and scattered in XLA."""
     from paddle_tpu.pallas import ssd_step as _s
 
     return dispatch("ssd_step", policy(
-        _s.fits(state_dtype, rows, d_state, lanes), True))
+        _s.fits(state_dtype, rows, d_state, lanes, groups), True))
 
 
 def use_s6_step(state_dtype, d_state: int, channels: int) -> bool:
@@ -300,8 +302,9 @@ def use_latent_paged_attention(pool_dtype, page_size: int, rows: int,
 
 
 def use_grouped_gemm(row_dtype, w_dtype, rows: int, d: int, f: int) -> bool:
-    """A routed layer's three grouped GEMMs over ``rows`` sorted rows of
-    width ``d`` (experts of ``d x f``: gate and up together, then down)
+    """A routed layer's grouped GEMMs over ``rows`` sorted rows of
+    width ``d`` (experts of ``d x f``: the matrices in front of the
+    activation together, gate and up or up alone, then down)
     by the kernel wherever ``fits()`` holds both ways round,
     by the decode kernels' rule: no threshold; else
     ``jax.lax.ragged_dot`` (``grouped_gemm_reference``)."""

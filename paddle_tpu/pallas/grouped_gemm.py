@@ -44,7 +44,9 @@ group's.
 ``gate_up`` is the same walk with two matrices a visit: it reads the
 tile once for both and writes ``silu(g) * u`` rounded to the rows'
 dtype, the ``h`` of a SwiGLU, where two calls would write two float32
-arrays for XLA to read back.
+arrays for XLA to read back.  ``up`` is the walk with ONE matrix whose
+epilogue squares the rectified product: the ``h`` of an expert of two
+matrices, ``relu(xs @ w_up)^2`` (Nemotron-H's ``relu2``).
 """
 
 from __future__ import annotations
@@ -55,6 +57,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.pallas import expert_acts
 
 _F32 = jnp.float32
 LANES = 128
@@ -164,11 +168,13 @@ def visits(sizes, rows: int, tm: int):
 
 
 def _kernel(tile_of, group_of, next_of, starts, ends, x_ref, *refs, tm, tn,
-            mats, precision):
+            mats, precision, act):
     """One visit.  ``x_ref`` (tm, K), the visit's row tile; ``mats``
     stacks of matrices (C, K, N) in HBM; the output tile (tm, tn); a
     double buffer (2, K, tn) a stack, a DMA semaphore a buffer, and in
-    SMEM the half that holds the visit's group."""
+    SMEM the half that holds the visit's group.  ``act``: what is
+    written of the visit's float32 products (one a stack), or None for
+    the one product as it is."""
     w_hbm, o_ref = refs[:mats], refs[mats]
     bufs, sems, half = refs[mats + 1:2 * mats + 1], refs[-2], refs[-1]
     n, v = pl.program_id(0), pl.program_id(1)
@@ -220,7 +226,7 @@ def _kernel(tile_of, group_of, next_of, starts, ends, x_ref, *refs, tm, tn,
         y = [jnp.dot(x_ref[...], buf[slot, :, at],
                      preferred_element_type=_F32, precision=precision)
              for buf in bufs]
-        y = y[0] if mats == 1 else jax.nn.silu(y[0]) * y[1]
+        y = y[0] if act is None else act(*y)
         kept = jnp.where(first_visit, 0, o_ref[:, at])
         o_ref[:, at] = jnp.where(mine, y.astype(o_ref.dtype), kept)
         return carry
@@ -228,7 +234,7 @@ def _kernel(tile_of, group_of, next_of, starts, ends, x_ref, *refs, tm, tn,
     jax.lax.fori_loop(0, tn // tc, columns, 0)
 
 
-def _plan(xs, ws, sizes, walk, out_dtype, tm, tn, interpret):
+def _plan(xs, ws, sizes, walk, out_dtype, tm, tn, interpret, act=None):
     """The kernel of one call, ``pl.pallas_call``'s other arguments and
     the call's operands."""
     M, K = xs.shape
@@ -241,7 +247,7 @@ def _plan(xs, ws, sizes, walk, out_dtype, tm, tn, interpret):
     # them: the default is one bfloat16 pass on a TPU
     precision = (jax.lax.Precision.HIGHEST if xs.dtype == _F32 else None)
     kernel = functools.partial(_kernel, tm=tm, tn=tn, mats=len(ws),
-                               precision=precision)
+                               precision=precision, act=act)
     kwargs = dict(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,    # the walk, in SMEM
@@ -287,6 +293,18 @@ def gate_up(xs, w_gate, w_up, sizes, *, walk=None, tm: int = 0, tn: int = 0,
     """``silu(xs @ w_gate[g]) * (xs @ w_up[g])`` over the same walk,
     both products float32, the result rounded to the rows' dtype."""
     kernel, kwargs, operands = _plan(xs, (w_gate, w_up), sizes, walk,
-                                     xs.dtype, tm, tn, interpret)
+                                     xs.dtype, tm, tn, interpret,
+                                     expert_acts.swiglu)
     return pl.pallas_call(kernel, name="grouped_gemm_gate_up",
+                          **kwargs)(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
+def up(xs, w_up, sizes, *, walk=None, tm: int = 0, tn: int = 0,
+       interpret: bool = False):
+    """``relu(xs @ w_up[g])^2`` over the same walk, the product float32,
+    the result rounded to the rows' dtype."""
+    kernel, kwargs, operands = _plan(xs, (w_up,), sizes, walk, xs.dtype,
+                                     tm, tn, interpret, expert_acts.relu2)
+    return pl.pallas_call(kernel, name="grouped_gemm_up",
                           **kwargs)(*operands)

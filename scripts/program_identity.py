@@ -3,7 +3,9 @@
 text (XLA:CPU, toy size) of `_decode_step`, `_verify_step`,
 `_prefill_chunk` and `_prefill_bucket` for `Gpt2Block`, `OlmoeBlock`,
 `ExaoneMoeBlock` and `OlmoHybridBlock` (the step and the bucket alone:
-a model over state entries refuses the other two) with the metadata
+a model over state entries refuses the other two) and, since PR 64, for
+Granite, Ling, LFM2, MiMo, Kanana, GLM-5 and Phi-4-mini-flash at their
+own tests' toy sizes, with the metadata
 dropped (op_name / source lines, and the file and function tables at
 the head of the text), one file a program:
 
@@ -17,6 +19,7 @@ functions changes only what is dropped here.  It cannot see the TPU
 lowering of a Pallas kernel; compare the kernels' jaxprs for that.
 """
 
+import importlib
 import os
 import re
 import sys
@@ -51,11 +54,17 @@ def dump(out, name, model, dm):
             extra=model.extra_pools),
     }
     if getattr(model, "supports_verify", True):
-        lowered["verify"] = dm._verify_step.lower(
-            *pools, tables, lens, np.zeros((S, 3), np.int32), **kw)
-        lowered["chunk"] = dm._prefill_chunk.lower(
-            *pools, jnp.zeros((P,), jnp.int32), np.int32(8),
-            jnp.zeros((5,), jnp.int32), **kw)
+        for key, program, args in (
+                ("verify", dm._verify_step,
+                 (tables, lens, np.zeros((S, 3), np.int32))),
+                ("chunk", dm._prefill_chunk,
+                 (jnp.zeros((P,), jnp.int32), np.int32(8),
+                  jnp.zeros((5,), jnp.int32)))):
+            try:
+                lowered[key] = program.lower(*pools, *args, **kw)
+            except RuntimeError as refused:     # by name: no such program
+                if not type(refused).__name__.startswith("Unsupported"):
+                    raise
     for key, low in lowered.items():
         with open(os.path.join(out, f"{name}.{key}.txt"), "w") as f:
             f.write(strip(low.compile().as_text()))
@@ -87,6 +96,26 @@ def main(out):
         linear_num_value_heads=4, linear_key_head_dim=6,
         linear_value_head_dim=10, max_len=64, num_pages=40, page_size=8,
         pages_per_seq=8, state_entries=5, dtype="float32"), dm)
+    # every other model of the skeleton, at its own tests' toy sizes
+    # (this file's tree's: the sizes are data, the models PYTHONPATH's)
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tests"))
+    for name, module, cls, sizes, where in (
+            ("granite", "granite_hybrid", "GraniteHybridLM", "_GRANITE",
+             "hybrid_models"),
+            ("ling", "ling_hybrid", "LingHybridLM", "SIZES",
+             "test_ling_hybrid"),
+            ("lfm2", "lfm2_moe", "Lfm2MoeLM", "SIZES", "test_lfm2_moe"),
+            ("mimo", "mimo_v2", "MimoV2LM", "SIZES", "test_mimo_v2"),
+            ("kanana", "kanana_mla", "KananaMlaLM", "SIZES",
+             "test_kanana_mla"),
+            ("glm", "glm_dsa", "GlmDsaLM", "SIZES", "test_glm_dsa"),
+            ("phi4", "phi4_flash", "Phi4FlashLM", "SIZES",
+             "test_phi4_flash")):
+        model = getattr(importlib.import_module(
+            f"paddle_tpu.models.{module}"), cls)
+        dump(out, name, model(seed=3, **getattr(
+            importlib.import_module(where), sizes)), dm)
     print("tree", os.path.dirname(os.path.dirname(dm.__file__)), "dumped",
           len(os.listdir(out)), "programs to", out)
 

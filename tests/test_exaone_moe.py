@@ -12,6 +12,7 @@ what the issue sets; the mildest ablation (the bias in the weights)
 reads 1.3e-3, every other one 1e-2 to 0.7.
 """
 
+import json
 import os
 import re
 import sys
@@ -297,18 +298,67 @@ def test_a_share_is_the_same_sum_on_both_paths(tie):
     ((4608, 8, 16, 128), 2 * 4608),
     ((4, 8, 16, 128), 32),              # a step of few slots: all of it
     ((2048, 8, 64, 64), 2048 * 8),      # every expert held: rows x k
-    ((300, 3, 8, 16), 900),             # half of them held: twice is all
+    # half of them held: twice is all, in whole row tiles of the kernel
+    # since PR 64 (900 rows were no block the kernel takes)
+    ((300, 3, 8, 16), 1024),
+    ((32, 6, 16, 128), 256),            # Nemotron-H's step: 192, a tile up
+    ((20, 6, 16, 128), 120),            # under one row tile: as it is
     ((279, 3, 2, 16), 256),             # rounded up to the GEMM's row tile
     ((279, 3, 6, 16), 768)],
     ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
 def test_a_block_is_twice_the_even_share_in_whole_tiles(shape, want):
     rows, k, held, experts = shape
     B = moe.grouped_block_rows(rows, k, held, experts)
-    assert B == want <= rows * k
-    assert B == rows * k or (B % moe.GROUPED_ROW_TILE == 0
-                             and B >= 2 * rows * k * held / experts)
+    full, tile = rows * k, moe._gg.ROW_TILE
+    assert B == want
+    if held == experts or full < tile:
+        # as before PR 64: never over all of it
+        assert B <= full
+        assert B == full or (B % moe.GROUPED_ROW_TILE == 0
+                             and B >= 2 * full * held / experts)
+    else:
+        # a share's block is whole row tiles: all of it is a tile up
+        assert B % tile == 0 and B <= -(-full // tile) * tile
+        assert B >= min(2 * full * held / experts, full)
     assert [int(moe.grouped_blocks(n, B)) for n in (0, 1, B, B + 1)] == [
         0, 1, 1, 2]
+
+
+# (top_k, held, router's width, slots) of every accepted cell that holds
+# a share of its experts, from perf/configs and perf/traffic
+HELD_CELLS = {
+    "k-exaone-236b-a23b-generate-mixed": (8, 16, 128, 64),
+    "kanana-2-30b-a3b-generate-longdoc": (6, 16, 128, 64),
+    "glm-5-generate-longctx": (8, 16, 256, 32),
+    "ling-3.0-flash-generate-reasoning": (8, 128, 512, 128),
+    "mimo-v2.5-generate-agent": (8, 16, 256, 48),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(HELD_CELLS))
+def test_no_accepted_cells_block_moved_with_the_whole_tiles(cell):
+    """PR 64 rounds a share's ``rows x top_k`` up to whole row tiles; at
+    every shape an accepted cell calls with (its step at its slots, its
+    prefill buckets of 64 x 2^i rows, K-EXAONE's 4,608-row chunk pair)
+    the block is what the rule gave before, written out here."""
+    k, held, experts, slots = HELD_CELLS[cell]
+    perf = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perf")
+    with open(os.path.join(perf, "workloads", cell + ".json")) as f:
+        wl = json.load(f)
+    with open(os.path.join(perf, "configs", wl["config"] + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(perf, "traffic", wl["traffic"] + ".json")) as f:
+        assert json.load(f)["gen_slots"] == slots
+    assert (cfg["num_experts_per_tok"], cfg["num_experts"]) == (k, held)
+    assert experts in (cfg.get("num_experts_published"),
+                       cfg.get("n_routed_experts_published"))
+    for rows in [slots, 4608] + [64 << i for i in range(8)]:
+        full = rows * k
+        want = -(-2 * full * held // experts)
+        before = min(full, -(-want // moe.GROUPED_ROW_TILE)
+                     * moe.GROUPED_ROW_TILE)
+        assert moe.grouped_block_rows(rows, k, held, experts) == before, rows
+        assert full % moe._gg.ROW_TILE == 0
 
 
 def _routed_to(choices, rng, E=16):
